@@ -74,9 +74,11 @@ class ProgramSpec:
     notes: str = ""
 
 
-def _scenario():
-    """A small zoned cluster + two-template backlog through the REAL
-    encoder (the same row/vocab layout production snapshots have)."""
+def _scenario(num_nodes: int = 13):
+    """A zoned cluster of `num_nodes` + two-template backlog through
+    the REAL encoder (the same row/vocab layout production snapshots
+    have). The default is small and non-pow2 (exercises node padding);
+    tests/test_chip_compile.py asks for the served path's real sizes."""
     from kubernetes_tpu.api.types import (
         Container,
         Node,
@@ -104,14 +106,14 @@ def _scenario():
                 conditions=[NodeCondition("Ready", "True")],
             ),
         )
-        for i in range(13)  # non-pow2: exercises node padding
+        for i in range(num_nodes)
     ]
     existing = [
         Pod(
             metadata=ObjectMeta(name=f"audit-e{i}",
                                 labels={"app": "web"}),
             spec=PodSpec(
-                node_name=f"audit-n{i % 13:02d}",
+                node_name=f"audit-n{i % num_nodes:02d}",
                 containers=[Container(requests={"cpu": "500m",
                                                 "memory": "1Gi"})],
             ),
@@ -136,22 +138,40 @@ def _scenario():
     return snap, batch
 
 
-def build_programs(include_mesh: bool = True) -> List[ProgramSpec]:
-    """Construct every registered program + its representative args."""
+def build_programs(include_mesh: bool = True, num_nodes: int = 13,
+                   run_length: int = 24) -> List[ProgramSpec]:
+    """Construct every registered program + its representative args.
+
+    Shapes follow the DRIVERS' OWN bucketing of a `num_nodes` cluster
+    whose longest template run is `run_length` pods: the node axis pads
+    to next_pow2(n, 64) (scheduler/tpu_algorithm), J comes from
+    wave.pick_j, the replay lengths from wave.replay_k_bucket, the scan
+    batch from the wave's pod_floor — so the same registry yields the
+    toy audit shapes and the real widths the chip-compile tests use."""
     import jax
     import jax.numpy as jnp
 
     from kubernetes_tpu.models.batch import BatchScheduler, SchedulerConfig
     from kubernetes_tpu.models.pack import pack_arrays
     from kubernetes_tpu.models.probe import WaveProbe
-    from kubernetes_tpu.models.wave import WaveScheduler, group_buffer
+    from kubernetes_tpu.models.wave import (
+        ZREPLAY_GROUP_K_FLOOR,
+        ZREPLAY_K_FLOOR,
+        WaveScheduler,
+        group_buffer,
+        pick_j,
+        replay_k_bucket,
+    )
     from kubernetes_tpu.models.zreplay import (
         _zreplay_fn,
         _zreplay_group_fn,
     )
+    from kubernetes_tpu.parallel.mesh import _pad_snapshot
+    from kubernetes_tpu.snapshot.pad import next_pow2, pad_batch
 
     config = SchedulerConfig()
-    snap, batch = _scenario()
+    snap, batch = _scenario(num_nodes)
+    snap = _pad_snapshot(snap, next_pow2(snap.num_nodes, 64))
     N = snap.num_nodes
     num_zones = max(int(snap.zone_id.max()) + 1, 1)
     num_values = int(snap.svc_num_values)
@@ -162,7 +182,11 @@ def build_programs(include_mesh: bool = True) -> List[ProgramSpec]:
     static.update(BatchScheduler.config_static(config, snap))
     carry = sched.initial_carry(snap)
     carry_leaves = len(jax.tree_util.tree_leaves(carry))
-    pods = {f: jnp.asarray(getattr(batch, f))
+    wave = WaveScheduler(config)
+    # the scan flush pads its pod axis to a pow2 bucket (wave.flush)
+    scan_batch = pad_batch(batch,
+                           next_pow2(batch.num_pods, wave.pod_floor))
+    pods = {f: jnp.asarray(getattr(scan_batch, f))
             for f in BatchScheduler.POD_FIELDS}
 
     rep = 0  # template-alpha row
@@ -173,9 +197,8 @@ def build_programs(include_mesh: bool = True) -> List[ProgramSpec]:
     buf = jnp.asarray(buf_host)
     counts = jnp.zeros((N,), jnp.int64)
 
-    wave = WaveScheduler(config)
     probe = WaveProbe(config)
-    J = 128
+    J, _rows = pick_j(config, wave.max_j, snap, batch, rep, run_length)
 
     specs: List[ProgramSpec] = [
         ProgramSpec(
@@ -202,8 +225,10 @@ def build_programs(include_mesh: bool = True) -> List[ProgramSpec]:
     # — with the fused fit+score+top-of-table reduction as a pallas_call
     # (ops/pallas_probe). The auditor recurses into the kernel jaxpr
     # via the pallas_call params, so the callback/f64/denylist rules
-    # cover the kernel body too.
-    probe_pallas = WaveProbe(config, kernel="pallas")
+    # cover the kernel body too. Interpret mode by name: the audit
+    # reads the kernel's jaxpr, and the compiled lowering is refused
+    # by the TPU compiler (64-bit types; tests/test_chip_compile.py).
+    probe_pallas = WaveProbe(config, kernel="pallas-interpret")
     specs.append(ProgramSpec(
         name="probe_pallas",
         fn=probe_pallas._compiled(num_zones, num_values, J),
@@ -315,7 +340,7 @@ def build_programs(include_mesh: bool = True) -> List[ProgramSpec]:
     zone_perm = jnp.asarray(
         np.ascontiguousarray(np.asarray(snap.zone_id)[perm], np.int32))
     veto_perm = jnp.asarray(np.zeros(N, bool))
-    K = 64
+    K = replay_k_bucket(run_length, ZREPLAY_K_FLOOR)
     zfn = jax.jit(functools.partial(
         _zreplay_fn, config, num_zones, num_values, J, K, layout,
         wave._apply_fn, False,
@@ -335,8 +360,9 @@ def build_programs(include_mesh: bool = True) -> List[ProgramSpec]:
     Gz = 8
     reps = [0, 24] * (Gz // 2)
     Gz_bucket, gzlayout, gzbuf_host = group_buffer(batch, reps)
+    Kg = replay_k_bucket(run_length, ZREPLAY_GROUP_K_FLOOR)
     zgfn = jax.jit(functools.partial(
-        _zreplay_group_fn, config, num_zones, num_values, J, K,
+        _zreplay_group_fn, config, num_zones, num_values, J, Kg,
         Gz_bucket, gzlayout, wave._apply_fn, None, None,
         wave._apply_group_fn,
     ))
@@ -348,7 +374,7 @@ def build_programs(include_mesh: bool = True) -> List[ProgramSpec]:
               zone_perm, jnp.asarray(np.zeros((Gz_bucket, N), bool)),
               jnp.asarray(np.ones(Gz_bucket, bool)),
               jnp.asarray(np.full(Gz_bucket, 32, np.int64)),
-              jnp.asarray(np.full(Gz_bucket, K, np.int32)),
+              jnp.asarray(np.full(Gz_bucket, Kg, np.int32)),
               np.int64(0)),
         allow_f64=True,
         carry_out_leaves=carry_leaves,
@@ -368,7 +394,7 @@ def build_programs(include_mesh: bool = True) -> List[ProgramSpec]:
     )
 
     cand = [
-        (snap.node_names[i % 13], i % 3, i, (500, 1 << 20, 0, 1))
+        (snap.node_names[i % num_nodes], i % 3, i, (500, 1 << 20, 0, 1))
         for i in range(9)
     ]
     vprio, vord, vres, _idx = pack_candidates(
@@ -482,9 +508,7 @@ def _mesh_programs(config, snap, batch, pod_layout, pod_buf_host,
     driver."""
     import jax
 
-    from kubernetes_tpu.parallel.compat import have_shard_map
-
-    if not have_shard_map() or len(jax.devices()) < 2:
+    if len(jax.devices()) < 2:
         return []
 
     from jax.sharding import Mesh
@@ -518,6 +542,9 @@ def _mesh_programs(config, snap, batch, pod_layout, pod_buf_host,
     J = 128
     M_bucket = 64
     wave = M.MeshWaveScheduler(mesh, config=config)
+    # zero-size carry leaves keep their result shardings unspecified,
+    # exactly as the driver dispatches them (mesh._carry_out_shardings)
+    empty = M.empty_leaves(carry)
 
     # the sharding-drift declarations: the SAME single-source specs the
     # resident placement uses — the audit fails if the driver's jit
@@ -534,7 +561,7 @@ def _mesh_programs(config, snap, batch, pod_layout, pod_buf_host,
             name="mesh_scan",
             fn=wave.scan._jit_for(static, n, n_per_shard, num_zones,
                                   num_values, batch.num_pods,
-                                  tuple(pods)),
+                                  tuple(pods), empty=empty),
             args=(static, carry, pods),
             allow_f64=True,
             carry_out_leaves=carry_leaves,
@@ -566,7 +593,7 @@ def _mesh_programs(config, snap, batch, pod_layout, pod_buf_host,
         ProgramSpec(
             name="mesh_apply",
             fn=wave._apply_program(static, n, n_per_shard, pod_layout,
-                                   donate=True),
+                                   donate=True, empty=empty),
             args=(static, carry, pod_buf_host, touch_idx, touch_cnt),
             carry_out_leaves=carry_leaves,
             expected_host_leaves=0,
@@ -598,7 +625,7 @@ def _mesh_programs(config, snap, batch, pod_layout, pod_buf_host,
     specs.append(ProgramSpec(
         name="mesh_apply_group",
         fn=wave._apply_group_program(static, n, n_per_shard, glayout,
-                                     donate=True),
+                                     donate=True, empty=empty),
         args=(static, carry, gbuf_host, g_idx, g_cnt),
         carry_out_leaves=carry_leaves,
         expected_host_leaves=0,

@@ -35,22 +35,31 @@ def _pipeline_snapshot():
     return trace_profile.exclusive_totals() if trace_span.enabled() else None
 
 
-def _wait_sched_ready(sched, out, timeout: float = 180.0) -> None:
+def _wait_sched_ready(sched, out, timeout: float = 180.0) -> float:
     """Block until the scheduling loop is open (informers synced +
-    run-path TPU programs warm). The density number measures steady-state
-    scheduling throughput — the reference's scheduler is likewise fully
-    up (informers synced, no compile analogue) before its harness starts
-    creating pods (scheduler_test.go:41 schedulerConfigFactory wiring).
-    Daemon boot cost is reported separately here, not buried in the
-    throughput window."""
+    run-path TPU programs warm); -> the seconds it took. The density
+    number measures steady-state scheduling throughput — the
+    reference's scheduler is likewise fully up (informers synced, no
+    compile analogue) before its harness starts creating pods
+    (scheduler_test.go:41 schedulerConfigFactory wiring). Daemon boot
+    cost is reported separately here, not buried in the throughput
+    window. A daemon whose device backend or warmup failed never
+    becomes ready: raise its reason at once instead of waiting out the
+    deadline."""
     t0 = time.time()
-    if sched.ready.wait(timeout):
-        print(f"scheduler ready in {time.time() - t0:.1f}s", file=out)
-    else:
-        raise RuntimeError(
-            f"scheduler not ready after {timeout:.0f}s; the density "
-            "window would silently include boot cost"
-        )
+    while not sched.ready.wait(0.1):
+        if sched.start_error is not None:
+            raise RuntimeError(
+                "scheduler daemon failed to start"
+            ) from sched.start_error
+        if time.time() - t0 > timeout:
+            raise RuntimeError(
+                f"scheduler not ready after {timeout:.0f}s; the density "
+                "window would silently include boot cost"
+            )
+    secs = time.time() - t0
+    print(f"scheduler ready in {secs:.1f}s", file=out)
+    return secs
 
 
 def _phase_table(before, wall: float, out,
@@ -227,7 +236,7 @@ def _scrape_counters(client) -> dict:
 
 def schedule_pods_separate(
     num_nodes: int, num_pods: int, provider: str = "TPUProvider",
-    out=sys.stdout,
+    out=sys.stdout, check=None,
 ):
     """The density test across PROCESS boundaries, like the reference's
     real deployment (separate daemons): the apiserver runs in its own
@@ -235,8 +244,12 @@ def schedule_pods_separate(
     scheduler + measurement here. Returns a per-rep stats dict:
     pods_per_sec (the headline window), pipeline_seconds /
     sustained_pods_per_sec (creation-start -> all-bound — the honest
-    end-to-end number when the headline window is degenerate), and the
-    apiserver's request/watch-event/cache counters."""
+    end-to-end number when the headline window is degenerate),
+    ready_seconds (daemon set-up, outside every window), and the
+    apiserver's request/watch-event/cache counters. `check(url)`, when
+    given, runs once every pod is bound and while the apiserver
+    process is still up — the seam chip_smoke.py reads the bindings
+    back through; its result lands under "check"."""
     import subprocess
 
     from kubernetes_tpu.client.transport import HTTPTransport
@@ -247,7 +260,8 @@ def schedule_pods_separate(
     api_proc = subprocess.Popen(
         [sys.executable, "-m", "kubernetes_tpu.hyperkube", "apiserver",
          "--port", "0", "--enable-binary-wire"],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+        # stderr is the parent's: a child that dies says why
+        stdout=subprocess.PIPE, text=True,
     )
     creator = None
     sched = None
@@ -273,7 +287,7 @@ def schedule_pods_separate(
         sched = SchedulerServer(
             client, SchedulerServerOptions(algorithm_provider=provider)
         ).start()
-        _wait_sched_ready(sched, out)
+        ready_secs = _wait_sched_ready(sched, out)
 
         def count_scheduled() -> int:
             return len(sched.factory.assigned_informer.store.list_keys())
@@ -303,6 +317,7 @@ def schedule_pods_separate(
         pipeline_secs = time.time() - t0
         stats = {
             "pods_per_sec": rate,
+            "ready_seconds": round(ready_secs, 2),
             "creation_seconds": round(created_secs, 2),
             "pipeline_seconds": round(pipeline_secs, 2),
             "sustained_pods_per_sec": round(num_pods / pipeline_secs, 1),
@@ -350,6 +365,8 @@ def schedule_pods_separate(
                 f"{stats.get('batch_objects', 0)} objects",
                 file=out,
             )
+        if check is not None:
+            stats["check"] = check(url)
         return stats
     finally:
         if sched is not None:

@@ -197,7 +197,7 @@ _LIB_FAILED = False
 def _load_lib():
     global _LIB, _LIB_FAILED
     if _LIB is None and not _LIB_FAILED:
-        # Build on demand (cached by mtime): the driver environment runs
+        # Build on demand (keyed by source hash): the driver environment runs
         # bench/tests with no manual `make` step, and the Python fallback
         # is ~10x slower — the fast path must be self-provisioning.
         from kubernetes_tpu.native.build import ensure_replay

@@ -1,26 +1,20 @@
 """Native (C) components.
 
-- `_replay.so`: the wave-replay engine (pure C, loaded via ctypes from
+- `_replay`: the wave-replay engine (pure C, loaded via ctypes from
   models/replay.py).
 - `_kquantity`: resource-quantity parser fast path (CPython extension).
+- `_ktlv`: the TLV wire codec (CPython extension, runtime/tlv.py).
 - `pause.c` (under build/pause/): the pod sandbox placeholder binary,
   mirroring the reference's only C file (build/pause/pause.c).
 
-Both libraries are self-provisioning: `build.ensure_all()` compiles them
-on demand (cached by source mtime) whenever a C compiler is present, so
-no manual `make -C kubernetes_tpu/native` step is needed. Importing this
-package without a built `_kquantity` and without a compiler raises
-ImportError; callers (api/resource.py) degrade to the pure-Python parser.
+The libraries are self-provisioning: `build.ensure_all()` compiles them
+on demand whenever a C compiler is present, each into a file named by a
+hash of its source and flags, so no manual build step is needed and a
+library built from other source is never loaded. Without a compiler or
+Python headers `_kquantity` is None and api/resource.py degrades to the
+pure-Python parser.
 """
 
 from kubernetes_tpu.native import build as _build
 
-_build.ensure_kquantity()
-
-try:
-    from kubernetes_tpu.native import _kquantity  # noqa: E402,F401
-except ImportError:
-    # No compiler / no Python headers: the package itself must stay
-    # importable (build.ensure_replay is reached through it), and
-    # api/resource.py degrades to the pure-Python parser.
-    pass
+_kquantity = _build.load_extension("_kquantity")

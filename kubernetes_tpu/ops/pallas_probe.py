@@ -11,15 +11,26 @@ tables once and emits a [BJ, N] tab block plus a frontier partial).
 
 Contract: bit-identical to the lax build. The kernel body calls the
 SAME score/predicate kernels (ops/priorities, ops/predicates) the lax
-path uses — on the CPU backend the kernel runs in interpret mode,
-where those jnp ops execute directly, so equality is by construction;
-on TPU the Mosaic lowering compiles the same ops. The BA score's f64
-reference math rides into the kernel (this file is on the auditor's
-f64 allowlist for exactly that reason).
+path uses — in interpret mode those jnp ops execute directly, so
+equality is by construction. The BA score's f64 reference math rides
+into the kernel (this file is on the auditor's f64 allowlist for
+exactly that reason).
+
+Interpret mode is the CALLER'S explicit choice (`interpret=True`,
+reached through WaveProbe(kernel="pallas-interpret")): tests, the
+analysis registry and the bench's CPU A/B ask for it by name. The
+default is the compiled lowering, which the TPU compiler REFUSES for
+this kernel today ("64-bit types are not supported": its refs, iota
+and accumulators are int64/f64 — tests/test_chip_compile.py pins the
+refusal), and which no other backend has. So KUBERNETES_TPU_KERNEL=
+pallas fails when the probe is constructed, with the compiler's
+reason, instead of quietly interpreting.
 
 Gating: the kernel is DEFAULT OFF. models/probe routes the resource
-section here only when the probe was built with kernel="pallas"
-(WaveProbe reads KUBERNETES_TPU_KERNEL at construction). Consumers
+section here only when the probe was built with kernel="pallas" or
+"pallas-interpret" (WaveProbe reads KUBERNETES_TPU_KERNEL at
+construction; the environment can only ask for the compiled build).
+Consumers
 that leave the j-table dead (the grouped header probe, the device
 replay) stay on the lax build unconditionally — a pallas_call is
 opaque to XLA's dead-code elimination, so routing them here would
@@ -106,7 +117,8 @@ def _kernel(pod_ref, a_cpu_ref, a_mem_ref, a_gpu_ref, a_pods_ref,
 
 
 def resource_probe(J: int, alloc, usage, pod, terms, *,
-                   wants_res: bool = True, bf16: bool = False):
+                   wants_res: bool = True, bf16: bool = False,
+                   interpret: bool = False):
     """-> (frontier i64[N], tab i64[J, N]) for a run-of-identical probe.
 
     alloc: (alloc_mcpu, alloc_mem, alloc_gpu, alloc_pods) node tables;
@@ -115,7 +127,8 @@ def resource_probe(J: int, alloc, usage, pod, terms, *,
     _POD_SCALARS are consumed); terms: (("lr"|"ba", weight), ...) —
     the config's LR/BA priorities in declaration order (accumulation
     order matters for the bf16 profile's rounding parity with the lax
-    build). Interpret mode off-TPU; compiled Mosaic lowering on TPU.
+    build). interpret=False is the compiled lowering, whatever the
+    backend; interpret=True runs the kernel body as jnp ops.
     """
     a_cpu, a_mem, a_gpu, a_pods = alloc
     N = a_cpu.shape[0]
@@ -135,6 +148,19 @@ def resource_probe(J: int, alloc, usage, pod, terms, *,
             jax.ShapeDtypeStruct((N,), jnp.int64),
             jax.ShapeDtypeStruct((J, N), jnp.int64),
         ],
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret,
     )(pod_vec, a_cpu, a_mem, a_gpu, a_pods, *usage)
     return frontier, tab
+
+
+def check_compiled_lowering() -> None:
+    """Lower the compiled (non-interpret) kernel once at a tiny shape
+    for the default backend; raises what that backend's compiler
+    raises. WaveProbe calls this at construction so asking for the
+    kernel where it cannot compile fails up front, not mid-wave."""
+    J, N = 16, 128
+    i64 = jax.ShapeDtypeStruct((N,), jnp.int64)
+    pod = {f: jax.ShapeDtypeStruct((), jnp.int64) for f in _POD_SCALARS}
+    jax.jit(functools.partial(
+        resource_probe, J, terms=(("lr", 1), ("ba", 1)),
+    )).lower((i64,) * 4, (i64,) * 6, pod)

@@ -11,12 +11,14 @@ Round 7: the cluster state is DEVICE-RESIDENT across waves
 (parallel/resident.ResidentClusterState).  Every program is pjit-shaped
 — ``jax.jit`` with explicit ``in_shardings``/``out_shardings`` built
 from the same PartitionSpecs the shard_map bodies declare — and the
-commit folds DONATE their carry input (``donate_argnums``, gated by
-``runtime_donation()``: on accelerator backends wave-to-wave commits
-mutate the resident sharded buffers in place, zero host round trips
-and zero realloc; this jaxlib's CPU client has a donation race, so CPU
-runs undonated while the auditor still enforces the donation contract
-on the lowered form).  Commit counts ship in scatter form (touched
+commit folds DONATE their carry input (``donate_argnums``): wave-to-
+wave commits mutate the resident sharded buffers in place, zero host
+round trips and zero realloc, on every backend (the CPU client's
+donation race that once kept CPU runs undonated did not reproduce on
+jaxlib 0.9.0 — PR 22 re-ran the churn loop that showed it — so the
+tests now execute the donated folds too; the auditor enforces the
+donation contract on the lowered form).  Commit counts ship in
+scatter form (touched
 node ids + amounts, O(pending pods)) instead of dense O(nodes) rows;
 steady-state waves ship no node table bytes at all (the jaxpr
 auditor's donation/transfer contract and tests/test_resident.py
@@ -33,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PSpec
 
-from kubernetes_tpu.parallel.compat import shard_map
 from kubernetes_tpu.parallel.resident import (
     AXIS,
     CARRY_FIELDS,
@@ -896,28 +897,27 @@ def _ns_tree(mesh: Mesh, specs):
     )
 
 
-def runtime_donation() -> bool:
-    """Whether the fold programs DONATE their carry at runtime.
+def empty_leaves(carry) -> tuple:
+    """Indices of the carry's zero-size leaves (a backlog without
+    inter-pod terms or services carries empty tables)."""
+    return tuple(i for i, x in enumerate(carry) if np.size(x) == 0)
 
-    On real accelerator backends donation is the point of the resident
-    design: the commit folds mutate the sharded carry in place, zero
-    realloc.  This jaxlib's CPU client, however, intermittently
-    corrupts the heap when a donated buffer is repossessed across
-    repeated aliased executions (reproduced as a ~1/3 segfault in the
-    daemon churn loop; a post-fold block_until_ready narrows but does
-    NOT close the window) — so on the CPU backend the folds run
-    undonated and pay a per-fold realloc instead.  The donation
-    CONTRACT is still enforced on every backend: the jaxpr auditor
-    lowers the donated form of each fold and requires every donated
-    leaf to alias an output (analysis/jaxpr_audit).
-    ``KUBERNETES_TPU_MESH_DONATE=1|0`` overrides the platform policy.
-    """
-    import os
 
-    env = os.environ.get("KUBERNETES_TPU_MESH_DONATE")
-    if env is not None:
-        return env not in ("0", "false", "off")
-    return jax.default_backend() != "cpu"
+def _carry_out_shardings(mesh: Mesh, empty: tuple):
+    """NamedShardings for a program's carry OUTPUT, with the zero-size
+    leaves left unspecified. The TPU compiler (libtpu 0.0.34) aborts
+    the process — `import_shardy_attrs.cc: Check failed:
+    funcResultSharding.getNumOperands() == 1 (2 vs. 1)` — on any
+    program with two or more zero-size int64 results that carry a
+    declared sharding, which ip_rev_pref/ip_rev_anti are whenever the
+    backlog has no inter-pod terms. An empty array has no bytes to
+    place: the compiler gives those results the replicated sharding
+    they had anyway (found compiling for a described v5e 2x2, PR 22;
+    no CPU run can show it)."""
+    return tuple(
+        None if i in empty else NamedSharding(mesh, s)
+        for i, s in enumerate(CARRY_SPECS)
+    )
 
 
 def _counts_from_touch(n_global, touch_idx, touch_cnt):
@@ -983,16 +983,17 @@ class MeshBatchScheduler:
         return np.asarray(chosen), final
 
     def _jit_for(self, static, n, n_per_shard, num_zones, num_values,
-                 num_pods, pods_keys):
+                 num_pods, pods_keys, empty=()):
         """The pjit-shaped sharded-scan program for one shape class:
         explicit in/out shardings, carry deliberately UNDONATED (see the
         NB below — donation + lax.scan inside shard_map miscompiles on
         this jaxlib's CPU backend, so a scan flush re-allocates its
         carry); host numpy inputs are placed per in_shardings on call.
-        Shared with analysis/programs so the audited program IS the
-        dispatched one."""
+        `empty` names the carry's zero-size leaves
+        (_carry_out_shardings). Shared with analysis/programs so the
+        audited program IS the dispatched one."""
         key = (n, n_per_shard, num_pods, num_zones, num_values,
-               tuple(sorted(static)))
+               tuple(sorted(static)), empty)
         run = self._jitted.get(key)
         if run is None:
             body = functools.partial(
@@ -1010,7 +1011,7 @@ class MeshBatchScheduler:
                 _static_specs(static), CARRY_SPECS,
                 {k: PSpec() for k in pods_keys},
             )
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 spmd,
                 mesh=self.mesh,
                 in_specs=specs,
@@ -1028,7 +1029,10 @@ class MeshBatchScheduler:
             run = jax.jit(
                 sharded,
                 in_shardings=_ns_tree(self.mesh, specs),
-                out_shardings=_ns_tree(self.mesh, (CARRY_SPECS, PSpec())),
+                out_shardings=(
+                    _carry_out_shardings(self.mesh, empty),
+                    NamedSharding(self.mesh, PSpec()),
+                ),
             )
             self._jitted[key] = run
         return run
@@ -1038,7 +1042,8 @@ class MeshBatchScheduler:
         """Run the sharded scan with an EXTERNAL carry (the mesh wave's
         fallback flush threads its resident carry through here)."""
         run = self._jit_for(static, n, n_per_shard, num_zones,
-                            num_values, num_pods, tuple(pods))
+                            num_values, num_pods, tuple(pods),
+                            empty=empty_leaves(carry))
         with self.mesh:
             final, chosen = run(static, carry, pods)
         return final, chosen
@@ -1142,17 +1147,18 @@ class MeshWaveScheduler:
     # -- pjit programs (builders shared with analysis/programs) --------------
 
     def _pjit_program(self, cache, key, body, arg_specs, out_specs,
-                      donate_carry=False):
+                      donate_carry=False, out_shardings=None):
         """One compile-cache slot for every mesh program: shard_map(body)
         wrapped pjit-shaped (jit with in/out shardings built from the
         SAME PartitionSpecs the shard_map declares), the carry (argnum
         1) donated when asked.  The four program families below differ
         only in body/specs/donation — one builder keeps their wrapping
-        from drifting."""
+        from drifting.  The folds pass their own `out_shardings`
+        (_carry_out_shardings; their key names the empty leaves)."""
         run = cache.get(key)
         if run is None:
             run = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body,
                     mesh=self.mesh,
                     in_specs=arg_specs,
@@ -1160,7 +1166,9 @@ class MeshWaveScheduler:
                     check_vma=False,
                 ),
                 in_shardings=_ns_tree(self.mesh, arg_specs),
-                out_shardings=_ns_tree(self.mesh, out_specs),
+                out_shardings=(
+                    _ns_tree(self.mesh, out_specs)
+                    if out_shardings is None else out_shardings),
                 donate_argnums=(1,) if donate_carry else (),
             )
             cache[key] = run
@@ -1195,17 +1203,16 @@ class MeshWaveScheduler:
         )
 
     def _apply_program(self, static, n, n_per_shard, pod_layout,
-                       donate=None):
-        """The commit fold: with donation the carry input aliases the
-        output (resident buffers mutate in place — runtime_donation()
-        decides per backend); scatter-form counts ride replicated.
-        Different idx/cnt bucket sizes compile per shape under this one
-        wrapper (jit's shape cache keys them)."""
-        if donate is None:
-            donate = runtime_donation()
+                       donate=True, empty=()):
+        """The commit fold: with donation (the runtime form; tests and
+        the auditor also lower the undonated one) the carry input
+        aliases the output — resident buffers mutate in place;
+        scatter-form counts ride replicated. Different idx/cnt bucket
+        sizes compile per shape under this one wrapper (jit's shape
+        cache keys them)."""
         return self._pjit_program(
             self._apply_jit,
-            ("apply", n, n_per_shard, pod_layout, donate,
+            ("apply", n, n_per_shard, pod_layout, donate, empty,
              tuple(sorted(static))),
             functools.partial(_mesh_apply_fn, self.config, pod_layout,
                               n),
@@ -1213,15 +1220,14 @@ class MeshWaveScheduler:
              PSpec()),
             CARRY_SPECS,
             donate_carry=donate,
+            out_shardings=_carry_out_shardings(self.mesh, empty),
         )
 
     def _apply_group_program(self, static, n, n_per_shard, pod_layout,
-                             donate=None):
-        if donate is None:
-            donate = runtime_donation()
+                             donate=True, empty=()):
         return self._pjit_program(
             self._apply_jit,
-            ("gapply", n, n_per_shard, pod_layout, donate,
+            ("gapply", n, n_per_shard, pod_layout, donate, empty,
              tuple(sorted(static))),
             functools.partial(_mesh_apply_group_fn, self.config,
                               pod_layout, n),
@@ -1229,6 +1235,7 @@ class MeshWaveScheduler:
              PSpec()),
             CARRY_SPECS,
             donate_carry=donate,
+            out_shardings=_carry_out_shardings(self.mesh, empty),
         )
 
     # -- dispatch wrappers ---------------------------------------------------
@@ -1253,15 +1260,15 @@ class MeshWaveScheduler:
     def _apply_run(self, static, carry, pod_layout, pod_buf, counts, n,
                    n_per_shard):
         idx, cnt = _sparse_counts(counts)
-        run = self._apply_program(static, n, n_per_shard, pod_layout)
+        run = self._apply_program(static, n, n_per_shard, pod_layout,
+                                  empty=empty_leaves(carry))
         self.resident.count_h2d(idx.nbytes + cnt.nbytes)
         with self.mesh:
             carry = run(static, carry, pod_buf, idx, cnt)
-        if runtime_donation():
-            # drain the donated fold before anything can re-donate its
-            # aliased buffers (the fold is the last dispatch of its
-            # run, so only fold-vs-host bookkeeping overlap is lost)
-            jax.block_until_ready(carry)
+        # drain the donated fold before anything can re-donate its
+        # aliased buffers (the fold is the last dispatch of its run,
+        # so only fold-vs-host bookkeeping overlap is lost)
+        jax.block_until_ready(carry)
         self.resident.set_carry(carry)
         return carry
 
@@ -1287,13 +1294,13 @@ class MeshWaveScheduler:
         cm[: counts_mat.shape[0]] = counts_mat
         idx, cnt = _sparse_group_counts(cm)
         run = self._apply_group_program(static, n, n_per_shard,
-                                        pod_layout)
+                                        pod_layout,
+                                        empty=empty_leaves(carry))
         self.resident.count_h2d(idx.nbytes + cnt.nbytes)
         with self.mesh:
             carry = run(static, carry, group_buf, idx, cnt)
-        if runtime_donation():
-            # see _apply_run: donated folds drain before re-donation
-            jax.block_until_ready(carry)
+        # see _apply_run: donated folds drain before re-donation
+        jax.block_until_ready(carry)
         self.resident.set_carry(carry)
         return carry
 

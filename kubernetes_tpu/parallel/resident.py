@@ -479,38 +479,31 @@ class ResidentClusterState:
         )
         updated = run(tuple(arrays), buf)
         # donated dispatches drain before their aliased buffers can be
-        # re-donated (see mesh.runtime_donation)
+        # re-donated (see mesh._apply_run)
         jax.block_until_ready(updated)
         for (f, host, _s, _ax), dev in zip(fields, updated):
             self._store(f, dev, host)
         self.count_h2d(buf.nbytes, table=True)
 
     def _scatter_program(self, names, axes, specs, layout, shapes,
-                         n_per_shard, donate=None):
+                         n_per_shard, donate=True):
         """The pjit row-scatter program for one (field set, row bucket,
-        shape) class — donated per mesh.runtime_donation (in-place
-        update of the resident arrays on backends whose client aliases
-        safely).  Shared with analysis/programs so the audited donation
+        shape) class — donated (in-place update of the resident
+        arrays).  Shared with analysis/programs so the audited donation
         contract covers the exact dispatched program."""
         import jax
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as PSpec
 
-        if donate is None:
-            from kubernetes_tpu.parallel.mesh import runtime_donation
-
-            donate = runtime_donation()
         jkey = (names, axes, layout, shapes, n_per_shard, donate)
         run = self._scatter_jit.get(jkey)
         if run is None:
-            from kubernetes_tpu.parallel.compat import shard_map
-
             body = functools.partial(
                 _scatter_fn, n_per_shard, names, axes, layout,
             )
             arr_sh = tuple(NamedSharding(self.mesh, s) for s in specs)
             run = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=self.mesh,
                     in_specs=(tuple(specs), PSpec()),
                     out_specs=tuple(specs),

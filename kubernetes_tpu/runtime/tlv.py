@@ -403,10 +403,11 @@ def _resolve_class(name: str, nf: int):
 def _load_native():
     try:
         from kubernetes_tpu.native import build as _build
-        if _build.ensure_ktlv() is None:
-            return None
-        from kubernetes_tpu.native import _ktlv as mod  # type: ignore
+
+        mod = _build.load_extension("_ktlv")
     except Exception:
+        return None
+    if mod is None:
         return None
     mod.setup(TLVError, _FIELDS, fields_of, _resolve_class,
               _RESOLVE_CACHE, _BY_NAME)

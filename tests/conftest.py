@@ -26,15 +26,9 @@ os.environ["XLA_FLAGS"] = (
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # older jax (< 0.5) has no jax_num_cpu_devices option; the
-    # --xla_force_host_platform_device_count XLA flag above is the
-    # equivalent and is honored by every version in use here
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 
-# Build the native engines up front (cached by mtime) so the C-replay
+# Build the native engines up front (cached by source hash) so the C-replay
 # differential fuzz tests exercise replay.c instead of silently skipping
 # (the round-2 failure: the driver's test run never executed the C path).
 from kubernetes_tpu.native.build import ensure_all
@@ -44,36 +38,21 @@ ensure_all()
 
 # -- optional-dependency auto-skip --------------------------------------------
 #
-# The image lacks `cryptography` (service-account JWT signing) and this
-# jax build predates `jax.shard_map` (the mesh scheduler's entry point).
-# Tests needing either are environment gaps, not regressions — report
-# them as SKIPPED instead of collection errors / failures so tier-1
-# output only goes red for real breakage. Both conversions are gated on
-# the dependency actually being absent: with the dep installed, a
-# matching error is a genuine failure and stays one.
+# The image lacks `cryptography` (service-account JWT signing). Tests
+# needing it are an environment gap, not a regression — report them as
+# SKIPPED instead of collection errors / failures so tier-1 output only
+# goes red for real breakage. The conversion is gated on the dependency
+# actually being absent: with it installed, a matching error is a
+# genuine failure and stays one.
 
-import importlib
+import importlib.util
 
 import pytest
 
-
-def _have_module(name):
-    try:
-        importlib.import_module(name)
-        return True
-    except ImportError:
-        return False
-
-
-_MISSING_DEPS = []
-if not _have_module("cryptography"):
-    _MISSING_DEPS.append("cryptography")
-# parallel/compat.py bridges `jax.shard_map` to the 0.4.x experimental
-# spelling, so the mesh path only goes missing when NEITHER exists
-from kubernetes_tpu.parallel.compat import have_shard_map
-
-if not have_shard_map():
-    _MISSING_DEPS.append("shard_map")
+_MISSING_DEPS = [
+    dep for dep in ("cryptography",)
+    if importlib.util.find_spec(dep) is None
+]
 
 
 def _missing_dep_in(exc) -> str:
@@ -120,9 +99,8 @@ def pytest_pycollect_makemodule(module_path, parent):
 
 @pytest.hookimpl(hookwrapper=True)
 def pytest_runtest_makereport(item, call):
-    """Lazily-imported optional deps fail inside the test call (the
-    mesh path resolves shard_map through kubernetes_tpu.parallel.compat
-    at dispatch time); remap those failures to skips the same way."""
+    """A lazily-imported optional dep fails inside the test call; remap
+    those failures to skips the same way."""
     outcome = yield
     rep = outcome.get_result()
     if rep.when in ("setup", "call") and rep.failed and call.excinfo is not None:

@@ -179,7 +179,9 @@ def test_pallas_probe_bit_identical_to_lax():
     config, nz, nv, J, static, carry, pod = _probe_inputs()
     lax_out = WaveProbe(config, kernel="lax")._compiled(
         nz, nv, J)(static, carry, pod)
-    pal_out = WaveProbe(config, kernel="pallas")._compiled(
+    # interpret mode by name: the compiled lowering exists on no CPU
+    # backend and is refused on the TPU (tests/test_chip_compile.py)
+    pal_out = WaveProbe(config, kernel="pallas-interpret")._compiled(
         nz, nv, J)(static, carry, pod)
     a = np.asarray(lax_out["packed"])
     b = np.asarray(pal_out["packed"])
@@ -203,7 +205,10 @@ def test_probe_kernel_env_selection(monkeypatch):
     monkeypatch.delenv("KUBERNETES_TPU_KERNEL", raising=False)
     assert WaveProbe(SchedulerConfig()).kernel == "lax"
     monkeypatch.setenv("KUBERNETES_TPU_KERNEL", "pallas")
-    assert WaveProbe(SchedulerConfig()).kernel == "pallas"
+    # the environment asks for the COMPILED kernel; a backend that
+    # cannot compile it refuses at construction instead of interpreting
+    with pytest.raises(ValueError, match="interpret mode"):
+        WaveProbe(SchedulerConfig())
     # explicit ctor arg beats the env (the shadow-driver seam)
     assert WaveProbe(SchedulerConfig(), kernel="lax").kernel == "lax"
 

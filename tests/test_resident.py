@@ -85,15 +85,9 @@ def _carry_ptrs(carry):
 
 def test_resident_buffers_stable_and_zero_table_bytes(mesh):
     """Across N steady-state waves: (a) zero node-table bytes ship
-    host->device, (b) per-wave upload stays O(pending pods), (c) when
-    runtime donation is active, the donated folds keep the carry in
-    the SAME device buffers (pointer set stable — donation aliases,
-    never reallocates).  On the CPU backend runtime donation is policy-
-    disabled (mesh.runtime_donation: jaxlib CPU donation race), so the
-    pointer assertion only arms where donation runs — the donation
-    CONTRACT itself is lowering-audited in test_analysis either way."""
-    from kubernetes_tpu.parallel.mesh import runtime_donation
-
+    host->device, (b) per-wave upload stays O(pending pods), (c) the
+    donated folds keep the carry in the SAME device buffers (pointer
+    set stable — donation aliases, never reallocates)."""
     state = ClusterState.build(_nodes(200))
     pods = _pods(1)
     snap, batch = _encode(state, pods)
@@ -112,10 +106,9 @@ def test_resident_buffers_stable_and_zero_table_bytes(mesh):
             "steady-state wave shipped node-table bytes"
         )
         uploads.append(m.resident.stats["wave_h2d_bytes"])
-        if runtime_donation():
-            assert _carry_ptrs(carry) == warm_ptrs, (
-                "carry left its resident buffers: donation is copying"
-            )
+        assert _carry_ptrs(carry) == warm_ptrs, (
+            "carry left its resident buffers: donation is copying"
+        )
     # pod row buffer + scatter-form counts only: KBs, not the ~200KB
     # the node tables of even this small cluster would cost
     assert max(uploads) < 64 * 1024, uploads
@@ -289,11 +282,24 @@ def test_donated_fold_lowering_aliases_every_carry_leaf(mesh):
         for f in BatchScheduler.POD_FIELDS
     })
     idx, cnt = _sparse_counts(np.zeros(N, np.int64))
-    fn = m._apply_program(static, N, nps, layout, donate=True)
+    from kubernetes_tpu.parallel.mesh import empty_leaves
+
+    # the driver's own call shape: zero-size leaves (no inter-pod terms
+    # here) keep their result sharding unspecified and hold no buffer
+    # to alias; every leaf WITH bytes must alias
+    empty = empty_leaves(carry)
+    fn = m._apply_program(static, N, nps, layout, donate=True,
+                          empty=empty)
     txt = fn.lower(static, carry, buf, idx, cnt).as_text()
-    assert txt.count("tf.aliasing_output") == len(CARRY_FIELDS), (
+    assert 0 < len(empty) < len(CARRY_FIELDS)
+    assert (txt.count("tf.aliasing_output")
+            == len(CARRY_FIELDS) - len(empty)), (
         "a donated carry leaf is silently copied in the lowered fold"
     )
+    # with nothing declared empty every leaf aliases by declaration
+    full = m._apply_program(static, N, nps, layout, donate=True)
+    assert (full.lower(static, carry, buf, idx, cnt).as_text()
+            .count("tf.aliasing_output")) == len(CARRY_FIELDS)
     undonated = m._apply_program(static, N, nps, layout, donate=False)
     txt2 = undonated.lower(static, carry, buf, idx, cnt).as_text()
     assert txt2.count("tf.aliasing_output") == 0
