@@ -608,6 +608,11 @@ def main(argv=None):
     args = ap.parse_args(argv)
     import os
 
+    from kubernetes_tpu.metrics import install_gc_metrics
+
+    # the collector's pauses as this process's own counters on its
+    # /metrics, whichever component it is
+    install_gc_metrics()
     prof_path = os.environ.get("KUBERNETES_TPU_PROFILE", "")
     if prof_path:
         # perf diagnosis for daemon subprocesses: a low-overhead stack

@@ -189,6 +189,18 @@ class Histogram(_Metric):
             self._count += 1
             self._counts[i] += 1
 
+    def observe_many(self, values) -> None:
+        """A whole wave's observations in one numpy pass and one lock
+        round trip (`values`: a 1-d float array)."""
+        import numpy as np
+
+        idx = np.searchsorted(self.buckets, values, side="left")
+        binned = np.bincount(idx, minlength=len(self._counts)).tolist()
+        with self._lock:
+            self._sum += float(values.sum())
+            self._count += len(values)
+            self._counts = [a + b for a, b in zip(self._counts, binned)]
+
     @property
     def count(self) -> int:
         with self._lock:
@@ -351,7 +363,8 @@ scheduler_binding_latency = registry.register(
 _SECONDS_BUCKETS = exponential_buckets(1e-5, 2, 24)
 
 #: per-phase wall seconds of the scheduling wire path, labeled
-#: phase=encode|probe|score|replay|transfer|wire|bind
+#: phase=encode|probe|score|replay|transfer|wire|bind|prepare|assume|
+#: ingest, and the idle states queue_wait|gather
 #: (trace/profile.py owns the phase vocabulary)
 scheduler_wave_phase_seconds = registry.register(
     HistogramVec(
@@ -359,7 +372,21 @@ scheduler_wave_phase_seconds = registry.register(
         "Wire-path phase latency in seconds, labeled by phase",
         label="phase",
         buckets=_SECONDS_BUCKETS,
-        label_bound=8,
+        label_bound=16,
+    )
+)
+
+#: how long each pod of a wave sat in the daemon's FIFO, enqueue to the
+#: wave's start (scheduler/core.py observes a whole wave at once). No
+#: bucket is wider than 10 ms up to 0.5 s, so a window's median is read
+#: from a bucket diff to a few ms; doubling after that.
+scheduler_pod_queue_wait_seconds = registry.register(
+    Histogram(
+        "scheduler_pod_queue_wait_seconds",
+        "Seconds a pod waited in the scheduler's queue before its wave "
+        "started",
+        buckets=[round(0.01 * i, 2) for i in range(1, 51)]
+        + exponential_buckets(1.0, 2, 7),
     )
 )
 
@@ -952,3 +979,61 @@ telemetry_series_dropped_total = registry.register(
         label_bound=256,
     )
 )
+
+# -- the collector, in every process ------------------------------------------
+
+#: seconds this process stood still in its garbage collector, by
+#: generation. Every thread stops for a collection, so a long one is a
+#: stall of the daemon or the door that nothing else explains.
+process_gc_pause_seconds_total = registry.register(
+    Counter(
+        "process_gc_pause_seconds_total",
+        "Seconds spent paused in the garbage collector, labeled by "
+        "generation",
+        label_bound=3,
+    )
+)
+
+#: collections that paused the process for GC_LONG_PAUSE_SECONDS or more
+process_gc_long_pauses_total = registry.register(
+    Counter(
+        "process_gc_long_pauses_total",
+        "Garbage collections that paused the process for 100 ms or "
+        "more, labeled by generation",
+        label_bound=3,
+    )
+)
+
+GC_LONG_PAUSE_SECONDS = 0.1
+_gc_installed = False
+
+
+def install_gc_metrics() -> None:
+    """Idempotently hook gc.callbacks into the two process_gc_*
+    counters. Fires once per collection, so it costs nothing per
+    request; each daemon's entry point installs it (hyperkube)."""
+    global _gc_installed
+    if _gc_installed:
+        return
+    _gc_installed = True
+    import gc
+    import time
+
+    pause = [process_gc_pause_seconds_total.child(generation=str(g))
+             for g in range(3)]
+    long_pause = [process_gc_long_pauses_total.child(generation=str(g))
+                  for g in range(3)]
+    for child in pause + long_pause:
+        child(0.0)  # the series exist from the first scrape on
+    began = [0.0]
+
+    def on_gc(phase: str, info: dict) -> None:
+        if phase == "start":
+            began[0] = time.perf_counter()
+            return
+        took = time.perf_counter() - began[0]
+        pause[info["generation"]](took)
+        if took >= GC_LONG_PAUSE_SECONDS:
+            long_pause[info["generation"]]()
+
+    gc.callbacks.append(on_gc)

@@ -704,13 +704,14 @@ class BatchScheduler:
             )
 
             @jax.jit
-            def run(static, carry, pods):
-                final, chosen = jax.lax.scan(
-                    functools.partial(scan_body, static), carry, pods
-                )
+            def batch_scan(static, carry, pods):
+                with jax.named_scope("scan"):
+                    final, chosen = jax.lax.scan(
+                        functools.partial(scan_body, static), carry, pods
+                    )
                 return final, chosen
 
-            fn = run
+            fn = batch_scan
             self._jitted[key] = fn
         return fn
 

@@ -27,6 +27,7 @@ import numpy as np
 from kubernetes_tpu.trace.profile import phase_timer
 
 
+@jax.named_scope("unpack")
 def _unpack(layout, buf):
     out = {}
     for name, dstr, shape, off, nb in layout:
@@ -102,6 +103,10 @@ class Packer:
             Packer.total_h2d_bytes += buf.nbytes
             fn = self._unpack.get(key)
             if fn is None:
-                fn = jax.jit(functools.partial(_unpack, key))
+                pack_unpack = functools.partial(_unpack, key)
+                # the program's name in a trace and a compile log:
+                # `jit_pack_unpack`, where a bare partial has none
+                pack_unpack.__name__ = "pack_unpack"
+                fn = jax.jit(pack_unpack)
                 self._unpack[key] = fn
             return fn(buf)

@@ -349,6 +349,7 @@ def _probe_rows(config: SchedulerConfig, num_zones: int, num_values: int,
     return stk, tab
 
 
+@jax.named_scope("probe")
 def _probe_fn(config: SchedulerConfig, num_zones: int, num_values: int, J: int,
               static, carry, pod, *, kernel: str = "lax",
               score_mode: str = "i64"):
@@ -362,6 +363,7 @@ def _probe_fn(config: SchedulerConfig, num_zones: int, num_values: int, J: int,
     return {"packed": jnp.concatenate([stk, tabw], axis=0)}
 
 
+@jax.named_scope("probe")
 def _group_probe_fn(config: SchedulerConfig, num_zones: int, num_values: int,
                     G: int, layout, static, carry, group_buf):
     """Header-row probe for G stacked run representatives in one traced
@@ -436,7 +438,9 @@ class WaveProbe:
         key = (num_zones, num_values, J)
         fn = self._jitted.get(key)
         if fn is None:
-            fn = jax.jit(self._probe_partial(num_zones, num_values, J))
+            wave_probe = self._probe_partial(num_zones, num_values, J)
+            wave_probe.__name__ = "wave_probe"  # the program: jit_wave_probe
+            fn = jax.jit(wave_probe)
             self._jitted[key] = fn
         return fn
 
@@ -455,7 +459,8 @@ class WaveProbe:
 
             probe_fn = self._probe_partial(num_zones, num_values, J)
 
-            def fused(static, carry, prev_buf, counts, next_buf):
+            def probe_fused_prev(static, carry, prev_buf, counts,
+                                 next_buf):
                 # prev/next share the backlog's layout (vocab widths
                 # are backlog-constant)
                 if prev_buf is not None:
@@ -465,7 +470,7 @@ class WaveProbe:
                 packed = probe_fn(static, carry, next_pod)
                 return carry, packed
 
-            def fused_same(static, carry, buf, counts):
+            def probe_fused_same(static, carry, buf, counts):
                 # the dominant shape: a run re-probing ITSELF past the
                 # table horizon folds its own previous counts — unpack
                 # the one buffer once (and ship it once)
@@ -474,16 +479,16 @@ class WaveProbe:
                 packed = probe_fn(static, carry, pod)
                 return carry, packed
 
-            fn = {
-                "prev": jax.jit(fused),
-                "same": jax.jit(fused_same),
+            def probe_fused_first(static, carry, next_buf):
                 # variant without the apply fold (the backlog's first
                 # probe): prev_buf=None burns a separate trace
-                "first": jax.jit(
-                    lambda static, carry, next_buf: fused(
-                        static, carry, None, None, next_buf
-                    )
-                ),
+                return probe_fused_prev(static, carry, None, None,
+                                        next_buf)
+
+            fn = {
+                "prev": jax.jit(probe_fused_prev),
+                "same": jax.jit(probe_fused_same),
+                "first": jax.jit(probe_fused_first),
             }
             self._jitted[key] = fn
         return fn
@@ -562,7 +567,8 @@ class WaveProbe:
             kind = prev_key[0] if prev_key else None
             prev_layout = prev_key[1] if prev_key else None
 
-            def grouped(static, carry, prev_buf, prev_counts, group_buf):
+            def group_probe(static, carry, prev_buf, prev_counts,
+                            group_buf):
                 if kind == "single":
                     carry = apply_fn(static, carry,
                                      _unpack_pod(prev_layout, prev_buf),
@@ -576,7 +582,7 @@ class WaveProbe:
                 )
                 return carry, out
 
-            fn = jax.jit(grouped)
+            fn = jax.jit(group_probe)
             self._jitted[key] = fn
         return fn
 

@@ -658,6 +658,10 @@ class WaveScheduler:
             # pre-resident driver re-shipped every wave) — the bench's
             # steady-state byte-reduction numerator
             "table_bytes_reused": 0,
+            # device programs launched, all waves: the cumulative total
+            # beside the per-wave `dispatches` dict, which _wave_setup
+            # empties (a window's launches are a diff of this)
+            "dispatches": 0,
         }
 
     # fraction of changed rows above which a scatter-row update loses
@@ -680,8 +684,10 @@ class WaveScheduler:
         key = (np.dtype(dtype).str, tail, bucket)
         fn = self._row_set_jit.get(key)
         if fn is None:
-            fn = jax.jit(lambda a, r, v: a.at[r].set(v),
-                         donate_argnums=0)
+            def row_set(a, r, v):
+                return a.at[r].set(v)
+
+            fn = jax.jit(row_set, donate_argnums=0)
             self._row_set_jit[key] = fn
         return fn
 
@@ -774,6 +780,7 @@ class WaveScheduler:
 
     # -- carry commit of a whole run -----------------------------------------
 
+    @jax.named_scope("apply")
     def _apply_fn(self, static, carry, pod, counts):
         """Fold j identical commits per node into the carry — the exact
         sum of the scan's per-step commit section over the run."""
@@ -922,11 +929,11 @@ class WaveScheduler:
         if fn is None:
             from kubernetes_tpu.models.pack import unpack as _unpack_pod
 
-            def run(static_, carry_, buf_, counts_):
+            def wave_apply_packed(static_, carry_, buf_, counts_):
                 pod = _unpack_pod(layout, buf_)
                 return self._apply_fn(static_, carry_, pod, counts_)
 
-            fn = jax.jit(run)
+            fn = jax.jit(wave_apply_packed)
             self._apply_packed_jit[layout] = fn
         # carry-fold commit (async dispatch: the timer sees the enqueue
         # plus whatever the device makes it wait for)
@@ -934,6 +941,7 @@ class WaveScheduler:
             self._count("apply")
             return fn(static, carry, buf, jnp.asarray(counts))
 
+    @jax.named_scope("fold")
     def _apply_group_fn(self, layout, static, carry, buf, counts):
         """Fold a whole GROUP of runs' commits (counts i64[G, N], one
         row per stacked pod in `buf`) into the carry in one scatter.
@@ -974,11 +982,11 @@ class WaveScheduler:
         """Standalone dispatch of the grouped fold (the settle path)."""
         fn = self._apply_group_jit.get(layout)
         if fn is None:
-            def run(static_, carry_, buf_, counts_):
+            def wave_apply_group(static_, carry_, buf_, counts_):
                 return self._apply_group_fn(layout, static_, carry_,
                                             buf_, counts_)
 
-            fn = jax.jit(run)
+            fn = jax.jit(wave_apply_group)
             self._apply_group_jit[layout] = fn
         with phase_timer("replay"):
             self._count("apply")
@@ -986,6 +994,7 @@ class WaveScheduler:
 
     def _count(self, key: str) -> None:
         self.dispatches[key] = self.dispatches.get(key, 0) + 1
+        self.stats["dispatches"] += 1
 
     # -- backlog -------------------------------------------------------------
 
@@ -1244,9 +1253,9 @@ class WaveScheduler:
                     # the next run's pack + upload overlaps the
                     # device's scoring of THIS probe. ONE probe timer
                     # spans the whole device window with the staging
-                    # encode timer nested inside, so the trace
-                    # accountant's overlap_totals attributes exactly
-                    # the hidden staging seconds to the overlap.
+                    # encode timer nested inside: the hidden staging
+                    # seconds are the encode occurrences' wall
+                    # (phase_totals) inside this probe.
                     with phase_timer("probe"):
                         self._count("probe")
                         carry, raw = self.probe.probe_fused_dispatch(

@@ -238,6 +238,7 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
     return j, chosen, L, n_done, stopped
 
 
+@jax.named_scope("zreplay")
 def _zreplay_fn(config, num_zones, num_values, J, K, layout, apply_fn,
                 fold_prev, static, carry, prev_buf, prev_counts,
                 pod_buf, zone_id, veto, has_selectors, rows_dyn, k_real,
@@ -266,6 +267,7 @@ def _zreplay_fn(config, num_zones, num_values, J, K, layout, apply_fn,
     return carry, chosen, counts, L, n_done
 
 
+@jax.named_scope("zreplay")
 def _zreplay_group_fn(config, num_zones, num_values, J, K, G, layout,
                       apply_fn, prev_kind, prev_layout, apply_group_fn,
                       static, carry, prev_buf, prev_counts, group_buf,
@@ -325,10 +327,12 @@ class ZReplay:
         key = (num_zones, num_values, J, K_bucket, layout, fold_prev)
         fn = self._jitted.get(key)
         if fn is None:
-            fn = jax.jit(functools.partial(
+            zreplay_run = functools.partial(
                 _zreplay_fn, self.config, num_zones, num_values, J,
                 K_bucket, layout, self.apply_fn, fold_prev,
-            ))
+            )
+            zreplay_run.__name__ = "zreplay_run"  # jit_zreplay_run
+            fn = jax.jit(zreplay_run)
             self._jitted[key] = fn
         if not fold_prev:
             prev_buf = jnp.zeros(0, jnp.uint8)
@@ -356,11 +360,13 @@ class ZReplay:
                prev_kind, prev_layout)
         fn = self._jitted.get(key)
         if fn is None:
-            fn = jax.jit(functools.partial(
+            zreplay_group = functools.partial(
                 _zreplay_group_fn, self.config, num_zones, num_values,
                 J, K_bucket, G, layout, self.apply_fn, prev_kind,
                 prev_layout, self.apply_group_fn,
-            ))
+            )
+            zreplay_group.__name__ = "zreplay_group"  # jit_zreplay_group
+            fn = jax.jit(zreplay_group)
             self._jitted[key] = fn
         if prev_kind is None:
             prev_buf = jnp.zeros(0, jnp.uint8)

@@ -217,17 +217,24 @@ def read_frames(fp):
                 pos += hdr + n
                 # "wire" phase: the CPU cost of the TLV watch ingest
                 # (decode only — the blocking read below is idle time,
-                # not work, and must not inflate the attribution)
+                # not work, and must not inflate the attribution).
+                # "ingest" phase: what the consumer does with the
+                # events before it asks for the next frame (store,
+                # queue, cache handlers): the timer is open while this
+                # generator is suspended at its yield, once per frame
+                # and not per event of a burst
                 if body.startswith(MAGIC_BURST):
                     # coalesced burst: one frame fans back out into its
                     # individual events
                     with phase_timer("wire"):
                         events = list(iter_burst(body))
-                    yield from events
+                    with phase_timer("ingest"):
+                        yield from events
                     continue
                 with phase_timer("wire"):
                     obj = decode(body)
-                yield obj
+                with phase_timer("ingest"):
+                    yield obj
                 continue
         # compact + refill (read1: return as soon as any data arrives —
         # a frame must not wait for a full block on a quiet stream)

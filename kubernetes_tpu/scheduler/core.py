@@ -27,6 +27,7 @@ from kubernetes_tpu.metrics import (
     scheduler_algorithm_latency,
     scheduler_binding_latency,
     scheduler_e2e_latency,
+    scheduler_pod_queue_wait_seconds,
 )
 from kubernetes_tpu.oracle.scheduler import (
     FitError,
@@ -126,6 +127,11 @@ class SchedulerConfig:
     # pop up to this many additional waiting pods per cycle (0 = strictly
     # serial, reference-identical pacing)
     drain_waiting: Callable[[int], List[Pod]] = None
+    # pods -> the time.monotonic() stamp each got where it entered the
+    # queue (NaN where it has none), taken out of the queue's side
+    # table: what the wave's queue-wait histogram is reduced from.
+    # None = no stamps (and with tracing off the factory keeps none).
+    queue_stamps: Callable[[List[Pod]], object] = None
     # wave cap: with power-of-two bucketing in the TPU algorithm this also
     # bounds the set of compiled program shapes — each fresh shape costs a
     # full XLA compile. Runs of identical pods bypass
@@ -186,6 +192,54 @@ class _LazyState:
 
     def __getattr__(self, name):
         return getattr(self._real(), name)
+
+
+class _WaveTrace:
+    """One trace per wave in the span ring (trace/spans.BUFFER): the
+    root `scheduler.wave` and its stage children `wave.gather` (first
+    pop to wave start), `wave.prepare` (duplicate filter, snapshot,
+    gang plan), `wave.algorithm` and `wave.assume`, recorded together
+    when the cycle ends, and `wave.bind` (submitted to the pool ->
+    acknowledged), which the pool thread records under the same ids.
+    The stages are consecutive: a mark ends one and begins the next."""
+
+    __slots__ = ("trace_id", "span_id", "marks", "attrs")
+    STAGES = ("wave.gather", "wave.prepare", "wave.algorithm",
+              "wave.assume")
+
+    def __init__(self):
+        self.trace_id = trace_span.new_trace_id()
+        self.span_id = trace_span.new_span_id()
+        self.marks = [time.time()]
+        self.attrs: dict = {}
+
+    def mark(self) -> None:
+        self.marks.append(time.time())
+
+    def finish(self) -> None:
+        """Record the wave and the stages it reached (an early return
+        closes the stage it was in)."""
+        self.mark()
+        for name, t0, t1 in zip(self.STAGES, self.marks, self.marks[1:]):
+            trace_span.record_span(name, self.trace_id, t0, t1,
+                                   parent_id=self.span_id)
+        trace_span.record_span(
+            "scheduler.wave", self.trace_id, self.marks[0], self.marks[-1],
+            span_id=self.span_id, **self.attrs)
+
+    def queue_wait(self, stamps, now: float) -> None:
+        """The wave's pods' waits in the queue, from their stamps, in
+        one pass: into the histogram and min/median/max on the span."""
+        import numpy as np
+
+        waits = now - stamps
+        waits = waits[~np.isnan(waits)]
+        if waits.size:
+            scheduler_pod_queue_wait_seconds.observe_many(waits)
+            self.attrs.update(
+                queue_wait_min=float(waits.min()),
+                queue_wait_median=float(np.median(waits)),
+                queue_wait_max=float(waits.max()))
 
 
 class Scheduler:
@@ -269,131 +323,169 @@ class Scheduler:
 
     def schedule_one(self) -> None:
         """scheduler.go:93 scheduleOne (+ the TPU wave extension)."""
-        cfg = self.config
-        pod = cfg.next_pod()
+        with trace_profile.phase_timer("queue_wait"):
+            pod = self.config.next_pod()
         if pod is None:
             raise StopIteration
+        wt = _WaveTrace() if trace_span.enabled() else None
+        try:
+            with trace_profile.annotation("sched/wave"):
+                self._schedule_wave_from(pod, wt)
+        finally:
+            if wt is not None:
+                wt.finish()
+
+    def _gather_wave(self, pod: Pod) -> List[Pod]:
+        """The wave: the popped pod, whatever else waits, and (a burst
+        in flight) what a short wait adds. Draining is `prepare` work;
+        the sleep is the idle state `gather`."""
+        cfg = self.config
         wave: List[Pod] = [pod]
-        if cfg.drain_waiting is not None and hasattr(
+        if cfg.drain_waiting is None or not hasattr(
             cfg.algorithm, "schedule_backlog"
         ):
+            return wave
+        with trace_profile.phase_timer("prepare"):
             wave += cfg.drain_waiting(cfg.max_batch - 1)
-            floor = min(cfg.wave_floor, cfg.max_batch)
-            if 1 < len(wave) < floor and cfg.wave_gather_seconds > 0:
-                # burst in flight (the drain caught extra pods): give
-                # arrivals a moment to fill the wave so the per-wave
-                # fixed cost amortizes. The window scales with the
-                # previous wave's measured cost — a 100 ms wave is
-                # worth waiting ~2x that to fill, a 5 ms wave is not.
-                # Two consecutive empty probes = the burst ended;
-                # dispatch what we have. Idle singletons never reach
-                # here — no added latency when nothing is arriving.
-                window = min(
-                    max(2.0 * self._last_wave_secs,
-                        cfg.wave_gather_seconds),
-                    cfg.wave_gather_max,
-                )
-                deadline = time.monotonic() + window
-                idle_probes = 0
-                while len(wave) < floor and time.monotonic() < deadline:
+        floor = min(cfg.wave_floor, cfg.max_batch)
+        if 1 < len(wave) < floor and cfg.wave_gather_seconds > 0:
+            # burst in flight (the drain caught extra pods): give
+            # arrivals a moment to fill the wave so the per-wave
+            # fixed cost amortizes. The window scales with the
+            # previous wave's measured cost — a 100 ms wave is
+            # worth waiting ~2x that to fill, a 5 ms wave is not.
+            # Two consecutive empty probes = the burst ended;
+            # dispatch what we have. Idle singletons never reach
+            # here — no added latency when nothing is arriving.
+            window = min(
+                max(2.0 * self._last_wave_secs,
+                    cfg.wave_gather_seconds),
+                cfg.wave_gather_max,
+            )
+            deadline = time.monotonic() + window
+            idle_probes = 0
+            while len(wave) < floor and time.monotonic() < deadline:
+                with trace_profile.phase_timer("gather"):
                     time.sleep(0.005)
+                with trace_profile.phase_timer("prepare"):
                     more = cfg.drain_waiting(cfg.max_batch - len(wave))
-                    if more:
-                        wave += more
-                        idle_probes = 0
-                    else:
-                        idle_probes += 1
-                        if idle_probes >= 2:
-                            break
-        cache = cfg.scheduler_cache
-        if cache is not None and hasattr(cache, "pod_keys"):
-            # duplicate watch deliveries (relist after a broken pipe)
-            # re-enqueue pods already decided; scheduling them again
-            # would phantom-commit capacity inside the wave. One locked
-            # key-set copy, not one lock round-trip per pod.
-            known = cache.pod_keys()
-            fresh = [
-                p for p in wave
-                if f"{p.metadata.namespace}/{p.metadata.name}" not in known
-            ]
-            if len(fresh) != len(wave):
-                log.debug(
-                    "dropped %d duplicate-delivery pods from the wave",
-                    len(wave) - len(fresh),
-                )
-                wave = fresh
-            if not wave:
-                return
-            pod = wave[0]  # the popped pod itself may have been dropped
-        start = DEFAULT_CLOCK.now()
-        wall_start = time.time() if trace_span.enabled() else 0.0
-        state = self._snapshot()
-        gang_layout: List[dict] = []
-        if cfg.gang_director is not None:
-            # gang planning: park minMember-short gangs before they
-            # touch the backlog, order [singletons | gangs by priority]
-            # with members contiguous, attach throughput score rows.
-            # Waves without gang-labeled pods come back untouched.
-            wave, gang_layout, pre_parked = \
-                cfg.gang_director.plan_wave(wave, state)
-            if pre_parked:
-                self._handle_failures(pre_parked, reason="GangParked")
-            if not wave:
-                return
-            pod = wave[0]
-        try:
-            with trace_span.span("scheduler.wave", pods=len(wave)):
-                if len(wave) == 1 and not gang_layout:
-                    hosts: List[Optional[str]] = [
-                        cfg.algorithm.schedule(wave[0], state)
-                    ]
-                    errors: Dict[int, Exception] = {}
+                if more:
+                    wave += more
+                    idle_probes = 0
                 else:
-                    hosts, errors = self._schedule_wave(
-                        wave, state, gangs=gang_layout or None)
+                    idle_probes += 1
+                    if idle_probes >= 2:
+                        break
+        return wave
+
+    def _schedule_wave_from(self, pod: Pod,
+                            wt: Optional[_WaveTrace]) -> None:
+        cfg = self.config
+        wave = self._gather_wave(pod)
+        if wt is not None:
+            wt.mark()  # wave start: gather ends, prepare begins
+            if cfg.queue_stamps is not None:
+                stamps = cfg.queue_stamps(wave)
+                if stamps is not None:
+                    wt.queue_wait(stamps, time.monotonic())
+        with trace_profile.phase_timer("prepare"):
+            cache = cfg.scheduler_cache
+            if cache is not None and hasattr(cache, "pod_keys"):
+                # duplicate watch deliveries (relist after a broken pipe)
+                # re-enqueue pods already decided; scheduling them again
+                # would phantom-commit capacity inside the wave. One
+                # locked key-set copy, not one lock round-trip per pod.
+                known = cache.pod_keys()
+                fresh = [
+                    p for p in wave
+                    if f"{p.metadata.namespace}/{p.metadata.name}"
+                    not in known
+                ]
+                if len(fresh) != len(wave):
+                    log.debug(
+                        "dropped %d duplicate-delivery pods from the wave",
+                        len(wave) - len(fresh),
+                    )
+                    wave = fresh
+                if not wave:
+                    return
+                pod = wave[0]  # the popped pod itself may have been dropped
+            start = DEFAULT_CLOCK.now()
+            wall_start = time.time() if wt is not None else 0.0
+            state = self._snapshot()
+            gang_layout: List[dict] = []
+            if cfg.gang_director is not None:
+                # gang planning: park minMember-short gangs before they
+                # touch the backlog, order [singletons | gangs by priority]
+                # with members contiguous, attach throughput score rows.
+                # Waves without gang-labeled pods come back untouched.
+                wave, gang_layout, pre_parked = \
+                    cfg.gang_director.plan_wave(wave, state)
+                if pre_parked:
+                    self._handle_failures(pre_parked, reason="GangParked")
+                if not wave:
+                    return
+                pod = wave[0]
+        if wt is not None:
+            wt.attrs["pods"] = len(wave)
+            wt.mark()  # prepare ends, algorithm begins
+        try:
+            if len(wave) == 1 and not gang_layout:
+                hosts: List[Optional[str]] = [
+                    cfg.algorithm.schedule(wave[0], state)
+                ]
+                errors: Dict[int, Exception] = {}
+            else:
+                hosts, errors = self._schedule_wave(
+                    wave, state, gangs=gang_layout or None)
         except Exception as e:
             # histograms are microsecond-unit like the reference's
             # (metrics.go ExponentialBuckets(1000, 2, 15) over us)
             scheduler_algorithm_latency.observe(
                 (DEFAULT_CLOCK.now() - start) * 1e6
             )
+            if wt is not None:
+                wt.attrs["error"] = type(e).__name__
             self._handle_failure(pod, e)
             return
         self._last_wave_secs = DEFAULT_CLOCK.now() - start
         scheduler_algorithm_latency.observe(
             self._last_wave_secs * 1e6
         )
-        if cfg.gang_director is not None and gang_layout:
-            # all-or-nothing enforcement over the returned hosts (the
-            # wave driver already discarded eligible-run partials; this
-            # also covers scan/mesh fallbacks) + preemption planning
-            # for parked gangs with priority
-            hosts, gang_errors = cfg.gang_director.after_wave(
-                wave, list(hosts), gang_layout, state)
-            errors.update(gang_errors)
-        if trace_span.enabled():
-            # attribute the wave's algorithm window to every traced
-            # pod's own trace (one wall-clock read, per-pod dict gets)
-            wall_end = time.time()
-            for p, host in zip(wave, hosts):
-                tid = trace_span.extract(p)
-                if tid:
-                    trace_span.record_span(
-                        "scheduler.schedule", tid, wall_start, wall_end,
-                        pod=f"{p.metadata.namespace}/{p.metadata.name}",
-                        node=host or "", wave=len(wave),
-                    )
+        if wt is not None:
+            wt.mark()  # algorithm ends, assume begins
+        with trace_profile.phase_timer("assume"):
+            if cfg.gang_director is not None and gang_layout:
+                # all-or-nothing enforcement over the returned hosts (the
+                # wave driver already discarded eligible-run partials;
+                # this also covers scan/mesh fallbacks) + preemption
+                # planning for parked gangs with priority
+                hosts, gang_errors = cfg.gang_director.after_wave(
+                    wave, list(hosts), gang_layout, state)
+                errors.update(gang_errors)
+            if wt is not None:
+                # attribute the wave's algorithm window to every traced
+                # pod's own trace (one wall-clock read, per-pod dict gets)
+                wall_end = time.time()
+                for p, host in zip(wave, hosts):
+                    tid = trace_span.extract(p)
+                    if tid:
+                        trace_span.record_span(
+                            "scheduler.schedule", tid, wall_start, wall_end,
+                            pod=f"{p.metadata.namespace}/{p.metadata.name}",
+                            node=host or "", wave=len(wave),
+                        )
 
-        successes: List[Tuple[Pod, str]] = []
-        failures: List[Tuple[Pod, Exception]] = []
-        for i, (p, host) in enumerate(zip(wave, hosts)):
-            if host is None:
-                failures.append((p, errors.get(i) or FitError(p, {})))
-                continue
-            successes.append((p, host))
-        self._handle_failures(failures)
-        if successes:
-            self._assume_and_bind_wave(successes, start)
+            successes: List[Tuple[Pod, str]] = []
+            failures: List[Tuple[Pod, Exception]] = []
+            for i, (p, host) in enumerate(zip(wave, hosts)):
+                if host is None:
+                    failures.append((p, errors.get(i) or FitError(p, {})))
+                    continue
+                successes.append((p, host))
+            self._handle_failures(failures)
+            if successes:
+                self._assume_and_bind_wave(successes, start, wt)
 
     def _handle_failures(
         self, failed: List[Tuple[Pod, Exception]],
@@ -460,7 +552,8 @@ class Scheduler:
             return e
 
     def _assume_and_bind_wave(
-        self, pairs: List[Tuple[Pod, str]], cycle_start: float
+        self, pairs: List[Tuple[Pod, str]], cycle_start: float,
+        wt: Optional[_WaveTrace] = None,
     ) -> None:
         """Wave commit (scheduler.go:112-152 AssumePod + async bind, wave
         form): assume every pod, then bind — ONE bulk request when the
@@ -543,19 +636,29 @@ class Scheduler:
                     host,
                 )
 
+        submitted = time.time()
+
         def bind_all() -> None:
             with trace_profile.phase_timer("bind"):
-                _bind_all_inner()
+                acked = _bind_all_inner()
+            if wt is not None:
+                # the wave's own trace, recorded from the pool thread
+                trace_span.record_span(
+                    "wave.bind", wt.trace_id, submitted, acked,
+                    parent_id=wt.span_id, pods=len(pairs))
 
-        def _bind_all_inner() -> None:
+        def _bind_all_inner() -> float:
+            """-> the wall clock when the apiserver had answered."""
             bind_start = DEFAULT_CLOCK.now()
             if cfg.binder_many is not None and len(pairs) > 1:
                 try:
                     results = cfg.binder_many(pairs)
                 except Exception as e:
+                    acked = time.time()
                     for (pod, _h), assumed in zip(pairs, assumed_list):
                         fail(pod, assumed, e)
-                    return
+                    return acked
+                acked = time.time()
                 now = DEFAULT_CLOCK.now()
                 per = (now - bind_start) / len(pairs)
                 for i, ((pod, host), assumed) in enumerate(
@@ -571,7 +674,7 @@ class Scheduler:
                         fail(pod, assumed, RuntimeError(
                             res.get("message", "bind failed")
                         ))
-                return
+                return acked
             for (pod, host), assumed in zip(pairs, assumed_list):
                 t0 = DEFAULT_CLOCK.now()
                 try:
@@ -581,6 +684,7 @@ class Scheduler:
                     continue
                 now = DEFAULT_CLOCK.now()
                 succeed(pod, host, now - t0, now)
+            return time.time()
 
         # async bind (scheduler.go:124-152), on the shared pool
         try:

@@ -13,7 +13,8 @@ from __future__ import annotations
 
 import logging
 import threading
-from typing import List, Optional
+import time
+from typing import Callable, Dict, List, Optional
 
 from kubernetes_tpu.api.types import Pod
 from kubernetes_tpu.client.cache.fifo import FIFO
@@ -40,6 +41,7 @@ from kubernetes_tpu.scheduler.policy import (
     resolve_policy,
     resolve_policy_tpu,
 )
+from kubernetes_tpu.trace import spans as trace_span
 from kubernetes_tpu.utils.flowcontrol import Backoff
 
 log = logging.getLogger(__name__)
@@ -107,9 +109,10 @@ class ConfigFactory:
             direct=True,
         )
         # unassigned pods -> FIFO (factory.go:339, selector :431-440)
+        self._pod_feed = _ResponsibleFIFO(self.pod_queue, scheduler_name)
         self.unassigned_reflector = Reflector(
             client.resource("pods", namespace=""),
-            _ResponsibleFIFO(self.pod_queue, scheduler_name),
+            self._pod_feed,
             field_selector="spec.nodeName==",
             name="unassigned-pods",
         )
@@ -291,6 +294,7 @@ class ConfigFactory:
             pod_condition_updater_many=self._update_pod_conditions_many,
             next_pod=self._next_pod,
             drain_waiting=self._drain_waiting,
+            queue_stamps=self._pod_feed.take_stamps,
             error=self._make_error_handler(),
             snapshot_extras=self._snapshot_extras,
             node_lister=self.node_lister,
@@ -436,11 +440,20 @@ class ConfigFactory:
 
 class _ResponsibleFIFO:
     """Store adapter filtering FIFO adds by the multi-scheduler
-    annotation (factory.go:404 responsibleForPod)."""
+    annotation (factory.go:404 responsibleForPod).
 
-    def __init__(self, fifo: FIFO, scheduler_name: str):
+    It also stamps each pod once, where it enters the queue, with the
+    clock's reading, in a side table keyed like the FIFO; a wave takes
+    its pods' stamps out at its start (`take_stamps`) and a delete
+    drops one, so the table holds only pods that wait. With tracing
+    off nothing is stamped."""
+
+    def __init__(self, fifo: FIFO, scheduler_name: str,
+                 clock: Callable[[], float] = time.monotonic):
         self.fifo = fifo
         self.scheduler_name = scheduler_name
+        self._clock = clock
+        self._stamps: Dict[str, float] = {}
 
     def _responsible(self, pod: Pod) -> bool:
         want = pod.metadata.annotations.get(SCHEDULER_ANNOTATION_KEY, "")
@@ -450,17 +463,39 @@ class _ResponsibleFIFO:
 
     def add(self, pod: Pod) -> None:
         if self._responsible(pod):
+            if trace_span.enabled():
+                self._stamps.setdefault(self.fifo.key_func(pod),
+                                        self._clock())
             self.fifo.add(pod)
 
     def update(self, pod: Pod) -> None:
-        if self._responsible(pod):
-            self.fifo.update(pod)
+        self.add(pod)
 
     def delete(self, pod: Pod) -> None:
+        self._stamps.pop(self.fifo.key_func(pod), None)
         self.fifo.delete(pod)
 
     def replace(self, pods) -> None:
-        self.fifo.replace([p for p in pods if self._responsible(p)])
+        pods = [p for p in pods if self._responsible(p)]
+        if trace_span.enabled():
+            # a relist: pods that were waiting keep their stamp
+            now, old = self._clock(), self._stamps
+            keys = [self.fifo.key_func(p) for p in pods]
+            self._stamps = {k: old.get(k, now) for k in keys}
+        self.fifo.replace(pods)
+
+    def take_stamps(self, pods):
+        """-> float64[len(pods)]: each pod's stamp, taken out of the
+        table (NaN where it has none: re-queued after a failure, or
+        popped twice); None with tracing off."""
+        import numpy as np
+
+        if not trace_span.enabled():
+            self._stamps.clear()
+            return None
+        key, pop = self.fifo.key_func, self._stamps.pop
+        return np.fromiter((pop(key(p), np.nan) for p in pods),
+                           np.float64, len(pods))
 
     def list(self):
         return self.fifo.list()

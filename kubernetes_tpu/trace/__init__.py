@@ -10,11 +10,21 @@ for the TPU wire path:
     parent/child propagation via a context var, and a trace-id pod
     annotation that rides the TLV wire, so one pod's journey
     apiserver -> scheduler -> bind is a single trace across processes.
+    Each wave of the scheduler is one trace in the ring too:
+    ``scheduler.wave`` with the stage children wave.gather / .prepare
+    / .algorithm / .assume / .bind (scheduler/core._WaveTrace).
   * profile.py — per-phase histograms (encode / probe / score / replay
-    / transfer / wire / bind) and XLA compile-vs-execute attribution
-    via jax.monitoring (scheduler_xla_compile_seconds).
+    / transfer / wire / bind / prepare / assume / ingest) on one
+    exclusive timeline (``exclusive_totals()``) that also holds the
+    two idle states queue_wait / gather (``idle_totals()``); the
+    annotation switch (``set_annotations``: each timer also opens a
+    ``jax.profiler.TraceAnnotation("sched/<phase>")`` while a profiler
+    runs, so host phases and device operations share one clock); and
+    XLA compile-vs-execute attribution via jax.monitoring
+    (scheduler_xla_compile_seconds, ``recent_compiles()``).
   * httpd.py   — the component observability mux (/healthz, /metrics,
-    /configz, /debug/traces) the scheduler daemon serves, the
+    /configz, /debug/traces with the last compiles, /debug/profile to
+    trace the daemon's own process) the scheduler daemon serves, the
     reference's own-:10251-mux idiom.
   * slo.py     — a watchdog sampling e2e scheduling latency against a
     configurable objective, emitting API Events on breach.

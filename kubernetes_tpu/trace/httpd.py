@@ -5,7 +5,8 @@ prometheus /metrics (plugin/cmd/kube-scheduler/app/server.go:92-108);
 in this framework only the apiserver's shared mux rendered the registry
 until now. This module is that per-daemon mux: a tiny threaded HTTP
 server any component can hang its /healthz, /metrics, /configz,
-/debug/traces?limit=N, and /debug/audit endpoints on. The scheduler daemon serves it by
+/debug/traces?limit=N, /debug/profile?seconds=N and /debug/audit
+endpoints on. The scheduler daemon serves it by
 default (scheduler/server.py); the kubelet reuses render_traces() on
 its existing node-API server.
 """
@@ -13,7 +14,9 @@ its existing node-API server.
 from __future__ import annotations
 
 import json
+import tempfile
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Dict, Optional
 from urllib.parse import parse_qs, urlparse
@@ -33,12 +36,57 @@ def render_traces(query: Dict[str, str]) -> dict:
         limit=max(1, min(limit, 4096)),
         trace_id=query.get("trace") or None,
     )
+    from kubernetes_tpu.trace import profile as _profile
+
     return {
         "kind": "TraceList",
         "enabled": _span.enabled(),
         "totalRecorded": _span.BUFFER.total_recorded,
         "items": items,
+        # the last programs built here: which step recompiled
+        "compiles": _profile.recent_compiles(),
     }
+
+
+PROFILE_MAX_SECONDS = 10.0
+_profile_lock = threading.Lock()
+
+
+def run_profile(query: Dict[str, str]) -> tuple:
+    """The /debug/profile handler: run jax.profiler in THIS process for
+    ?seconds=N (at most PROFILE_MAX_SECONDS), with the phase timers'
+    annotations on, and answer with the directory the trace was written
+    to. How an operator traces a daemon that is its own OS process: the
+    .xplane.pb holds the device's lines and the host's `sched/<phase>`
+    annotations on one clock. -> (status, payload)."""
+    from kubernetes_tpu.trace import profile as _profile
+
+    try:
+        seconds = float(query.get("seconds", "2"))
+    except ValueError:
+        return 400, {"message": "seconds must be a number"}
+    if not 0 < seconds <= PROFILE_MAX_SECONDS:
+        return 400, {"message": "seconds must be over 0 and at most "
+                                f"{PROFILE_MAX_SECONDS:g}"}
+    if not _profile_lock.acquire(blocking=False):
+        return 409, {"message": "a profile is already running"}
+    try:
+        import jax
+
+        log_dir = tempfile.mkdtemp(prefix="kubernetes-tpu-profile-")
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0  # the annotations, not every call
+        was_on = _profile.set_annotations(True)
+        try:
+            jax.profiler.start_trace(log_dir, profiler_options=options)
+            time.sleep(seconds)
+            jax.profiler.stop_trace()
+        finally:
+            _profile.set_annotations(was_on)
+        return 200, {"kind": "Profile", "seconds": seconds,
+                     "directory": log_dir}
+    finally:
+        _profile_lock.release()
 
 
 def start_component_server(
@@ -102,6 +150,9 @@ def start_component_server(
                     return
                 if path == "/debug/traces":
                     self._send(200, render_traces(query))
+                    return
+                if path == "/debug/profile":
+                    self._send(*run_profile(query))
                     return
                 if path == "/debug/audit":
                     from kubernetes_tpu.audit import render_audit

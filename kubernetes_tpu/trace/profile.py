@@ -13,12 +13,39 @@ layers hang on their seams:
     transfer  host<->device shipping (models/pack Packer.ship)
     wire      TLV watch-frame decode + response decode in the client
     bind      the async bind commit (wave bulk bind included)
+    prepare   the scheduling loop before the algorithm: FIFO drains,
+              duplicate filter, snapshot, gang plan (scheduler/core)
+    assume    the scheduling loop after it: failure handling, the
+              assume loop, the hand-over to the bind pool
+    ingest    what a watch consumer does with decoded events before it
+              asks for the next frame: store, FIFO, scheduler cache,
+              the incremental snapshot's upkeep (runtime/binary)
+
+and two IDLE states on the same timeline, entered through the same
+timer, which are waiting and not work:
+
+    queue_wait  the loop blocked in next_pod(): nothing to schedule
+    gather      the wave-gather sleep (scheduler/core)
+
+The three phases after ``bind`` rank below every phase that existed
+before them, so they take only time that no earlier phase claimed, and
+the idle states rank last of all. ``exclusive_totals()`` returns the
+working phases, ``idle_totals()`` the idle states: phases + idle + the
+time inside no timer is the window.
 
 Timers observe into ``scheduler_wave_phase_seconds{phase=...}``; the
 bench prints a per-rep breakdown by diffing ``phase_totals()`` around
 the measurement window. Timers are gated on the trace switch
 (KUBERNETES_TPU_TRACE): disabled, each is a no-op costing one global
 read, which is what the <=5% overhead budget is measured against.
+
+Whoever starts a jax profiler in this process (the daemon's
+/debug/profile, the benchmark's traced run) also calls
+``set_annotations(True)``: every timer then opens a
+``jax.profiler.TraceAnnotation("sched/<phase>")`` as well, so that the
+host phases lie in the host plane of the same .xplane.pb, on the same
+clock, as the device's "XLA Ops" line. Off (the default) a timer pays
+one more global read; the switch is no environment variable.
 
 XLA compile time is attributed separately from execute time by routing
 jax.monitoring's '/jax/core/compile/backend_compile_duration' events
@@ -31,7 +58,8 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict
+from collections import deque
+from typing import Dict, List
 
 from kubernetes_tpu.metrics import (
     scheduler_wave_phase_seconds,
@@ -40,7 +68,38 @@ from kubernetes_tpu.metrics import (
 from kubernetes_tpu.trace import spans as _span
 
 #: the closed phase vocabulary (the bench table iterates this order)
-PHASES = ("encode", "probe", "score", "replay", "transfer", "wire", "bind")
+PHASES = ("encode", "probe", "score", "replay", "transfer", "wire", "bind",
+          "prepare", "assume", "ingest")
+#: waiting, not work: ranked after every phase, reported apart
+IDLE_STATES = ("queue_wait", "gather")
+_TIMELINE = PHASES + IDLE_STATES
+
+#: jax.profiler.TraceAnnotation while annotations are on, else None
+_ANNOTATION = None
+# the phase open on this thread (what a compile is put down to)
+_TLS = threading.local()
+
+
+def set_annotations(on: bool) -> bool:
+    """Open a TraceAnnotation("sched/<phase>") with every phase timer
+    (and "sched/wave" per wave), for as long as a profiler runs.
+    -> whether they were on before."""
+    global _ANNOTATION
+    was = _ANNOTATION is not None
+    if on:
+        import jax
+
+        _ANNOTATION = jax.profiler.TraceAnnotation
+    else:
+        _ANNOTATION = None
+    return was
+
+
+def annotation(name: str):
+    """``with annotation("sched/wave"): ...``: a TraceAnnotation while
+    annotations are on, else the shared no-op."""
+    cls = _ANNOTATION
+    return _NULL if cls is None else cls(name)
 
 
 class _ExclusiveAccountant:
@@ -57,11 +116,11 @@ class _ExclusiveAccountant:
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._rank = {p: i for i, p in enumerate(PHASES)}
-        self._depth = [0] * len(PHASES)
-        self._active = -1  # lowest active rank, -1 = idle
+        self._rank = {p: i for i, p in enumerate(_TIMELINE)}
+        self._depth = [0] * len(_TIMELINE)
+        self._active = -1  # lowest active rank, -1 = nothing entered
         self._last = time.perf_counter()
-        self._totals = [0.0] * len(PHASES)
+        self._totals = [0.0] * len(_TIMELINE)
 
     def enter(self, phase: str) -> None:
         i = self._rank[phase]
@@ -95,25 +154,34 @@ class _ExclusiveAccountant:
                 self._active = nxt
 
     def snapshot(self) -> Dict[str, float]:
+        """Seconds per phase and idle state, in _TIMELINE order."""
         with self._lock:
             now = time.perf_counter()
             if self._active >= 0:
                 self._totals[self._active] += now - self._last
             self._last = now
-            return dict(zip(PHASES, self._totals))
+            return dict(zip(_TIMELINE, self._totals))
 
 
 _ACCOUNTANT = _ExclusiveAccountant()
 
 
 class _PhaseTimer:
-    __slots__ = ("_hist", "_phase", "_t0")
+    __slots__ = ("_hist", "_phase", "_t0", "_outer", "_ann")
 
     def __init__(self, hist, phase):
         self._hist = hist
         self._phase = phase
 
     def __enter__(self) -> "_PhaseTimer":
+        cls = _ANNOTATION
+        if cls is None:
+            self._ann = None
+        else:
+            self._ann = cls("sched/" + self._phase)
+            self._ann.__enter__()
+        self._outer = getattr(_TLS, "phase", None)
+        _TLS.phase = self._phase
         _ACCOUNTANT.enter(self._phase)
         self._t0 = time.perf_counter()
         return self
@@ -121,6 +189,9 @@ class _PhaseTimer:
     def __exit__(self, *exc) -> bool:
         self._hist.observe(time.perf_counter() - self._t0)
         _ACCOUNTANT.exit(self._phase)
+        _TLS.phase = self._outer
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -137,13 +208,14 @@ class _NullTimer:
 _NULL = _NullTimer()
 
 # child histograms resolved once (labels() takes a lock on first use)
-_HIST = {p: scheduler_wave_phase_seconds.labels(p) for p in PHASES}
+_HIST = {p: scheduler_wave_phase_seconds.labels(p) for p in _TIMELINE}
 
 
 def phase_timer(phase: str):
     """``with phase_timer("probe"): ...`` — observes wall seconds into
     the phase histogram (per-occurrence work) and the exclusive
-    timeline (wall partition); no-op while tracing is disabled."""
+    timeline (wall partition); no-op while tracing is disabled. Takes
+    an idle state's name too."""
     if not _span._ENABLED:
         return _NULL
     return _PhaseTimer(_HIST[phase], phase)
@@ -159,30 +231,38 @@ def phase_totals() -> Dict[str, float]:
 
 
 def exclusive_totals() -> Dict[str, float]:
-    """Cumulative EXCLUSIVE seconds per phase (the single-timeline
-    partition): diffs over a window sum to <= the window's wall, so
-    the bench breakdown reads as 'where the wall went'."""
-    return _ACCOUNTANT.snapshot()
+    """Cumulative EXCLUSIVE seconds per working phase (the
+    single-timeline partition): diffs over a window sum to <= the
+    window's wall, so the bench breakdown reads as 'where the wall
+    went'. The shortfall is waiting (idle_totals()) plus the time
+    inside no timer at all."""
+    snap = _ACCOUNTANT.snapshot()
+    return {p: snap[p] for p in PHASES}
 
 
-def overlap_totals() -> Dict[str, float]:
-    """Cumulative OVERLAPPED seconds per phase: occurrence wall
-    (phase_totals) minus the exclusive timeline's attribution — the
-    time a phase spent running concurrently under a higher-priority
-    phase. The double-buffered wave pipeline
-    (KUBERNETES_TPU_PIPELINE) shows up here as encode/transfer
-    seconds hidden under an in-flight probe window; a serial run
-    reads ~0 everywhere. Diff over a bench window like the other
-    totals."""
-    pt = phase_totals()
-    et = exclusive_totals()
-    return {p: max(0.0, pt[p] - et[p]) for p in PHASES}
+def idle_totals() -> Dict[str, float]:
+    """Cumulative EXCLUSIVE seconds per idle state, on the timeline of
+    exclusive_totals(): waiting that no working phase overlapped. A
+    window's phases + idle + the time inside no timer is the window."""
+    snap = _ACCOUNTANT.snapshot()
+    return {p: snap[p] for p in IDLE_STATES}
 
 
 # -- XLA compile-vs-execute attribution ---------------------------------------
 
 _install_lock = threading.Lock()
 _installed = False
+#: the last 64 programs built in this process, oldest first: which step
+#: recompiled (served on /debug/traces as "compiles")
+_COMPILES: deque = deque(maxlen=64)
+
+
+def recent_compiles() -> List[dict]:
+    """[{"program", "phase", "seconds", "cache", "at"}], oldest first.
+    `program` is the name jax.monitoring passes (the jitted function's),
+    `phase` the phase timer open on the compiling thread, `cache` says
+    whether the persistent cache served it ("hit") or XLA built it."""
+    return list(_COMPILES)
 
 
 def install_compile_listener() -> None:
@@ -201,11 +281,25 @@ def install_compile_listener() -> None:
         except Exception:
             return
 
+        def _on_event(event: str, **kw) -> None:
+            # fires inside the compile it belongs to, on its thread
+            if event.endswith("compilation_cache/cache_hits"):
+                _TLS.cache_hit = True
+
         def _on_duration(event: str, duration: float, **kw) -> None:
             if event.endswith("backend_compile_duration"):
                 scheduler_xla_compile_seconds.observe(duration)
+                phase = getattr(_TLS, "phase", None)
+                hit = getattr(_TLS, "cache_hit", False)
+                _TLS.cache_hit = False
+                _COMPILES.append({
+                    "program": str(kw.get("fun_name") or phase or ""),
+                    "phase": phase, "seconds": duration,
+                    "cache": "hit" if hit else "miss", "at": time.time(),
+                })
 
         try:
+            monitoring.register_event_listener(_on_event)
             monitoring.register_event_duration_secs_listener(_on_duration)
         except Exception:
             pass

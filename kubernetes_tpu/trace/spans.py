@@ -64,7 +64,7 @@ def new_trace_id() -> str:
     return rand_hex(16)
 
 
-def _new_span_id() -> str:
+def new_span_id() -> str:
     return rand_hex(8)
 
 
@@ -74,9 +74,11 @@ def current_trace_id() -> Optional[str]:
 
 
 class TraceBuffer:
-    """Thread-safe bounded ring of finished spans (oldest evicted)."""
+    """Thread-safe bounded ring of finished spans (oldest evicted).
+    The default holds 60 s of waves at 20 a second, six spans each,
+    twice over."""
 
-    def __init__(self, capacity: int = 4096):
+    def __init__(self, capacity: int = 16384):
         self._lock = threading.Lock()
         self._spans: deque = deque(maxlen=capacity)
         self._recorded = 0
@@ -99,6 +101,17 @@ class TraceBuffer:
         if trace_id:
             spans = [s for s in spans if s.get("trace_id") == trace_id]
         return spans[-max(limit, 0):][::-1]
+
+    def since(self, start: float) -> Optional[List[Dict[str, Any]]]:
+        """Spans that started at `start` (wall clock) or later, oldest
+        first; None when the ring has evicted spans and its oldest is
+        younger than `start`: it no longer holds that window."""
+        with self._lock:
+            spans = list(self._spans)
+            evicted = self._recorded > len(spans)
+        if evicted and spans[0]["start"] > start:
+            return None
+        return [s for s in spans if s["start"] >= start]
 
     def clear(self) -> None:
         with self._lock:
@@ -133,7 +146,7 @@ class Span:
             self.parent_id = None
         else:
             self.trace_id, self.parent_id = parent
-        self.span_id = _new_span_id()
+        self.span_id = new_span_id()
         self._token = _CTX.set((self.trace_id, self.span_id))
         self.start = time.time()
         self._t0 = time.perf_counter()
@@ -196,7 +209,7 @@ def trace_context(trace_id: Optional[str], span_id: str = ""):
     if not trace_id or not _ENABLED:
         yield
         return
-    token = _CTX.set((trace_id, span_id or _new_span_id()))
+    token = _CTX.set((trace_id, span_id or new_span_id()))
     try:
         yield
     finally:
@@ -205,15 +218,17 @@ def trace_context(trace_id: Optional[str], span_id: str = ""):
 
 def record_span(name: str, trace_id: Optional[str], start: float,
                 end: float, parent_id: Optional[str] = None,
-                **attrs: Any) -> None:
+                span_id: Optional[str] = None, **attrs: Any) -> None:
     """Record a completed span retroactively. The wave paths time a
     phase once and attribute it to every traced pod in the wave without
-    per-pod context switches — this is that attribution primitive."""
+    per-pod context switches — this is that attribution primitive. A
+    caller that hands out `span_id` itself can record children (from
+    any thread) before or after their parent."""
     if not _ENABLED or not trace_id:
         return
     rec = {
         "trace_id": trace_id,
-        "span_id": _new_span_id(),
+        "span_id": span_id or new_span_id(),
         "parent_id": parent_id,
         "name": name,
         "start": start,
