@@ -1114,6 +1114,20 @@ def get_affinity(pod: Pod) -> Optional[Affinity]:
     return aff
 
 
+def has_pod_affinity(pod: Pod) -> bool:
+    """True when this pod contributes to (or poisons) the inter-pod
+    affinity program: the incremental snapshot's global-coupling gate."""
+    if pod.spec.affinity is None and AFFINITY_ANNOTATION not in pod.metadata.annotations:
+        return False
+    try:
+        aff = get_affinity(pod)
+    except Exception:
+        return True  # malformed annotation == poison (encoder marks it)
+    return aff is not None and (
+        aff.pod_affinity is not None or aff.pod_anti_affinity is not None
+    )
+
+
 def get_tolerations(pod: Pod) -> List[Toleration]:
     """Tolerations from the spec field, else the alpha annotation."""
     if pod.spec.tolerations is not None:

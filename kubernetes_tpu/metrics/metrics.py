@@ -390,6 +390,20 @@ scheduler_pod_queue_wait_seconds = registry.register(
     )
 )
 
+#: lookups of a pod's scheduling contribution (oracle/state.py
+#: pod_contribution), labeled result=hit|miss: a hit took the numbers
+#: NodeInfo and the incremental encoder need from the per-template memo,
+#: a miss parsed the pod. One template under a controller reads ~100%
+#: hits; all-distinct pods read ~100% misses and pay the key on top.
+scheduler_pod_contribution_lookups_total = registry.register(
+    Counter(
+        "scheduler_pod_contribution_lookups_total",
+        "Lookups of a pod's scheduling contribution in the per-template "
+        "memo, labeled by result (hit | miss)",
+        label_bound=2,
+    )
+)
+
 #: XLA compile time, attributed separately from execute time (fed by
 #: jax.monitoring compile-duration events; trace/profile.py installs
 #: the listener). The first jit call of every fresh program shape lands

@@ -64,15 +64,22 @@ DEFAULT_PRIORITIES: Tuple[PriorityConfig, ...] = (
 class FitError(Exception):
     """generic_scheduler.go:40 FitError."""
 
-    def __init__(self, pod: Pod, failed_predicates: Dict[str, str]):
+    def __init__(self, pod: Pod, failed_predicates: Dict[str, str],
+                 detail: Optional[str] = None):
+        """`detail` is the per-node part of the message; a caller that
+        fails many pods for the same reasons (one template on a full
+        cluster) passes the first error's, so that a line per node is
+        sorted and joined once and not once per pod."""
         self.pod = pod
         self.failed_predicates = failed_predicates
-        super().__init__(
-            f"pod ({pod.name}) failed to fit in any node\n"
-            + "\n".join(
+        if detail is None:
+            detail = "\n".join(
                 f"fit failure on node ({n}): {r}"
                 for n, r in sorted(failed_predicates.items())
             )
+        self.detail = detail
+        super().__init__(
+            f"pod ({pod.name}) failed to fit in any node\n" + detail
         )
 
 

@@ -8,10 +8,12 @@ ClusterState is the Python analogue of the `GetNodeNameToInfoMap` snapshot
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from kubernetes_tpu.api.types import (
+    AFFINITY_ANNOTATION,
     Node,
     PersistentVolume,
     PersistentVolumeClaim,
@@ -19,9 +21,11 @@ from kubernetes_tpu.api.types import (
     ReplicaSet,
     ReplicationController,
     Service,
+    has_pod_affinity,
     pod_nonzero_request,
     pod_resource_request,
 )
+from kubernetes_tpu.metrics import scheduler_pod_contribution_lookups_total
 
 
 @dataclass
@@ -39,32 +43,44 @@ class NodeInfo:
     requested_gpu: int = 0
     nonzero_milli_cpu: int = 0
     nonzero_memory: int = 0
+    # (namespace, name) -> where add_pod put the pod in `pods`, so that
+    # remove_pod need not walk the node's 20-110 pods, each a cache miss,
+    # for every deleted pod. A hint only: `pods` is a public list and a
+    # clone starts without hints; an entry that does not point at its pod
+    # is ignored and the walk decides.
+    _at: Dict[Tuple[str, str], int] = field(
+        default_factory=dict, repr=False, compare=False)
 
     def add_pod(self, pod: Pod) -> None:
-        cpu, mem, gpu = _calculate_resource(pod)
-        n0cpu, n0mem = pod_nonzero_request(pod)
-        self.requested_milli_cpu += cpu
-        self.requested_memory += mem
-        self.requested_gpu += gpu
-        self.nonzero_milli_cpu += n0cpu
-        self.nonzero_memory += n0mem
+        c = pod_contribution(pod)
+        self.requested_milli_cpu += c.cpu
+        self.requested_memory += c.mem
+        self.requested_gpu += c.gpu
+        self.nonzero_milli_cpu += c.nonzero_cpu
+        self.nonzero_memory += c.nonzero_mem
+        self._at[_pod_key(pod)] = len(self.pods)
         self.pods.append(pod)
 
     def remove_pod(self, pod: Pod) -> None:
-        key = (pod.namespace, pod.name)
-        for i, p in enumerate(self.pods):
-            if (p.namespace, p.name) == key:
-                self.pods[i] = self.pods[-1]
-                self.pods.pop()
-                cpu, mem, gpu = _calculate_resource(pod)
-                n0cpu, n0mem = pod_nonzero_request(pod)
-                self.requested_milli_cpu -= cpu
-                self.requested_memory -= mem
-                self.requested_gpu -= gpu
-                self.nonzero_milli_cpu -= n0cpu
-                self.nonzero_memory -= n0mem
-                return
-        raise KeyError(f"no pod {key} on node")
+        key = _pod_key(pod)
+        pods = self.pods
+        i = self._at.pop(key, None)
+        if i is None or i >= len(pods) or _pod_key(pods[i]) != key:
+            for i, p in enumerate(pods):
+                if _pod_key(p) == key:
+                    break
+            else:
+                raise KeyError(f"no pod {key} on node")
+        last = pods.pop()
+        if i < len(pods):  # the last pod takes the place of the one gone
+            pods[i] = last
+            self._at[_pod_key(last)] = i
+        c = pod_contribution(pod)
+        self.requested_milli_cpu -= c.cpu
+        self.requested_memory -= c.mem
+        self.requested_gpu -= c.gpu
+        self.nonzero_milli_cpu -= c.nonzero_cpu
+        self.nonzero_memory -= c.nonzero_mem
 
     def clone(self) -> "NodeInfo":
         return NodeInfo(
@@ -76,6 +92,11 @@ class NodeInfo:
             nonzero_milli_cpu=self.nonzero_milli_cpu,
             nonzero_memory=self.nonzero_memory,
         )
+
+
+def _pod_key(pod: Pod) -> Tuple[str, str]:
+    meta = pod.metadata
+    return (meta.namespace, meta.name)
 
 
 def _calculate_resource(pod: Pod) -> Tuple[int, int, int]:
@@ -90,6 +111,91 @@ def _calculate_resource(pod: Pod) -> Tuple[int, int, int]:
     mem = sum(resource_list_memory(c.requests) for c in pod.spec.containers)
     gpu = sum(resource_list_gpu(c.requests) for c in pod.spec.containers)
     return cpu, mem, gpu
+
+
+class PodContribution(NamedTuple):
+    """What one assigned pod adds to its node: everything NodeInfo and
+    the incremental snapshot (snapshot/incremental.py) take from it.
+    Immutable, and shared by every pod of one template."""
+
+    cpu: int  # _calculate_resource
+    mem: int
+    gpu: int
+    nonzero_cpu: int  # pod_nonzero_request
+    nonzero_mem: int
+    host_ports: Tuple[int, ...]  # non-zero, in container order
+    # the spread class: (namespace, frozenset(labels), deleting)
+    class_key: Tuple[str, frozenset, bool]
+    affinity: bool  # has_pod_affinity
+
+
+#: contributions seen, under a structural key of exactly the fields
+#: pod_contribution reads. Bounded: the oldest entry goes when a new one
+#: would pass the bound, so a cluster of all-distinct pods pays the key
+#: and a miss per pod and holds at most this many entries.
+_CONTRIBUTIONS: Dict[tuple, PodContribution] = {}
+_CONTRIBUTIONS_MAX = 4096
+_contributions_lock = threading.Lock()  # guards insert + evict
+_count_hit = scheduler_pod_contribution_lookups_total.child(result="hit")
+_count_miss = scheduler_pod_contribution_lookups_total.child(result="miss")
+
+
+def pod_contribution(pod: Pod) -> PodContribution:
+    """The pod's PodContribution, derived once per template.
+
+    The key is rebuilt from the pod's current fields on every call, so a
+    pod mutated after a lookup (a `requests` dict edited in place) gets
+    the answer for what it holds now, never a stale one. The name and
+    the node are not in the key: neither is read."""
+    meta = pod.metadata
+    spec = pod.spec
+    if spec.affinity is not None:
+        # an Affinity object is not hashable; such pods are few, and the
+        # inter-pod ones take the encoder's per-event path anyway
+        _count_miss()
+        return _derive_contribution(pod)
+    key = (
+        meta.namespace,
+        tuple(meta.labels.items()),
+        meta.deletion_timestamp is not None,
+        meta.annotations.get(AFFINITY_ANNOTATION),
+        tuple([
+            (tuple(c.requests.items()),
+             tuple([p.host_port for p in c.ports]) if c.ports else ())
+            for c in spec.containers
+        ]),
+    )
+    c = _CONTRIBUTIONS.get(key)
+    if c is not None:
+        _count_hit()
+        return c
+    _count_miss()
+    c = _derive_contribution(pod)
+    with _contributions_lock:
+        if len(_CONTRIBUTIONS) >= _CONTRIBUTIONS_MAX:
+            del _CONTRIBUTIONS[next(iter(_CONTRIBUTIONS))]
+        _CONTRIBUTIONS[key] = c
+    return c
+
+
+def _derive_contribution(pod: Pod) -> PodContribution:
+    meta = pod.metadata
+    return PodContribution(
+        *_calculate_resource(pod),
+        *pod_nonzero_request(pod),
+        tuple(
+            p.host_port
+            for c in pod.spec.containers
+            for p in c.ports
+            if p.host_port != 0
+        ),
+        (
+            meta.namespace,
+            frozenset(meta.labels.items()),
+            meta.deletion_timestamp is not None,
+        ),
+        has_pod_affinity(pod),
+    )
 
 
 @dataclass

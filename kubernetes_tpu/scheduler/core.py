@@ -36,6 +36,7 @@ from kubernetes_tpu.oracle.scheduler import (
     select_host,
 )
 from kubernetes_tpu.oracle.state import ClusterState
+from kubernetes_tpu.snapshot.encode import pod_feature_key
 from kubernetes_tpu.trace import profile as trace_profile
 from kubernetes_tpu.trace import spans as trace_span
 from kubernetes_tpu.utils.clock import DEFAULT_CLOCK
@@ -192,6 +193,25 @@ class _LazyState:
 
     def __getattr__(self, name):
         return getattr(self._real(), name)
+
+
+class _AssignedOnce:
+    """A state read by one pass of the host predicates, with the list
+    of every assigned pod built once: the inter-pod affinity predicate
+    asks for it at every node it reaches, a walk over the whole cluster
+    per node."""
+
+    def __init__(self, state):
+        self._state = state
+        self._assigned = None
+
+    def all_assigned_pods(self):
+        if self._assigned is None:
+            self._assigned = self._state.all_assigned_pods()
+        return self._assigned
+
+    def __getattr__(self, name):
+        return getattr(self._state, name)
 
 
 class _WaveTrace:
@@ -535,21 +555,40 @@ class Scheduler:
         else:
             hosts = self.config.algorithm.schedule_backlog(wave, state)
         errors: Dict[int, Exception] = {}
+        explained: Dict[tuple, Exception] = {}
         for i, (p, h) in enumerate(zip(wave, hosts)):
             if h is None:
-                errors[i] = self._explain_failure(p, state)
+                errors[i] = self._explain_failure(p, state, explained)
         return list(hosts), errors
 
-    def _explain_failure(self, pod: Pod, state: ClusterState) -> Exception:
+    def _explain_failure(
+        self, pod: Pod, state: ClusterState,
+        explained: Dict[tuple, Exception],
+    ) -> Exception:
         """Recover per-node failure reasons for an unschedulable pod by
-        running the host predicates once (rare path; the device program
-        reports fit/no-fit only)."""
-        try:
-            oracle = GenericScheduler()
-            _, failed = oracle.find_nodes_that_fit(pod, state)
-            return FitError(pod, failed)
-        except Exception as e:  # pragma: no cover
-            return e
+        running the host predicates (the device program reports
+        fit/no-fit only). Once per template and wave: the predicates
+        read of the pending pod only what its feature key holds, and
+        every failure of a wave is explained on the same state, so
+        `explained` (feature key -> the first such pod's error) serves
+        a template's other pods. A pass walks every node, and a full
+        cluster fails a template's pods by the thousand: a pass per pod
+        held the loop for minutes, the informers behind it, and the
+        deletes that would have made room were never seen."""
+        key = pod_feature_key(pod)
+        first = explained.get(key)
+        if first is None:
+            try:
+                _, failed = GenericScheduler().find_nodes_that_fit(
+                    pod, _AssignedOnce(state))
+                first = FitError(pod, failed)
+            except Exception as e:  # pragma: no cover
+                first = e
+            explained[key] = first
+            return first
+        if isinstance(first, FitError):
+            return FitError(pod, first.failed_predicates, first.detail)
+        return first
 
     def _assume_and_bind_wave(
         self, pairs: List[Tuple[Pod, str]], cycle_start: float,

@@ -412,28 +412,55 @@ class ConfigFactory:
         )
 
     def _make_error_handler(self):
-        """factory.go:476-512: async re-queue with per-pod backoff."""
+        """factory.go:476-512: async re-queue with per-pod backoff.
+
+        One worker over a heap of due times, not a thread per failed
+        pod: a full cluster fails a wave's pods by the thousand, and
+        starting a thread for each held the scheduling loop for
+        seconds while the deletes that would have made room waited."""
+        import heapq
+        import itertools
+
+        due = threading.Condition()
+        heap: list = []  # (when, tie-break, pod)
+        order = itertools.count()
+        worker: Optional[threading.Thread] = None
+
+        def requeue(pod: Pod) -> None:
+            try:
+                fresh = self.client.pods(pod.metadata.namespace).get(
+                    pod.metadata.name
+                )
+                if not fresh.spec.node_name:
+                    self.pod_queue.add(fresh)
+            except Exception:
+                pass  # deleted; drop
+
+        def work() -> None:
+            while not self._stopped:
+                with due:
+                    wait = heap[0][0] - time.monotonic() if heap else 1.0
+                    if wait > 0:
+                        # an earlier due time wakes it; a stop is
+                        # seen within the second
+                        due.wait(min(wait, 1.0))
+                        continue
+                    pod = heapq.heappop(heap)[2]
+                requeue(pod)
 
         def handle(pod: Pod, err: Exception) -> None:
+            nonlocal worker
             if self._stopped:
                 return
-
-            def requeue() -> None:
-                key = f"{pod.metadata.namespace}/{pod.metadata.name}"
-                delay = self.pod_backoff.next_(key)
-                threading.Event().wait(delay)
-                if self._stopped:
-                    return
-                try:
-                    fresh = self.client.pods(pod.metadata.namespace).get(
-                        pod.metadata.name
-                    )
-                    if not fresh.spec.node_name:
-                        self.pod_queue.add(fresh)
-                except Exception:
-                    pass  # deleted; drop
-
-            threading.Thread(target=requeue, daemon=True).start()
+            key = f"{pod.metadata.namespace}/{pod.metadata.name}"
+            when = time.monotonic() + self.pod_backoff.next_(key)
+            with due:
+                heapq.heappush(heap, (when, next(order), pod))
+                if worker is None:
+                    worker = threading.Thread(
+                        target=work, daemon=True, name="scheduler-requeue")
+                    worker.start()
+                due.notify()
 
         return handle
 

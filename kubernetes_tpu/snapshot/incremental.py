@@ -9,7 +9,10 @@ module restores the reference's cost model at the array level:
 
   * `IncrementalEncoder` subscribes to SchedulerCache mutations
     (cache.add_listener) and patches the node-axis arrays in place —
-    O(changed rows) per event, never O(cluster) per wave.
+    O(changed rows) per event, never O(cluster) per wave. A wave's pod
+    events go in a batch at a time: each carries its template's shared
+    contribution (oracle/state.pod_contribution), and the aggregates
+    take one scatter-add per batch, not eight scalar writes per pod.
   * Vocabularies live in a persistent `VocabBundle`, append-only, so ids
     agree across waves; per-wave pending pods are encoded by a plain
     SnapshotEncoder sharing the bundle with `visit_state=False`
@@ -43,15 +46,18 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from kubernetes_tpu.api.types import (
-    AFFINITY_ANNOTATION,
     Node,
     Pod,
-    get_affinity,
     get_taints,
-    pod_nonzero_request,
+    has_pod_affinity,
 )
 from kubernetes_tpu.oracle.priorities import get_zone_key
-from kubernetes_tpu.oracle.state import ClusterState, _calculate_resource
+from kubernetes_tpu.oracle.state import (
+    ClusterState,
+    PodContribution,
+    _pod_key,
+    pod_contribution,
+)
 from kubernetes_tpu.snapshot.encode import (
     ClusterSnapshot,
     PodBatch,
@@ -67,40 +73,7 @@ from kubernetes_tpu.api.resource import (
     resource_list_cpu_milli,
     resource_list_memory,
 )
-
-
-def _has_pod_affinity(pod: Pod) -> bool:
-    """True when this pod contributes to (or poisons) the inter-pod
-    affinity program — the global-coupling gate."""
-    if pod.spec.affinity is None and AFFINITY_ANNOTATION not in pod.metadata.annotations:
-        return False
-    try:
-        aff = get_affinity(pod)
-    except Exception:
-        return True  # malformed annotation == poison (encoder marks it)
-    return aff is not None and (
-        aff.pod_affinity is not None or aff.pod_anti_affinity is not None
-    )
-
-
-class _PodContribution:
-    """Exactly what one assigned pod added to its node's row — recorded
-    at add time so removal is a perfect inverse (no re-parse drift)."""
-
-    __slots__ = ("slot", "cpu", "mem", "gpu", "nzcpu", "nzmem", "ports",
-                 "class_id", "affinity")
-
-    def __init__(self, slot, cpu, mem, gpu, nzcpu, nzmem, ports, class_id,
-                 affinity):
-        self.slot = slot
-        self.cpu = cpu
-        self.mem = mem
-        self.gpu = gpu
-        self.nzcpu = nzcpu
-        self.nzmem = nzmem
-        self.ports = ports  # list of port ids
-        self.class_id = class_id
-        self.affinity = affinity
+from kubernetes_tpu.trace import profile as trace_profile
 
 
 def _grow_cols(a: np.ndarray, cols: int) -> np.ndarray:
@@ -137,8 +110,13 @@ class IncrementalEncoder:
         self._schedulable = np.zeros(0, bool)
         self._node_gone = np.zeros(0, bool)  # node deleted, pods linger
         self._pod_count_slot = np.zeros(0, np.int64)
-        # per-pod contributions
-        self._contribs: Dict[Tuple[str, str], _PodContribution] = {}
+        # (namespace, name) -> (slot, contribution): exactly what the
+        # pod added to its node's row, recorded at add time so removal
+        # is a perfect inverse (no re-parse drift). The contribution is
+        # the template's shared, immutable one.
+        self._contribs: Dict[
+            Tuple[str, str], Tuple[int, PodContribution]
+        ] = {}
         self._affinity_pods = 0  # cluster-wide gate counter
         # per-(slot) port id multiset
         self._port_counts: List[Optional[Dict[int, int]]] = []
@@ -286,7 +264,15 @@ class IncrementalEncoder:
     # -- cache listener ------------------------------------------------------
 
     def on_cache_event(self, kind: str, obj) -> None:
-        """Called under the cache lock; just queue (apply at wave time)."""
+        """Called under the cache lock; just queue (apply at wave time).
+        A pod event is queued as what applying it takes, read while the
+        cache has the pod in hand (NodeInfo has just read the same
+        fields): its key and, for an add, its node and its contribution.
+        Applying a wave's events then touches no pod object again."""
+        if kind == "pod_add":
+            obj = (_pod_key(obj), obj.spec.node_name, pod_contribution(obj))
+        elif kind == "pod_remove":
+            obj = (_pod_key(obj), None, None)
         with self._lock:
             self._events.append((kind, obj))
 
@@ -436,39 +422,27 @@ class IncrementalEncoder:
             self._dirty_node_side = True
         return slot
 
-    def _apply_pod_add(self, pod: Pod) -> None:
-        key = (pod.namespace, pod.metadata.name)
-        if key in self._contribs:
-            self._apply_pod_remove(pod)  # defensive: treat as update
+    def _bump(self, slot: int, c: PodContribution, class_id: int,
+              sign: int) -> None:
+        self.req_mcpu[slot] += sign * c.cpu
+        self.req_mem[slot] += sign * c.mem
+        self.req_gpu[slot] += sign * c.gpu
+        self.nz_mcpu[slot] += sign * c.nonzero_cpu
+        self.nz_mem[slot] += sign * c.nonzero_mem
+        self.pod_count[slot] += sign
+        self._pod_count_slot[slot] += sign
+        self.class_count[slot, class_id] += sign
+
+    def _add_one(self, key: Tuple[str, str], node_name: str,
+                 c: PodContribution) -> None:
+        """One pod_add applied on the spot (the per-event path)."""
         v = self.vocabs
-        slot = self._slot_for_pod(pod.spec.node_name)
-        cpu, mem, gpu = _calculate_resource(pod)
-        nzcpu, nzmem = pod_nonzero_request(pod)
-        ports = []
-        for c in pod.spec.containers:
-            for p in c.ports:
-                if p.host_port != 0:
-                    ports.append(v.ports.get(p.host_port))
-        class_key = (
-            pod.namespace,
-            frozenset(pod.metadata.labels.items()),
-            pod.metadata.deletion_timestamp is not None,
-        )
-        class_id = v.classes.get(class_key)
-        affinity = _has_pod_affinity(pod)
+        slot = self._slot_for_pod(node_name)
+        ports = [v.ports.get(p) for p in c.host_ports]
+        class_id = v.classes.get(c.class_key)
         self._widths_sync()
-        contrib = _PodContribution(
-            slot, cpu, mem, gpu, nzcpu, nzmem, ports, class_id, affinity
-        )
-        self._contribs[key] = contrib
-        self.req_mcpu[slot] += cpu
-        self.req_mem[slot] += mem
-        self.req_gpu[slot] += gpu
-        self.nz_mcpu[slot] += nzcpu
-        self.nz_mem[slot] += nzmem
-        self.pod_count[slot] += 1
-        self._pod_count_slot[slot] += 1
-        self.class_count[slot, class_id] += 1
+        self._contribs[key] = (slot, c)
+        self._bump(slot, c, class_id, 1)
         if ports:
             pc = self._port_counts[slot]
             if pc is None:
@@ -478,26 +452,18 @@ class IncrementalEncoder:
             self.port_mask[slot] = _pack_bits(
                 list(pc), self.port_mask.shape[1]
             )
-        if affinity:
+        if c.affinity:
             self._affinity_pods += 1
 
-    def _apply_pod_remove(self, pod: Pod) -> None:
-        key = (pod.namespace, pod.metadata.name)
-        contrib = self._contribs.pop(key, None)
-        if contrib is None:
-            return
-        slot = contrib.slot
-        self.req_mcpu[slot] -= contrib.cpu
-        self.req_mem[slot] -= contrib.mem
-        self.req_gpu[slot] -= contrib.gpu
-        self.nz_mcpu[slot] -= contrib.nzcpu
-        self.nz_mem[slot] -= contrib.nzmem
-        self.pod_count[slot] -= 1
-        self._pod_count_slot[slot] -= 1
-        self.class_count[slot, contrib.class_id] -= 1
-        if contrib.ports:
+    def _remove_one(self, slot: int, c: PodContribution) -> None:
+        """One held pod taken out on the spot (the per-event path); its
+        `_contribs` entry is already popped."""
+        v = self.vocabs
+        self._bump(slot, c, v.classes.ids[c.class_key], -1)
+        if c.host_ports:
             pc = self._port_counts[slot] or {}
-            for pid in contrib.ports:
+            for port in c.host_ports:
+                pid = v.ports.ids[port]
                 n = pc.get(pid, 0) - 1
                 if n <= 0:
                     pc.pop(pid, None)
@@ -506,26 +472,126 @@ class IncrementalEncoder:
             self.port_mask[slot] = _pack_bits(
                 list(pc), self.port_mask.shape[1]
             )
-        if contrib.affinity:
+        if c.affinity:
             self._affinity_pods -= 1
         if self._node_gone[slot] and self._pod_count_slot[slot] == 0:
             self._free_slot(slot)
 
+    def _apply_pod_events(self, run: List[Tuple[str, tuple]]) -> None:
+        """One run of consecutive pod events (as on_cache_event queued
+        them) as a batch: per event a row lookup, a slot lookup and a
+        dict insert or pop; per batch one integer scatter-add per
+        aggregate. The arrays come out exactly as event-by-event
+        application leaves them.
+
+        The sums commute, so only three things depend on order, and
+        each keeps it: `_contribs` is updated event by event; a spread
+        class takes its vocabulary id when its contribution first
+        appears; and whatever touches a gone-node slot (a pod on an
+        unknown node materialises one, its last pod leaving frees and
+        zeroes it) or carries host ports or affinity is applied on the
+        spot (`_add_one` / `_remove_one`). Within a batch a slot is
+        either live throughout and takes deferred deltas only, or gone
+        or free and takes immediate ones only: no node event falls
+        inside a batch, so a zeroed row never meets a deferred delta."""
+        contribs = self._contribs
+        slot_of = self.slot_of
+        classes = self.vocabs.classes
+        # id(contribution) -> its row in `sums` / `class_ids`, or -1 for
+        # one that goes event by event; `alive` pins each contribution so
+        # that an id names one of them for the whole batch
+        rows: Dict[int, int] = {}
+        alive: List[PodContribution] = []
+        sums: List[Tuple[int, int, int, int, int]] = []
+        class_ids: List[int] = []
+
+        def first_seen(c: PodContribution) -> int:
+            alive.append(c)
+            if c.host_ports or c.affinity:
+                rows[id(c)] = -1
+                return -1
+            known = len(classes)
+            class_ids.append(classes.get(c.class_key))
+            if len(classes) != known:
+                self._widths_sync()
+            sums.append((c.cpu, c.mem, c.gpu, c.nonzero_cpu, c.nonzero_mem))
+            rows[id(c)] = row = len(sums) - 1
+            return row
+
+        # deferred deltas, as (slot, row) pairs: pods in, pods out
+        in_at: List[int] = []
+        in_row: List[int] = []
+        out_at: List[int] = []
+        out_row: List[int] = []
+        any_gone = bool(self._node_gone.any())
+        fallbacks = 0
+        for kind, (key, node_name, added) in run:
+            # an add of a pod already held is an update: out, then in
+            held = contribs.pop(key, None)
+            if held is not None:
+                slot, c = held
+                row = rows.get(id(c))
+                if row is None:
+                    row = first_seen(c)
+                if row < 0 or (any_gone and self._node_gone[slot]):
+                    self._remove_one(slot, c)
+                    fallbacks += 1
+                else:
+                    out_at.append(slot)
+                    out_row.append(row)
+            if kind == "pod_remove":
+                continue
+            row = rows.get(id(added))
+            if row is None:
+                row = first_seen(added)
+            slot = slot_of.get(node_name)
+            if (row < 0 or slot is None
+                    or (any_gone and self._node_gone[slot])):
+                self._add_one(key, node_name, added)
+                any_gone = True  # it may have materialised a gone slot
+                fallbacks += 1
+                continue
+            contribs[key] = (slot, added)
+            in_at.append(slot)
+            in_row.append(row)
+        if in_at or out_at:
+            slots = np.array(in_at + out_at, np.intp)
+            row_of = np.array(in_row + out_row, np.intp)
+            sign = np.ones(len(slots), np.int64)
+            sign[len(in_at):] = -1
+            deltas = np.array(sums, np.int64)[row_of] * sign[:, None]
+            for col, f in enumerate(
+                ("req_mcpu", "req_mem", "req_gpu", "nz_mcpu", "nz_mem")
+            ):
+                np.add.at(getattr(self, f), slots, deltas[:, col])
+            np.add.at(self.pod_count, slots, sign)
+            np.add.at(self._pod_count_slot, slots, sign)
+            np.add.at(
+                self.class_count,
+                (slots, np.array(class_ids, np.intp)[row_of]), sign,
+            )
+        self._dirty_pod_side = True
+        trace_profile.count_encoder_batch(len(run), fallbacks)
+
     def apply_pending(self) -> None:
-        for kind, obj in self._drain():
-            if kind == "pod_add":
-                self._apply_pod_add(obj)
-                self._dirty_pod_side = True
-            elif kind == "pod_remove":
-                self._apply_pod_remove(obj)
-                self._dirty_pod_side = True
-            elif kind == "node_set":
+        run: List[Tuple[str, tuple]] = []  # consecutive pod events
+        for event in self._drain():
+            kind, obj = event
+            if kind == "pod_add" or kind == "pod_remove":
+                run.append(event)
+                continue
+            if run:  # a node event ends the batch: order against it holds
+                self._apply_pod_events(run)
+                run = []
+            if kind == "node_set":
                 self._apply_node_set(obj)
                 self._dirty_node_side = True
             elif kind == "node_remove":
                 self._apply_node_remove(obj)
                 self._dirty_node_side = True
                 self._dirty_pod_side = True
+        if run:
+            self._apply_pod_events(run)
 
     # -- wave view -----------------------------------------------------------
 
@@ -579,7 +645,7 @@ class IncrementalEncoder:
         if self._affinity_pods > 0 or not self._config_ok():
             return None, None, frozenset()
         for p in pending:
-            if p.spec.volumes or _has_pod_affinity(p):
+            if p.spec.volumes or has_pod_affinity(p):
                 return None, None, frozenset()
         # encode pending pods against the shared vocabs; the light state
         # carries only the spread listers (no node scan)

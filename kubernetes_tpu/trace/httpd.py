@@ -45,6 +45,8 @@ def render_traces(query: Dict[str, str]) -> dict:
         "items": items,
         # the last programs built here: which step recompiled
         "compiles": _profile.recent_compiles(),
+        # whether cache deltas reach the snapshot a batch at a time
+        "encoder": _profile.encoder_totals(),
     }
 
 

@@ -248,6 +248,29 @@ def idle_totals() -> Dict[str, float]:
     return {p: snap[p] for p in IDLE_STATES}
 
 
+# -- the incremental encoder's totals -------------------------------------------
+
+_encoder_lock = threading.Lock()
+#: pod events the incremental encoder (snapshot/incremental.py) applied
+#: in this process, the batches they came in, and the events inside a
+#: batch that had to be applied one at a time (host ports, affinity, a
+#: gone-node slot); served on /debug/traces as "encoder"
+_ENCODER = {"events": 0, "batches": 0, "per_event_fallbacks": 0}
+
+
+def count_encoder_batch(events: int, per_event_fallbacks: int) -> None:
+    """One batch of `events` pod events went into the snapshot arrays."""
+    with _encoder_lock:
+        _ENCODER["events"] += events
+        _ENCODER["batches"] += 1
+        _ENCODER["per_event_fallbacks"] += per_event_fallbacks
+
+
+def encoder_totals() -> Dict[str, int]:
+    with _encoder_lock:
+        return dict(_ENCODER)
+
+
 # -- XLA compile-vs-execute attribution ---------------------------------------
 
 _install_lock = threading.Lock()

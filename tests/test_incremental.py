@@ -367,3 +367,158 @@ def test_daemon_warmup_compiles_incremental_shapes():
     assert all(g is not None for g in got)
     # the wave must hit only programs warmup already compiled
     assert not compiles, compiles
+
+
+# -- a batch of deltas against the same deltas one at a time ----------------
+
+_BATCH_TEMPLATES = (
+    # (labels, requests, host ports, affinity annotation)
+    ({"name": "sched-perf"}, {"cpu": "100m", "memory": "500Mi"}, (), None),
+    ({"app": "web"}, {"cpu": "250m"}, (), None),
+    ({"app": "db", "tier": "be"}, {"memory": "1Gi"}, (), None),
+    ({}, {}, (), None),
+    ({"app": "web"}, {"cpu": "100m"}, (8080,), None),
+    ({"app": "lb"}, {"cpu": "50m"}, (9090, 8080), None),
+    ({"app": "near"}, {"cpu": "100m"}, (),
+     '{"podAntiAffinity": {"requiredDuringSchedulingIgnoredDuringExecution":'
+     ' [{"labelSelector": {"matchLabels": {"app": "near"}},'
+     ' "topologyKey": "kubernetes.io/hostname"}]}}'),
+)
+
+
+def _batch_pod(rng, name, node_name, fresh_class=None):
+    from kubernetes_tpu.api.types import AFFINITY_ANNOTATION
+
+    weights = (8, 4, 3, 2, 2, 1, 1)
+    labels, reqs, ports, affinity = rng.choices(_BATCH_TEMPLATES, weights)[0]
+    labels = dict(labels)
+    if fresh_class is not None:  # a spread class nobody has seen yet
+        labels["gen"] = fresh_class
+    meta = ObjectMeta(name=name, labels=labels)
+    if affinity is not None:
+        meta.annotations[AFFINITY_ANNOTATION] = affinity
+    if rng.random() < 0.05:
+        meta.deletion_timestamp = "2026-01-01T00:00:00Z"
+    return Pod(
+        metadata=meta,
+        spec=PodSpec(
+            node_name=node_name,
+            containers=[Container(
+                requests=dict(reqs),
+                ports=[ContainerPort(host_port=p) for p in ports],
+            )],
+        ),
+    )
+
+
+def _batch_events(rng, steps, nodes, pods, seq):
+    """`steps` raw cache events: what SchedulerCache would send and what
+    it never would (a re-add with no remove between, a remove of a pod
+    nobody holds), so the encoder's defensive branches run too. `nodes`
+    and `pods` (name -> object) follow what the stream leaves live."""
+    events = []
+    for _ in range(steps):
+        op = rng.random()
+        if op < 0.08 or not nodes:
+            name = f"node-{rng.randrange(24):03d}"
+            nodes[name] = rand_node(rng, name)
+            events.append(("node_set", nodes[name]))
+        elif op < 0.12:
+            # under its pods, if it has any: they keep the row as a
+            # gone-node slot until the last of them leaves
+            name = rng.choice(list(nodes))
+            events.append(("node_remove", nodes.pop(name)))
+        elif op < 0.62:
+            r = rng.random()
+            if r < 0.08:
+                node_name = f"unsynced-{rng.randrange(3)}"
+            else:
+                node_name = rng.choice(list(nodes))
+            if pods and rng.random() < 0.1:
+                name = rng.choice(list(pods))  # a re-add: an update
+            else:
+                seq[0] += 1
+                name = f"pod-{seq[0]}"
+            fresh = f"g{seq[0]}" if rng.random() < 0.06 else None
+            pod = _batch_pod(rng, name, node_name, fresh)
+            pods[name] = pod
+            events.append(("pod_add", pod))
+            if rng.random() < 0.15:  # in and out inside one batch
+                events.append(("pod_remove", pods.pop(name)))
+        elif pods:
+            if rng.random() < 0.05:
+                events.append(
+                    ("pod_remove", _batch_pod(rng, "never-held", "node-000")))
+                continue
+            # pods that hold the affinity gate shut go first, so that most
+            # rounds end with a snapshot to compare
+            gated = [n for n, p in pods.items() if p.metadata.annotations]
+            name = rng.choice(gated or list(pods))
+            events.append(("pod_remove", pods.pop(name)))
+    return events
+
+
+def _assert_same_view(a, b, context):
+    import dataclasses
+
+    assert (a is None) == (b is None), context
+    if a is None:
+        return
+    for f in dataclasses.fields(a):
+        x, y = getattr(a, f.name), getattr(b, f.name)
+        where = f"{context}: {f.name}"
+        if isinstance(x, np.ndarray):
+            assert isinstance(y, np.ndarray), where
+            assert x.dtype == y.dtype and x.shape == y.shape, where
+            assert np.array_equal(x, y, equal_nan=x.dtype.kind == "f"), where
+        else:
+            assert x == y, where
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_batched_deltas_equal_one_at_a_time(seed):
+    """One apply_pending over a stream of cache events leaves exactly
+    what applying the same events one by one leaves: every snapshot
+    field, dtype for dtype, the batch, `keep`, the vocabularies' ids in
+    their order of first appearance, and the encoder's own books."""
+    rng = random.Random(9100 + seed)
+    batched, single = IncrementalEncoder(initial_slots=4), \
+        IncrementalEncoder(initial_slots=4)
+    nodes, pods, seq = {}, {}, [0]
+    views = 0
+    for rnd in range(8):
+        events = _batch_events(rng, rng.choice([5, 40, 120]), nodes, pods,
+                               seq)
+        for kind, obj in events:
+            batched.on_cache_event(kind, obj)
+            single.on_cache_event(kind, obj)
+            single.apply_pending()
+        pending = [rand_pending(rng, rnd)]
+        snap_a, batch_a, keep_a = batched.wave_view(pending)
+        snap_b, batch_b, keep_b = single.wave_view(pending)
+        ctx = f"seed {seed} round {rnd}"
+        _assert_same_view(snap_a, snap_b, ctx)
+        _assert_same_view(batch_a, batch_b, ctx)
+        assert keep_a == keep_b, ctx
+        views += snap_a is not None
+        for vocab in ("classes", "ports", "kv", "keys", "taints", "zones"):
+            assert (list(getattr(batched.vocabs, vocab).ids.items())
+                    == list(getattr(single.vocabs, vocab).ids.items())), \
+                f"{ctx}: vocabulary {vocab}"
+        # the books a snapshot does not show (or shows only when no
+        # affinity pod holds the gate shut)
+        for f in ("req_mcpu", "req_mem", "req_gpu", "nz_mcpu", "nz_mem",
+                  "pod_count", "_pod_count_slot", "class_count",
+                  "port_mask", "_node_gone", "_schedulable"):
+            x, y = getattr(batched, f), getattr(single, f)
+            assert x.dtype == y.dtype and np.array_equal(x, y), f"{ctx}: {f}"
+        for f in ("slot_of", "_free", "node_names", "_port_counts",
+                  "_affinity_pods", "_contribs", "_order_dirty"):
+            assert getattr(batched, f) == getattr(single, f), f"{ctx}: {f}"
+        # and the sums are the held pods', whatever the order was
+        want = np.zeros_like(batched.req_mcpu)
+        for slot, c in batched._contribs.values():
+            want[slot] += c.cpu
+        assert np.array_equal(batched.req_mcpu, want), ctx
+        assert set(batched._contribs) == {("default", n) for n in pods}, ctx
+    assert views >= 2, "the affinity gate hid every snapshot of this seed"
