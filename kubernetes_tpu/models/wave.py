@@ -419,6 +419,13 @@ def pick_j(config: SchedulerConfig, max_j: int, snap: ClusterSnapshot,
     return J, min(depth, J)
 
 
+#: which path decided a pod, in the order of `stats["pods_by_path"]`:
+#: the serial scan program (`flush`), a run's own probe or device
+#: replay (`run_single`), a grouped header probe replayed on the host,
+#: a grouped device replay
+PATHS = ("scan", "single", "group_host", "group_device")
+_SCAN, _SINGLE, _GROUP_HOST, _GROUP_DEVICE = range(len(PATHS))
+
 #: pick-scan length floors of the zoned device replay: one run per
 #: dispatch pads to 256; the grouped form runs K steps PER RUN, so its
 #: padding costs G times over and the floor is lower
@@ -662,6 +669,14 @@ class WaveScheduler:
             # beside the per-wave `dispatches` dict, which _wave_setup
             # empties (a window's launches are a diff of this)
             "dispatches": 0,
+            # the same launches by kind (`_count`'s keys), and the pods
+            # each path decided (PATHS), all waves: a window's shares
+            # are diffs of these. They add up to the pods handed to
+            # schedule_backlog; `pods_unplaced` are those among them
+            # that fitted nowhere (-1 in its answer)
+            "dispatches_by_kind": {},
+            "pods_by_path": dict.fromkeys(PATHS, 0),
+            "pods_unplaced": 0,
         }
 
     # fraction of changed rows above which a scatter-row update loses
@@ -995,6 +1010,21 @@ class WaveScheduler:
     def _count(self, key: str) -> None:
         self.dispatches[key] = self.dispatches.get(key, 0) + 1
         self.stats["dispatches"] += 1
+        by_kind = self.stats["dispatches_by_kind"]
+        by_kind[key] = by_kind.get(key, 0) + 1
+
+    def _count_wave(self, via: np.ndarray, out: np.ndarray) -> None:
+        """A finished wave into the cumulative tallies, here and on
+        /debug/traces (trace/profile.wave_totals)."""
+        from kubernetes_tpu.trace.profile import count_wave
+
+        pods = dict(zip(PATHS, np.bincount(via, minlength=len(PATHS))
+                        .tolist()))
+        unplaced = int(np.count_nonzero(out < 0))
+        for path, n in pods.items():
+            self.stats["pods_by_path"][path] += n
+        self.stats["pods_unplaced"] += unplaced
+        count_wave(pods, self.dispatches, unplaced)
 
     # -- backlog -------------------------------------------------------------
 
@@ -1078,6 +1108,10 @@ class WaveScheduler:
             snap, keep, source, last_node_index)
         P = len(rep_idx)
         out = np.full(P, -1, np.int32)
+        # the path that decided each position (an index into PATHS): a
+        # fast path marks its span when it takes it, and what it hands
+        # on to `pending` is marked again by the scan that decides it
+        via = np.full(P, _SCAN, np.int8)
         perm = np.asarray(snap.name_desc_order).astype(np.int64)
         N = snap.num_nodes
 
@@ -1120,6 +1154,7 @@ class WaveScheduler:
                 return carry
             carry = settle(carry)
             rows = np.asarray(pending, np.int64)
+            via[rows] = _SCAN
             seg = gather_batch(batch, rep_idx[rows])
             seg = pad_batch(seg, next_pow2(len(rows), self.pod_floor))
             pods = self._packer.ship({
@@ -1217,6 +1252,7 @@ class WaveScheduler:
             svc_ctx = info["svc_ctx"]
             layout, buf = _pack_run(rep)
             done = done0
+            via[start + done:start + length] = _SINGLE
             while done < length:
                 K = length - done
                 J, rows = self._pick_j(snap, batch, rep, K)
@@ -1344,6 +1380,8 @@ class WaveScheduler:
             replays them in FIFO order."""
             nonlocal L_host
             G = len(group)
+            for g in group:
+                via[g["start"]:g["start"] + g["length"]] = _GROUP_HOST
             G_bucket, glayout, gbuf = group_buffer(batch, [g["rep"] for g in group])
             prev = fold.pop() if fold else None
             with phase_timer("probe"):
@@ -1382,6 +1420,8 @@ class WaveScheduler:
                 self._zreplay = ZReplay(self.config, self._apply_fn,
                                         self._apply_group_fn)
             G = len(group)
+            for g in group:
+                via[g["start"]:g["start"] + g["length"]] = _GROUP_DEVICE
             G_bucket, glayout, gbuf = group_buffer(batch, [g["rep"] for g in group])
             maxlen = max(g["length"] for g in group)
             K_bucket = replay_k_bucket(maxlen, ZREPLAY_GROUP_K_FLOOR)
@@ -1486,4 +1526,5 @@ class WaveScheduler:
             idx += 1
         carry = settle(carry)
         carry = flush(carry)
+        self._count_wave(via, out)
         return out, carry, L_host

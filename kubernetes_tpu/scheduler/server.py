@@ -272,7 +272,8 @@ class SchedulerServer:
                     self.factory.unassigned_reflector.wait_for_sync(
                         timeout=10
                     )
-                    n = len(self.factory.node_lister.list())
+                    nodes = self.factory.node_lister.list()
+                    n = len(nodes)
                     # warmup only pays off for a genuinely idle daemon:
                     # if work arrives within the grace window, the first
                     # real wave compiles/loads exactly the shapes it
@@ -295,7 +296,7 @@ class SchedulerServer:
                             time.sleep(0.05)
                     if n and idle:
                         try:
-                            algo.warmup(n, phase="run")
+                            algo.warmup(n, phase="run", nodes=nodes)
                         except Exception as e:
                             # the programs every wave needs did not
                             # compile or run on this device: never
@@ -332,7 +333,8 @@ class SchedulerServer:
                                     idle_since = _t.monotonic()
                                 elif _t.monotonic() - idle_since >= 5.0:
                                     try:
-                                        algo.warmup(n, phase="scan")
+                                        algo.warmup(n, phase="scan",
+                                                    nodes=nodes)
                                     except Exception:
                                         log.error(
                                             "scan warmup failed",

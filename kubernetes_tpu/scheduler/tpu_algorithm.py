@@ -118,9 +118,9 @@ class TPUScheduleAlgorithm:
 
             self._inc = IncrementalEncoder(config=algo_config)
             cache.add_listener(self._inc.on_cache_event)
-            self._service_lister = service_lister
-            self._controller_lister = controller_lister
-            self._replica_set_lister = replica_set_lister
+        self._service_lister = service_lister
+        self._controller_lister = controller_lister
+        self._replica_set_lister = replica_set_lister
         # selectHost's round-robin counter persists across waves, like the
         # reference's genericScheduler.lastNodeIndex persists across pods
         self._last_node_index = 0
@@ -148,13 +148,24 @@ class TPUScheduleAlgorithm:
             rep_idx[i] = r
         return reps, rep_idx
 
-    def warmup(self, num_nodes: int, phase: str = "all") -> None:
+    def warmup(self, num_nodes: int, phase: str = "all",
+               nodes: Optional[Sequence] = None) -> None:
         """Compile the wave programs for an `num_nodes`-sized cluster
         before the first real pod arrives (server.py runs this in the
         background while informers sync): a cold XLA compile
         otherwise lands on the first scheduling cycle.
-        Uses a synthetic cluster shaped like the common case (label-only
-        pods, unlabeled nodes) so the program shapes match.
+        Uses a synthetic cluster shaped like the one it will serve, as
+        far as that is known before a pod arrives: the caller's own
+        `nodes` where it has them (their labels and zones set the label
+        and zone widths; unlabeled synthetic ones otherwise), and pods
+        that carry the selectors of the daemon's ReplicationControllers
+        (label-only `app: warm` pods where there are none), one of each
+        already bound, so the spread-class axis is as wide as the
+        controllers' templates make it. The per-bucket backlogs deal the
+        templates in turn, as replication managers replacing replicas
+        do: with one controller that is one run (the probe path), with
+        hundreds every run has length 1 and each bucket warms the scan
+        program at the cluster's real shapes.
 
         phase "run" warms only the run path (probe+replay+apply — what
         every template-created backlog hits); phase "scan" warms the
@@ -187,7 +198,7 @@ class TPUScheduleAlgorithm:
         )
         from kubernetes_tpu.oracle.state import ClusterState as CS
 
-        nodes = [
+        nodes = list(nodes) if nodes else [
             Node(
                 metadata=ObjectMeta(name=f"warm-{i:05d}"),
                 status=NodeStatus(
@@ -197,16 +208,27 @@ class TPUScheduleAlgorithm:
             )
             for i in range(max(num_nodes, 1))
         ]
+        lister = self._controller_lister
+        controllers = list(lister.list()) if lister is not None else []
+        templates = [dict(rc.spec.selector) for rc in controllers
+                     if rc.spec.selector] or [{"app": "warm"}]
 
-        def pod(name, cpu):
+        def pod(name, cpu, turn=0):
             return PodT(
-                metadata=ObjectMeta(name=name, labels={"app": "warm"}),
+                metadata=ObjectMeta(
+                    name=name, labels=templates[turn % len(templates)]),
                 spec=PodSpec(containers=[
                     Container(image="warm", requests={"cpu": cpu})
                 ]),
             )
 
-        state = CS.build(nodes)
+        bound = []
+        if controllers:
+            for t in range(len(templates)):
+                p = pod(f"wbound-{t}", "100m", t)
+                p.spec.node_name = nodes[t % len(nodes)].metadata.name
+                bound.append(p)
+        state = CS.build(nodes, bound, controllers=controllers)
         # an eligible run (probe+replay+apply programs); the lone pods
         # distinct only in their requests (below min_run => the scan
         # program) warm in phase "scan" — differing by resources keeps
@@ -216,7 +238,7 @@ class TPUScheduleAlgorithm:
             self._warm_one(
                 [pod(f"w{i}", "100m")
                  for i in range(max(self._wave.min_run, 2))],
-                state, nodes,
+                state, nodes, bound,
             )
             # two adjacent template runs warm the GROUPED programs
             # (header probe + grouped fold) — the multi-template
@@ -225,7 +247,7 @@ class TPUScheduleAlgorithm:
             self._warm_one(
                 [pod(f"wg{i}", "100m") for i in range(n)]
                 + [pod(f"wh{i}", "150m") for i in range(n)],
-                state, nodes,
+                state, nodes, bound,
             )
             # every pod-axis pow2 bucket a daemon wave can land in:
             # burst-adaptive gathering produces waves anywhere in
@@ -240,9 +262,9 @@ class TPUScheduleAlgorithm:
             bucket = max(self._wave.pod_floor, self._wave.min_run, 2)
             while bucket <= cap:
                 self._warm_one(
-                    [pod(f"wb{bucket}-{i}", "100m")
+                    [pod(f"wb{bucket}-{i}", "100m", i)
                      for i in range(bucket)],
-                    state, nodes,
+                    state, nodes, bound,
                 )
                 bucket *= 2
             if _eager_scan_warm():
@@ -257,11 +279,12 @@ class TPUScheduleAlgorithm:
                     self._warm_one(
                         [pod(f"wsb{k}-{i}", f"{200 + i}m")
                          for i in range(k)],
-                        state, nodes,
+                        state, nodes, bound,
                     )
         if phase in ("all", "scan"):
             self._warm_one([pod("w-scan", "200m"),
-                            pod("w-scan2", "300m")], state, nodes)
+                            pod("w-scan2", "300m")], state, nodes,
+                           bound)
 
     def _warmup_mesh(self, num_nodes: int, scan: bool = False) -> None:
         """Compile the sharded programs for the cluster's node bucket
@@ -357,7 +380,7 @@ class TPUScheduleAlgorithm:
                 self._inc = saved_inc
                 self._last_node_index = saved_last
 
-    def _warm_one(self, backlog, state, nodes) -> None:
+    def _warm_one(self, backlog, state, nodes, bound) -> None:
         with self._sched_lock:
             saved_last, saved_inc = self._last_node_index, self._inc
             try:
@@ -375,6 +398,8 @@ class TPUScheduleAlgorithm:
                     inc = IncrementalEncoder(config=self._wave.config)
                     for n in nodes:
                         inc.on_cache_event("node_set", n)
+                    for p in bound:
+                        inc.on_cache_event("pod_add", p)
                     self._inc = inc
                 else:
                     self._inc = None  # compile via the full-encode path

@@ -47,6 +47,8 @@ def render_traces(query: Dict[str, str]) -> dict:
         "compiles": _profile.recent_compiles(),
         # whether cache deltas reach the snapshot a batch at a time
         "encoder": _profile.encoder_totals(),
+        # which path decided the pods, and what was launched for them
+        "wave": _profile.wave_totals(),
     }
 
 

@@ -59,7 +59,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Dict, List
+from typing import Any, Dict, List
 
 from kubernetes_tpu.metrics import (
     scheduler_wave_phase_seconds,
@@ -269,6 +269,38 @@ def count_encoder_batch(events: int, per_event_fallbacks: int) -> None:
 def encoder_totals() -> Dict[str, int]:
     with _encoder_lock:
         return dict(_ENCODER)
+
+
+# -- the single-chip wave driver's totals -----------------------------------------
+
+_wave_lock = threading.Lock()
+#: what the wave driver (models/wave.WaveScheduler.schedule_backlog) did
+#: in this process, all waves: pods decided by each path, device
+#: programs launched by kind, pods that fitted nowhere; served on
+#: /debug/traces as "wave"
+_WAVE: Dict[str, Any] = {"waves": 0, "pods_by_path": {},
+                         "dispatches_by_kind": {}, "pods_unplaced": 0}
+
+
+def count_wave(pods_by_path: Dict[str, int], dispatches: Dict[str, int],
+               unplaced: int) -> None:
+    """One wave is decided: `pods_by_path` pods went through each path,
+    `dispatches` programs were launched by kind, `unplaced` pods
+    fitted nowhere."""
+    with _wave_lock:
+        _WAVE["waves"] += 1
+        _WAVE["pods_unplaced"] += unplaced
+        for key, add in (("pods_by_path", pods_by_path),
+                         ("dispatches_by_kind", dispatches)):
+            tally = _WAVE[key]
+            for k, n in add.items():
+                tally[k] = tally.get(k, 0) + n
+
+
+def wave_totals() -> Dict[str, Any]:
+    with _wave_lock:
+        return {k: dict(v) if isinstance(v, dict) else v
+                for k, v in _WAVE.items()}
 
 
 # -- XLA compile-vs-execute attribution ---------------------------------------

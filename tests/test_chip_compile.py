@@ -114,6 +114,42 @@ def test_served_program_compiles_for_v5e(bucket, name, registry, one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 1 << 30
 
 
+#: seconds the chip's compiler may take over one shipment's unpack
+#: program. The chip's host compiles about three times slower than this
+#: sandbox and the deployment asks for under 30 s there; the uint8
+#: buffer this guards against took 175 s here at the second size
+UNPACK_COMPILE_S = 10.0
+
+
+@pytest.mark.parametrize("pods,controllers", [(1024, 1), (4096, 500)],
+                         ids=["density-1k", "spread-3k"])
+def test_shipment_unpack_compiles_for_v5e_in_seconds(pods, controllers,
+                                                     one_chip):
+    """models/pack's program over a wave's pod rows, at the widths the
+    two deployments ship: one template, and 500 controllers whose
+    spread_match is i64[4096, 500] (17 MB)."""
+    import time
+
+    import jax
+
+    from kubernetes_tpu.models.pack import pack_arrays, unpack
+
+    rows = {"spread_match": np.zeros((pods, controllers), np.int64),
+            "class_id": np.zeros(pods, np.int32),
+            "zero_req": np.zeros(pods, np.bool_),
+            "port_mask": np.zeros((pods, 2), np.uint32),
+            "pref_num": np.zeros((pods, 1, 1), np.float64),
+            "req_mem": np.zeros(pods, np.int64)}
+    layout, buf = pack_arrays(rows)
+    began = time.monotonic()
+    compiled = jax.jit(lambda b: unpack(layout, b)).lower(
+        jax.ShapeDtypeStruct(buf.shape, buf.dtype,
+                             sharding=one_chip)).compile()
+    took = time.monotonic() - began
+    assert took < UNPACK_COMPILE_S, f"{took:.1f}s for {buf.nbytes} bytes"
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * buf.nbytes
+
+
 @pytest.mark.xfail(
     strict=True, raises=NotImplementedError,
     reason="the v5e compiler refuses the kernel's compiled lowering: "

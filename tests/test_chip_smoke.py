@@ -173,7 +173,7 @@ def test_daemon_with_a_failed_warmup_never_reports_ready(monkeypatch, caplog):
     )
     from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
 
-    def broken(self, num_nodes, phase="all"):
+    def broken(self, num_nodes, phase="all", nodes=None):
         raise RuntimeError("the device refused the probe program")
 
     monkeypatch.setattr(TPUScheduleAlgorithm, "warmup", broken)
