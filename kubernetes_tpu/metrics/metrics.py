@@ -404,6 +404,20 @@ scheduler_pod_contribution_lookups_total = registry.register(
     )
 )
 
+#: lookups of a pending pod's encoded PodBatch row by template
+#: (snapshot/pending_rows.py), labeled result=hit|miss: a hit gathered
+#: the stored row, a miss went through SnapshotEncoder.encode_pods. A
+#: backlog of a few hundred controllers' pods reads ~100% hits after
+#: its first wave; all-distinct pods read ~100% misses.
+scheduler_pending_row_lookups_total = registry.register(
+    Counter(
+        "scheduler_pending_row_lookups_total",
+        "Lookups of a pending pod's encoded row in the per-template "
+        "store, labeled by result (hit | miss)",
+        label_bound=2,
+    )
+)
+
 #: XLA compile time, attributed separately from execute time (fed by
 #: jax.monitoring compile-duration events; trace/profile.py installs
 #: the listener). The first jit call of every fresh program shape lands

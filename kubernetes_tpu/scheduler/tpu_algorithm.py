@@ -130,7 +130,8 @@ class TPUScheduleAlgorithm:
 
     def _dedup(self, pods: Sequence[Pod]):
         """Template-created pods (RC/RS/Job) are identical up to their
-        name: encode one representative per distinct feature key."""
+        name: encode one representative per distinct feature key.
+        -> (representatives, each pod's representative, their keys)"""
         import numpy as np
 
         from kubernetes_tpu.snapshot.encode import pod_feature_key
@@ -146,7 +147,7 @@ class TPUScheduleAlgorithm:
                 rep_of_key[k] = r
                 reps.append(p)
             rep_idx[i] = r
-        return reps, rep_idx
+        return reps, rep_idx, list(rep_of_key)
 
     def warmup(self, num_nodes: int, phase: str = "all",
                nodes: Optional[Sequence] = None) -> None:
@@ -438,7 +439,7 @@ class TPUScheduleAlgorithm:
         from kubernetes_tpu.snapshot.pad import next_pow2
 
         with trace_profile.phase_timer("encode"):
-            reps, rep_idx = self._dedup(pods)
+            reps, rep_idx, keys = self._dedup(pods)
             snap = batch = None
             keep = frozenset()
             source = "full"
@@ -451,6 +452,7 @@ class TPUScheduleAlgorithm:
                     services=ls(self._service_lister),
                     controllers=ls(self._controller_lister),
                     replica_sets=ls(self._replica_set_lister),
+                    keys=keys,
                 )
                 if snap is not None:
                     # identify the ENCODER INSTANCE, not just the kind: a
@@ -560,7 +562,7 @@ class TPUScheduleAlgorithm:
         from kubernetes_tpu.snapshot.pad import next_pow2
 
         with trace_profile.phase_timer("encode"):
-            reps, rep_idx = self._dedup(pods)
+            reps, rep_idx, keys = self._dedup(pods)
             snap = batch = None
             if self._inc is not None:
                 def ls(l):
@@ -571,6 +573,7 @@ class TPUScheduleAlgorithm:
                     services=ls(self._service_lister),
                     controllers=ls(self._controller_lister),
                     replica_sets=ls(self._replica_set_lister),
+                    keys=keys,
                 )
             if snap is None:
                 enc = SnapshotEncoder(

@@ -16,6 +16,7 @@ sidecar for Gt/Lt keys. Matching a requirement is then 2-4 bitwise ops per
 
 from __future__ import annotations
 
+import itertools
 import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -39,9 +40,6 @@ from kubernetes_tpu.api.resource import parse_quantity, resource_list_cpu_milli,
 from kubernetes_tpu.api.types import Taint
 from kubernetes_tpu.oracle.predicates import (
     _requirement_valid,
-    get_pod_controllers,
-    get_pod_replica_sets,
-    get_pod_services,
     is_pod_best_effort,
     label_selector_as_selector,
     taint_tolerated_by_tolerations,
@@ -199,6 +197,102 @@ def build_set_table(set_members, kv_ids, lw: int) -> np.ndarray:
     for idx, fs in enumerate(set_members):
         out[idx] = _pack_bits([kv_ids[kv] for kv in fs], lw)
     return out
+
+
+def _set_selector_key(ns: str, label_map) -> tuple:
+    """What a set-as-selector (a service's, a controller's) is built
+    from; the 0 tells it from a LabelSelector's key (1)."""
+    return (ns, 0, tuple(sorted(label_map.items())) if label_map else ())
+
+
+def _label_selector_key(ns: str, sel) -> tuple:
+    if sel is None:
+        return (ns, 1, None)
+    return (
+        ns, 1,
+        tuple(sorted(sel.match_labels.items())),
+        tuple((e.key, e.operator, tuple(e.values))
+              for e in sel.match_expressions),
+    )
+
+
+class SpreadSelectors:
+    """The spread listers' selectors (services, ReplicationControllers,
+    replica sets), each built once and held under a key of what it was
+    built from: the object's namespace and its selector's content.
+    Objects that agree in both share one entry, which changes no row:
+    SelectorSpreadPriority asks whether ANY selector matches.
+
+    `selecting(pod)` is get_pod_services + get_pod_controllers +
+    get_pod_replica_sets (oracle/predicates.py) without a Selector per
+    (pod, object) pair. `sync` takes the listers as they are now and
+    says which entries came and went, so a holder of derived rows
+    (snapshot/pending_rows.py) can repair exactly those."""
+
+    def __init__(self):
+        self.entries: Dict[tuple, labelpkg.Selector] = {}
+        self._by_ns: Dict[str, Dict[tuple, labelpkg.Selector]] = {}
+        self._stamp: Optional[tuple] = None
+
+    def sync(self, services=(), controllers=(), replica_sets=()
+             ) -> Tuple[List[tuple], List[tuple]]:
+        """-> (keys added, keys removed) since the last sync. The keys
+        are rebuilt from the listed objects' current fields on every
+        call, so an edit in place is seen like an add and a delete."""
+        listed = [
+            (_set_selector_key(o.metadata.namespace, o.spec.selector), o)
+            for o in itertools.chain(services, controllers)
+        ] + [
+            (_label_selector_key(o.metadata.namespace, o.spec.selector), o)
+            for o in replica_sets
+        ]
+        stamp = tuple(k for k, _o in listed)
+        if stamp == self._stamp:
+            return [], []
+        self._stamp = stamp
+        source = dict(listed)
+        removed = [k for k in self.entries if k not in source]
+        added = [k for k in source if k not in self.entries]
+        for k in removed:
+            del self.entries[k]
+            del self._by_ns[k[0]][k]
+        for k in added:
+            sel = source[k].spec.selector
+            built = (labelpkg.selector_from_set(sel) if k[1] == 0
+                     else label_selector_as_selector(sel))
+            self.entries[k] = built
+            self._by_ns.setdefault(k[0], {})[k] = built
+        return added, removed
+
+    def selecting(self, namespace: str, labels: Dict[str, str],
+                  among: Optional[Sequence[tuple]] = None) -> List[tuple]:
+        """Keys of the entries (all, or those of `among`) in `namespace`
+        whose selector matches `labels`."""
+        in_ns = self._by_ns.get(namespace)
+        if not in_ns:
+            return []
+        if among is None:
+            return [k for k, s in in_ns.items() if s.matches(labels)]
+        return [k for k in among
+                if k[0] == namespace and in_ns[k].matches(labels)]
+
+
+def spread_match_row(selectors: Sequence[labelpkg.Selector], namespace: str,
+                     class_list: Sequence[tuple], out: np.ndarray,
+                     start: int = 0) -> None:
+    """out[c] = 1 for each spread class c >= start (a
+    `VocabBundle.classes` key) of live pods in `namespace` whose labels
+    any of `selectors` matches: the pods SelectorSpreadPriority counts
+    for a pod these selectors select (selector_spreading.go:146)."""
+    for c in range(start, len(class_list)):
+        ns, labels_fs, deleted = class_list[c]
+        if deleted or ns != namespace:
+            continue
+        lbls = dict(labels_fs)
+        for s in selectors:
+            if s.matches(lbls):
+                out[c] = 1
+                break
 
 
 #: snapshot fields that seed the scheduler carry's stacked resource
@@ -437,6 +531,7 @@ class SnapshotEncoder:
         self._interpod = None
         self._volumes = None
         self._services = None
+        self._terms = None
         self._build_vocabs()
 
     @property
@@ -757,10 +852,51 @@ class SnapshotEncoder:
             )
         return idx
 
-    def encode_pods(self, max_terms=None, max_reqs=None) -> PodBatch:
-        w = self.widths
+    def batch_fields(self) -> dict:
+        """The PodBatch fields that belong to the batch as a whole: the
+        inter-pod, volume and service programs compiled over its pods,
+        and an image-count table as wide as its image vocabulary (filled
+        by encode_pods, or by whoever assembles the batch from rows)."""
         P = len(self.pods)
-        # one annotation parse per pod: failures become (None, True)
+        return dict(
+            ip_match_spec=self.interpod.match_spec,
+            ip_ha_lt=self.interpod.ha_lt,
+            ip_ha_self=self.interpod.ha_self,
+            ip_hq_lt=self.interpod.hq_lt,
+            ip_fwd_lt=self.interpod.fwd_lt,
+            ip_fwd_w=self.interpod.fwd_w,
+            ip_own_hard=self.interpod.own_hard,
+            ip_own_pref=self.interpod.own_pref,
+            ip_own_anti_hard=self.interpod.own_anti_hard,
+            ip_own_anti_pref=self.interpod.own_anti_pref,
+            ip_has_affinity=self.interpod.has_affinity,
+            ip_has_anti=self.interpod.has_anti,
+            ip_sym_reject=self.interpod.sym_reject,
+            ip_poison=np.full(P, self.interpod.poison, bool),
+            vp_vol_rw=self.volumes.p_vol_rw,
+            vp_vol_ro=self.volumes.p_vol_ro,
+            vp_ebs=self.volumes.p_ebs,
+            vp_gce=self.volumes.p_gce,
+            vp_ebs_bad=self.volumes.p_ebs_bad,
+            vp_gce_bad=self.volumes.p_gce_bad,
+            vp_has_ebs=self.volumes.p_has_ebs,
+            vp_has_gce=self.volumes.p_has_gce,
+            vp_vz_zone=self.volumes.p_vz_zone,
+            vp_vz_region=self.volumes.p_vz_region,
+            vp_vz_fail=self.volumes.p_vz_fail,
+            img_count=np.zeros((P, max(0, len(self.images))), np.int64),
+            svc_group=self.services_program.group,
+            svc_member=self.services_program.member,
+            svc_fixed=self.services_program.fixed,
+        )
+
+    def _pod_terms(self):
+        """-> (affs, parse_failed, req_terms, pref_terms), one entry a
+        pod: its parsed affinity (one annotation parse per pod; a
+        failure is (None, True)), its required node-affinity terms (None
+        where it states none) and its preferred ones."""
+        if self._terms is not None:
+            return self._terms
         affs = []
         parse_failed = []
         for p in self.pods:
@@ -770,17 +906,10 @@ class SnapshotEncoder:
             except Exception:
                 affs.append(None)
                 parse_failed.append(True)
-
-        def na(a):
-            return a.node_affinity if a is not None else None
-
-        R1 = max(
-            [1] + [len(p.spec.node_selector) for p in self.pods]
-        )
         req_terms = []
         pref_terms = []
         for a in affs:
-            n = na(a)
+            n = a.node_affinity if a is not None else None
             if n is not None and n.required_during_scheduling_ignored_during_execution is not None:
                 req_terms.append(
                     list(n.required_during_scheduling_ignored_during_execution.node_selector_terms)
@@ -792,22 +921,41 @@ class SnapshotEncoder:
                 if n is not None
                 else []
             )
-        T = max_terms or max([1] + [len(t) for t in req_terms if t is not None])
-        TP = max([1] + [len(t) for t in pref_terms])
-        R = max_reqs or max(
-            [1]
-            + [
-                len(term.match_expressions)
-                for terms in req_terms
-                if terms
-                for term in terms
-            ]
-            + [
-                len(wt.preference.match_expressions)
-                for terms in pref_terms
-                for wt in terms
-            ]
-        )
+        self._terms = (affs, parse_failed, req_terms, pref_terms)
+        return self._terms
+
+    def term_widths(self) -> np.ndarray:
+        """i64[P, 4]: what each pod alone asks of the batch's program
+        axes (R1, T, TP, R). A batch's axis is the largest any of its
+        pods asks, and at least 1; a row is zero beyond its own."""
+        _affs, _failed, req_terms, pref_terms = self._pod_terms()
+        out = np.zeros((len(self.pods), 4), np.int64)
+        for i, p in enumerate(self.pods):
+            out[i] = (
+                len(p.spec.node_selector),
+                len(req_terms[i] or ()),
+                len(pref_terms[i]),
+                max(
+                    [len(t.match_expressions) for t in req_terms[i] or ()]
+                    + [len(wt.preference.match_expressions)
+                       for wt in pref_terms[i]],
+                    default=0,
+                ),
+            )
+        return out
+
+    def encode_pods(self, max_terms=None, max_reqs=None) -> PodBatch:
+        w = self.widths
+        P = len(self.pods)
+        affs, parse_failed, req_terms, pref_terms = self._pod_terms()
+
+        def na(a):
+            return a.node_affinity if a is not None else None
+
+        own = self.term_widths().max(axis=0, initial=1)
+        R1, TP = int(own[0]), int(own[2])
+        T = max_terms or int(own[1])
+        R = max_reqs or int(own[3])
 
         b = PodBatch(
             pod_keys=[(p.namespace, p.name) for p in self.pods],
@@ -849,37 +997,13 @@ class SnapshotEncoder:
             spread_match=np.zeros((P, w["C"]), np.int64),
             class_id=np.zeros(P, np.int32),
             unschedulable=np.zeros(P, bool),
-            ip_match_spec=self.interpod.match_spec,
-            ip_ha_lt=self.interpod.ha_lt,
-            ip_ha_self=self.interpod.ha_self,
-            ip_hq_lt=self.interpod.hq_lt,
-            ip_fwd_lt=self.interpod.fwd_lt,
-            ip_fwd_w=self.interpod.fwd_w,
-            ip_own_hard=self.interpod.own_hard,
-            ip_own_pref=self.interpod.own_pref,
-            ip_own_anti_hard=self.interpod.own_anti_hard,
-            ip_own_anti_pref=self.interpod.own_anti_pref,
-            ip_has_affinity=self.interpod.has_affinity,
-            ip_has_anti=self.interpod.has_anti,
-            ip_sym_reject=self.interpod.sym_reject,
-            ip_poison=np.full(P, self.interpod.poison, bool),
-            vp_vol_rw=self.volumes.p_vol_rw,
-            vp_vol_ro=self.volumes.p_vol_ro,
-            vp_ebs=self.volumes.p_ebs,
-            vp_gce=self.volumes.p_gce,
-            vp_ebs_bad=self.volumes.p_ebs_bad,
-            vp_gce_bad=self.volumes.p_gce_bad,
-            vp_has_ebs=self.volumes.p_has_ebs,
-            vp_has_gce=self.volumes.p_has_gce,
-            vp_vz_zone=self.volumes.p_vz_zone,
-            vp_vz_region=self.volumes.p_vz_region,
-            vp_vz_fail=self.volumes.p_vz_fail,
-            img_count=np.zeros((P, max(0, len(self.images))), np.int64),
-            svc_group=self.services_program.group,
-            svc_member=self.services_program.member,
-            svc_fixed=self.services_program.fixed,
+            **self.batch_fields(),
         )
         class_list = list(self.classes.ids.keys())
+        # the listers' selectors, built once for the batch
+        spread = SpreadSelectors()
+        spread.sync(self.state.services, self.state.controllers,
+                    self.state.replica_sets)
         for i, pod in enumerate(self.pods):
             cpu, mem, gpu = pod_resource_request(pod)
             b.req_mcpu[i], b.req_mem[i], b.req_gpu[i] = cpu, mem, gpu
@@ -980,21 +1104,14 @@ class SnapshotEncoder:
             b.tol_mask[i] = _pack_bits(tolerated_ids, w["TW"])
             b.best_effort[i] = is_pod_best_effort(pod)
             # spread selectors
-            selectors = []
-            for svc in get_pod_services(self.state, pod):
-                selectors.append(labelpkg.selector_from_set(svc.spec.selector))
-            for rc in get_pod_controllers(self.state, pod):
-                selectors.append(labelpkg.selector_from_set(rc.spec.selector))
-            for rs in get_pod_replica_sets(self.state, pod):
-                selectors.append(label_selector_as_selector(rs.spec.selector))
+            selectors = [
+                spread.entries[k]
+                for k in spread.selecting(pod.namespace, pod.metadata.labels)
+            ]
             b.has_selectors[i] = bool(selectors)
             if selectors:
-                for c_idx, (ns, labels_fs, deleted) in enumerate(class_list):
-                    if deleted or ns != pod.namespace:
-                        continue
-                    lbls = dict(labels_fs)
-                    if any(s.matches(lbls) for s in selectors):
-                        b.spread_match[i, c_idx] = 1
+                spread_match_row(selectors, pod.namespace, class_list,
+                                 b.spread_match[i])
             b.class_id[i] = self.classes.get(self._class_key(pod))
             for c in pod.spec.containers:
                 iid = self.images.get(c.image, add=False)

@@ -14,9 +14,21 @@ module restores the reference's cost model at the array level:
     contribution (oracle/state.pod_contribution), and the aggregates
     take one scatter-add per batch, not eight scalar writes per pod.
   * Vocabularies live in a persistent `VocabBundle`, append-only, so ids
-    agree across waves; per-wave pending pods are encoded by a plain
+    agree across waves; a wave's pending pods are interned by a plain
     SnapshotEncoder sharing the bundle with `visit_state=False`
     (O(backlog), not O(cluster)).
+  * A template's pending-side PodBatch row is encoded once and kept
+    (`self.rows`, snapshot/pending_rows.py): a wave's batch is gathered
+    from the rows stored under its pods' `pod_feature_key`s, and only a
+    key the store does not hold goes through `encode_pods`. A row is
+    reused for as long as what it was derived from stands, which is
+    looked at on every wave and never configured: the spread listers
+    (an entry added, deleted or re-selected repairs the rows it selects
+    or selected, and no other), the spread-class vocabulary (a row is
+    extended by the classes new to it), the taint vocabulary and the
+    scheduler config (either rebuilds the store), the port vocabulary's
+    width (zero-padding), `slot_of` for a pod that names its node, and
+    the wave's own image vocabulary (both read afresh every wave).
   * Bitset widths / class columns grow by column-padding when a vocab
     crosses a word boundary (O(N) once, amortized nil).
   * Node slots are stable: removed nodes free their slot (zeroed
@@ -66,8 +78,10 @@ from kubernetes_tpu.snapshot.encode import (
     _pack_bits,
     _words,
     build_set_table,
+    pod_feature_key,
     service_config_labels,
 )
+from kubernetes_tpu.snapshot.pending_rows import PendingRows
 from kubernetes_tpu.api.resource import (
     parse_quantity,
     resource_list_cpu_milli,
@@ -98,6 +112,8 @@ class IncrementalEncoder:
         # (a monotonic counter — id() reuses freed addresses)
         self.source_token = f"inc:{next(_SOURCE_COUNTER)}"
         self.vocabs = VocabBundle()
+        # encoded pending-pod rows by template (snapshot/pending_rows.py)
+        self.rows = PendingRows(self.vocabs)
         self._lock = threading.Lock()
         self._events: List[Tuple[str, object]] = []
         # slot map
@@ -636,19 +652,33 @@ class IncrementalEncoder:
         services=(),
         controllers=(),
         replica_sets=(),
+        keys: Optional[Sequence[tuple]] = None,
     ) -> Tuple[Optional[ClusterSnapshot], Optional[PodBatch], frozenset]:
         """Apply queued deltas and emit (snapshot, batch, keep) for this
         wave — `keep` names snapshot fields whose device copies from the
         previous wave are still valid — or (None, None, ø) when a scope
-        gate forces the full encoder."""
+        gate forces the full encoder.
+
+        `keys` are the pending pods' `pod_feature_key`s where the caller
+        has them (its dedup computed them). The batch is field for field
+        what `encode_pods` over `pending` and the three listers gives,
+        but a row whose key the store holds is gathered, not encoded:
+        it is reused while the listers' entries that select it, the
+        taints seen and the config are the ones it was made under, and
+        is repaired (its spread columns), extended (classes first seen
+        since) or rebuilt (a taint first seen) in this call otherwise —
+        see snapshot/pending_rows.py for each dependency."""
         self.apply_pending()
         if self._affinity_pods > 0 or not self._config_ok():
             return None, None, frozenset()
         for p in pending:
             if p.spec.volumes or has_pod_affinity(p):
                 return None, None, frozenset()
-        # encode pending pods against the shared vocabs; the light state
-        # carries only the spread listers (no node scan)
+        # the wave's encoder interns the pending pods' vocabulary into
+        # the shared bundle and holds what is the wave's own (its image
+        # vocabulary, the empty per-batch programs); the light state
+        # carries only the spread listers (no node scan). The rows come
+        # from the store: only a key it does not hold is encoded.
         light = ClusterState(
             services=list(services),
             controllers=list(controllers),
@@ -658,7 +688,12 @@ class IncrementalEncoder:
             light, list(pending), config=self.config, vocabs=self.vocabs,
             visit_state=False, node_id=dict(self.slot_of),
         )
-        batch = enc.encode_pods()
+        if keys is None:
+            keys = [pod_feature_key(p) for p in pending]
+        batch = self.rows.batch(
+            enc, keys, light.services, light.controllers,
+            light.replica_sets,
+        )
         self._widths_sync()
         keep = set(self.WAVE_CONST_FIELDS)
         if not self._dirty_node_side:

@@ -47,6 +47,8 @@ def render_traces(query: Dict[str, str]) -> dict:
         "compiles": _profile.recent_compiles(),
         # whether cache deltas reach the snapshot a batch at a time
         "encoder": _profile.encoder_totals(),
+        # whether a wave's pending-pod rows are reused or encoded anew
+        "pending_rows": _profile.pending_row_totals(),
         # which path decided the pods, and what was launched for them
         "wave": _profile.wave_totals(),
     }

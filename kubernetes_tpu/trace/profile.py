@@ -271,6 +271,25 @@ def encoder_totals() -> Dict[str, int]:
         return dict(_ENCODER)
 
 
+#: lookups of a pending pod's encoded row by template in the incremental
+#: encoder's store (snapshot/pending_rows.py): rows gathered, rows
+#: encoded, whole-store rebuilds; served on /debug/traces as
+#: "pending_rows"
+_PENDING_ROWS = {"row_hits": 0, "row_misses": 0, "row_resets": 0}
+
+
+def count_pending_rows(hits: int, misses: int, resets: int) -> None:
+    with _encoder_lock:
+        _PENDING_ROWS["row_hits"] += hits
+        _PENDING_ROWS["row_misses"] += misses
+        _PENDING_ROWS["row_resets"] += resets
+
+
+def pending_row_totals() -> Dict[str, int]:
+    with _encoder_lock:
+        return dict(_PENDING_ROWS)
+
+
 # -- the single-chip wave driver's totals -----------------------------------------
 
 _wave_lock = threading.Lock()

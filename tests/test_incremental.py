@@ -522,3 +522,65 @@ def test_batched_deltas_equal_one_at_a_time(seed):
         assert np.array_equal(batched.req_mcpu, want), ctx
         assert set(batched._contribs) == {("default", n) for n in pods}, ctx
     assert views >= 2, "the affinity gate hid every snapshot of this seed"
+
+
+def test_kept_rows_across_waves_match_oracle_at_the_zoned_shape():
+    """Three waves through the daemon path at the zoned deployment's
+    shape (300 nodes in 3 zones x 50 controllers, dealt in turn), a
+    controller added before the second and one deleted before the third:
+    the rows kept from wave to wave (snapshot/pending_rows.py) follow
+    the listers, and every pick is the serial generic scheduler's."""
+    cache = SchedulerCache(clock=FakeClock(0.0))
+    for i in range(300):
+        name = f"znode-{i:05d}"
+        cache.add_node(Node(
+            metadata=ObjectMeta(name=name, labels={
+                "kubernetes.io/hostname": name, ZONE: "abc"[i % 3]}),
+            status=NodeStatus(
+                allocatable={"cpu": "4", "memory": "32Gi", "pods": "110"},
+                conditions=[NodeCondition("Ready", "True")])))
+
+    def controller(name, selector):
+        from kubernetes_tpu.api.types import (
+            ReplicationController, ReplicationControllerSpec)
+
+        return ReplicationController(
+            metadata=ObjectMeta(name=name),
+            spec=ReplicationControllerSpec(selector=selector))
+
+    rc_lister = _Lister()
+    rc_lister.items = [controller(f"rc-{t}", {"rc": f"rc-{t}"})
+                       for t in range(50)]
+    algo = TPUScheduleAlgorithm(
+        min_run=1, cache=cache, service_lister=_Lister(),
+        controller_lister=rc_lister, replica_set_lister=_Lister())
+    oracle = GenericScheduler(
+        predicates=ORACLE_PREDICATES, priorities=ORACLE_PRIORITIES)
+    serial = 0
+    for wave in range(3):
+        if wave == 1:
+            # selects every fifth controller's pods as one more group
+            rc_lister.items.append(controller("tier", {"tier": "t0"}))
+        elif wave == 2:
+            del rc_lister.items[7]
+        pending = []
+        for _replica in range(2):
+            for t in range(50):
+                serial += 1
+                pending.append(Pod(
+                    metadata=ObjectMeta(
+                        name=f"p-t{t}-{serial:06d}",
+                        labels={"rc": f"rc-{t}", "tier": f"t{t % 5}"}),
+                    spec=PodSpec(containers=[Container(requests={
+                        "cpu": "100m", "memory": "500Mi"})])))
+        state = restricted_state(cache, controllers=rc_lister.items)
+        want = oracle.schedule_backlog(pending, state.clone())
+        got = algo.schedule_backlog(pending, state)
+        assert got == want, f"wave {wave}: first off at " + str(next(
+            i for i, (a, b) in enumerate(zip(got, want)) if a != b))
+        assert None not in got
+        for p, host in zip(pending, want):
+            p.spec.node_name = host
+            cache.add_pod(p)
+    rows = algo._inc.rows
+    assert (rows.hits, rows.misses, rows.resets) == (100, 50, 0)
