@@ -433,8 +433,6 @@ BENCH_FILE = "BENCH_r10.json"
 BENCH_FILE_R11 = "BENCH_r11.json"
 #: round-12 record: the telemetry-pipeline overhead A/B
 BENCH_FILE_R12 = "BENCH_r12.json"
-#: round-13 record: the kernel-path raw curve (--raw-curve)
-BENCH_FILE_R13 = "BENCH_r13.json"
 
 
 def _bench_merge(update: dict, path: str = None) -> None:
@@ -672,377 +670,6 @@ def run_proc_curve(seconds: int, procs_list, rates, num_nodes: int,
         k: v["sustained_ceiling_pods_per_sec"]
         for k, v in curve.items()
     }}))
-
-
-def build_multi(num_nodes, num_pods, templates=8, block=512):
-    """Multi-template backlog for the kernel-path raw curve: pods come
-    in `block`-sized runs cycling `templates` distinct groups, each
-    group carrying a PREFERRED anti-affinity term against the NEXT
-    group's labels. A soft non-self term never blocks placement but
-    makes the run impure (its commits grow other pods' term counts),
-    so every run takes the per-run probe path instead of grouping —
-    the shape the double-buffered pipeline stages across. The
-    single-template headline build() never exercises staging: one run
-    per wave has no successor to stage."""
-    from kubernetes_tpu.api.types import (
-        Container,
-        Node,
-        NodeCondition,
-        NodeStatus,
-        ObjectMeta,
-        Pod,
-        PodSpec,
-        Service,
-        ServiceSpec,
-    )
-    from kubernetes_tpu.oracle import ClusterState
-
-    nodes = [
-        Node(
-            metadata=ObjectMeta(
-                name=f"node-{i:05d}",
-                labels={"kubernetes.io/hostname": f"node-{i:05d}"},
-            ),
-            status=NodeStatus(
-                allocatable={"cpu": "4", "memory": "32Gi", "pods": "110"},
-                conditions=[NodeCondition("Ready", "True")],
-            ),
-        )
-        for i in range(num_nodes)
-    ]
-
-    def pod(i):
-        t = (i // block) % templates
-        p = Pod(
-            metadata=ObjectMeta(
-                name=f"pod-{i:06d}",
-                labels={"group": f"g{t:02d}"},
-            ),
-            spec=PodSpec(containers=[Container(
-                requests={"cpu": "100m", "memory": "500Mi"})]),
-        )
-        p.metadata.annotations = {
-            "scheduler.alpha.kubernetes.io/affinity": json.dumps({
-                "podAntiAffinity": {
-                    "preferredDuringSchedulingIgnoredDuringExecution": [{
-                        "weight": 1,
-                        "podAffinityTerm": {
-                            "labelSelector": {"matchLabels": {
-                                "group":
-                                    f"g{(t + 1) % templates:02d}"}},
-                            "topologyKey": "kubernetes.io/hostname",
-                            "namespaces": [],
-                        },
-                    }],
-                },
-            })
-        }
-        return p
-
-    pods = [pod(i) for i in range(num_pods)]
-    services = [
-        Service(
-            metadata=ObjectMeta(name=f"svc-{t:02d}"),
-            spec=ServiceSpec(selector={"group": f"g{t:02d}"}),
-        )
-        for t in range(templates)
-    ]
-    state = ClusterState.build(nodes, services=services)
-    return state, pods
-
-
-def _run_env(env, fn):
-    """fn() with env vars overridden (None = unset), restored after.
-    The kernel/quant/pipeline gates read their env at scheduler
-    construction, so each A/B arm builds its algorithm inside this."""
-    saved = {k: os.environ.get(k) for k in env}
-    try:
-        for k, v in env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        return fn()
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-
-
-def _measure_kernel_variant(state, pods, env, reps=3, kernel=None):
-    """One raw-curve arm: fresh algorithm under `env`, one cold run
-    (compiles + table placement) and `reps` warm reps with per-rep
-    wall/h2d plus the trace accountant's phase deltas over the warm
-    window. `kernel` swaps in a probe built with that kernel through
-    the WaveProbe(kernel=...) seam (the only way to ask for the
-    INTERPRETED Pallas kernel). -> (cold decisions, record)."""
-    from kubernetes_tpu.metrics.metrics import (
-        scheduler_xla_compile_seconds,
-    )
-    from kubernetes_tpu.models.pack import Packer
-    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
-    from kubernetes_tpu.trace import profile as trace_profile
-
-    def run():
-        trace_profile.install_compile_listener()
-        algo = TPUScheduleAlgorithm()
-        if kernel is not None:
-            from kubernetes_tpu.models.probe import WaveProbe
-
-            algo._wave.probe = WaveProbe(
-                algo._wave.config, kernel=kernel,
-                score_mode=algo._wave.probe.score_mode)
-        n_pods = len(pods)
-        b0 = Packer.total_h2d_bytes
-        t0 = time.time()
-        cold = algo.schedule_backlog(pods, state)
-        cold_s = time.time() - t0
-        cold_h2d = Packer.total_h2d_bytes - b0
-        n_sched = sum(1 for h in cold if h is not None)
-        assert n_sched == n_pods, f"only {n_sched}/{n_pods} scheduled"
-        wave = getattr(algo, "_wave", None)
-        cold_table_bytes = (wave.stats["table_bytes_total"]
-                            if wave is not None else 0)
-        pt0 = trace_profile.phase_totals()
-        et0 = trace_profile.exclusive_totals()
-        stats0 = dict(wave.stats) if wave is not None else {}
-        steady_compiles = None
-        times, h2d = [], []
-        for r in range(reps):
-            algo._last_node_index = 0
-            b1 = Packer.total_h2d_bytes
-            t1 = time.time()
-            warm = algo.schedule_backlog(pods, state)
-            times.append(time.time() - t1)
-            h2d.append(Packer.total_h2d_bytes - b1)
-            assert warm == cold, "warm rerun diverged"
-            if r == 0:
-                # steady state starts after the first warm rep (a cold
-                # run can end mid-fold, so rep 1 may still hit one
-                # fresh shape; reps 2+ must hit only cached programs)
-                steady_compiles = scheduler_xla_compile_seconds.count
-        pt1 = trace_profile.phase_totals()
-        et1 = trace_profile.exclusive_totals()
-        stats1 = dict(wave.stats) if wave is not None else {}
-        warm_waves = stats1.get("waves", 0) - stats0.get("waves", 0)
-        warm_reused = (stats1.get("table_bytes_reused", 0)
-                       - stats0.get("table_bytes_reused", 0))
-        phases = {p: round(pt1[p] - pt0[p], 4)
-                  for p in trace_profile.PHASES}
-        exclusive = {p: round(et1[p] - et0[p], 4)
-                     for p in trace_profile.PHASES}
-        overlap = {p: round(max(0.0, phases[p] - exclusive[p]), 4)
-                   for p in trace_profile.PHASES}
-        rec = {
-            "env": {k: v for k, v in env.items() if v is not None},
-            **({"kernel": kernel} if kernel else {}),
-            "cold_wall_s": round(cold_s, 3),
-            "cold_h2d_bytes": int(cold_h2d),
-            # every node-table byte the cold run placed/shipped — the
-            # quantization win is this number's wide/quant ratio
-            "cold_table_bytes": int(cold_table_bytes),
-            "warm_wall_s": [round(t, 4) for t in times],
-            "warm_h2d_bytes_per_rep": [int(b) for b in h2d],
-            "pods_per_sec_best": round(n_pods / min(times), 1),
-            "pods_per_sec_median": round(
-                n_pods / statistics.median(times), 1),
-            "steady_recompiles":
-                scheduler_xla_compile_seconds.count - steady_compiles,
-            # steady-state bytes/wave: what the warm window actually
-            # shipped (pod buffers + scatters; table ships ride the
-            # same Packer counter) vs what the pre-resident driver
-            # would have shipped (+ every reused table, every wave)
-            "steady_h2d_bytes_per_wave": (
-                round(sum(h2d) / warm_waves, 1) if warm_waves else None),
-            "steady_h2d_bytes_per_wave_preresident": (
-                round((sum(h2d) + warm_reused) / warm_waves, 1)
-                if warm_waves else None),
-            "dispatches_last_wave":
-                dict(wave.dispatches) if wave is not None else {},
-            "table_stats": dict(wave.stats) if wave is not None else {},
-            "phase_seconds_warm": phases,
-            "phase_exclusive_seconds_warm": exclusive,
-            # occurrence-minus-exclusive: staging seconds hidden under
-            # an in-flight probe window (pipelined arms only)
-            "phase_overlap_seconds_warm": overlap,
-        }
-        return cold, rec
-
-    return _run_env(env, run)
-
-
-#: the on-hardware re-measure invocation for the quant + pipeline
-#: arms. The Pallas arm is not in it: the v5e compiler refuses the
-#: kernel's compiled lowering ("64-bit types are not supported",
-#: tests/test_chip_compile.py), so on a TPU that arm fails by name
-TPU_REMEASURE_CMD = (
-    "KUBERNETES_TPU_QUANT=int KUBERNETES_TPU_PIPELINE=1 "
-    "python bench.py --raw-curve"
-)
-
-
-def run_raw_curve(num_nodes=1000, num_pods=12288, templates=8, reps=3,
-                  pallas_nodes=64, pallas_pods=256):
-    """Round-19 kernel-path A/B over one multi-template selector
-    backlog: wide-vs-quantized resident node tables, serial-vs-
-    pipelined wave loop, and (small config) lax-vs-Pallas probe
-    kernel. Decisions must be bit-identical across every arm. Gates:
-    quantization shrinks cold table bytes >= 2x, the pipelined arm's
-    accounted wall fits inside max-phase + 15%, and the pipelined warm
-    reps recompile nothing. Record lands in BENCH_r13.json; exits
-    non-zero on a breach. On a CPU backend the Pallas arm asks for
-    interpret mode BY NAME (correctness, not speed); on a TPU it asks
-    for the compiled kernel, which the compiler refuses today."""
-    _assert_sanitizers_off()
-    from kubernetes_tpu.native.build import ensure_all
-
-    ensure_all()
-    import jax
-
-    state, pods = build_multi(num_nodes, num_pods, templates=templates)
-    arms = [
-        ("wide_serial", {"KUBERNETES_TPU_QUANT": "off",
-                         "KUBERNETES_TPU_PIPELINE": None,
-                         "KUBERNETES_TPU_KERNEL": None}),
-        ("quant_serial", {"KUBERNETES_TPU_QUANT": "int",
-                          "KUBERNETES_TPU_PIPELINE": None,
-                          "KUBERNETES_TPU_KERNEL": None}),
-        ("wide_pipeline", {"KUBERNETES_TPU_QUANT": "off",
-                           "KUBERNETES_TPU_PIPELINE": "1",
-                           "KUBERNETES_TPU_KERNEL": None}),
-        ("quant_pipeline", {"KUBERNETES_TPU_QUANT": "int",
-                            "KUBERNETES_TPU_PIPELINE": "1",
-                            "KUBERNETES_TPU_KERNEL": None}),
-    ]
-    variants = {}
-    base_dec = None
-    for name, env in arms:
-        print(f"# raw-curve arm: {name}", file=sys.stderr)
-        dec, rec = _measure_kernel_variant(state, pods, env, reps=reps)
-        if base_dec is None:
-            base_dec = dec
-        else:
-            assert dec == base_dec, f"{name} decisions diverged"
-        rec["decisions_match_wide_serial"] = dec == base_dec
-        variants[name] = rec
-        print(f"#   {rec['pods_per_sec_best']:.0f} best pods/s, cold "
-              f"table bytes {rec['cold_table_bytes']}, steady "
-              f"recompiles {rec['steady_recompiles']}", file=sys.stderr)
-
-    # quantization's cold-placement shrink (informational: only the
-    # four NARROWABLE vocab/count tables narrow)
-    wide_b = variants["wide_serial"]["cold_table_bytes"]
-    quant_b = variants["quant_serial"]["cold_table_bytes"]
-    quant_reduction = (wide_b / quant_b) if quant_b else float("inf")
-    # the headline byte gate: steady-state h2d+table bytes/wave with
-    # the full stack vs the pre-resident driver (which re-shipped
-    # every table every wave — the seed's single-chip behavior)
-    full = variants["quant_pipeline"]
-    now_b = full["steady_h2d_bytes_per_wave"]
-    before_b = full["steady_h2d_bytes_per_wave_preresident"]
-    steady_reduction = (before_b / now_b) if now_b else float("inf")
-
-    pl = variants["quant_pipeline"]
-    # the accountant's bound over the pipelined probe windows: window
-    # wall (probe occurrence) vs its two legs — device-side exclusive
-    # time and the staging seconds hidden inside (probe overlap).
-    # With a real device the legs run concurrently and the window
-    # collapses to max(leg) + 15%; on a CPU-only box the legs
-    # SERIALIZE on the same cores, so that bound is a hardware
-    # property — there the gate checks the box-realizable half:
-    # staging IS attributed as overlap and pipelining does not
-    # regress wall vs the serial arm
-    probe_occ = pl["phase_seconds_warm"]["probe"]
-    probe_excl = pl["phase_exclusive_seconds_warm"]["probe"]
-    hidden = pl["phase_overlap_seconds_warm"]["probe"]
-    # best-of-reps on both sides: a single jittery rep (GC pause, OS
-    # scheduling) must not flip a wall comparison on a shared CPU box
-    pipe_wall = min(pl["warm_wall_s"])
-    serial_wall = min(variants["quant_serial"]["warm_wall_s"])
-    window_bound_ok = probe_occ <= max(probe_excl, hidden) * 1.15
-    on_tpu = jax.default_backend() == "tpu"
-    pipeline_rec = {
-        "warm_wall_s": round(pipe_wall, 4),
-        "serial_warm_wall_s": round(serial_wall, 4),
-        "probe_window_s": round(probe_occ, 4),
-        "probe_device_exclusive_s": round(probe_excl, 4),
-        "probe_hidden_overlap_s": round(hidden, 4),
-        "staging_overlapped": hidden > 0,
-        # the on-hardware form of "pipelined wall <= max-phase + 15%":
-        # gated on TPU, recorded (with its inputs) for the re-measure
-        # elsewhere
-        "probe_window_within_max_leg_15pct": window_bound_ok,
-        "wall_within_serial_15pct": pipe_wall <= serial_wall * 1.15,
-        "steady_recompiles": pl["steady_recompiles"],
-    }
-
-    pallas_kernel = "pallas" if on_tpu else "pallas-interpret"
-    print("# raw-curve: lax-vs-pallas probe kernel (small config; "
-          f"kernel={pallas_kernel})", file=sys.stderr)
-    s2, p2 = build_multi(pallas_nodes, pallas_pods, templates=4,
-                         block=64)
-    lax_dec, lax_rec = _measure_kernel_variant(
-        s2, p2, {"KUBERNETES_TPU_QUANT": "off",
-                 "KUBERNETES_TPU_PIPELINE": None,
-                 "KUBERNETES_TPU_KERNEL": "lax"}, reps=1)
-    pal_dec, pal_rec = _measure_kernel_variant(
-        s2, p2, {"KUBERNETES_TPU_QUANT": "off",
-                 "KUBERNETES_TPU_PIPELINE": None,
-                 "KUBERNETES_TPU_KERNEL": None}, reps=1,
-        kernel=pallas_kernel)
-    assert pal_dec == lax_dec, "pallas decisions diverged from lax"
-
-    gates = {
-        "decisions_bit_identical": all(
-            v["decisions_match_wide_serial"] for v in variants.values()),
-        "steady_bytes_per_wave_reduction_ge_2x": steady_reduction >= 2.0,
-        "pipelined_staging_overlapped":
-            pipeline_rec["staging_overlapped"],
-        "pipelined_wall_within_bound": (
-            pipeline_rec["probe_window_within_max_leg_15pct"] if on_tpu
-            else pipeline_rec["wall_within_serial_15pct"]),
-        "pipelined_zero_steady_recompiles":
-            pipeline_rec["steady_recompiles"] == 0,
-        "pallas_decisions_identical": pal_dec == lax_dec,
-    }
-    record = {
-        "config": {"num_nodes": num_nodes, "num_pods": num_pods,
-                   "templates": templates, "reps": reps,
-                   "backend": jax.default_backend()},
-        "variants": variants,
-        "cold_table_bytes_quant_reduction_x": round(quant_reduction, 2),
-        "steady_bytes_per_wave_reduction_x": round(steady_reduction, 2),
-        "pipeline": pipeline_rec,
-        "pallas_ab": {
-            "num_nodes": pallas_nodes, "num_pods": pallas_pods,
-            "lax": lax_rec, "pallas": pal_rec,
-            "decisions_identical": pal_dec == lax_dec,
-            "note": ("interpret-mode Pallas measures correctness, "
-                     "not speed"),
-        },
-        "gates": gates,
-        "tpu_remeasure": TPU_REMEASURE_CMD,
-    }
-    _bench_merge({"raw_curve": record}, path=BENCH_FILE_R13)
-    print(_dumps({
-        "metric": "raw_curve",
-        "backend": jax.default_backend(),
-        "steady_bytes_per_wave_reduction_x": round(steady_reduction, 2),
-        "cold_table_bytes_quant_reduction_x": round(quant_reduction, 2),
-        "probe_hidden_overlap_s":
-            pipeline_rec["probe_hidden_overlap_s"],
-        "best_pods_per_sec": {
-            k: v["pods_per_sec_best"] for k, v in variants.items()},
-        "gates": gates,
-    }))
-    if not all(gates.values()):
-        breached = [k for k, v in gates.items() if not v]
-        print(f"# RAW-CURVE GATE BREACH: {', '.join(breached)}",
-              file=sys.stderr)
-        sys.exit(1)
-    return record
 
 
 def main():
@@ -1879,22 +1506,6 @@ def _cli():
              "record is only valid as a deliberate control arm.",
     )
     ap.add_argument(
-        "--raw-curve", action="store_true",
-        help="run the round-19 kernel-path A/B instead of the "
-             "headline: wide-vs-quantized resident node tables, "
-             "serial-vs-pipelined wave loop, and lax-vs-Pallas probe "
-             "kernel (small config; interpret mode off-TPU) over one "
-             "multi-template selector backlog. Decisions must stay "
-             "bit-identical across every arm; byte/overlap accounting "
-             "lands in BENCH_r13.json; exits non-zero on a gate "
-             "breach.",
-    )
-    ap.add_argument(
-        "--raw-curve-pods", type=int, default=12288, metavar="P",
-        help="backlog size for --raw-curve (default 12288: 512-pod "
-             "blocks cycling 8 selector templates)",
-    )
-    ap.add_argument(
         "--telemetry-ab", type=int, default=0, metavar="SECONDS",
         help="measure the telemetry pipeline's overhead: the same "
              "smoke soak with the collector on and off, gated on the "
@@ -1906,9 +1517,6 @@ def _cli():
         os.environ["KUBERNETES_TPU_TELEMETRY"] = "0"
     if args.telemetry_ab:
         run_telemetry_ab(args.telemetry_ab)
-        return
-    if args.raw_curve:
-        run_raw_curve(num_pods=args.raw_curve_pods)
         return
     if args.wire_soak and not args.no_telemetry:
         from kubernetes_tpu import telemetry as _telemetry

@@ -45,8 +45,8 @@ enforcing:
   wrapper actually carries must match leaf-for-leaf.  A program whose
   carry drifts to a different PartitionSpec than the resident
   placement would silently reshard O(nodes) buffers on EVERY dispatch.
-* ``dtype-contract`` — the quantized-placement width contract
-  (parallel/quant): programs registered with ``narrow_dtypes`` must
+* ``dtype-contract`` — the narrow-placement width contract
+  (ops/narrow): programs registered with ``narrow_dtypes`` must
   receive each declared table AT its narrow dtype and must never widen
   a node-axis narrow integer to int32/int64 in-program (gather/scatter
   index feeds exempt) — a silent upcast reads the full-width bytes the
@@ -579,7 +579,7 @@ def _dtype_findings(spec: ProgramSpec, jaxpr) -> List[Finding]:
                             f"a node-axis array (shape {shape}) inside "
                             "a quantized program — a declared-narrow "
                             "table is being upcast in-program; consume "
-                            "it via quant.narrow_eq/narrow_matvec "
+                            "it via ops/narrow's narrow_eq/narrow_matvec "
                             "instead",
                         ))
             for sub in _subjaxprs(eqn):

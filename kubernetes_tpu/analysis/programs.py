@@ -64,7 +64,7 @@ class ProgramSpec:
     scatter_allowed: Optional[Tuple[Tuple[str, Tuple[int, ...]], ...]] = None
     #: DTYPE contract (dtype-contract audit): static table name ->
     #: numpy dtype the program's matching input leaf must carry, for
-    #: tables the quantized placement (parallel/quant) declares narrow.
+    #: tables the narrow placement (ops/narrow) declares narrow.
     #: The auditor additionally rejects any widening
     #: convert_element_type from a narrow int to int32/int64 on a
     #: node-axis array inside the program (except pure gather/scatter
@@ -220,37 +220,18 @@ def build_programs(include_mesh: bool = True, num_nodes: int = 13,
         ),
     ]
 
-    # the Pallas probe build (KUBERNETES_TPU_KERNEL=pallas): same
-    # transfer contract as the lax build — ONE packed host-bound array
-    # — with the fused fit+score+top-of-table reduction as a pallas_call
-    # (ops/pallas_probe). The auditor recurses into the kernel jaxpr
-    # via the pallas_call params, so the callback/f64/denylist rules
-    # cover the kernel body too. Interpret mode by name: the audit
-    # reads the kernel's jaxpr, and the compiled lowering is refused
-    # by the TPU compiler (64-bit types; tests/test_chip_compile.py).
-    probe_pallas = WaveProbe(config, kernel="pallas-interpret")
-    specs.append(ProgramSpec(
-        name="probe_pallas",
-        fn=probe_pallas._compiled(num_zones, num_values, J),
-        args=(static, carry, pod),
-        carry_out_leaves=0,
-        expected_host_leaves=1,
-        notes="fused Pallas probe kernel (ops/pallas_probe): "
-              "bit-identical to the lax build by test contract",
-    ))
-
-    # quantized placements (parallel/quant): the probe traced against
+    # narrow placements (ops/narrow): the probe traced against
     # narrowed static node tables at BOTH narrow widths, with the dtype
     # contract asserting the tables arrive narrow and are never widened
     # in-program (the placement bandwidth win is real, not cosmetic)
-    from kubernetes_tpu.parallel import quant as _quant
+    from kubernetes_tpu.ops import narrow as _narrow
 
     for qdt in (np.int8, np.int16):
         qstatic = dict(static)
         decl = []
-        for f in _quant.NARROWABLE:
+        for f in _narrow.NARROWABLE:
             host_f = np.asarray(getattr(snap, f))
-            nat = _quant.narrow_dtype(f, host_f)
+            nat = _narrow.narrow_dtype(f, host_f)
             dt = np.dtype(qdt) if np.dtype(qdt).itemsize >= nat.itemsize \
                 else nat
             qstatic[f] = jnp.asarray(host_f.astype(dt))

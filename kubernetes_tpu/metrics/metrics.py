@@ -438,18 +438,6 @@ scheduler_slo_breach_total = registry.register(
     )
 )
 
-#: bf16 quantized-profile shadow-compare divergences (parallel/quant
-#: ShadowGate): a sampled wave whose full-width re-run picked different
-#: nodes. Any increment also trips the session's permanent fallback to
-#: the full-width path, so a nonzero rate here means the bf16 profile
-#: is unsound for this workload's score magnitudes.
-scheduler_quant_shadow_divergence_total = registry.register(
-    Counter(
-        "scheduler_quant_shadow_divergence_total",
-        "Quantized-profile shadow-compare decision divergences",
-    )
-)
-
 # -- AI-cluster workload subsystem (gangs / preemption / quota) ---------------
 
 #: gangs fully bound (all-or-nothing success), per wave driver

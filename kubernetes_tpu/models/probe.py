@@ -112,20 +112,11 @@ class RunTables:
 
 
 def _probe_rows(config: SchedulerConfig, num_zones: int, num_values: int,
-                J: int, static, carry, pod, *, kernel: str = "lax",
-                score_mode: str = "i64"):
+                J: int, static, carry, pod):
     """The probe body: -> (stk i64[N_STK_ROWS, N] header rows,
     tab i64[J, N] weighted LR+BA j-table). Callers that consume only
     `stk` (the grouped header probe, the device replay) leave `tab`
-    dead and XLA eliminates it — which is why they must stay on
-    kernel="lax": a pallas_call is opaque to DCE.
-
-    kernel="pallas" routes the resource section (fit frontier + LR/BA
-    j-table) through the hand-written Pallas kernel's compiled
-    lowering (ops/pallas_probe); "pallas-interpret" through the same
-    kernel interpreted (bit-identical by construction). score_mode=
-    "bf16" accumulates the j-table in bfloat16 with an i32 final
-    reduce (the declared quantization profile, parallel/quant)."""
+    dead and XLA eliminates it."""
     (
         res,
         port_mask,
@@ -163,34 +154,7 @@ def _probe_rows(config: SchedulerConfig, num_zones: int, num_values: int,
     )
 
     j = jnp.arange(J, dtype=jnp.int64)[:, None]  # (J, 1)
-    bf16 = score_mode == "bf16"
-    use_pallas = kernel in ("pallas", "pallas-interpret") and J > 1
-    frontier = None
-    if use_pallas:
-        from kubernetes_tpu.ops import pallas_probe as PLP
-
-        terms = tuple(
-            ("lr" if n == LEAST_REQUESTED else "ba", int(w))
-            for n, w in config.priorities
-            if n in (LEAST_REQUESTED, BALANCED_ALLOCATION)
-        )
-        frontier, tab = PLP.resource_probe(
-            J,
-            (static["alloc_mcpu"], static["alloc_mem"],
-             static["alloc_gpu"], static["alloc_pods"]),
-            res, pod, terms,
-            wants_res=wants_resources(config), bf16=bf16,
-            interpret=kernel == "pallas-interpret",
-        )
-        if wants_ports(config):
-            # host-port self-conflict (predicates.go:574) applied to
-            # the frontier directly: res_fit is monotone in j, so
-            # killing every j>0 row caps the frontier at 1
-            has_ports = (pod["port_mask"] != 0).any()
-            frontier = jnp.where(
-                has_ports, jnp.minimum(frontier, jnp.int64(1)), frontier
-            )
-    elif wants_resources(config):
+    if wants_resources(config):
         res_fit = P.pod_fits_resources(
             pod["req_mcpu"],
             pod["req_mem"],
@@ -207,7 +171,7 @@ def _probe_rows(config: SchedulerConfig, num_zones: int, num_values: int,
         )
     else:
         res_fit = jnp.ones((J, N), bool)
-    if not use_pallas and wants_ports(config):
+    if wants_ports(config):
         # host-port self-conflict: once one copy holds the pod's host
         # ports on a node, no further copy fits there (predicates.go:574)
         has_ports = (pod["port_mask"] != 0).any()
@@ -215,25 +179,19 @@ def _probe_rows(config: SchedulerConfig, num_zones: int, num_values: int,
 
     nzj_cpu = nz_mcpu[None, :] + j * pod["nz_mcpu"]
     nzj_mem = nz_mem[None, :] + j * pod["nz_mem"]
-    if not use_pallas:
-        tab = jnp.zeros((J, N), jnp.bfloat16 if bf16 else jnp.int64)
+    tab = jnp.zeros((J, N), jnp.int64)
     static_add = jnp.zeros((N,), jnp.int64)
     zeros = jnp.zeros((N,), jnp.int64)
     stk_rows = {"spread_base": zeros, "spread_selfmatch": zeros,
                 "na_counts": zeros, "tt_counts": zeros, "ip_totals": zeros}
     for name, weight in config.priorities:
         if name in (LEAST_REQUESTED, BALANCED_ALLOCATION):
-            if use_pallas:
-                continue  # the kernel already accumulated this term
             score = (R.least_requested if name == LEAST_REQUESTED
                      else R.balanced_resource_allocation)(
                 pod["nz_mcpu"], pod["nz_mem"], nzj_cpu, nzj_mem,
                 static["alloc_mcpu"], static["alloc_mem"],
             )
-            term = jnp.int64(weight) * score
-            # bf16 profile: per-term downcast then bf16 accumulate —
-            # the Pallas kernel mirrors this order exactly
-            tab = tab + (term.astype(jnp.bfloat16) if bf16 else term)
+            tab = tab + jnp.int64(weight) * score
         elif name == SELECTOR_SPREAD:
             # unmasked base counts; the replay applies the fit mask and
             # maxCount normalization per pick (ops/priorities.py:62)
@@ -328,11 +286,7 @@ def _probe_rows(config: SchedulerConfig, num_zones: int, num_values: int,
     # non-increasing in j (commits only consume capacity, and the
     # host-port self-conflict kills j>0 outright), so its sum over j —
     # the fit frontier — reconstructs it host-side as j < frontier[n].
-    if frontier is None:
-        frontier = res_fit.sum(0, dtype=jnp.int64)
-    if bf16 and not use_pallas:
-        # i32 final reduce of the bf16 accumulator (parallel/quant)
-        tab = tab.astype(jnp.int32).astype(jnp.int64)
+    frontier = res_fit.sum(0, dtype=jnp.int64)
     stk = jnp.stack([
         fit_static.astype(jnp.int64),
         frontier,
@@ -351,10 +305,9 @@ def _probe_rows(config: SchedulerConfig, num_zones: int, num_values: int,
 
 @jax.named_scope("probe")
 def _probe_fn(config: SchedulerConfig, num_zones: int, num_values: int, J: int,
-              static, carry, pod, *, kernel: str = "lax",
-              score_mode: str = "i64"):
+              static, carry, pod):
     stk, tab = _probe_rows(config, num_zones, num_values, J, static, carry,
-                           pod, kernel=kernel, score_mode=score_mode)
+                           pod)
     N = stk.shape[1]
     dt = _tab_dtype(config)
     k = 8 // np.dtype(dt).itemsize  # J is pow2 >= 16, always divisible
@@ -403,36 +356,15 @@ def _tab_dtype(config: SchedulerConfig):
 
 
 class WaveProbe:
-    """Compiles/caches the probe program per (config, J); emits RunTables.
+    """Compiles/caches the probe program per (config, J); emits RunTables."""
 
-    kernel: "lax" (default), "pallas" (the hand-written kernel,
-    ops/pallas_probe, compiled) or "pallas-interpret" (the same kernel
-    interpreted — only ever an explicit argument) — None reads
-    KUBERNETES_TPU_KERNEL once at construction. Asking for "pallas"
-    where the backend's compiler refuses the kernel raises here, with
-    the compiler's reason. score_mode: "i64" or "bf16" — None reads the
-    KUBERNETES_TPU_QUANT profile (parallel/quant.score_mode). Both are
-    per-instance so a shadow driver can force the full-width build."""
-
-    def __init__(self, config: Optional[SchedulerConfig] = None, *,
-                 kernel: Optional[str] = None,
-                 score_mode: Optional[str] = None):
-        from kubernetes_tpu.ops import pallas_probe as _plp
-        from kubernetes_tpu.parallel import quant as _quant
-
+    def __init__(self, config: Optional[SchedulerConfig] = None):
         self.config = config or SchedulerConfig()
-        self.kernel = kernel or (
-            "pallas" if _plp.requested() else "lax")
-        if self.kernel == "pallas":
-            _plp.check_compiled_lowering()
-        self.score_mode = score_mode or _quant.score_mode()
         self._jitted = {}
 
     def _probe_partial(self, num_zones: int, num_values: int, J: int):
         return functools.partial(
-            _probe_fn, self.config, num_zones, num_values, J,
-            kernel=self.kernel, score_mode=self.score_mode,
-        )
+            _probe_fn, self.config, num_zones, num_values, J)
 
     def _compiled(self, num_zones: int, num_values: int, J: int):
         key = (num_zones, num_values, J)
@@ -493,17 +425,17 @@ class WaveProbe:
             self._jitted[key] = fn
         return fn
 
-    def probe_fused_dispatch(self, static, carry, prev_buf, counts,
-                             next_buf, num_zones: int, num_values: int,
-                             J: int, layout, apply_fn):
-        """Enqueue the fused apply+probe program and return
-        (new_carry, raw) WITHOUT forcing the device->host transfer —
-        jax dispatch is async, so the caller can stage the next run's
-        host-side work while the device scores this one, then call
-        probe_fused_collect to block on the packed product. The
-        carry/raw handles are ordinary device arrays; nothing about
-        the program or its compiled shape differs from the serial
-        path, so decisions stay bit-identical."""
+    def probe_fused(self, static, carry, prev_buf, counts, next_buf,
+                    num_zones: int, num_values: int, J: int,
+                    rows: Optional[int], layout, apply_fn,
+                    has_selectors: bool,
+                    zone_id: Optional[np.ndarray] = None,
+                    self_anti_veto: Optional[np.ndarray] = None,
+                    svc_ctx: Optional[dict] = None):
+        """-> (new_carry, RunTables). prev_buf/counts None on the
+        backlog's first probe (nothing to fold yet). One fused
+        apply+probe program and the one device->host transfer of its
+        packed product."""
         fns = self._compiled_fused(num_zones, num_values, J, layout,
                                    apply_fn)
         if prev_buf is None:
@@ -513,43 +445,14 @@ class WaveProbe:
         else:
             carry2, raw = fns["prev"](static, carry, prev_buf, counts,
                                       next_buf)
-        return carry2, raw
-
-    def probe_fused_collect(self, raw, num_zones: int, J: int,
-                            rows: Optional[int], has_selectors: bool,
-                            zone_id: Optional[np.ndarray] = None,
-                            self_anti_veto: Optional[np.ndarray] = None,
-                            svc_ctx: Optional[dict] = None) -> "RunTables":
-        """Block on a probe_fused_dispatch product (the one
-        device->host transfer) and unpack it into RunTables."""
         if rows is None:
             rows = J
         rows = max(1, min(rows, J))
         arr = np.ascontiguousarray(jax.device_get(raw["packed"]))
-        return tables_from_packed(
+        return carry2, tables_from_packed(
             self.config, arr, num_zones, J, rows,
             has_selectors=has_selectors, zone_id=zone_id,
             self_anti_veto=self_anti_veto, svc_ctx=svc_ctx,
-        )
-
-    def probe_fused(self, static, carry, prev_buf, counts, next_buf,
-                    num_zones: int, num_values: int, J: int,
-                    rows: Optional[int], layout, apply_fn,
-                    has_selectors: bool,
-                    zone_id: Optional[np.ndarray] = None,
-                    self_anti_veto: Optional[np.ndarray] = None,
-                    svc_ctx: Optional[dict] = None):
-        """-> (new_carry, RunTables). prev_buf/counts None on the
-        backlog's first probe (nothing to fold yet). The serial form:
-        dispatch immediately followed by collect."""
-        carry2, raw = self.probe_fused_dispatch(
-            static, carry, prev_buf, counts, next_buf, num_zones,
-            num_values, J, layout, apply_fn,
-        )
-        return carry2, self.probe_fused_collect(
-            raw, num_zones, J, rows, has_selectors=has_selectors,
-            zone_id=zone_id, self_anti_veto=self_anti_veto,
-            svc_ctx=svc_ctx,
         )
 
     def _compiled_group(self, num_zones: int, num_values: int, G: int,

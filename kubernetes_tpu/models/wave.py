@@ -59,21 +59,10 @@ from kubernetes_tpu.models.probe import (
     tables_from_stk,
 )
 from kubernetes_tpu.models.replay import ReplayResult, replay_fast
+from kubernetes_tpu.ops.narrow import narrow_dtype
 from kubernetes_tpu.snapshot.encode import ClusterSnapshot, PodBatch
 from kubernetes_tpu.snapshot.pad import next_pow2, pad_batch
 from kubernetes_tpu.trace.profile import phase_timer
-
-#: KUBERNETES_TPU_PIPELINE=1: double-buffered run pipeline — stage the
-#: next run's pod buffer (pack + async upload) while the current probe
-#: is in flight on device (models/probe dispatch/collect split)
-ENV_PIPELINE = "KUBERNETES_TPU_PIPELINE"
-
-
-def _pipeline_enabled() -> bool:
-    import os
-
-    return os.environ.get(ENV_PIPELINE, "").strip().lower() in (
-        "1", "true", "on", "yes")
 
 _WAVE_PRIORITIES = {
     LEAST_REQUESTED,
@@ -602,26 +591,10 @@ class WaveScheduler:
 
     def __init__(self, config: Optional[SchedulerConfig] = None,
                  min_run: int = 16, max_j: int = 1024, pod_floor: int = 64,
-                 replay=None, kernel: Optional[str] = None,
-                 quant_mode: Optional[str] = None,
-                 pipeline: Optional[bool] = None):
-        from kubernetes_tpu.parallel import quant as _quant
-
+                 replay=None):
         self.config = config or SchedulerConfig()
         self.scan = BatchScheduler(self.config)
-        # kernel/quant_mode default from KUBERNETES_TPU_KERNEL /
-        # KUBERNETES_TPU_QUANT; explicit values let a shadow driver or
-        # an A/B bench force a specific build (parallel/quant)
-        self._quant_mode = _quant.mode() if quant_mode is None else quant_mode
-        self.probe = WaveProbe(
-            self.config, kernel=kernel,
-            score_mode=_quant.score_mode(self._quant_mode))
-        # double-buffered run pipeline (KUBERNETES_TPU_PIPELINE):
-        # decision-data compute order is unchanged — only HOST staging
-        # moves under the device's probe window — so decisions stay
-        # bit-identical to the serial loop (tests/test_kernel.py)
-        self.pipeline = (_pipeline_enabled() if pipeline is None
-                         else bool(pipeline))
+        self.probe = WaveProbe(self.config)
         self.min_run = min_run
         self.max_j = max_j
         self.pod_floor = pod_floor
@@ -656,7 +629,7 @@ class WaveScheduler:
         self._dev: dict = {}
         self._dev_source: Optional[str] = None
         self._row_set_jit: dict = {}
-        # per-wave/total table-shipment accounting (bench --raw-curve)
+        # per-wave/total table-shipment accounting
         self.stats = {
             "waves": 0, "table_ships": 0, "table_reuses": 0,
             "table_scatters": 0, "wave_table_bytes": 0,
@@ -710,20 +683,16 @@ class WaveScheduler:
         """Device copies for `fields` (+ `extra` host arrays), shipping
         every miss in ONE batched device_put: each individual transfer
         has a fixed cost, so per-field puts dominate a cold wave. Placed
-        copies may ride a narrowed dtype (parallel/quant); mirrors
+        copies may ride a narrowed dtype (ops/narrow); mirrors
         keep full width, and a narrow-range overflow changes the
         placement dtype, which misses the cache and rebuilds wider."""
-        from kubernetes_tpu.parallel import quant as _quant
-
         out = {}
         missing = {}
         scatters = []
         for f in fields:
             host = getattr(snap, f)
             host_np = np.asarray(host)
-            place_dt = (_quant.narrow_dtype(f, host_np)
-                        if _quant.narrow_enabled(self._quant_mode)
-                        else host_np.dtype)
+            place_dt = narrow_dtype(f, host_np)
             ent = self._dev.get(f)
             if (
                 ent is not None
@@ -1197,60 +1166,18 @@ class WaveScheduler:
                 # the director's post-hoc check guards the binds
                 info["gang"] = None
 
-        # -- double-buffered staging (KUBERNETES_TPU_PIPELINE) --------
-        # rep -> (layout, device buf) packed + async-uploaded while an
-        # earlier run's probe was in flight. jax.device_put returns
-        # before the transfer completes, so the upload rides under the
-        # device's scoring window; run_single consumes the staged
-        # buffer instead of re-packing. Decision data is untouched —
-        # the staged buffer is bit-for-bit the buffer the serial loop
-        # would have packed at its later point.
-        staged: dict = {}
-
-        def _pack_run(rep):
-            ent = staged.pop(rep, None)
-            if ent is not None:
-                return ent
-            return pack_arrays({
-                f: np.asarray(getattr(batch, f)[rep])
-                for f in BatchScheduler.POD_FIELDS
-            })
-
-        def _stage_from(j):
-            """Stage the next host-path single run at or after infos[j]
-            (called between a probe's dispatch and collect). Runs that
-            will group pack their own fused group buffer, so staging
-            skips a pure run whose successor would group with it."""
-            while j < len(infos):
-                nxt = infos[j]
-                if not nxt["eligible"] or nxt["device"]:
-                    j += 1
-                    continue
-                if (nxt["pure"] and j + 1 < len(infos)
-                        and infos[j + 1]["pure"]
-                        and not infos[j + 1]["device"]):
-                    return  # will take the grouped header-probe path
-                if nxt["rep"] not in staged:
-                    with phase_timer("encode"):
-                        self._count("stage")
-                        l2, b2 = pack_arrays({
-                            f: np.asarray(getattr(batch, f)[nxt["rep"]])
-                            for f in BatchScheduler.POD_FIELDS
-                        })
-                        staged[nxt["rep"]] = (l2, jax.device_put(b2))
-                return
-
-        def run_single(carry, info, done0=0, next_idx=None):
+        def run_single(carry, info, done0=0):
             """The per-run fast path: probe_fused (or the single-run
             device replay) + host replay + deferred fold — one device
-            round trip per re-probe, exactly the pre-grouping shape.
-            Pipelined, the probe splits into dispatch + collect and the
-            NEXT run's buffer stages in the gap."""
+            round trip per re-probe, exactly the pre-grouping shape."""
             nonlocal L_host
             rep, start, length = info["rep"], info["start"], info["length"]
             self_anti_veto = info["veto"]
             svc_ctx = info["svc_ctx"]
-            layout, buf = _pack_run(rep)
+            layout, buf = pack_arrays({
+                f: np.asarray(getattr(batch, f)[rep])
+                for f in BatchScheduler.POD_FIELDS
+            })
             done = done0
             via[start + done:start + length] = _SINGLE
             while done < length:
@@ -1284,45 +1211,18 @@ class WaveScheduler:
                     L_host = res.last_node_index
                     done += res.n_done
                     continue
-                if self.pipeline:
-                    # dispatch (async enqueue) .. stage .. collect:
-                    # the next run's pack + upload overlaps the
-                    # device's scoring of THIS probe. ONE probe timer
-                    # spans the whole device window with the staging
-                    # encode timer nested inside: the hidden staging
-                    # seconds are the encode occurrences' wall
-                    # (phase_totals) inside this probe.
-                    with phase_timer("probe"):
-                        self._count("probe")
-                        carry, raw = self.probe.probe_fused_dispatch(
-                            static, carry, prev_buf, prev_counts, buf,
-                            num_zones, num_values, J, layout,
-                            self._apply_fn,
-                        )
-                        if next_idx is not None:
-                            _stage_from(next_idx)
-                        tables = self.probe.probe_fused_collect(
-                            raw, num_zones, J, rows,
-                            has_selectors=bool(
-                                batch.has_selectors[rep]),
-                            zone_id=(np.asarray(snap.zone_id)
-                                     if zoned else None),
-                            self_anti_veto=self_anti_veto,
-                            svc_ctx=svc_ctx,
-                        )
-                else:
-                    with phase_timer("probe"):
-                        self._count("probe")
-                        carry, tables = self.probe.probe_fused(
-                            static, carry, prev_buf, prev_counts, buf,
-                            num_zones, num_values, J, rows, layout,
-                            self._apply_fn,
-                            has_selectors=bool(batch.has_selectors[rep]),
-                            zone_id=(np.asarray(snap.zone_id)
-                                     if zoned else None),
-                            self_anti_veto=self_anti_veto,
-                            svc_ctx=svc_ctx,
-                        )
+                with phase_timer("probe"):
+                    self._count("probe")
+                    carry, tables = self.probe.probe_fused(
+                        static, carry, prev_buf, prev_counts, buf,
+                        num_zones, num_values, J, rows, layout,
+                        self._apply_fn,
+                        has_selectors=bool(batch.has_selectors[rep]),
+                        zone_id=(np.asarray(snap.zone_id)
+                                 if zoned else None),
+                        self_anti_veto=self_anti_veto,
+                        svc_ctx=svc_ctx,
+                    )
                 if tables.sa_bail:
                     # ServiceAffinity dynamics the tables can't express
                     # (mid-run re-pin hazard): scan the rest of the run
@@ -1516,13 +1416,12 @@ class WaveScheduler:
                         carry, group)
                 if partial is not None:
                     g_idx, done = partial
-                    carry = run_single(carry, group[g_idx], done0=done,
-                                       next_idx=idx + g_idx + 1)
+                    carry = run_single(carry, group[g_idx], done0=done)
                     idx += g_idx + 1
                 else:
                     idx += consumed
                 continue
-            carry = run_single(carry, info, next_idx=idx + 1)
+            carry = run_single(carry, info)
             idx += 1
         carry = settle(carry)
         carry = flush(carry)

@@ -9,10 +9,10 @@ generic_scheduler.go:109)."""
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from kubernetes_tpu.ops import bitset
+from kubernetes_tpu.ops.narrow import narrow_matvec
 from kubernetes_tpu.ops.predicates import _requirement_matrix
 
 MAX_PRIORITY = 10
@@ -20,17 +20,13 @@ MAX_PRIORITY = 10
 
 def taint_intolerable_counts(node_taint_count, pod_intolerable_prefer):
     """i64[N] per-list intolerable-taint counts. The node table may
-    ride a narrowed placement dtype (parallel/quant): the 0/1 pod
+    ride a narrowed placement dtype (ops/narrow): the 0/1 pod
     indicator casts DOWN to it and the contraction accumulates in
-    int32 via dot_general's preferred element type, so the big table
-    is never widened. Matches the plain int32 matmul bit-for-bit."""
-    counts = jax.lax.dot_general(
-        node_taint_count,
-        pod_intolerable_prefer.astype(node_taint_count.dtype),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-    return counts.astype(jnp.int64)
+    int32, so the big table is never widened. Matches the plain int32
+    matmul bit-for-bit."""
+    return narrow_matvec(
+        node_taint_count, pod_intolerable_prefer, jnp.int32
+    ).astype(jnp.int64)
 
 
 def _calculate_score(requested, capacity):

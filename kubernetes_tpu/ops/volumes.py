@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from kubernetes_tpu.ops.narrow import narrow_eq
+
 
 def _intersects(a, b):
     """Any shared bit between (..., W) masks."""
@@ -49,23 +51,6 @@ def max_pd_count(pod_mask, pod_bad, pod_has_new, node_mask, node_bad, max_volume
     return ~pod_bad & (~pod_has_new | ok)
 
 
-def _narrow_eq(node_vals, pod_val):
-    """Equality against a possibly dtype-narrowed node table
-    (parallel/quant): the small pod-side comparand casts DOWN to the
-    table dtype with a wide-side range guard, so an out-of-vocab pod
-    value can never alias into the narrow range and the big table is
-    never upcast."""
-    pod_val = jnp.asarray(pod_val)
-    if node_vals.dtype == pod_val.dtype:
-        return node_vals == pod_val
-    info = jnp.iinfo(node_vals.dtype)
-    return (
-        (node_vals == pod_val.astype(node_vals.dtype))
-        & (pod_val >= info.min)
-        & (pod_val <= info.max)
-    )
-
-
 def volume_zone(
     pod_zone, pod_region, pod_fail, node_zone, node_region, node_has
 ):
@@ -73,7 +58,7 @@ def volume_zone(
     zone/region label always pass (constraints empty)."""
     match = (
         ~pod_fail
-        & ((pod_zone < 0) | _narrow_eq(node_zone, pod_zone))
-        & ((pod_region < 0) | _narrow_eq(node_region, pod_region))
+        & ((pod_zone < 0) | narrow_eq(node_zone, pod_zone))
+        & ((pod_region < 0) | narrow_eq(node_region, pod_region))
     )
     return ~node_has | match

@@ -43,6 +43,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from kubernetes_tpu.ops.narrow import narrow_dtype
+
 AXIS = "nodes"
 
 #: carry leaf order — matches models/batch.BatchScheduler.initial_carry
@@ -216,19 +218,10 @@ class ResidentClusterState:
     #: full table anyway)
     SCATTER_FRAC = 0.25
 
-    def __init__(self, mesh, quant_mode: Optional[str] = None):
+    def __init__(self, mesh):
         from kubernetes_tpu.analysis import races as _races
-        from kubernetes_tpu.parallel import quant as _quant
 
         self.mesh = mesh
-        # quantized placement (parallel/quant): declared-narrow STATIC
-        # node tables place at their audited width; carry leaves stay
-        # full width (the device folds accumulate into them). The
-        # placed dtype is part of the topology signature, so a value
-        # outgrowing its narrow range rebuilds the table wider.
-        self._quant = _quant
-        self._quant_mode = (_quant.mode() if quant_mode is None
-                            else quant_mode)
         self._key = None  # topology signature (shapes/dtypes/field set)
         self._static: Dict[str, object] = {}
         self._carry: Optional[tuple] = None
@@ -263,12 +256,15 @@ class ResidentClusterState:
     # -- sync ----------------------------------------------------------------
 
     def _placed_dtype(self, f: str, arr: np.ndarray) -> np.dtype:
-        """Device-placement dtype for a field: the quant width audit
-        for declared-narrow static tables, the host dtype otherwise."""
-        if f in CARRY_FIELDS or not self._quant.narrow_enabled(
-                self._quant_mode):
+        """Device-placement dtype for a field (ops/narrow):
+        declared-narrow STATIC node tables place at their audited
+        width; carry leaves stay full width (the device folds
+        accumulate into them). The placed dtype is part of the topology
+        signature, so a value outgrowing its narrow range rebuilds the
+        table wider."""
+        if f in CARRY_FIELDS:
             return arr.dtype
-        return self._quant.narrow_dtype(f, arr)
+        return narrow_dtype(f, arr)
 
     def _placed(self, f: str, arr: np.ndarray) -> np.ndarray:
         dt = self._placed_dtype(f, arr)

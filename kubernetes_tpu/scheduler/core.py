@@ -16,7 +16,6 @@ one-at-a-time loop while amortizing snapshot + dispatch cost.
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import time
 from dataclasses import dataclass, field
@@ -87,30 +86,10 @@ class ExtendedGenericScheduler(GenericScheduler):
         return host
 
 
-def _wave_cap() -> int:
-    raw = os.environ.get("KUBERNETES_TPU_WAVE_CAP", "")
-    if raw:
-        try:
-            return int(raw)
-        except ValueError:
-            log.warning(
-                "ignoring malformed KUBERNETES_TPU_WAVE_CAP=%r; using 4096",
-                raw,
-            )
-    return 4096
-
-
-def _wave_floor() -> int:
-    raw = os.environ.get("KUBERNETES_TPU_WAVE_FLOOR", "")
-    if raw:
-        try:
-            return int(raw)
-        except ValueError:
-            log.warning(
-                "ignoring malformed KUBERNETES_TPU_WAVE_FLOOR=%r; "
-                "using 1024", raw,
-            )
-    return 1024
+#: SchedulerConfig's defaults for max_batch and wave_floor, which say
+#: why; the warmup compiles every pod bucket up to WAVE_CAP
+WAVE_CAP = 4096
+WAVE_FLOOR = 1024
 
 
 @dataclass
@@ -141,8 +120,7 @@ class SchedulerConfig:
     # end-to-end on the 30k-pod density run: smaller waves pipeline
     # better against the async bulk binds and watch ingest (decisions
     # are sequential-equivalent regardless of the cap).
-    # KUBERNETES_TPU_WAVE_CAP overrides, for perf experiments.
-    max_batch: int = field(default_factory=lambda: _wave_cap())
+    max_batch: int = WAVE_CAP
     # Burst-adaptive wave gathering: when a drain catches a burst
     # mid-arrival (extra pods were already waiting) but the wave is
     # still under this floor, the driver briefly waits for the queue to
@@ -151,8 +129,8 @@ class SchedulerConfig:
     # sequential-equivalent regardless of wave boundaries, so gathering
     # changes pacing, never placement. An idle-arrival singleton skips
     # the wait entirely (zero added latency when there is no burst).
-    # KUBERNETES_TPU_WAVE_FLOOR overrides; 0 disables gathering.
-    wave_floor: int = field(default_factory=lambda: _wave_floor())
+    # 0 disables gathering.
+    wave_floor: int = WAVE_FLOOR
     # minimum gather window; the driver scales it adaptively up to
     # wave_gather_max by the PREVIOUS wave's measured wall cost, so
     # cheap waves dispatch almost immediately while expensive waves

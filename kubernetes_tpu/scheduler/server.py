@@ -301,9 +301,10 @@ class SchedulerServer:
                             # the programs every wave needs did not
                             # compile or run on this device: never
                             # report ready over a broken device path
-                            self.start_error = e
+                            # (logged before a waiter can see it)
                             log.error("warmup failed; scheduler will "
                                       "not report ready", exc_info=True)
+                            self.start_error = e
                             return
 
                         def _scan_phase():

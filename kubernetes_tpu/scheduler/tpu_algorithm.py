@@ -70,8 +70,6 @@ class TPUScheduleAlgorithm:
         self._opt = None
         self._mesh_sched = None
         self._inc = None
-        self._shadow_gate = None
-        self._shadow_wave = None
         if mesh is not None and self._profile == PROFILE_OPTIMIZING:
             # the optimizing profile is single-chip for now; the mesh
             # path keeps the greedy driver (its resident-state grouped
@@ -94,18 +92,6 @@ class TPUScheduleAlgorithm:
                                        replay=replay)
             self._sched = self._wave.scan
             algo_config = self._wave.config
-            from kubernetes_tpu.parallel import quant as _quant
-
-            if _quant.score_mode(self._wave._quant_mode) == "bf16":
-                # the bf16 j-table profile is a DECLARED approximation:
-                # sampled waves re-run on a full-width shadow driver
-                # and any decision divergence increments the metric
-                # and permanently falls the session back to full width
-                # (parallel/quant.ShadowGate)
-                self._shadow_gate = _quant.ShadowGate()
-                self._shadow_wave = WaveScheduler(
-                    config=config, min_run=min_run, replay=replay,
-                    quant_mode="off")
         if cache is not None:
             # daemon mode: maintain the snapshot incrementally from
             # cache deltas instead of re-encoding the cluster per wave
@@ -257,11 +243,10 @@ class TPUScheduleAlgorithm:
             # ~4.5s of trace + compile-cache-read CPU interleaved with
             # the first minutes of a 30k-pod create burst, all of it
             # removable by compiling here, before the loop opens.
-            from kubernetes_tpu.scheduler.core import _wave_cap
+            from kubernetes_tpu.scheduler.core import WAVE_CAP
 
-            cap = _wave_cap()
             bucket = max(self._wave.pod_floor, self._wave.min_run, 2)
-            while bucket <= cap:
+            while bucket <= WAVE_CAP:
                 self._warm_one(
                     [pod(f"wb{bucket}-{i}", "100m", i)
                      for i in range(bucket)],
@@ -498,10 +483,6 @@ class TPUScheduleAlgorithm:
                     "score_add": add,
                 })
         driver = self._wave
-        if self._shadow_gate is not None and self._shadow_gate.fallen_back:
-            # a shadow-compare divergence already proved the bf16
-            # profile unsound for this workload: full width from here on
-            driver = self._shadow_wave
         if self._profile == "optimizing":
             if self._opt is None:
                 from kubernetes_tpu.scheduler.optimizer.profile import (
@@ -510,36 +491,10 @@ class TPUScheduleAlgorithm:
 
                 self._opt = OptimizingWaveDriver(self._wave)
             driver = self._opt
-        saved_last = self._last_node_index
         chosen, _final, last = driver.schedule_backlog(
             snap, batch, rep_idx, last_node_index=self._last_node_index,
             keep=keep, source=source, gangs=wave_gangs,
         )
-        if (self._shadow_gate is not None and driver is self._wave
-                and self._shadow_gate.should_check()):
-            import numpy as np
-
-            # full-width re-run from the same round-robin counter; the
-            # shadow driver's own mirrors content-compare the view, so
-            # keep stays empty (its last sighting may be waves old)
-            s_chosen, _sf, s_last = self._shadow_wave.schedule_backlog(
-                snap, batch, rep_idx, last_node_index=saved_last,
-                keep=frozenset(), source=source, gangs=wave_gangs,
-            )
-            matched = np.array_equal(np.asarray(chosen),
-                                     np.asarray(s_chosen))
-            self._shadow_gate.record(matched)
-            if not matched:
-                from kubernetes_tpu.metrics import (
-                    scheduler_quant_shadow_divergence_total,
-                )
-
-                scheduler_quant_shadow_divergence_total.inc()
-                log.warning(
-                    "bf16 quantized profile diverged from full width "
-                    "(wave of %d pods); falling back to full width",
-                    len(pods))
-                chosen, last = s_chosen, s_last
         self._last_node_index = last
         names = snap.node_names
         return [

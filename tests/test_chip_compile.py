@@ -150,31 +150,6 @@ def test_shipment_unpack_compiles_for_v5e_in_seconds(pods, controllers,
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * buf.nbytes
 
 
-@pytest.mark.xfail(
-    strict=True, raises=NotImplementedError,
-    reason="the v5e compiler refuses the kernel's compiled lowering: "
-           "'64-bit types are not supported' (ops/pallas_probe refs, "
-           "iota and accumulators are int64/f64). ROADMAP D1/S6 decide "
-           "whether it is rewritten in 32 bit or deleted; the day it "
-           "compiles, this strict xfail says so")
-def test_pallas_probe_compiled_lowering_for_v5e(registry, one_chip):
-    import functools
-
-    import jax
-
-    # the registry's probe_pallas entry is the INTERPRETED kernel (the
-    # audit reads its jaxpr). The compiled build is the same probe
-    # program with kernel="pallas": rebuild it from the registered
-    # lax probe's own partial, so the shapes and statics stay the
-    # registry's
-    spec = registry(1024)["probe"]
-    inner = spec.fn.__wrapped__
-    assert inner.keywords["kernel"] == "lax"
-    fn = jax.jit(functools.partial(
-        inner.func, *inner.args, **{**inner.keywords, "kernel": "pallas"}))
-    fn.lower(*_shapes(spec.args, one_chip))
-
-
 def test_mesh_fold_compiles_for_v5e_2x2(topo, one_chip, monkeypatch):
     """The donated commit fold of the mesh driver at the --mesh phase's
     size (20,000 nodes -> the 32,768 bucket, 8,192 per shard), for the

@@ -1,15 +1,17 @@
-"""Round-19 kernel-path contracts: the Pallas probe build must be
-bit-identical to the lax build, quantized table placement must be
-lossless (including the int8 -> int16 boundary rebuild), the
-double-buffered pipeline must reproduce the serial loop's decisions
-exactly, the bf16 profile must ride the ShadowGate, and the trace
-accountant must attribute staged encode seconds as probe overlap.
+"""Single-chip driver contracts: narrow table placement (ops/narrow)
+must be lossless (including the int8 -> int16 boundary rebuild), the
+driver's decisions on backlogs of consecutive impure runs must equal
+the oracle's, the dtype-contract audit must hold the narrow tables
+narrow in-program, and the package's environment knobs are the ones
+written out here.
 
 Every identity here is exact array/decision equality — the kernel
 path's whole contract is that raw speed changes NOTHING observable."""
 
 import json
+import os
 import random
+import re
 import types
 
 import numpy as np
@@ -26,24 +28,21 @@ from kubernetes_tpu.api.types import (
     Service,
     ServiceSpec,
 )
-from kubernetes_tpu.models.batch import BatchScheduler, SchedulerConfig
-from kubernetes_tpu.models.probe import WaveProbe
 from kubernetes_tpu.models.wave import WaveScheduler
+from kubernetes_tpu.ops import narrow
 from kubernetes_tpu.oracle import ClusterState
-from kubernetes_tpu.parallel import quant
 from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
-from kubernetes_tpu.snapshot.encode import SnapshotEncoder
 
 from tests.test_conformance import random_scenario
 from tests.test_wave import oracle_backlog
 
 
-# -- parallel/quant units ------------------------------------------------------
+# -- ops/narrow units ----------------------------------------------------------
 
 
 def test_narrow_dtype_boundaries():
     def dt(vals, dtype=np.int32, name="zone_id"):
-        return quant.narrow_dtype(name, np.asarray(vals, dtype))
+        return narrow.narrow_dtype(name, np.asarray(vals, dtype))
 
     assert dt([0, 127]) == np.int8
     assert dt([0, 128]) == np.int16
@@ -61,12 +60,12 @@ def test_narrow_dtype_scope():
     # only the declared-narrowable names shrink; bitsets/floats/bytes
     # pass through untouched
     big = np.arange(4, dtype=np.int64)
-    assert quant.narrow_dtype("alloc_cpu", big) == np.int64
-    assert quant.narrow_dtype("label_kv", np.zeros(4, np.uint32)) \
+    assert narrow.narrow_dtype("alloc_cpu", big) == np.int64
+    assert narrow.narrow_dtype("label_kv", np.zeros(4, np.uint32)) \
         == np.uint32
-    assert quant.narrow_dtype("zone_id", np.zeros(4, np.float32)) \
+    assert narrow.narrow_dtype("zone_id", np.zeros(4, np.float32)) \
         == np.float32
-    assert quant.narrow_dtype("zone_id", np.zeros(4, np.int16)) \
+    assert narrow.narrow_dtype("zone_id", np.zeros(4, np.int16)) \
         == np.int16  # already narrow: no re-audit churn
 
 
@@ -76,14 +75,14 @@ def test_narrow_eq_out_of_range_guard():
     table = jnp.asarray(np.array([1, 2, 3, 127], np.int8))
     # in-range compare matches the wide compare exactly
     assert np.array_equal(
-        np.asarray(quant.narrow_eq(table, jnp.asarray(3))),
+        np.asarray(narrow.narrow_eq(table, jnp.asarray(3))),
         np.array([False, False, True, False]))
     # an out-of-vocab wide comparand must NOT alias into the narrow
     # range (300 % 256 = 44 would otherwise be a valid int8)
     assert not np.asarray(
-        quant.narrow_eq(table, jnp.asarray(300))).any()
+        narrow.narrow_eq(table, jnp.asarray(300))).any()
     assert not np.asarray(
-        quant.narrow_eq(table, jnp.asarray(-300))).any()
+        narrow.narrow_eq(table, jnp.asarray(-300))).any()
 
 
 def test_narrow_matvec_matches_wide():
@@ -92,31 +91,17 @@ def test_narrow_matvec_matches_wide():
     rng = np.random.default_rng(7)
     table = rng.integers(0, 100, (32, 8)).astype(np.int8)
     vec = rng.integers(0, 2, 8).astype(np.int32)  # 0/1 indicator
-    got = np.asarray(quant.narrow_matvec(
+    got = np.asarray(narrow.narrow_matvec(
         jnp.asarray(table), jnp.asarray(vec), np.int32))
     want = table.astype(np.int32) @ vec
     assert got.dtype == np.int32 and np.array_equal(got, want)
 
 
-def test_shadow_gate_stride_and_fallback():
-    g = quant.ShadowGate(stride=4)
-    checks = [g.should_check() for _ in range(9)]
-    assert checks == [True, False, False, False, True,
-                      False, False, False, True]
-    g.record(True)
-    assert not g.fallen_back and g.divergence == 0
-    g.record(False)
-    assert g.fallen_back and g.divergence == 1
-    # fallen back: no further waves sample
-    assert not g.should_check()
-    assert quant.ShadowGate(stride=0).should_check() is False
-
-
-# -- quantized placement: device dtype + boundary rebuild ----------------------
+# -- narrow placement: device dtype + boundary rebuild -------------------------
 
 
 def test_to_dev_many_narrow_placement_and_boundary_rebuild():
-    ws = WaveScheduler(quant_mode="int")
+    ws = WaveScheduler()
     zid = (np.arange(24) % 3).astype(np.int32)
     snap = types.SimpleNamespace(zone_id=zid)
     out = ws._to_dev_many(snap, ["zone_id"], keep=frozenset())
@@ -145,81 +130,24 @@ def test_to_dev_many_narrow_placement_and_boundary_rebuild():
     assert out["zone_id"].dtype == np.int32
 
 
-def test_to_dev_many_wide_mode_off():
-    ws = WaveScheduler(quant_mode="off")
-    snap = types.SimpleNamespace(zone_id=(np.arange(8) % 3)
-                                 .astype(np.int32))
-    out = ws._to_dev_many(snap, ["zone_id"], keep=frozenset())
-    assert out["zone_id"].dtype == np.int32
+def test_to_dev_many_holds_non_narrowable_table_at_full_width():
+    # alloc_mcpu's values would fit int8; it is not on the declared
+    # list (resource tables hold byte counts), so it places as it is
+    ws = WaveScheduler()
+    snap = types.SimpleNamespace(
+        alloc_mcpu=np.full(8, 100, np.int64))
+    out = ws._to_dev_many(snap, ["alloc_mcpu"], keep=frozenset())
+    assert out["alloc_mcpu"].dtype == np.int64
+    assert ws._dev["alloc_mcpu"][3].dtype == np.int64
 
 
-# -- probe builds: pallas == lax, bf16 == i64 on the audit scenario ------------
-
-
-def _probe_inputs(J=64):
-    import jax.numpy as jnp
-
-    from kubernetes_tpu.analysis.programs import _scenario
-
-    config = SchedulerConfig()
-    snap, batch = _scenario()
-    num_zones = max(int(snap.zone_id.max()) + 1, 1)
-    num_values = int(snap.svc_num_values)
-    sched = BatchScheduler(config)
-    static = {f: jnp.asarray(getattr(snap, f))
-              for f in BatchScheduler.STATIC_FIELDS}
-    static.update(BatchScheduler.config_static(config, snap))
-    carry = sched.initial_carry(snap)
-    pod = {f: jnp.asarray(np.asarray(getattr(batch, f))[0])
-           for f in BatchScheduler.POD_FIELDS}
-    return config, num_zones, num_values, J, static, carry, pod
-
-
-def test_pallas_probe_bit_identical_to_lax():
-    config, nz, nv, J, static, carry, pod = _probe_inputs()
-    lax_out = WaveProbe(config, kernel="lax")._compiled(
-        nz, nv, J)(static, carry, pod)
-    # interpret mode by name: the compiled lowering exists on no CPU
-    # backend and is refused on the TPU (tests/test_chip_compile.py)
-    pal_out = WaveProbe(config, kernel="pallas-interpret")._compiled(
-        nz, nv, J)(static, carry, pod)
-    a = np.asarray(lax_out["packed"])
-    b = np.asarray(pal_out["packed"])
-    assert a.dtype == b.dtype and a.shape == b.shape
-    assert np.array_equal(a, b)
-
-
-def test_bf16_probe_matches_i64_on_default_profile():
-    # the default profile's summed |weight|*10 bound fits bf16's exact
-    # integer range, so the bf16 accumulator is bit-identical here
-    config, nz, nv, J, static, carry, pod = _probe_inputs()
-    i64 = WaveProbe(config, score_mode="i64")._compiled(
-        nz, nv, J)(static, carry, pod)
-    b16 = WaveProbe(config, score_mode="bf16")._compiled(
-        nz, nv, J)(static, carry, pod)
-    assert np.array_equal(np.asarray(i64["packed"]),
-                          np.asarray(b16["packed"]))
-
-
-def test_probe_kernel_env_selection(monkeypatch):
-    monkeypatch.delenv("KUBERNETES_TPU_KERNEL", raising=False)
-    assert WaveProbe(SchedulerConfig()).kernel == "lax"
-    monkeypatch.setenv("KUBERNETES_TPU_KERNEL", "pallas")
-    # the environment asks for the COMPILED kernel; a backend that
-    # cannot compile it refuses at construction instead of interpreting
-    with pytest.raises(ValueError, match="interpret mode"):
-        WaveProbe(SchedulerConfig())
-    # explicit ctor arg beats the env (the shadow-driver seam)
-    assert WaveProbe(SchedulerConfig(), kernel="lax").kernel == "lax"
-
-
-# -- end-to-end bit-identity: quant / pipeline / full stack --------------------
+# -- end-to-end decision identity against the oracle ---------------------------
 
 
 def _staged_backlog(num_nodes=16, num_pods=120, templates=3, block=10):
     """Blocks of impure runs (soft anti-affinity against the NEXT
-    group) — the shape where the pipeline actually stages; mirrors
-    bench.build_multi at test scale."""
+    group): consecutive single runs, each handing its fold to the
+    next run's probe."""
     nodes = [
         Node(
             metadata=ObjectMeta(
@@ -266,140 +194,32 @@ def _staged_backlog(num_nodes=16, num_pods=120, templates=3, block=10):
     return ClusterState.build(nodes, services=services), pods
 
 
-def test_pipeline_decisions_identical_to_serial():
-    from kubernetes_tpu.parallel.mesh import _pad_snapshot
-    from kubernetes_tpu.snapshot.encode import pod_feature_key
-    from kubernetes_tpu.snapshot.pad import next_pow2
-
-    state, pods = _staged_backlog()
-    uniq, rep_of, rep_list = [], {}, []
-    for p in pods:
-        k = pod_feature_key(p)
-        if k not in rep_of:
-            rep_of[k] = len(uniq)
-            uniq.append(p)
-        rep_list.append(rep_of[k])
-    enc = SnapshotEncoder(state, uniq)
-    snap = enc.encode_nodes()
-    batch = enc.encode_pods()
-    snap = _pad_snapshot(snap, next_pow2(snap.num_nodes, 4))
-    rep_idx = np.asarray(rep_list, np.int64)
-
-    serial = WaveScheduler(min_run=1, pipeline=False)
-    piped = WaveScheduler(min_run=1, pipeline=True)
-    s_chosen, s_carry, s_last = serial.schedule_backlog(
-        snap, batch, rep_idx)
-    p_chosen, p_carry, p_last = piped.schedule_backlog(
-        snap, batch, rep_idx)
-    assert np.array_equal(s_chosen, p_chosen)
-    assert s_last == p_last
-    # the pipelined driver actually staged (the wave kept per-wave
-    # dispatch tallies; staging shows up as its own count)
-    assert piped.dispatches.get("stage", 0) > 0
-    assert serial.dispatches.get("stage", 0) == 0
-
-
-def test_pipeline_env_gate(monkeypatch):
-    monkeypatch.delenv("KUBERNETES_TPU_PIPELINE", raising=False)
-    assert WaveScheduler().pipeline is False
-    monkeypatch.setenv("KUBERNETES_TPU_PIPELINE", "1")
-    assert WaveScheduler().pipeline is True
-    assert WaveScheduler(pipeline=False).pipeline is False
-
-
-def test_full_stack_matches_oracle_end_to_end(monkeypatch):
-    # quant int + pipeline on, against the oracle: the whole round-19
-    # stack must change nothing observable
-    state, pods = _staged_backlog(num_nodes=12, num_pods=90,
-                                  templates=3, block=10)
+@pytest.mark.parametrize("num_nodes,num_pods,templates", [
+    (16, 120, 3), (12, 90, 3), (10, 60, 2), (8, 40, 2)])
+def test_full_stack_matches_oracle_end_to_end(num_nodes, num_pods,
+                                              templates):
+    state, pods = _staged_backlog(num_nodes=num_nodes, num_pods=num_pods,
+                                  templates=templates, block=10)
     want = oracle_backlog(state, pods)
-    monkeypatch.setenv("KUBERNETES_TPU_QUANT", "int")
-    monkeypatch.setenv("KUBERNETES_TPU_PIPELINE", "1")
-    got = TPUScheduleAlgorithm().schedule_backlog(pods, state)
+    # min_run=1: blocks of 10 stay under the default min_run and would
+    # all take the scan
+    algo = TPUScheduleAlgorithm(min_run=1)
+    got = algo.schedule_backlog(pods, state)
     assert got == want
+    # every pod through run_single, each run folding its predecessor
+    assert algo._wave.stats["pods_by_path"]["single"] == num_pods
+    assert algo._wave.dispatches["probe"] == num_pods // 10
 
 
 @pytest.mark.parametrize("seed", [11, 23])
-def test_quant_decision_identity_fuzz(monkeypatch, seed):
+def test_quant_decision_identity_fuzz(seed):
     rng = random.Random(seed)
     state, pending = random_scenario(
         rng, n_nodes=10, n_existing=12, n_pending=30,
         interpod_p=0.2, volumes_p=0.3)
-    monkeypatch.setenv("KUBERNETES_TPU_QUANT", "off")
-    wide = TPUScheduleAlgorithm().schedule_backlog(pending,
-                                                   state.clone())
-    monkeypatch.setenv("KUBERNETES_TPU_QUANT", "int")
-    narrow = TPUScheduleAlgorithm().schedule_backlog(pending,
-                                                     state.clone())
-    assert narrow == wide
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("seed", [3, 5, 17, 29])
-def test_quant_pipeline_identity_fuzz_slow(monkeypatch, seed):
-    rng = random.Random(seed)
-    state, pending = random_scenario(
-        rng, n_nodes=14, n_existing=20, n_pending=60,
-        interpod_p=0.3, volumes_p=0.3)
-    monkeypatch.delenv("KUBERNETES_TPU_QUANT", raising=False)
-    monkeypatch.delenv("KUBERNETES_TPU_PIPELINE", raising=False)
-    base = TPUScheduleAlgorithm().schedule_backlog(pending,
-                                                   state.clone())
-    monkeypatch.setenv("KUBERNETES_TPU_QUANT", "int")
-    monkeypatch.setenv("KUBERNETES_TPU_PIPELINE", "1")
-    full = TPUScheduleAlgorithm().schedule_backlog(pending,
-                                                   state.clone())
-    assert full == base
-
-
-# -- bf16 ShadowGate wiring ----------------------------------------------------
-
-
-def test_bf16_profile_builds_shadow_and_matches(monkeypatch):
-    monkeypatch.setenv("KUBERNETES_TPU_QUANT", "bf16")
-    monkeypatch.setenv("KUBERNETES_TPU_QUANT_SHADOW", "1")
-    state, pods = _staged_backlog(num_nodes=10, num_pods=60,
-                                  templates=2, block=10)
-    algo = TPUScheduleAlgorithm()
-    assert algo._shadow_gate is not None
-    assert algo._shadow_wave is not None
-    got = algo.schedule_backlog(pods, state.clone())
-    assert algo._shadow_gate.checked >= 1
-    assert algo._shadow_gate.divergence == 0
-    monkeypatch.setenv("KUBERNETES_TPU_QUANT", "off")
-    wide = TPUScheduleAlgorithm().schedule_backlog(pods, state.clone())
-    assert got == wide
-
-
-def test_bf16_shadow_divergence_falls_back(monkeypatch):
-    from kubernetes_tpu.metrics import (
-        scheduler_quant_shadow_divergence_total,
-    )
-
-    monkeypatch.setenv("KUBERNETES_TPU_QUANT", "bf16")
-    monkeypatch.setenv("KUBERNETES_TPU_QUANT_SHADOW", "1")
-    state, pods = _staged_backlog(num_nodes=8, num_pods=40,
-                                  templates=2, block=10)
-    algo = TPUScheduleAlgorithm()
-    shadow = algo._shadow_wave
-    real_fn = shadow.schedule_backlog
-
-    def lying_shadow(*a, **kw):
-        chosen, carry, last = real_fn(*a, **kw)
-        bad = np.asarray(chosen).copy()
-        bad[0] = -1 if bad[0] != -1 else 0
-        return bad, carry, last
-
-    shadow.schedule_backlog = lying_shadow
-    before = scheduler_quant_shadow_divergence_total.get()
-    algo.schedule_backlog(pods, state.clone())
-    assert scheduler_quant_shadow_divergence_total.get() == before + 1
-    assert algo._shadow_gate.fallen_back
-    # after the trip the shadow (full-width) wave IS the driver; undo
-    # the lie and confirm the next backlog schedules sanely through it
-    shadow.schedule_backlog = real_fn
-    got = algo.schedule_backlog(pods, state.clone())
-    assert sum(1 for h in got if h is not None) > 0
+    want = oracle_backlog(state.clone(), pending)
+    got = TPUScheduleAlgorithm().schedule_backlog(pending, state.clone())
+    assert got == want
 
 
 # -- dtype contract (analysis gate) --------------------------------------------
@@ -456,7 +276,7 @@ def test_dtype_contract_flags_wide_arrival():
 def test_registered_quant_programs_clean():
     # the registry's probe_quant_* specs carry the contract; they must
     # trace clean end to end (the CI gate runs audit_all; this is the
-    # fast in-suite slice for the two quant builds + pallas)
+    # fast in-suite slice for the two narrow builds)
     from kubernetes_tpu.analysis.jaxpr_audit import audit_program
     from kubernetes_tpu.analysis.programs import build_programs
 
@@ -465,3 +285,46 @@ def test_registered_quant_programs_clean():
         assert name in specs
         assert specs[name].narrow_dtypes
         assert audit_program(specs[name]) == []
+
+
+# -- knob census ---------------------------------------------------------------
+
+
+def test_environment_knob_census():
+    # every KUBERNETES_TPU_* name the package mentions: an option added
+    # or removed is a one-line diff here
+    import kubernetes_tpu
+
+    found = set()
+    for root, _dirs, files in os.walk(
+            os.path.dirname(kubernetes_tpu.__file__)):
+        for fname in files:
+            if fname.endswith(".py"):
+                with open(os.path.join(root, fname)) as f:
+                    found.update(
+                        re.findall(r"KUBERNETES_TPU_[A-Z_]+", f.read()))
+    assert sorted(found) == [
+        "KUBERNETES_TPU_APF",
+        "KUBERNETES_TPU_APF_BORROW",
+        "KUBERNETES_TPU_APF_QUEUE_WAIT",
+        "KUBERNETES_TPU_APF_SEATS",
+        "KUBERNETES_TPU_AUDIT",
+        "KUBERNETES_TPU_AUDIT_LOG",
+        "KUBERNETES_TPU_AUDIT_RING",
+        "KUBERNETES_TPU_DEFAULT_GC",
+        "KUBERNETES_TPU_DEFRAG_BUDGET",
+        "KUBERNETES_TPU_EVENT_TTL",
+        "KUBERNETES_TPU_GIL_SWITCH_INTERVAL",
+        "KUBERNETES_TPU_MESH",
+        "KUBERNETES_TPU_NO_XLA_CACHE",
+        "KUBERNETES_TPU_OPT_SLOTS",
+        "KUBERNETES_TPU_PROFILE",
+        "KUBERNETES_TPU_RACE_REPORT",
+        "KUBERNETES_TPU_RACE_SANITIZER",
+        "KUBERNETES_TPU_TELEMETRY",
+        "KUBERNETES_TPU_TRACE",
+        "KUBERNETES_TPU_WARM_SCAN",
+        "KUBERNETES_TPU_WATCH_CACHE",
+        "KUBERNETES_TPU_WATCH_CACHE_SIZES",
+        "KUBERNETES_TPU_WATCH_COALESCE",
+    ]
