@@ -363,10 +363,9 @@ def evaluate_pod(config: SchedulerConfig, num_zones: int, num_values: int, stati
                 static["alloc_mem"],
             )
         elif name == SELECTOR_SPREAD:
-            s = R.selector_spread(
+            s = R.spread_score(
                 pod["has_selectors"],
-                pod["spread_match"],
-                class_count,
+                R.spread_counts(class_count, pod["spread_match"]),
                 static["zone_id"],
                 num_zones,
                 fit,

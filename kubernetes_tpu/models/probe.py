@@ -195,10 +195,8 @@ def _probe_rows(config: SchedulerConfig, num_zones: int, num_values: int,
         elif name == SELECTOR_SPREAD:
             # unmasked base counts; the replay applies the fit mask and
             # maxCount normalization per pick (ops/priorities.py:62)
-            stk_rows["spread_base"] = (
-                class_count.astype(jnp.int32)
-                @ pod["spread_match"].astype(jnp.int32)
-            ).astype(jnp.int64)
+            stk_rows["spread_base"] = R.spread_counts(
+                class_count, pod["spread_match"])
             stk_rows["spread_selfmatch"] = jnp.broadcast_to(
                 (pod["spread_match"][pod["class_id"]] > 0).astype(jnp.int64),
                 (N,),

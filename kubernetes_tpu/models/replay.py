@@ -44,7 +44,7 @@ def _scores(t: RunTables, j: np.ndarray, fit: np.ndarray) -> np.ndarray:
     score = t.tab[j, np.arange(N)] + t.static_add
     any_fit = bool(fit.any())
     if t.spread_base is not None:
-        # ops/priorities.selector_spread (float32 math, both branches)
+        # ops/priorities.spread_score (float32 math, both branches)
         c = t.spread_base + (j if t.spread_selfmatch else 0)
         c = np.where(fit, c, 0)
         M = int(c[fit].max()) if any_fit else 0

@@ -12,7 +12,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from kubernetes_tpu.ops import bitset
-from kubernetes_tpu.ops.narrow import narrow_matvec
+from kubernetes_tpu.ops.narrow import narrow_eq, narrow_matvec
 from kubernetes_tpu.ops.predicates import _requirement_matrix
 
 MAX_PRIORITY = 10
@@ -71,36 +71,46 @@ def equal(num_nodes):
     return jnp.ones((num_nodes,), jnp.int64)
 
 
-def selector_spread(
+def spread_counts(class_count, spread_match):
+    """The contraction of selector spread: class_count i64[N, C] with a
+    pod's spread_match i64[C] 0/1 -> i64[N], per node the same-namespace,
+    non-deleted pods matching ANY selector of the pod. Contracted in
+    int32: per-node pod counts are far below 2^31, and XLA's x64
+    rewriter has no TPU lowering for s64 dot_general."""
+    return (
+        class_count.astype(jnp.int32) @ spread_match.astype(jnp.int32)
+    ).astype(jnp.int64)
+
+
+def zone_sums(counts, zone_id, num_zones):
+    """counts[N] summed by zone_id -> [num_zones], as masked reductions
+    over a [zones, N] membership and not a scatter-add by zone_id: on
+    the chip the scatter is one serial update a node (245 us of the
+    scan's 467 us step at N = 4096 in emulated int64; PERF.md, PR 31),
+    the reduction a few microseconds, and the integers are the same."""
+    in_zone = narrow_eq(zone_id[None, :], jnp.arange(num_zones)[:, None])
+    return jnp.where(in_zone, counts[None, :], 0).sum(axis=1)
+
+
+def spread_score(
     pod_has_selectors,
-    pod_spread_match,  # i64[C] 0/1
-    class_count,  # i64[N, C]
+    counts,  # i64[N]: spread_counts
     zone_id,  # i32[N]
     num_zones,  # static int (vocab size incl. 0 == none)
     fit_mask,  # bool[N]
 ):
-    """selector_spreading.go:84 CalculateSpreadPriority.
-
-    count_n = number of same-namespace, non-deleted pods on node n
-    matching ANY selector of the pod = class_count @ spread_match.
-    maxCount and the zone aggregation run over FILTERED nodes only
-    (nodes.Items is the filtered list). float32 math as in Go."""
-    # contraction in int32: per-node pod counts are far below 2^31, and
-    # XLA's x64 rewriter has no TPU lowering for s64 dot_general
-    counts = (
-        class_count.astype(jnp.int32) @ pod_spread_match.astype(jnp.int32)
-    ).astype(jnp.int64)
+    """selector_spreading.go:84 CalculateSpreadPriority, from the
+    counts on: maxCount and the zone aggregation run over FILTERED
+    nodes only (nodes.Items is the filtered list). float32 math as in
+    Go."""
     counts = jnp.where(fit_mask, counts, 0)
     max_count = counts.max(where=fit_mask, initial=0)
 
     # zone aggregation: zone 0 == "no zone" and never participates.
     # countsByZone exists for every zone seen among filtered nodes
     # (including zero counts), so haveZones == any filtered node is zoned.
-    zcounts = jnp.zeros((num_zones,), jnp.int64).at[zone_id].add(counts)
-    zone_seen = jnp.zeros((num_zones,), jnp.int32).at[zone_id].add(
-        (fit_mask & (zone_id > 0)).astype(jnp.int32)
-    )
-    have_zones = jnp.any(zone_seen > 0)
+    zcounts = zone_sums(counts, zone_id, num_zones)
+    have_zones = jnp.any(fit_mask & (zone_id > 0))
     max_zone = jnp.where(jnp.arange(num_zones) > 0, zcounts, 0).max(initial=0)
 
     f = jnp.full(counts.shape, jnp.float32(MAX_PRIORITY))

@@ -499,23 +499,17 @@ def _spread_sharded(
     pod_has_selectors, pod_spread_match, class_count, zone_id, num_zones, fit_mask
 ):
     """selector_spread with the max/zone reductions made mesh-global."""
-    counts = (
-        class_count.astype(jnp.int32) @ pod_spread_match.astype(jnp.int32)
-    ).astype(jnp.int64)
+    counts = R.spread_counts(class_count, pod_spread_match)
     counts = jnp.where(fit_mask, counts, 0)
     max_count = jax.lax.pmax(
         counts.max(where=fit_mask, initial=0).astype(jnp.int32), AXIS
     ).astype(jnp.int64)
 
-    zcounts_local = jnp.zeros((num_zones,), jnp.int32).at[zone_id].add(
-        jnp.where(fit_mask, counts, 0).astype(jnp.int32)
-    )
-    zcounts = jax.lax.psum(zcounts_local, AXIS).astype(jnp.int64)
-    zone_seen_local = jnp.zeros((num_zones,), jnp.int32).at[zone_id].add(
-        (fit_mask & (zone_id > 0)).astype(jnp.int32)
-    )
-    zone_seen = jax.lax.psum(zone_seen_local, AXIS)
-    have_zones = jnp.any(zone_seen > 0)
+    zcounts = jax.lax.psum(
+        R.zone_sums(counts.astype(jnp.int32), zone_id, num_zones), AXIS
+    ).astype(jnp.int64)
+    have_zones = jax.lax.psum(
+        jnp.any(fit_mask & (zone_id > 0)).astype(jnp.int32), AXIS) > 0
     max_zone = jnp.where(jnp.arange(num_zones) > 0, zcounts, 0).max(initial=0)
 
     f = jnp.full(counts.shape, jnp.float32(R.MAX_PRIORITY))
@@ -594,10 +588,8 @@ def _mesh_probe_rows(config, num_zones, num_values, J, n_per_shard,
                 static["alloc_mcpu"], static["alloc_mem"],
             )
         elif name == "SelectorSpreadPriority":
-            stk_rows["spread_base"] = (
-                class_count.astype(jnp.int32)
-                @ pod["spread_match"].astype(jnp.int32)
-            ).astype(jnp.int64)
+            stk_rows["spread_base"] = R.spread_counts(
+                class_count, pod["spread_match"])
             stk_rows["spread_selfmatch"] = jnp.broadcast_to(
                 (pod["spread_match"][pod["class_id"]] > 0).astype(jnp.int64),
                 (N,),

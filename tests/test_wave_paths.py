@@ -1,8 +1,11 @@
 """Which path of the wave driver decided a pod, counted: the tallies
 WaveScheduler.stats["pods_by_path"] / ["dispatches_by_kind"] /
 ["pods_unplaced"] add up to the pods handed in, wave after wave, and
-/debug/traces shows the same numbers."""
+/debug/traces shows the same numbers. And the scan path picks as the
+serial oracle does where selector rows are all distinct, multi-hot, or
+followed by a pod that fits nowhere and by padding."""
 
+import numpy as np
 import pytest
 
 from kubernetes_tpu.api.types import (
@@ -15,6 +18,8 @@ from kubernetes_tpu.api.types import (
     PodSpec,
     ReplicationController,
     ReplicationControllerSpec,
+    Service,
+    ServiceSpec,
 )
 from kubernetes_tpu.models.wave import PATHS
 from kubernetes_tpu.trace import profile
@@ -142,3 +147,135 @@ def test_debug_traces_shows_the_wave_totals():
     assert shown == profile.wave_totals()
     assert {"waves", "pods_by_path", "dispatches_by_kind",
             "pods_unplaced"} <= set(shown)
+
+
+# -- the scan path against the serial oracle ---------------------------------
+
+
+def _labelled(name, labels, cpu="100m"):
+    return Pod(metadata=ObjectMeta(name=name, labels=dict(labels)),
+               spec=PodSpec(containers=[Container(requests={
+                   "cpu": cpu, "memory": "500Mi"})]))
+
+
+def _own_controllers():
+    """(a) every pod has its own controller: runs of length 1, every
+    spread_match row another."""
+    return (_controllers(23), [],
+            [_pod(t, 0) for t in range(23)])
+
+
+def _multi_hot():
+    """(b) `both-*` pods are selected by two controllers and a service:
+    their row is hot in four classes, so the commit of a plain rc-0,
+    tier or svc pod has to raise THEIR counts too, not only its own
+    row's."""
+    controllers = _controllers(2) + [ReplicationController(
+        metadata=ObjectMeta(name="tier"),
+        spec=ReplicationControllerSpec(selector={"tier": "x"}))]
+    services = [Service(metadata=ObjectMeta(name="svc"),
+                        spec=ServiceSpec(selector={"app": "svc"}))]
+    kinds = [
+        ("both", {"rc": "rc-0", "tier": "x", "app": "svc"}),
+        ("rc0", {"rc": "rc-0"}),
+        ("tier", {"tier": "x"}),
+        ("svc", {"app": "svc"}),
+        ("rc1", {"rc": "rc-1"}),
+        ("free", {"nobody": "selects"}),
+    ]
+    backlog = [_labelled(f"{kind}-{i:03d}", labels)
+               for i in range(9) for kind, labels in kinds]
+    return controllers, services, backlog
+
+
+def _unfit_in_the_middle():
+    """(c) a pod in the middle fits nowhere (64 CPUs on 4-CPU nodes)
+    and 37 pods pad to 64 scan steps: neither moves a count."""
+    backlog = _dealt_in_turn(6, 6)
+    backlog.insert(17, _labelled("huge", {"rc": "rc-2"}, cpu="64"))
+    return _controllers(6), [], backlog
+
+
+SCAN_CASES = {
+    "own-controllers": _own_controllers,
+    "multi-hot-row": _multi_hot,
+    "unfit-in-the-middle": _unfit_in_the_middle,
+}
+
+
+def _oracle(state, backlog):
+    from kubernetes_tpu.oracle import GenericScheduler
+
+    from tests.test_conformance import ORACLE_PREDICATES, ORACLE_PRIORITIES
+
+    return GenericScheduler(
+        predicates=ORACLE_PREDICATES, priorities=ORACLE_PRIORITIES,
+    ).schedule_backlog(backlog, state.clone())
+
+
+@pytest.mark.parametrize("zones", ["abc", ""], ids=["zoned", "unzoned"])
+@pytest.mark.parametrize("case", sorted(SCAN_CASES))
+def test_scan_picks_equal_the_serial_oracles(case, zones):
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    controllers, services, backlog = SCAN_CASES[case]()
+    # some pods bound already, so the first pick's counts are not all zero
+    bound = []
+    nodes = _nodes(9, zones)
+    for i, p in enumerate(backlog[::4]):
+        q = _labelled(f"bound-{i:03d}", p.metadata.labels)
+        q.spec.node_name = nodes[(i * i) % 9].metadata.name
+        bound.append(q)
+    state = ClusterState.build(nodes, bound, services=services,
+                               controllers=controllers)
+    want = _oracle(state, backlog)
+    algo = TPUScheduleAlgorithm(min_run=10 ** 6)  # every run to the scan
+    got = algo.schedule_backlog(backlog, state)
+    assert got == want
+    assert algo._wave.stats["pods_by_path"]["scan"] == len(backlog)
+    assert (None in got) == (case == "unfit-in-the-middle")
+
+
+@pytest.mark.parametrize("zones", ["abc", ""], ids=["zoned", "unzoned"])
+def test_two_scans_in_a_row_hand_on_the_class_counts(zones):
+    """(d) the class_count a scan returns in its carry is the table it
+    was given plus one per (chosen node, class) pair, entry for entry;
+    and a second scan started from it picks as the oracle does over
+    both waves."""
+    import jax.numpy as jnp
+
+    from kubernetes_tpu.models.batch import BatchScheduler
+    from kubernetes_tpu.models.wave import gather_batch
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.snapshot.encode import SnapshotEncoder
+
+    controllers, services, backlog = _multi_hot()
+    backlog.insert(20, _labelled("huge", {"rc": "rc-1"}, cpu="64"))
+    state = ClusterState.build(_nodes(9, zones), services=services,
+                               controllers=controllers)
+    want = _oracle(state, backlog)
+    snap, batch = SnapshotEncoder(state, backlog).encode()
+    sched = BatchScheduler()
+    static = {f: jnp.asarray(getattr(snap, f))
+              for f in BatchScheduler.STATIC_FIELDS}
+    num_zones = max(int(snap.zone_id.max()) + 1, 1)
+    run = sched._compiled(num_zones, int(snap.svc_num_values))
+    carry = sched.initial_carry(snap)
+    table = np.asarray(snap.class_count).copy()
+    CLASS_COUNT = 2  # its place in the carry (models/batch._scan_fn)
+    got = []
+    cut = 31
+    for rows in (np.arange(cut), np.arange(cut, len(backlog))):
+        part = gather_batch(batch, rows)
+        carry, chosen = run(static, carry, {
+            f: jnp.asarray(getattr(part, f))
+            for f in BatchScheduler.POD_FIELDS})
+        chosen = np.asarray(chosen)
+        placed = chosen >= 0
+        np.add.at(table, (chosen[placed], part.class_id[placed]), 1)
+        assert np.array_equal(np.asarray(carry[CLASS_COUNT]), table)
+        got += [snap.node_names[i] if i >= 0 else None for i in chosen]
+    assert got == want
+    assert got.count(None) == 1
+    assert int(carry[BatchScheduler.LAST_IDX]) == len(backlog) - 1
