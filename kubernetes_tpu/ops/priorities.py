@@ -113,6 +113,24 @@ def spread_score(
     have_zones = jnp.any(fit_mask & (zone_id > 0))
     max_zone = jnp.where(jnp.arange(num_zones) > 0, zcounts, 0).max(initial=0)
 
+    return spread_blend(pod_has_selectors, counts, max_count, zcounts,
+                        max_zone, have_zones, zone_id)
+
+
+def spread_blend(
+    pod_has_selectors,
+    counts,  # i64[N]: the masked counts
+    max_count,  # their maximum over the nodes that fit
+    zcounts,  # i64[Z]: zone_sums of them
+    max_zone,  # the largest of the zoned ones
+    have_zones,  # any fitting node is zoned
+    zone_id,  # i32[N]
+):
+    """CalculateSpreadPriority's arithmetic, from the reductions on:
+    the node share, the zone share and their blend in float32,
+    truncated. The single-chip scorer reduces over its node axis, the
+    sharded one (parallel/mesh._spread_sharded) over every shard's, and
+    both score here, so that they cannot differ by an ulp."""
     f = jnp.full(counts.shape, jnp.float32(MAX_PRIORITY))
     f = jnp.where(
         max_count > 0,

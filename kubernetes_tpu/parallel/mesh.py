@@ -68,6 +68,7 @@ from kubernetes_tpu.ops import priorities as R
 from kubernetes_tpu.ops import services as SV
 from kubernetes_tpu.ops import volumes as V
 from kubernetes_tpu.snapshot.encode import ClusterSnapshot, PodBatch, service_config_labels
+from kubernetes_tpu.trace.profile import phase_timer
 
 
 def _pad_snapshot(snap: ClusterSnapshot, multiple: int) -> ClusterSnapshot:
@@ -498,7 +499,10 @@ def _mesh_scan_fn(config, num_zones, n_per_shard, n_global, num_values,
 def _spread_sharded(
     pod_has_selectors, pod_spread_match, class_count, zone_id, num_zones, fit_mask
 ):
-    """selector_spread with the max/zone reductions made mesh-global."""
+    """selector_spread with the max/zone reductions made mesh-global;
+    the arithmetic from there on is the single-chip scorer's own
+    (`ops/priorities.spread_blend`), so that the two cannot differ by
+    an ulp of a weight."""
     counts = R.spread_counts(class_count, pod_spread_match)
     counts = jnp.where(fit_mask, counts, 0)
     max_count = jax.lax.pmax(
@@ -512,22 +516,8 @@ def _spread_sharded(
         jnp.any(fit_mask & (zone_id > 0)).astype(jnp.int32), AXIS) > 0
     max_zone = jnp.where(jnp.arange(num_zones) > 0, zcounts, 0).max(initial=0)
 
-    f = jnp.full(counts.shape, jnp.float32(R.MAX_PRIORITY))
-    f = jnp.where(
-        max_count > 0,
-        jnp.float32(R.MAX_PRIORITY)
-        * ((max_count - counts).astype(jnp.float32) / max_count.astype(jnp.float32)),
-        f,
-    )
-    node_zcount = zcounts[zone_id]
-    zone_score = jnp.float32(R.MAX_PRIORITY) * (
-        (max_zone - node_zcount).astype(jnp.float32) / max_zone.astype(jnp.float32)
-    )
-    zone_weighting = jnp.float32(2.0 / 3.0)
-    blended = f * (jnp.float32(1.0) - zone_weighting) + zone_weighting * zone_score
-    f = jnp.where(have_zones & (zone_id > 0), blended, f)
-    f = jnp.where(pod_has_selectors, f, jnp.float32(R.MAX_PRIORITY))
-    return jnp.where(jnp.isnan(f), jnp.int64(-(2**63)), f.astype(jnp.int64))
+    return R.spread_blend(pod_has_selectors, counts, max_count, zcounts,
+                          max_zone, have_zones, zone_id)
 
 
 def _mesh_probe_rows(config, num_zones, num_values, J, n_per_shard,
@@ -680,6 +670,7 @@ def _mesh_probe_rows(config, num_zones, num_values, J, n_per_shard,
     return stk, tab
 
 
+@jax.named_scope("probe")
 def _mesh_probe_fn(config, num_zones, num_values, J, n_per_shard,
                    n_global, pod_layout, static, carry, pod_buf):
     """Per-shard wave probe (models/probe._probe_fn, sharded): this
@@ -705,6 +696,7 @@ def _mesh_probe_fn(config, num_zones, num_values, J, n_per_shard,
     return jnp.concatenate([stk, tabw], axis=0)
 
 
+@jax.named_scope("probe")
 def _mesh_group_probe_fn(config, num_zones, num_values, G, n_per_shard,
                          n_global, pod_layout, static, carry, group_buf):
     """The grouped header probe, sharded: vmap of _mesh_probe_rows over
@@ -730,6 +722,7 @@ def _mesh_group_probe_fn(config, num_zones, num_values, G, n_per_shard,
     return stk.reshape(G * N_STK_ROWS, n_per_shard)
 
 
+@jax.named_scope("fold")
 def _mesh_apply_group_fn(config, pod_layout, n_global, static, carry,
                          group_buf, touch_idx, touch_cnt):
     """The grouped commit fold, sharded and donated: commits arrive in
@@ -774,6 +767,7 @@ def _mesh_apply_group_fn(config, pod_layout, n_global, static, carry,
     return (res, port_mask, class_count, last_idx) + tuple(rest)
 
 
+@jax.named_scope("apply")
 def _mesh_apply_fn(config, pod_layout, n_global, static, carry, pod_buf,
                    touch_idx, touch_cnt):
     """The wave commit fold, sharded and donated: commits arrive in
@@ -994,9 +988,10 @@ class MeshBatchScheduler:
             )
 
             def spmd(static_, carry_, pods_):
-                final, chosen = jax.lax.scan(
-                    functools.partial(body, static_), carry_, pods_
-                )
+                with jax.named_scope("scan"):
+                    final, chosen = jax.lax.scan(
+                        functools.partial(body, static_), carry_, pods_
+                    )
                 return final, chosen
 
             specs = (
@@ -1018,8 +1013,11 @@ class MeshBatchScheduler:
             # fold programs, whose bodies are scan-free, alias
             # correctly and keep their donation). The scan is the
             # fallback path, so the realloc cost is off the hot wave.
+            def mesh_scan(static_, carry_, pods_):
+                return sharded(static_, carry_, pods_)
+
             run = jax.jit(
-                sharded,
+                mesh_scan,
                 in_shardings=_ns_tree(self.mesh, specs),
                 out_shardings=(
                     _carry_out_shardings(self.mesh, empty),
@@ -1135,6 +1133,19 @@ class MeshWaveScheduler:
         # per-wave device-dispatch tally (tests assert the grouped path
         # keeps this independent of the template count)
         self.dispatches: dict = {}
+        # all waves, under WaveScheduler.stats' keys and meanings
+        # (`pods_by_path` adds up to the pods handed in; a window's
+        # numbers are diffs), plus what only a mesh has: the picks that
+        # landed on each shard's nodes (they add up to the pods placed)
+        # and the resident state's shipped bytes
+        from kubernetes_tpu.models.wave import PATHS
+
+        self.stats = {
+            "waves": 0, "dispatches": 0, "dispatches_by_kind": {},
+            "pods_by_path": dict.fromkeys(PATHS, 0), "pods_unplaced": 0,
+            "picks_by_shard": [0] * int(mesh.devices.size),
+            "h2d_bytes_total": 0,
+        }
 
     # -- pjit programs (builders shared with analysis/programs) --------------
 
@@ -1146,17 +1157,21 @@ class MeshWaveScheduler:
         1) donated when asked.  The four program families below differ
         only in body/specs/donation — one builder keeps their wrapping
         from drifting.  The folds pass their own `out_shardings`
-        (_carry_out_shardings; their key names the empty leaves)."""
+        (_carry_out_shardings; their key names the empty leaves).
+        key[0] is the family and names the program: jit_mesh_<family>
+        in a trace and among the compiles."""
         run = cache.get(key)
         if run is None:
+            program = jax.shard_map(
+                body,
+                mesh=self.mesh,
+                in_specs=arg_specs,
+                out_specs=out_specs,
+                check_vma=False,
+            )
+            program.__name__ = "mesh_" + key[0]
             run = jax.jit(
-                jax.shard_map(
-                    body,
-                    mesh=self.mesh,
-                    in_specs=arg_specs,
-                    out_specs=out_specs,
-                    check_vma=False,
-                ),
+                program,
                 in_shardings=_ns_tree(self.mesh, arg_specs),
                 out_shardings=(
                     _ns_tree(self.mesh, out_specs)
@@ -1185,7 +1200,7 @@ class MeshWaveScheduler:
                              num_values, G, pod_layout):
         return self._pjit_program(
             self._probe_jit,
-            ("gprobe", n, n_per_shard, num_zones, num_values, G,
+            ("group_probe", n, n_per_shard, num_zones, num_values, G,
              pod_layout, tuple(sorted(static))),
             functools.partial(_mesh_group_probe_fn, self.config,
                               num_zones, num_values, G, n_per_shard, n,
@@ -1219,7 +1234,7 @@ class MeshWaveScheduler:
                              donate=True, empty=()):
         return self._pjit_program(
             self._apply_jit,
-            ("gapply", n, n_per_shard, pod_layout, donate, empty,
+            ("apply_group", n, n_per_shard, pod_layout, donate, empty,
              tuple(sorted(static))),
             functools.partial(_mesh_apply_group_fn, self.config,
                               pod_layout, n),
@@ -1246,8 +1261,9 @@ class MeshWaveScheduler:
                    n_per_shard, num_zones, num_values, J):
         run = self._probe_program(static, n, n_per_shard, num_zones,
                                   num_values, J, pod_layout)
-        with self.mesh:
-            return run(static, carry, pod_buf)
+        with phase_timer("probe"), self.mesh:
+            return np.ascontiguousarray(
+                jax.device_get(run(static, carry, pod_buf)))
 
     def _apply_run(self, static, carry, pod_layout, pod_buf, counts, n,
                    n_per_shard):
@@ -1255,12 +1271,12 @@ class MeshWaveScheduler:
         run = self._apply_program(static, n, n_per_shard, pod_layout,
                                   empty=empty_leaves(carry))
         self.resident.count_h2d(idx.nbytes + cnt.nbytes)
-        with self.mesh:
+        with phase_timer("replay"), self.mesh:
             carry = run(static, carry, pod_buf, idx, cnt)
-        # drain the donated fold before anything can re-donate its
-        # aliased buffers (the fold is the last dispatch of its run,
-        # so only fold-vs-host bookkeeping overlap is lost)
-        jax.block_until_ready(carry)
+            # drain the donated fold before anything can re-donate its
+            # aliased buffers (the fold is the last dispatch of its
+            # run, so only fold-vs-host bookkeeping overlap is lost)
+            jax.block_until_ready(carry)
         self.resident.set_carry(carry)
         return carry
 
@@ -1275,9 +1291,9 @@ class MeshWaveScheduler:
         run = self._group_probe_program(static, n, n_per_shard,
                                         num_zones, num_values, G,
                                         pod_layout)
-        with self.mesh:
-            raw = run(static, carry, group_buf)
-        arr = np.ascontiguousarray(jax.device_get(raw))
+        with phase_timer("probe"), self.mesh:
+            arr = np.ascontiguousarray(
+                jax.device_get(run(static, carry, group_buf)))
         return arr.reshape(G, N_STK_ROWS, n)
 
     def _apply_group_run(self, static, carry, pod_layout, group_buf,
@@ -1289,10 +1305,10 @@ class MeshWaveScheduler:
                                         pod_layout,
                                         empty=empty_leaves(carry))
         self.resident.count_h2d(idx.nbytes + cnt.nbytes)
-        with self.mesh:
+        with phase_timer("replay"), self.mesh:
             carry = run(static, carry, group_buf, idx, cnt)
-        # see _apply_run: donated folds drain before re-donation
-        jax.block_until_ready(carry)
+            # see _apply_run: donated folds drain before re-donation
+            jax.block_until_ready(carry)
         self.resident.set_carry(carry)
         return carry
 
@@ -1318,6 +1334,9 @@ class MeshWaveScheduler:
         from kubernetes_tpu.models.replay import ReplayResult
         from kubernetes_tpu.models.pack import pack_arrays
         from kubernetes_tpu.models.wave import (
+            _GROUP_HOST,
+            _SCAN,
+            _SINGLE,
             _host_group_cap,
             _permute_tables,
             classify_runs,
@@ -1337,9 +1356,12 @@ class MeshWaveScheduler:
         P = len(rep_idx)
 
         self.resident.begin_wave()
-        static, carry = self.resident.sync(
-            self.config, snap, last_node_index, reuse=reuse
-        )
+        # "transfer": the mirror compare and whatever it ships (deltas
+        # as row scatters, a changed replicated table whole)
+        with phase_timer("transfer"):
+            static, carry = self.resident.sync(
+                self.config, snap, last_node_index, reuse=reuse
+            )
         num_zones = max(int(snap.zone_id.max()) + 1, 1)
         num_values = int(snap.svc_num_values)
         zoned = bool(np.any(np.asarray(snap.zone_id) > 0))
@@ -1347,35 +1369,50 @@ class MeshWaveScheduler:
         perm = np.asarray(snap.name_desc_order).astype(np.int64)
         runs = split_runs(rep_idx)
         self.dispatches = {}
+        self.stats["waves"] += 1
+        # which path decided each backlog position (wave.PATHS)
+        via = np.full(P, _SINGLE, np.int8)
         pending: list = []
         L_host = int(last_node_index)
         blocks = _opaque_blocks(self.config)
+        replicated = NamedSharding(self.mesh, PSpec())
 
         def count(key):
             self.dispatches[key] = self.dispatches.get(key, 0) + 1
+            self.stats["dispatches"] += 1
+            by_kind = self.stats["dispatches_by_kind"]
+            by_kind[key] = by_kind.get(key, 0) + 1
 
         def flush(carry):
             nonlocal L_host
             if not pending:
                 return carry
             rows = np.asarray(pending, np.int64)
-            seg = gather_batch(batch, rep_idx[rows])
-            segp = pad_batch(seg, next_pow2(len(rows), self.pod_floor))
-            pods = {
-                f: np.asarray(getattr(segp, f))
-                for f in BatchScheduler.POD_FIELDS
-            }
-            count("scan")
-            self.resident.count_h2d(
-                sum(v.nbytes for v in pods.values()))
-            carry, chosen = self.scan._exec(
-                static, carry, pods, N, n_per_shard, num_zones,
-                num_values, segp.num_pods,
-            )
-            self.resident.set_carry(carry)
-            chosen_host = np.asarray(chosen)[: len(rows)]
-            out[rows] = chosen_host
-            L_host = int(jax.device_get(carry[BatchScheduler.LAST_IDX]))
+            via[rows] = _SCAN
+            with phase_timer("transfer"):
+                seg = gather_batch(batch, rep_idx[rows])
+                segp = pad_batch(seg,
+                                 next_pow2(len(rows), self.pod_floor))
+                pods = {
+                    f: np.asarray(getattr(segp, f))
+                    for f in BatchScheduler.POD_FIELDS
+                }
+                self.resident.count_h2d(
+                    sum(v.nbytes for v in pods.values()))
+                pods = jax.device_put(pods, replicated)
+            # "score", as on one chip: from the dispatch of the sharded
+            # scan to the host's read of its picks
+            with phase_timer("score"):
+                count("scan")
+                carry, chosen = self.scan._exec(
+                    static, carry, pods, N, n_per_shard, num_zones,
+                    num_values, segp.num_pods,
+                )
+                self.resident.set_carry(carry)
+                chosen_host = np.asarray(chosen)[: len(rows)]
+                out[rows] = chosen_host
+                L_host = int(
+                    jax.device_get(carry[BatchScheduler.LAST_IDX]))
             # host-visible pure-channel commits keep the mirrors exact;
             # the opaque feature blocks resync from the next snapshot
             segf = {
@@ -1425,17 +1462,18 @@ class MeshWaveScheduler:
                 for f in BatchScheduler.POD_FIELDS
             }
             pod_layout, pod_buf = pack_arrays(pod_host)
-            pod_buf = self._place_replicated(pod_buf)
+            with phase_timer("transfer"):
+                pod_buf = self._place_replicated(pod_buf)
             done = done0
+            via[start + done:start + length] = _SINGLE
             while done < length:
                 K = length - done
                 J, rows_n = self._pick_j(snap, batch, rep, K)
                 count("probe")
-                packed = self._probe_run(
+                arr = self._probe_run(
                     static, carry, pod_layout, pod_buf, N, n_per_shard,
                     num_zones, num_values, J,
                 )
-                arr = np.ascontiguousarray(jax.device_get(packed))
                 tables = tables_from_packed(
                     self.config, arr, num_zones, J, rows_n,
                     has_selectors=bool(batch.has_selectors[rep]),
@@ -1448,9 +1486,10 @@ class MeshWaveScheduler:
                     # (mid-run re-pin hazard): scan the rest of the run
                     pending.extend(range(start + done, start + length))
                     break
-                res: ReplayResult = self._replay(
-                    _permute_tables(tables, perm), K, L_host
-                )
+                with phase_timer("replay"):
+                    res: ReplayResult = self._replay(
+                        _permute_tables(tables, perm), K, L_host
+                    )
                 if res.n_done == 0:
                     pending.extend(range(start + done, start + length))
                     break
@@ -1481,22 +1520,28 @@ class MeshWaveScheduler:
             resident usage mirror and replays in FIFO order."""
             nonlocal L_host
             G = len(group)
+            for g in group:
+                via[g["start"]:g["start"] + g["length"]] = _GROUP_HOST
             G_bucket, glayout, gbuf = group_buffer(
                 batch, [g["rep"] for g in group], floor=1
             )
-            gbuf = self._place_replicated(gbuf)
+            with phase_timer("transfer"):
+                gbuf = self._place_replicated(gbuf)
             count("group_probe")
             headers = self._group_probe_run(
                 static, carry, glayout, gbuf, N, n_per_shard,
                 num_zones, num_values, G_bucket,
             )
-            usage = self.resident.usage()
-            counts_mat, n_full, partial_done, L_host = host_group_replay(
-                self.config, snap, batch,
-                [(g["rep"], g["start"], g["length"]) for g in group],
-                headers[:G], usage, self._replay, perm, L_host, out,
-                zoned, self.max_j, num_zones,
-            )
+            with phase_timer("replay"):
+                usage = self.resident.usage()
+                counts_mat, n_full, partial_done, L_host = \
+                    host_group_replay(
+                        self.config, snap, batch,
+                        [(g["rep"], g["start"], g["length"])
+                         for g in group],
+                        headers[:G], usage, self._replay, perm, L_host,
+                        out, zoned, self.max_j, num_zones,
+                    )
             if counts_mat.any():
                 count("apply")
                 carry = self._apply_group_run(
@@ -1553,7 +1598,31 @@ class MeshWaveScheduler:
             idx += 1
         carry = flush(carry)
         self.resident.finish_wave(carry, L_host)
+        self._count_wave(via, out, n_per_shard)
         return out, carry, L_host
+
+    def _count_wave(self, via: np.ndarray, out: np.ndarray,
+                    n_per_shard: int) -> None:
+        """A finished wave into the cumulative tallies, here and on
+        /debug/traces (trace/profile.wave_totals), as
+        WaveScheduler._count_wave."""
+        from kubernetes_tpu.models.wave import PATHS
+        from kubernetes_tpu.trace.profile import count_wave
+
+        pods = dict(zip(PATHS, np.bincount(via, minlength=len(PATHS))
+                        .tolist()))
+        placed = out[out >= 0]
+        unplaced = int(out.size - placed.size)
+        for path, n in pods.items():
+            self.stats["pods_by_path"][path] += n
+        self.stats["pods_unplaced"] += unplaced
+        shards = self.stats["picks_by_shard"]
+        for shard, n in enumerate(np.bincount(
+                placed // n_per_shard, minlength=len(shards)).tolist()):
+            shards[shard] += n
+        self.stats["h2d_bytes_total"] = \
+            self.resident.stats["h2d_bytes_total"]
+        count_wave(pods, self.dispatches, unplaced)
 
     def _pick_j(self, snap: ClusterSnapshot, batch: PodBatch, rep: int,
                 K: int):

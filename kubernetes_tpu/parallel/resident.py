@@ -217,6 +217,14 @@ class ResidentClusterState:
     #: instead of scattering (the packed-row shipment would approach the
     #: full table anyway)
     SCATTER_FRAC = 0.25
+    #: the smallest row bucket a scatter pads to
+    ROW_FLOOR = 64
+
+    @classmethod
+    def _row_bucket(cls, rows: int) -> int:
+        from kubernetes_tpu.snapshot.pad import next_pow2
+
+        return next_pow2(rows, floor=cls.ROW_FLOOR)
 
     def __init__(self, mesh):
         from kubernetes_tpu.analysis import races as _races
@@ -403,8 +411,14 @@ class ResidentClusterState:
             rows_union = rows if rows_union is None else np.union1d(
                 rows_union, rows)
         if rows_union is not None and (
-            len(rows_union) > n_global * self.SCATTER_FRAC
+            self._row_bucket(len(rows_union))
+            > max(n_global * self.SCATTER_FRAC, self.ROW_FLOOR)
+            or len(rows_union) > n_global * self.SCATTER_FRAC
         ):
+            # (the bucket, not the rows alone: on a node axis that is
+            # no power of two, 1,025 to 1,280 changed rows of 5,120
+            # would scatter at the 2,048 bucket, which no warm-up
+            # reaches, since a wave of 2,048 pods re-places)
             replace.extend((f, host, spec)
                            for f, host, spec, _ax in scatter)
             scatter = []
@@ -442,10 +456,9 @@ class ResidentClusterState:
         import jax
 
         from kubernetes_tpu.models.pack import pack_arrays
-        from kubernetes_tpu.snapshot.pad import next_pow2
 
         self.stats["scatters"] += 1
-        M = next_pow2(len(rows), floor=64)
+        M = self._row_bucket(len(rows))
         idx = np.full(M, -1, np.int64)
         idx[: len(rows)] = rows
         packed = {"__idx__": idx}
@@ -494,17 +507,19 @@ class ResidentClusterState:
         jkey = (names, axes, layout, shapes, n_per_shard, donate)
         run = self._scatter_jit.get(jkey)
         if run is None:
-            body = functools.partial(
+            body = jax.named_scope("scatter")(functools.partial(
                 _scatter_fn, n_per_shard, names, axes, layout,
-            )
+            ))
             arr_sh = tuple(NamedSharding(self.mesh, s) for s in specs)
+            mesh_scatter = jax.shard_map(
+                body, mesh=self.mesh,
+                in_specs=(tuple(specs), PSpec()),
+                out_specs=tuple(specs),
+                check_vma=False,
+            )
+            mesh_scatter.__name__ = "mesh_scatter"
             run = jax.jit(
-                jax.shard_map(
-                    body, mesh=self.mesh,
-                    in_specs=(tuple(specs), PSpec()),
-                    out_specs=tuple(specs),
-                    check_vma=False,
-                ),
+                mesh_scatter,
                 in_shardings=(arr_sh, NamedSharding(self.mesh, PSpec())),
                 out_shardings=arr_sh,
                 donate_argnums=(0,) if donate else (),

@@ -179,17 +179,18 @@ def _build_mesh():
 
 def _tpu_algorithm_factory(factory_args):
     """Build the batched TPU ScheduleAlgorithm (lazy import keeps jax out
-    of pure control-plane processes). The daemon wires the scheduler
-    cache so waves run off the incrementally-maintained snapshot; on a
-    multi-chip host the node axis shards across the device mesh
-    (MeshBatchScheduler — decisions bit-identical to single-chip, the
-    dryrun asserts it)."""
+    of pure control-plane processes). Either driver gets the scheduler
+    cache and the spread listers, so waves run off the incrementally
+    maintained snapshot; on a multi-chip host the node axis shards
+    across the device mesh (MeshWaveScheduler). Its decisions equal the
+    single-chip driver's: asserted on 8 virtual CPU devices by the
+    dryrun and tests/test_mesh_daemon.py, and on four v5e chips by the
+    benchmark's mesh-20k.fill cell against its plain reference
+    (PR 32)."""
     from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
 
-    mesh = _build_mesh()
-    if mesh is not None:
-        return TPUScheduleAlgorithm(mesh=mesh)
     return TPUScheduleAlgorithm(
+        mesh=_build_mesh(),
         cache=factory_args.scheduler_cache,
         service_lister=factory_args.service_lister,
         controller_lister=factory_args.controller_lister,

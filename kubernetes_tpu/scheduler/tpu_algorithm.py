@@ -22,6 +22,12 @@ from kubernetes_tpu.trace import profile as trace_profile
 
 log = logging.getLogger(__name__)
 
+#: node slots a device on a mesh: the sharded node axis is a multiple
+#: of this times the devices (lanes of 128; a cluster that outgrows its
+#: bucket compiles the mesh programs anew, as one that doubles does on
+#: a single chip)
+MESH_SLOTS_PER_SHARD = 256
+
 
 def _eager_scan_warm() -> bool:
     """KUBERNETES_TPU_WARM_SCAN=1: compile the scan-path programs during
@@ -80,29 +86,25 @@ class TPUScheduleAlgorithm:
         if mesh is not None:
             from kubernetes_tpu.parallel.mesh import MeshWaveScheduler
 
-            self._mesh_sched = MeshWaveScheduler(
+            # `_wave` is the wave driver of either build (its config,
+            # floors, stats and per-wave dispatches); `_mesh_sched` is
+            # set when it is the sharded one
+            self._wave = self._mesh_sched = MeshWaveScheduler(
                 mesh, config=config, min_run=min_run
             )
-            self._sched = self._mesh_sched.scan
-            algo_config = self._mesh_sched.config
         else:
             from kubernetes_tpu.models.wave import WaveScheduler
 
             self._wave = WaveScheduler(config=config, min_run=min_run,
                                        replay=replay)
-            self._sched = self._wave.scan
-            algo_config = self._wave.config
+        self._sched = self._wave.scan
         if cache is not None:
             # daemon mode: maintain the snapshot incrementally from
             # cache deltas instead of re-encoding the cluster per wave
             # (both drivers: the mesh resident state additionally
             # content-compares the view against its host mirrors, so an
             # unchanged incremental view ships zero node-table bytes)
-            from kubernetes_tpu.snapshot.incremental import (
-                IncrementalEncoder,
-            )
-
-            self._inc = IncrementalEncoder(config=algo_config)
+            self._inc = self._new_encoder()
             cache.add_listener(self._inc.on_cache_event)
         self._service_lister = service_lister
         self._controller_lister = controller_lister
@@ -113,6 +115,18 @@ class TPUScheduleAlgorithm:
         # serializes warmup against real waves (the scheduler loop itself
         # is single-threaded; warmup runs on a server thread)
         self._sched_lock = threading.Lock()
+
+    def _new_encoder(self):
+        """An incremental encoder for this build's driver. On a mesh
+        the node axis grows by MESH_SLOTS_PER_SHARD slots a device, so
+        that every shard holds real nodes; the single-chip driver keeps
+        the doubling its programs' shapes were compiled for."""
+        from kubernetes_tpu.snapshot.incremental import IncrementalEncoder
+
+        step = None
+        if self._mesh_sched is not None:
+            step = MESH_SLOTS_PER_SHARD * self._mesh_sched.mesh.devices.size
+        return IncrementalEncoder(config=self._wave.config, slot_step=step)
 
     def _dedup(self, pods: Sequence[Pod]):
         """Template-created pods (RC/RS/Job) are identical up to their
@@ -161,19 +175,13 @@ class TPUScheduleAlgorithm:
         opens for business after the template-path slice instead of the
         whole program set.
 
-        The mesh path warms too (one synthetic backlog through the
-        sharded program): a multi-chip daemon otherwise lands its cold
-        XLA compile on the first real pod's wave."""
-        if self._mesh_sched is not None:
-            # "run" warms the sharded probe/apply (template waves);
-            # "scan" warms the sharded fallback scan (heterogeneous or
-            # sub-min_run pods) — a cold scan compile would otherwise
-            # land on the first mixed backlog's flush
-            if phase in ("all", "run"):
-                self._warmup_mesh(num_nodes, scan=False)
-            if phase in ("all", "scan"):
-                self._warmup_mesh(num_nodes, scan=True)
-            return
+        The mesh driver warms through the same backlogs (its sharded
+        header probe and folds for the runs, its sharded scan at every
+        pod bucket for the lone pods), and one more thing with them:
+        the warm-up's encoder never hears of a backlog's picks, so the
+        nodes the backlog before filled differ from the resident
+        state's mirrors, and the row scatter that ships a wave's churn
+        compiles here at every row bucket up to the wave cap."""
         from kubernetes_tpu.api.types import (
             Container,
             Node,
@@ -216,26 +224,25 @@ class TPUScheduleAlgorithm:
                 p.spec.node_name = nodes[t % len(nodes)].metadata.name
                 bound.append(p)
         state = CS.build(nodes, bound, controllers=controllers)
+        shared = []  # the mesh driver's one encoder for every backlog
+
+        def warm(backlog):
+            self._warm_one(backlog, state, nodes, bound, shared)
+
         # an eligible run (probe+replay+apply programs); the lone pods
         # distinct only in their requests (below min_run => the scan
         # program) warm in phase "scan" — differing by resources keeps
         # every vocab width, and therefore every compiled shape,
         # identical to the run's
         if phase in ("all", "run"):
-            self._warm_one(
-                [pod(f"w{i}", "100m")
-                 for i in range(max(self._wave.min_run, 2))],
-                state, nodes, bound,
-            )
+            warm([pod(f"w{i}", "100m")
+                  for i in range(max(self._wave.min_run, 2))])
             # two adjacent template runs warm the GROUPED programs
             # (header probe + grouped fold) — the multi-template
             # backlog shape every RC/RS burst mix hits
             n = max(self._wave.min_run, 2)
-            self._warm_one(
-                [pod(f"wg{i}", "100m") for i in range(n)]
-                + [pod(f"wh{i}", "150m") for i in range(n)],
-                state, nodes, bound,
-            )
+            warm([pod(f"wg{i}", "100m") for i in range(n)]
+                 + [pod(f"wh{i}", "150m") for i in range(n)])
             # every pod-axis pow2 bucket a daemon wave can land in:
             # burst-adaptive gathering produces waves anywhere in
             # [pod_floor, wave cap], and each bucket is its own compiled
@@ -247,11 +254,8 @@ class TPUScheduleAlgorithm:
 
             bucket = max(self._wave.pod_floor, self._wave.min_run, 2)
             while bucket <= WAVE_CAP:
-                self._warm_one(
-                    [pod(f"wb{bucket}-{i}", "100m", i)
-                     for i in range(bucket)],
-                    state, nodes, bound,
-                )
+                warm([pod(f"wb{bucket}-{i}", "100m", i)
+                      for i in range(bucket)])
                 bucket *= 2
             if _eager_scan_warm():
                 # sub-min_run trickle waves hit the SCAN program, whose
@@ -262,111 +266,12 @@ class TPUScheduleAlgorithm:
                 # compile cache pays tens of seconds here before the
                 # loop opens; the wire bench and soak harness set it.
                 for k in (2, bucket // 2):
-                    self._warm_one(
-                        [pod(f"wsb{k}-{i}", f"{200 + i}m")
-                         for i in range(k)],
-                        state, nodes, bound,
-                    )
+                    warm([pod(f"wsb{k}-{i}", f"{200 + i}m")
+                          for i in range(k)])
         if phase in ("all", "scan"):
-            self._warm_one([pod("w-scan", "200m"),
-                            pod("w-scan2", "300m")], state, nodes,
-                           bound)
+            warm([pod("w-scan", "200m"), pod("w-scan2", "300m")])
 
-    def _warmup_mesh(self, num_nodes: int, scan: bool = False) -> None:
-        """Compile the sharded programs for the cluster's node bucket
-        before real pods arrive. scan=False: a min_run template run
-        (the sharded probe + apply); scan=True: heterogeneous pods
-        (the sharded fallback scan)."""
-        from kubernetes_tpu.api.types import (
-            Container,
-            Node,
-            NodeCondition,
-            NodeStatus,
-            ObjectMeta,
-            Pod as PodT,
-            PodSpec,
-        )
-        from kubernetes_tpu.oracle.state import ClusterState as CS
-
-        nodes = [
-            Node(
-                metadata=ObjectMeta(name=f"warm-{i:05d}"),
-                status=NodeStatus(
-                    allocatable={"cpu": "4", "memory": "32Gi",
-                                 "pods": "110"},
-                    conditions=[NodeCondition("Ready", "True")],
-                ),
-            )
-            for i in range(max(num_nodes, 1))
-        ]
-        if scan:
-            # distinct per-pod requests: never a run => the flush path
-            backlog = [
-                PodT(
-                    metadata=ObjectMeta(name=f"ws{i}",
-                                        labels={"app": "warm"}),
-                    spec=PodSpec(containers=[
-                        Container(image="warm",
-                                  requests={"cpu": f"{100 + i}m"})
-                    ]),
-                )
-                for i in range(2)
-            ]
-        else:
-            # a min_run-sized template run warms the sharded PROBE and
-            # APPLY programs; a second adjacent template warms the
-            # sharded GROUPED header probe + grouped fold. The two
-            # templates arrive as separate waves below so the
-            # single-run programs still compile.
-            backlog = [
-                PodT(
-                    metadata=ObjectMeta(name=f"w{i}",
-                                        labels={"app": "warm"}),
-                    spec=PodSpec(containers=[
-                        Container(image="warm", requests={"cpu": "100m"})
-                    ]),
-                )
-                for i in range(max(self._mesh_sched.min_run, 2))
-            ]
-        state = CS.build(nodes)
-        grouped = None
-        if not scan:
-            n = max(self._mesh_sched.min_run, 2)
-            grouped = [
-                PodT(
-                    metadata=ObjectMeta(name=f"wg{t}-{i}",
-                                        labels={"app": "warm"}),
-                    spec=PodSpec(containers=[
-                        Container(image="warm",
-                                  requests={"cpu": f"{100 + 50 * t}m"})
-                    ]),
-                )
-                for t in range(2) for i in range(n)
-            ]
-        with self._sched_lock:
-            saved_last, saved_inc = self._last_node_index, self._inc
-            try:
-                if saved_inc is not None:
-                    # daemon mode: warm through a throwaway incremental
-                    # encoder fed the synthetic cluster (same seam as
-                    # _warm_one) so the REAL view is never consulted
-                    from kubernetes_tpu.snapshot.incremental import (
-                        IncrementalEncoder,
-                    )
-
-                    inc = IncrementalEncoder(
-                        config=self._mesh_sched.config)
-                    for n in nodes:
-                        inc.on_cache_event("node_set", n)
-                    self._inc = inc
-                self._schedule_backlog_mesh(backlog, state)
-                if grouped is not None:
-                    self._schedule_backlog_mesh(grouped, state)
-            finally:
-                self._inc = saved_inc
-                self._last_node_index = saved_last
-
-    def _warm_one(self, backlog, state, nodes, bound) -> None:
+    def _warm_one(self, backlog, state, nodes, bound, shared) -> None:
         with self._sched_lock:
             saved_last, saved_inc = self._last_node_index, self._inc
             try:
@@ -376,20 +281,30 @@ class TPUScheduleAlgorithm:
                     # the full encoder's padded ones — warming the wrong
                     # program would leave the cold compile on the first
                     # real wave. Feed a throwaway encoder the synthetic
-                    # cluster through the same cache-event seam.
-                    from kubernetes_tpu.snapshot.incremental import (
-                        IncrementalEncoder,
-                    )
-
-                    inc = IncrementalEncoder(config=self._wave.config)
-                    for n in nodes:
-                        inc.on_cache_event("node_set", n)
-                    for p in bound:
-                        inc.on_cache_event("pod_add", p)
+                    # cluster through the same cache-event seam. It never
+                    # hears of a warm backlog's picks, so its view is the
+                    # synthetic cluster every time. The mesh driver's
+                    # resident state goes by content: one such encoder
+                    # (`shared`, the warm-up's) serves all of a
+                    # warm-up's backlogs (feeding 20,000 nodes and
+                    # encoding 1,250 templates' rows a dozen times was
+                    # 200 s of a set-up at that size). The single-chip driver's
+                    # device cache goes by provenance (`source`,
+                    # `keep`): each of its backlogs gets an encoder of
+                    # its own, as before.
+                    inc = shared[0] if shared else None
+                    if inc is None:
+                        inc = self._new_encoder()
+                        for n in nodes:
+                            inc.on_cache_event("node_set", n)
+                        for p in bound:
+                            inc.on_cache_event("pod_add", p)
+                        if self._mesh_sched is not None:
+                            shared.append(inc)
                     self._inc = inc
                 else:
                     self._inc = None  # compile via the full-encode path
-                self._schedule_backlog_locked(backlog, state)
+                self._schedule_locked(backlog, state)
             finally:
                 self._inc = saved_inc
                 self._last_node_index = saved_last
@@ -406,14 +321,15 @@ class TPUScheduleAlgorithm:
         post-hoc all-or-nothing check before binding."""
         if not pods:
             return []
-        if self._mesh_sched is not None:
-            # same lock as the single-chip path: serializes real waves
-            # against the background warmup's counter save/restore
-            with self._sched_lock:
-                return self._schedule_backlog_mesh(pods, state)
+        # the lock serializes real waves against the background
+        # warmup's counter save/restore
         with self._sched_lock:
-            return self._schedule_backlog_locked(pods, state,
-                                                 gangs=gangs)
+            return self._schedule_locked(pods, state, gangs=gangs)
+
+    def _schedule_locked(self, pods, state, gangs=None):
+        if self._mesh_sched is not None:
+            return self._schedule_backlog_mesh(pods, state)
+        return self._schedule_backlog_locked(pods, state, gangs=gangs)
 
     def _schedule_backlog_locked(
         self, pods: Sequence[Pod], state: ClusterState,
@@ -519,6 +435,9 @@ class TPUScheduleAlgorithm:
         with trace_profile.phase_timer("encode"):
             reps, rep_idx, keys = self._dedup(pods)
             snap = batch = None
+            # the incremental view comes in its encoder's own bucket,
+            # a multiple of the mesh (`_new_encoder`)
+            bucket = 1
             if self._inc is not None:
                 def ls(l):
                     return l.list() if l is not None else ()
@@ -536,15 +455,15 @@ class TPUScheduleAlgorithm:
                 )
                 snap = enc.encode_nodes()
                 batch = enc.encode_pods()
+                # bucket the node axis for compile reuse (pow2, floor 64)
+                bucket = next_pow2(snap.num_nodes, 64)
             n_real = snap.num_nodes
             if n_real == 0:
                 return [None] * len(pods)
-            # bucket the node axis for compile reuse (pow2, floor 64),
             # then to a mesh multiple so the shard math sees the final N
             # here and node ids map back to THIS snapshot's names
-            n_dev = self._mesh_sched.mesh.devices.size
-            snap = _pad_snapshot(snap, next_pow2(n_real, 64))
-            snap = _pad_snapshot(snap, n_dev)
+            snap = _pad_snapshot(snap, bucket)
+            snap = _pad_snapshot(snap, self._mesh_sched.mesh.devices.size)
         chosen, _final, last = self._mesh_sched.schedule_backlog(
             snap, batch, rep_idx, last_node_index=self._last_node_index
         )

@@ -216,6 +216,37 @@ def _label_selector_key(ns: str, sel) -> tuple:
     )
 
 
+def selector_anchor(sel: labelpkg.Selector) -> Optional[Tuple[str, frozenset]]:
+    """(key, values) of the selector's first In requirement: labels it
+    matches carry that key with one of those values, so a lookup by
+    label pair finds every candidate. None where it has none (match-all,
+    Exists, NotIn ...): such a selector can match labels that share no
+    pair with it and has to meet them all."""
+    for r in sel.requirements:
+        if r.operator == labelpkg.IN:
+            return r.key, r.values
+    return None
+
+
+class ClassPairs:
+    """The live spread classes (`VocabBundle.classes` keys, a list that
+    only grows) by namespace and label pair: the classes a selector with
+    an anchor can match, without meeting every class."""
+
+    def __init__(self):
+        self.by_pair: Dict[tuple, List[int]] = {}
+        self._upto = 0
+
+    def extend(self, class_list: Sequence[tuple]) -> "ClassPairs":
+        for c in range(self._upto, len(class_list)):
+            ns, labels_fs, deleted = class_list[c]
+            if not deleted:
+                for k, v in labels_fs:
+                    self.by_pair.setdefault((ns, k, v), []).append(c)
+        self._upto = len(class_list)
+        return self
+
+
 class SpreadSelectors:
     """The spread listers' selectors (services, ReplicationControllers,
     replica sets), each built once and held under a key of what it was
@@ -232,7 +263,22 @@ class SpreadSelectors:
     def __init__(self):
         self.entries: Dict[tuple, labelpkg.Selector] = {}
         self._by_ns: Dict[str, Dict[tuple, labelpkg.Selector]] = {}
+        # the same entries for `selecting`: those with an anchor under
+        # (namespace, key, value) for each of its values, the others
+        # under their namespace; `_seq` keeps the order of `_by_ns`
+        self._by_pair: Dict[tuple, Dict[tuple, labelpkg.Selector]] = {}
+        self._loose: Dict[str, Dict[tuple, labelpkg.Selector]] = {}
+        self._seq: Dict[tuple, int] = {}
+        self._added = itertools.count()
         self._stamp: Optional[tuple] = None
+
+    def _places(self, k: tuple, built: labelpkg.Selector):
+        """The dicts of `_by_pair` / `_loose` that hold entry `k`."""
+        anchor = selector_anchor(built)
+        if anchor is None:
+            return [self._loose.setdefault(k[0], {})]
+        return [self._by_pair.setdefault((k[0], anchor[0], v), {})
+                for v in anchor[1]]
 
     def sync(self, services=(), controllers=(), replica_sets=()
              ) -> Tuple[List[tuple], List[tuple]]:
@@ -254,14 +300,20 @@ class SpreadSelectors:
         removed = [k for k in self.entries if k not in source]
         added = [k for k in source if k not in self.entries]
         for k in removed:
+            for place in self._places(k, self.entries[k]):
+                del place[k]
             del self.entries[k]
             del self._by_ns[k[0]][k]
+            del self._seq[k]
         for k in added:
             sel = source[k].spec.selector
             built = (labelpkg.selector_from_set(sel) if k[1] == 0
                      else label_selector_as_selector(sel))
             self.entries[k] = built
             self._by_ns.setdefault(k[0], {})[k] = built
+            for place in self._places(k, built):
+                place[k] = built
+            self._seq[k] = next(self._added)
         return added, removed
 
     def selecting(self, namespace: str, labels: Dict[str, str],
@@ -271,25 +323,53 @@ class SpreadSelectors:
         in_ns = self._by_ns.get(namespace)
         if not in_ns:
             return []
-        if among is None:
-            return [k for k, s in in_ns.items() if s.matches(labels)]
-        return [k for k in among
-                if k[0] == namespace and in_ns[k].matches(labels)]
+        if among is not None:
+            return [k for k in among
+                    if k[0] == namespace and in_ns[k].matches(labels)]
+        # an anchored entry can match only labels that carry one of its
+        # pairs: meet those, and the entries without an anchor
+        found = [k for k, s in self._loose.get(namespace, {}).items()
+                 if s.matches(labels)]
+        for key, value in labels.items():
+            for k, s in self._by_pair.get((namespace, key, value),
+                                          {}).items():
+                if s.matches(labels):
+                    found.append(k)
+        # (labels hold one value a key, so an entry anchored on several
+        # values is found once); in the order `_by_ns` lists them
+        found.sort(key=self._seq.__getitem__)
+        return found
 
 
 def spread_match_row(selectors: Sequence[labelpkg.Selector], namespace: str,
                      class_list: Sequence[tuple], out: np.ndarray,
-                     start: int = 0) -> None:
+                     pairs: ClassPairs, start: int = 0) -> None:
     """out[c] = 1 for each spread class c >= start (a
     `VocabBundle.classes` key) of live pods in `namespace` whose labels
     any of `selectors` matches: the pods SelectorSpreadPriority counts
-    for a pod these selectors select (selector_spreading.go:146)."""
-    for c in range(start, len(class_list)):
+    for a pod these selectors select (selector_spreading.go:146).
+    A selector with an anchor meets only the classes that carry one of
+    its pairs (`pairs`: the classes of `class_list` by label pair); one
+    without meets every class."""
+    loose, n = [], len(class_list)
+    for s in selectors:
+        anchor = selector_anchor(s)
+        if anchor is None:
+            loose.append(s)
+            continue
+        for value in anchor[1]:
+            for c in pairs.by_pair.get((namespace, anchor[0], value), ()):
+                if start <= c < n and not out[c] and \
+                        s.matches(dict(class_list[c][1])):
+                    out[c] = 1
+    if not loose:
+        return
+    for c in range(start, n):
         ns, labels_fs, deleted = class_list[c]
         if deleted or ns != namespace:
             continue
         lbls = dict(labels_fs)
-        for s in selectors:
+        for s in loose:
             if s.matches(lbls):
                 out[c] = 1
                 break
@@ -1000,6 +1080,7 @@ class SnapshotEncoder:
             **self.batch_fields(),
         )
         class_list = list(self.classes.ids.keys())
+        pairs = ClassPairs().extend(class_list)
         # the listers' selectors, built once for the batch
         spread = SpreadSelectors()
         spread.sync(self.state.services, self.state.controllers,
@@ -1111,7 +1192,7 @@ class SnapshotEncoder:
             b.has_selectors[i] = bool(selectors)
             if selectors:
                 spread_match_row(selectors, pod.namespace, class_list,
-                                 b.spread_match[i])
+                                 b.spread_match[i], pairs)
             b.class_id[i] = self.classes.get(self._class_key(pod))
             for c in pod.spec.containers:
                 iid = self.images.get(c.image, add=False)

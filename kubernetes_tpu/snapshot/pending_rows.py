@@ -56,6 +56,7 @@ from kubernetes_tpu.snapshot.encode import (
     SnapshotEncoder,
     SpreadSelectors,
     VocabBundle,
+    ClassPairs,
     spread_match_row,
 )
 from kubernetes_tpu.trace import profile as trace_profile
@@ -107,6 +108,7 @@ class PendingRows:
     def __init__(self, vocabs: VocabBundle):
         self.vocabs = vocabs
         self.selectors = SpreadSelectors()
+        self._pairs = ClassPairs()  # of vocabs.classes, which only grows
         self.hits = self.misses = self.resets = 0
         self._config = None
         self._taints = 0
@@ -181,7 +183,7 @@ class PendingRows:
         if sel:
             entries = self.selectors.entries
             spread_match_row([entries[k] for k in sel], self._ns[row],
-                             class_list, sm[row])
+                             class_list, sm[row], pairs=self._pairs)
         self._classes_done[row] = len(class_list)
 
     def _reselect(self, added: Sequence[tuple], removed: Sequence[tuple],
@@ -210,9 +212,10 @@ class PendingRows:
                 self._respread(row, class_list)
             elif new:
                 self._arrays["has_selectors"][row] = True
+                done = int(self._classes_done[row])
                 spread_match_row(
                     [entries[k] for k in new], self._ns[row],
-                    class_list[: int(self._classes_done[row])], sm[row])
+                    class_list[:done], sm[row][:done], pairs=self._pairs)
 
     # -- a wave's batch --------------------------------------------------------
 
@@ -232,6 +235,7 @@ class PendingRows:
             self._config, self._taints = enc.config, len(v.taints)
         class_list = list(v.classes.ids)
         n_classes = len(class_list)
+        self._pairs.extend(class_list)
         added, removed = self.selectors.sync(
             services, controllers, replica_sets)
         if self._n and (added or removed):
@@ -271,7 +275,8 @@ class PendingRows:
                 if sel:
                     spread_match_row(
                         [entries[k] for k in sel], self._ns[row], class_list,
-                        sm[row], start=int(self._classes_done[row]))
+                        sm[row], start=int(self._classes_done[row]),
+                        pairs=self._pairs)
                 self._classes_done[row] = n_classes
         dims = dict(enc.widths)
         asks = self._asks[at].max(axis=0, initial=1)

@@ -150,14 +150,22 @@ def test_shipment_unpack_compiles_for_v5e_in_seconds(pods, controllers,
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * buf.nbytes
 
 
-def test_mesh_fold_compiles_for_v5e_2x2(topo, one_chip, monkeypatch):
-    """The donated commit fold of the mesh driver at the --mesh phase's
-    size (20,000 nodes -> the 32,768 bucket, 8,192 per shard), for the
-    described 2x2. Guards mesh._carry_out_shardings: with the result
-    shardings of the carry's zero-size int64 leaves declared, libtpu
-    0.0.34 does not raise — it ABORTS the process
-    (import_shardy_attrs.cc: funcResultSharding.getNumOperands() == 1),
-    so a regression here shows as a crashed test worker."""
+#: the sharded driver's programs as analysis/programs registers them
+#: (jit_mesh_scan, jit_mesh_probe, ... in a trace), served by the
+#: benchmark's mesh-20k.fill cell and chip_smoke.py --mesh
+MESH_SERVED = ("mesh_scan", "mesh_probe", "mesh_group_probe",
+               "mesh_apply", "mesh_apply_group")
+
+#: the two whose compile at the 32,768 bucket takes over 20 s here
+MESH_HEAVY = {"mesh_scan", "mesh_probe"}
+
+
+@pytest.fixture(scope="module")
+def mesh_registry(topo, one_chip):
+    """name -> ProgramSpec of the mesh driver at the --mesh phase's
+    size (20,000 nodes -> the 32,768 bucket, 8,192 per shard), on a
+    mesh of the described 2x2. The benchmark's cell serves the same
+    programs at 1,280 slots a shard (5,000 nodes in 5,120 slots)."""
     import jax
 
     from kubernetes_tpu.analysis import programs
@@ -166,18 +174,37 @@ def test_mesh_fold_compiles_for_v5e_2x2(topo, one_chip, monkeypatch):
     # described chips (steered here, in the test — not an option of
     # the program). The resident-scatter entry places real arrays,
     # which a described device cannot hold; it is not under test.
-    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
-    monkeypatch.setattr(
-        programs, "_resident_scatter_program",
-        lambda *a, **k: programs.ProgramSpec(name="-", fn=None, args=()))
-    specs = {s.name: s for s in programs.build_programs(
-        include_mesh=True, num_nodes=20_000, run_length=500)}
-    spec = specs["mesh_apply"]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+        patch.setattr(
+            programs, "_resident_scatter_program",
+            lambda *a, **k: programs.ProgramSpec(name="-", fn=None,
+                                                 args=()))
+        return {s.name: s for s in programs.build_programs(
+            include_mesh=True, num_nodes=20_000, run_length=500)}
+
+
+@pytest.mark.parametrize("name", [
+    pytest.param(n, marks=[pytest.mark.slow] if n in MESH_HEAVY else [])
+    for n in MESH_SERVED])
+def test_mesh_program_compiles_for_v5e_2x2(name, mesh_registry):
+    """Each program of the mesh driver, for the described 2x2. The
+    donated folds guard mesh._carry_out_shardings: with the result
+    shardings of the carry's zero-size int64 leaves declared, libtpu
+    0.0.34 does not raise — it ABORTS the process
+    (import_shardy_attrs.cc: funcResultSharding.getNumOperands() == 1),
+    so a regression here shows as a crashed test worker."""
+    import jax
+
+    spec = mesh_registry[name]
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype),
         spec.args)
     compiled = spec.fn.lower(*shapes).compile()
     mem = compiled.memory_analysis()
-    # the fold mutates the resident carry in place: every byte of the
-    # node-sharded carry aliases, per device
-    assert mem.alias_size_in_bytes > 6 * 8 * (32_768 // 4)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 1 << 30
+    assert "jit_" + name in compiled.as_text()[:400]
+    if name in ("mesh_apply", "mesh_apply_group"):
+        # the fold mutates the resident carry in place: every byte of
+        # the node-sharded carry aliases, per device
+        assert mem.alias_size_in_bytes > 6 * 8 * (32_768 // 4)

@@ -313,6 +313,30 @@ def test_pod_on_unsynced_node_invalidates_name_order():
     assert "name_desc_order" not in keep
 
 
+@pytest.mark.parametrize("step,nodes,slots", [
+    (None, 70, 128), (32, 70, 96), (32, 96, 96), (32, 97, 128),
+])
+def test_node_axis_grows_by_its_step(step, nodes, slots):
+    """With a `slot_step` (the mesh driver's: a multiple of its
+    devices) the node axis grows by that step and the view's node axis
+    is a multiple of it; without one it doubles. The nodes' slots and
+    what the view says of them are the same either way."""
+    cache = SchedulerCache(clock=FakeClock())
+    inc = IncrementalEncoder(slot_step=step)
+    cache.add_listener(inc.on_cache_event)
+    rng = random.Random(step or 1)
+    for i in range(nodes):
+        cache.add_node(rand_node(rng, f"node-{i:03d}"))
+    pending = [Pod(metadata=ObjectMeta(name="pend", labels={"app": "web"}),
+                   spec=PodSpec(containers=[
+                       Container(requests={"cpu": "100m"})]))]
+    snap, _batch, _keep = inc.wave_view(pending)
+    assert snap.num_nodes == slots == len(snap.node_names)
+    assert snap.node_names[:nodes] == [f"node-{i:03d}"
+                                       for i in range(nodes)]
+    assert not any(snap.node_names[nodes:])
+
+
 def test_daemon_warmup_compiles_incremental_shapes():
     """warmup() in daemon mode must compile the programs the incremental
     wave path will actually run — the full encoder's static shapes differ

@@ -332,10 +332,12 @@ def test_selectors_are_matched_once_per_template_not_once_per_wave(monkeypatch):
 
     work, hits, misses = wave()
     assert (hits, misses) == (0, T)
-    # per template: each controller's selector once, then its own one
-    # selector against each class; nothing per (template, controller) pair
-    # beyond that
-    assert T * T <= work <= T * (T + T)
+    # per template: the controllers whose selector carries one of its
+    # label pairs (its own), then that selector against the classes that
+    # carry the pair (its own): nothing per (template, controller) pair,
+    # nor per (template, class) pair, since the selectors and the
+    # classes are found by label pair
+    assert T <= work <= 3 * T
     for _ in range(2):
         assert wave() == (0, T, 0)  # no selector evaluated at all
     # one more controller, selecting ten of the templates: its selector
@@ -352,3 +354,80 @@ def test_selectors_are_matched_once_per_template_not_once_per_wave(monkeypatch):
     moved = {k: v - shown[k]
              for k, v in profile.pending_row_totals().items()}
     assert moved == {"row_hits": 4 * T, "row_misses": T, "row_resets": 0}
+
+
+@pytest.mark.parametrize("seed", [3, 2 ** 31 + 4, 5])
+def test_lookups_by_label_pair_find_what_meeting_every_entry_finds(seed):
+    """SpreadSelectors.selecting and spread_match_row look selectors
+    and classes up by label pair; the plain definitions, written out
+    here, meet every entry and every class. Same answers, in the same order, on
+    selectors of every kind: sets, match-all, In with several values,
+    NotIn, Exists, and the same labels in another namespace."""
+    from kubernetes_tpu.snapshot.encode import (
+        ClassPairs,
+        SpreadSelectors,
+        spread_match_row,
+    )
+
+    rng = random.Random(seed)
+    vals = ["a", "b", "c", "d"]
+
+    def labels():
+        return {k: rng.choice(vals) for k in ("app", "tier", "rel")
+                if rng.random() < 0.7}
+
+    def expression():
+        op = rng.choice(["In", "In", "NotIn", "Exists", "DoesNotExist"])
+        return LabelSelectorRequirement(
+            key=rng.choice(["app", "tier", "rel"]), operator=op,
+            values=tuple(rng.sample(vals, rng.randint(1, 3)))
+            if op in ("In", "NotIn") else ())
+
+    controllers = [ReplicationController(
+        metadata=ObjectMeta(name=f"rc{i}",
+                            namespace=rng.choice(["default", "other"])),
+        spec=ReplicationControllerSpec(selector=labels()))
+        for i in range(40)]
+    replica_sets = [ReplicaSet(
+        metadata=ObjectMeta(name=f"rs{i}",
+                            namespace=rng.choice(["default", "other"])),
+        spec=ReplicaSetSpec(selector=LabelSelector(
+            match_labels=labels() if rng.random() < 0.5 else {},
+            match_expressions=tuple(expression()
+                                    for _ in range(rng.randint(0, 2))))))
+        for i in range(40)]
+    spread = SpreadSelectors()
+    spread.sync((), controllers, replica_sets)
+    class_list = [(rng.choice(["default", "other"]),
+                   frozenset(labels().items()), rng.random() < 0.1)
+                  for _ in range(120)]
+    pairs = ClassPairs().extend(class_list[:70]).extend(class_list)
+    for step in range(3):
+        for _ in range(60):
+            ns, lbls = rng.choice(["default", "other", "none"]), labels()
+            plain = [k for k, s in spread._by_ns.get(ns, {}).items()
+                     if s.matches(lbls)]
+            assert spread.selecting(ns, lbls) == plain
+            selectors = [spread.entries[k] for k in plain]
+            start = rng.choice([0, 0, 50])
+            want = np.zeros(len(class_list), np.int64)
+            got = want.copy()
+            for c in range(start, len(class_list)):
+                c_ns, c_labels, deleted = class_list[c]
+                want[c] = not deleted and c_ns == ns and any(
+                    s.matches(dict(c_labels)) for s in selectors)
+            spread_match_row(selectors, ns, class_list, got, pairs,
+                             start=start)
+            assert np.array_equal(got, want)
+            # a shorter class list bounds the columns written
+            short = np.zeros(60, np.int64)
+            spread_match_row(selectors, ns, class_list[:60], short, pairs,
+                             start=start)
+            assert np.array_equal(short, want[:60])
+        # entries go and come: the lookups follow
+        gone = rng.sample(range(40), 10)
+        controllers = [c for i, c in enumerate(controllers)
+                       if i not in gone]
+        replica_sets = replica_sets[step * 5:]
+        added, removed = spread.sync((), controllers, replica_sets)
+        assert removed and not added

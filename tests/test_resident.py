@@ -202,6 +202,39 @@ def test_node_update_scatter_matches_rebuild(mesh):
         assert np.array_equal(np.asarray(a), np.asarray(b)), f
 
 
+@pytest.mark.parametrize("changed,scatters,replaces", [
+    (60, 1, 0),   # the 64 bucket: under a quarter of 320 slots
+    (70, 0, 1),   # under a quarter too, but its bucket of 128 is not
+    (90, 0, 1),   # over a quarter
+])
+def test_row_bucket_past_the_scatter_share_re_places(
+        mesh, changed, scatters, replaces):
+    """On a node axis that is no power of two (the mesh driver's
+    encoder grows it by a step) a count of changed rows under the
+    scatter share can pad to a bucket over it: one that no warm-up
+    compiles, since a wave of that many pods re-places. Such a sync
+    re-places the tables, as one over the share does."""
+    pods = _pods(1)
+    m_cfg = MeshWaveScheduler(mesh).config
+
+    def snap(cpu_of_first):
+        nodes = _nodes(320)
+        for node in nodes[:changed]:
+            node.status.allocatable["cpu"] = cpu_of_first
+        enc = SnapshotEncoder(ClusterState.build(nodes), pods)
+        return enc.encode_nodes()
+
+    res = ResidentClusterState(mesh)
+    res.sync(m_cfg, snap("4"), 0)
+    before = dict(res.stats)
+    static_s, _carry = res.sync(m_cfg, snap("8"), 0)
+    assert res.stats["rebuilds"] == before["rebuilds"] == 1
+    assert res.stats["scatters"] - before["scatters"] == scatters
+    assert res.stats["replaces"] - before["replaces"] == replaces
+    want = np.where(np.arange(320) < changed, 8000, 4000)
+    assert np.array_equal(np.asarray(static_s["alloc_mcpu"]), want)
+
+
 def test_node_remove_scatter_matches_rebuild_and_decisions(mesh):
     """Node removal (a live node becomes a never-fit padded slot):
     scatter-synced resident state schedules identically to single-chip

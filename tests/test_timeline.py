@@ -24,19 +24,19 @@ from kubernetes_tpu.trace import spans
 from kubernetes_tpu.trace.spans import TraceBuffer
 
 
-def _pod(name):
+def _pod(name, cpu="100m"):
     return t.Pod(
         metadata=t.ObjectMeta(name=name, namespace="default"),
         spec=t.PodSpec(containers=[
-            t.Container(name="c", requests={"cpu": "100m"})]),
+            t.Container(name="c", requests={"cpu": cpu})]),
     )
 
 
-def _node(name):
+def _node(name, cpu="4"):
     return t.Node(
         metadata=t.ObjectMeta(name=name),
         status=t.NodeStatus(
-            allocatable={"cpu": "4", "memory": "32Gi", "pods": "110"},
+            allocatable={"cpu": cpu, "memory": "32Gi", "pods": "110"},
             conditions=[t.NodeCondition("Ready", "True")]),
     )
 
@@ -415,41 +415,92 @@ def test_debug_profile_writes_a_trace_with_the_phases_in_it(annotations_off):
 
 def _jit_sites():
     """(file, line, what is jitted) for every jax.jit call and @jax.jit
-    decorator under models/."""
+    decorator under models/ and parallel/."""
     import ast
     import os
 
     import kubernetes_tpu.models as models
+    import kubernetes_tpu.parallel as parallel
 
-    root = os.path.dirname(models.__file__)
     sites = []
-    for fname in sorted(os.listdir(root)):
-        if not fname.endswith(".py"):
-            continue
-        tree = ast.parse(open(os.path.join(root, fname)).read())
-        for node in ast.walk(tree):
-            if isinstance(node, ast.Call) and \
-                    ast.unparse(node.func) == "jax.jit":
-                sites.append((fname, node.lineno, node.args[0]))
-            elif isinstance(node, ast.FunctionDef) and any(
-                    ast.unparse(d) == "jax.jit"
-                    for d in node.decorator_list):
-                sites.append((fname, node.lineno,
-                              ast.Name(id=node.name)))
+    for package in (models, parallel):
+        root = os.path.dirname(package.__file__)
+        for fname in sorted(os.listdir(root)):
+            if not fname.endswith(".py"):
+                continue
+            where = f"{os.path.basename(root)}/{fname}"
+            tree = ast.parse(open(os.path.join(root, fname)).read())
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Call) and \
+                        ast.unparse(node.func) == "jax.jit":
+                    sites.append((where, node.lineno, node.args[0]))
+                elif isinstance(node, ast.FunctionDef) and any(
+                        ast.unparse(d) == "jax.jit"
+                        for d in node.decorator_list):
+                    sites.append((where, node.lineno,
+                                  ast.Name(id=node.name)))
     return sites
 
 
-def test_every_jit_site_under_models_jits_a_named_function():
+def test_every_jit_site_under_models_and_parallel_jits_a_named_function():
     import ast
 
     sites = _jit_sites()
-    assert len(sites) >= 10, sites
+    assert len(sites) >= 13, sites
+    assert sum(1 for fname, _l, _a in sites
+               if fname.startswith("parallel/")) >= 3, sites
     for fname, line, arg in sites:
-        where = f"models/{fname}:{line}"
+        where = f"{fname}:{line}"
         # a lambda reads jit__lambda and a functools.partial
         # jit__unknown in a trace: neither says which program it is
         assert isinstance(arg, ast.Name), (where, ast.unparse(arg))
         assert arg.id not in ("run", "fn", "f"), where
+
+
+def test_the_mesh_drivers_programs_carry_their_own_names():
+    """Every program the sharded driver builds says which it is in a
+    trace and among the compiles: jit_mesh_scan, jit_mesh_group_probe,
+    jit_mesh_apply_group, jit_mesh_probe, jit_mesh_apply and the
+    resident state's jit_mesh_scatter."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    profile.install_compile_listener()
+    t_before = time.time()
+    algo = TPUScheduleAlgorithm(
+        mesh=Mesh(np.array(jax.devices()[:2]), ("nodes",)))
+    state = ClusterState.build([_node(f"tm{i:03d}") for i in range(200)])
+    wave = algo._wave
+    # a run (the grouped header probe and fold), then two lone pods
+    # (the sharded scan)
+    assert all(algo.schedule_backlog(
+        [_pod(f"tmp{i}") for i in range(48)]
+        + [_pod("tm-a", cpu="150m"), _pod("tm-b", cpu="250m")], state))
+    assert wave.dispatches == {"group_probe": 1, "apply": 1, "scan": 1}
+    # the state never learned of those 50 binds: the next view differs
+    # from the mirrors on their nodes' rows, under a quarter of the
+    # 256 bucket, and the resident row scatter ships them
+    assert all(algo.schedule_backlog([_pod("tm-c", cpu="150m")], state))
+    assert wave.resident.stats["scatters"] >= 1
+    # and the per-run probe and fold, which the resident modes bypass
+    wave.reuse_default = "reship"
+    assert all(algo.schedule_backlog(
+        [_pod(f"tmq{i}") for i in range(48)], state))
+    assert wave.dispatches == {"probe": 1, "apply": 1}
+    assert wave.stats["waves"] == 3
+    assert wave.stats["dispatches"] == 6 == sum(
+        wave.stats["dispatches_by_kind"].values())
+    built = {c["program"] for c in profile.recent_compiles()
+             if c["at"] >= t_before}
+    for name in ("mesh_scan", "mesh_group_probe", "mesh_apply_group",
+                 "mesh_scatter", "mesh_probe", "mesh_apply"):
+        assert f"jit({name})" in built, (name, sorted(built))
+    assert not any("unnamed" in p or "unknown" in p or "lambda" in p
+                   for p in built), sorted(built)
 
 
 def test_programs_a_backlog_builds_carry_their_own_names():
