@@ -414,6 +414,19 @@ def pick_j(config: SchedulerConfig, max_j: int, snap: ClusterSnapshot,
 #: a grouped device replay
 PATHS = ("scan", "single", "group_host", "group_device")
 _SCAN, _SINGLE, _GROUP_HOST, _GROUP_DEVICE = range(len(PATHS))
+#: what `stats` counts of the grouped header probe, in both drivers
+GROUP_COUNTERS = ("group_runs", "group_d2h_bytes", "group_reprobes")
+
+
+def count_group(stats: dict, counted: dict) -> None:
+    """Some of `GROUP_COUNTERS` into a driver's cumulative `stats`, and
+    into the process-wide totals on /debug/traces."""
+    from kubernetes_tpu.trace.profile import count_wave_group
+
+    for key, n in counted.items():
+        stats[key] += n
+    count_wave_group(counted)
+
 
 #: pick-scan length floors of the zoned device replay: one run per
 #: dispatch pads to 256; the grouped form runs K steps PER RUN, so its
@@ -650,6 +663,13 @@ class WaveScheduler:
             "dispatches_by_kind": {},
             "pods_by_path": dict.fromkeys(PATHS, 0),
             "pods_unplaced": 0,
+            # the grouped header probe replayed on the host
+            # (`run_group_host`), all waves: the runs that went through
+            # it, the bytes its probes fetched from the device (every
+            # run slot's header rows and the resource block), and the
+            # groups that stopped before their last run and sent one to
+            # `run_single`, which costs a probe of its own
+            **dict.fromkeys(GROUP_COUNTERS, 0),
         }
 
     # fraction of changed rows above which a scatter-row update loses
@@ -1291,6 +1311,9 @@ class WaveScheduler:
                     G_bucket, glayout, self._apply_fn,
                     self._apply_group_fn,
                 )
+            count_group(self.stats, {
+                "group_runs": G,
+                "group_d2h_bytes": headers.nbytes + usage.nbytes})
             with phase_timer("replay"):
                 counts_mat, n_full, partial_done, L_host = \
                     host_group_replay(
@@ -1307,6 +1330,7 @@ class WaveScheduler:
                 fold.append(("group", gbuf, glayout, cm))
             if n_full == G:
                 return carry, G, None
+            count_group(self.stats, {"group_reprobes": 1})
             return carry, n_full, (n_full, partial_done)
 
         def run_group_device(carry, group):

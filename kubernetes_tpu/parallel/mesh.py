@@ -1138,11 +1138,12 @@ class MeshWaveScheduler:
         # numbers are diffs), plus what only a mesh has: the picks that
         # landed on each shard's nodes (they add up to the pods placed)
         # and the resident state's shipped bytes
-        from kubernetes_tpu.models.wave import PATHS
+        from kubernetes_tpu.models.wave import GROUP_COUNTERS, PATHS
 
         self.stats = {
             "waves": 0, "dispatches": 0, "dispatches_by_kind": {},
             "pods_by_path": dict.fromkeys(PATHS, 0), "pods_unplaced": 0,
+            **dict.fromkeys(GROUP_COUNTERS, 0),
             "picks_by_shard": [0] * int(mesh.devices.size),
             "h2d_bytes_total": 0,
         }
@@ -1340,6 +1341,7 @@ class MeshWaveScheduler:
             _host_group_cap,
             _permute_tables,
             classify_runs,
+            count_group,
             gather_batch,
             group_buffer,
             host_group_replay,
@@ -1532,6 +1534,10 @@ class MeshWaveScheduler:
                 static, carry, glayout, gbuf, N, n_per_shard,
                 num_zones, num_values, G_bucket,
             )
+            # (the usage is the resident state's host mirror: only the
+            # headers cross from the device)
+            count_group(self.stats, {"group_runs": G,
+                                     "group_d2h_bytes": headers.nbytes})
             with phase_timer("replay"):
                 usage = self.resident.usage()
                 counts_mat, n_full, partial_done, L_host = \
@@ -1560,6 +1566,7 @@ class MeshWaveScheduler:
                                                   counts_mat[g])
             if n_full == G:
                 return carry, G, None
+            count_group(self.stats, {"group_reprobes": 1})
             return carry, n_full, (n_full, partial_done)
 
         host_cap = _host_group_cap(N)

@@ -181,7 +181,9 @@ class TPUScheduleAlgorithm:
         the warm-up's encoder never hears of a backlog's picks, so the
         nodes the backlog before filled differ from the resident
         state's mirrors, and the row scatter that ships a wave's churn
-        compiles here at every row bucket up to the wave cap."""
+        compiles here at every row bucket up to the wave cap. The
+        single-chip driver's own row scatter is warmed apart
+        (`_warm_row_scatter`)."""
         from kubernetes_tpu.api.types import (
             Container,
             Node,
@@ -268,8 +270,54 @@ class TPUScheduleAlgorithm:
                 for k in (2, bucket // 2):
                     warm([pod(f"wsb{k}-{i}", f"{200 + i}m")
                           for i in range(k)])
+            if bound and self._mesh_sched is None and self._inc is not None:
+                def bind(name, node):
+                    p = pod(name, "100m")
+                    p.spec.node_name = node.metadata.name
+                    return p
+
+                self._warm_row_scatter(
+                    [pod(f"wr{i}", "100m")
+                     for i in range(max(self._wave.min_run, 2))],
+                    state, nodes, bound, bind)
         if phase in ("all", "scan"):
             warm([pod("w-scan", "200m"), pod("w-scan2", "300m")])
+
+    def _warm_row_scatter(self, backlog, state, nodes, bound, bind) -> None:
+        """The single-chip driver ships a table of which few rows
+        changed since the wave before as a row scatter, a program per
+        power of two of rows (WaveScheduler._to_dev_many). A wave's
+        churn touches most nodes, so a storm meets the small buckets
+        only in a lull, and compiled them there (two programs inside a
+        measured window in one full-size rehearsal of five; PERF.md,
+        PR 39). Here one encoder serves a row of small waves, and
+        between them hears of 1, 2, 4, ... newly bound pods of a
+        controller on as many nodes, up to the share of the node slots
+        at which the driver ships the table whole (`bind(name, node)`
+        makes one): the spread counts' rows change, and every bucket is
+        met."""
+        from kubernetes_tpu.models.wave import WaveScheduler
+
+        inc = self._warm_encoder(nodes, bound)
+        self._warm_one(backlog, state, nodes, bound, [inc])
+        most = int(WaveScheduler.SCATTER_FRAC * len(inc.node_names))
+        rows, serial = 1, 0
+        while rows <= min(most, len(nodes)):
+            for node in nodes[:rows]:
+                inc.on_cache_event("pod_add", bind(f"wrow-{serial}", node))
+                serial += 1
+            self._warm_one(backlog, state, nodes, bound, [inc])
+            rows *= 2
+
+    def _warm_encoder(self, nodes, bound):
+        """A throwaway encoder that holds the warm-up's synthetic
+        cluster, fed through the cache-event seam as the daemon's is."""
+        inc = self._new_encoder()
+        for n in nodes:
+            inc.on_cache_event("node_set", n)
+        for p in bound:
+            inc.on_cache_event("pod_add", p)
+        return inc
 
     def _warm_one(self, backlog, state, nodes, bound, shared) -> None:
         with self._sched_lock:
@@ -294,11 +342,7 @@ class TPUScheduleAlgorithm:
                     # its own, as before.
                     inc = shared[0] if shared else None
                     if inc is None:
-                        inc = self._new_encoder()
-                        for n in nodes:
-                            inc.on_cache_event("node_set", n)
-                        for p in bound:
-                            inc.on_cache_event("pod_add", p)
+                        inc = self._warm_encoder(nodes, bound)
                         if self._mesh_sched is not None:
                             shared.append(inc)
                     self._inc = inc

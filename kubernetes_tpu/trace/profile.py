@@ -295,10 +295,13 @@ def pending_row_totals() -> Dict[str, int]:
 _wave_lock = threading.Lock()
 #: what the wave driver (models/wave.WaveScheduler.schedule_backlog) did
 #: in this process, all waves: pods decided by each path, device
-#: programs launched by kind, pods that fitted nowhere; served on
+#: programs launched by kind, pods that fitted nowhere, and what the
+#: grouped header probe did (models/wave.GROUP_COUNTERS); served on
 #: /debug/traces as "wave"
 _WAVE: Dict[str, Any] = {"waves": 0, "pods_by_path": {},
-                         "dispatches_by_kind": {}, "pods_unplaced": 0}
+                         "dispatches_by_kind": {}, "pods_unplaced": 0,
+                         "group_runs": 0, "group_d2h_bytes": 0,
+                         "group_reprobes": 0}
 
 
 def count_wave(pods_by_path: Dict[str, int], dispatches: Dict[str, int],
@@ -314,6 +317,14 @@ def count_wave(pods_by_path: Dict[str, int], dispatches: Dict[str, int],
             tally = _WAVE[key]
             for k, n in add.items():
                 tally[k] = tally.get(k, 0) + n
+
+
+def count_wave_group(counted: Dict[str, int]) -> None:
+    """A grouped header probe was replayed on the host: its runs, the
+    bytes it fetched, whether it stopped early (`group_*` of _WAVE)."""
+    with _wave_lock:
+        for k, n in counted.items():
+            _WAVE[k] += n
 
 
 def wave_totals() -> Dict[str, Any]:

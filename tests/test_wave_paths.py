@@ -1,9 +1,12 @@
 """Which path of the wave driver decided a pod, counted: the tallies
 WaveScheduler.stats["pods_by_path"] / ["dispatches_by_kind"] /
 ["pods_unplaced"] add up to the pods handed in, wave after wave, and
-/debug/traces shows the same numbers. And the scan path picks as the
-serial oracle does where selector rows are all distinct, multi-hot, or
-followed by a pod that fits nowhere and by padding."""
+/debug/traces shows the same numbers. A group of runs whose templates
+commit different requests goes through one grouped header probe and
+picks as the serial oracle does, and the `group_*` counters say so on
+both drivers. And the scan path picks as the serial oracle does where
+selector rows are all distinct, multi-hot, or followed by a pod that
+fits nowhere and by padding."""
 
 import numpy as np
 import pytest
@@ -147,6 +150,97 @@ def test_debug_traces_shows_the_wave_totals():
     assert shown == profile.wave_totals()
     assert {"waves", "pods_by_path", "dispatches_by_kind",
             "pods_unplaced"} <= set(shown)
+
+
+# -- a group of runs with distinct commit vectors -----------------------------
+
+
+def _shaped_rows(controllers, replicas):
+    """A controller's replicas in a row, each controller's pods asking
+    for resources of their own (benchmark/configs/hetero-1k.json's
+    formula)."""
+    return [Pod(
+        metadata=ObjectMeta(name=f"rc{t}-{i:04d}", labels={"rc": f"rc-{t}"}),
+        spec=PodSpec(containers=[Container(requests={
+            "cpu": f"{50 + (t % 8) * 25}m",
+            "memory": f"{100 + (t % 5) * 100}Mi"})]))
+        for t in range(controllers) for i in range(replicas)]
+
+
+GROUP_CASES = {
+    # name: (nodes, pods a node holds, controllers, replicas a run)
+    "roomy": (30, "110", 12, 40),
+    # 9 nodes of 20 pods hold 180 of the 200: the last runs meet nodes
+    # that PodFitsResources filters, and 20 pods fit nowhere
+    "full": (9, "20", 5, 40),
+    # more runs than eight: sixteen run slots in the one probe
+    "many-runs": (20, "110", 11, 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUP_CASES))
+def test_a_group_of_distinct_commit_vectors_picks_as_the_serial_oracle(case):
+    from kubernetes_tpu.models.probe import N_STK_ROWS
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    nodes, pods_a_node, controllers, replicas = GROUP_CASES[case]
+    backlog = _shaped_rows(controllers, replicas)
+    state = ClusterState.build(_nodes(nodes, "", pods=pods_a_node),
+                               controllers=_controllers(controllers))
+    want = _oracle(state, backlog)
+    algo = TPUScheduleAlgorithm()
+    shown_before = profile.wave_totals()
+    got = algo.schedule_backlog(backlog, state)
+    assert got == want
+    assert (None in got) == (case == "full")
+    stats = algo._wave.stats
+    assert stats["pods_by_path"]["group_host"] \
+        + stats["pods_by_path"]["single"] == len(backlog)
+    # one probe for the whole group: every run's header rows and the
+    # resource block's six, 8 bytes a node slot, run slots by power of
+    # two from eight
+    by_kind = stats["dispatches_by_kind"]
+    slots = 8 if controllers <= 8 else 16
+    assert by_kind["group_probe"] == 1
+    assert stats["group_runs"] == controllers
+    assert stats["group_d2h_bytes"] == (slots * N_STK_ROWS + 6) * 64 * 8
+    # a group that stops early hands its run to a probe of its own
+    assert stats["group_reprobes"] == by_kind.get("probe", 0)
+    assert stats["pods_by_path"]["single"] == 0 or stats["group_reprobes"]
+    shown = profile.wave_totals()
+    for key in ("group_runs", "group_d2h_bytes", "group_reprobes"):
+        assert shown[key] - shown_before[key] == stats[key]
+
+
+def test_the_mesh_driver_keeps_the_same_group_counters():
+    import jax
+    from jax.sharding import Mesh
+
+    from kubernetes_tpu.models.wave import GROUP_COUNTERS, WaveScheduler
+    from kubernetes_tpu.parallel.mesh import MeshWaveScheduler
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("nodes",))
+    on_mesh, on_chip = MeshWaveScheduler(mesh).stats, WaveScheduler().stats
+    assert GROUP_COUNTERS == ("group_runs", "group_d2h_bytes",
+                              "group_reprobes")
+    for key in GROUP_COUNTERS:
+        assert on_mesh[key] == on_chip[key] == 0
+    assert set(on_chip["pods_by_path"]) == set(on_mesh["pods_by_path"])
+    # and counts in them what its own grouped header probe does (the
+    # usage comes from the resident state's mirror: headers only)
+    from kubernetes_tpu.models.probe import N_STK_ROWS
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    backlog = _shaped_rows(4, 40)
+    state = ClusterState.build(_nodes(30, ""), controllers=_controllers(4))
+    algo = TPUScheduleAlgorithm(mesh=mesh)
+    assert algo.schedule_backlog(backlog, state) == _oracle(state, backlog)
+    stats = algo._wave.stats
+    assert stats["pods_by_path"]["group_host"] == 160
+    assert stats["group_runs"] == 4 and stats["group_reprobes"] == 0
+    assert stats["group_d2h_bytes"] == 4 * N_STK_ROWS * 64 * 8
 
 
 # -- the scan path against the serial oracle ---------------------------------
