@@ -356,10 +356,11 @@ def build_programs(include_mesh: bool = True, num_nodes: int = 13,
               jnp.asarray(np.ones(Gz_bucket, bool)),
               jnp.asarray(np.full(Gz_bucket, 32, np.int64)),
               jnp.asarray(np.full(Gz_bucket, Kg, np.int32)),
-              np.int64(0)),
+              np.int32(Gz), np.int64(0)),
         allow_f64=True,
         carry_out_leaves=carry_leaves,
-        expected_host_leaves=3,  # chosen[G,K], n_done[G], L
+        # chosen[G,K], n_done[G], L, the loops' own counters [2]
+        expected_host_leaves=4,
         notes="grouped zoned device replay: G runs, one dispatch",
     ))
 

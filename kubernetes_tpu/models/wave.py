@@ -416,11 +416,17 @@ PATHS = ("scan", "single", "group_host", "group_device")
 _SCAN, _SINGLE, _GROUP_HOST, _GROUP_DEVICE = range(len(PATHS))
 #: what `stats` counts of the grouped header probe, in both drivers
 GROUP_COUNTERS = ("group_runs", "group_d2h_bytes", "group_reprobes")
+#: what `stats` counts of the grouped device replay (the single-chip
+#: driver's alone: the mesh has none): the pick steps and the run-slot
+#: iterations `jit_zreplay_group`'s two loops ran, by the program's own
+#: counters, and the pods it placed
+ZREPLAY_COUNTERS = ("zreplay_steps", "zreplay_slots", "zreplay_picks")
 
 
 def count_group(stats: dict, counted: dict) -> None:
-    """Some of `GROUP_COUNTERS` into a driver's cumulative `stats`, and
-    into the process-wide totals on /debug/traces."""
+    """Some of `GROUP_COUNTERS` or `ZREPLAY_COUNTERS` into a driver's
+    cumulative `stats`, and into the process-wide totals on
+    /debug/traces."""
     from kubernetes_tpu.trace.profile import count_wave_group
 
     for key, n in counted.items():
@@ -428,15 +434,16 @@ def count_group(stats: dict, counted: dict) -> None:
     count_wave_group(counted)
 
 
-#: pick-scan length floors of the zoned device replay: one run per
-#: dispatch pads to 256; the grouped form runs K steps PER RUN, so its
-#: padding costs G times over and the floor is lower
+#: pick-buffer length floors of the zoned device replay: one run per
+#: dispatch pads to 256; the grouped form keeps K picks PER RUN SLOT, so
+#: its padding costs G times over and the floor is lower. The buckets
+#: bound the compiled shapes; the pick loop ends at a run's real length
 ZREPLAY_K_FLOOR = 256
 ZREPLAY_GROUP_K_FLOOR = 64
 
 
 def replay_k_bucket(length: int, floor: int) -> int:
-    """The compiled pick-scan length for a device-replayed run (or a
+    """The compiled pick-buffer length for a device-replayed run (or a
     group's longest run) of `length` pods."""
     return next_pow2(min(length, 1 << 16), floor=floor)
 
@@ -670,6 +677,8 @@ class WaveScheduler:
             # groups that stopped before their last run and sent one to
             # `run_single`, which costs a probe of its own
             **dict.fromkeys(GROUP_COUNTERS, 0),
+            # the grouped device replay (`run_group_device`), all waves
+            **dict.fromkeys(ZREPLAY_COUNTERS, 0),
         }
 
     # fraction of changed rows above which a scatter-row update loses
@@ -1335,7 +1344,7 @@ class WaveScheduler:
 
         def run_group_device(carry, group):
             """K zoned-spread runs, ONE fused device dispatch: probe +
-            pick scan + commit fold per run inside one outer lax.scan
+            pick loop + commit fold per run inside one outer loop
             (models/zreplay.run_group), carry threaded run to run."""
             nonlocal L_host
             from kubernetes_tpu.models.zreplay import ZReplay
@@ -1372,11 +1381,15 @@ class WaveScheduler:
                 carry, chosen, n_done, L = self._zreplay.run_group(
                     static, carry, prev, gbuf, glayout, num_zones,
                     num_values, J_g, K_bucket, G_bucket, zone_perm,
-                    vetos, has_sels, rows_arr, k_reals, L_host,
+                    vetos, has_sels, rows_arr, k_reals, G, L_host,
                 )
                 chosen = np.asarray(chosen)
                 n_done = np.asarray(n_done)
                 L_host = int(L)
+                steps, slots = np.asarray(self._zreplay.group_ran)
+            count_group(self.stats, {
+                "zreplay_steps": int(steps), "zreplay_slots": int(slots),
+                "zreplay_picks": int((chosen >= 0).sum())})
             partial = None
             consumed = 0
             for i, g in enumerate(group):
@@ -1407,7 +1420,9 @@ class WaveScheduler:
             if info["device"]:
                 # device-path runs group freely (each probe runs against
                 # the live in-program carry — no purity needed), bounded
-                # by the pick-scan waste of the shared K bucket
+                # by what the shared K bucket still wastes: the picks
+                # come back as [G bucket, K bucket] whatever the runs'
+                # lengths (the pick loop itself ends at each run's)
                 picks = info["length"]
                 while (jdx < len(infos) and len(group) < 512
                        and info["length"] <= (1 << 16)):

@@ -1,27 +1,30 @@
-"""Device replay: wave pick sequences as ONE lax.scan dispatch.
+"""Device replay: wave pick sequences as ONE device dispatch.
 
 The host replays (replay.py's C engine and numpy spec) assume scores
 decompose into per-node functions of that node's commit count. The
 ZONE-blended SelectorSpread breaks that: every commit re-weights a whole
 zone, so the C engine can't bucket and the numpy spec pays ~0.4 ms per
 pick — a zoned 50k-pod north-star took ~20 s. Here the whole pick
-sequence runs ON DEVICE instead: probe + K scan steps + the commit fold
-in one jitted program, one dispatch, one small transfer out. Each step
+sequence runs ON DEVICE instead: probe + the run's pick steps + the
+commit fold in one jitted program, one dispatch, one small transfer out.
+K (and the group's G) only size the compiled buffers: the pick loop ends
+at the run's real length and the run loop at the group's real run
+count, both read from the program's input. Each step
 reassembles the combined score exactly as models/replay._scores (same
 float32/float64 formulas, same NaN -> minInt64 quirk, same selectHost
 round-robin in name-desc order) — differentially tested against the
 host spec replay and the oracle by tests/test_wave.py.
 
-Two entry points share the same probe+scan body:
+Two entry points share the same probe+replay body:
 
   * ZReplay.run — one run per dispatch (the original shape), and
-  * ZReplay.run_group — G runs per dispatch: an OUTER lax.scan carries
+  * ZReplay.run_group — up to G runs per dispatch: an OUTER loop carries
     the live device carry across runs, so each run's probe sees every
     earlier run's commits and a 500-template zoned backlog costs ONE
     device round trip instead of 500. A run that trips its table
-    horizon aborts the remainder (n_done reports how far each run got)
-    and the host driver resumes from there — output stays bit-identical
-    to the serial per-run sequence.
+    horizon ends the loop (n_done reports how far each run got) and the
+    host driver resumes from there — output stays bit-identical to the
+    serial per-run sequence.
 
 Scope: runs whose only cross-node coupling is the zone blend (the
 common zoned-cluster case). ServiceAffinity/ServiceAntiAffinity
@@ -57,14 +60,13 @@ def _weights(config: SchedulerConfig):
 
 
 def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
-                zone_id, veto, has_selectors, rows_dyn, k_real, L0,
-                active0):
-    """Probe `pod` against the live carry, then K pick steps.
+                zone_id, veto, has_selectors, rows_dyn, k_real, L0):
+    """Probe `pod` against the live carry, then one pick step per pod of
+    the run: the loop ends at k_real (<= K) or at a table-horizon bail.
 
-    zone_id/veto are PERMUTED to name-desc order already. active0 gates
-    every commit (False == this run is aborted: compute shapes run but
-    nothing schedules). Returns (j i64[N] permuted-space commit counts,
-    chosen i32[K] permuted-space ids, L, n_done, bailed)."""
+    zone_id/veto are PERMUTED to name-desc order already. Returns
+    (j i64[N] permuted-space commit counts, chosen i32[K] permuted-space
+    ids, -1 past the last step, L, n_done, bailed, the steps run)."""
     stk, _tab = _probe_rows(config, num_zones, num_values, J, static,
                             carry, pod)
     perm = static["name_desc_order"].astype(jnp.int32)
@@ -192,10 +194,9 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
             )
         return score
 
-    def step(state, i):
-        j, fit, zc, L, n_done, stopped = state
-        active = (~stopped) & (i < k_real) & active0
-        can = active & fit.any()
+    def step(state):
+        i, j, fit, zc, L, n_done, stopped, chosen = state
+        sched = fit.any()
         score = scores(j, fit, zc)
         smax = jnp.where(fit, score, jnp.int64(-(2**63))).max()
         ties = fit & (score == smax)
@@ -203,7 +204,6 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
         r = (L % num_ties).astype(jnp.int32)
         tie_rank = jnp.cumsum(ties.astype(jnp.int32)) - 1
         m = jnp.argmax(ties & (tie_rank == r)).astype(jnp.int32)
-        sched = can
         # zone-count bookkeeping around the commit (only column m moves)
         sm = jnp.where(selfmatch, jnp.int64(1), jnp.int64(0))
         c_old_m = spread_base[m] + sm * j[m]
@@ -211,10 +211,9 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
         j = j.at[m].add(jnp.where(sched, 1, 0))
         L = L + sched.astype(jnp.int64)
         jm = j[m]
-        # at most one bail can ever fire (stopped gates sched after)
+        # the bail ends the loop: at most one ever fires
         bail = sched & (jm >= rows_dyn)
         n_done = jnp.where(bail, i + 1, n_done)
-        stopped = stopped | bail
         new_fit_m = fit_static[m] & (jm < frontier[m])
         fit = fit.at[m].set(jnp.where(sched, new_fit_m, fit[m]))
         c_new_m = spread_base[m] + sm * jm
@@ -222,20 +221,26 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
         zc = zc.at[zone_id[m]].add(
             jnp.where(sched, contrib_new - contrib_old, 0)
         )
-        chosen = jnp.where(sched, m, jnp.int32(-1))
-        return (j, fit, zc, L, n_done, stopped), chosen
+        chosen = chosen.at[i].set(jnp.where(sched, m, jnp.int32(-1)))
+        return i + 1, j, fit, zc, L, n_done, stopped | bail, chosen
+
+    def more(state):
+        i, stopped = state[0], state[6]
+        return (i < k_real) & ~stopped
 
     zc0 = jnp.zeros((num_zones,), jnp.int64).at[zone_id].add(
         jnp.where(fit0, spread_base, 0)
     )
+    k_real = k_real.astype(jnp.int32)
     state0 = (
-        jnp.zeros((N,), jnp.int64), fit0, zc0, jnp.int64(L0),
-        k_real.astype(jnp.int32), jnp.bool_(False),
+        jnp.int32(0), jnp.zeros((N,), jnp.int64), fit0, zc0,
+        jnp.int64(L0), k_real, jnp.bool_(False),
+        jnp.full((K,), -1, jnp.int32),
     )
-    (j, _fit, _zc, L, n_done, stopped), chosen = jax.lax.scan(
-        step, state0, jnp.arange(K, dtype=jnp.int32)
+    steps, j, _fit, _zc, L, n_done, stopped, chosen = jax.lax.while_loop(
+        more, step, state0
     )
-    return j, chosen, L, n_done, stopped
+    return j, chosen, L, n_done, stopped, steps
 
 
 @jax.named_scope("zreplay")
@@ -243,7 +248,8 @@ def _zreplay_fn(config, num_zones, num_values, J, K, layout, apply_fn,
                 fold_prev, static, carry, prev_buf, prev_counts,
                 pod_buf, zone_id, veto, has_selectors, rows_dyn, k_real,
                 L0):
-    """probe + K-step device replay + commit fold, one program.
+    """probe + device replay of k_real (<= K) picks + commit fold, one
+    program.
 
     zone_id/veto are PERMUTED to name-desc order already; probe rows are
     permuted inside. Returns (carry', chosen[K] permuted-space ids,
@@ -256,10 +262,9 @@ def _zreplay_fn(config, num_zones, num_values, J, K, layout, apply_fn,
     pod = _unpack_pod(layout, pod_buf)
     perm = static["name_desc_order"].astype(jnp.int32)
     N = perm.shape[0]
-    j, chosen, L, n_done, _stopped = _replay_run(
+    j, chosen, L, n_done, _stopped, _steps = _replay_run(
         config, num_zones, num_values, J, K, static, carry, pod,
         zone_id, veto, has_selectors, rows_dyn, k_real, L0,
-        jnp.bool_(True),
     )
     # permuted j -> node-order counts; fold THIS run's commits
     counts = jnp.zeros((N,), jnp.int64).at[perm].set(j)
@@ -271,12 +276,16 @@ def _zreplay_fn(config, num_zones, num_values, J, K, layout, apply_fn,
 def _zreplay_group_fn(config, num_zones, num_values, J, K, G, layout,
                       apply_fn, prev_kind, prev_layout, apply_group_fn,
                       static, carry, prev_buf, prev_counts, group_buf,
-                      zone_id, vetos, has_sels, rows_arr, k_reals, L0):
-    """G runs — probe + replay + fold each — in ONE device program: an
-    outer lax.scan threads the carry run to run, so every probe sees the
-    earlier runs' commits exactly as the serial per-run loop would.
-    A table-horizon bail aborts the remainder (aborted runs schedule
-    nothing and report n_done == 0); the host resumes from there."""
+                      zone_id, vetos, has_sels, rows_arr, k_reals, runs,
+                      L0):
+    """The group's `runs` (<= G) runs — probe + replay + fold each — in
+    ONE device program: an outer loop threads the carry run to run, so
+    every probe sees the earlier runs' commits exactly as the serial
+    per-run loop would. A table-horizon bail ends the loop (the runs
+    behind it, like the slots past `runs`, keep n_done == 0 and picks of
+    -1); the host resumes from there. Returns (carry', chosen[G, K],
+    n_done[G], L', ran i32[2]: the pick steps and the run-slot
+    iterations the two loops ran)."""
     from kubernetes_tpu.models.pack import unpack as _unpack_pod
 
     if prev_kind == "single":
@@ -289,25 +298,29 @@ def _zreplay_group_fn(config, num_zones, num_values, J, K, G, layout,
     perm = static["name_desc_order"].astype(jnp.int32)
     N = perm.shape[0]
 
-    def run_body(state, x):
-        carry, L, aborted = state
-        pod, veto, has_sel, rows_dyn, k_real = x
-        j, chosen, L2, n_done, bailed = _replay_run(
+    def run_body(state):
+        g, carry, L, _bailed, chosen, n_done, steps = state
+        pod = {f: v[g] for f, v in pods.items()}
+        j, picks, L, done, bailed, ran = _replay_run(
             config, num_zones, num_values, J, K, static, carry, pod,
-            zone_id, veto, has_sel, rows_dyn, k_real, L, ~aborted,
+            zone_id, vetos[g], has_sels[g], rows_arr[g], k_reals[g], L,
         )
         counts = jnp.zeros((N,), jnp.int64).at[perm].set(j)
-        # aborted runs committed nothing: counts == 0 and the fold is a
-        # no-op, so folding unconditionally keeps ONE trace
         carry = apply_fn(static, carry, pod, counts)
-        n_done = jnp.where(aborted, 0, n_done)
-        return (carry, L2, aborted | bailed), (chosen, n_done)
+        return (g + 1, carry, L, bailed, chosen.at[g].set(picks),
+                n_done.at[g].set(done), steps + ran)
 
-    (carry, L, _ab), (chosen, n_done) = jax.lax.scan(
-        run_body, (carry, L0, jnp.bool_(False)),
-        (pods, vetos, has_sels, rows_arr, k_reals),
+    def more(state):
+        g, bailed = state[0], state[3]
+        return (g < runs) & ~bailed
+
+    slots, carry, L, _bailed, chosen, n_done, steps = jax.lax.while_loop(
+        more, run_body,
+        (jnp.int32(0), carry, jnp.int64(L0), jnp.bool_(False),
+         jnp.full((G, K), -1, jnp.int32), jnp.zeros((G,), jnp.int32),
+         jnp.int32(0)),
     )
-    return carry, chosen, n_done, L
+    return carry, chosen, n_done, L, jnp.stack([steps, slots])
 
 
 class ZReplay:
@@ -319,6 +332,10 @@ class ZReplay:
         self.apply_fn = apply_fn
         self.apply_group_fn = apply_group_fn
         self._jitted = {}
+        #: i32[2] on the device: the pick steps and run-slot iterations
+        #: the last run_group dispatch ran (its return stays the four
+        #: values its callers unpack)
+        self.group_ran = None
 
     def run(self, static, carry, prev_buf, prev_counts, pod_buf, layout,
             num_zones, num_values, J, K_bucket, zone_id_perm, veto_perm,
@@ -348,10 +365,11 @@ class ZReplay:
     def run_group(self, static, carry, prev, group_buf, layout,
                   num_zones, num_values, J, K_bucket, G,
                   zone_id_perm, vetos_perm, has_sels, rows_arr, k_reals,
-                  L0):
+                  runs, L0):
         """-> (carry', chosen i32[G, K_bucket] permuted-space,
-        n_done i32[G], L'). `prev` is a deferred fold riding this
-        dispatch: None or (kind, buf, layout, counts)."""
+        n_done i32[G], L'), `runs` (<= G) being the group's real run
+        count. `prev` is a deferred fold riding this dispatch: None or
+        (kind, buf, layout, counts)."""
         prev_kind = prev_layout = None
         prev_buf = prev_counts = None
         if prev is not None:
@@ -371,9 +389,10 @@ class ZReplay:
         if prev_kind is None:
             prev_buf = jnp.zeros(0, jnp.uint8)
             prev_counts = jnp.zeros(0, jnp.int64)
-        return fn(
+        carry, chosen, n_done, L, self.group_ran = fn(
             static, carry, prev_buf, jnp.asarray(prev_counts), group_buf,
             jnp.asarray(zone_id_perm), jnp.asarray(vetos_perm),
             jnp.asarray(has_sels), jnp.asarray(rows_arr),
-            jnp.asarray(k_reals), np.int64(L0),
+            jnp.asarray(k_reals), np.int32(runs), np.int64(L0),
         )
+        return carry, chosen, n_done, L

@@ -295,13 +295,15 @@ def pending_row_totals() -> Dict[str, int]:
 _wave_lock = threading.Lock()
 #: what the wave driver (models/wave.WaveScheduler.schedule_backlog) did
 #: in this process, all waves: pods decided by each path, device
-#: programs launched by kind, pods that fitted nowhere, and what the
-#: grouped header probe did (models/wave.GROUP_COUNTERS); served on
-#: /debug/traces as "wave"
+#: programs launched by kind, pods that fitted nowhere, what the
+#: grouped header probe did (models/wave.GROUP_COUNTERS) and what the
+#: grouped device replay's loops ran (models/wave.ZREPLAY_COUNTERS);
+#: served on /debug/traces as "wave"
 _WAVE: Dict[str, Any] = {"waves": 0, "pods_by_path": {},
                          "dispatches_by_kind": {}, "pods_unplaced": 0,
                          "group_runs": 0, "group_d2h_bytes": 0,
-                         "group_reprobes": 0}
+                         "group_reprobes": 0, "zreplay_steps": 0,
+                         "zreplay_slots": 0, "zreplay_picks": 0}
 
 
 def count_wave(pods_by_path: Dict[str, int], dispatches: Dict[str, int],
@@ -321,7 +323,9 @@ def count_wave(pods_by_path: Dict[str, int], dispatches: Dict[str, int],
 
 def count_wave_group(counted: Dict[str, int]) -> None:
     """A grouped header probe was replayed on the host: its runs, the
-    bytes it fetched, whether it stopped early (`group_*` of _WAVE)."""
+    bytes it fetched, whether it stopped early (`group_*` of _WAVE); or
+    a grouped device replay came back: the steps and run slots its
+    loops ran, the pods it placed (`zreplay_*`)."""
     with _wave_lock:
         for k, n in counted.items():
             _WAVE[k] += n

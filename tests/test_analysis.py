@@ -61,6 +61,18 @@ def test_registry_covers_the_wave_programs():
                 "resident_scatter"} <= names
 
 
+def test_the_replay_loops_brought_no_program_more():
+    """The device replay's loops end at counts read from the program's
+    input: one `zreplay` and one `zreplay_group` stand for every run
+    count and run length inside their buckets, and the gate audits as
+    many programs as it did before the loops had a traced bound."""
+    names = [s.name for s in registered_programs()]
+    assert [n for n in names if n.startswith("zreplay")] \
+        == ["zreplay", "zreplay_group"]
+    if len(jax.devices()) >= 2:
+        assert len(names) == len(set(names)) == 22, names
+
+
 def test_donation_contract_is_audited():
     """Every registered resident-state program declares donation and
     passes the aliasing audit; the donated folds cover the carry."""

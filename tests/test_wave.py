@@ -323,8 +323,8 @@ ZONE = "failure-domain.beta.kubernetes.io/zone"
 
 
 def zoned_density_nodes(n, zones=("a", "b", "c"), unzoned_every=0,
-                        pods_cap="110"):
-    nodes = density_nodes(n, pods_cap=pods_cap)
+                        pods_cap="110", **kw):
+    nodes = density_nodes(n, pods_cap=pods_cap, **kw)
     for i, node in enumerate(nodes):
         if unzoned_every and i % unzoned_every == 0:
             continue  # leave some nodes without a zone (zone 0 path)
@@ -869,7 +869,7 @@ def test_wave_grouped_port_conflicts_across_runs():
 
 def test_wave_grouped_zoned_multi_template():
     # many selector templates on a ZONED cluster ride the grouped DEVICE
-    # dispatch (zreplay.run_group): one outer scan, carry threaded run
+    # dispatch (zreplay.run_group): one outer loop, carry threaded run
     # to run — the config-4 shape
     state = spread_state(zoned_density_nodes(12))
     pods = template_pods(6, 15)
@@ -1018,9 +1018,9 @@ def test_wave_grouped_mesh_matches_oracle():
     assert d.get("group_probe", 0) >= 1, f"mesh grouping idle: {d}"
 
 
-def _wave_direct(state, pods, max_j):
+def _wave_scheduler_run(state, pods, max_j=1024, replay=None):
     """Drive WaveScheduler directly (dedup + pad like the algorithm
-    shell) with a clamped table horizon."""
+    shell): -> (hosts, the scheduler)."""
     from kubernetes_tpu.models.wave import WaveScheduler
     from kubernetes_tpu.parallel.mesh import _pad_snapshot
     from kubernetes_tpu.snapshot.pad import next_pow2
@@ -1036,12 +1036,18 @@ def _wave_direct(state, pods, max_j):
     snap = enc.encode_nodes()
     batch = enc.encode_pods()
     snap_p = _pad_snapshot(snap, next_pow2(snap.num_nodes, 4))
-    ws = WaveScheduler(min_run=1, max_j=max_j)
+    ws = WaveScheduler(min_run=1, max_j=max_j, replay=replay)
     chosen, _, _ = ws.schedule_backlog(
         snap_p, batch, np.asarray(rep_idx, np.int64)
     )
     got = [snap.node_names[c] if 0 <= c < snap.num_nodes else None
            for c in chosen]
+    return got, ws
+
+
+def _wave_direct(state, pods, max_j):
+    """The same with a clamped table horizon: -> (hosts, dispatches)."""
+    got, ws = _wave_scheduler_run(state, pods, max_j=max_j)
     return got, ws.dispatches
 
 
@@ -1060,10 +1066,102 @@ def test_wave_grouped_host_horizon_resume():
 
 def test_wave_grouped_device_horizon_resume():
     # the same horizon abort through the grouped DEVICE dispatch: the
-    # outer scan aborts at the bail, later runs schedule nothing, the
+    # outer loop ends at the bail, later runs schedule nothing, the
     # host resumes from the bail point
     state = spread_state(zoned_density_nodes(2, pods_cap="1000"))
     pods = template_pods(3, 300, cpu0=1, mem_step=0)
     got, d = _wave_direct(state, pods, max_j=128)
     assert got == oracle_backlog(state, pods)
     assert d.get("zreplay", 0) >= 1, f"no single-path resume: {d}"
+
+
+# -- the device replay's two loops end at the real runs and picks --------------
+#
+# The grouped program is compiled for a run-slot bucket (a power of two
+# from 8) and a pick bucket (a power of two from 64); its loops run the
+# group's real run count and each run's real length. Each case is one
+# the padding used to cover.
+
+
+def _runs(lengths):
+    """One template a run, `lengths[t]` pods of template t in a row."""
+    pods = []
+    for t, k in enumerate(lengths):
+        pods += template_pods(1, k, cpu0=1 + 5 * t, name0=f"r{t:02d}-")
+    return pods
+
+
+def _roomy(n=24):
+    return spread_state(zoned_density_nodes(n, cpu="16"))
+
+
+REPLAY_LOOP_CASES = {
+    # name: (state, run lengths, max_j, what the first grouped dispatch's
+    #        counters must read: (run slots, pick steps, pods placed), or
+    #        None where no grouped dispatch is made)
+    "5-runs": (lambda: _roomy(12), [12] * 5, 1024, (5, 60, 60)),
+    "8-runs": (lambda: _roomy(12), [10] * 8, 1024, (8, 80, 80)),
+    "80-runs": (_roomy, [8] * 80, 1024, (80, 640, 640)),
+    # one group, its pick bucket the longest run's (65 -> 128)
+    "mixed-lengths": (_roomy, [40, 1, 7, 64, 65], 1024, (5, 177, 177)),
+    # 6 nodes x 8 pods hold 48 of the 70: the fourth run places 6 and
+    # pays its 14 steps, the fifth places none
+    "exhausts-mid-run": (
+        lambda: spread_state(zoned_density_nodes(6, pods_cap="8")),
+        [14] * 5, 1024, (5, 70, 48)),
+    # the middle run (300 pods on 2 nodes) trips the 128-row horizon at
+    # its 255th pick; the loop ends there and the third slot is never
+    # entered
+    "horizon-bail-in-the-middle": (
+        lambda: spread_state(zoned_density_nodes(2, pods_cap="1000")),
+        [100, 300, 100], 128, (2, 355, 355)),
+    # a lone run: jit_zreplay_run, through the same pick loop
+    "lone-run": (lambda: _roomy(12), [64], 1024, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REPLAY_LOOP_CASES))
+def test_device_replay_loops_end_at_the_real_runs_and_picks(
+        case, monkeypatch):
+    from kubernetes_tpu.models.replay import replay_spec
+    from kubernetes_tpu.models.zreplay import ZReplay
+
+    make_state, lengths, max_j, first_ran = REPLAY_LOOP_CASES[case]
+    state, pods = make_state(), _runs(lengths)
+    came_back = []
+    sound = ZReplay.run_group
+
+    def recorded(self, *a, **kw):
+        carry, chosen, n_done, L = sound(self, *a, **kw)
+        came_back.append((np.asarray(chosen), np.asarray(n_done),
+                          np.asarray(self.group_ran)))
+        return carry, chosen, n_done, L
+
+    monkeypatch.setattr(ZReplay, "run_group", recorded)
+    want = oracle_backlog(state, pods)
+    got, ws = _wave_scheduler_run(state, pods, max_j=max_j)
+    got_host, _ = _wave_scheduler_run(state, pods, max_j=max_j,
+                                      replay=replay_spec)
+    assert got == got_host == want
+    if first_ran is None:
+        assert not came_back and ws.dispatches == {"zreplay": 1}
+        assert ws.stats["zreplay_steps"] == ws.stats["zreplay_slots"] \
+            == ws.stats["zreplay_picks"] == 0
+        return
+    chosen, n_done, ran = came_back[0]
+    slots, steps, picks = first_ran
+    assert (int(ran[1]), int(ran[0]), int((chosen >= 0).sum())) == first_ran
+    # far under what the buckets would have paid
+    assert steps < chosen.shape[0] * chosen.shape[1]
+    # a slot the loop never entered keeps the buffers' initial values
+    assert not n_done[slots:].any() and (chosen[slots:] == -1).all()
+    for g, k in enumerate(lengths[:slots]):
+        assert (chosen[g, k:] == -1).all()
+    if case == "horizon-bail-in-the-middle":
+        assert list(n_done[:3]) == [100, 255, 0]
+        assert ws.dispatches.get("zreplay", 0) >= 1  # the resume
+    else:
+        assert list(n_done[:slots]) == lengths
+        assert ws.dispatches == {"zreplay_group": 1}
+        assert (ws.stats["zreplay_slots"], ws.stats["zreplay_steps"],
+                ws.stats["zreplay_picks"]) == first_ran

@@ -4,7 +4,10 @@ WaveScheduler.stats["pods_by_path"] / ["dispatches_by_kind"] /
 /debug/traces shows the same numbers. A group of runs whose templates
 commit different requests goes through one grouped header probe and
 picks as the serial oracle does, and the `group_*` counters say so on
-both drivers. And the scan path picks as the serial oracle does where
+both drivers. The grouped device replay's `zreplay_*` counters say how
+many run slots and pick steps its loops ran, and a wave of another run
+count inside one bucket builds no program. And the scan path picks as
+the serial oracle does where
 selector rows are all distinct, multi-hot, or followed by a pod that
 fits nowhere and by padding."""
 
@@ -24,7 +27,7 @@ from kubernetes_tpu.api.types import (
     Service,
     ServiceSpec,
 )
-from kubernetes_tpu.models.wave import PATHS
+from kubernetes_tpu.models.wave import PATHS, ZREPLAY_COUNTERS
 from kubernetes_tpu.trace import profile
 
 ZONE = "failure-domain.beta.kubernetes.io/zone"
@@ -149,7 +152,72 @@ def test_debug_traces_shows_the_wave_totals():
     shown = render_traces({"limit": "1"})["wave"]
     assert shown == profile.wave_totals()
     assert {"waves", "pods_by_path", "dispatches_by_kind",
-            "pods_unplaced"} <= set(shown)
+            "pods_unplaced", *ZREPLAY_COUNTERS} <= set(shown)
+
+
+# -- the grouped device replay's own counters ----------------------------------
+
+
+@pytest.mark.parametrize("case, runs", [
+    ("rows-zoned", 4), ("rows-then-turns", 3),
+    ("rows-unzoned", 0), ("dealt-in-turn-zoned", 0), ("one-row-zoned", 0),
+])
+def test_zreplay_counters_say_what_the_two_loops_ran(case, runs):
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+    from kubernetes_tpu.trace.httpd import render_traces
+
+    nodes, zones, controllers, backlog, _only = CASES[case]
+    state = ClusterState.build(_nodes(nodes, zones),
+                               controllers=_controllers(controllers))
+    algo = TPUScheduleAlgorithm()
+    shown_before = render_traces({"limit": "1"})["wave"]
+    assert None not in algo.schedule_backlog(backlog, state)
+    stats = algo._wave.stats
+    # every pod of a whole run is placed: a step a pick, a slot a run
+    assert stats["zreplay_slots"] == runs
+    assert stats["zreplay_steps"] == stats["zreplay_picks"] == 40 * runs \
+        == stats["pods_by_path"]["group_device"]
+    # where the parent paid 8 run slots x 64 pick steps
+    assert stats["zreplay_steps"] <= 160 < 8 * 64
+    shown = render_traces({"limit": "1"})["wave"]
+    for key in ZREPLAY_COUNTERS:
+        assert shown[key] - shown_before[key] == stats[key]
+
+
+def test_a_wave_of_another_run_count_builds_no_program():
+    """7 runs, then 8 of the same seven controllers (the program is
+    built per width of the class axis): both in the bucket of 8 run
+    slots and 64 picks; the run count is an argument of the one
+    program, not a shape."""
+    import time
+
+    from kubernetes_tpu.oracle import ClusterState, GenericScheduler
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    from tests.test_conformance import ORACLE_PREDICATES, ORACLE_PRIORITIES
+
+    profile.install_compile_listener()
+    state = ClusterState.build(_nodes(30), controllers=_controllers(7))
+    algo = TPUScheduleAlgorithm()
+    # one oracle for both waves: the round-robin index goes on counting
+    oracle = GenericScheduler(predicates=ORACLE_PREDICATES,
+                              priorities=ORACLE_PRIORITIES)
+    backlog = _in_rows(7, 33)
+    assert algo.schedule_backlog(backlog, state) \
+        == oracle.schedule_backlog(backlog, state.clone())
+    stats = algo._wave.stats
+    assert (stats["zreplay_slots"], stats["zreplay_steps"]) == (7, 231)
+    t_between = time.time()
+    backlog = _in_rows(7, 20) + [_pod(0, 20 + i) for i in range(25)]
+    assert algo.schedule_backlog(backlog, state) \
+        == oracle.schedule_backlog(backlog, state.clone())
+    assert (stats["zreplay_slots"], stats["zreplay_steps"]) == (15, 396)
+    assert stats["dispatches_by_kind"]["zreplay_group"] == 2
+    assert len(algo._wave._zreplay._jitted) == 1
+    built = [c["program"] for c in profile.recent_compiles()
+             if c["at"] >= t_between]
+    assert not [p for p in built if "zreplay" in p], built
 
 
 # -- a group of runs with distinct commit vectors -----------------------------
