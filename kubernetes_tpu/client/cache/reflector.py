@@ -19,6 +19,7 @@ from kubernetes_tpu.metrics import (
     reflector_watch_duration_seconds,
     watch_events_total,
 )
+from kubernetes_tpu.trace.profile import thread_role
 
 log = logging.getLogger(__name__)
 
@@ -80,6 +81,7 @@ class Reflector:
     # -- core ----------------------------------------------------------------
 
     def _loop(self) -> None:
+        thread_role("informer")
         backoff = self.relist_backoff
         while not self._stop.is_set():
             failed = False

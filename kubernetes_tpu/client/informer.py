@@ -26,6 +26,7 @@ from kubernetes_tpu.client.cache.store import (
     meta_namespace_key_func,
 )
 from kubernetes_tpu.client.rest import ResourceClient
+from kubernetes_tpu.trace.profile import thread_role
 
 log = logging.getLogger(__name__)
 
@@ -145,6 +146,7 @@ class Informer:
         return self._initial_processed.wait(timeout)
 
     def _process_loop(self) -> None:
+        thread_role("informer")
         while True:
             try:
                 # deltas are applied under the FIFO lock (pop_process) so

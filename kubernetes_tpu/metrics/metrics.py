@@ -80,6 +80,35 @@ class Counter(_Metric):
         return "\n".join(lines)
 
 
+class CounterFunc(Counter):
+    """A counter family whose owner keeps the totals itself, with no
+    lock on its hot path, and hands them over when asked: `collect()`
+    -> {((label, value), ...) sorted by label: total}
+    (prometheus.NewCounterFunc)."""
+
+    def __init__(self, name: str, help_: str, collect,
+                 label_bound: Optional[int] = None):
+        super().__init__(name, help_, label_bound=label_bound)
+        self._collect = collect
+
+    def _read(self) -> None:
+        values = self._collect()
+        with self._lock:
+            self._values = values
+
+    def get(self, **labels: str) -> float:
+        self._read()
+        return super().get(**labels)
+
+    def total(self) -> float:
+        self._read()
+        return super().total()
+
+    def render(self) -> str:
+        self._read()
+        return super().render()
+
+
 class Gauge(_Metric):
     def __init__(
         self,
@@ -373,6 +402,31 @@ scheduler_wave_phase_seconds = registry.register(
         label="phase",
         buckets=_SECONDS_BUCKETS,
         label_bound=16,
+    )
+)
+
+
+def _thread_phase_seconds() -> Dict[Tuple[Tuple[str, str], ...], float]:
+    from kubernetes_tpu.trace import profile  # it imports this module
+
+    return {(("clock", clock), ("phase", phase), ("role", role)): cell[clock]
+            for role, phases in profile.thread_totals().items()
+            for phase, cell in phases.items()
+            for clock in ("wall", "cpu")}
+
+
+#: each role's threads' own seconds a phase (trace/profile.py's
+#: per-thread ledger): role=loop|binder|informer|other, phase= a phase,
+#: an idle state or device_wait, clock=wall|cpu. Is the loop starved of
+#: the interpreter lock by its informers: its wall less its cpu over
+#: the working phases, less device_wait's
+scheduler_thread_phase_seconds_total = registry.register(
+    CounterFunc(
+        "scheduler_thread_phase_seconds_total",
+        "Seconds each role's threads spent inside their own phase "
+        "timers, by wall clock and by thread CPU clock",
+        _thread_phase_seconds,
+        label_bound=128,
     )
 )
 

@@ -59,6 +59,7 @@ from kubernetes_tpu.models.batch import (
 from kubernetes_tpu.ops import interpod as IP
 from kubernetes_tpu.ops import predicates as P
 from kubernetes_tpu.ops import priorities as R
+from kubernetes_tpu.trace.profile import device_wait
 
 
 @dataclass
@@ -446,7 +447,8 @@ class WaveProbe:
         if rows is None:
             rows = J
         rows = max(1, min(rows, J))
-        arr = np.ascontiguousarray(jax.device_get(raw["packed"]))
+        with device_wait():
+            arr = np.ascontiguousarray(jax.device_get(raw["packed"]))
         return carry2, tables_from_packed(
             self.config, arr, num_zones, J, rows,
             has_selectors=has_selectors, zone_id=zone_id,
@@ -506,7 +508,8 @@ class WaveProbe:
             prev_counts = jnp.zeros(0, jnp.int64)
         carry2, raw = fn(static, carry, prev_buf,
                          jnp.asarray(prev_counts), group_buf)
-        arr = np.ascontiguousarray(jax.device_get(raw))
+        with device_wait():
+            arr = np.ascontiguousarray(jax.device_get(raw))
         N = arr.shape[1]
         headers = arr[: G * N_STK_ROWS].reshape(G, N_STK_ROWS, N)
         usage = arr[G * N_STK_ROWS:]
@@ -529,7 +532,8 @@ class WaveProbe:
         rows = max(1, min(rows, J))
         raw = self._compiled(num_zones, num_values, J)(static, carry, pod)
         # ONE device->host transfer for the whole probe product
-        arr = np.ascontiguousarray(jax.device_get(raw["packed"]))
+        with device_wait():
+            arr = np.ascontiguousarray(jax.device_get(raw["packed"]))
         return tables_from_packed(
             self.config, arr, num_zones, J, rows,
             has_selectors=(bool(np.asarray(pod["has_selectors"]))

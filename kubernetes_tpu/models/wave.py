@@ -62,7 +62,7 @@ from kubernetes_tpu.models.replay import ReplayResult, replay_fast
 from kubernetes_tpu.ops.narrow import narrow_dtype
 from kubernetes_tpu.snapshot.encode import ClusterSnapshot, PodBatch
 from kubernetes_tpu.snapshot.pad import next_pow2, pad_batch
-from kubernetes_tpu.trace.profile import phase_timer
+from kubernetes_tpu.trace.profile import device_wait, phase_timer
 
 _WAVE_PRIORITIES = {
     LEAST_REQUESTED,
@@ -925,13 +925,15 @@ class WaveScheduler:
             num_zones, num_values, J, K_bucket, zone_perm, veto_perm,
             bool(batch.has_selectors[rep]), rows, k_real, L_host,
         )
-        chosen = np.asarray(chosen)
-        n_done = int(n_done)
+        with device_wait():
+            chosen = np.asarray(chosen)
+            n_done = int(n_done)
+            L = int(L)
         return carry, ReplayResult(
             chosen=chosen[:n_done],
             counts=None,  # already folded on device
             n_done=n_done,
-            last_node_index=int(L),
+            last_node_index=L,
             scheduled=int((chosen[:n_done] >= 0).sum()),
         )
 
@@ -1043,27 +1045,31 @@ class WaveScheduler:
         self.dispatches = {}
         self.stats["waves"] += 1
         self.stats["wave_table_bytes"] = 0
-        res_host = np.stack([
-            np.asarray(snap.req_mcpu), np.asarray(snap.req_mem),
-            np.asarray(snap.req_gpu), np.asarray(snap.nz_mcpu),
-            np.asarray(snap.nz_mem), np.asarray(snap.pod_count),
-        ])
-        dev = self._to_dev_many(
-            snap,
-            tuple(BatchScheduler.STATIC_FIELDS) + self._CARRY_FIELDS,
-            keep,
-            extra={"__res__": res_host,
-                   "__lidx__": np.int64(last_node_index)},
-        )
-        static = {f: dev[f] for f in BatchScheduler.STATIC_FIELDS}
-        # config-resolved node masks are HOST arrays: place them once
-        # per wave (a numpy leaf in `static` would re-upload at every
-        # per-run probe/apply dispatch)
-        static.update({
-            k: jnp.asarray(v)
-            for k, v in BatchScheduler.config_static(
-                self.config, snap).items()
-        })
+        # all of it host-to-device placement: the stack of the resource
+        # rows and config_static's masks as much as Packer.ship inside
+        # _to_dev_many, which opens the same phase
+        with phase_timer("transfer"):
+            res_host = np.stack([
+                np.asarray(snap.req_mcpu), np.asarray(snap.req_mem),
+                np.asarray(snap.req_gpu), np.asarray(snap.nz_mcpu),
+                np.asarray(snap.nz_mem), np.asarray(snap.pod_count),
+            ])
+            dev = self._to_dev_many(
+                snap,
+                tuple(BatchScheduler.STATIC_FIELDS) + self._CARRY_FIELDS,
+                keep,
+                extra={"__res__": res_host,
+                       "__lidx__": np.int64(last_node_index)},
+            )
+            static = {f: dev[f] for f in BatchScheduler.STATIC_FIELDS}
+            # config-resolved node masks are HOST arrays: place them
+            # once per wave (a numpy leaf in `static` would re-upload
+            # at every per-run probe/apply dispatch)
+            static.update({
+                k: jnp.asarray(v)
+                for k, v in BatchScheduler.config_static(
+                    self.config, snap).items()
+            })
         num_zones = max(
             int(snap.zone_id.max()) + 1 if snap.zone_id.size else 1, 1
         )
@@ -1166,8 +1172,9 @@ class WaveScheduler:
             with phase_timer("score"):
                 self._count("scan")
                 new_carry, chosen = run(static, carry, pods)
-                out[rows] = np.asarray(chosen)[: len(rows)]
-                L_host = int(new_carry[self.LAST_IDX])
+                with device_wait():
+                    out[rows] = np.asarray(chosen)[: len(rows)]
+                    L_host = int(new_carry[self.LAST_IDX])
             pending.clear()
             return new_carry
 
@@ -1383,10 +1390,11 @@ class WaveScheduler:
                     num_values, J_g, K_bucket, G_bucket, zone_perm,
                     vetos, has_sels, rows_arr, k_reals, G, L_host,
                 )
-                chosen = np.asarray(chosen)
-                n_done = np.asarray(n_done)
-                L_host = int(L)
-                steps, slots = np.asarray(self._zreplay.group_ran)
+                with device_wait():
+                    chosen = np.asarray(chosen)
+                    n_done = np.asarray(n_done)
+                    L_host = int(L)
+                    steps, slots = np.asarray(self._zreplay.group_ran)
             count_group(self.stats, {
                 "zreplay_steps": int(steps), "zreplay_slots": int(slots),
                 "zreplay_picks": int((chosen >= 0).sum())})

@@ -68,7 +68,7 @@ from kubernetes_tpu.ops import priorities as R
 from kubernetes_tpu.ops import services as SV
 from kubernetes_tpu.ops import volumes as V
 from kubernetes_tpu.snapshot.encode import ClusterSnapshot, PodBatch, service_config_labels
-from kubernetes_tpu.trace.profile import phase_timer
+from kubernetes_tpu.trace.profile import device_wait, fetch, phase_timer
 
 
 def _pad_snapshot(snap: ClusterSnapshot, multiple: int) -> ClusterSnapshot:
@@ -966,7 +966,7 @@ class MeshBatchScheduler:
             static, carry, pods, n, n_per_shard, num_zones, num_values,
             batch.num_pods,
         )
-        return np.asarray(chosen), final
+        return fetch(chosen), final
 
     def _jit_for(self, static, n, n_per_shard, num_zones, num_values,
                  num_pods, pods_keys, empty=()):
@@ -1263,8 +1263,9 @@ class MeshWaveScheduler:
         run = self._probe_program(static, n, n_per_shard, num_zones,
                                   num_values, J, pod_layout)
         with phase_timer("probe"), self.mesh:
-            return np.ascontiguousarray(
-                jax.device_get(run(static, carry, pod_buf)))
+            raw = run(static, carry, pod_buf)
+            with device_wait():
+                return np.ascontiguousarray(jax.device_get(raw))
 
     def _apply_run(self, static, carry, pod_layout, pod_buf, counts, n,
                    n_per_shard):
@@ -1277,7 +1278,8 @@ class MeshWaveScheduler:
             # drain the donated fold before anything can re-donate its
             # aliased buffers (the fold is the last dispatch of its
             # run, so only fold-vs-host bookkeeping overlap is lost)
-            jax.block_until_ready(carry)
+            with device_wait():
+                jax.block_until_ready(carry)
         self.resident.set_carry(carry)
         return carry
 
@@ -1293,8 +1295,9 @@ class MeshWaveScheduler:
                                         num_zones, num_values, G,
                                         pod_layout)
         with phase_timer("probe"), self.mesh:
-            arr = np.ascontiguousarray(
-                jax.device_get(run(static, carry, group_buf)))
+            raw = run(static, carry, group_buf)
+            with device_wait():
+                arr = np.ascontiguousarray(jax.device_get(raw))
         return arr.reshape(G, N_STK_ROWS, n)
 
     def _apply_group_run(self, static, carry, pod_layout, group_buf,
@@ -1309,7 +1312,8 @@ class MeshWaveScheduler:
         with phase_timer("replay"), self.mesh:
             carry = run(static, carry, group_buf, idx, cnt)
             # see _apply_run: donated folds drain before re-donation
-            jax.block_until_ready(carry)
+            with device_wait():
+                jax.block_until_ready(carry)
         self.resident.set_carry(carry)
         return carry
 
@@ -1411,10 +1415,11 @@ class MeshWaveScheduler:
                     num_values, segp.num_pods,
                 )
                 self.resident.set_carry(carry)
-                chosen_host = np.asarray(chosen)[: len(rows)]
+                with device_wait():
+                    chosen_host = np.asarray(chosen)[: len(rows)]
+                    L_host = int(
+                        jax.device_get(carry[BatchScheduler.LAST_IDX]))
                 out[rows] = chosen_host
-                L_host = int(
-                    jax.device_get(carry[BatchScheduler.LAST_IDX]))
             # host-visible pure-channel commits keep the mirrors exact;
             # the opaque feature blocks resync from the next snapshot
             segf = {

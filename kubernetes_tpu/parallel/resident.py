@@ -44,6 +44,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from kubernetes_tpu.ops.narrow import narrow_dtype
+from kubernetes_tpu.trace.profile import device_wait
 
 AXIS = "nodes"
 
@@ -489,7 +490,8 @@ class ResidentClusterState:
         updated = run(tuple(arrays), buf)
         # donated dispatches drain before their aliased buffers can be
         # re-donated (see mesh._apply_run)
-        jax.block_until_ready(updated)
+        with device_wait():
+            jax.block_until_ready(updated)
         for (f, host, _s, _ax), dev in zip(fields, updated):
             self._store(f, dev, host)
         self.count_h2d(buf.nbytes, table=True)

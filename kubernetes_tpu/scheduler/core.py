@@ -251,7 +251,8 @@ class Scheduler:
         from concurrent.futures import ThreadPoolExecutor
 
         self._bind_pool = ThreadPoolExecutor(
-            max_workers=16, thread_name_prefix="bind"
+            max_workers=16, thread_name_prefix="bind",
+            initializer=trace_profile.thread_role, initargs=("binder",),
         )
         # previous wave's algorithm wall seconds — the adaptive
         # wave-gather window scales off it
@@ -275,6 +276,9 @@ class Scheduler:
         self._bind_pool.shutdown(wait=False)
 
     def _loop(self) -> None:
+        # the thread whose steps, one after the other, are a wave's
+        # period: its own ledger in trace/profile.thread_totals()
+        trace_profile.thread_role("loop")
         while not self.config.stop_everything.is_set():
             try:
                 self.schedule_one()

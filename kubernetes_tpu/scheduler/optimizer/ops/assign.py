@@ -47,6 +47,8 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from kubernetes_tpu.trace.profile import fetch
+
 #: resource rows of the req/commit/cap tables, in order
 RES_ROWS = 4  # mcpu, mem bytes, devices, pod slots
 
@@ -260,7 +262,7 @@ class AssignSolver:
             owner = fn(jnp.asarray(fit), jnp.asarray(score),
                        jnp.asarray(req), jnp.asarray(commit),
                        jnp.asarray(check), jnp.asarray(cap))
-            return np.asarray(owner), "beam"
+            return fetch(owner), "beam"
         rounds = auction_rounds(P, N)
         key = ("auction", P, N, rounds)
         fn = self._jit.get(key)
@@ -275,4 +277,4 @@ class AssignSolver:
                    jnp.asarray(check), jnp.asarray(cap),
                    jnp.asarray(prio), jnp.asarray(order),
                    jnp.asarray(eps0))
-        return np.asarray(owner), "auction"
+        return fetch(owner), "auction"

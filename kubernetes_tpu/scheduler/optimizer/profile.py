@@ -69,7 +69,7 @@ from kubernetes_tpu.scheduler.optimizer.ops.assign import (
     AssignSolver,
 )
 from kubernetes_tpu.snapshot.pad import next_pow2, pad_batch
-from kubernetes_tpu.trace.profile import phase_timer
+from kubernetes_tpu.trace.profile import device_wait, phase_timer
 
 log = logging.getLogger(__name__)
 
@@ -226,8 +226,9 @@ class OptimizingWaveDriver:
             with phase_timer("score"):
                 wave._count("scan")
                 carry, chosen = run(static, carry, pods)
-                out[rows] = np.asarray(chosen)[: len(rows)]
-                L_host = int(carry[wave.LAST_IDX])
+                with device_wait():
+                    out[rows] = np.asarray(chosen)[: len(rows)]
+                    L_host = int(carry[wave.LAST_IDX])
         return out, carry, L_host
 
     # -- the joint solve -----------------------------------------------------

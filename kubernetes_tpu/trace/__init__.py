@@ -6,8 +6,8 @@ server.go:92-108). This package grows that into per-phase attribution
 for the TPU wire path:
 
   * spans.py   — lightweight span API: ``span(name, **attrs)`` context
-    manager, thread-safe in-memory ring buffer, JSON-lines export,
-    parent/child propagation via a context var, and a trace-id pod
+    manager, thread-safe in-memory ring buffer, parent/child
+    propagation via a context var, and a trace-id pod
     annotation that rides the TLV wire, so one pod's journey
     apiserver -> scheduler -> bind is a single trace across processes.
     Each wave of the scheduler is one trace in the ring too:
@@ -16,7 +16,12 @@ for the TPU wire path:
   * profile.py — per-phase histograms (encode / probe / score / replay
     / transfer / wire / bind / prepare / assume / ingest) on one
     exclusive timeline (``exclusive_totals()``) that also holds the
-    two idle states queue_wait / gather (``idle_totals()``); the
+    two idle states queue_wait / gather (``idle_totals()``); beside it
+    a ledger per thread (``thread_totals()``: each thread's own wall
+    and CPU seconds a phase under the role its owner declared with
+    ``thread_role``, and ``device_wait()`` round the host reads that
+    block on the device), served as ``threads`` on /debug/traces and as
+    scheduler_thread_phase_seconds_total on /metrics; the
     annotation switch (``set_annotations``: each timer also opens a
     ``jax.profiler.TraceAnnotation("sched/<phase>")`` while a profiler
     runs, so host phases and device operations share one clock); and
@@ -47,7 +52,6 @@ from kubernetes_tpu.trace.spans import (
     record_span,
     set_enabled,
     span,
-    trace_context,
 )
 
 __all__ = [
@@ -63,5 +67,4 @@ __all__ = [
     "record_span",
     "set_enabled",
     "span",
-    "trace_context",
 ]

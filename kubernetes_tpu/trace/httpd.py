@@ -51,6 +51,9 @@ def render_traces(query: Dict[str, str]) -> dict:
         "pending_rows": _profile.pending_row_totals(),
         # which path decided the pods, and what was launched for them
         "wave": _profile.wave_totals(),
+        # whose time it was: each role's threads' own wall and CPU
+        # seconds a phase, and the loop's waits for the device
+        "threads": _profile.thread_totals(),
     }
 
 

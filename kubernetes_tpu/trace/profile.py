@@ -47,6 +47,24 @@ host phases lie in the host plane of the same .xplane.pb, on the same
 clock, as the device's "XLA Ops" line. Off (the default) a timer pays
 one more global read; the switch is no environment variable.
 
+Beside the one timeline every timer keeps a ledger of its own THREAD
+(``thread_totals()``): self wall seconds (a nested timer's time is taken
+from the one that encloses it on that thread), self CPU seconds of the
+thread and entries, under the role the thread's owner declared
+(``thread_role``: loop / binder / informer, else "other"). The
+timeline says which phase the process was in; the ledger says whose
+time it was: a wave's period is set by the loop's thread alone, and on
+that thread wall less CPU is time it was runnable and not running (the
+interpreter lock, the OS). ``device_wait()`` marks a host read that
+blocks on the device, on the reading thread's record under the key
+"device_wait" (no phase: it overlays the phase it happens in) and, while
+annotations are on, as ``sched/device_wait`` on the profiler's clock.
+The ledger costs an entry two ``time.thread_time()`` reads, a system
+call: 0.3 us each on a plain kernel, 5.8 us each on the sandboxed host
+of the benchmark's chip, where the clock also ticks in 10 ms steps; at
+the 30 to 250 entries a second the daemon makes that is under 0.4% of
+a core (PERF.md section 6, PR 41).
+
 XLA compile time is attributed separately from execute time by routing
 jax.monitoring's '/jax/core/compile/backend_compile_duration' events
 into ``scheduler_xla_compile_seconds`` — the first jit call of a fresh
@@ -59,7 +77,11 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
+from threading import get_ident
+from time import perf_counter, thread_time
 from typing import Any, Dict, List
+
+import numpy as np
 
 from kubernetes_tpu.metrics import (
     scheduler_wave_phase_seconds,
@@ -165,9 +187,91 @@ class _ExclusiveAccountant:
 
 _ACCOUNTANT = _ExclusiveAccountant()
 
+#: a host read that blocks on the device: a key of a thread's record
+#: beside the phases and idle states, and no phase itself
+DEVICE_WAIT = "device_wait"
+#: what a thread is called until its owner says (`thread_role`)
+OTHER = "other"
+
+
+class _ThreadLedger:
+    """One thread's own account: [wall s, cpu s, entries] per phase,
+    idle state and DEVICE_WAIT. Only its thread writes it, so a timer
+    takes no lock; the keys are all there from the start, so a reader
+    on another thread never meets a dict that changes size."""
+
+    __slots__ = ("role", "thread", "cells", "open")
+
+    def __init__(self):
+        self.role = OTHER
+        self.thread = threading.current_thread()
+        self.cells = {k: [0.0, 0.0, 0] for k in _TIMELINE + (DEVICE_WAIT,)}
+        self.open = None  # the innermost _PhaseTimer open on the thread
+
+
+_ledgers_lock = threading.Lock()
+_LEDGERS: List[_ThreadLedger] = []
+#: role -> key -> [wall, cpu, entries] of threads that have ended
+_RETIRED: Dict[str, Dict[str, list]] = {}
+
+
+def _add_cells(into: Dict[str, Dict[str, list]], rec: _ThreadLedger) -> None:
+    mine = into.setdefault(
+        rec.role, {k: [0.0, 0.0, 0] for k in rec.cells})
+    for key, cell in rec.cells.items():
+        wall, cpu, count = cell
+        acc = mine[key]
+        acc[0] += wall
+        acc[1] += cpu
+        acc[2] += count
+
+
+def _ledger() -> _ThreadLedger:
+    """This thread's record, registered once; the records of threads
+    that have ended are folded into _RETIRED on the way, so a process
+    that starts threads all its life keeps a bounded list."""
+    try:
+        return _TLS.ledger
+    except AttributeError:
+        pass
+    rec = _TLS.ledger = _ThreadLedger()
+    with _ledgers_lock:
+        gone = [r for r in _LEDGERS if not r.thread.is_alive()]
+        for r in gone:
+            _add_cells(_RETIRED, r)
+            _LEDGERS.remove(r)
+        _LEDGERS.append(rec)
+    return rec
+
+
+def thread_role(role: str) -> None:
+    """Said once by the code that owns the calling thread, at the top
+    of its function: the role its time is booked under in
+    thread_totals(). A thread nobody spoke for is "other"."""
+    _ledger().role = role
+
+
+def thread_totals() -> Dict[str, Dict[str, Dict[str, float]]]:
+    """{role: {phase | idle state | "device_wait": {"wall", "cpu",
+    "count"}}}, cumulative like exclusive_totals(): each thread's SELF
+    seconds inside its own timers, summed over the threads of a role.
+    Unlike the timeline's these overlap across threads and add up to
+    more than the wall; within one thread they add up to at most its
+    wall. "device_wait" lies inside the phase it was read in and is not
+    taken from it."""
+    with _ledgers_lock:
+        out = {role: {k: list(c) for k, c in cells.items()}
+               for role, cells in _RETIRED.items()}
+        for rec in _LEDGERS:
+            _add_cells(out, rec)
+    return {role: {k: {"wall": c[0], "cpu": c[1], "count": c[2]}
+                   for k, c in cells.items()}
+            for role, cells in out.items()}
+
 
 class _PhaseTimer:
-    __slots__ = ("_hist", "_phase", "_t0", "_outer", "_ann")
+    __slots__ = ("_hist", "_phase", "_t0", "_c0", "_ann", "_rec", "_up",
+                 "_inner_wall", "_inner_cpu")
 
     def __init__(self, hist, phase):
         self._hist = hist
@@ -180,18 +284,64 @@ class _PhaseTimer:
         else:
             self._ann = cls("sched/" + self._phase)
             self._ann.__enter__()
-        self._outer = getattr(_TLS, "phase", None)
+        rec = self._rec = _ledger()
+        self._up = rec.open
+        rec.open = self
+        self._inner_wall = self._inner_cpu = 0.0
         _TLS.phase = self._phase
         _ACCOUNTANT.enter(self._phase)
-        self._t0 = time.perf_counter()
+        self._c0 = thread_time()
+        self._t0 = perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._hist.observe(time.perf_counter() - self._t0)
+        wall = perf_counter() - self._t0
+        cpu = thread_time() - self._c0
+        self._hist.observe(wall)
         _ACCOUNTANT.exit(self._phase)
-        _TLS.phase = self._outer
+        up = self._up
+        _TLS.phase = None if up is None else up._phase
+        rec = self._rec
+        # a timer held open across a generator's yield can be closed by
+        # whoever drops the generator: another thread's CPU clock says
+        # nothing of this one's, so such an exit is not booked
+        if rec.open is self and rec.thread.ident == get_ident():
+            rec.open = up
+            cell = rec.cells[self._phase]
+            cell[0] += wall - self._inner_wall
+            cell[1] += cpu - self._inner_cpu
+            cell[2] += 1
+            if up is not None:
+                up._inner_wall += wall
+                up._inner_cpu += cpu
         if self._ann is not None:
             self._ann.__exit__(*exc)
+        return False
+
+
+class _DeviceWait:
+    __slots__ = ("_t0", "_c0", "_ann")
+
+    def __enter__(self) -> "_DeviceWait":
+        cls = _ANNOTATION
+        if cls is None:
+            self._ann = None
+        else:
+            self._ann = cls("sched/" + DEVICE_WAIT)
+            self._ann.__enter__()
+        self._c0 = thread_time()
+        self._t0 = perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        wall = perf_counter() - self._t0
+        cpu = thread_time() - self._c0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        cell = _ledger().cells[DEVICE_WAIT]
+        cell[0] += wall
+        cell[1] += cpu
+        cell[2] += 1
         return False
 
 
@@ -219,6 +369,24 @@ def phase_timer(phase: str):
     if not _span._ENABLED:
         return _NULL
     return _PhaseTimer(_HIST[phase], phase)
+
+
+def device_wait():
+    """``with device_wait(): picks = np.asarray(chosen)`` round a host
+    read that waits for the device: booked on the reading thread's
+    record as DEVICE_WAIT (thread_totals()), and while annotations are
+    on a TraceAnnotation("sched/device_wait") that ends when the host
+    has the value, on the clock of the device's "XLA Modules" line. An
+    enqueue gets none. No-op while tracing is disabled."""
+    if not _span._ENABLED:
+        return _NULL
+    return _DeviceWait()
+
+
+def fetch(x) -> np.ndarray:
+    """np.asarray(x) of a device array, as a device_wait()."""
+    with device_wait():
+        return np.asarray(x)
 
 
 def phase_totals() -> Dict[str, float]:

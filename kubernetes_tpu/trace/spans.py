@@ -2,8 +2,8 @@
 
 Shape follows pkg/util/trace.go scaled up to cross-process traces: a
 span records (trace_id, span_id, parent_id, name, start, duration,
-attrs) into a process-global ring buffer served at /debug/traces and
-exportable as JSON lines. Parent/child nesting propagates through a
+attrs) into a process-global ring buffer served at /debug/traces.
+Parent/child nesting propagates through a
 contextvar (thread- and contextvars-safe). The trace id crosses the TLV
 wire as a pod ANNOTATION (metadata.annotations is an ordinary dict field
 of the registered ObjectMeta dataclass, so no wire schema change): the
@@ -19,8 +19,6 @@ read.
 
 from __future__ import annotations
 
-import contextlib
-import json
 import os
 import threading
 import time
@@ -117,15 +115,6 @@ class TraceBuffer:
         with self._lock:
             self._spans.clear()
 
-    def export_jsonl(self, fp) -> int:
-        """Write buffered spans as JSON lines, oldest first; returns the
-        count written."""
-        with self._lock:
-            spans = list(self._spans)
-        for s in spans:
-            fp.write(json.dumps(s) + "\n")
-        return len(spans)
-
 
 #: process-global buffer (the /debug/traces source on every daemon)
 BUFFER = TraceBuffer()
@@ -200,20 +189,6 @@ def span(name: str, **attrs: Any):
     if not _ENABLED:
         return _NULL
     return Span(name, attrs)
-
-
-@contextlib.contextmanager
-def trace_context(trace_id: Optional[str], span_id: str = ""):
-    """Adopt a remote trace id (wire continuation): spans opened inside
-    attach to `trace_id` instead of starting a fresh trace."""
-    if not trace_id or not _ENABLED:
-        yield
-        return
-    token = _CTX.set((trace_id, span_id or new_span_id()))
-    try:
-        yield
-    finally:
-        _CTX.reset(token)
 
 
 def record_span(name: str, trace_id: Optional[str], start: float,

@@ -9,7 +9,6 @@ follower reconnect; stalled-follower drop closes the socket).
 """
 
 import json
-import io
 import socket
 import threading
 import time
@@ -88,15 +87,7 @@ def test_span_threads_do_not_share_context():
     assert seen["tid"] != root.trace_id
 
 
-def test_trace_context_adopts_remote_trace():
-    tid = trace.new_trace_id()
-    with trace.trace_context(tid):
-        with trace.span("adopted") as s:
-            assert s.trace_id == tid
-    assert trace.current_trace_id() is None
-
-
-def test_buffer_ring_limit_and_jsonl_export():
+def test_buffer_ring_limit():
     buf = TraceBuffer(capacity=4)
     for i in range(10):
         buf.record({"trace_id": "t", "span_id": str(i), "name": "s",
@@ -105,10 +96,6 @@ def test_buffer_ring_limit_and_jsonl_export():
     snap = buf.snapshot(limit=100)
     assert len(snap) == 4  # ring evicted the oldest
     assert [s["span_id"] for s in snap] == ["9", "8", "7", "6"]
-    out = io.StringIO()
-    assert buf.export_jsonl(out) == 4
-    lines = [json.loads(l) for l in out.getvalue().splitlines()]
-    assert [l["span_id"] for l in lines] == ["6", "7", "8", "9"]
 
 
 def test_disabled_tracing_records_nothing():
