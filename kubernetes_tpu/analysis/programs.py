@@ -359,7 +359,7 @@ def build_programs(include_mesh: bool = True, num_nodes: int = 13,
               np.int32(Gz), np.int64(0)),
         allow_f64=True,
         carry_out_leaves=carry_leaves,
-        # chosen[G,K], n_done[G], L, the loops' own counters [2]
+        # chosen[G,K], n_done[G], L, the loops' own counters [3]
         expected_host_leaves=4,
         notes="grouped zoned device replay: G runs, one dispatch",
     ))

@@ -418,9 +418,12 @@ _SCAN, _SINGLE, _GROUP_HOST, _GROUP_DEVICE = range(len(PATHS))
 GROUP_COUNTERS = ("group_runs", "group_d2h_bytes", "group_reprobes")
 #: what `stats` counts of the grouped device replay (the single-chip
 #: driver's alone: the mesh has none): the pick steps and the run-slot
-#: iterations `jit_zreplay_group`'s two loops ran, by the program's own
-#: counters, and the pods it placed
-ZREPLAY_COUNTERS = ("zreplay_steps", "zreplay_slots", "zreplay_picks")
+#: iterations `jit_zreplay_group`'s two loops ran and the pick steps
+#: that evaluated the carried score again (a node picked twice since
+#: the last evaluation, or one that left the fit set), by the program's
+#: own counters, and the pods it placed
+ZREPLAY_COUNTERS = ("zreplay_steps", "zreplay_slots", "zreplay_rescores",
+                    "zreplay_picks")
 
 
 def count_group(stats: dict, counted: dict) -> None:
@@ -1394,9 +1397,11 @@ class WaveScheduler:
                     chosen = np.asarray(chosen)
                     n_done = np.asarray(n_done)
                     L_host = int(L)
-                    steps, slots = np.asarray(self._zreplay.group_ran)
+                    steps, slots, rescores = np.asarray(
+                        self._zreplay.group_ran)
             count_group(self.stats, {
                 "zreplay_steps": int(steps), "zreplay_slots": int(slots),
+                "zreplay_rescores": int(rescores),
                 "zreplay_picks": int((chosen >= 0).sum())})
             partial = None
             consumed = 0

@@ -10,10 +10,14 @@ commit fold in one jitted program, one dispatch, one small transfer out.
 K (and the group's G) only size the compiled buffers: the pick loop ends
 at the run's real length and the run loop at the group's real run
 count, both read from the program's input. Each step
-reassembles the combined score exactly as models/replay._scores (same
-float32/float64 formulas, same NaN -> minInt64 quirk, same selectHost
-round-robin in name-desc order) — differentially tested against the
-host spec replay and the oracle by tests/test_wave.py.
+scores with the combined score exactly as models/replay._scores gives it
+(same float32/float64 formulas, same NaN -> minInt64 quirk, same
+selectHost round-robin in name-desc order) — differentially tested
+against the host spec replay and the oracle by tests/test_wave.py — but
+evaluates at every step only the float32 spread term, which every commit
+moves: the rest of the score, emulated int64 and float64 on the chip, is
+carried from step to step and evaluated again when a pick spends it
+(_replay_run).
 
 Two entry points share the same probe+replay body:
 
@@ -48,7 +52,7 @@ from kubernetes_tpu.models.batch import (
     TAINT_TOLERATION,
     SchedulerConfig,
 )
-from kubernetes_tpu.models.probe import _probe_rows
+from kubernetes_tpu.models.probe import N_STK_ROWS, _probe_rows
 
 
 def _weights(config: SchedulerConfig):
@@ -59,19 +63,75 @@ def _weights(config: SchedulerConfig):
             int(w.get(INTER_POD_AFFINITY, 0)))
 
 
+def _round_robin(L, n, N):
+    """selectHost's L % n as int32, for the count L >= 0 (int64) of pods
+    placed so far and 1 <= n <= N ties (int32). An int64 remainder is
+    some 1,800 scalar instructions of emulated long division on the
+    chip, at every pick; under 2^16 nodes the same remainder comes from
+    L's two 32-bit halves, hi * 2^32 + lo, reduced mod n one by one
+    (every product stays under n * (n - 1) < 2^32)."""
+    if N >= 1 << 16:
+        return (L % n).astype(jnp.int32)
+    n = n.astype(jnp.uint32)
+    hi = (L >> 32).astype(jnp.uint32)
+    lo = L.astype(jnp.uint32)  # the low half: the conversion wraps
+    two32 = (jnp.uint32(0xFFFFFFFF) % n + 1) % n  # 2^32 mod n
+    return (((hi % n) * two32 + lo % n) % n).astype(jnp.int32)
+
+
+def _name_desc_tables(static):
+    """What a replay program needs of the name-desc permutation, made
+    ONCE a program and outside its run loop: the permutation, its
+    inverse (a run's commit counts go back to node order by a gather;
+    a scatter by `perm` is one serial update a node on the chip) and
+    the two allocatable rows LeastRequested / BalancedResourceAllocation
+    read, permuted."""
+    perm = static["name_desc_order"].astype(jnp.int32)
+    N = perm.shape[0]
+    inv = jnp.zeros((N,), jnp.int32).at[perm].set(
+        jnp.arange(N, dtype=jnp.int32), unique_indices=True)
+    return perm, inv, (static["alloc_mcpu"][perm],
+                       static["alloc_mem"][perm])
+
+
 def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
-                zone_id, veto, has_selectors, rows_dyn, k_real, L0):
+                perm, alloc, zone_id, veto, has_selectors, rows_dyn,
+                k_real, L0):
     """Probe `pod` against the live carry, then one pick step per pod of
     the run: the loop ends at k_real (<= K) or at a table-horizon bail.
 
-    zone_id/veto are PERMUTED to name-desc order already. Returns
-    (j i64[N] permuted-space commit counts, chosen i32[K] permuted-space
-    ids, -1 past the last step, L, n_done, bailed, the steps run)."""
+    A step scores in emulated 64-bit only what its pick changed. The
+    score is `base` (everything but the spread term: int64 and float64,
+    a function of the commit counts j and the fit mask alone) plus the
+    float32 spread term (which moves with every commit through the zone
+    sums). So the picks run in EPOCHS: an epoch evaluates `cur` = base at
+    j and `nxt` = base at j + 1 for all nodes, and its steps carry
+    `cur`: a pick of node m moves cur[m] to nxt[m] and marks m used. An
+    epoch ends when nxt[m] was spent already (m picked twice in it) or
+    fit[m] flipped (the maxima NodeAffinity / TaintToleration /
+    InterPodAffinity normalise by may move), and the next one evaluates
+    again. Same functions on the same inputs as a step that evaluated
+    all of it: the same integers. Where picks spread over the nodes a
+    run is one epoch; where they pile on one node an epoch is two steps,
+    which costs what the per-step evaluation did.
+
+    A step takes its pick as a MASK over the nodes and makes every
+    update a select on it (the commit count, the fit bit, the zone sums,
+    `cur`): on the chip a read or a write of one element at a traced
+    index is an op of its own with a gap behind it, and a step that
+    kept its books at node m made some twenty of them.
+
+    zone_id/veto/alloc are PERMUTED to name-desc order already. Returns
+    (j i32[N] permuted-space commit counts, chosen i32[K] permuted-space
+    ids, -1 past the last step, L, n_done, bailed, the steps run, the
+    epochs after the first: the rescores)."""
     stk, _tab = _probe_rows(config, num_zones, num_values, J, static,
                             carry, pod)
-    perm = static["name_desc_order"].astype(jnp.int32)
     N = perm.shape[0]
-    stk = stk[:, perm]
+    # ONE gather by the permutation: the header rows and, behind them,
+    # the two usage rows LR/BA read (a gather costs by its indices, not
+    # by the rows it moves)
+    stk = jnp.concatenate([stk, carry[0][3:5]])[:, perm]
     fit_static = stk[0] != 0
     frontier = stk[1]
     static_add = stk[2]
@@ -80,9 +140,12 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
     na_counts = stk[5]
     tt_counts = stk[6]
     ip_totals = stk[7]
-    # LR/BA scores are recomputed directly per step (int math, exactly
-    # the j-table's contents — R.least_requested/balanced mirror):
-    # cheaper on TPU than a variable-row gather from the packed table
+    nz_cpu0 = stk[N_STK_ROWS]
+    nz_mem0 = stk[N_STK_ROWS + 1]
+    alloc_cpu, alloc_mem = alloc
+    # LR/BA scores are computed directly (int math, exactly the
+    # j-table's contents — R.least_requested/balanced mirror): cheaper
+    # on TPU than a variable-row gather from the packed table
     from kubernetes_tpu.ops import priorities as R
 
     w_lr = w_ba = 0
@@ -91,71 +154,35 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
             w_lr += int(wt)
         elif name == BALANCED_ALLOCATION:
             w_ba += int(wt)
-    res = carry[0]  # (6, N) node-order
-    nz_cpu0 = res[3][perm]
-    nz_mem0 = res[4][perm]
-    alloc_cpu = static["alloc_mcpu"][perm]
-    alloc_mem = static["alloc_mem"][perm]
     # the veto (hostname self-anti): one committed copy per node
     frontier = jnp.where(veto, jnp.minimum(frontier, 1), frontier)
     w_spread, w_na, w_tt, w_ip = _weights(config)
 
     fit0 = fit_static & (0 < frontier)
+    # which nodes lie in which zone, and which zones are zones (0 is
+    # "no zone"): made once a run, read at every step
+    in_zone = zone_id[None, :] == jnp.arange(
+        num_zones, dtype=zone_id.dtype)[:, None]
+    is_zone = np.arange(num_zones) > 0
 
-    def scores(j, fit, zc):
+    def zone_sums(counts):
+        """ops/priorities.zone_sums' masked reduction (a scatter-add by
+        zone_id is one serial update a node on the chip), over the
+        run's own membership."""
+        return jnp.where(in_zone, counts[None, :], 0).sum(axis=1)
+
+    def base_pair(j, fit):
+        """-> (i64[N], i64[N]): the score without its spread term, all
+        nodes, at the commit counts j and at j + 1 (one evaluation over
+        both: the program holds the division chains once)."""
         score = static_add
-        if w_lr or w_ba:
-            nzj_cpu = nz_cpu0 + j * pod["nz_mcpu"]
-            nzj_mem = nz_mem0 + j * pod["nz_mem"]
-            if w_lr:
-                score = score + jnp.int64(w_lr) * R.least_requested(
-                    pod["nz_mcpu"], pod["nz_mem"], nzj_cpu, nzj_mem,
-                    alloc_cpu, alloc_mem,
-                )
-            if w_ba:
-                score = score + jnp.int64(w_ba) * \
-                    R.balanced_resource_allocation(
-                        pod["nz_mcpu"], pod["nz_mem"], nzj_cpu, nzj_mem,
-                        alloc_cpu, alloc_mem,
-                    )
-        if w_spread:
-            c = spread_base + jnp.where(selfmatch, j, 0)
-            M = jnp.maximum(c.max(where=fit, initial=0), 0)
-            cm = jnp.where(fit, c, 0)
-            f = jnp.where(
-                M > 0,
-                jnp.float32(10.0) * ((M - cm).astype(jnp.float32)
-                                     / M.astype(jnp.float32)),
-                jnp.float32(10.0),
-            )
-            zoned = num_zones > 1
-            if zoned:
-                # zc is maintained INCREMENTALLY in the scan state (a
-                # full scatter-add per step serializes on TPU)
-                have_zones = (fit & (zone_id > 0)).any()
-                max_zone = jnp.where(
-                    jnp.arange(num_zones) > 0, zc, 0
-                ).max(initial=0)
-                zone_score = jnp.float32(10.0) * (
-                    (max_zone - zc[zone_id]).astype(jnp.float32)
-                    / max_zone.astype(jnp.float32)
-                )
-                blended = (f * jnp.float32(1.0 / 3.0)
-                           + jnp.float32(2.0 / 3.0) * zone_score)
-                f = jnp.where(have_zones & (zone_id > 0), blended, f)
-            f = jnp.where(has_selectors, f, jnp.float32(10.0))
-            nan = jnp.isnan(f)
-            fi = jnp.where(nan, jnp.float32(0), f).astype(jnp.int64)
-            score = score + w_spread * jnp.where(
-                nan, jnp.int64(-(2**63)), fi
-            )
         # The na/tt/ip normalizers keep the host's EXACT float64
         # expression shapes (replay._scores): integer-division rewrites
         # are NOT equivalent under double rounding — TaintToleration's
         # (1.0 - c/mx)*10.0 truncates to 0 where (10*(mx-c))//mx gives 1
         # (e.g. mx=20, c=18), a divergence an adversarial review repro
-        # caught. float64 is emulated on TPU but measured negligible
-        # here; the scan's cost was the per-step zone scatter.
+        # caught. float64 is emulated on TPU, which is why it is
+        # evaluated when the fit set changes and not at every step.
         if w_na:
             mx = jnp.maximum(na_counts.max(where=fit, initial=0), 0)
             f = jnp.where(
@@ -192,55 +219,127 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
             score = score + w_ip * jnp.where(
                 fit, f.astype(jnp.int64), 0
             )
-        return score
+        if not (w_lr or w_ba):
+            return score, score
+        # both commit counts side by side on the node axis (a [2, N]
+        # stack would take the chip's tiles a quarter full)
+        def twice(x):
+            return jnp.concatenate([x, x])
 
-    def step(state):
-        i, j, fit, zc, L, n_done, stopped, chosen = state
-        sched = fit.any()
-        score = scores(j, fit, zc)
-        smax = jnp.where(fit, score, jnp.int64(-(2**63))).max()
-        ties = fit & (score == smax)
-        num_ties = jnp.maximum(ties.sum(), 1)
-        r = (L % num_ties).astype(jnp.int32)
-        tie_rank = jnp.cumsum(ties.astype(jnp.int32)) - 1
-        m = jnp.argmax(ties & (tie_rank == r)).astype(jnp.int32)
-        # zone-count bookkeeping around the commit (only column m moves)
-        sm = jnp.where(selfmatch, jnp.int64(1), jnp.int64(0))
-        c_old_m = spread_base[m] + sm * j[m]
-        contrib_old = jnp.where(fit[m], c_old_m, 0)
-        j = j.at[m].add(jnp.where(sched, 1, 0))
-        L = L + sched.astype(jnp.int64)
-        jm = j[m]
-        # the bail ends the loop: at most one ever fires
-        bail = sched & (jm >= rows_dyn)
-        n_done = jnp.where(bail, i + 1, n_done)
-        new_fit_m = fit_static[m] & (jm < frontier[m])
-        fit = fit.at[m].set(jnp.where(sched, new_fit_m, fit[m]))
-        c_new_m = spread_base[m] + sm * jm
-        contrib_new = jnp.where(fit[m], c_new_m, 0)
-        zc = zc.at[zone_id[m]].add(
-            jnp.where(sched, contrib_new - contrib_old, 0)
+        jj = jnp.concatenate([j, j + 1])
+        usage = (pod["nz_mcpu"], pod["nz_mem"],
+                 twice(nz_cpu0) + jj * pod["nz_mcpu"],
+                 twice(nz_mem0) + jj * pod["nz_mem"],
+                 twice(alloc_cpu), twice(alloc_mem))
+        lrba = jnp.zeros((2 * N,), jnp.int64)
+        if w_lr:
+            lrba = lrba + jnp.int64(w_lr) * R.least_requested(*usage)
+        if w_ba:
+            lrba = lrba + jnp.int64(w_ba) * \
+                R.balanced_resource_allocation(*usage)
+        return score + lrba[:N], score + lrba[N:]
+
+    def counted(j, fit):
+        """What SelectorSpread counts on each node that fits."""
+        return jnp.where(
+            fit, spread_base + jnp.where(selfmatch, j, 0).astype(jnp.int64),
+            0)
+
+    def spread(j, fit, zc):
+        """The weighted SelectorSpread term (float32 as upstream): it
+        moves with every commit, through M and the zone sums."""
+        cm = counted(j, fit)
+        M = jnp.maximum(cm.max(where=fit, initial=0), 0)
+        f = jnp.where(
+            M > 0,
+            jnp.float32(10.0) * ((M - cm).astype(jnp.float32)
+                                 / M.astype(jnp.float32)),
+            jnp.float32(10.0),
         )
-        chosen = chosen.at[i].set(jnp.where(sched, m, jnp.int32(-1)))
-        return i + 1, j, fit, zc, L, n_done, stopped | bail, chosen
+        zoned = num_zones > 1
+        if zoned:
+            # zc is maintained INCREMENTALLY in the loop state (a
+            # full scatter-add per step serializes on TPU)
+            have_zones = (fit & (zone_id > 0)).any()
+            max_zone = jnp.where(is_zone, zc, 0).max(initial=0)
+            # a zone's score once a zone, then dealt to its nodes: the
+            # same float32 values as the expression over zc[zone_id]
+            zone_score = (jnp.float32(10.0) * (
+                (max_zone - zc).astype(jnp.float32)
+                / max_zone.astype(jnp.float32)
+            ))[zone_id]
+            blended = (f * jnp.float32(1.0 / 3.0)
+                       + jnp.float32(2.0 / 3.0) * zone_score)
+            f = jnp.where(have_zones & (zone_id > 0), blended, f)
+        f = jnp.where(has_selectors, f, jnp.float32(10.0))
+        nan = jnp.isnan(f)
+        fi = jnp.where(nan, jnp.float32(0), f).astype(jnp.int64)
+        return w_spread * jnp.where(nan, jnp.int64(-(2**63)), fi)
 
     def more(state):
         i, stopped = state[0], state[6]
         return (i < k_real) & ~stopped
 
-    zc0 = jnp.zeros((num_zones,), jnp.int64).at[zone_id].add(
-        jnp.where(fit0, spread_base, 0)
-    )
+    def epoch(state):
+        """Evaluate the carried score at j and at j + 1, then pick
+        until a pick spends the evaluation or the run ends."""
+        i0 = state[0]
+        cur0, nxt = base_pair(state[1].astype(jnp.int64), state[2])
+
+        def step(state):
+            (i, j, fit, zc, L, n_done, stopped, chosen, cur, used,
+             _stale) = state
+            sched = fit.any()
+            score = cur + spread(j, fit, zc) if w_spread else cur
+            smax = jnp.where(fit, score, jnp.int64(-(2**63))).max()
+            ties = fit & (score == smax)
+            num_ties = jnp.maximum(ties.sum(dtype=jnp.int32), 1)
+            r = _round_robin(L, num_ties, N)
+            tie_rank = jnp.cumsum(ties.astype(jnp.int32)) - 1
+            # the pick as a mask: one node where any fits, none where
+            # none does (then nothing below moves)
+            pick = ties & (tie_rank == r)
+            m = jnp.argmax(pick).astype(jnp.int32)
+            j_new = j + pick.astype(jnp.int32)
+            fit_new = jnp.where(
+                pick, fit_static & (j_new < frontier), fit)
+            # the zone sums move by what the picked node counted before
+            # and counts now
+            zc = zc + zone_sums(jnp.where(
+                pick, counted(j_new, fit_new) - counted(j, fit), 0))
+            L = L + sched.astype(jnp.int64)
+            # the bail ends the loop: at most one ever fires
+            bail = (pick & (j_new >= rows_dyn)).any()
+            n_done = jnp.where(bail, i + 1, n_done)
+            chosen = chosen.at[i].set(jnp.where(sched, m, jnp.int32(-1)))
+            # the picked node moved to its next commit count, where the
+            # carried score is nxt's: unless that was spent already (the
+            # node picked before in this epoch) or the node left the fit
+            # set (the normalisers' maxima may move) — then the epoch
+            # ends
+            stale = (pick & (used | (fit_new != fit))).any()
+            return (i + 1, j_new, fit_new, zc, L, n_done, stopped | bail,
+                    chosen, jnp.where(pick, nxt, cur), used | pick, stale)
+
+        def fresh(state):
+            return more(state) & ~state[10]
+
+        out = jax.lax.while_loop(
+            fresh, step,
+            (*state[:8], cur0, jnp.zeros((N,), bool), jnp.bool_(False)),
+        )
+        # a run's first epoch is no rescore
+        return (*out[:8], state[8] + (i0 > 0).astype(jnp.int32))
+
+    zc0 = zone_sums(counted(jnp.int32(0), fit0))
     k_real = k_real.astype(jnp.int32)
-    state0 = (
-        jnp.int32(0), jnp.zeros((N,), jnp.int64), fit0, zc0,
-        jnp.int64(L0), k_real, jnp.bool_(False),
-        jnp.full((K,), -1, jnp.int32),
-    )
-    steps, j, _fit, _zc, L, n_done, stopped, chosen = jax.lax.while_loop(
-        more, step, state0
-    )
-    return j, chosen, L, n_done, stopped, steps
+    steps, j, _fit, _zc, L, n_done, stopped, chosen, rescores = \
+        jax.lax.while_loop(more, epoch, (
+            jnp.int32(0), jnp.zeros((N,), jnp.int32), fit0, zc0,
+            jnp.int64(L0), k_real, jnp.bool_(False),
+            jnp.full((K,), -1, jnp.int32), jnp.int32(0),
+        ))
+    return j, chosen, L, n_done, stopped, steps, rescores
 
 
 @jax.named_scope("zreplay")
@@ -260,14 +359,13 @@ def _zreplay_fn(config, num_zones, num_values, J, K, layout, apply_fn,
         prev_pod = _unpack_pod(layout, prev_buf)
         carry = apply_fn(static, carry, prev_pod, prev_counts)
     pod = _unpack_pod(layout, pod_buf)
-    perm = static["name_desc_order"].astype(jnp.int32)
-    N = perm.shape[0]
-    j, chosen, L, n_done, _stopped, _steps = _replay_run(
-        config, num_zones, num_values, J, K, static, carry, pod,
-        zone_id, veto, has_selectors, rows_dyn, k_real, L0,
+    perm, inv, alloc = _name_desc_tables(static)
+    j, chosen, L, n_done, _stopped, _steps, _rescores = _replay_run(
+        config, num_zones, num_values, J, K, static, carry, pod, perm,
+        alloc, zone_id, veto, has_selectors, rows_dyn, k_real, L0,
     )
     # permuted j -> node-order counts; fold THIS run's commits
-    counts = jnp.zeros((N,), jnp.int64).at[perm].set(j)
+    counts = j[inv].astype(jnp.int64)
     carry = apply_fn(static, carry, pod, counts)
     return carry, chosen, counts, L, n_done
 
@@ -284,8 +382,9 @@ def _zreplay_group_fn(config, num_zones, num_values, J, K, G, layout,
     per-run loop would. A table-horizon bail ends the loop (the runs
     behind it, like the slots past `runs`, keep n_done == 0 and picks of
     -1); the host resumes from there. Returns (carry', chosen[G, K],
-    n_done[G], L', ran i32[2]: the pick steps and the run-slot
-    iterations the two loops ran)."""
+    n_done[G], L', ran i32[3]: the pick steps and the run-slot
+    iterations the two loops ran, and the steps that evaluated the
+    carried score again)."""
     from kubernetes_tpu.models.pack import unpack as _unpack_pod
 
     if prev_kind == "single":
@@ -295,32 +394,33 @@ def _zreplay_group_fn(config, num_zones, num_values, J, K, G, layout,
         carry = apply_group_fn(prev_layout, static, carry, prev_buf,
                                prev_counts)
     pods = _unpack_pod(layout, group_buf)  # each field: leading G axis
-    perm = static["name_desc_order"].astype(jnp.int32)
-    N = perm.shape[0]
+    perm, inv, alloc = _name_desc_tables(static)
 
     def run_body(state):
-        g, carry, L, _bailed, chosen, n_done, steps = state
+        g, carry, L, _bailed, chosen, n_done, steps, rescores = state
         pod = {f: v[g] for f, v in pods.items()}
-        j, picks, L, done, bailed, ran = _replay_run(
+        j, picks, L, done, bailed, ran, rescored = _replay_run(
             config, num_zones, num_values, J, K, static, carry, pod,
-            zone_id, vetos[g], has_sels[g], rows_arr[g], k_reals[g], L,
+            perm, alloc, zone_id, vetos[g], has_sels[g], rows_arr[g],
+            k_reals[g], L,
         )
-        counts = jnp.zeros((N,), jnp.int64).at[perm].set(j)
-        carry = apply_fn(static, carry, pod, counts)
+        carry = apply_fn(static, carry, pod, j[inv].astype(jnp.int64))
         return (g + 1, carry, L, bailed, chosen.at[g].set(picks),
-                n_done.at[g].set(done), steps + ran)
+                n_done.at[g].set(done), steps + ran,
+                rescores + rescored)
 
     def more(state):
         g, bailed = state[0], state[3]
         return (g < runs) & ~bailed
 
-    slots, carry, L, _bailed, chosen, n_done, steps = jax.lax.while_loop(
+    (slots, carry, L, _bailed, chosen, n_done, steps,
+     rescores) = jax.lax.while_loop(
         more, run_body,
         (jnp.int32(0), carry, jnp.int64(L0), jnp.bool_(False),
          jnp.full((G, K), -1, jnp.int32), jnp.zeros((G,), jnp.int32),
-         jnp.int32(0)),
+         jnp.int32(0), jnp.int32(0)),
     )
-    return carry, chosen, n_done, L, jnp.stack([steps, slots])
+    return carry, chosen, n_done, L, jnp.stack([steps, slots, rescores])
 
 
 class ZReplay:
@@ -332,9 +432,9 @@ class ZReplay:
         self.apply_fn = apply_fn
         self.apply_group_fn = apply_group_fn
         self._jitted = {}
-        #: i32[2] on the device: the pick steps and run-slot iterations
-        #: the last run_group dispatch ran (its return stays the four
-        #: values its callers unpack)
+        #: i32[3] on the device: the pick steps and run-slot iterations
+        #: the last run_group dispatch ran and the steps that rescored
+        #: (its return stays the four values its callers unpack)
         self.group_ran = None
 
     def run(self, static, carry, prev_buf, prev_counts, pod_buf, layout,

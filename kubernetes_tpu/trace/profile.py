@@ -471,7 +471,8 @@ _WAVE: Dict[str, Any] = {"waves": 0, "pods_by_path": {},
                          "dispatches_by_kind": {}, "pods_unplaced": 0,
                          "group_runs": 0, "group_d2h_bytes": 0,
                          "group_reprobes": 0, "zreplay_steps": 0,
-                         "zreplay_slots": 0, "zreplay_picks": 0}
+                         "zreplay_slots": 0, "zreplay_rescores": 0,
+                         "zreplay_picks": 0}
 
 
 def count_wave(pods_by_path: Dict[str, int], dispatches: Dict[str, int],
@@ -493,7 +494,8 @@ def count_wave_group(counted: Dict[str, int]) -> None:
     """A grouped header probe was replayed on the host: its runs, the
     bytes it fetched, whether it stopped early (`group_*` of _WAVE); or
     a grouped device replay came back: the steps and run slots its
-    loops ran, the pods it placed (`zreplay_*`)."""
+    loops ran, the steps that rescored, the pods it placed
+    (`zreplay_*`)."""
     with _wave_lock:
         for k, n in counted.items():
             _WAVE[k] += n

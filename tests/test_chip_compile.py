@@ -102,16 +102,86 @@ def _params():
                 marks=[pytest.mark.slow] if slow else [])
 
 
-@pytest.mark.parametrize("bucket,name", list(_params()))
-def test_served_program_compiles_for_v5e(bucket, name, registry, one_chip):
+@pytest.fixture(scope="module")
+def served(registry, one_chip):
+    """(bucket, name) -> the program compiled for the described chip,
+    once a module: a test that reads the compiled text shares the
+    compile with the one that made it."""
     import jax
 
-    spec = registry(bucket)[name]
-    fn = spec.fn if hasattr(spec.fn, "lower") else jax.jit(spec.fn)
-    compiled = fn.lower(*_shapes(spec.args, one_chip)).compile()
-    mem = compiled.memory_analysis()
+    made = {}
+
+    def get(bucket, name):
+        if (bucket, name) not in made:
+            spec = registry(bucket)[name]
+            fn = spec.fn if hasattr(spec.fn, "lower") else jax.jit(spec.fn)
+            made[bucket, name] = fn.lower(
+                *_shapes(spec.args, one_chip)).compile()
+        return made[bucket, name]
+
+    return get
+
+
+@pytest.mark.parametrize("bucket,name", list(_params()))
+def test_served_program_compiles_for_v5e(bucket, name, served):
+    mem = served(bucket, name).memory_analysis()
     # one program's arguments + temporaries, against a 16 GB chip
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 1 << 30
+
+
+def _loop_bodies(text):
+    """The compiled text's `while` loops: [(the loop's name, its body's
+    instruction lines)], and every computation's lines by name."""
+    import re
+
+    computations, lines = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head and not line.startswith(" "):
+            lines = computations.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    loops = [(m.group(1), computations[m.group(2)])
+             for body in computations.values() for line in body
+             for m in [re.search(
+                 r"%?([\w.\-]+) = .* while\(.*body=%?([\w.\-]+)", line)]
+             if m]
+    return loops, computations
+
+
+def test_a_pick_of_the_grouped_replay_costs_what_it_changes(served):
+    """What only the chip's compiler shows, read from the program the
+    test above compiled (described-v5e text, no chip run): the pick step
+    of `jit_zreplay_group`, its innermost loop, holds the spread blend,
+    the maximum, the tie-break and the bump: 18 fusions. Before the
+    score without its spread term was carried from step to step it held
+    44, 21 of them LeastRequested's two emulated-int64 divisions over
+    every node; and `L % ties` in int64 was 1,800 scalar instructions,
+    which the body's length pins. No loop of the program scatters: a
+    run's first zone sums and its commit counts back in node order were
+    two emulated-int64 scatters a run slot, 0.53 ms on the chip."""
+    import re
+
+    loops, computations = _loop_bodies(served(1024, "zreplay_group")
+                                       .as_text())
+    # run slots > epochs > pick steps
+    assert len(loops) == 3, [name for name, _ in loops]
+    innermost = [body for _, body in loops
+                 if not any(" while(" in line for line in body)]
+    assert len(innermost) == 1
+    step, = innermost
+    fusions = [line for line in step if " fusion(" in line]
+    assert len(fusions) <= 24, len(fusions)
+    assert len(step) < 800, len(step)
+    for name, body in loops:
+        for line in body:
+            called = re.search(r" fusion\(.*calls=%?([\w.\-]+)", line)
+            if called:
+                assert not any(
+                    " scatter(" in op
+                    for op in computations[called.group(1)]), (name, line)
 
 
 #: seconds the chip's compiler may take over one shipment's unpack

@@ -1018,7 +1018,8 @@ def test_wave_grouped_mesh_matches_oracle():
     assert d.get("group_probe", 0) >= 1, f"mesh grouping idle: {d}"
 
 
-def _wave_scheduler_run(state, pods, max_j=1024, replay=None):
+def _wave_scheduler_run(state, pods, max_j=1024, replay=None,
+                        last_node_index=0):
     """Drive WaveScheduler directly (dedup + pad like the algorithm
     shell): -> (hosts, the scheduler)."""
     from kubernetes_tpu.models.wave import WaveScheduler
@@ -1038,7 +1039,8 @@ def _wave_scheduler_run(state, pods, max_j=1024, replay=None):
     snap_p = _pad_snapshot(snap, next_pow2(snap.num_nodes, 4))
     ws = WaveScheduler(min_run=1, max_j=max_j, replay=replay)
     chosen, _, _ = ws.schedule_backlog(
-        snap_p, batch, np.asarray(rep_idx, np.int64)
+        snap_p, batch, np.asarray(rep_idx, np.int64),
+        last_node_index=last_node_index,
     )
     got = [snap.node_names[c] if 0 <= c < snap.num_nodes else None
            for c in chosen]
@@ -1165,3 +1167,187 @@ def test_device_replay_loops_end_at_the_real_runs_and_picks(
         assert ws.dispatches == {"zreplay_group": 1}
         assert (ws.stats["zreplay_slots"], ws.stats["zreplay_steps"],
                 ws.stats["zreplay_picks"]) == first_ran
+
+
+# -- the device replay carries the score without its spread term ---------------
+#
+# A pick step takes the non-spread score of the node it picked from the
+# evaluation at j + 1 that opened its epoch; the epoch ends, and the next
+# evaluates again, when a node is picked twice in it or a node's fit bit
+# flips (models/zreplay._replay_run). Each case forces one of the two, or
+# neither, and the picks must be the host replay's and the serial
+# oracle's.
+
+
+def _existing(node, i, requests):
+    """A bound pod no service selects."""
+    return Pod(
+        metadata=ObjectMeta(name=f"held-{i:03d}", labels={"held": "yes"}),
+        spec=PodSpec(node_name=node.metadata.name,
+                     containers=[Container(requests=dict(requests))]))
+
+
+def _one_short_of(what):
+    """24 zoned nodes, every second one a single pod short of its
+    allocatable pods / memory: its fit bit flips at its first pick."""
+    nodes = zoned_density_nodes(
+        24, cpu="16", **({"pods_cap": "3"} if what == "pods" else {}))
+    held = []
+    for i, node in enumerate(nodes[::2]):
+        if what == "pods":
+            held += [_existing(node, 2 * i + k, {"cpu": "10m"})
+                     for k in range(2)]
+        else:
+            node.status.allocatable["memory"] = "300Mi"
+            held.append(_existing(node, i, {"memory": "200Mi"}))
+    state = spread_state(nodes)
+    for pod in held:
+        state.assign(pod)
+    return state
+
+
+def _preferring(pods):
+    """Every pod prefers disktype=ssd (weight 5) and any `gen` (3)."""
+    from kubernetes_tpu.api.types import (
+        Affinity, NodeAffinity, NodeSelectorRequirement, NodeSelectorTerm,
+        PreferredSchedulingTerm)
+
+    def term(weight, **req):
+        return PreferredSchedulingTerm(
+            weight=weight, preference=NodeSelectorTerm(
+                match_expressions=(NodeSelectorRequirement(**req),)))
+
+    for p in pods:
+        p.spec.affinity = Affinity(node_affinity=NodeAffinity(
+            preferred_during_scheduling_ignored_during_execution=(
+                term(5, key="disktype", operator="In", values=("ssd",)),
+                term(3, key="gen", operator="Exists"))))
+    return pods
+
+
+def _preferred_nodes():
+    """The nodes that hold NodeAffinity's maximum (both labels, count 8)
+    take 2 pods each and the ssd ones 4: the maximum the normaliser
+    divides by falls 8 -> 5 -> 3 as they leave the fit set."""
+    nodes = zoned_density_nodes(24, cpu="16")
+    for i, node in enumerate(nodes):
+        if i % 4 == 0:
+            node.metadata.labels["disktype"] = "ssd"
+            node.status.allocatable["pods"] = "4"
+        if i % 6 == 0:
+            node.metadata.labels["gen"] = "2"
+        if i % 12 == 0:
+            node.status.allocatable["pods"] = "2"
+    return spread_state(nodes)
+
+
+def _tainted_nodes():
+    """8 zoned nodes of 4 places with 13..20 PreferNoSchedule taints the
+    pods tolerate one of: TaintToleration's maximum moves as they fill."""
+    import json as _json
+
+    from kubernetes_tpu.api.types import TAINTS_ANNOTATION
+
+    nodes = zoned_density_nodes(8, zones=("a", "b"), pods_cap="4")
+    for i, node in enumerate(nodes):
+        node.metadata.annotations = {TAINTS_ANNOTATION: _json.dumps([
+            {"key": f"t{k}", "value": "v", "effect": "PreferNoSchedule"}
+            for k in range(13 + i)])}
+    return spread_state(nodes)
+
+
+def _tolerating(pods):
+    from kubernetes_tpu.api.types import Toleration
+
+    for p in pods:
+        p.spec.tolerations = [Toleration(
+            key="t0", operator="Equal", value="v",
+            effect="PreferNoSchedule")]
+    return pods
+
+
+def _forty_shapes(per):
+    """hetero-1k's 40 request pairs, `per` pods of each in a row."""
+    pods = []
+    for t in range(40):
+        pods += [Pod(
+            metadata=ObjectMeta(name=f"s{t:02d}-{i:02d}",
+                                labels={"name": "sched-perf"}),
+            spec=PodSpec(containers=[Container(requests={
+                "cpu": f"{50 + (t % 8) * 25}m",
+                "memory": f"{100 + (t % 5) * 100}Mi"})]))
+            for i in range(per)]
+    return pods
+
+
+def _self_anti_rows():
+    """Two runs with hostname self-anti-affinity on zoned nodes: the
+    veto takes every picked node out of the fit set, so every step ends
+    its epoch."""
+    nodes = zoned_density_nodes(12)
+    for node in nodes:
+        node.metadata.labels["kubernetes.io/hostname"] = node.metadata.name
+    services = [Service(metadata=ObjectMeta(name=f"svc-{app}"),
+                        spec=ServiceSpec(selector={"app": app}))
+                for app in "xy"]
+    return (ClusterState.build(nodes, services=services),
+            _anti_pods(8, {"app": "x"})
+            + _anti_pods(8, {"app": "y"}, name0=100,
+                         requests={"cpu": "200m"}))
+
+
+CARRIED_SCORE_CASES = {
+    # name: (-> (state, pods), the first lastNodeIndex, whether the
+    #        grouped program must have rescored: True / False / None
+    #        where the case does not say)
+    # 30 picks on 12 nodes: every node picked more than once in a run
+    "node-picked-twice": (
+        lambda: (_roomy(12), _runs([30, 30])), 0, True),
+    "one-pod-short-of-pods": (
+        lambda: (_one_short_of("pods"), _runs([20, 20])), 0, True),
+    "one-pod-short-of-memory": (
+        lambda: (_one_short_of("memory"), _runs([20, 20])), 0, True),
+    "preferred-node-affinity": (
+        lambda: (_preferred_nodes(), _preferring(_runs([30, 30]))), 0,
+        True),
+    "prefer-no-schedule-taints": (
+        lambda: (_tainted_nodes(), _tolerating(_runs([20, 20]))), 0, True),
+    # unequal requests leave unequal LeastRequested / Balanced scores
+    # behind, which can outweigh a node's spread share (10 / 3 points):
+    # a run may come back to a node, so the case does not say
+    "forty-request-shapes": (
+        lambda: (_roomy(48), _forty_shapes(10)), 0, None),
+    # one shape on as many nodes: no run comes back to a node
+    "one-shape-many-nodes": (
+        lambda: (_roomy(48), _runs([10] * 12)), 0, False),
+    "self-anti-veto": (_self_anti_rows, 0, True),
+    # selectHost's remainder from both halves of a 64-bit index, ties
+    # everywhere
+    "round-robin-past-2-to-the-32": (
+        lambda: (_roomy(12), _runs([12] * 3)), (1 << 33) + 5, None),
+    "round-robin-at-the-32-bit-edge": (
+        lambda: (_roomy(12), _runs([12] * 3)), (1 << 32) - 7, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CARRIED_SCORE_CASES))
+def test_device_replay_carries_the_score_and_picks_as_the_serial_does(case):
+    from kubernetes_tpu.models.replay import replay_spec
+
+    make, L0, rescored = CARRIED_SCORE_CASES[case]
+    state, pods = make()
+    oracle = GenericScheduler(
+        predicates=ORACLE_PREDICATES, priorities=ORACLE_PRIORITIES,
+        last_node_index=L0)
+    want = oracle.schedule_backlog(pods, state.clone())
+    got, ws = _wave_scheduler_run(state, pods, last_node_index=L0)
+    got_host, _ = _wave_scheduler_run(state, pods, replay=replay_spec,
+                                      last_node_index=L0)
+    assert got == got_host == want
+    stats = ws.stats
+    assert ws.dispatches.get("zreplay_group", 0) >= 1, ws.dispatches
+    assert stats["zreplay_picks"] == sum(h is not None for h in got)
+    if rescored is not None:
+        assert (stats["zreplay_rescores"] > 0) == rescored, stats
+    # an epoch holds a step at least
+    assert stats["zreplay_rescores"] <= stats["zreplay_steps"]

@@ -5,8 +5,9 @@ WaveScheduler.stats["pods_by_path"] / ["dispatches_by_kind"] /
 commit different requests goes through one grouped header probe and
 picks as the serial oracle does, and the `group_*` counters say so on
 both drivers. The grouped device replay's `zreplay_*` counters say how
-many run slots and pick steps its loops ran, and a wave of another run
-count inside one bucket builds no program. And the scan path picks as
+many run slots and pick steps its loops ran and how many steps evaluated
+the carried score again, and a wave of another run count inside one
+bucket builds no program. And the scan path picks as
 the serial oracle does where
 selector rows are all distinct, multi-hot, or followed by a pod that
 fits nowhere and by padding."""
@@ -183,6 +184,48 @@ def test_zreplay_counters_say_what_the_two_loops_ran(case, runs):
     shown = render_traces({"limit": "1"})["wave"]
     for key in ZREPLAY_COUNTERS:
         assert shown[key] - shown_before[key] == stats[key]
+
+
+def test_the_rows_shape_is_one_evaluation_a_run():
+    """`spread-3k.rows` cut down (72 runs of 40 on 3,000 nodes there):
+    a controller's 40 replicas in a row on a zoned cluster with more
+    nodes a zone than a run has pods, one request shape. No run comes
+    back to a node and no node fills, so the score a run carries is
+    evaluated once a run: no step rescores, and a step is a pick."""
+    from kubernetes_tpu.oracle import ClusterState, GenericScheduler
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    from tests.test_conformance import ORACLE_PREDICATES, ORACLE_PRIORITIES
+
+    state = ClusterState.build(_nodes(150), controllers=_controllers(9))
+    backlog = _in_rows(9, 40)
+    algo = TPUScheduleAlgorithm()
+    oracle = GenericScheduler(predicates=ORACLE_PREDICATES,
+                              priorities=ORACLE_PRIORITIES)
+    assert algo.schedule_backlog(backlog, state) \
+        == oracle.schedule_backlog(backlog, state.clone())
+    stats = algo._wave.stats
+    assert stats["pods_by_path"]["group_device"] == 360
+    assert stats["zreplay_slots"] == 9
+    assert stats["zreplay_steps"] == stats["zreplay_picks"] == 360
+    assert stats["zreplay_rescores"] == 0
+
+
+def test_a_run_longer_than_its_nodes_rescores_and_says_so():
+    """`rows-zoned`: 40 replicas on 30 nodes come back to a node, so the
+    carried score is evaluated again, and /debug/traces counts it."""
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    nodes, zones, controllers, backlog, _only = CASES["rows-zoned"]
+    state = ClusterState.build(_nodes(nodes, zones),
+                               controllers=_controllers(controllers))
+    algo = TPUScheduleAlgorithm()
+    before = profile.wave_totals()["zreplay_rescores"]
+    assert None not in algo.schedule_backlog(backlog, state)
+    rescores = algo._wave.stats["zreplay_rescores"]
+    assert 0 < rescores <= algo._wave.stats["zreplay_steps"] == 160
+    assert profile.wave_totals()["zreplay_rescores"] - before == rescores
 
 
 def test_a_wave_of_another_run_count_builds_no_program():
