@@ -424,17 +424,45 @@ GROUP_COUNTERS = ("group_runs", "group_d2h_bytes", "group_reprobes")
 #: own counters, and the pods it placed
 ZREPLAY_COUNTERS = ("zreplay_steps", "zreplay_slots", "zreplay_rescores",
                     "zreplay_picks")
+#: what `stats` counts of the runs that carry a self-anti veto (pods
+#: whose required hostname anti-affinity term selects their own labels;
+#: `run_eligible`), which `run_single` decides one probe a run: the
+#: runs, the pods they placed, and summed over the runs the real nodes
+#: the run's FIRST probe found unfit, on the tables it shipped (where
+#: nothing but the terms of bound pods excludes a node, how much of the
+#: cluster they have taken from a run before it starts); counted at the
+#: wave's end, with `pods_by_path`
+ANTI_COUNTERS = ("anti_runs", "anti_picks", "anti_nodes_excluded")
+#: which encoder made a wave's snapshot (counted where the scheduler
+#: chooses, scheduler/tpu_algorithm), and by which scope gate of the
+#: incremental one a wave went to the from-scratch encoder
+#: (snapshot/incremental.IncrementalEncoder.fallback)
+ENCODERS = ("incremental", "full")
 
 
 def count_group(stats: dict, counted: dict) -> None:
-    """Some of `GROUP_COUNTERS` or `ZREPLAY_COUNTERS` into a driver's
-    cumulative `stats`, and into the process-wide totals on
-    /debug/traces."""
+    """Some of `GROUP_COUNTERS`, `ZREPLAY_COUNTERS` or `ANTI_COUNTERS`
+    into a driver's cumulative `stats`, and into the process-wide totals
+    on /debug/traces."""
     from kubernetes_tpu.trace.profile import count_wave_group
 
     for key, n in counted.items():
         stats[key] += n
     count_wave_group(counted)
+
+
+def count_encoder(stats: dict, encoder: str,
+                  fallback: Optional[str] = None) -> None:
+    """A wave's snapshot was made by `encoder` (one of ENCODERS), sent
+    there by the scope gate `fallback` if by any: into a driver's
+    cumulative `stats` and the process-wide totals on /debug/traces."""
+    from kubernetes_tpu.trace.profile import count_wave_encoder
+
+    stats["waves_by_encoder"][encoder] += 1
+    if fallback:
+        by_reason = stats["encoder_fallbacks"]
+        by_reason[fallback] = by_reason.get(fallback, 0) + 1
+    count_wave_encoder(encoder, fallback)
 
 
 #: pick-buffer length floors of the zoned device replay: one run per
@@ -682,6 +710,13 @@ class WaveScheduler:
             **dict.fromkeys(GROUP_COUNTERS, 0),
             # the grouped device replay (`run_group_device`), all waves
             **dict.fromkeys(ZREPLAY_COUNTERS, 0),
+            # the runs with a self-anti veto (`run_single`), all waves
+            **dict.fromkeys(ANTI_COUNTERS, 0),
+            # the encoder behind each wave's snapshot, and the scope
+            # gates that sent waves to the from-scratch one
+            # (`count_encoder`; the scheduler counts, the driver keeps)
+            "waves_by_encoder": dict.fromkeys(ENCODERS, 0),
+            "encoder_fallbacks": {},
         }
 
     # fraction of changed rows above which a scatter-row update loses
@@ -1205,6 +1240,12 @@ class WaveScheduler:
                 # the director's post-hoc check guards the binds
                 info["gang"] = None
 
+        # what the wave's runs with a self-anti veto did (ANTI_COUNTERS),
+        # into `stats` with the wave's other tallies at its end: a read
+        # in the middle of a wave never finds picks ahead of the pods
+        # decided (one chip run's `anti_run_share.fill` read 101.4)
+        anti = dict.fromkeys(ANTI_COUNTERS, 0)
+
         def run_single(carry, info, done0=0):
             """The per-run fast path: probe_fused (or the single-run
             device replay) + host replay + deferred fold — one device
@@ -1262,6 +1303,12 @@ class WaveScheduler:
                         self_anti_veto=self_anti_veto,
                         svc_ctx=svc_ctx,
                     )
+                if self_anti_veto is not None and done == done0:
+                    # the run's first probe: the nodes it finds unfit (a
+                    # slot without allocatable is padding or a node gone)
+                    real = np.asarray(snap.alloc_pods) > 0
+                    anti["anti_nodes_excluded"] += int(np.count_nonzero(
+                        real & ~(tables.fit_static & tables.res_fit[0])))
                 if tables.sa_bail:
                     # ServiceAffinity dynamics the tables can't express
                     # (mid-run re-pin hazard): scan the rest of the run
@@ -1289,7 +1336,7 @@ class WaveScheduler:
                     # in-wave phantom usage — no binds happen, so the
                     # next wave starts from clean cluster state.
                     out[start:start + length] = -1
-                    return carry
+                    break
                 # a gang table-horizon partial (n_done < K, all picks
                 # valid) falls through: write + fold + re-probe, the
                 # same transactional continuation any run gets
@@ -1309,6 +1356,11 @@ class WaveScheduler:
                 # device last_idx; mirror it host-side
                 L_host = res.last_node_index
                 done += res.n_done
+            if self_anti_veto is not None:
+                # what is left to `pending` is the scan's, decided later
+                anti["anti_runs"] += 1
+                anti["anti_picks"] += int(np.count_nonzero(
+                    out[start + done0:start + length] >= 0))
             return carry
 
         def run_group_host(carry, group):
@@ -1478,4 +1530,6 @@ class WaveScheduler:
         carry = settle(carry)
         carry = flush(carry)
         self._count_wave(via, out)
+        if anti["anti_runs"]:
+            count_group(self.stats, anti)
         return out, carry, L_host

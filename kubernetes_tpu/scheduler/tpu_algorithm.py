@@ -379,6 +379,7 @@ class TPUScheduleAlgorithm:
         self, pods: Sequence[Pod], state: ClusterState,
         gangs: Optional[Sequence[dict]] = None,
     ) -> List[Optional[str]]:
+        from kubernetes_tpu.models.wave import count_encoder
         from kubernetes_tpu.parallel.mesh import _pad_snapshot
         from kubernetes_tpu.snapshot.encode import SnapshotEncoder
         from kubernetes_tpu.snapshot.pad import next_pow2
@@ -388,6 +389,7 @@ class TPUScheduleAlgorithm:
             snap = batch = None
             keep = frozenset()
             source = "full"
+            fallback = None
             if self._inc is not None:
                 def ls(l):
                     return l.list() if l is not None else ()
@@ -405,6 +407,9 @@ class TPUScheduleAlgorithm:
                     # one must never satisfy each other's `keep` (their
                     # vocab bit/slot assignments are encoder-local)
                     source = self._inc.source_token
+                fallback = self._inc.fallback
+            count_encoder(self._wave.stats,
+                          "full" if snap is None else "incremental", fallback)
             if snap is None:
                 # from-scratch encode (no daemon cache, or a scope gate
                 # hit: inter-pod affinity / volumes / SA-SAA config)

@@ -147,6 +147,8 @@ class IncrementalEncoder:
             Tuple[str, str], Tuple[int, PodContribution]
         ] = {}
         self._affinity_pods = 0  # cluster-wide gate counter
+        # the scope gate the last `wave_view` stopped at, if any
+        self.fallback: Optional[str] = None
         # per-(slot) port id multiset
         self._port_counts: List[Optional[Dict[int, int]]] = []
         self._order_dirty = True
@@ -641,6 +643,24 @@ class IncrementalEncoder:
             return False  # SA/SAA programs need the full compiler
         return True
 
+    def _scope_gate(self, pending: Sequence[Pod]) -> Optional[str]:
+        """Why this wave's snapshot cannot come from the kept state, if
+        it cannot: "config" (a policy the kept tables do not cover),
+        "affinity" (a bound or a pending pod carries an inter-pod term:
+        the five inter-pod tables are not kept from wave to wave),
+        "volumes" (a pending pod mounts one). The caller counts it
+        (models/wave.count_encoder)."""
+        if not self._config_ok():
+            return "config"
+        if self._affinity_pods > 0:
+            return "affinity"
+        for p in pending:
+            if has_pod_affinity(p):
+                return "affinity"
+            if p.spec.volumes:
+                return "volumes"
+        return None
+
     # snapshot fields per dirty group, for device-array reuse between
     # waves (models/wave.py `keep` protocol)
     NODE_SIDE_FIELDS = frozenset({
@@ -687,11 +707,9 @@ class IncrementalEncoder:
         since) or rebuilt (a taint first seen) in this call otherwise —
         see snapshot/pending_rows.py for each dependency."""
         self.apply_pending()
-        if self._affinity_pods > 0 or not self._config_ok():
+        self.fallback = self._scope_gate(pending)
+        if self.fallback is not None:
             return None, None, frozenset()
-        for p in pending:
-            if p.spec.volumes or has_pod_affinity(p):
-                return None, None, frozenset()
         # the wave's encoder interns the pending pods' vocabulary into
         # the shared bundle and holds what is the wave's own (its image
         # vocabulary, the empty per-batch programs); the light state

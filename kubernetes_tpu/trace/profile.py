@@ -79,7 +79,7 @@ import time
 from collections import deque
 from threading import get_ident
 from time import perf_counter, thread_time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -465,14 +465,18 @@ _wave_lock = threading.Lock()
 #: in this process, all waves: pods decided by each path, device
 #: programs launched by kind, pods that fitted nowhere, what the
 #: grouped header probe did (models/wave.GROUP_COUNTERS) and what the
-#: grouped device replay's loops ran (models/wave.ZREPLAY_COUNTERS);
-#: served on /debug/traces as "wave"
+#: grouped device replay's loops ran (models/wave.ZREPLAY_COUNTERS), what
+#: the runs with a self-anti veto did (models/wave.ANTI_COUNTERS), which
+#: encoder made each wave's snapshot and which scope gate sent it to the
+#: from-scratch one; served on /debug/traces as "wave"
 _WAVE: Dict[str, Any] = {"waves": 0, "pods_by_path": {},
                          "dispatches_by_kind": {}, "pods_unplaced": 0,
                          "group_runs": 0, "group_d2h_bytes": 0,
                          "group_reprobes": 0, "zreplay_steps": 0,
                          "zreplay_slots": 0, "zreplay_rescores": 0,
-                         "zreplay_picks": 0}
+                         "zreplay_picks": 0, "anti_runs": 0,
+                         "anti_picks": 0, "anti_nodes_excluded": 0,
+                         "waves_by_encoder": {}, "encoder_fallbacks": {}}
 
 
 def count_wave(pods_by_path: Dict[str, int], dispatches: Dict[str, int],
@@ -495,10 +499,22 @@ def count_wave_group(counted: Dict[str, int]) -> None:
     bytes it fetched, whether it stopped early (`group_*` of _WAVE); or
     a grouped device replay came back: the steps and run slots its
     loops ran, the steps that rescored, the pods it placed
-    (`zreplay_*`)."""
+    (`zreplay_*`); or a run with a self-anti veto was decided
+    (`anti_*`)."""
     with _wave_lock:
         for k, n in counted.items():
             _WAVE[k] += n
+
+
+def count_wave_encoder(encoder: str, fallback: Optional[str]) -> None:
+    """A wave's snapshot came from `encoder` ("incremental" or "full"),
+    sent there by the incremental encoder's scope gate `fallback`, if
+    by any."""
+    with _wave_lock:
+        for key, k in (("waves_by_encoder", encoder),
+                       ("encoder_fallbacks", fallback)):
+            if k:
+                _WAVE[key][k] = _WAVE[key].get(k, 0) + 1
 
 
 def wave_totals() -> Dict[str, Any]:

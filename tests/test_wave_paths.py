@@ -7,10 +7,17 @@ picks as the serial oracle does, and the `group_*` counters say so on
 both drivers. The grouped device replay's `zreplay_*` counters say how
 many run slots and pick steps its loops ran and how many steps evaluated
 the carried score again, and a wave of another run count inside one
-bucket builds no program. And the scan path picks as
+bucket builds no program. The `anti_*` counters say what the runs with
+a self-anti veto decided and how many nodes the terms of bound pods had
+taken from them, `waves_by_encoder` / `encoder_fallbacks` which encoder
+made a wave's snapshot and which scope gate sent it there, and a later
+wave of an unchanged set of terms builds no program. And the scan path
+picks as
 the serial oracle does where
 selector rows are all distinct, multi-hot, or followed by a pod that
 fits nowhere and by padding."""
+
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -28,7 +35,12 @@ from kubernetes_tpu.api.types import (
     Service,
     ServiceSpec,
 )
-from kubernetes_tpu.models.wave import PATHS, ZREPLAY_COUNTERS
+from kubernetes_tpu.models.wave import (
+    ANTI_COUNTERS,
+    ENCODERS,
+    PATHS,
+    ZREPLAY_COUNTERS,
+)
 from kubernetes_tpu.trace import profile
 
 ZONE = "failure-domain.beta.kubernetes.io/zone"
@@ -261,6 +273,266 @@ def test_a_wave_of_another_run_count_builds_no_program():
     built = [c["program"] for c in profile.recent_compiles()
              if c["at"] >= t_between]
     assert not [p for p in built if "zreplay" in p], built
+
+
+# -- runs with a self-anti veto, and the encoder behind a wave ----------------
+
+HOSTNAME = "kubernetes.io/hostname"
+
+
+def _anti_pod(t, i, groups=5):
+    """A replica of controller `t` whose required hostname anti-affinity
+    term selects its group: controllers t and t + groups
+    (benchmark/configs/antiaffinity-2k.json's shape)."""
+    import json
+
+    from kubernetes_tpu.api.types import AFFINITY_ANNOTATION
+
+    k = t % groups
+    term = {"podAntiAffinity": {
+        "requiredDuringSchedulingIgnoredDuringExecution": [{
+            "labelSelector": {"matchExpressions": [{
+                "key": "group", "operator": "In",
+                "values": [f"g{k}", f"g{k + groups}"]}]},
+            "topologyKey": HOSTNAME}]}}
+    return Pod(
+        metadata=ObjectMeta(name=f"anti{t}-{i:04d}",
+                            labels={"group": f"g{t}"},
+                            annotations={AFFINITY_ANNOTATION:
+                                         json.dumps(term)}),
+        spec=PodSpec(containers=[Container(requests={"cpu": "100m"})]))
+
+
+def _anti_controllers(n=10):
+    return [ReplicationController(
+        metadata=ObjectMeta(name=f"anti-{t}"),
+        spec=ReplicationControllerSpec(selector={"group": f"g{t}"}))
+        for t in range(n)]
+
+
+def _anti_rows(controllers, replicas, serial=0):
+    return [_anti_pod(t, serial + i) for t in controllers
+            for i in range(replicas)]
+
+
+ANTI_CASES = {
+    # name: (nodes, backlog, runs with a veto, pods they place, nodes
+    #        their first probes find unfit)
+    # two controllers of ONE group: the second's run meets the first's
+    # 16 picks as excluded nodes, inside the wave
+    "one-group-two-controllers": (40, _anti_rows((0, 5), 16), 2, 32, 16),
+    # the group fills every node: the second run places 8 of its 16
+    "the-group-fills-the-nodes": (24, _anti_rows((0, 5), 16), 2, 24, 16),
+    # two groups: neither's picks exclude a node from the other
+    "two-groups": (40, _anti_rows((0, 1), 16), 2, 32, 0),
+    # runs without terms carry no veto
+    "no-terms": (30, _in_rows(2, 16), 0, 0, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ANTI_CASES))
+def test_anti_counters_say_what_the_vetoed_runs_decided(case):
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+    from kubernetes_tpu.trace.httpd import render_traces
+
+    nodes, backlog, runs, picks, excluded = ANTI_CASES[case]
+    state = ClusterState.build(_nodes(nodes, ""),
+                               controllers=_anti_controllers()
+                               + _controllers(2))
+    algo = TPUScheduleAlgorithm()
+    shown_before = render_traces({"limit": "1"})["wave"]
+    got = algo.schedule_backlog(backlog, state)
+    assert got == _oracle(state, backlog)
+    stats = algo._wave.stats
+    assert ANTI_COUNTERS == ("anti_runs", "anti_picks",
+                             "anti_nodes_excluded")
+    assert (stats["anti_runs"], stats["anti_picks"],
+            stats["anti_nodes_excluded"]) == (runs, picks, excluded)
+    assert stats["pods_unplaced"] == len(backlog) - sum(
+        h is not None for h in got) == (8 if "fills" in case else 0)
+    if runs:
+        # one node holds one pod of the group, and a run is one probe
+        # (the one that finds its last node gone asks once more)
+        by_group = [h for h in got if h is not None]
+        assert len(set(by_group)) == len(by_group) or case == "two-groups"
+        assert stats["pods_by_path"]["single"] >= picks
+        assert stats["dispatches_by_kind"]["probe"] >= runs
+    shown = render_traces({"limit": "1"})["wave"]
+    for key in ANTI_COUNTERS:
+        assert shown[key] - shown_before[key] == stats[key]
+
+
+def test_anti_picks_never_run_ahead_of_the_pods_decided(monkeypatch):
+    """Both tallies move at a wave's end: a reader that falls into the
+    middle of a wave (the benchmark's second read did, on the chip:
+    `anti_run_share.fill` 101.4) finds the picks of whole waves only."""
+    from kubernetes_tpu.models.probe import WaveProbe
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    state = ClusterState.build(_nodes(40, ""),
+                               controllers=_anti_controllers())
+    algo = TPUScheduleAlgorithm()
+    stats = algo._wave.stats
+    seen = []
+    sound = WaveProbe.probe_fused
+
+    def watched(self, *args, **kwargs):
+        seen.append((stats["anti_picks"], stats["anti_runs"],
+                     sum(stats["pods_by_path"].values())))
+        return sound(self, *args, **kwargs)
+
+    monkeypatch.setattr(WaveProbe, "probe_fused", watched)
+    algo.schedule_backlog(_anti_rows((0, 1, 2), 16), state)
+    algo.schedule_backlog(_anti_rows((3, 4), 16, serial=50), state)
+    assert seen == [(0, 0, 0)] * 3 + [(48, 3, 48)] * 2
+    assert (stats["anti_picks"], stats["anti_runs"]) == (80, 5)
+
+
+def test_bound_pods_terms_take_nodes_from_a_run_before_it_starts():
+    """12 of 30 nodes hold a pod of the group: a run of its other
+    controller finds them unfit at its first probe, and nothing else."""
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    nodes = _nodes(30, "")
+    bound = _anti_rows((0,), 12, serial=100)
+    for i, p in enumerate(bound):
+        p.spec.node_name = nodes[2 * i].metadata.name
+    state = ClusterState.build(nodes, bound,
+                               controllers=_anti_controllers())
+    backlog = _anti_rows((5,), 16) + _anti_rows((1,), 16)
+    algo = TPUScheduleAlgorithm()
+    got = algo.schedule_backlog(backlog, state)
+    assert got == _oracle(state, backlog)
+    taken = {p.spec.node_name for p in bound}
+    assert not taken & set(got[:16]) and taken & set(got[16:])
+    stats = algo._wave.stats
+    assert (stats["anti_runs"], stats["anti_picks"]) == (2, 32)
+    assert stats["anti_nodes_excluded"] == 12
+
+
+def _volume_pod(i):
+    from kubernetes_tpu.api.types import GCEPersistentDisk, Volume
+
+    p = _pod(0, i)
+    p.spec.volumes = [Volume(name="data", gce_persistent_disk=GCEPersistentDisk(
+        pd_name=f"disk-{i}"))]
+    return p
+
+
+ENCODER_CASES = {
+    # name: (pods bound before the wave, the wave, has a scheduler
+    #        cache, encoder, the scope gate counted)
+    "a-pending-term": ([], lambda: _anti_rows((0, 5), 16), True,
+                       "full", "affinity"),
+    "a-bound-pods-term": (lambda: _anti_rows((3,), 1, serial=900),
+                          lambda: _in_rows(2, 16), True, "full",
+                          "affinity"),
+    "a-volume": ([], lambda: [_volume_pod(i) for i in range(3)], True,
+                 "full", "volumes"),
+    "neither": ([], lambda: _in_rows(2, 16), True, "incremental", None),
+    # no cache to keep a snapshot from: from scratch, and no gate
+    "no-cache": ([], lambda: _in_rows(2, 16), False, "full", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ENCODER_CASES))
+def test_waves_are_counted_by_encoder_and_fallbacks_by_reason(case):
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.cache import SchedulerCache
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+    from kubernetes_tpu.utils.clock import FakeClock
+
+    bound, wave, cached, encoder, reason = ENCODER_CASES[case]
+    nodes = _nodes(30, "")
+    bound = bound() if callable(bound) else bound
+    for p in bound:
+        p.spec.node_name = nodes[7].metadata.name
+    cache = SchedulerCache(clock=FakeClock()) if cached else None
+    if cached:
+        for n in nodes:
+            cache.add_node(n)
+        for p in bound:
+            cache.add_pod(p)
+    controllers = _anti_controllers() + _controllers(2)
+    algo = TPUScheduleAlgorithm(
+        cache=cache, controller_lister=SimpleNamespace(
+            list=lambda: controllers))
+    state = ClusterState.build(nodes, bound, controllers=controllers)
+    shown_before = profile.wave_totals()
+    backlog = wave()
+    assert algo.schedule_backlog(backlog, state) == _oracle(state, backlog)
+    stats = algo._wave.stats
+    assert set(stats["waves_by_encoder"]) == set(ENCODERS) \
+        == {"incremental", "full"}
+    assert stats["waves_by_encoder"][encoder] == 1 == stats["waves"]
+    assert stats["encoder_fallbacks"] == ({reason: 1} if reason else {})
+    # a second wave counts again, under the same reason
+    algo.schedule_backlog(backlog[:3], state)
+    assert stats["waves_by_encoder"][encoder] == 2
+    assert sum(stats["waves_by_encoder"].values()) == 2
+    assert stats["encoder_fallbacks"] == ({reason: 2} if reason else {})
+    shown = profile.wave_totals()
+    assert _delta(shown["waves_by_encoder"],
+                  shown_before["waves_by_encoder"]).get(encoder) == 2
+    moved = _delta(shown["encoder_fallbacks"],
+                   shown_before["encoder_fallbacks"])
+    assert {k: v for k, v in moved.items() if v} \
+        == ({reason: 2} if reason else {})
+
+
+def test_a_later_wave_of_the_same_terms_builds_no_program():
+    """The from-scratch encoder's tables are as wide as the terms and
+    spread classes the cluster and the wave hold (specs, logical terms,
+    classes), and the probe, the fold and the scan are built per width.
+    Once a pod of every controller is bound the widths stand: a wave of
+    other runs of the same controllers builds none of them again, and a
+    third wave shaped like the second builds nothing at all (the second
+    may still build the transfer programs of a layout it is first to
+    ship: `jit_pack_unpack`, `jit_row_set`)."""
+    import time
+
+    from kubernetes_tpu.oracle import ClusterState, GenericScheduler
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    from tests.test_conformance import ORACLE_PREDICATES, ORACLE_PRIORITIES
+
+    profile.install_compile_listener()
+    nodes = _nodes(64, "")
+    controllers = _anti_controllers()
+    bound = []
+    algo = TPUScheduleAlgorithm()
+    # one oracle for all waves: the round-robin index goes on counting
+    oracle = GenericScheduler(predicates=ORACLE_PREDICATES,
+                              priorities=ORACLE_PRIORITIES)
+
+    def wave(backlog):
+        state = ClusterState.build(nodes, bound, controllers=controllers)
+        t = time.time()
+        got = algo.schedule_backlog(backlog, state)
+        assert got == oracle.schedule_backlog(backlog, state.clone())
+        assert None not in got
+        for p, host in zip(backlog, got):
+            p.spec.node_name = host
+            bound.append(p)
+        return [c["program"] for c in profile.recent_compiles()
+                if c["at"] >= t]
+
+    # one pod of every controller (lone pods: the scan), then runs
+    first = wave(_anti_rows(range(10), 1, serial=0))
+    assert any("scan" in p for p in first)
+    runs = wave(_anti_rows((0, 6, 2, 5), 16, serial=10))
+    assert any("probe_fused" in p for p in runs)
+    again = wave(_anti_rows((7, 1, 8, 3, 9), 16, serial=30))
+    assert not [p for p in again
+                if "probe" in p or "apply" in p or "scan" in p], again
+    third = wave(_anti_rows((4, 9, 3, 6, 2), 16, serial=50))
+    assert third == [], third
+    stats = algo._wave.stats
+    assert stats["anti_runs"] == 14 and stats["anti_picks"] == 14 * 16
+    assert stats["waves_by_encoder"] == {"incremental": 0, "full": 4}
 
 
 # -- a group of runs with distinct commit vectors -----------------------------
