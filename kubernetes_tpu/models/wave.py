@@ -30,7 +30,7 @@ scheduler.go:122 AssumePod, iterated per pod.
 from __future__ import annotations
 
 from dataclasses import replace as dc_replace
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -452,17 +452,24 @@ def count_group(stats: dict, counted: dict) -> None:
 
 
 def count_encoder(stats: dict, encoder: str,
-                  fallback: Optional[str] = None) -> None:
+                  fallback: Optional[str] = None,
+                  rebuilds: Optional[Dict[str, int]] = None) -> None:
     """A wave's snapshot was made by `encoder` (one of ENCODERS), sent
-    there by the scope gate `fallback` if by any: into a driver's
-    cumulative `stats` and the process-wide totals on /debug/traces."""
+    there by the scope gate `fallback` if by any; before it the
+    incremental encoder rebuilt its inter-pod tables whole `rebuilds`
+    times, by reason (what its deltas do not cover:
+    snapshot/interpod.InterPodTables). Into a driver's cumulative
+    `stats` and the process-wide totals on /debug/traces."""
     from kubernetes_tpu.trace.profile import count_wave_encoder
 
     stats["waves_by_encoder"][encoder] += 1
     if fallback:
         by_reason = stats["encoder_fallbacks"]
         by_reason[fallback] = by_reason.get(fallback, 0) + 1
-    count_wave_encoder(encoder, fallback)
+    for reason, n in (rebuilds or {}).items():
+        by_reason = stats["interpod_rebuilds"]
+        by_reason[reason] = by_reason.get(reason, 0) + n
+    count_wave_encoder(encoder, fallback, rebuilds)
 
 
 #: pick-buffer length floors of the zoned device replay: one run per
@@ -717,6 +724,8 @@ class WaveScheduler:
             # (`count_encoder`; the scheduler counts, the driver keeps)
             "waves_by_encoder": dict.fromkeys(ENCODERS, 0),
             "encoder_fallbacks": {},
+            # whole rebuilds of the kept inter-pod tables, by reason
+            "interpod_rebuilds": {},
         }
 
     # fraction of changed rows above which a scatter-row update loses
@@ -746,13 +755,18 @@ class WaveScheduler:
             self._row_set_jit[key] = fn
         return fn
 
-    def _to_dev_many(self, snap, fields, keep: frozenset, extra=None):
+    def _to_dev_many(self, snap, fields, keep: frozenset, extra=None,
+                     reship: frozenset = frozenset()):
         """Device copies for `fields` (+ `extra` host arrays), shipping
         every miss in ONE batched device_put: each individual transfer
         has a fixed cost, so per-field puts dominate a cold wave. Placed
         copies may ride a narrowed dtype (ops/narrow); mirrors
         keep full width, and a narrow-range overflow changes the
-        placement dtype, which misses the cache and rebuilds wider."""
+        placement dtype, which misses the cache and rebuilds wider.
+        `reship` names fields whose producer says they moved as one:
+        each goes whole with the batch, compared with nothing, so the
+        set of tables shipped is the same every such wave (a set is one
+        `jit_pack_unpack` layout, built where it is first met)."""
         out = {}
         missing = {}
         scatters = []
@@ -763,6 +777,7 @@ class WaveScheduler:
             ent = self._dev.get(f)
             if (
                 ent is not None
+                and f not in reship
                 and ent[2] is not None
                 and ent[0] == host_np.shape
                 and ent[1] == host_np.dtype
@@ -1071,7 +1086,8 @@ class WaveScheduler:
         return pick_j(self.config, self.max_j, snap, batch, rep, K)
 
     def _wave_setup(self, snap: ClusterSnapshot, keep: frozenset,
-                    source: str, last_node_index: int):
+                    source: str, last_node_index: int,
+                    reship: frozenset = frozenset()):
         """Per-wave device placement shared by the greedy driver and
         the optimizing profile (scheduler/optimizer/profile.py):
         -> (static, carry, num_zones, num_values). Resets the per-wave
@@ -1098,6 +1114,7 @@ class WaveScheduler:
                 keep,
                 extra={"__res__": res_host,
                        "__lidx__": np.int64(last_node_index)},
+                reship=reship,
             )
             static = {f: dev[f] for f in BatchScheduler.STATIC_FIELDS}
             # config-resolved node masks are HOST arrays: place them
@@ -1123,13 +1140,15 @@ class WaveScheduler:
         keep: frozenset = frozenset(),
         source: str = "full",
         gangs: Optional[Sequence[dict]] = None,
+        reship: frozenset = frozenset(),
     ) -> Tuple[np.ndarray, tuple, int]:
         """-> (chosen i32[P] node ids with -1 == unschedulable,
         final carry, final lastNodeIndex). snap may be node-padded;
         batch holds one row per unique pod; rep_idx maps backlog
         position -> row. `keep` (from the incremental encoder) names
         snapshot fields unchanged since the previous wave — their
-        device copies are reused instead of re-shipped. `source`
+        device copies are reused instead of re-shipped; `reship` names
+        those that moved as one and ship whole (`_to_dev_many`). `source`
         identifies the snapshot's producer; a producer change drops the
         device cache (ids/bit positions are producer-relative).
 
@@ -1147,7 +1166,7 @@ class WaveScheduler:
         the returned hosts before anything binds. None/[] = no gangs,
         and the wave is bit-identical to the pre-gang driver."""
         static, carry, num_zones, num_values = self._wave_setup(
-            snap, keep, source, last_node_index)
+            snap, keep, source, last_node_index, reship)
         P = len(rep_idx)
         out = np.full(P, -1, np.int32)
         # the path that decided each position (an index into PATHS): a

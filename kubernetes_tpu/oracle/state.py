@@ -21,7 +21,7 @@ from kubernetes_tpu.api.types import (
     ReplicaSet,
     ReplicationController,
     Service,
-    has_pod_affinity,
+    get_affinity,
     pod_nonzero_request,
     pod_resource_request,
 )
@@ -113,6 +113,72 @@ def _calculate_resource(pod: Pod) -> Tuple[int, int, int]:
     return cpu, mem, gpu
 
 
+def selector_canon(sel) -> object:
+    """A LabelSelector's content, hashable: what two terms must agree in
+    to count the same pods (snapshot/interpod.py keys its specs on it)."""
+    if sel is None:
+        return None
+    return (
+        tuple(sorted((sel.match_labels or {}).items())),
+        tuple(
+            (e.key, e.operator, tuple(e.values or ()))
+            for e in (sel.match_expressions or ())
+        ),
+    )
+
+
+class PodTerms(NamedTuple):
+    """The inter-pod terms a pod owns, by the table that counts them
+    (snapshot/interpod.py), parsed once per template. A term is
+    (namespaces, selector, topology key): the namespaces as
+    GetNamespacesFromPodAffinityTerm resolves them for the owner (a
+    frozenset; empty for all), the selector as `selector_canon` has it.
+    Hashable, so equal terms of two templates are one entry."""
+
+    parsed: bool  # False: the annotation does not parse (it poisons)
+    hard: tuple = ()  # required affinity: terms
+    pref: tuple = ()  # preferred affinity: (term, weight)
+    anti_hard: tuple = ()  # required anti-affinity: terms
+    anti_pref: tuple = ()  # preferred anti-affinity: (term, weight)
+    affinity: bool = False  # states podAffinity, with terms or without
+    anti: bool = False  # states podAntiAffinity
+
+
+def pod_terms(pod: Pod) -> Optional[PodTerms]:
+    """The pod's PodTerms: None where it states no inter-pod affinity
+    (has_pod_affinity is false), `parsed` false where it cannot be
+    read."""
+    try:
+        aff = get_affinity(pod)
+    except Exception:
+        return PodTerms(False)
+    if aff is None or (aff.pod_affinity is None
+                       and aff.pod_anti_affinity is None):
+        return None
+    own = pod.metadata.namespace
+
+    def term(t):
+        # util/non_zero.go:96: nil is the owner's namespace, empty is all
+        names = (own,) if t.namespaces is None else t.namespaces
+        return (frozenset(names), selector_canon(t.label_selector),
+                t.topology_key)
+
+    def sides(side):
+        if side is None:
+            return (), ()
+        return (
+            tuple(term(t) for t in
+                  side.required_during_scheduling_ignored_during_execution),
+            tuple((term(wt.pod_affinity_term), wt.weight) for wt in
+                  side.preferred_during_scheduling_ignored_during_execution),
+        )
+
+    return PodTerms(True, *sides(aff.pod_affinity),
+                    *sides(aff.pod_anti_affinity),
+                    aff.pod_affinity is not None,
+                    aff.pod_anti_affinity is not None)
+
+
 class PodContribution(NamedTuple):
     """What one assigned pod adds to its node: everything NodeInfo and
     the incremental snapshot (snapshot/incremental.py) take from it.
@@ -126,7 +192,8 @@ class PodContribution(NamedTuple):
     host_ports: Tuple[int, ...]  # non-zero, in container order
     # the spread class: (namespace, frozenset(labels), deleting)
     class_key: Tuple[str, frozenset, bool]
-    affinity: bool  # has_pod_affinity
+    # the inter-pod terms it owns; None where has_pod_affinity is false
+    terms: Optional[PodTerms]
 
 
 #: contributions seen, under a structural key of exactly the fields
@@ -150,8 +217,9 @@ def pod_contribution(pod: Pod) -> PodContribution:
     meta = pod.metadata
     spec = pod.spec
     if spec.affinity is not None:
-        # an Affinity object is not hashable; such pods are few, and the
-        # inter-pod ones take the encoder's per-event path anyway
+        # an Affinity object is not hashable; such pods are few (their
+        # terms still come out equal, `PodTerms`, and share what the
+        # incremental encoder keeps per distinct terms)
         _count_miss()
         return _derive_contribution(pod)
     key = (
@@ -194,7 +262,7 @@ def _derive_contribution(pod: Pod) -> PodContribution:
             frozenset(meta.labels.items()),
             meta.deletion_timestamp is not None,
         ),
-        has_pod_affinity(pod),
+        pod_terms(pod),
     )
 
 

@@ -143,6 +143,7 @@ class OptimizingWaveDriver:
         keep: frozenset = frozenset(),
         source: str = "full",
         gangs: Optional[Sequence[dict]] = None,
+        reship: frozenset = frozenset(),
     ):
         """Same contract as WaveScheduler.schedule_backlog: ->
         (chosen i32[P] node ids with -1 == unschedulable, final carry,
@@ -150,7 +151,7 @@ class OptimizingWaveDriver:
         wave = self.wave
         config = self.config
         static, carry, num_zones, num_values = wave._wave_setup(
-            snap, keep, source, last_node_index)
+            snap, keep, source, last_node_index, reship)
         self.dispatches = wave.dispatches
         P = len(rep_idx)
         N = snap.num_nodes

@@ -387,9 +387,9 @@ class TPUScheduleAlgorithm:
         with trace_profile.phase_timer("encode"):
             reps, rep_idx, keys = self._dedup(pods)
             snap = batch = None
-            keep = frozenset()
+            keep = reship = frozenset()
             source = "full"
-            fallback = None
+            fallback = rebuilds = None
             if self._inc is not None:
                 def ls(l):
                     return l.list() if l is not None else ()
@@ -407,12 +407,15 @@ class TPUScheduleAlgorithm:
                     # one must never satisfy each other's `keep` (their
                     # vocab bit/slot assignments are encoder-local)
                     source = self._inc.source_token
+                    reship = self._inc.reship
                 fallback = self._inc.fallback
+                rebuilds = self._inc.take_rebuilds()
             count_encoder(self._wave.stats,
-                          "full" if snap is None else "incremental", fallback)
+                          "full" if snap is None else "incremental", fallback,
+                          rebuilds)
             if snap is None:
                 # from-scratch encode (no daemon cache, or a scope gate
-                # hit: inter-pod affinity / volumes / SA-SAA config)
+                # hit: volumes / SA-SAA config)
                 enc = SnapshotEncoder(state, reps, config=self._wave.config)
                 snap = enc.encode_nodes()
                 batch = enc.encode_pods()
@@ -458,7 +461,7 @@ class TPUScheduleAlgorithm:
             driver = self._opt
         chosen, _final, last = driver.schedule_backlog(
             snap, batch, rep_idx, last_node_index=self._last_node_index,
-            keep=keep, source=source, gangs=wave_gangs,
+            keep=keep, source=source, gangs=wave_gangs, reship=reship,
         )
         self._last_node_index = last
         names = snap.node_names

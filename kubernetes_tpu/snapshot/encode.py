@@ -151,6 +151,21 @@ def _words(n: int) -> int:
     return max(1, (n + 31) // 32)
 
 
+def grown(a: np.ndarray, shape: tuple, fill=0) -> np.ndarray:
+    """`a` with room for `shape`: itself where it has it, else a copy
+    with each short axis doubled (or more), the new room holding `fill`.
+    By doubling, because a vocabulary that gains an entry a node (every
+    node's own hostname label) would otherwise copy its table once per
+    32 nodes, 9 s of a 20,000-node cluster's first wave; a kept table is
+    cut to its vocabulary's width when a snapshot or a batch is made."""
+    if all(h >= w for h, w in zip(a.shape, shape)):
+        return a
+    out = np.full(tuple(h if h >= w else max(w, 2 * h)
+                        for h, w in zip(a.shape, shape)), fill, a.dtype)
+    out[tuple(slice(0, h) for h in a.shape)] = a
+    return out
+
+
 class _Dict:
     """Monotone string->id dictionary."""
 
@@ -175,7 +190,12 @@ class VocabBundle:
 
     Normally private to one encoder; the incremental snapshot
     (snapshot/incremental.py) owns a persistent bundle so per-wave
-    pod encodes and the long-lived node arrays agree on ids."""
+    pod encodes and the long-lived node arrays agree on ids. `terms`
+    is the incremental snapshot's too: the inter-pod vocabularies
+    (specs, topology combos, term classes, logical terms) and the
+    tables counted in their ids (snapshot/interpod.InterPodTables),
+    which it makes for its bundle; a from-scratch encoder compiles its
+    own per encode and has none."""
 
     def __init__(self):
         self.ports = _Dict()
@@ -188,6 +208,7 @@ class VocabBundle:
         self.classes = _Dict()  # (ns, frozenset(labels.items()), deleted)
         self.sets: Dict[frozenset, int] = {}
         self.set_members: List[frozenset] = []
+        self.terms = None
 
 
 def build_set_table(set_members, kv_ids, lw: int) -> np.ndarray:
@@ -571,6 +592,15 @@ class PodBatch:
         return len(self.pod_keys)
 
 
+#: the inter-pod fields of a PodBatch that are a pod's own (a row each:
+#: snapshot/interpod.InterPodTables.pod_rows), without their `ip_` prefix
+IP_ROW_FIELDS = (
+    "match_spec", "ha_lt", "ha_self", "hq_lt", "fwd_lt", "fwd_w",
+    "own_hard", "own_pref", "own_anti_hard", "own_anti_pref",
+    "has_affinity", "has_anti",
+)
+
+
 class SnapshotEncoder:
     """Builds all vocabularies over (cluster state, pending pods) and emits
     the columnar snapshot + pod batch. Vocabularies are derived jointly so
@@ -932,27 +962,37 @@ class SnapshotEncoder:
             )
         return idx
 
-    def batch_fields(self) -> dict:
+    def interpod_fields(self) -> dict:
+        """The PodBatch's inter-pod fields. With `visit_state=False` the
+        assigned pods are the caller's to keep, and so are their tables:
+        the rows are made in the bundle's persistent ids
+        (`VocabBundle.terms`), and what the assigned pods decide of them
+        (`sym_reject`, `poison`) is read off its counts."""
+        P = len(self.pods)
+        if self._visit_state:
+            ip = self.interpod
+            rows = {f: getattr(ip, f) for f in IP_ROW_FIELDS}
+            sym_reject, poison = ip.sym_reject, ip.poison
+        else:
+            terms = self.vocabs.terms
+            rows = terms.pod_rows(self.pods)
+            sym_reject, poison = terms.wave_flags(
+                rows["match_spec"], rows["has_anti"])
+        fields = {"ip_" + f: a for f, a in rows.items()}
+        fields["ip_sym_reject"] = sym_reject
+        fields["ip_poison"] = np.full(P, poison, bool)
+        return fields
+
+    def batch_fields(self, interpod: bool = True) -> dict:
         """The PodBatch fields that belong to the batch as a whole: the
-        inter-pod, volume and service programs compiled over its pods,
-        and an image-count table as wide as its image vocabulary (filled
-        by encode_pods, or by whoever assembles the batch from rows)."""
+        inter-pod (unless the caller has them: snapshot/pending_rows.py
+        keeps them by row), volume and service programs compiled over
+        its pods, and an image-count table as wide as its image
+        vocabulary (filled by encode_pods, or by whoever assembles the
+        batch from rows)."""
         P = len(self.pods)
         return dict(
-            ip_match_spec=self.interpod.match_spec,
-            ip_ha_lt=self.interpod.ha_lt,
-            ip_ha_self=self.interpod.ha_self,
-            ip_hq_lt=self.interpod.hq_lt,
-            ip_fwd_lt=self.interpod.fwd_lt,
-            ip_fwd_w=self.interpod.fwd_w,
-            ip_own_hard=self.interpod.own_hard,
-            ip_own_pref=self.interpod.own_pref,
-            ip_own_anti_hard=self.interpod.own_anti_hard,
-            ip_own_anti_pref=self.interpod.own_anti_pref,
-            ip_has_affinity=self.interpod.has_affinity,
-            ip_has_anti=self.interpod.has_anti,
-            ip_sym_reject=self.interpod.sym_reject,
-            ip_poison=np.full(P, self.interpod.poison, bool),
+            **(self.interpod_fields() if interpod else {}),
             vp_vol_rw=self.volumes.p_vol_rw,
             vp_vol_ro=self.volumes.p_vol_ro,
             vp_ebs=self.volumes.p_ebs,

@@ -35,18 +35,34 @@ module restores the reference's cost model at the array level:
     allocatable => never fit, exactly like pad.py's dummy nodes) and new
     nodes reuse free slots. Decisions depend on the name-desc order, not
     slot order, so slot assignment is invisible to scheduling.
+  * The inter-pod program's snapshot side is kept too
+    (`VocabBundle.terms`, snapshot/interpod.InterPodTables): its
+    vocabularies (specs, topology combos, term classes, logical terms,
+    a domain numbering per combo) are persistent like the others;
+    `topo_dom` follows the node events; and the five counting tables
+    and `spec_total` take a bound pod's share when it comes and give it
+    back when it goes, with the batch's one scatter-add. A pod's share
+    is derived once per template: the terms it owns ride on its
+    contribution (`PodContribution.terms`), the specs it matches are
+    its spread class's. A wave whose pods own terms, over a cluster
+    whose pods do, costs what any other wave costs.
 
 Scope gates (wave_view returns ok=False and the caller falls back to the
 from-scratch SnapshotEncoder — correctness is never at stake, only
-cost): any pod-affinity/anti-affinity in the cluster or wave (the
-inter-pod program's topology tables are global), volumes on wave pods,
-a Policy using ServiceAffinity/AntiAffinity, or a config without
-GeneralPredicates (free slots are masked via zeroed allocatable, which
-needs the resource predicate active).
+cost): volumes on wave pods, a Policy using
+ServiceAffinity/AntiAffinity, or a config without GeneralPredicates
+(free slots are masked via zeroed allocatable, which needs the resource
+predicate active). Inter-pod terms gate nothing. What the kept tables'
+deltas do not cover (a node relabelled or removed under its pods)
+rebuilds those tables whole from the held pods, O(bound pods), inside
+this encoder and counted by reason (`take_rebuilds`); owners without a
+node and pods whose annotation does not parse are kept as counts.
 
 tests/test_incremental.py drives randomized event streams and proves
 snapshot-after-deltas == snapshot-from-scratch, both semantically
-(decoded per-node views) and end-to-end (identical decisions).
+(decoded per-node views; the inter-pod tables against
+InterPodCompiler.compile by canonical key) and end-to-end (identical
+decisions).
 """
 
 from __future__ import annotations
@@ -57,12 +73,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from kubernetes_tpu.api.types import (
-    Node,
-    Pod,
-    get_taints,
-    has_pod_affinity,
-)
+from kubernetes_tpu.api.types import Node, Pod, get_taints
 from kubernetes_tpu.oracle.priorities import get_zone_key
 from kubernetes_tpu.oracle.state import (
     ClusterState,
@@ -78,9 +89,11 @@ from kubernetes_tpu.snapshot.encode import (
     _pack_bits,
     _words,
     build_set_table,
+    grown,
     pod_feature_key,
     service_config_labels,
 )
+from kubernetes_tpu.snapshot.interpod import InterPodTables
 from kubernetes_tpu.snapshot.pending_rows import PendingRows
 from kubernetes_tpu.api.resource import (
     parse_quantity,
@@ -91,17 +104,8 @@ from kubernetes_tpu.trace import profile as trace_profile
 
 
 def _grow_cols(a: np.ndarray, cols: int) -> np.ndarray:
-    """`a` with at least `cols` columns, grown by doubling: a vocabulary
-    that gains an entry a node (every node's own hostname label) would
-    otherwise copy the table once per 32 nodes, 9 s of a 20,000-node
-    cluster's first wave. A snapshot cuts each table to its vocabulary's
-    width (`_snapshot_arrays`)."""
-    if a.shape[1] >= cols:
-        return a
-    cols = max(cols, 2 * a.shape[1])
-    out = np.zeros((a.shape[0], cols), a.dtype)
-    out[:, : a.shape[1]] = a
-    return out
+    """`a` with at least `cols` columns (`grown`: by doubling)."""
+    return grown(a, (a.shape[0], cols))
 
 
 _SOURCE_COUNTER = itertools.count()
@@ -125,6 +129,7 @@ class IncrementalEncoder:
         # (a monotonic counter — id() reuses freed addresses)
         self.source_token = f"inc:{next(_SOURCE_COUNTER)}"
         self.vocabs = VocabBundle()
+        self.vocabs.terms = InterPodTables(self.vocabs.classes)
         # encoded pending-pod rows by template (snapshot/pending_rows.py)
         self.rows = PendingRows(self.vocabs)
         self._lock = threading.Lock()
@@ -146,9 +151,14 @@ class IncrementalEncoder:
         self._contribs: Dict[
             Tuple[str, str], Tuple[int, PodContribution]
         ] = {}
-        self._affinity_pods = 0  # cluster-wide gate counter
         # the scope gate the last `wave_view` stopped at, if any
         self.fallback: Optional[str] = None
+        # the fields of its snapshot that moved as one (the inter-pod
+        # counting tables, when a bound pod touched any): a driver that
+        # compares what is not in `keep` with its last copy, to ship
+        # the rows that differ, ships these whole
+        # (models/wave.WaveScheduler._to_dev_many)
+        self.reship: frozenset = frozenset()
         # per-(slot) port id multiset
         self._port_counts: List[Optional[Dict[int, int]]] = []
         self._order_dirty = True
@@ -160,6 +170,7 @@ class IncrementalEncoder:
         self._dirty_pod_side = True
         self._last_sets_len = -1
         self._last_img_vocab: Optional[tuple] = None
+        self._last_terms = (-1, -1)  # InterPodTables versions emitted
         self._grow(slot_step or initial_slots)
         # column-capacity trackers
         self._lw = 1
@@ -243,6 +254,7 @@ class IncrementalEncoder:
         self._port_counts += [None] * (cap - old)
         self._free += list(range(cap - 1, old - 1, -1))
         self._cap = cap
+        self.vocabs.terms.grow(cap)
         self._order_dirty = True
         self._dirty_node_side = True
         self._dirty_pod_side = True
@@ -332,6 +344,8 @@ class IncrementalEncoder:
         v = self.vocabs
         labels = dict(node.metadata.labels)
         self._node_labels[slot] = labels
+        v.terms.node_set(slot, labels, int(self._pod_count_slot[slot]),
+                         bool(self._node_gone[slot]))
         for k, val in labels.items():
             v.keys.get(k)
             v.kv.get((k, val))
@@ -419,6 +433,7 @@ class IncrementalEncoder:
         self.mem_pressure[slot] = False
         self.zone_id[slot] = 0
         self.class_count[slot, :] = 0
+        self.vocabs.terms.node_gone(slot, 0)
         self._free.append(slot)
         self._order_dirty = True
         self._dirty_node_side = True
@@ -434,6 +449,7 @@ class IncrementalEncoder:
             # node-less NodeInfos)
             self._node_gone[slot] = True
             self._schedulable[slot] = False
+            self.vocabs.terms.node_gone(slot, int(self._pod_count_slot[slot]))
         else:
             self._free_slot(slot)
 
@@ -468,6 +484,14 @@ class IncrementalEncoder:
         self.pod_count[slot] += sign
         self._pod_count_slot[slot] += sign
         self.class_count[slot, class_id] += sign
+        terms = self.vocabs.terms
+        if c.terms is not None or len(terms.specs):
+            terms.apply(
+                np.array([slot]), np.zeros(1, np.intp),
+                np.array([sign], np.int64), [class_id],
+                [None if c.terms is None else terms.owned(c.terms)],
+                gone=bool(self._node_gone[slot]),
+            )
 
     def _add_one(self, key: Tuple[str, str], node_name: str,
                  c: PodContribution) -> None:
@@ -488,8 +512,6 @@ class IncrementalEncoder:
             self.port_mask[slot] = _pack_bits(
                 list(pc), self.port_mask.shape[1]
             )
-        if c.affinity:
-            self._affinity_pods += 1
 
     def _remove_one(self, slot: int, c: PodContribution) -> None:
         """One held pod taken out on the spot (the per-event path); its
@@ -508,8 +530,6 @@ class IncrementalEncoder:
             self.port_mask[slot] = _pack_bits(
                 list(pc), self.port_mask.shape[1]
             )
-        if c.affinity:
-            self._affinity_pods -= 1
         if self._node_gone[slot] and self._pod_count_slot[slot] == 0:
             self._free_slot(slot)
 
@@ -523,16 +543,21 @@ class IncrementalEncoder:
         The sums commute, so only three things depend on order, and
         each keeps it: `_contribs` is updated event by event; a spread
         class takes its vocabulary id when its contribution first
-        appears; and whatever touches a gone-node slot (a pod on an
-        unknown node materialises one, its last pod leaving frees and
-        zeroes it) or carries host ports or affinity is applied on the
-        spot (`_add_one` / `_remove_one`). Within a batch a slot is
-        either live throughout and takes deferred deltas only, or gone
-        or free and takes immediate ones only: no node event falls
-        inside a batch, so a zeroed row never meets a deferred delta."""
+        appears, as do the terms it owns (`InterPodTables.owned`); and
+        whatever touches a gone-node slot (a pod on an unknown node
+        materialises one, its last pod leaving frees and zeroes it) or
+        carries host ports is applied on the spot (`_add_one` /
+        `_remove_one`). Within a batch a slot is either live throughout
+        and takes deferred deltas only, or gone or free and takes
+        immediate ones only: no node event falls inside a batch, so a
+        zeroed row never meets a deferred delta. The inter-pod tables
+        take the batch's deferred deltas in one `InterPodTables.apply`,
+        which is a length test where no pod of the batch owns a term
+        and none matches a spec."""
         contribs = self._contribs
         slot_of = self.slot_of
         classes = self.vocabs.classes
+        terms = self.vocabs.terms
         # id(contribution) -> its row in `sums` / `class_ids`, or -1 for
         # one that goes event by event; `alive` pins each contribution so
         # that an id names one of them for the whole batch
@@ -540,16 +565,18 @@ class IncrementalEncoder:
         alive: List[PodContribution] = []
         sums: List[Tuple[int, int, int, int, int]] = []
         class_ids: List[int] = []
+        owned: list = []  # per row: InterPodTables.owned, or None
 
         def first_seen(c: PodContribution) -> int:
             alive.append(c)
-            if c.host_ports or c.affinity:
+            if c.host_ports:
                 rows[id(c)] = -1
                 return -1
             known = len(classes)
             class_ids.append(classes.get(c.class_key))
             if len(classes) != known:
                 self._widths_sync()
+            owned.append(None if c.terms is None else terms.owned(c.terms))
             sums.append((c.cpu, c.mem, c.gpu, c.nonzero_cpu, c.nonzero_mem))
             rows[id(c)] = row = len(sums) - 1
             return row
@@ -606,6 +633,8 @@ class IncrementalEncoder:
                 self.class_count,
                 (slots, np.array(class_ids, np.intp)[row_of]), sign,
             )
+            if len(terms.specs) or any(o is not None for o in owned):
+                terms.apply(slots, row_of, sign, class_ids, owned)
         self._dirty_pod_side = True
         trace_profile.count_encoder_batch(len(run), fallbacks)
 
@@ -628,6 +657,22 @@ class IncrementalEncoder:
                 self._dirty_pod_side = True
         if run:
             self._apply_pod_events(run)
+        self._sync_terms()
+
+    def _sync_terms(self) -> None:
+        """The inter-pod tables brought up to the events applied and
+        the terms interned (rebuilt whole where a node event left them
+        stale); cheap where neither moved."""
+        self._widths_sync()
+        self.vocabs.terms.sync(self.class_count, self._contribs,
+                               self._node_gone)
+
+    def take_rebuilds(self) -> Dict[str, int]:
+        """Whole rebuilds of the inter-pod tables since the last call,
+        by reason: for the caller's counters (models/wave.count_encoder)."""
+        terms = self.vocabs.terms
+        taken, terms.rebuilds = terms.rebuilds, {}
+        return taken
 
     # -- wave view -----------------------------------------------------------
 
@@ -646,17 +691,11 @@ class IncrementalEncoder:
     def _scope_gate(self, pending: Sequence[Pod]) -> Optional[str]:
         """Why this wave's snapshot cannot come from the kept state, if
         it cannot: "config" (a policy the kept tables do not cover),
-        "affinity" (a bound or a pending pod carries an inter-pod term:
-        the five inter-pod tables are not kept from wave to wave),
         "volumes" (a pending pod mounts one). The caller counts it
         (models/wave.count_encoder)."""
         if not self._config_ok():
             return "config"
-        if self._affinity_pods > 0:
-            return "affinity"
         for p in pending:
-            if has_pod_affinity(p):
-                return "affinity"
             if p.spec.volumes:
                 return "volumes"
         return None
@@ -673,11 +712,20 @@ class IncrementalEncoder:
         "req_mcpu", "req_mem", "req_gpu", "nz_mcpu", "nz_mem",
         "pod_count", "port_mask", "class_count",
     })
+    # the inter-pod program (InterPodTables.snapshot_fields): what the
+    # node events and the term vocabulary shape, and the counting tables
+    # that bound pods move. Zero-width, and kept, where no pod ever
+    # carried a term
+    TERM_STATIC_FIELDS = frozenset({
+        "ip_topo_dom", "ip_u_topo", "ip_u_spec", "ip_lt_spec", "ip_lt_u",
+        "ip_lt_sign",
+    })
+    TERM_CARRY_FIELDS = frozenset({
+        "ip_term_count", "ip_own_anti", "ip_rev_hard", "ip_rev_pref",
+        "ip_rev_anti", "ip_spec_total",
+    })
     # deterministically empty under the wave gates: reusable by shape
     WAVE_CONST_FIELDS = frozenset({
-        "ip_topo_dom", "ip_u_topo", "ip_u_spec", "ip_lt_spec", "ip_lt_u",
-        "ip_lt_sign", "ip_term_count", "ip_own_anti", "ip_rev_hard",
-        "ip_rev_pref", "ip_rev_anti", "ip_spec_total",
         "vol_any", "vol_rw", "ebs_mask", "gce_mask", "ebs_bad", "gce_bad",
         "vz_zone", "vz_region", "vz_has",
         "svc_lbl_val", "svc_node_ord", "svc_ord_node", "svc_first_peer",
@@ -730,12 +778,21 @@ class IncrementalEncoder:
             enc, keys, light.services, light.controllers,
             light.replica_sets,
         )
-        self._widths_sync()
+        self._sync_terms()  # the wave's own terms may be first seen
         keep = set(self.WAVE_CONST_FIELDS)
         if not self._dirty_node_side:
             keep |= self.NODE_SIDE_FIELDS
         if not self._dirty_pod_side:
             keep |= self.POD_SIDE_FIELDS
+        terms = self.vocabs.terms
+        if terms.static_version == self._last_terms[0]:
+            keep |= self.TERM_STATIC_FIELDS
+        if terms.carry_version == self._last_terms[1]:
+            keep |= self.TERM_CARRY_FIELDS
+            self.reship = frozenset()
+        else:
+            self.reship = self.TERM_CARRY_FIELDS
+        self._last_terms = (terms.static_version, terms.carry_version)
         if len(self.vocabs.set_members) == self._last_sets_len:
             keep.add("set_table")
         img_vocab = tuple(enc.images.ids)
@@ -807,18 +864,7 @@ class IncrementalEncoder:
             ),
             noschedule_taints=self._taint_effect_mask("NoSchedule", w["TW"]),
             prefer_taints=self._taint_effect_mask("PreferNoSchedule", w["TW"]),
-            ip_topo_dom=enc.interpod.topo_dom,
-            ip_u_topo=enc.interpod.u_topo,
-            ip_u_spec=enc.interpod.u_spec,
-            ip_lt_spec=enc.interpod.lt_spec,
-            ip_lt_u=enc.interpod.lt_u,
-            ip_lt_sign=enc.interpod.lt_sign,
-            ip_term_count=enc.interpod.term_count,
-            ip_own_anti=enc.interpod.own_anti,
-            ip_rev_hard=enc.interpod.rev_hard,
-            ip_rev_pref=enc.interpod.rev_pref,
-            ip_rev_anti=enc.interpod.rev_anti,
-            ip_spec_total=enc.interpod.spec_total,
+            **v.terms.snapshot_fields(),
             # wave pods carry no volumes (gate), so the node-side volume
             # state is vacuous — but the arrays must still be node-axis
             # shaped for the predicate ops (the light compiler saw zero

@@ -49,29 +49,42 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from itertools import combinations
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
-from kubernetes_tpu.api.types import Pod, get_affinity
+from kubernetes_tpu.api.types import (
+    LabelSelector,
+    LabelSelectorRequirement,
+    Pod,
+    get_affinity,
+)
 from kubernetes_tpu.oracle.predicates import (
     DEFAULT_FAILURE_DOMAINS,
     get_namespaces_from_term,
     label_selector_as_selector,
 )
-from kubernetes_tpu.oracle.state import ClusterState
+from kubernetes_tpu.oracle.state import (
+    ClusterState,
+    PodTerms,
+    pod_terms,
+    selector_canon,
+)
+from kubernetes_tpu.snapshot.encode import grown
 
 
-def _selector_canon(sel) -> object:
-    if sel is None:
-        return None
-    return (
-        tuple(sorted((sel.match_labels or {}).items())),
-        tuple(
-            (e.key, e.operator, tuple(e.values or ()))
-            for e in (sel.match_expressions or ())
+def _selector_of(canon):
+    """The labels.Selector of a `selector_canon`."""
+    if canon is None:
+        return label_selector_as_selector(None)
+    labels, exprs = canon
+    return label_selector_as_selector(LabelSelector(
+        match_labels=dict(labels),
+        match_expressions=tuple(
+            LabelSelectorRequirement(key=k, operator=op, values=values)
+            for k, op, values in exprs
         ),
-    )
+    ))
 
 
 @dataclass
@@ -132,18 +145,22 @@ class _Vocab:
         return len(self.items)
 
 
-class InterPodCompiler:
-    def __init__(
-        self,
-        state: ClusterState,
-        pods: Sequence[Pod],
-        node_names: Sequence[str],
-        default_keys: Sequence[str] = DEFAULT_FAILURE_DOMAINS,
-    ):
-        self.state = state
-        self.pods = list(pods)
-        self.node_names = list(node_names)
-        self.node_id = {n: i for i, n in enumerate(self.node_names)}
+#: the tables of term OWNERS (LT, E, D) with their dtype, and the
+#: pending-pod column that says what a pod will add to each once it is
+#: committed: by the index `InterPodTables.entries` gives
+OWNER_TABLES = (("own_anti", np.int32, "own_anti_hard"),
+                ("rev_hard", np.int32, "own_hard"),
+                ("rev_pref", np.int64, "own_pref"),
+                ("rev_anti", np.int64, "own_anti_pref"))
+_OWN_ANTI, _REV_HARD, _REV_PREF, _REV_ANTI = range(4)
+
+
+class TermVocab:
+    """Specs, topology combos, term classes and logical terms, interned
+    in order of first appearance, and the tables that say what each
+    is."""
+
+    def __init__(self, default_keys: Sequence[str] = DEFAULT_FAILURE_DOMAINS):
         self.default_keys = tuple(default_keys)
         self.specs = _Vocab()  # (ns_frozenset, sel_canon) -> s
         self.spec_impl: List[Tuple[frozenset, object]] = []  # (names, selector)
@@ -153,15 +170,6 @@ class InterPodCompiler:
         self.lt_expansion: List[List[Tuple[int, int]]] = []  # lt -> [(u, sign)]
 
     # -- interning -----------------------------------------------------------
-
-    def _spec_id(self, owner: Pod, term) -> int:
-        names = get_namespaces_from_term(owner, term)
-        sel = label_selector_as_selector(term.label_selector)
-        key = (frozenset(names), _selector_canon(term.label_selector))
-        s = self.specs.get(key)
-        if s == len(self.spec_impl):
-            self.spec_impl.append((frozenset(names), sel))
-        return s
 
     def _combos(self, topology_key: str) -> List[Tuple[Tuple[str, ...], int]]:
         """Inclusion-exclusion expansion of a topology spec into key
@@ -175,24 +183,26 @@ class InterPodCompiler:
                 out.append((tuple(sorted(keys)), sign))
         return out
 
-    def _lt_id(self, owner: Pod, term) -> int:
-        s = self._spec_id(owner, term)
-        key = (s, term.topology_key)
-        lt = self.lts.get(key)
+    def _lt_of(self, s: int, topology_key: str) -> int:
+        lt = self.lts.get((s, topology_key))
         if lt == len(self.lt_expansion):
             exp = []
-            for keys, sign in self._combos(term.topology_key):
+            for keys, sign in self._combos(topology_key):
                 q = self.topos.get(keys)
                 u = self.units.get((s, q))
                 exp.append((u, sign))
             self.lt_expansion.append(exp)
         return lt
 
-    def _pod_matches_spec(self, pod: Pod, s: int) -> bool:
+    def matches_spec(self, namespace: str, labels: Dict[str, str],
+                     s: int) -> bool:
         names, sel = self.spec_impl[s]
-        if names and pod.namespace not in names:
+        if names and namespace not in names:
             return False
-        return sel.matches(pod.metadata.labels)
+        return sel.matches(labels)
+
+    def _pod_matches_spec(self, pod: Pod, s: int) -> bool:
+        return self.matches_spec(pod.namespace, pod.metadata.labels, s)
 
     def _pod_self_match(self, pod: Pod, s: int) -> bool:
         """First-pod-of-collection self check (predicates.go:826-832):
@@ -200,6 +210,521 @@ class InterPodCompiler:
         all-namespaces set contains nothing, so the escape is denied."""
         names, sel = self.spec_impl[s]
         return pod.namespace in names and sel.matches(pod.metadata.labels)
+
+    # -- tables of the vocabulary ----------------------------------------------
+
+    @property
+    def expansion_width(self) -> int:
+        return max([1] + [len(e) for e in self.lt_expansion])
+
+    def term_tables(self):
+        """-> (u_topo, u_spec, lt_spec, lt_u, lt_sign)."""
+        U, LT, E = len(self.units), len(self.lts), self.expansion_width
+        u_topo = np.zeros(U, np.int32)
+        u_spec = np.zeros(U, np.int32)
+        for (s, q), u in self.units.ids.items():
+            u_spec[u], u_topo[u] = s, q
+        lt_spec = np.zeros(LT, np.int32)
+        lt_u = np.full((LT, E), -1, np.int32)
+        lt_sign = np.zeros((LT, E), np.int8)
+        for (s, _k), lt in self.lts.ids.items():
+            lt_spec[lt] = s
+            for e, (u, sign) in enumerate(self.lt_expansion[lt]):
+                lt_u[lt, e], lt_sign[lt, e] = u, sign
+        return u_topo, u_spec, lt_spec, lt_u, lt_sign
+
+
+class _Domains:
+    """One topology combo's domains: an id for every tuple of label
+    values that some node has now, given back when the last such node
+    goes and taken again by the next domain first seen. Under node
+    churn (a hostname key: a domain a node) the ids so stay below the
+    most domains ever live at once, and the tables' shapes (and the
+    programs built for them) stay as they are; a table holds nothing at
+    an id given back, since a node that goes or moves with pods on it
+    has the tables counted again (`InterPodTables.stale`)."""
+
+    def __init__(self):
+        self.ids: Dict[tuple, int] = {}  # label values -> domain
+        self.nodes: Dict[tuple, int] = {}  # label values -> nodes there
+        self.free: List[int] = []
+        self.width = 0  # ids ever out at once
+
+    def take(self, values: tuple) -> int:
+        d = self.ids.get(values)
+        if d is None:
+            d = self.ids[values] = self.free.pop() if self.free \
+                else self.width
+            self.width = max(self.width, d + 1)
+            self.nodes[values] = 0
+        self.nodes[values] += 1
+        return d
+
+    def give(self, values: tuple) -> None:
+        self.nodes[values] -= 1
+        if not self.nodes[values]:
+            del self.nodes[values]
+            self.free.append(self.ids.pop(values))
+
+
+class Owned(NamedTuple):
+    """What the pods of one `PodTerms` add to the owner tables, in one
+    InterPodTables' ids."""
+
+    parsed: bool
+    entries: tuple  # (index into OWNER_TABLES, lt, weight)
+    anti_specs: tuple  # the spec of each required anti-affinity term
+
+
+class InterPodTables(TermVocab):
+    """The snapshot side of the inter-pod program, kept from wave to
+    wave for one IncrementalEncoder (snapshot/incremental.py): the
+    vocabularies persist, `topo_dom` follows the node events, and the
+    five counting tables and `spec_total` take each bound pod's share
+    when it comes and give it back when it goes. What comes out equals
+    `InterPodCompiler.compile` over the same cluster up to the numbering
+    of specs, terms and domains (the compiler interns the assigned pods
+    first; here a spec's and a term's id is kept for good, a domain's
+    for as long as a node has it: `_Domains`).
+
+    A pod's share has two parts. Which specs it matches is a matter of
+    its namespace and labels, that is of its spread class: `match` holds
+    classes x specs, each pair matched once, and `term_count` /
+    `spec_total` follow the encoder's `class_count`. A spec or a term
+    class first seen is counted from `class_count` at the next `sync`
+    (`_filled`: until then no event touches it). The terms a pod OWNS
+    (`Owned`, derived once per distinct `PodTerms`) go to the owner
+    tables at its node's domains; an owner whose node is unknown or gone
+    is counted per spec instead (`unknown_anti`: the symmetric check
+    then rejects every node for the pods that match), and a pod whose
+    annotation does not parse is counted in `unparsed` (the poison).
+
+    What the deltas do not cover marks the tables `stale`, and `sync`
+    rebuilds them whole, counted by reason in `rebuilds`: "relabel", a
+    node_set that moves a domain under the node's pods (or gives pods
+    on an unknown node their node); "node_removed", a node deleted
+    under its pods."""
+
+    def __init__(self, classes,
+                 default_keys: Sequence[str] = DEFAULT_FAILURE_DOMAINS):
+        super().__init__(default_keys)
+        self._classes = classes  # VocabBundle.classes: ids by class key
+        # per slot: the node's labels; None where the slot has no node
+        # (free, or held by pods whose node is unknown or gone)
+        self._labels: List[Optional[Dict[str, str]]] = []
+        self._doms: List[_Domains] = []  # per combo
+        self._domains = 1  # D: the widest combo's, at least 1
+        self._e = 1  # E: the longest expansion
+        self._units_of: List[List[int]] = []  # per spec
+        self.topo_dom = np.full((0, 0), -1, np.int32)  # (Q.., slots)
+        self.match = np.zeros((0, 0), np.int8)  # (classes.., specs..)
+        self._matched = (0, 0)
+        self.term_count = np.zeros((0, 1), np.int32)  # (U.., D..)
+        self.spec_total = np.zeros(0, np.int32)  # (S..)
+        self._filled = (0, 0)  # units, specs counted so far
+        self.owners = [np.zeros((0, 1, 1), dt)
+                       for _name, dt, _column in OWNER_TABLES]
+        self.unknown_anti = np.zeros(0, np.int64)  # (S..)
+        self.unparsed = 0
+        self._owned: Dict[PodTerms, Owned] = {}
+        self.stale: Optional[str] = None
+        self.rebuilds: Dict[str, int] = {}
+        # what `snapshot_fields` last gave, by the version it was made at
+        self.static_version = self.carry_version = 0
+        self._static = self._carry = (-1, None)
+
+    # -- a pod's terms (oracle.state.PodTerms) in these ids -------------------------
+
+    def _spec_of(self, names: frozenset, canon) -> int:
+        s = self.specs.get((names, canon))
+        if s == len(self.spec_impl):
+            self.spec_impl.append((names, _selector_of(canon)))
+        return s
+
+    def term_lt(self, term: tuple) -> int:
+        """The logical term of a `PodTerms` term."""
+        names, canon, topology_key = term
+        return self._lt_of(self._spec_of(names, canon), topology_key)
+
+    def entries(self, terms: Optional[PodTerms]) -> List[Tuple[int, int, int]]:
+        """-> [(index into OWNER_TABLES, logical term, weight)] of every
+        term a pod owns, interned here in the order a pass over its
+        affinity meets them."""
+        if terms is None:
+            return []
+        return (
+            [(_REV_HARD, self.term_lt(t), 1) for t in terms.hard]
+            + [(_REV_PREF, self.term_lt(t), w) for t, w in terms.pref]
+            + [(_OWN_ANTI, self.term_lt(t), 1) for t in terms.anti_hard]
+            + [(_REV_ANTI, self.term_lt(t), w) for t, w in terms.anti_pref])
+
+    # -- pending-pod arrays ------------------------------------------------------
+
+    def pod_rows(self, pods: Sequence[Pod]) -> Dict[str, np.ndarray]:
+        """The pending-pod arrays that are a pod's own (a row each,
+        whatever else the wave holds), the pods' terms interned here pod
+        by pod: as wide as the vocabulary and the pods' longest lists."""
+        parsed = [pod_terms(pod) for pod in pods]
+        owned = [self.entries(terms) for terms in parsed]
+        S, LT, P = len(self.specs), len(self.lts), len(pods)
+        ha_lists: List[List[Tuple[int, bool]]] = []
+        hq_lists: List[List[int]] = []
+        fwd_lists: List[List[Tuple[int, int]]] = []
+        for pod, entries in zip(pods, owned):
+            ha_lists.append([
+                (lt, self._pod_self_match(pod, self.lts.items[lt][0]))
+                for table, lt, _w in entries if table == _REV_HARD])
+            hq_lists.append([lt for table, lt, _w in entries
+                             if table == _OWN_ANTI])
+            # interpod_affinity.go:107 skips a weight of zero; the
+            # anti-affinity terms' weights count against a node
+            fwd_lists.append([
+                (lt, w if table == _REV_PREF else -w)
+                for table, lt, w in entries
+                if table in (_REV_PREF, _REV_ANTI) and w])
+        TA = max([1] + [len(x) for x in ha_lists])
+        TQ = max([1] + [len(x) for x in hq_lists])
+        TF = max([1] + [len(x) for x in fwd_lists])
+        rows = dict(
+            match_spec=np.zeros((P, S), np.int8),
+            ha_lt=np.full((P, TA), -1, np.int32),
+            ha_self=np.zeros((P, TA), bool),
+            hq_lt=np.full((P, TQ), -1, np.int32),
+            fwd_lt=np.full((P, TF), -1, np.int32),
+            fwd_w=np.zeros((P, TF), np.int64),
+            own_hard=np.zeros((P, LT), np.int32),
+            own_pref=np.zeros((P, LT), np.int64),
+            own_anti_hard=np.zeros((P, LT), np.int32),
+            own_anti_pref=np.zeros((P, LT), np.int64),
+            has_affinity=np.zeros(P, bool),
+            has_anti=np.zeros(P, bool),
+        )
+        for i, (pod, terms) in enumerate(zip(pods, parsed)):
+            for s in range(S):
+                rows["match_spec"][i, s] = self._pod_matches_spec(pod, s)
+            for j, (lt, selfm) in enumerate(ha_lists[i]):
+                rows["ha_lt"][i, j] = lt
+                rows["ha_self"][i, j] = selfm
+            for j, lt in enumerate(hq_lists[i]):
+                rows["hq_lt"][i, j] = lt
+            for j, (lt, w) in enumerate(fwd_lists[i]):
+                rows["fwd_lt"][i, j] = lt
+                rows["fwd_w"][i, j] = w
+            if terms is not None:
+                rows["has_affinity"][i] = terms.affinity
+                rows["has_anti"][i] = terms.anti
+            # what this pod will contribute once committed mid-scan
+            # (per logical term; the device scatters into all E slots)
+            for table, lt, w in owned[i]:
+                rows[OWNER_TABLES[table][2]][i, lt] += w
+        return rows
+
+    # -- shapes ----------------------------------------------------------------
+
+    def _lt_of(self, s: int, topology_key: str) -> int:
+        known = len(self.lt_expansion)
+        lt = super()._lt_of(s, topology_key)
+        if lt == known:
+            self._fit()
+        return lt
+
+    def _fit(self) -> None:
+        """Room for a logical term first seen, and for what came with
+        it: its spec, its term classes, their topology combos (each
+        node's domain under a new combo, in slot order)."""
+        S, Q, U = len(self.specs), len(self.topos), len(self.units)
+        self._e = max(self._e, len(self.lt_expansion[-1]))
+        self.topo_dom = grown(self.topo_dom, (Q, len(self._labels)), -1)
+        for q in range(len(self._doms), Q):
+            self._doms.append(_Domains())
+            for slot, labels in enumerate(self._labels):
+                if labels is not None:
+                    self._place(q, slot, None, labels)
+        while len(self._units_of) < S:
+            self._units_of.append([])
+        for u in range(sum(len(x) for x in self._units_of), U):
+            self._units_of[self.units.items[u][0]].append(u)
+        self.spec_total = grown(self.spec_total, (S,))
+        self.unknown_anti = grown(self.unknown_anti, (S,))
+        self._fit_domains()
+        self.static_version += 1
+        self.carry_version += 1
+
+    def _fit_domains(self) -> None:
+        U, LT, D = len(self.units), len(self.lts), self._domains
+        self.term_count = grown(self.term_count, (U, D))
+        self.owners = [grown(t, (LT, self._e, D)) for t in self.owners]
+
+    def _values(self, q: int, labels: Optional[Dict[str, str]]
+                ) -> Optional[tuple]:
+        """The label values that name a node's domain under combo q;
+        None where it has none (no node, or a key missing or empty:
+        never co-located)."""
+        if labels is None:
+            return None
+        vv = tuple(labels.get(k, "") for k in self.topos.items[q])
+        return None if "" in vv else vv
+
+    def _place(self, q: int, slot: int, old: Optional[Dict[str, str]],
+               new: Optional[Dict[str, str]]) -> bool:
+        """`topo_dom[q, slot]` for a slot whose node's labels were
+        `old` and are `new` (None: no node); whether it moved."""
+        was, now = self._values(q, old), self._values(q, new)
+        if was == now:
+            return False
+        doms = self._doms[q]
+        if was is not None:
+            doms.give(was)
+        d = -1 if now is None else doms.take(now)
+        if doms.width > self._domains:
+            self._domains = doms.width
+            self._fit_domains()
+            self.carry_version += 1
+        moved = self.topo_dom[q, slot] != d
+        self.topo_dom[q, slot] = d
+        return bool(moved)
+
+    # -- node events -------------------------------------------------------------
+
+    def grow(self, slots: int) -> None:
+        self._labels += [None] * (slots - len(self._labels))
+        self.topo_dom = grown(self.topo_dom, (len(self.topos), slots), -1)
+        self.static_version += 1
+
+    def _mark_stale(self, reason: str) -> None:
+        if len(self.specs) and self.stale is None:
+            self.stale = reason  # nothing is counted by node before a spec
+
+    def node_set(self, slot: int, labels: Dict[str, str], pods: int,
+                 was_gone: bool) -> None:
+        """The slot's node is (now) this one; `pods` of it are held,
+        `was_gone` if they were held without a node."""
+        old, self._labels[slot] = self._labels[slot], labels
+        moved = False
+        for q in range(len(self._doms)):
+            moved |= self._place(q, slot, old, labels)
+        if moved:
+            self.static_version += 1
+        if pods and (moved or was_gone):
+            self._mark_stale("relabel")
+
+    def node_gone(self, slot: int, pods: int) -> None:
+        """The slot has no node any more: it is free, or `pods` of the
+        node linger on it."""
+        old, self._labels[slot] = self._labels[slot], None
+        moved = False
+        for q in range(len(self._doms)):
+            moved |= self._place(q, slot, old, None)
+        if moved:
+            self.static_version += 1
+        if pods:
+            self._mark_stale("node_removed")
+
+    # -- pod events ----------------------------------------------------------------
+
+    def owned(self, terms: PodTerms) -> Owned:
+        o = self._owned.get(terms)
+        if o is None:
+            entries = tuple(self.entries(terms))
+            o = self._owned[terms] = Owned(terms.parsed, entries, tuple(
+                self.lts.items[lt][0] for table, lt, _w in entries
+                if table == _OWN_ANTI))
+        return o
+
+    def _sync_match(self) -> None:
+        """Every class against every spec, each pair once."""
+        C, S = len(self._classes), len(self.specs)
+        c0, s0 = self._matched
+        if (C, S) == (c0, s0):
+            return
+        self._matched = (C, S)
+        if not S:
+            return
+        self.match = grown(self.match, (C, S))
+        for c, (ns, labels_fs, _deleted) in enumerate(self._classes.ids):
+            first = s0 if c < c0 else 0
+            if first < S:
+                labels = dict(labels_fs)
+                for s in range(first, S):
+                    self.match[c, s] = self.matches_spec(ns, labels, s)
+
+    def apply(self, slots: np.ndarray, row_of: np.ndarray, sign: np.ndarray,
+              row_class: Sequence[int], row_owned: Sequence[Optional[Owned]],
+              gone: bool = False) -> None:
+        """Pod events into the tables: event i is a pod of template
+        `row_of[i]` (its class `row_class[r]`, what it owns
+        `row_owned[r]`) coming to (`sign[i]` +1) or leaving (-1)
+        `slots[i]`; `gone` if those slots have no node."""
+        if self.stale is not None:
+            return  # `sync` counts the held pods again, these among them
+        self._sync_match()
+        U0, S0 = self._filled
+        changed = False
+        if S0:
+            m = self.match[np.asarray(row_class, np.intp), :S0]
+            hit = np.flatnonzero(m.any(axis=0))
+            if hit.size:
+                changed = True
+                ev = m[row_of]  # events x specs
+                self.spec_total[:S0] += (
+                    ev.astype(np.int32) * sign[:, None].astype(np.int32)
+                ).sum(axis=0, dtype=np.int32)
+                for s in hit.tolist():
+                    at = np.flatnonzero(ev[:, s])
+                    for u in self._units_of[s]:
+                        if u < U0:
+                            self._add_at(self.term_count[u], u, slots[at],
+                                         sign[at])
+        for r, o in enumerate(row_owned):
+            if o is None:
+                continue
+            at = np.flatnonzero(row_of == r)
+            if at.size:
+                changed = True
+                self._apply_owned(o, slots[at], sign[at], gone)
+        if changed:
+            self.carry_version += 1
+
+    def _add_at(self, row: np.ndarray, u: int, slots: np.ndarray,
+                amount: np.ndarray) -> None:
+        """row[domain of each slot under u's combo] += amount."""
+        d = self.topo_dom[self.units.items[u][1], slots]
+        ok = d >= 0
+        np.add.at(row, d[ok], amount[ok].astype(row.dtype))
+
+    def _apply_owned(self, o: Owned, slots: np.ndarray, sign: np.ndarray,
+                     gone: bool) -> None:
+        if not o.parsed:
+            self.unparsed += int(sign.sum())
+        elif gone:
+            for s in o.anti_specs:
+                self.unknown_anti[s] += int(sign.sum())
+        else:
+            for table, lt, w in o.entries:
+                for e, (u, _sign) in enumerate(self.lt_expansion[lt]):
+                    self._add_at(self.owners[table][lt, e], u, slots,
+                                 sign * w)
+
+    # -- a wave's tables ---------------------------------------------------------------
+
+    def sync(self, class_count: np.ndarray, contribs, node_gone: np.ndarray
+             ) -> None:
+        """Bring the tables up to the events applied and the vocabulary
+        interned: whole again if `stale` (the owners from `contribs`,
+        the encoder's (slot, contribution) of every held pod), and the
+        specs and term classes not counted yet from `class_count`."""
+        if self.stale is not None:
+            self.rebuilds[self.stale] = self.rebuilds.get(self.stale, 0) + 1
+            self.stale = None
+            self.term_count[:] = 0
+            self.spec_total[:] = 0
+            self.unknown_anti[:] = 0
+            for table in self.owners:
+                table[:] = 0
+            self.unparsed = 0
+            self._filled = (0, 0)
+            by_owned: Dict[int, Tuple[Owned, List[int]]] = {}
+            for slot, c in contribs.values():
+                if c.terms is not None:
+                    o = self.owned(c.terms)
+                    by_owned.setdefault(id(o), (o, []))[1].append(slot)
+            for o, held in by_owned.values():
+                at = np.array(held, np.intp)
+                lost = node_gone[at]
+                for part, gone in ((at[~lost], False), (at[lost], True)):
+                    if part.size:
+                        self._apply_owned(
+                            o, part, np.ones(part.size, np.int64), gone)
+            self.carry_version += 1
+        self._sync_match()
+        U0, S0 = self._filled
+        U, S = len(self.units), len(self.specs)
+        if (U, S) == (U0, S0):
+            return
+        self._filled = (U, S)
+        self.carry_version += 1
+        C = self._matched[0]
+        new_units: Dict[int, List[int]] = {s: [] for s in range(S0, S)}
+        for u in range(U0, U):
+            new_units.setdefault(self.units.items[u][0], []).append(u)
+        slots = np.arange(class_count.shape[0])
+        for s, units in new_units.items():
+            classes = np.flatnonzero(self.match[:C, s])
+            if not classes.size:
+                continue
+            held = class_count[:, classes].sum(axis=1)
+            if s >= S0:
+                self.spec_total[s] = held.sum()
+            at = np.flatnonzero(held)
+            for u in units:
+                self._add_at(self.term_count[u], u, slots[at], held[at])
+
+    def snapshot_fields(self) -> Dict[str, np.ndarray]:
+        """The twelve `ip_*` fields of a ClusterSnapshot, cut to the
+        vocabularies' widths. The same arrays come again for as long as
+        nothing changed them (`static_version`, `carry_version`)."""
+        S, Q, U, LT = (len(self.specs), len(self.topos), len(self.units),
+                       len(self.lts))
+        E, D = self._e, self._domains
+        if self._static[0] != self.static_version:
+            u_topo, u_spec, lt_spec, lt_u, lt_sign = self.term_tables()
+            self._static = (self.static_version, dict(
+                # cut on both axes: `grown` leaves room beyond the
+                # combos and beyond the node axis alike. (No combo, no
+                # node axis: what a compiler that saw no term gives, and
+                # the programs are built per shape)
+                ip_topo_dom=self.topo_dom[:Q, :len(self._labels)].copy()
+                if Q else np.zeros((0, 0), np.int32), ip_u_topo=u_topo,
+                ip_u_spec=u_spec, ip_lt_spec=lt_spec, ip_lt_u=lt_u,
+                ip_lt_sign=lt_sign))
+        if self._carry[0] != self.carry_version:
+            fields = {
+                "ip_" + name: table[:LT, :E, :D].copy()
+                for (name, _dt, _column), table
+                in zip(OWNER_TABLES, self.owners)}
+            fields["ip_term_count"] = self.term_count[:U, :D].copy() \
+                if U else np.zeros((0, 1), np.int32)
+            fields["ip_spec_total"] = self.spec_total[:S].copy()
+            self._carry = (self.carry_version, fields)
+        return {**self._static[1], **self._carry[1]}
+
+    def wave_flags(self, match_spec: np.ndarray, has_anti: np.ndarray
+                   ) -> Tuple[np.ndarray, bool]:
+        """-> (sym_reject, poison) of a wave's pods, which are not the
+        pods' own: an owner of a required anti-affinity term that has no
+        node rejects every node for the pods its spec matches, and one
+        bound pod that does not parse poisons every pod."""
+        poison = self.unparsed > 0
+        hot = self.unknown_anti[: match_spec.shape[1]] > 0
+        sym = has_anti & (poison | (match_spec[:, hot] != 0).any(axis=1))
+        return sym, poison
+
+
+class InterPodCompiler(TermVocab):
+    def __init__(
+        self,
+        state: ClusterState,
+        pods: Sequence[Pod],
+        node_names: Sequence[str],
+        default_keys: Sequence[str] = DEFAULT_FAILURE_DOMAINS,
+    ):
+        super().__init__(default_keys)
+        self.state = state
+        self.pods = list(pods)
+        self.node_names = list(node_names)
+        self.node_id = {n: i for i, n in enumerate(self.node_names)}
+
+    def _lt_id(self, owner: Pod, term) -> int:
+        """The logical term of an owner's PodAffinityTerm, straight from
+        the objects (not through `oracle.state.pod_terms`, which the
+        kept tables and the stored rows are made from: this class is
+        what the tests hold those to)."""
+        names = frozenset(get_namespaces_from_term(owner, term))
+        s = self.specs.get((names, selector_canon(term.label_selector)))
+        if s == len(self.spec_impl):
+            self.spec_impl.append(
+                (names, label_selector_as_selector(term.label_selector)))
+        return self._lt_of(s, term.topology_key)
 
     @staticmethod
     def _affinity(pod: Pod):
@@ -245,7 +770,7 @@ class InterPodCompiler:
 
         S, Q, U, LT = len(self.specs), len(self.topos), len(self.units), len(self.lts)
         N, P = len(self.node_names), len(pods)
-        E = max([1] + [len(e) for e in self.lt_expansion])
+        E = self.expansion_width
 
         # topology domains per combo
         topo_dom = np.full((Q, N), -1, np.int32)
@@ -262,17 +787,7 @@ class InterPodCompiler:
             n_dom = max(n_dom, len(vals))
         D = n_dom
 
-        u_topo = np.zeros(U, np.int32)
-        u_spec = np.zeros(U, np.int32)
-        for (s, q), u in self.units.ids.items():
-            u_spec[u], u_topo[u] = s, q
-        lt_spec = np.zeros(LT, np.int32)
-        lt_u = np.full((LT, E), -1, np.int32)
-        lt_sign = np.zeros((LT, E), np.int8)
-        for (s, _k), lt in self.lts.ids.items():
-            lt_spec[lt] = s
-            for e, (u, sign) in enumerate(self.lt_expansion[lt]):
-                lt_u[lt, e], lt_sign[lt, e] = u, sign
+        u_topo, u_spec, lt_spec, lt_u, lt_sign = self.term_tables()
 
         # initial carry from assigned pods
         term_count = np.zeros((U, max(1, D)), np.int32)

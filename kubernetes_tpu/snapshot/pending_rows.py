@@ -31,13 +31,21 @@ it observed at every `batch()`; nothing is a setting):
   * the wave's own image vocabulary (per wave by design,
     `SnapshotEncoder._build_vocabs`): `img_count` is filled per wave
     from each row's image names;
+  * the inter-pod vocabulary (`VocabBundle.terms`), which only grows:
+    the logical terms a row names and owns are its own entries and never
+    move, its `ip_own_*` columns are zero for a term first seen later
+    (zero-padding), and its `ip_match_spec` is extended by the specs new
+    to it, from its own namespace and labels. `ip_sym_reject` and
+    `ip_poison` are not a row's: every wave reads them off the bound
+    pods' counts (`InterPodTables.wave_flags`);
   * the ids a row holds (label keys, value sets, numeric keys, ports,
-    its class) are its own entries of append-only vocabularies, interned
-    before it was encoded: they never move.
+    its class, its logical terms) are its own entries of append-only
+    vocabularies, interned before it was encoded: they never move.
 
-The program axes (R1, T, TP, R) of a batch are the largest any of its
-pods asks; each row remembers what it asks alone, is stored zero-padded,
-and is cut to the wave's. tests/test_pending_rows.py holds every field
+The program axes (R1, T, TP, R, and the inter-pod lists' TA, TQ, TF) of
+a batch are the largest any of its pods asks; each row remembers what it
+asks alone, is stored zero-padded (a list of term ids one up, so that
+the padding reads -1), and is cut to the wave's. tests/test_pending_rows.py holds every field
 of an assembled batch to a fresh `encode_pods` over the same
 vocabularies and listers, one event kind at a time.
 """
@@ -57,13 +65,16 @@ from kubernetes_tpu.snapshot.encode import (
     SpreadSelectors,
     VocabBundle,
     ClassPairs,
+    grown,
     spread_match_row,
 )
 from kubernetes_tpu.trace import profile as trace_profile
 
 #: row fields of PodBatch and the batch axes that shape each beyond the
-#: pod axis: vocabulary widths (PW, TW, TV, C: `SnapshotEncoder.widths`)
-#: and program axes (R1, T, TP, R: `SnapshotEncoder.term_widths`)
+#: pod axis: vocabulary widths (PW, TW, TV, C: `SnapshotEncoder.widths`;
+#: S, LT: the inter-pod specs and logical terms) and program axes (R1,
+#: T, TP, R: `SnapshotEncoder.term_widths`; TA, TQ, TF: the longest list
+#: of required affinity, required anti-affinity and preferred terms)
 _ROW_FIELDS: Dict[str, tuple] = {
     "req_mcpu": (), "req_mem": (), "req_gpu": (), "zero_req": (),
     "commit_mcpu": (), "commit_mem": (), "commit_gpu": (),
@@ -82,18 +93,18 @@ _ROW_FIELDS: Dict[str, tuple] = {
     "has_tolerations": (), "best_effort": (),
     "has_selectors": (), "spread_match": ("C",),
     "class_id": (), "unschedulable": (),
+    "ip_match_spec": ("S",), "ip_ha_lt": ("TA",), "ip_ha_self": ("TA",),
+    "ip_hq_lt": ("TQ",), "ip_fwd_lt": ("TF",), "ip_fwd_w": ("TF",),
+    "ip_own_hard": ("LT",), "ip_own_pref": ("LT",),
+    "ip_own_anti_hard": ("LT",), "ip_own_anti_pref": ("LT",),
+    "ip_has_affinity": (), "ip_has_anti": (),
 }
-_TERM_AXES = ("R1", "T", "TP", "R")
+_TERM_AXES = ("R1", "T", "TP", "R", "TA", "TQ", "TF")
+#: lists of logical-term ids, -1 beyond a pod's own: held one up
+_ONE_UP = ("ip_ha_lt", "ip_hq_lt", "ip_fwd_lt")
 
 _count_hit = scheduler_pending_row_lookups_total.child(result="hit")
 _count_miss = scheduler_pending_row_lookups_total.child(result="miss")
-
-
-def _grown(a: np.ndarray, shape: tuple) -> np.ndarray:
-    """`a` zero-padded to `shape`, which is no smaller on any axis."""
-    out = np.zeros(shape, a.dtype)
-    out[tuple(slice(0, h) for h in a.shape)] = a
-    return out
 
 
 class PendingRows:
@@ -128,9 +139,10 @@ class PendingRows:
         self._images: List[tuple] = []
         self._named: Dict[int, str] = {}
         # per row: the program axes it asks alone; the classes its
-        # spread_match is computed for
+        # spread_match and the specs its ip_match_spec are computed for
         self._asks = np.zeros((0, len(_TERM_AXES)), np.int64)
         self._classes_done = np.zeros(0, np.int64)
+        self._specs_done = np.zeros(0, np.int64)
 
     def __len__(self) -> int:
         return self._n
@@ -145,9 +157,8 @@ class PendingRows:
         want = (self._n,) + tuple(tail)
         if a is None:
             a = np.zeros((max(self._n, 64),) + want[1:], dtype)
-        elif any(h < w for h, w in zip(a.shape, want)):
-            a = _grown(a, tuple(h if h >= w else max(w, 2 * h)
-                                for h, w in zip(a.shape, want)))
+        else:
+            a = grown(a, want)
         self._arrays[name] = a
         return a
 
@@ -169,6 +180,7 @@ class PendingRows:
                        if renumber[r] >= 0}
         self._asks = self._asks[keep]
         self._classes_done = self._classes_done[keep]
+        self._specs_done = self._specs_done[keep]
         self._n = len(keep)
         return renumber
 
@@ -278,7 +290,18 @@ class PendingRows:
                         sm[row], start=int(self._classes_done[row]),
                         pairs=self._pairs)
                 self._classes_done[row] = n_classes
-        dims = dict(enc.widths)
+        terms = v.terms
+        n_specs = len(terms.specs)
+        if n_specs:
+            # specs first seen since a row was last matched
+            ms = self._array("ip_match_spec", (n_specs,))
+            behind = np.flatnonzero(self._specs_done[at] < n_specs)
+            for row in dict.fromkeys(at[behind].tolist()):
+                for s in range(int(self._specs_done[row]), n_specs):
+                    ms[row, s] = terms.matches_spec(
+                        self._ns[row], self._labels[row], s)
+                self._specs_done[row] = n_specs
+        dims = dict(enc.widths, S=n_specs, LT=len(terms.lts))
         asks = self._asks[at].max(axis=0, initial=1)
         dims.update(zip(_TERM_AXES, (int(x) for x in asks)))
         fields = {}
@@ -286,13 +309,18 @@ class PendingRows:
             tail = tuple(dims[a] for a in axes)
             a = self._array(name, tail)
             fields[name] = a[(at,) + tuple(slice(0, w) for w in tail)]
+        for name in _ONE_UP:
+            fields[name] -= 1
+        fields["ip_sym_reject"], poison = terms.wave_flags(
+            fields["ip_match_spec"], fields["ip_has_anti"])
+        fields["ip_poison"] = np.full(len(pods), poison, bool)
         if self._named:
             host_req = fields["host_req"]
             for i, row in enumerate(rows):
                 name = self._named.get(row)
                 if name is not None:
                     host_req[i] = enc.node_id.get(name, -2)
-        fields.update(enc.batch_fields())
+        fields.update(enc.batch_fields(interpod=False))
         img_count, image_id = fields["img_count"], enc.images.ids
         for i, row in enumerate(rows):
             for image in self._images[row]:
@@ -315,11 +343,19 @@ class PendingRows:
         self._n = first + len(pods)
         for name in _ROW_FIELDS:
             src = getattr(b, name)
+            if name in _ONE_UP:
+                src = src + 1
             a = self._array(name, src.shape[1:], src.dtype)
             a[(new,) + tuple(slice(0, w) for w in src.shape[1:])] = src
-        self._asks = np.concatenate([self._asks, sub.term_widths()])
+        self._asks = np.concatenate([self._asks, np.concatenate(
+            [sub.term_widths()]
+            + [(getattr(b, name) >= 0).sum(axis=1)[:, None]
+               for name in _ONE_UP], axis=1)])
         self._classes_done = np.concatenate(
             [self._classes_done, np.zeros(len(pods), np.int64)])
+        self._specs_done = np.concatenate(
+            [self._specs_done,
+             np.full(len(pods), b.ip_match_spec.shape[1], np.int64)])
         for row, pod, key in zip(new.tolist(), pods, keys):
             self._index[key] = row
             gave_up = bool(b.unschedulable[row - first])

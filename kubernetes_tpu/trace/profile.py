@@ -421,8 +421,8 @@ def idle_totals() -> Dict[str, float]:
 _encoder_lock = threading.Lock()
 #: pod events the incremental encoder (snapshot/incremental.py) applied
 #: in this process, the batches they came in, and the events inside a
-#: batch that had to be applied one at a time (host ports, affinity, a
-#: gone-node slot); served on /debug/traces as "encoder"
+#: batch that had to be applied one at a time (host ports, a gone-node
+#: slot); served on /debug/traces as "encoder"
 _ENCODER = {"events": 0, "batches": 0, "per_event_fallbacks": 0}
 
 
@@ -467,8 +467,9 @@ _wave_lock = threading.Lock()
 #: grouped header probe did (models/wave.GROUP_COUNTERS) and what the
 #: grouped device replay's loops ran (models/wave.ZREPLAY_COUNTERS), what
 #: the runs with a self-anti veto did (models/wave.ANTI_COUNTERS), which
-#: encoder made each wave's snapshot and which scope gate sent it to the
-#: from-scratch one; served on /debug/traces as "wave"
+#: encoder made each wave's snapshot, which scope gate sent it to the
+#: from-scratch one, and how often the incremental one rebuilt its
+#: inter-pod tables whole, by reason; served on /debug/traces as "wave"
 _WAVE: Dict[str, Any] = {"waves": 0, "pods_by_path": {},
                          "dispatches_by_kind": {}, "pods_unplaced": 0,
                          "group_runs": 0, "group_d2h_bytes": 0,
@@ -476,7 +477,8 @@ _WAVE: Dict[str, Any] = {"waves": 0, "pods_by_path": {},
                          "zreplay_slots": 0, "zreplay_rescores": 0,
                          "zreplay_picks": 0, "anti_runs": 0,
                          "anti_picks": 0, "anti_nodes_excluded": 0,
-                         "waves_by_encoder": {}, "encoder_fallbacks": {}}
+                         "waves_by_encoder": {}, "encoder_fallbacks": {},
+                         "interpod_rebuilds": {}}
 
 
 def count_wave(pods_by_path: Dict[str, int], dispatches: Dict[str, int],
@@ -506,15 +508,20 @@ def count_wave_group(counted: Dict[str, int]) -> None:
             _WAVE[k] += n
 
 
-def count_wave_encoder(encoder: str, fallback: Optional[str]) -> None:
+def count_wave_encoder(encoder: str, fallback: Optional[str],
+                       rebuilds: Optional[Dict[str, int]] = None) -> None:
     """A wave's snapshot came from `encoder` ("incremental" or "full"),
     sent there by the incremental encoder's scope gate `fallback`, if
-    by any."""
+    by any; `rebuilds` whole rebuilds of its inter-pod tables came
+    before it, by reason."""
     with _wave_lock:
         for key, k in (("waves_by_encoder", encoder),
                        ("encoder_fallbacks", fallback)):
             if k:
                 _WAVE[key][k] = _WAVE[key].get(k, 0) + 1
+        tally = _WAVE["interpod_rebuilds"]
+        for reason, n in (rebuilds or {}).items():
+            tally[reason] = tally.get(reason, 0) + n
 
 
 def wave_totals() -> Dict[str, Any]:

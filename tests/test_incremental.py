@@ -313,28 +313,45 @@ def test_pod_on_unsynced_node_invalidates_name_order():
     assert "name_desc_order" not in keep
 
 
+@pytest.mark.parametrize("owner", [False, True],
+                         ids=["plain", "term-owner"])
 @pytest.mark.parametrize("step,nodes,slots", [
     (None, 70, 128), (32, 70, 96), (32, 96, 96), (32, 97, 128),
 ])
-def test_node_axis_grows_by_its_step(step, nodes, slots):
+def test_node_axis_grows_by_its_step(step, nodes, slots, owner):
     """With a `slot_step` (the mesh driver's: a multiple of its
     devices) the node axis grows by that step and the view's node axis
     is a multiple of it; without one it doubles. The nodes' slots and
-    what the view says of them are the same either way."""
+    what the view says of them are the same either way, and where the
+    pending pod owns a term the nodes' domains are as long as every
+    other node array (the kept `topo_dom` has room beyond the axis)."""
+    from kubernetes_tpu.api.types import AFFINITY_ANNOTATION
+
     cache = SchedulerCache(clock=FakeClock())
     inc = IncrementalEncoder(slot_step=step)
     cache.add_listener(inc.on_cache_event)
     rng = random.Random(step or 1)
     for i in range(nodes):
         cache.add_node(rand_node(rng, f"node-{i:03d}"))
-    pending = [Pod(metadata=ObjectMeta(name="pend", labels={"app": "web"}),
-                   spec=PodSpec(containers=[
-                       Container(requests={"cpu": "100m"})]))]
+    meta = ObjectMeta(name="pend", labels={"app": "web"})
+    if owner:
+        meta.annotations[AFFINITY_ANNOTATION] = _terms_json(
+            "podAntiAffinity", [_term({"app": "web"}, HOSTNAME)])
+    pending = [Pod(metadata=meta, spec=PodSpec(containers=[
+        Container(requests={"cpu": "100m"})]))]
     snap, _batch, _keep = inc.wave_view(pending)
     assert snap.num_nodes == slots == len(snap.node_names)
     assert snap.node_names[:nodes] == [f"node-{i:03d}"
                                        for i in range(nodes)]
     assert not any(snap.node_names[nodes:])
+    assert snap.ip_topo_dom.shape == ((1, slots) if owner else (0, 0))
+    # and so they stay when the axis grows under the kept tables
+    for i in range(nodes, nodes + 2 * (step or slots)):
+        cache.add_node(rand_node(rng, f"node-{i:03d}"))
+    snap, _batch, _keep = inc.wave_view(pending)
+    assert snap.num_nodes > slots
+    assert snap.ip_topo_dom.shape == (
+        (1, snap.num_nodes) if owner else (0, 0))
 
 
 def test_daemon_warmup_compiles_incremental_shapes():
@@ -395,6 +412,26 @@ def test_daemon_warmup_compiles_incremental_shapes():
 
 # -- a batch of deltas against the same deltas one at a time ----------------
 
+HOSTNAME = "kubernetes.io/hostname"
+
+
+def _term(labels, key, namespaces=None):
+    t = {"labelSelector": {"matchLabels": labels}, "topologyKey": key}
+    if namespaces is not None:
+        t["namespaces"] = namespaces
+    return t
+
+
+def _terms_json(side, required=(), preferred=()):
+    import json
+
+    return json.dumps({side: {
+        "requiredDuringSchedulingIgnoredDuringExecution": list(required),
+        "preferredDuringSchedulingIgnoredDuringExecution": [
+            {"weight": w, "podAffinityTerm": t} for w, t in preferred],
+    }})
+
+
 _BATCH_TEMPLATES = (
     # (labels, requests, host ports, affinity annotation)
     ({"name": "sched-perf"}, {"cpu": "100m", "memory": "500Mi"}, (), None),
@@ -403,18 +440,38 @@ _BATCH_TEMPLATES = (
     ({}, {}, (), None),
     ({"app": "web"}, {"cpu": "100m"}, (8080,), None),
     ({"app": "lb"}, {"cpu": "50m"}, (9090, 8080), None),
+    # required anti-affinity to its own kind, by hostname
     ({"app": "near"}, {"cpu": "100m"}, (),
-     '{"podAntiAffinity": {"requiredDuringSchedulingIgnoredDuringExecution":'
-     ' [{"labelSelector": {"matchLabels": {"app": "near"}},'
-     ' "topologyKey": "kubernetes.io/hostname"}]}}'),
+     _terms_json("podAntiAffinity", [_term({"app": "near"}, HOSTNAME)])),
+    # required affinity by a second key, preferred by hostname
+    ({"app": "db"}, {"cpu": "100m"}, (),
+     _terms_json("podAffinity", [_term({"app": "web"}, ZONE)],
+                 [(5, _term({"app": "db"}, HOSTNAME))])),
+    # the empty key (any default failure domain: the inclusion-exclusion
+    # expansion), required and against every namespace
+    ({"app": "far"}, {"cpu": "50m"}, (),
+     _terms_json("podAntiAffinity", [_term({"app": "lb"}, "", [])],
+                 [(3, _term({"app": "web"}, ZONE))])),
+    # preferred by the empty key, with host ports (the per-event path)
+    ({"app": "lb"}, {"cpu": "50m"}, (7070,),
+     _terms_json("podAffinity", (), [(2, _term({"app": "far"}, ""))])),
+    # an annotation that does not parse: the poison
+    ({"app": "odd"}, {"cpu": "10m"}, (), "{not json"),
+    # a preferred term of weight 0 (owned, counted, never scored) beside
+    # one with its namespaces spelled out
+    ({"app": "web", "tier": "fe"}, {"cpu": "50m"}, (),
+     _terms_json("podAntiAffinity", (), [
+         (0, _term({"app": "db"}, ZONE)),
+         (7, _term({"app": "web"}, HOSTNAME, ["default", "elsewhere"]))])),
 )
+_BATCH_WEIGHTS = (8, 4, 3, 2, 2, 1, 3, 2, 2, 1, 1, 2)
 
 
 def _batch_pod(rng, name, node_name, fresh_class=None):
     from kubernetes_tpu.api.types import AFFINITY_ANNOTATION
 
-    weights = (8, 4, 3, 2, 2, 1, 1)
-    labels, reqs, ports, affinity = rng.choices(_BATCH_TEMPLATES, weights)[0]
+    labels, reqs, ports, affinity = rng.choices(
+        _BATCH_TEMPLATES, _BATCH_WEIGHTS)[0]
     labels = dict(labels)
     if fresh_class is not None:  # a spread class nobody has seen yet
         labels["gen"] = fresh_class
@@ -439,7 +496,10 @@ def _batch_events(rng, steps, nodes, pods, seq):
     """`steps` raw cache events: what SchedulerCache would send and what
     it never would (a re-add with no remove between, a remove of a pod
     nobody holds), so the encoder's defensive branches run too. `nodes`
-    and `pods` (name -> object) follow what the stream leaves live."""
+    and `pods` (name -> object) follow what the stream leaves live.
+    A node set again is a relabel (it may move the node's zone under its
+    pods), a node removed leaves its pods lingering, an `unsynced-*`
+    node is never known: each with term owners on it, some of the time."""
     events = []
     for _ in range(steps):
         op = rng.random()
@@ -474,10 +534,11 @@ def _batch_events(rng, steps, nodes, pods, seq):
                 events.append(
                     ("pod_remove", _batch_pod(rng, "never-held", "node-000")))
                 continue
-            # pods that hold the affinity gate shut go first, so that most
-            # rounds end with a snapshot to compare
-            gated = [n for n, p in pods.items() if p.metadata.annotations]
-            name = rng.choice(gated or list(pods))
+            # the one pod that does not parse goes before the others:
+            # it poisons every wave it sees
+            odd = [n for n, p in pods.items()
+                   if p.metadata.labels.get("app") == "odd"]
+            name = rng.choice(odd or list(pods))
             events.append(("pod_remove", pods.pop(name)))
     return events
 
@@ -499,17 +560,138 @@ def _assert_same_view(a, b, context):
             assert x == y, where
 
 
+def _decoded_terms(fields, vocab, node_col):
+    """The inter-pod tables by canonical key, whatever the numbering:
+    {("count", spec, combo, node): n} for `term_count`,
+    {(table, spec, topology key, combo, node): n} for the four owner
+    tables, {("total", spec): n}; zeros left out. `fields` are the
+    snapshot's `ip_*` arrays without the prefix, `vocab` the TermVocab
+    they are numbered in, `node_col` the column of each node known."""
+    out = {}
+    topo_dom = fields["topo_dom"]
+    for (s, q), u in vocab.units.ids.items():
+        for node, col in node_col.items():
+            d = topo_dom[q, col]
+            if d >= 0 and fields["term_count"][u, d]:
+                out["count", vocab.specs.items[s], vocab.topos.items[q],
+                    node] = int(fields["term_count"][u, d])
+    for table in ("own_anti", "rev_hard", "rev_pref", "rev_anti"):
+        for (s, key), lt in vocab.lts.ids.items():
+            for e, (u, _sign) in enumerate(vocab.lt_expansion[lt]):
+                q = vocab.units.items[u][1]
+                for node, col in node_col.items():
+                    d = topo_dom[q, col]
+                    if d >= 0 and fields[table][lt, e, d]:
+                        out[table, vocab.specs.items[s], key,
+                            vocab.topos.items[q], node] = int(
+                                fields[table][lt, e, d])
+    for spec, s in vocab.specs.ids.items():
+        if fields["spec_total"][s]:
+            out["total", spec] = int(fields["spec_total"][s])
+    return out
+
+
+def _assert_tables_equal_the_compilers(inc, snap, batch, nodes, pods,
+                                       pending, ctx):
+    """The kept inter-pod tables of `snap` against
+    InterPodCompiler.compile over the cluster the events left (`nodes`,
+    `pods`: what is live) and the same pending pods; and what the bound
+    pods decide of the pending ones (`sym_reject`, `poison`)."""
+    from kubernetes_tpu.snapshot.interpod import InterPodCompiler
+
+    state = ClusterState.build(list(nodes.values()), list(pods.values()))
+    names = [n for n, info in state.node_infos.items()
+             if info.node is not None]
+    compiler = InterPodCompiler(state, pending, names)
+    prog = compiler.compile()
+    want = _decoded_terms(
+        {f: getattr(prog, f) for f in (
+            "topo_dom", "term_count", "own_anti", "rev_hard", "rev_pref",
+            "rev_anti", "spec_total")},
+        compiler, {n: i for i, n in enumerate(names)})
+    got = _decoded_terms(
+        {f[3:]: getattr(snap, f) for f in (
+            "ip_topo_dom", "ip_term_count", "ip_own_anti", "ip_rev_hard",
+            "ip_rev_pref", "ip_rev_anti", "ip_spec_total")},
+        inc.vocabs.terms,
+        {n: inc.slot_of[n] for n in names})
+    # the kept vocabulary only grows: it may hold specs and terms of
+    # pods long gone, which the compiler never met. What it counts for
+    # those nothing reads; an owner table holds nothing there
+    known = set(compiler.specs.items)
+    units = {(compiler.specs.items[s], compiler.topos.items[q])
+             for s, q in compiler.units.items}
+    for key in list(got):
+        if key[1] not in known or (
+                key[0] == "count" and key[1:3] not in units):
+            assert key[0] in ("count", "total"), f"{ctx}: {key}"
+            del got[key]
+    assert got == want, ctx
+    # a slot without a node has no domain, whatever its pods
+    for name, slot in inc.slot_of.items():
+        if name not in names and len(snap.ip_topo_dom):
+            assert (snap.ip_topo_dom[:, slot] == -1).all(), ctx
+    assert np.array_equal(batch.ip_sym_reject, prog.sym_reject), ctx
+    assert batch.ip_poison.tolist() == [prog.poison] * len(pending), ctx
+    # the pending side, all twelve of a pod's own fields, held to the
+    # compiler's own derivation (straight from get_affinity: nothing of
+    # `pod_terms` / `TermVocab.pod_rows`, which made the batch's) term
+    # for term by canonical key, the two numberings apart
+    terms = inc.vocabs.terms
+
+    def named(vocab, lt):
+        return (vocab.specs.items[vocab.lts.items[lt][0]],
+                vocab.lts.items[lt][1])
+
+    for i in range(len(pending)):
+        for mine, theirs, beside in (("ip_ha_lt", "ha_lt", "ha_self"),
+                                     ("ip_hq_lt", "hq_lt", None),
+                                     ("ip_fwd_lt", "fwd_lt", "fwd_w")):
+            a = [(named(terms, lt),
+                  beside and getattr(batch, "ip_" + beside)[i, j].item())
+                 for j, lt in enumerate(getattr(batch, mine)[i]) if lt >= 0]
+            b = [(named(compiler, lt),
+                  beside and getattr(prog, beside)[i, j].item())
+                 for j, lt in enumerate(getattr(prog, theirs)[i]) if lt >= 0]
+            assert a == b, f"{ctx}: {mine} of pod {i}"
+        for column in ("own_hard", "own_pref", "own_anti_hard",
+                       "own_anti_pref"):
+            a = {named(terms, lt): int(w) for lt, w in
+                 enumerate(getattr(batch, "ip_" + column)[i]) if w}
+            b = {named(compiler, lt): int(w) for lt, w in
+                 enumerate(getattr(prog, column)[i]) if w}
+            assert a == b, f"{ctx}: {column} of pod {i}"
+        for flag in ("has_affinity", "has_anti"):
+            assert getattr(batch, "ip_" + flag)[i] == getattr(prog, flag)[i], \
+                f"{ctx}: {flag} of pod {i}"
+        assert {terms.specs.items[s]
+                for s in np.flatnonzero(batch.ip_match_spec[i])} & known == {
+            compiler.specs.items[s]
+            for s in np.flatnonzero(prog.match_spec[i])}, ctx
+    return prog
+
+
+def _term_pending(rng, rnd):
+    """A wave's pending pods: a plain one and some of the templates."""
+    pending = [rand_pending(rng, rnd)]
+    for j in range(rng.randint(0, 3)):
+        pending.append(_batch_pod(rng, f"pend-{rnd}-{j}", ""))
+    return pending
+
+
 @pytest.mark.parametrize("seed", range(12))
 def test_batched_deltas_equal_one_at_a_time(seed):
     """One apply_pending over a stream of cache events leaves exactly
     what applying the same events one by one leaves: every snapshot
     field, dtype for dtype, the batch, `keep`, the vocabularies' ids in
-    their order of first appearance, and the encoder's own books."""
+    their order of first appearance, and the encoder's own books, the
+    kept inter-pod tables among them. And after every batch those
+    tables are the from-scratch compiler's, by canonical key."""
     rng = random.Random(9100 + seed)
     batched, single = IncrementalEncoder(initial_slots=4), \
         IncrementalEncoder(initial_slots=4)
     nodes, pods, seq = {}, {}, [0]
-    views = 0
+    poisoned = 0
     for rnd in range(8):
         events = _batch_events(rng, rng.choice([5, 40, 120]), nodes, pods,
                                seq)
@@ -517,27 +699,46 @@ def test_batched_deltas_equal_one_at_a_time(seed):
             batched.on_cache_event(kind, obj)
             single.on_cache_event(kind, obj)
             single.apply_pending()
-        pending = [rand_pending(rng, rnd)]
+        pending = _term_pending(rng, rnd)
         snap_a, batch_a, keep_a = batched.wave_view(pending)
         snap_b, batch_b, keep_b = single.wave_view(pending)
         ctx = f"seed {seed} round {rnd}"
+        # no gate holds a term-owning wave back: every round has a view
+        assert snap_a is not None and batched.fallback is None, ctx
         _assert_same_view(snap_a, snap_b, ctx)
         _assert_same_view(batch_a, batch_b, ctx)
         assert keep_a == keep_b, ctx
-        views += snap_a is not None
+        prog = _assert_tables_equal_the_compilers(
+            batched, snap_a, batch_a, nodes, pods, pending, ctx)
+        poisoned += prog.poison
         for vocab in ("classes", "ports", "kv", "keys", "taints", "zones"):
             assert (list(getattr(batched.vocabs, vocab).ids.items())
                     == list(getattr(single.vocabs, vocab).ids.items())), \
                 f"{ctx}: vocabulary {vocab}"
-        # the books a snapshot does not show (or shows only when no
-        # affinity pod holds the gate shut)
+        ta, tb = batched.vocabs.terms, single.vocabs.terms
+        for vocab in ("specs", "topos", "units", "lts"):
+            assert getattr(ta, vocab).items == getattr(tb, vocab).items, \
+                f"{ctx}: vocabulary {vocab}"
+        assert [d.ids for d in ta._doms] == [d.ids for d in tb._doms], ctx
+        # the books a snapshot does not show
         for f in ("req_mcpu", "req_mem", "req_gpu", "nz_mcpu", "nz_mem",
                   "pod_count", "_pod_count_slot", "class_count",
                   "port_mask", "_node_gone", "_schedulable"):
             x, y = getattr(batched, f), getattr(single, f)
             assert x.dtype == y.dtype and np.array_equal(x, y), f"{ctx}: {f}"
+        # (the tables themselves are the snapshot's; these are kept in
+        # arrays with room to grow, cut here to what is in use)
+        C, S = ta._matched
+        assert (C, S) == tb._matched == (len(ta._classes), len(ta.specs)), ctx
+        assert np.array_equal(ta.match[:C, :S], tb.match[:C, :S]), ctx
+        assert np.array_equal(ta.unknown_anti[:S], tb.unknown_anti[:S]), ctx
+        assert (ta.unparsed, ta._filled, ta.stale) == (
+            tb.unparsed, tb._filled, tb.stale), ctx
+        # one at a time rebuilds at every event that leaves the tables
+        # stale; a batch once, however many it held
+        assert set(ta.rebuilds) <= set(tb.rebuilds), ctx
         for f in ("slot_of", "_free", "node_names", "_port_counts",
-                  "_affinity_pods", "_contribs", "_order_dirty"):
+                  "_contribs", "_order_dirty"):
             assert getattr(batched, f) == getattr(single, f), f"{ctx}: {f}"
         # and the sums are the held pods', whatever the order was
         want = np.zeros_like(batched.req_mcpu)
@@ -545,7 +746,299 @@ def test_batched_deltas_equal_one_at_a_time(seed):
             want[slot] += c.cpu
         assert np.array_equal(batched.req_mcpu, want), ctx
         assert set(batched._contribs) == {("default", n) for n in pods}, ctx
-    assert views >= 2, "the affinity gate hid every snapshot of this seed"
+    assert batched.vocabs.terms.rebuilds, "no round left the tables stale"
+
+
+def _plain_node(name, zone=None):
+    labels = {HOSTNAME: name}
+    if zone:
+        labels[ZONE] = zone
+    return Node(
+        metadata=ObjectMeta(name=name, labels=labels),
+        status=NodeStatus(
+            allocatable={"cpu": "4", "memory": "8Gi", "pods": "110"},
+            conditions=[NodeCondition("Ready", "True")]))
+
+
+def _owner(name, node_name, annotation, labels=None):
+    from kubernetes_tpu.api.types import AFFINITY_ANNOTATION
+
+    return Pod(
+        metadata=ObjectMeta(name=name, labels=labels or {"app": "web"},
+                            annotations={AFFINITY_ANNOTATION: annotation}),
+        spec=PodSpec(node_name=node_name, containers=[
+            Container(requests={"cpu": "100m"})]))
+
+
+def _renamed(name, hostname, zone):
+    node = _plain_node(name, zone=zone)
+    node.metadata.labels[HOSTNAME] = hostname
+    return node
+
+
+_ANTI_WEB_ZONE = _terms_json(
+    "podAntiAffinity", [_term({"app": "web"}, ZONE)],
+    [(4, _term({"app": "web"}, ""))])
+
+UNCOVERED_CASES = {
+    # name: (the event after the first wave, the rebuild it is counted
+    #        under if any, whether the wave's pods are rejected
+    #        everywhere, whether it poisons the wave)
+    "a-relabel-moves-a-domain": (
+        lambda nodes, pods: ("node_set", _plain_node("n-1", zone="a")),
+        "relabel", False, False),
+    "a-relabel-that-moves-none": (
+        lambda nodes, pods: ("node_set", _plain_node("n-1", zone="b")),
+        None, False, False),
+    # the node's old hostname domain had this node alone: the new one
+    # takes the id it gave back, and nothing moved (`_Domains`)
+    "a-relabel-to-a-hostname-nobody-has": (
+        lambda nodes, pods: ("node_set", _renamed("n-1", "fresh", "b")),
+        None, False, False),
+    "a-node-removed-under-its-owners": (
+        lambda nodes, pods: ("node_remove", nodes["n-1"]),
+        "node_removed", True, False),
+    "an-owner-on-an-unknown-node": (
+        lambda nodes, pods: ("pod_add", _owner(
+            "lost", "never-seen", _ANTI_WEB_ZONE)),
+        None, True, False),
+    "an-unknown-node-arrives-under-its-owner": (
+        lambda nodes, pods: [
+            ("pod_add", _owner("lost", "late", _ANTI_WEB_ZONE)),
+            ("node_set", _plain_node("late", zone="b"))],
+        "relabel", False, False),
+    "an-annotation-that-does-not-parse": (
+        lambda nodes, pods: ("pod_add", _owner("odd", "n-2", "{not json")),
+        None, True, True),
+    "a-new-topology-key": (
+        lambda nodes, pods: ("pod_add", _owner("rack", "n-2", _terms_json(
+            "podAffinity", [_term({"app": "web"}, "rack")]))),
+        None, False, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(UNCOVERED_CASES))
+def test_what_the_deltas_do_not_cover_is_exact_or_a_counted_rebuild(case):
+    """Each event the pod-by-pod deltas cannot follow leaves the kept
+    tables exactly the compiler's all the same: kept as a count (owners
+    without a node, pods that do not parse), or rebuilt whole under a
+    reason of its own; and no wave goes to the from-scratch encoder."""
+    event, reason, rejected, poison = UNCOVERED_CASES[case]
+    inc = IncrementalEncoder(initial_slots=4)
+    nodes = {f"n-{i}": _plain_node(f"n-{i}", zone="ab"[i % 2])
+             for i in range(4)}
+    pods = {}
+    for node in nodes.values():
+        inc.on_cache_event("node_set", node)
+    for i in range(6):
+        pod = _owner(f"own-{i}", f"n-{i % 3}", _ANTI_WEB_ZONE)
+        pods[pod.metadata.name] = pod
+        inc.on_cache_event("pod_add", pod)
+    pending = [_owner("pend", "", _ANTI_WEB_ZONE)]
+    snap, batch, _keep = inc.wave_view(pending)
+    assert inc.fallback is None and inc.take_rebuilds() == {}
+    _assert_tables_equal_the_compilers(
+        inc, snap, batch, nodes, pods, pending, f"{case}: before")
+    events = event(nodes, pods)
+    for kind, obj in events if isinstance(events, list) else [events]:
+        inc.on_cache_event(kind, obj)
+        if kind == "node_set":
+            nodes[obj.metadata.name] = obj
+        elif kind == "node_remove":
+            del nodes[obj.metadata.name]
+        else:
+            pods[obj.metadata.name] = obj
+    snap, batch, keep = inc.wave_view(pending)
+    assert inc.fallback is None
+    assert inc.take_rebuilds() == ({reason: 1} if reason else {})
+    prog = _assert_tables_equal_the_compilers(
+        inc, snap, batch, nodes, pods, pending, f"{case}: after")
+    assert prog.sym_reject.tolist() == [rejected]
+    assert prog.poison is poison
+    # only the events that moved nothing leave the counting tables
+    # kept; moved, they are one unit: none is kept, and a driver that
+    # ships what differs from its last copy ships all six (`reship`)
+    kept = case in ("a-relabel-that-moves-none",
+                    "a-relabel-to-a-hostname-nobody-has")
+    assert (keep >= inc.TERM_CARRY_FIELDS) == kept
+    assert not keep & inc.TERM_CARRY_FIELDS or keep >= inc.TERM_CARRY_FIELDS
+    assert inc.reship == (frozenset() if kept else inc.TERM_CARRY_FIELDS)
+    # a quiet wave after it keeps all twelve
+    _snap, _batch, keep = inc.wave_view(pending)
+    assert keep >= inc.TERM_STATIC_FIELDS | inc.TERM_CARRY_FIELDS
+    assert inc.reship == frozenset()
+    assert inc.take_rebuilds() == {}
+
+
+@pytest.mark.parametrize("lingering", [False, True],
+                         ids=["empty-nodes-go", "a-node-goes-under-a-pod"])
+def test_domains_given_back_are_taken_again(lingering):
+    """Under node churn with a hostname term (a domain a node) the
+    domain axis stays as wide as the most nodes ever live at once: a
+    node that goes gives its domain back and the next one takes it, so
+    the tables keep their shapes; what they count stays the
+    compiler's."""
+    anti = _terms_json("podAntiAffinity", [_term({"app": "web"}, HOSTNAME)])
+    inc = IncrementalEncoder(initial_slots=8)
+    nodes = {f"n-{i}": _plain_node(f"n-{i}", zone="ab"[i % 2])
+             for i in range(6)}
+    pods = {}
+    for node in nodes.values():
+        inc.on_cache_event("node_set", node)
+    for i in range(3):
+        pods[f"own-{i}"] = _owner(f"own-{i}", f"n-{i}", anti)
+        inc.on_cache_event("pod_add", pods[f"own-{i}"])
+    pending = [_owner("pend", "", anti)]
+    snap, batch, _keep = inc.wave_view(pending)
+    assert snap.ip_term_count.shape == (1, 6)
+    rebuilds = {}
+    for rnd in range(12):
+        # the newest pod's node stays; an empty one goes (or, once, the
+        # one under the oldest pod, which lingers until the pod goes)
+        gone = next(iter(pods.values())).spec.node_name \
+            if lingering and rnd == 3 else next(
+            n for n in nodes
+            if not any(p.spec.node_name == n for p in pods.values()))
+        inc.on_cache_event("node_remove", nodes.pop(gone))
+        name = f"n-{6 + rnd}"
+        nodes[name] = _plain_node(name, zone="ab"[rnd % 2])
+        inc.on_cache_event("node_set", nodes[name])
+        if rnd % 2:
+            old = pods.pop(next(iter(pods)))
+            inc.on_cache_event("pod_remove", old)
+            pods[f"own-{name}"] = _owner(f"own-{name}", name, anti)
+            inc.on_cache_event("pod_add", pods[f"own-{name}"])
+        snap, batch, _keep = inc.wave_view(pending)
+        ctx = f"round {rnd}"
+        _assert_tables_equal_the_compilers(
+            inc, snap, batch, nodes, pods, pending, ctx)
+        assert snap.ip_term_count.shape == (1, 6), ctx
+        assert snap.ip_own_anti.shape == (1, 1, 6), ctx
+        assert sorted(snap.ip_topo_dom[0][snap.ip_topo_dom[0] >= 0]) \
+            == list(range(6)), ctx
+        for reason, n in inc.take_rebuilds().items():
+            rebuilds[reason] = rebuilds.get(reason, 0) + n
+    assert rebuilds == ({"node_removed": 1} if lingering else {})
+
+
+def _oracle_view(state):
+    """A copy of a restricted state for the oracle to assume pods into,
+    its `full` kept (ClusterState.clone drops it): the pods that linger
+    on a removed node stay visible, as they are to the encoders."""
+    full = state.full.clone()
+    sub = ClusterState(services=list(state.services),
+                       controllers=list(state.controllers))
+    sub.node_infos = {n: full.node_infos[n] for n in state.node_infos}
+    sub.full = full
+    return sub
+
+
+def _term_assigned(rng, i, node_name):
+    pod = _batch_pod(rng, f"assigned-{i}", node_name)
+    pod.metadata.deletion_timestamp = None
+    return pod
+
+
+def _served_term_waves(seed, mesh=None):
+    """Rounds of cache events (term owners bound and deleted, nodes set
+    again and removed) and waves whose pods own terms themselves,
+    through the cache-wired TPUScheduleAlgorithm; every pick held to
+    the serial generic scheduler's. -> the algorithm."""
+    import copy
+
+    rng = random.Random(8800 + seed)
+    cache = SchedulerCache(clock=FakeClock(0.0))
+    algo = TPUScheduleAlgorithm(
+        min_run=1, mesh=mesh, cache=cache, service_lister=_Lister(),
+        controller_lister=_Lister(), replica_set_lister=_Lister())
+    oracle = GenericScheduler(
+        predicates=ORACLE_PREDICATES, priorities=ORACLE_PRIORITIES)
+    live_nodes, live_pods, seq = {}, {}, [0]
+    for i in range(12):
+        name = f"node-{i:03d}"
+        live_nodes[name] = _plain_node(name, zone="abc"[i % 3])
+        cache.add_node(live_nodes[name])
+    placed = 0
+    for rnd in range(5):
+        for _ in range(25):
+            op = rng.random()
+            if op < 0.1:
+                name = rng.choice(list(live_nodes))
+                node = _plain_node(name, zone=rng.choice(["a", "b", None]))
+                cache.update_node(live_nodes[name], node)
+                live_nodes[name] = node
+            elif op < 0.14 and len(live_nodes) > 6:
+                cache.remove_node(live_nodes.pop(rng.choice(list(live_nodes))))
+            elif op < 0.7:
+                seq[0] += 1
+                pod = _term_assigned(rng, seq[0],
+                                     rng.choice(list(live_nodes)))
+                if pod.metadata.labels.get("app") == "odd":
+                    continue  # a poisoned cycle places nothing: no test
+                cache.add_pod(pod)
+                live_pods[pod.metadata.name] = pod
+            elif live_pods:
+                cache.remove_pod(live_pods.pop(rng.choice(list(live_pods))))
+        pending = []
+        for j in range(rng.randint(2, 8)):
+            p = _batch_pod(rng, f"pend-{rnd}-{j}", "")
+            p.metadata.deletion_timestamp = None
+            if p.metadata.labels.get("app") == "odd":
+                continue
+            pending += [p] + [copy.deepcopy(p) for _ in
+                              range(rng.randint(0, 3))]
+        for k, p in enumerate(pending):
+            p.metadata.name = f"pend-{rnd}-{k}"
+        state = restricted_state(cache)
+        want = oracle.schedule_backlog(pending, _oracle_view(state))
+        got = algo.schedule_backlog(pending, state)
+        assert got == want, f"seed {seed} round {rnd}"
+        assert algo._inc.fallback is None
+        placed += sum(h is not None for h in want)
+        for p, host in zip(pending, want):
+            if host is None:
+                continue
+            bound = copy.deepcopy(p)
+            bound.metadata.name = f"{p.metadata.name}-bound"
+            bound.spec.node_name = host
+            cache.add_pod(bound)
+            live_pods[bound.metadata.name] = bound
+    assert placed >= 10
+    return algo
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_term_owning_waves_decide_as_the_oracle_from_the_kept_tables(seed):
+    """Every wave's snapshot is the incremental encoder's, whatever
+    terms the bound and the pending pods own, and every pick the serial
+    generic scheduler's."""
+    stats = _served_term_waves(seed)._wave.stats
+    assert stats["waves_by_encoder"] == {"incremental": 5, "full": 0}
+    assert stats["encoder_fallbacks"] == {}
+
+
+@pytest.mark.parametrize("per_shard", [None, 1],
+                         ids=["one-step", "three-steps"])
+@pytest.mark.parametrize("seed", range(2))
+def test_term_owning_waves_decide_as_the_oracle_on_the_mesh(
+        seed, per_shard, monkeypatch):
+    """The same through the mesh driver on four devices, whose
+    resident state takes the kept tables by content: on a node axis of
+    one step, and on one that grew twice (12 nodes at one slot a shard
+    a step: 4, 8, 12 slots), where the kept `topo_dom` has doubled past
+    the axis and the snapshot's is cut to it."""
+    import jax
+    from jax.sharding import Mesh
+
+    from kubernetes_tpu.scheduler import tpu_algorithm
+
+    if per_shard:
+        monkeypatch.setattr(tpu_algorithm, "MESH_SLOTS_PER_SHARD", per_shard)
+    algo = _served_term_waves(
+        seed, mesh=Mesh(np.array(jax.devices()[:4]), ("nodes",)))
+    assert algo._inc._cap == (12 if per_shard else 1024)
+    assert algo._inc.vocabs.terms.topo_dom.shape[1] == (
+        16 if per_shard else 1024)
 
 
 def test_kept_rows_across_waves_match_oracle_at_the_zoned_shape():

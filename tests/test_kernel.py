@@ -97,6 +97,40 @@ def test_narrow_matvec_matches_wide():
     assert got.dtype == np.int32 and np.array_equal(got, want)
 
 
+def test_to_dev_many_ships_a_unit_whole_whatever_differs():
+    """Fields their producer says moved as one (`reship`) go with the
+    batch each whole, the unchanged and the barely changed with the
+    rest: which tables a wave ships does not hang on which of them
+    happen to differ. Left to the comparison, one is reused and one
+    becomes a row scatter."""
+    ws = WaveScheduler()
+    snap = types.SimpleNamespace(
+        a=np.arange(64, dtype=np.int64).reshape(16, 4),
+        b=np.arange(16, dtype=np.int64), c=np.zeros((16, 2), np.int64))
+    fields = ["a", "b", "c"]
+    ws._to_dev_many(snap, fields, keep=frozenset())
+    base = dict(ws.stats)
+    snap.a = snap.a + 1  # every row
+    snap.b = snap.b.copy()
+    snap.b[3] = 99  # one row of sixteen
+    out = ws._to_dev_many(snap, fields, keep=frozenset(),
+                          reship=frozenset(fields))
+    assert ws.stats["table_ships"] == base["table_ships"] + 3
+    assert ws.stats["table_scatters"] == base["table_scatters"]
+    assert ws.stats["table_reuses"] == base["table_reuses"]
+    for f in fields:
+        assert np.array_equal(np.asarray(out[f]), getattr(snap, f))
+    snap.a = snap.a + 1
+    snap.b = snap.b.copy()
+    snap.b[4] = 98
+    out = ws._to_dev_many(snap, fields, keep=frozenset())
+    assert ws.stats["table_ships"] == base["table_ships"] + 4
+    assert ws.stats["table_scatters"] == base["table_scatters"] + 1
+    assert ws.stats["table_reuses"] == base["table_reuses"] + 1
+    for f in fields:
+        assert np.array_equal(np.asarray(out[f]), getattr(snap, f))
+
+
 # -- narrow placement: device dtype + boundary rebuild -------------------------
 
 

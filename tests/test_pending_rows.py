@@ -71,7 +71,7 @@ def _fresh_view(inc, pending, services, controllers, replica_sets):
                           vocabs=inc.vocabs, visit_state=False,
                           node_id=dict(inc.slot_of))
     batch = enc.encode_pods()
-    inc._widths_sync()
+    inc._sync_terms()  # the wave's own terms, where they are first seen
     inc._dirty_node_side = inc._dirty_pod_side = False
     return inc._snapshot_arrays(enc), batch
 
@@ -81,10 +81,11 @@ class _Cluster:
     events: `kept` assembles its waves from stored rows, `plain` encodes
     every pending pod of every wave."""
 
-    def __init__(self, rng):
+    def __init__(self, rng, interpod_p=0.0):
         self.rng = rng
         state, templates = random_scenario(
-            rng, n_nodes=10, n_existing=14, n_pending=24)
+            rng, n_nodes=10, n_existing=14, n_pending=24,
+            interpod_p=interpod_p)
         for i, t in enumerate(templates):
             for c in t.spec.containers:
                 c.image = rng.choice(IMAGES)
@@ -273,6 +274,116 @@ def test_kept_rows_equal_a_fresh_encode(event):
         # event's, once for nothing else
         assert rows.resets == (1 if event == "node-taint" else 0), event
         assert c.plain.rows.hits == c.plain.rows.misses == 0
+
+
+# -- the inter-pod columns of a kept row ------------------------------------------
+
+def _terms(side, required=(), preferred=()):
+    """An affinity annotation: `required` terms as (labels, topology
+    key[, namespaces]), `preferred` ones with their weight first."""
+    from tests.test_incremental import _term, _terms_json
+
+    return _terms_json(side, [_term(*t) for t in required],
+                       [(w, _term(*t)) for w, *t in preferred])
+
+
+def _with_terms(pod, annotation):
+    from kubernetes_tpu.api.types import AFFINITY_ANNOTATION
+
+    pod.metadata.annotations[AFFINITY_ANNOTATION] = annotation
+    return pod
+
+
+HOSTNAME = "kubernetes.io/hostname"
+
+
+def _spec_seen_later(c):
+    # a selector no term had: every stored row meets the spec once
+    c.templates.append(_with_terms(c.template("picky"), _terms(
+        "podAffinity", [({"app": "web", "track": "canary"}, HOSTNAME)])))
+
+
+def _term_seen_later(c):
+    # specs the scenario's terms may hold already, under keys and in
+    # tables none of them used: logical terms first seen, rows widened
+    c.templates.append(_with_terms(c.template("wide"), _terms(
+        "podAntiAffinity", [({"app": "web"}, "rack"), ({"app": "db"}, "")],
+        [(7, {"app": "web"}, "rack"), (0, {"app": "db"}, HOSTNAME)])))
+
+
+def _longer_lists(c):
+    # more terms of each kind than any row of the store has
+    c.templates.append(_with_terms(c.template("many"), _terms(
+        "podAffinity",
+        [({"app": v}, HOSTNAME) for v in ("web", "db", "cache", "x")],
+        [(w, {"app": "web"}, k) for w, k in
+         ((1, HOSTNAME), (2, "rack"), (3, ""), (4, "zone-b"), (5, "c"))])))
+
+
+def _bound_owner(c):
+    # bound pods that own terms: the counting tables move, and a spec
+    # comes from the cluster's side that the rows have to be matched to
+    c.cache.add_pod(_with_terms(
+        c.bound("owner-0", {"app": "guard"}), _terms(
+            "podAntiAffinity", [({"app": "web"}, HOSTNAME, [])],
+            [(9, {"app": "db"}, "")])))
+    c.cache.add_pod(_with_terms(
+        c.bound("owner-1", {"app": "guard"}, node="node-004"), _terms(
+            "podAffinity", [({"app": "guard"}, "")])))
+
+
+def _owner_without_a_node(c):
+    # the symmetric check rejects every node for the pods its spec
+    # matches: a wave's `ip_sym_reject`, no row's
+    c.cache.add_pod(_with_terms(
+        c.bound("lost", {"app": "guard"}, node="node-never"), _terms(
+            "podAntiAffinity", [({"app": "web"}, HOSTNAME)])))
+    c.templates.append(_with_terms(c.template("wary"), _terms(
+        "podAntiAffinity", [({"app": "none"}, HOSTNAME)])))
+
+
+def _bound_pod_does_not_parse(c):
+    c.cache.add_pod(_with_terms(c.bound("odd", {"app": "odd"}), "{"))
+    c.templates.append(_with_terms(c.template("strange"), "[not json"))
+
+
+TERM_EVENTS = {
+    "spec-seen-later": _spec_seen_later,
+    "term-seen-later": _term_seen_later,
+    "longer-lists": _longer_lists,
+    "bound-owner": _bound_owner,
+    "owner-without-a-node": _owner_without_a_node,
+    "bound-pod-does-not-parse": _bound_pod_does_not_parse,
+}
+
+
+@pytest.mark.parametrize("event", sorted(TERM_EVENTS))
+def test_kept_term_rows_equal_a_fresh_encode(event):
+    """Templates and bound pods that own inter-pod terms (the fuzz's,
+    as `spec.affinity`; the events', as annotations): every `ip_*`
+    field of the batch assembled from kept rows, and of the snapshot
+    made from kept tables, is what a fresh encode over the same
+    vocabularies gives, before the event and after."""
+    for seed in range(3):
+        c = _Cluster(random.Random(7300 + seed), interpod_p=0.4)
+        for w in range(2):
+            c.wave(f"{event} seed {seed} wave {w}")
+        terms = c.kept.vocabs.terms
+        before = len(terms.specs), len(terms.lts)
+        assert min(before) > 0, "the fuzz made no term"
+        TERM_EVENTS[event](c)
+        for w in range(2):
+            batch = c.wave(f"{event} seed {seed}, wave {w} after")
+        if event.endswith("seen-later") or event == "bound-owner":
+            assert (len(terms.specs), len(terms.lts)) > before
+        if event == "owner-without-a-node":
+            assert batch.ip_sym_reject.any() \
+                and not batch.ip_sym_reject.all()
+        assert batch.ip_poison.all() == (
+            event == "bound-pod-does-not-parse")
+        rows = c.kept.rows
+        assert rows.hits > 0 and rows.misses > 0 and rows.resets == 0
+        assert c.kept.fallback is None
 
 
 def test_a_full_store_drops_the_rows_the_wave_does_not_use(monkeypatch):

@@ -1,8 +1,8 @@
 """A pod's scheduling contribution (oracle/state.py pod_contribution):
 what NodeInfo and the incremental snapshot take from an assigned pod,
 derived once per template and shared. The plain functions it is built
-from (_calculate_resource, pod_nonzero_request, has_pod_affinity) stay
-the reference here."""
+from (_calculate_resource, pod_nonzero_request, has_pod_affinity,
+get_affinity) stay the reference here."""
 
 import copy
 import random
@@ -17,6 +17,7 @@ from kubernetes_tpu.api.types import (
     ObjectMeta,
     Pod,
     PodSpec,
+    get_affinity,
     has_pod_affinity,
     pod_nonzero_request,
 )
@@ -36,6 +37,38 @@ def _lookups():
     return c.get(result="hit"), c.get(result="miss")
 
 
+def _expected_terms(pod):
+    """PodTerms from the parsed affinity, term by term: the namespaces
+    a term resolves to for its owner, its selector's content, its key."""
+    if not has_pod_affinity(pod):
+        return None
+    try:
+        aff = get_affinity(pod)
+    except Exception:
+        return (False, (), (), (), (), False, False)
+
+    def term(t):
+        names = {pod.namespace} if t.namespaces is None else set(t.namespaces)
+        sel = t.label_selector
+        canon = None if sel is None else (
+            tuple(sorted(sel.match_labels.items())),
+            tuple((e.key, e.operator, tuple(e.values))
+                  for e in sel.match_expressions))
+        return (frozenset(names), canon, t.topology_key)
+
+    def side(s):
+        if s is None:
+            return (), ()
+        return (
+            tuple(term(t) for t in
+                  s.required_during_scheduling_ignored_during_execution),
+            tuple((term(w.pod_affinity_term), w.weight) for w in
+                  s.preferred_during_scheduling_ignored_during_execution))
+
+    return (True, *side(aff.pod_affinity), *side(aff.pod_anti_affinity),
+            aff.pod_affinity is not None, aff.pod_anti_affinity is not None)
+
+
 def _expected(pod):
     return (
         *_calculate_resource(pod),
@@ -44,7 +77,7 @@ def _expected(pod):
               if p.host_port != 0),
         (pod.namespace, frozenset(pod.metadata.labels.items()),
          pod.metadata.deletion_timestamp is not None),
-        has_pod_affinity(pod),
+        _expected_terms(pod),
     )
 
 
@@ -90,7 +123,7 @@ def test_spec_affinity_is_derived_not_memoised():
     pod.metadata.annotations.pop(AFFINITY_ANNOTATION, None)
     _, misses = _lookups()
     assert tuple(pod_contribution(pod)) == _expected(pod)
-    assert pod_contribution(pod).affinity
+    assert pod_contribution(pod).terms.parsed
     assert _lookups()[1] == misses + 2
 
 

@@ -10,7 +10,9 @@ the carried score again, and a wave of another run count inside one
 bucket builds no program. The `anti_*` counters say what the runs with
 a self-anti veto decided and how many nodes the terms of bound pods had
 taken from them, `waves_by_encoder` / `encoder_fallbacks` which encoder
-made a wave's snapshot and which scope gate sent it there, and a later
+made a wave's snapshot and which scope gate sent it there (an inter-pod
+term sends none: the tables are kept), `interpod_rebuilds` how often the
+kept inter-pod tables were rebuilt whole and why, and a later
 wave of an unchanged set of terms builds no program. And the scan path
 picks as
 the serial oracle does where
@@ -424,17 +426,25 @@ def _volume_pod(i):
 
 ENCODER_CASES = {
     # name: (pods bound before the wave, the wave, has a scheduler
-    #        cache, encoder, the scope gate counted)
+    #        cache, encoder, the scope gate counted, whether the bound
+    #        pods' node is relabelled under them before the wave)
+    # terms gate nothing: the inter-pod tables are kept
     "a-pending-term": ([], lambda: _anti_rows((0, 5), 16), True,
-                       "full", "affinity"),
+                       "incremental", None, False),
     "a-bound-pods-term": (lambda: _anti_rows((3,), 1, serial=900),
-                          lambda: _in_rows(2, 16), True, "full",
-                          "affinity"),
+                          lambda: _in_rows(2, 16), True, "incremental",
+                          None, False),
+    # what the kept tables' deltas do not cover rebuilds them whole,
+    # counted by reason, and the wave stays with the kept snapshot
+    "a-relabel-under-a-term-owner": (
+        lambda: _anti_rows((3,), 1, serial=900), lambda: _in_rows(2, 16),
+        True, "incremental", None, True),
     "a-volume": ([], lambda: [_volume_pod(i) for i in range(3)], True,
-                 "full", "volumes"),
-    "neither": ([], lambda: _in_rows(2, 16), True, "incremental", None),
+                 "full", "volumes", False),
+    "neither": ([], lambda: _in_rows(2, 16), True, "incremental", None,
+                False),
     # no cache to keep a snapshot from: from scratch, and no gate
-    "no-cache": ([], lambda: _in_rows(2, 16), False, "full", None),
+    "no-cache": ([], lambda: _in_rows(2, 16), False, "full", None, False),
 }
 
 
@@ -445,7 +455,7 @@ def test_waves_are_counted_by_encoder_and_fallbacks_by_reason(case):
     from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
     from kubernetes_tpu.utils.clock import FakeClock
 
-    bound, wave, cached, encoder, reason = ENCODER_CASES[case]
+    bound, wave, cached, encoder, reason, relabel = ENCODER_CASES[case]
     nodes = _nodes(30, "")
     bound = bound() if callable(bound) else bound
     for p in bound:
@@ -456,10 +466,19 @@ def test_waves_are_counted_by_encoder_and_fallbacks_by_reason(case):
             cache.add_node(n)
         for p in bound:
             cache.add_pod(p)
+    rebuilds = {"relabel": 1} if relabel else {}
     controllers = _anti_controllers() + _controllers(2)
     algo = TPUScheduleAlgorithm(
         cache=cache, controller_lister=SimpleNamespace(
             list=lambda: controllers))
+    if relabel:
+        # the owner's term counts by hostname: its node moves into its
+        # neighbour's domain (a hostname nobody has would take the
+        # domain the old one gave back, and move nothing)
+        moved = _nodes(30, "")[7]
+        moved.metadata.labels["kubernetes.io/hostname"] = "znode-00008"
+        cache.update_node(nodes[7], moved)
+        nodes[7] = moved
     state = ClusterState.build(nodes, bound, controllers=controllers)
     shown_before = profile.wave_totals()
     backlog = wave()
@@ -469,11 +488,14 @@ def test_waves_are_counted_by_encoder_and_fallbacks_by_reason(case):
         == {"incremental", "full"}
     assert stats["waves_by_encoder"][encoder] == 1 == stats["waves"]
     assert stats["encoder_fallbacks"] == ({reason: 1} if reason else {})
-    # a second wave counts again, under the same reason
+    assert stats["interpod_rebuilds"] == rebuilds
+    # a second wave counts again, under the same reason; nothing moved
+    # under the kept tables since, so they are not rebuilt again
     algo.schedule_backlog(backlog[:3], state)
     assert stats["waves_by_encoder"][encoder] == 2
     assert sum(stats["waves_by_encoder"].values()) == 2
     assert stats["encoder_fallbacks"] == ({reason: 2} if reason else {})
+    assert stats["interpod_rebuilds"] == rebuilds
     shown = profile.wave_totals()
     assert _delta(shown["waves_by_encoder"],
                   shown_before["waves_by_encoder"]).get(encoder) == 2
@@ -481,6 +503,9 @@ def test_waves_are_counted_by_encoder_and_fallbacks_by_reason(case):
                    shown_before["encoder_fallbacks"])
     assert {k: v for k, v in moved.items() if v} \
         == ({reason: 2} if reason else {})
+    rebuilt = _delta(shown["interpod_rebuilds"],
+                     shown_before["interpod_rebuilds"])
+    assert {k: v for k, v in rebuilt.items() if v} == rebuilds
 
 
 def test_a_later_wave_of_the_same_terms_builds_no_program():
