@@ -591,7 +591,7 @@ def tables_from_stk(config: SchedulerConfig, stk: np.ndarray,
     if self_anti_veto is not None and rows > 1:
         # hostname-topology hard anti-affinity against the run's own
         # labels: one committed copy excludes every further copy on
-        # that node (wave.run_eligible computed where the term's
+        # that node (wave.run_verdict computed where the term's
         # domain exists) — the same res_fit row shape as the
         # host-port self-conflict
         res_fit[1:, self_anti_veto] = False

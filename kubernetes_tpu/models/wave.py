@@ -292,20 +292,32 @@ def host_group_replay(config: SchedulerConfig, snap: ClusterSnapshot,
     return counts_mat, n_full, partial_done, L_host
 
 
-def run_eligible(config: SchedulerConfig, batch: PodBatch, i: int,
-                 snap: ClusterSnapshot, *, config_ok: bool = None):
-    """-> (eligible, self_anti_veto) for pod row i's run. Eligible means
-    its commits don't feed back into its own fit/score except through
-    the channels the tables model (resources, ports-self, spread
-    counts, and — via the returned veto — hostname-topology hard
-    anti-affinity against itself, the one-per-node pattern:
-    self_anti_veto is then bool[N] marking nodes where one committed
-    copy excludes every further copy).
+#: why `run_verdict` leaves a run to the serial scan, the keys of
+#: `stats["scan_reasons"]`: the policy (`config_eligible`), a required
+#: podAffinity term of the pod's own, a preferred term that selects the
+#: pod's own labels, a required anti-affinity term that selects them
+#: over a topology whose domains hold more than one node, a volume
+SCAN_REASONS = ("config", "hard_affinity", "self_preferred", "zone_anti",
+                "volumes")
+
+
+def run_verdict(config: SchedulerConfig, batch: PodBatch, i: int,
+                snap: ClusterSnapshot, *, config_ok: bool = None):
+    """-> (reason, self_anti_veto) for pod row i's run. `reason` is None
+    for an eligible run, else the one of SCAN_REASONS it is refused for
+    (`classify_runs` keeps it, and a wave's end counts the refused
+    runs' pods under it in `stats["scan_reasons"]`). Eligible means its
+    commits don't feed back into its own fit/score except through the
+    channels the tables model (resources, ports-self, spread counts,
+    and — via the returned veto — hostname-topology hard anti-affinity
+    against itself, the one-per-node pattern: self_anti_veto is then
+    bool[N] marking nodes where one committed copy excludes every
+    further copy).
     config_ok is a hoistable per-backlog invariant."""
     if config_ok is None:
         config_ok = config_eligible(config)
     if not config_ok:
-        return False, None
+        return "config", None
     b = batch
     # own inter-pod terms: the run stays eligible as long as none of
     # them feed back into the run's OWN fit/score in a way the tables
@@ -315,10 +327,15 @@ def run_eligible(config: SchedulerConfig, batch: PodBatch, i: int,
     # that DOES self-match is expressible when its topology is
     # hostname-like: each commit kills only its own node's fit
     # (generalizing the host-port self-conflict row of res_fit).
+    # The three refusals below are what podaffinity-2k's pods meet
+    # (PR 45: `hard_affinity` first, so that is the reason its runs
+    # are counted under; its preferred hostname term alone would read
+    # `self_preferred`), and `interpod_scan_share.fill` is their
+    # measure: it falls when the tables learn a term (ROADMAP M8 / D3).
     if b.ip_ha_lt.size and np.any(b.ip_ha_lt[i] >= 0):
         # own hard AFFINITY: the first-pod bootstrap + domain growth
         # feedback (predicates.go:819-843) is not table-expressible
-        return False, None
+        return "hard_affinity", None
     lt_spec = np.asarray(snap.ip_lt_spec) if snap.ip_lt_spec is not None \
         else np.zeros(0, np.int32)
     ms = b.ip_match_spec[i] if b.ip_match_spec.size else None
@@ -331,7 +348,7 @@ def run_eligible(config: SchedulerConfig, batch: PodBatch, i: int,
             if lt >= 0 and self_match(int(lt)):
                 # preferred term scoring its own copies: the slope in j
                 # isn't in the tables (yet)
-                return False, None
+                return "self_preferred", None
     veto = None
     if b.ip_hq_lt.size:
         for lt in b.ip_hq_lt[i]:
@@ -339,16 +356,16 @@ def run_eligible(config: SchedulerConfig, batch: PodBatch, i: int,
                 continue
             dom = _lt_pernode_dom(snap, int(lt))
             if dom is None:
-                return False, None  # zone-coupled self anti-affinity
+                return "zone_anti", None  # zone-coupled self anti-affinity
             v = dom >= 0  # nodes where the term can ever co-locate
             veto = v if veto is None else (veto | v)
     # volume commits conflict with the run's own copies
     if np.any(b.vp_vol_rw[i]) or np.any(b.vp_vol_ro[i]):
-        return False, None
+        return "volumes", None
     if np.any(b.vp_ebs[i]) or np.any(b.vp_gce[i]):
-        return False, None
+        return "volumes", None
     if b.vp_has_ebs[i] or b.vp_has_gce[i] or b.vp_ebs_bad[i] or b.vp_gce_bad[i]:
-        return False, None
+        return "volumes", None
     # (service-member runs stay eligible: the replay models the
     # ServiceAffinity first-pick pin and the per-pick ServiceAntiAffinity
     # renormalization from the probe's svc rows; the apply fold records
@@ -356,7 +373,7 @@ def run_eligible(config: SchedulerConfig, batch: PodBatch, i: int,
     # the probe carries the node->zone map and the replay recomputes the
     # 2/3 blend per pick — the coupling is linear in per-zone counts,
     # exactly table shape.)
-    return True, veto
+    return None, veto
 
 
 def _host_group_cap(num_nodes: int) -> int:
@@ -426,13 +443,28 @@ ZREPLAY_COUNTERS = ("zreplay_steps", "zreplay_slots", "zreplay_rescores",
                     "zreplay_picks")
 #: what `stats` counts of the runs that carry a self-anti veto (pods
 #: whose required hostname anti-affinity term selects their own labels;
-#: `run_eligible`), which `run_single` decides one probe a run: the
+#: `run_verdict`), which `run_single` decides one probe a run: the
 #: runs, the pods they placed, and summed over the runs the real nodes
 #: the run's FIRST probe found unfit, on the tables it shipped (where
 #: nothing but the terms of bound pods excludes a node, how much of the
 #: cluster they have taken from a run before it starts); counted at the
 #: wave's end, with `pods_by_path`
 ANTI_COUNTERS = ("anti_runs", "anti_picks", "anti_nodes_excluded")
+#: what `stats` counts of the runs whose pod owns a required podAffinity
+#: term (every run of a wave, whatever its length; `run_verdict` sends
+#: those of `min_run` pods and more to the scan as `hard_affinity`): the
+#: runs, and summed over them the real nodes the term keeps the pod off
+#: on the snapshot the wave began with (`affinity_nodes_excluded`:
+#: counted on the kept inter-pod tables on the host, `anti_nodes_excluded`'s
+#: twin); counted at the wave's end by `count_runs`, in both drivers
+AFFINITY_COUNTERS = ("affinity_runs", "affinity_nodes_excluded")
+#: what `stats` counts of the daemon's re-warm of the scan at inter-pod
+#: widths first seen (scheduler/tpu_algorithm.TPUScheduleAlgorithm._rewarm;
+#: the single-chip driver's alone): the times it held the loop, their
+#: seconds, the programs built in them, and the warm waves whose widths
+#: were not the live ones
+REWARM_COUNTERS = ("rewarms", "rewarm_seconds", "rewarm_programs",
+                   "rewarm_mismatches")
 #: which encoder made a wave's snapshot (counted where the scheduler
 #: chooses, scheduler/tpu_algorithm), and by which scope gate of the
 #: incremental one a wave went to the from-scratch encoder
@@ -441,9 +473,9 @@ ENCODERS = ("incremental", "full")
 
 
 def count_group(stats: dict, counted: dict) -> None:
-    """Some of `GROUP_COUNTERS`, `ZREPLAY_COUNTERS` or `ANTI_COUNTERS`
-    into a driver's cumulative `stats`, and into the process-wide totals
-    on /debug/traces."""
+    """Some of `GROUP_COUNTERS`, `ZREPLAY_COUNTERS`, `ANTI_COUNTERS`,
+    `AFFINITY_COUNTERS` or `REWARM_COUNTERS` into a driver's cumulative
+    `stats`, and into the process-wide totals on /debug/traces."""
     from kubernetes_tpu.trace.profile import count_wave_group
 
     for key, n in counted.items():
@@ -564,14 +596,18 @@ def classify_runs(config: SchedulerConfig, snap: ClusterSnapshot,
     svc_free = not service_config_labels(config)
     infos: List[dict] = []
     for rep, start, length in runs:
-        eligible, veto = (False, None)
+        # `refused`: why a run long enough for the run machinery goes
+        # to the scan all the same (None for an eligible run and for
+        # one that is merely short)
+        eligible, veto, refused = False, None, None
         # a gang span takes the run machinery at ANY length (typical
         # gangs are 2-16 pods, under the default min_run): the probe/
         # replay path is where the all-or-nothing commit is enforced
         if length >= min_run or start in gang_starts:
-            eligible, veto = run_eligible(
+            refused, veto = run_verdict(
                 config, batch, rep, snap, config_ok=config_ok,
             )
+            eligible = refused is None
         svc_ctx = svc_run_context(
             config, snap, batch, rep, num_values
         ) if eligible else None
@@ -586,9 +622,74 @@ def classify_runs(config: SchedulerConfig, snap: ClusterSnapshot,
         infos.append({
             "rep": rep, "start": start, "length": length,
             "eligible": eligible, "veto": veto, "svc_ctx": svc_ctx,
-            "device": device, "pure": pure,
+            "device": device, "pure": pure, "refused": refused,
         })
     return infos
+
+
+def count_runs(stats: dict, snap: ClusterSnapshot, batch: PodBatch,
+               infos: Sequence[dict]) -> None:
+    """What a wave's classification says of its runs, into a driver's
+    cumulative `stats` and the process-wide totals on /debug/traces, at
+    the wave's end beside `pods_by_path`: `scan_reasons` ({reason: the
+    pods of the runs `run_verdict` refused}) and AFFINITY_COUNTERS."""
+    from kubernetes_tpu.trace.profile import count_wave_reasons
+
+    reasons: Dict[str, int] = {}
+    for info in infos:
+        if info["refused"] is not None:
+            reasons[info["refused"]] = \
+                reasons.get(info["refused"], 0) + info["length"]
+    if reasons:
+        tally = stats["scan_reasons"]
+        for reason, n in reasons.items():
+            tally[reason] = tally.get(reason, 0) + n
+        count_wave_reasons(reasons)
+    owners = (np.asarray(batch.ip_ha_lt) >= 0).any(axis=1)
+    if not owners.any():
+        return
+    excluded: Dict[int, int] = {}  # by pod row: a wave's runs repeat it
+    runs = nodes = 0
+    for info in infos:
+        rep = info["rep"]
+        if owners[rep]:
+            if rep not in excluded:
+                excluded[rep] = affinity_nodes_excluded(snap, batch, rep)
+            runs += 1
+            nodes += excluded[rep]
+    count_group(stats, {"affinity_runs": runs,
+                        "affinity_nodes_excluded": nodes})
+
+
+def affinity_nodes_excluded(snap: ClusterSnapshot, batch: PodBatch,
+                            rep: int) -> int:
+    """The real nodes (allocatable pods > 0) that pod row `rep`'s
+    required podAffinity terms keep it off on the wave's snapshot: for
+    some term no bound pod that matches lies in the node's domain, and
+    the first-pod escape does not hold (ops/interpod.match_interpod's
+    hard-affinity half, in numpy on the kept tables: no device read)."""
+    lt_u = np.asarray(snap.ip_lt_u)
+    sign = np.asarray(snap.ip_lt_sign)
+    u_topo = np.asarray(snap.ip_u_topo)
+    topo_dom = np.asarray(snap.ip_topo_dom)
+    term_count = np.asarray(snap.ip_term_count)
+    lt_spec = np.asarray(snap.ip_lt_spec)
+    spec_total = np.asarray(snap.ip_spec_total)
+    out = np.zeros(topo_dom.shape[1], bool)
+    for lt, own in zip(np.asarray(batch.ip_ha_lt)[rep],
+                       np.asarray(batch.ip_ha_self)[rep]):
+        if lt < 0:
+            continue
+        if own and spec_total[lt_spec[lt]] == 0:
+            continue  # the first pod of its collection goes anywhere
+        cnt = np.zeros(topo_dom.shape[1], np.int64)
+        for u, sg in zip(lt_u[lt], sign[lt]):
+            if u >= 0:
+                dom = topo_dom[u_topo[u]]
+                cnt += np.where(dom >= 0, int(sg) * term_count[
+                    u, np.clip(dom, 0, term_count.shape[1] - 1)], 0)
+        out |= cnt <= 0
+    return int(np.count_nonzero(out & (np.asarray(snap.alloc_pods) > 0)))
 
 
 def gather_batch(batch: PodBatch, rows: np.ndarray) -> PodBatch:
@@ -719,6 +820,12 @@ class WaveScheduler:
             **dict.fromkeys(ZREPLAY_COUNTERS, 0),
             # the runs with a self-anti veto (`run_single`), all waves
             **dict.fromkeys(ANTI_COUNTERS, 0),
+            # the runs whose pod owns a required podAffinity term, and
+            # why runs of `min_run` pods went to the scan (`count_runs`)
+            **dict.fromkeys(AFFINITY_COUNTERS, 0),
+            "scan_reasons": {},
+            # the daemon's re-warm of the scan (the scheduler counts)
+            **dict.fromkeys(REWARM_COUNTERS, 0),
             # the encoder behind each wave's snapshot, and the scope
             # gates that sent waves to the from-scratch one
             # (`count_encoder`; the scheduler counts, the driver keeps)
@@ -887,7 +994,7 @@ class WaveScheduler:
         if LT and E and ip_own_anti.shape[2]:
             # the run's OWN terms, folded per node with multiplicity
             # counts[n] — ops/interpod.interpod_commit vectorized over N
-            # (run_eligible guarantees these terms never feed back into
+            # (run_verdict guarantees these terms never feed back into
             # this run's own fit/score; later pods need the exact state)
             lt_u = static["ip_lt_u"]  # (LT, E)
             q = static["ip_u_topo"][jnp.clip(lt_u, 0, U - 1)]
@@ -1551,4 +1658,5 @@ class WaveScheduler:
         self._count_wave(via, out)
         if anti["anti_runs"]:
             count_group(self.stats, anti)
+        count_runs(self.stats, snap, batch, infos)
         return out, carry, L_host

@@ -1138,12 +1138,17 @@ class MeshWaveScheduler:
         # numbers are diffs), plus what only a mesh has: the picks that
         # landed on each shard's nodes (they add up to the pods placed)
         # and the resident state's shipped bytes
-        from kubernetes_tpu.models.wave import GROUP_COUNTERS, PATHS
+        from kubernetes_tpu.models.wave import (
+            AFFINITY_COUNTERS,
+            GROUP_COUNTERS,
+            PATHS,
+        )
 
         self.stats = {
             "waves": 0, "dispatches": 0, "dispatches_by_kind": {},
             "pods_by_path": dict.fromkeys(PATHS, 0), "pods_unplaced": 0,
             **dict.fromkeys(GROUP_COUNTERS, 0),
+            **dict.fromkeys(AFFINITY_COUNTERS, 0), "scan_reasons": {},
             "picks_by_shard": [0] * int(mesh.devices.size),
             "h2d_bytes_total": 0,
         }
@@ -1345,6 +1350,7 @@ class MeshWaveScheduler:
             _host_group_cap,
             _permute_tables,
             classify_runs,
+            count_runs,
             count_group,
             gather_batch,
             group_buffer,
@@ -1611,6 +1617,7 @@ class MeshWaveScheduler:
         carry = flush(carry)
         self.resident.finish_wave(carry, L_host)
         self._count_wave(via, out, n_per_shard)
+        count_runs(self.stats, snap, batch, infos)
         return out, carry, L_host
 
     def _count_wave(self, via: np.ndarray, out: np.ndarray,

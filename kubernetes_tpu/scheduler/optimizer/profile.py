@@ -61,7 +61,7 @@ from kubernetes_tpu.models.wave import (
     config_eligible,
     gather_batch,
     group_buffer,
-    run_eligible,
+    run_verdict,
     run_pure,
 )
 from kubernetes_tpu.scheduler.optimizer.ops.assign import (
@@ -119,9 +119,9 @@ class OptimizingWaveDriver:
         out = {}
         for rep in np.unique(np.asarray(rep_idx)):
             rep = int(rep)
-            eligible, veto = run_eligible(config, batch, rep, snap,
-                                          config_ok=True)
-            if not eligible or veto is not None:
+            refused, veto = run_verdict(config, batch, rep, snap,
+                                        config_ok=True)
+            if refused is not None or veto is not None:
                 continue
             if not run_pure(config, batch, rep, svc_free=svc_free):
                 continue

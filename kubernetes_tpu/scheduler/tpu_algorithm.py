@@ -22,6 +22,15 @@ from kubernetes_tpu.trace import profile as trace_profile
 
 log = logging.getLogger(__name__)
 
+#: after this long the re-warm of the scan starts no further pod bucket
+#: and hands the loop back for a wave (`TPUScheduleAlgorithm._rewarm`):
+#: from the compile cache all seven buckets take seconds; where they
+#: compile, 20-45 s each on the chip, two or three fit and the last one
+#: started ends at 105 s at most, behind a wave that built its own
+#: bucket in 45 s: half of the 300 s the benchmark's load generator
+#: gives a prefill step
+REWARM_SLICE_S = 60.0
+
 #: node slots a device on a mesh: the sharded node axis is a multiple
 #: of this times the devices (lanes of 128; a cluster that outgrows its
 #: bucket compiles the mesh programs anew, as one that doubles does on
@@ -40,6 +49,30 @@ def _eager_scan_warm() -> bool:
     return os.environ.get(
         "KUBERNETES_TPU_WARM_SCAN", "").strip().lower() in (
         "1", "true", "on", "yes")
+
+
+def interpod_widths(snap, batch) -> Optional[tuple]:
+    """The widths of the inter-pod tables that the wave programs are
+    traced per, beyond the node and pod axes: topology combos, term
+    classes, specs, logical terms and their expansion, the domain axis
+    (the snapshot's `ip_*` tables) and the pods' own term lists (hard
+    affinity, hard anti-affinity, preferred). None where neither the
+    cluster nor the wave has ever carried a term: every table is then
+    zero-width and the start-up warm-up's programs serve."""
+    import numpy as np
+
+    terms, expansion = (tuple(np.shape(snap.ip_lt_u)) + (0, 0))[:2]
+    units, specs = len(snap.ip_u_topo), len(snap.ip_spec_total)
+    if not (units or specs or terms):
+        return None
+    return (np.shape(snap.ip_topo_dom)[0], units, specs, terms, expansion,
+            np.shape(snap.ip_term_count)[-1], batch.ip_ha_lt.shape[1],
+            batch.ip_hq_lt.shape[1], batch.ip_fwd_lt.shape[1])
+
+
+#: `interpod_widths`' entries by name, for the span and the log
+WIDTH_NAMES = ("combos", "classes", "specs", "terms", "expansion", "domains",
+               "own_affinity", "own_anti", "own_preferred")
 
 
 def _ids_to_names(chosen, node_names, n_real) -> List[Optional[str]]:
@@ -106,6 +139,19 @@ class TPUScheduleAlgorithm:
             # unchanged incremental view ships zero node-table bytes)
             self._inc = self._new_encoder()
             cache.add_listener(self._inc.on_cache_event)
+        # the re-warm of the scan at inter-pod widths first seen
+        # (`_rewarm`): the daemon's own encoder (a warm-up swaps
+        # `_inc`), a pod of every template seen pending (by feature
+        # key), whether its pods take the scan as a rule, the widths
+        # already warmed, the widths being warmed and their pod buckets
+        # still to warm, and the last wave's widths
+        self._live_inc = self._inc
+        self._templates: dict = {}
+        self._warmed_widths: set = set()
+        self._scan_bound = False
+        self._rewarm_widths = None
+        self._rewarm_left: List[int] = []
+        self._last_widths = None
         self._service_lister = service_lister
         self._controller_lister = controller_lister
         self._replica_set_lister = replica_set_lister
@@ -183,7 +229,25 @@ class TPUScheduleAlgorithm:
         state's mirrors, and the row scatter that ships a wave's churn
         compiles here at every row bucket up to the wave cap. The
         single-chip driver's own row scatter is warmed apart
-        (`_warm_row_scatter`)."""
+        (`_warm_row_scatter`).
+
+        What is warmed when, on one chip: here, before the loop opens,
+        every program at the cluster's node, label, zone and
+        spread-class widths with ZERO-WIDTH inter-pod tables (the
+        run's probe, replay and fold, the grouped programs at two run
+        slots, the scan at every pod bucket, nine row-scatter bucket
+        pairs); behind the first wave that shows a set of inter-pod
+        widths on a cluster whose pods take the scan as a rule
+        (`_after_live_wave` says by what evidence), the scan again at
+        those widths and every pod bucket (`_rewarm`, with
+        KUBERNETES_TPU_WARM_SCAN on: the terms are the pods'
+        annotations, which nothing here can know). Still
+        warmed by nobody, and compiled where first met: the grouped
+        programs at the run-slot buckets a wave's run count makes (8 to
+        128), the spread-class axis while it grows as controllers'
+        pods first appear, and the probes and the fold at inter-pod
+        widths (a benchmark mix's prefill steps meet them:
+        PERF.md section 7)."""
         from kubernetes_tpu.api.types import (
             Container,
             Node,
@@ -252,13 +316,10 @@ class TPUScheduleAlgorithm:
             # ~4.5s of trace + compile-cache-read CPU interleaved with
             # the first minutes of a 30k-pod create burst, all of it
             # removable by compiling here, before the loop opens.
-            from kubernetes_tpu.scheduler.core import WAVE_CAP
-
-            bucket = max(self._wave.pod_floor, self._wave.min_run, 2)
-            while bucket <= WAVE_CAP:
+            buckets = self._pod_buckets()
+            for bucket in buckets:
                 warm([pod(f"wb{bucket}-{i}", "100m", i)
                       for i in range(bucket)])
-                bucket *= 2
             if _eager_scan_warm():
                 # sub-min_run trickle waves hit the SCAN program, whose
                 # warm normally waits for 5s of sustained idleness — a
@@ -267,7 +328,7 @@ class TPUScheduleAlgorithm:
                 # interleaved with creation). Opt-in because a cold
                 # compile cache pays tens of seconds here before the
                 # loop opens; the wire bench and soak harness set it.
-                for k in (2, bucket // 2):
+                for k in (2, buckets[-1]):
                     warm([pod(f"wsb{k}-{i}", f"{200 + i}m")
                           for i in range(k)])
             if bound and self._mesh_sched is None and self._inc is not None:
@@ -282,6 +343,17 @@ class TPUScheduleAlgorithm:
                     state, nodes, bound, bind)
         if phase in ("all", "scan"):
             warm([pod("w-scan", "200m"), pod("w-scan2", "300m")])
+
+    def _pod_buckets(self) -> List[int]:
+        """Every pod-axis pow2 bucket a daemon wave can land in, smallest
+        first: each is a compiled shape of its own."""
+        from kubernetes_tpu.scheduler.core import WAVE_CAP
+
+        buckets, bucket = [], max(self._wave.pod_floor, self._wave.min_run, 2)
+        while bucket <= WAVE_CAP:
+            buckets.append(bucket)
+            bucket *= 2
+        return buckets
 
     def _warm_row_scatter(self, backlog, state, nodes, bound, bind) -> None:
         """The single-chip driver ships a table of which few rows
@@ -321,37 +393,42 @@ class TPUScheduleAlgorithm:
 
     def _warm_one(self, backlog, state, nodes, bound, shared) -> None:
         with self._sched_lock:
-            saved_last, saved_inc = self._last_node_index, self._inc
-            try:
-                if saved_inc is not None:
-                    # daemon mode schedules off the incremental view, whose
-                    # static-array shapes (empty-vocab widths) differ from
-                    # the full encoder's padded ones — warming the wrong
-                    # program would leave the cold compile on the first
-                    # real wave. Feed a throwaway encoder the synthetic
-                    # cluster through the same cache-event seam. It never
-                    # hears of a warm backlog's picks, so its view is the
-                    # synthetic cluster every time. The mesh driver's
-                    # resident state goes by content: one such encoder
-                    # (`shared`, the warm-up's) serves all of a
-                    # warm-up's backlogs (feeding 20,000 nodes and
-                    # encoding 1,250 templates' rows a dozen times was
-                    # 200 s of a set-up at that size). The single-chip driver's
-                    # device cache goes by provenance (`source`,
-                    # `keep`): each of its backlogs gets an encoder of
-                    # its own, as before.
-                    inc = shared[0] if shared else None
-                    if inc is None:
-                        inc = self._warm_encoder(nodes, bound)
-                        if self._mesh_sched is not None:
-                            shared.append(inc)
-                    self._inc = inc
-                else:
-                    self._inc = None  # compile via the full-encode path
-                self._schedule_locked(backlog, state)
-            finally:
-                self._inc = saved_inc
-                self._last_node_index = saved_last
+            self._warm_one_locked(backlog, state, nodes, bound, shared)
+
+    def _warm_one_locked(self, backlog, state, nodes, bound, shared) -> None:
+        """`_warm_one` under a caller's `_sched_lock` (the re-warm runs
+        inside a wave's own call)."""
+        saved_last, saved_inc = self._last_node_index, self._inc
+        try:
+            if saved_inc is not None:
+                # daemon mode schedules off the incremental view, whose
+                # static-array shapes (empty-vocab widths) differ from
+                # the full encoder's padded ones — warming the wrong
+                # program would leave the cold compile on the first
+                # real wave. Feed a throwaway encoder the synthetic
+                # cluster through the same cache-event seam. It never
+                # hears of a warm backlog's picks, so its view is the
+                # synthetic cluster every time. The mesh driver's
+                # resident state goes by content: one such encoder
+                # (`shared`, the warm-up's) serves all of a
+                # warm-up's backlogs (feeding 20,000 nodes and
+                # encoding 1,250 templates' rows a dozen times was
+                # 200 s of a set-up at that size). The single-chip driver's
+                # device cache goes by provenance (`source`,
+                # `keep`): each of its backlogs gets an encoder of
+                # its own, as before.
+                inc = shared[0] if shared else None
+                if inc is None:
+                    inc = self._warm_encoder(nodes, bound)
+                    if self._mesh_sched is not None:
+                        shared.append(inc)
+                self._inc = inc
+            else:
+                self._inc = None  # compile via the full-encode path
+            self._schedule_locked(backlog, state)
+        finally:
+            self._inc = saved_inc
+            self._last_node_index = saved_last
 
     def schedule_backlog(
         self, pods: Sequence[Pod], state: ClusterState,
@@ -459,16 +536,135 @@ class TPUScheduleAlgorithm:
 
                 self._opt = OptimizingWaveDriver(self._wave)
             driver = self._opt
+        scanned = self._wave.stats["pods_by_path"]["scan"]
         chosen, _final, last = driver.schedule_backlog(
             snap, batch, rep_idx, last_node_index=self._last_node_index,
             keep=keep, source=source, gangs=wave_gangs, reship=reship,
         )
+        scanned = self._wave.stats["pods_by_path"]["scan"] - scanned
         self._last_node_index = last
         names = snap.node_names
-        return [
+        hosts = [
             (names[i] or None) if 0 <= i < len(names) else None
             for i in (int(c) for c in chosen)
         ]
+        if source != "full":
+            self._last_widths = interpod_widths(snap, batch)
+            if self._inc is self._live_inc and _eager_scan_warm():
+                self._after_live_wave(reps, keys, state, snap, batch, scanned)
+        return hosts
+
+    def _after_live_wave(self, reps, keys, state, snap, batch,
+                         scanned: int) -> None:
+        """Behind a wave of the daemon's own (never a warm-up's): keep a
+        pod of every template seen pending, and warm the scan where the
+        wave's inter-pod widths are new, or buckets are still left, on
+        a cluster whose pods take the scan as a rule. The evidence for
+        that, since a warm wave costs its whole bucket of steps (2 ms a
+        step with ten logical terms on the chip: 30 s for the seven
+        buckets from the compile cache; PERF.md, PR 45): a template that
+        `run_verdict` refuses whatever its run's length (an own
+        required podAffinity term, a preferred term on its own copies,
+        a zone-coupled anti-affinity term, the policy), or a wave whose
+        scan decided more pods than the smallest bucket holds. Where
+        every term is the run tables' (a hostname anti-affinity term)
+        the scan meets these widths only through the run a wave's end
+        cuts short, in its smallest bucket, which that wave builds."""
+        from kubernetes_tpu.models.wave import run_verdict
+
+        widths = self._last_widths
+        if widths is not None and widths not in self._warmed_widths:
+            fresh = [i for i, k in enumerate(keys)
+                     if k not in self._templates]
+            self._scan_bound = (
+                self._scan_bound or scanned > self._wave.pod_floor
+                or any(run_verdict(self._wave.config, batch, i, snap)[0]
+                       is not None for i in fresh))
+            if self._scan_bound:
+                # widths newer than the ones being warmed take their
+                # place: what was left of those serves a cluster that
+                # is gone
+                self._warmed_widths.add(widths)
+                self._rewarm_widths = widths
+                self._rewarm_left = self._pod_buckets()
+        if len(self._templates) + len(reps) > 8192:
+            self._templates.clear()  # as PendingRows.MAX_ROWS bounds rows
+        self._templates.update(zip(keys, reps))
+        if self._rewarm_left:
+            self._rewarm(state)
+
+    def _rewarm(self, state) -> None:
+        """Warm `jit_batch_scan` (and the transfers round it) at the
+        inter-pod widths a wave has just shown, for every pod bucket
+        from `pod_floor` to the wave cap, smallest first, before the
+        loop decides its next wave. `warmup` cannot: it runs before a
+        pod arrives and knows the controllers' selectors, not their
+        pods' annotations, so its programs have zero-width inter-pod
+        tables; and the scan is traced per width of those tables and
+        per pod bucket, so every bucket a later wave lands in first
+        would compile there, 20-45 s on the chip, inside a measured
+        window or a check batch (PERF.md, PRs 28, 35 and 45).
+
+        Through `_warm_one`'s seam: a throwaway encoder fed the nodes
+        and bound pods of the wave's own snapshot of the scheduler
+        cache, so that its vocabularies, and so its widths, are the live
+        ones; backlogs of a pod of every template seen pending, dealt
+        in turn (runs of length 1: the scan). The live encoder,
+        `_last_node_index` and the driver's device mirrors are as they
+        were afterwards. Where the warm view's widths are not the live
+        ones (a term only deleted pods carried) that is counted
+        (`rewarm_mismatches`) and logged. It starts no further bucket
+        after REWARM_SLICE_S and goes on behind the next wave. Counted in
+        `stats` (`rewarms`, `rewarm_seconds`, `rewarm_programs`) and as
+        the span `scheduler.rewarm`; its time on the timeline is the
+        warm waves' own phases (`encode`, `transfer`, `score`)."""
+        import time
+
+        from kubernetes_tpu.models.wave import count_group
+        from kubernetes_tpu.trace import spans as trace_span
+
+        widths = self._rewarm_widths
+        began, built = time.time(), trace_profile.compile_count()
+        nodes = [info.node for info in state.node_infos.values()
+                 if info.node is not None]
+        bound = state.all_assigned_pods()
+        templates = list(self._templates.values())
+        inc = self._warm_encoder(nodes, bound)
+        wave = self._wave
+        mirrors = wave._dev, wave._dev_source
+        wave._dev, wave._dev_source = {}, None
+        buckets, off = [], 0
+        try:
+            while self._rewarm_left:
+                bucket = self._rewarm_left.pop(0)
+                self._warm_one_locked(
+                    [templates[i % len(templates)] for i in range(bucket)],
+                    state, nodes, bound, [inc])
+                buckets.append(bucket)
+                off += self._last_widths != widths
+                if time.time() - began >= REWARM_SLICE_S:
+                    break  # the loop's turn; the rest behind its wave
+        finally:
+            wave._dev, wave._dev_source = mirrors
+            self._last_widths = widths
+        ended = time.time()
+        counted = {"rewarms": 1, "rewarm_seconds": ended - began,
+                   "rewarm_programs": trace_profile.compile_count() - built}
+        if off:
+            counted["rewarm_mismatches"] = off
+            log.warning("re-warm: %d of %d warm waves had other inter-pod "
+                        "widths than the live %s", off, len(buckets),
+                        dict(zip(WIDTH_NAMES, widths)))
+        count_group(wave.stats, counted)
+        log.info("re-warmed the scan at %s, pod buckets %s, in %.1fs (%d "
+                 "programs; %d buckets left)", dict(zip(WIDTH_NAMES, widths)),
+                 buckets, ended - began, counted["rewarm_programs"],
+                 len(self._rewarm_left))
+        trace_span.record_span(
+            "scheduler.rewarm", trace_span.new_trace_id(), began, ended,
+            buckets=buckets, left=len(self._rewarm_left),
+            programs=counted["rewarm_programs"],
+            **dict(zip(WIDTH_NAMES, widths)))
 
     def _schedule_backlog_mesh(
         self, pods: Sequence[Pod], state: ClusterState

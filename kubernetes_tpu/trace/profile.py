@@ -478,7 +478,15 @@ _WAVE: Dict[str, Any] = {"waves": 0, "pods_by_path": {},
                          "zreplay_picks": 0, "anti_runs": 0,
                          "anti_picks": 0, "anti_nodes_excluded": 0,
                          "waves_by_encoder": {}, "encoder_fallbacks": {},
-                         "interpod_rebuilds": {}}
+                         "interpod_rebuilds": {},
+                         # models/wave.count_runs: why runs went to the
+                         # scan, and the runs that own a required
+                         # podAffinity term; scheduler/tpu_algorithm's
+                         # re-warm of the scan at new inter-pod widths
+                         "scan_reasons": {}, "affinity_runs": 0,
+                         "affinity_nodes_excluded": 0, "rewarms": 0,
+                         "rewarm_seconds": 0.0, "rewarm_programs": 0,
+                         "rewarm_mismatches": 0}
 
 
 def count_wave(pods_by_path: Dict[str, int], dispatches: Dict[str, int],
@@ -502,10 +510,21 @@ def count_wave_group(counted: Dict[str, int]) -> None:
     a grouped device replay came back: the steps and run slots its
     loops ran, the steps that rescored, the pods it placed
     (`zreplay_*`); or a run with a self-anti veto was decided
-    (`anti_*`)."""
+    (`anti_*`); or a wave held runs that own a required podAffinity
+    term (`affinity_*`); or the daemon warmed the scan again
+    (`rewarm*`)."""
     with _wave_lock:
         for k, n in counted.items():
             _WAVE[k] += n
+
+
+def count_wave_reasons(reasons: Dict[str, int]) -> None:
+    """A wave sent runs of `min_run` pods and more to the scan:
+    `reasons` pods by why (models/wave.SCAN_REASONS)."""
+    with _wave_lock:
+        tally = _WAVE["scan_reasons"]
+        for reason, n in reasons.items():
+            tally[reason] = tally.get(reason, 0) + n
 
 
 def count_wave_encoder(encoder: str, fallback: Optional[str],
@@ -537,6 +556,16 @@ _installed = False
 #: the last 64 programs built in this process, oldest first: which step
 #: recompiled (served on /debug/traces as "compiles")
 _COMPILES: deque = deque(maxlen=64)
+
+
+#: programs built in this process since the listener went in (each a
+#: `backend_compile_duration` event, served from the persistent cache
+#: or not): a difference of two reads says what a stretch built
+_COMPILE_COUNT = [0]
+
+
+def compile_count() -> int:
+    return _COMPILE_COUNT[0]
 
 
 def recent_compiles() -> List[dict]:
@@ -571,6 +600,7 @@ def install_compile_listener() -> None:
         def _on_duration(event: str, duration: float, **kw) -> None:
             if event.endswith("backend_compile_duration"):
                 scheduler_xla_compile_seconds.observe(duration)
+                _COMPILE_COUNT[0] += 1
                 phase = getattr(_TLS, "phase", None)
                 hit = getattr(_TLS, "cache_hit", False)
                 _TLS.cache_hit = False

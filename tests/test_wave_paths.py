@@ -781,3 +781,397 @@ def test_two_scans_in_a_row_hand_on_the_class_counts(zones):
     assert got == want
     assert got.count(None) == 1
     assert int(carry[BatchScheduler.LAST_IDX]) == len(backlog) - 1
+
+
+# -- terms over a topology that couples nodes: why the scan, and its re-warm --
+
+
+def _term_pod(t, i, kinds, groups=5):
+    """A replica of controller `t` with terms that select its service
+    (controllers t and t + groups), `kinds` of: "affinity" (required
+    podAffinity over the zone; benchmark/configs/podaffinity-2k.json's
+    first term), "soft" (preferred podAntiAffinity, weight 100, over
+    the hostname: its second), "zone_anti" (required podAntiAffinity
+    over the zone)."""
+    import json
+
+    from kubernetes_tpu.api.types import AFFINITY_ANNOTATION
+
+    k = t % groups
+    selector = {"matchExpressions": [{
+        "key": "group", "operator": "In",
+        "values": [f"g{k}", f"g{k + groups}"]}]}
+    stated: dict = {}
+    if "affinity" in kinds:
+        stated["podAffinity"] = {
+            "requiredDuringSchedulingIgnoredDuringExecution": [{
+                "labelSelector": selector, "topologyKey": ZONE}]}
+    anti = {}
+    if "soft" in kinds:
+        anti["preferredDuringSchedulingIgnoredDuringExecution"] = [{
+            "weight": 100, "podAffinityTerm": {
+                "labelSelector": selector, "topologyKey": HOSTNAME}}]
+    if "zone_anti" in kinds:
+        anti["requiredDuringSchedulingIgnoredDuringExecution"] = [{
+            "labelSelector": selector, "topologyKey": ZONE}]
+    if anti:
+        stated["podAntiAffinity"] = anti
+    return Pod(
+        metadata=ObjectMeta(name=f"term{t}-{i:05d}",
+                            labels={"group": f"g{t}"},
+                            annotations={AFFINITY_ANNOTATION:
+                                         json.dumps(stated)}),
+        spec=PodSpec(containers=[Container(requests={"cpu": "100m"})]))
+
+
+def _term_rows(controllers, replicas, kinds=("affinity", "soft"), serial=0):
+    return [_term_pod(t, serial + i, kinds) for t in controllers
+            for i in range(replicas)]
+
+
+def _one_disk_pod(i):
+    """Replicas that mount ONE disk: a run, where `_volume_pod`'s
+    disks make every pod a template of its own."""
+    p = _volume_pod(0)
+    p.metadata.name = f"disk0-{i:04d}"
+    return p
+
+
+REASON_CASES = {
+    # name: (the wave, {reason: pods} counted)
+    # the deployment's two terms: the required one is met first
+    "zone-affinity-and-soft-spread": (
+        lambda: _term_rows((0, 6), 16), {"hard_affinity": 32}),
+    "soft-spread-alone": (
+        lambda: _term_rows((0, 6), 16, ("soft",)), {"self_preferred": 32}),
+    "zone-anti-affinity": (
+        lambda: _term_rows((0, 1), 16, ("zone_anti",)), {"zone_anti": 32}),
+    "a-volume": (lambda: [_one_disk_pod(i) for i in range(16)],
+                 {"volumes": 16}),
+    # a run shorter than `min_run` is the scan's for its length alone
+    "short-runs": (lambda: _term_rows(range(10), 3), {}),
+    # the hostname anti-affinity term is the tables': no reason
+    "hostname-anti": (lambda: _anti_rows((0, 1), 16), {}),
+    "no-terms": (lambda: _in_rows(2, 16), {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REASON_CASES))
+def test_scan_reasons_say_why_a_run_went_to_the_scan(case):
+    from kubernetes_tpu.models.wave import SCAN_REASONS
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+    from kubernetes_tpu.trace.httpd import render_traces
+
+    wave, reasons = REASON_CASES[case]
+    backlog = wave()
+    state = ClusterState.build(_nodes(30), controllers=_anti_controllers()
+                               + _controllers(2))
+    algo = TPUScheduleAlgorithm()
+    shown_before = render_traces({"limit": "1"})["wave"]["scan_reasons"]
+    assert algo.schedule_backlog(backlog, state) == _oracle(state, backlog)
+    stats = algo._wave.stats
+    assert stats["scan_reasons"] == reasons
+    assert set(reasons) <= set(SCAN_REASONS)
+    if reasons:
+        assert stats["pods_by_path"]["scan"] == len(backlog)
+    shown = render_traces({"limit": "1"})["wave"]["scan_reasons"]
+    assert {k: v for k, v in _delta(shown, shown_before).items() if v} \
+        == reasons
+
+
+def test_affinity_counters_say_how_much_of_the_cluster_the_term_takes():
+    """Service 0 holds pods in zone b: a run of either of its
+    controllers is kept off the 20 nodes of zones a and c; service 1
+    holds none anywhere, so its first pod may go to all 30 (the
+    escape); runs without a required podAffinity term are not counted."""
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    nodes = _nodes(30)
+    bound = _term_rows((0, 5), 2, serial=900)
+    for p, n in zip(bound, (1, 4, 7, 1)):
+        p.spec.node_name = nodes[n].metadata.name
+    state = ClusterState.build(nodes, bound, controllers=_anti_controllers())
+    backlog = (_term_rows((5,), 16) + _term_rows((1,), 16)
+               + _term_rows((0,), 3) + _anti_rows((2,), 16))
+    algo = TPUScheduleAlgorithm()
+    got = algo.schedule_backlog(backlog, state)
+    assert got == _oracle(state, backlog)
+    zone_b = {n.metadata.name for n in nodes[1::3]}
+    assert set(got[:16]) <= zone_b and set(got[32:35]) <= zone_b
+    stats = algo._wave.stats
+    assert (stats["affinity_runs"], stats["affinity_nodes_excluded"]) \
+        == (3, 20 + 0 + 20)
+    assert stats["scan_reasons"] == {"hard_affinity": 32}
+
+
+def _daemon(nodes, controllers, bound=()):
+    """The served wave driver as the daemon holds it: a scheduler cache
+    feeding the kept snapshot."""
+    from kubernetes_tpu.scheduler.cache import SchedulerCache
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+    from kubernetes_tpu.utils.clock import FakeClock
+
+    cache = SchedulerCache(clock=FakeClock())
+    for n in nodes:
+        cache.add_node(n)
+    for p in bound:
+        cache.add_pod(p)
+    algo = TPUScheduleAlgorithm(
+        cache=cache, controller_lister=SimpleNamespace(
+            list=lambda: controllers))
+    return cache, algo
+
+
+#: (zoned nodes, rounds, runs a round, pods a run, share deleted between
+#: rounds, seed): every stream begins with the first pod of each service
+#: (the escape) and meets both of its controllers; a share of 1.0
+#: empties the services, which choose a zone again
+SERVED_CASES = [(24, 3, 6, 5, 0.3, 11), (48, 3, 8, 16, 1.0, 2 ** 31 + 12),
+                (96, 2, 10, 8, 0.5, 13)]
+
+
+@pytest.mark.parametrize("n,rounds,runs,row,deleted,seed", SERVED_CASES)
+def test_the_served_driver_decides_zone_affinity_as_the_serial_oracle(
+        n, rounds, runs, row, deleted, seed):
+    import random
+
+    rng = random.Random(seed)
+    nodes, controllers = _nodes(n), _anti_controllers()
+    cache, algo = _daemon(nodes, controllers)
+    oracle = _a_serial_oracle()
+    live = []
+    zones_taken = set()
+    for r in range(rounds):
+        order = rng.sample(range(10), 10)
+        backlog = []
+        for j in range(runs):
+            backlog += _term_rows((order[j % 10],), row,
+                                  serial=1000 * r + 100 * j)
+        state = cache.snapshot(controllers=controllers)
+        got = algo.schedule_backlog(backlog, state)
+        assert got == oracle.schedule_backlog(backlog, state.clone())
+        assert None not in got
+        for p, host in zip(backlog, got):
+            p.spec.node_name = host
+            cache.add_pod(p)
+            live.append(p)
+        zone_of = {nd.metadata.name: nd.metadata.labels[ZONE]
+                   for nd in nodes}
+        for k in range(5):
+            zones = {zone_of[p.spec.node_name] for p in live
+                     if int(p.metadata.labels["group"][1:]) % 5 == k}
+            assert len(zones) <= 1
+            zones_taken |= {(k, z) for z in zones}
+        gone = rng.sample(range(len(live)), int(deleted * len(live)))
+        for i in sorted(gone, reverse=True):
+            cache.remove_pod(live.pop(i))
+    stats = algo._wave.stats
+    assert stats["waves_by_encoder"] == {"incremental": rounds, "full": 0}
+    assert stats["pods_by_path"]["scan"] == rounds * runs * row
+    assert stats["affinity_runs"] == rounds * runs
+    assert stats["rewarms"] == 0  # no KUBERNETES_TPU_WARM_SCAN here
+
+
+def _a_serial_oracle():
+    from kubernetes_tpu.oracle import GenericScheduler
+
+    from tests.test_conformance import ORACLE_PREDICATES, ORACLE_PRIORITIES
+
+    return GenericScheduler(predicates=ORACLE_PREDICATES,
+                            priorities=ORACLE_PRIORITIES)
+
+
+def _dealt(controllers, pods, serial, make=None):
+    """`pods` pods of `controllers` dealt in turn (runs of length 1):
+    the deployment's two terms on each, or what `make(t, i)` makes."""
+    make = make or (lambda t, i: _term_pod(t, i, ("affinity", "soft")))
+    return [make(controllers[i % len(controllers)], serial + i)
+            for i in range(pods)]
+
+
+def _recorded_waves(algo):
+    """Wrap the driver: what every wave of the daemon's own encoder was
+    handed (a warm-up's waves come from an encoder of their own)."""
+    seen = []
+    inner = algo._wave.schedule_backlog
+
+    def schedule_backlog(snap, batch, rep_idx, **kw):
+        if kw.get("source") == algo._live_inc.source_token:
+            seen.append((snap, batch, kw["keep"], kw["reship"],
+                         kw["last_node_index"]))
+        return inner(snap, batch, rep_idx, **kw)
+
+    algo._wave.schedule_backlog = schedule_backlog
+    return seen
+
+
+def _same_arrays(a, b):
+    import dataclasses
+
+    for f in dataclasses.fields(a):
+        x, y = getattr(a, f.name), getattr(b, f.name)
+        if isinstance(x, np.ndarray):
+            assert x.dtype == y.dtype and x.shape == y.shape, f.name
+            assert np.array_equal(x, y, equal_nan=x.dtype.kind == "f"), \
+                f.name
+        else:
+            assert x == y, f.name
+
+
+def test_the_rewarm_builds_every_bucket_once_and_leaves_the_view_alone(
+        monkeypatch):
+    """With KUBERNETES_TPU_WARM_SCAN on, the first wave that carries a
+    term warms the scan at every pod bucket; afterwards a wave of any
+    bucket builds no scan, a second wave of the same widths never
+    re-warms, and what the live encoder hands the driver next is bit
+    for bit what it hands a daemon that never re-warmed."""
+    import time
+
+    from kubernetes_tpu.trace import spans
+
+    profile.install_compile_listener()
+    controllers = _anti_controllers()
+    first = lambda: _dealt(range(10), 10, 0)  # noqa: E731
+    second = lambda: _dealt(range(10), 70, 100)  # noqa: E731
+
+    def two_waves(warm):
+        if warm:
+            monkeypatch.setenv("KUBERNETES_TPU_WARM_SCAN", "1")
+        else:
+            monkeypatch.delenv("KUBERNETES_TPU_WARM_SCAN", raising=False)
+        nodes = _nodes(48)
+        cache, algo = _daemon(nodes, controllers)
+        seen = _recorded_waves(algo)
+        picks = []
+        for backlog in (first(), second()):
+            state = cache.snapshot(controllers=controllers)
+            got = algo.schedule_backlog(backlog, state)
+            picks.append(got)
+            for p, host in zip(backlog, got):
+                p.spec.node_name = host
+                cache.add_pod(p)
+        return cache, algo, seen, picks
+
+    t_began = time.time()
+    cache, algo, seen, picks = two_waves(warm=True)
+    _cache, plain, seen_plain, picks_plain = two_waves(warm=False)
+    stats = algo._wave.stats
+    assert stats["rewarms"] == 1 and stats["rewarm_mismatches"] == 0
+    assert stats["rewarm_programs"] >= 7 and stats["rewarm_seconds"] > 0
+    assert plain._wave.stats["rewarms"] == 0
+    assert picks == picks_plain
+    # the wave after the re-warm: the same snapshot, batch, `keep`,
+    # `reship` and round-robin counter as without it
+    assert len(seen) == len(seen_plain) == 2
+    for (snap, batch, keep, reship, last), \
+            (snap_p, batch_p, keep_p, reship_p, last_p) in zip(seen,
+                                                               seen_plain):
+        _same_arrays(snap, snap_p)
+        _same_arrays(batch, batch_p)
+        assert (keep, reship, last) == (keep_p, reship_p, last_p)
+    assert algo._last_node_index == plain._last_node_index
+    # one span, with the widths and the buckets
+    mine = [s for s in spans.BUFFER.snapshot(limit=16384)
+            if s["name"] == "scheduler.rewarm" and s["start"] >= t_began]
+    assert len(mine) == 1
+    assert mine[0]["attrs"]["buckets"] == [64, 128, 256, 512, 1024, 2048,
+                                           4096]
+    assert mine[0]["attrs"]["domains"] == 48
+    # now a wave in every bucket: no scan is built, whatever else is
+    monkeypatch.setenv("KUBERNETES_TPU_WARM_SCAN", "1")
+    serial = 1000
+    for pods in (10, 100, 200, 400, 900, 1800):
+        backlog = _dealt(range(10), pods, serial)
+        serial += pods
+        state = cache.snapshot(controllers=controllers)
+        t = time.time()
+        got = algo.schedule_backlog(backlog, state)
+        built = [c["program"] for c in profile.recent_compiles()
+                 if c["at"] >= t]
+        assert not [p for p in built if "scan" in p], (pods, built)
+        for p, host in zip(backlog, got):
+            if host is not None:
+                p.spec.node_name = host
+                cache.add_pod(p)
+    assert stats["rewarms"] == 1
+
+
+def test_a_cluster_without_terms_never_rewarms(monkeypatch):
+    """The spread-class axis grows wave by wave as controllers' pods
+    first appear, and must not trigger it: only inter-pod widths do."""
+    monkeypatch.setenv("KUBERNETES_TPU_WARM_SCAN", "1")
+    controllers = _controllers(12)
+    cache, algo = _daemon(_nodes(30), controllers)
+    oracle = _a_serial_oracle()
+    serial = 0
+    for wave in ((0, 1, 2), (3, 4, 5, 6), tuple(range(12))):
+        backlog = _dealt(wave, 24, serial, make=_pod)
+        serial += 24
+        state = cache.snapshot(controllers=controllers)
+        got = algo.schedule_backlog(backlog, state)
+        assert got == oracle.schedule_backlog(backlog, state.clone())
+        for p, host in zip(backlog, got):
+            p.spec.node_name = host
+            cache.add_pod(p)
+    stats = algo._wave.stats
+    assert stats["rewarms"] == 0 and stats["rewarm_programs"] == 0
+    assert algo._warmed_widths == set() and algo._last_widths is None
+
+
+def test_the_rewarm_hands_the_loop_back_and_goes_on_behind_the_next_wave(
+        monkeypatch):
+    """A slice of no seconds: one bucket a wave, smallest first, until
+    none is left; widths seen meanwhile are not warmed twice."""
+    from kubernetes_tpu.scheduler import tpu_algorithm
+
+    monkeypatch.setenv("KUBERNETES_TPU_WARM_SCAN", "1")
+    monkeypatch.setattr(tpu_algorithm, "REWARM_SLICE_S", 0.0)
+    controllers = _anti_controllers()
+    cache, algo = _daemon(_nodes(24), controllers)
+    stats = algo._wave.stats
+    left = []
+    for wave in range(8):
+        backlog = _dealt(range(10), 10, 100 * wave)
+        state = cache.snapshot(controllers=controllers)
+        got = algo.schedule_backlog(backlog, state)
+        for p, host in zip(backlog, got):
+            p.spec.node_name = host
+            cache.add_pod(p)
+        left.append(len(algo._rewarm_left))
+    assert left == [6, 5, 4, 3, 2, 1, 0, 0]
+    assert stats["rewarms"] == 7 and len(algo._warmed_widths) == 1
+
+
+def test_terms_the_run_tables_hold_rewarm_only_once_the_scan_is_used(
+        monkeypatch):
+    """A hostname anti-affinity term is the run tables': its runs go
+    run by run, and the scan meets the term widths through lone pods
+    and cut runs alone, in its smallest bucket, which the wave that
+    meets it builds. No re-warm, until a wave's scan decides more pods
+    than that bucket holds: then every bucket is warmed, once."""
+    monkeypatch.setenv("KUBERNETES_TPU_WARM_SCAN", "1")
+    controllers = _anti_controllers()
+    cache, algo = _daemon(_nodes(200, ""), controllers)
+    stats = algo._wave.stats
+
+    def wave(backlog):
+        state = cache.snapshot(controllers=controllers)
+        got = algo.schedule_backlog(backlog, state)
+        for p, host in zip(backlog, got):
+            if host is not None:
+                p.spec.node_name = host
+                cache.add_pod(p)
+
+    wave(_anti_rows(range(10), 1, serial=0))  # one of each: lone pods
+    wave(_anti_rows((0, 6, 2), 16, serial=10))  # runs: the probes
+    wave(_anti_rows((7, 3), 16, serial=30) + _anti_rows((1,), 9, serial=50))
+    assert stats["rewarms"] == 0 and algo._last_widths is not None
+    assert stats["anti_runs"] == 5 and not algo._scan_bound
+    # dealt in turn: 70 runs of one pod, the scan's second bucket
+    wave([_anti_pod(t % 10, 100 + t) for t in range(70)])
+    assert stats["rewarms"] == 1 and algo._scan_bound
+    assert algo._warmed_widths == {algo._last_widths}
+    wave([_anti_pod(t % 10, 200 + t) for t in range(70)])
+    assert stats["rewarms"] == 1
