@@ -4,7 +4,8 @@ The reference schedules 50k pods as 50k serial scheduleOne cycles
 (scheduler.go:93), each a fresh O(nodes x predicates) CPU scan. Here the
 backlog is a single jitted lax.scan whose carry is the mutable slice of
 the cluster state (requested/nonzero resources, pod counts, port masks,
-per-class pod counts, lastNodeIndex) and whose per-step body is:
+per-class pod counts, lastNodeIndex, the inter-pod domain tables) and
+whose per-step body is:
 
     fit[N]    = AND of predicate masks          (ops.predicates)
     score[N]  = sum_i weight_i * priority_i[N]  (ops.priorities)
@@ -14,6 +15,14 @@ per-class pod counts, lastNodeIndex) and whose per-step body is:
 which is bit-identical to the serial loop because the commit threading
 reproduces scheduler.go:122 AssumePod between cycles and the selection
 reproduces selectHost exactly.
+
+The 17-leaf carry is what callers hand in and get back (models/wave.py,
+the probe, the folds, the encoder's kept tables). Inside the loop it
+travels as `(carry, views)`: the views are the per-node expansions of
+the five inter-pod tables (ops/interpod.Views), made once a dispatch
+from the incoming carry, read by every step in place of a gather and
+committed beside the tables; they are loop-local and dropped when the
+scan returns.
 """
 
 from __future__ import annotations
@@ -137,6 +146,18 @@ def interpod_carry_tables(static, ip_term_count, num_nodes):
     )
 
 
+def interpod_views(config: "SchedulerConfig", static, carry):
+    """The views of a carry's five inter-pod tables (ops/interpod.Views);
+    None where the config has neither the predicate nor the priority."""
+    if not (MATCH_INTER_POD_AFFINITY in config.predicates
+            or any(n == INTER_POD_AFFINITY for n, _ in config.priorities)):
+        return None
+    return IP.interpod_views(
+        *carry[4:9], static["ip_topo_dom"], static["ip_u_topo"],
+        static["ip_lt_u"], static["ip_lt_sign"], carry[0].shape[1],
+    )
+
+
 def fit_mask(
     config: "SchedulerConfig",
     static,
@@ -144,13 +165,16 @@ def fit_mask(
     pod,
     cnt_lt,
     include_resources: bool = True,
+    own_lt=None,
 ):
     """The full predicate AND for one pod against one carry state.
 
     `include_resources=False` drops the carry-dependent PodFitsResources
     term (the wave probe tabulates it separately over the commit count —
     models/probe.py); everything else is evaluated against the given
-    carry exactly as the serial scan does."""
+    carry exactly as the serial scan does. `own_lt` is the view of the
+    carry's `ip_own_anti` where the caller carries it (the scan); absent,
+    it is gathered here."""
     (
         res,
         port_mask,
@@ -279,13 +303,14 @@ def fit_mask(
                 num_nodes,
             )
     if want_ip_pred:
-        own_lt = IP.gather_lt(
-            ip_own_anti,
-            static["ip_u_topo"],
-            static["ip_topo_dom"],
-            static["ip_lt_u"],
-            static["ip_lt_sign"],
-        )
+        if own_lt is None:
+            own_lt = IP.gather_lt(
+                ip_own_anti,
+                static["ip_u_topo"],
+                static["ip_topo_dom"],
+                static["ip_lt_u"],
+                static["ip_lt_sign"],
+            )
         fit = fit & IP.match_interpod(
             cnt_lt,
             own_lt,
@@ -303,11 +328,14 @@ def fit_mask(
     return fit
 
 
-def evaluate_pod(config: SchedulerConfig, num_zones: int, num_values: int, static, carry, pod):
+def evaluate_pod(config: SchedulerConfig, num_zones: int, num_values: int, static, carry, pod,
+                 views=None):
     """Fit mask + weighted priority total for one pod against a frozen
     carry — Schedule() up to selectHost (generic_scheduler.go:72-115).
     Shared by the scan body and debug_evaluate (the conformance probe for
-    ported reference test tables)."""
+    ported reference test tables). `views` are the carry's inter-pod
+    views where the caller carries them (the scan); absent, they are
+    derived from the carry's tables here."""
     (
         # res: i64 (6, N) = [req_mcpu, req_mem, req_gpu, nz_mcpu, nz_mem,
         # pod_count] stacked so the per-step commit is ONE scatter (the
@@ -336,11 +364,12 @@ def evaluate_pod(config: SchedulerConfig, num_zones: int, num_values: int, stati
 
     want_ip_pred = MATCH_INTER_POD_AFFINITY in config.predicates
     want_ip_prio = any(n == INTER_POD_AFFINITY for n, _ in config.priorities)
-    cnt_lt = None
-    if want_ip_pred or want_ip_prio:
-        cnt_lt = interpod_carry_tables(static, ip_term_count, num_nodes)
+    if views is None:
+        views = interpod_views(config, static, carry)
+    cnt_lt, own_lt = (None, None) if views is None else views[:2]
 
-    fit = fit_mask(config, static, carry, pod, cnt_lt, include_resources=True)
+    fit = fit_mask(config, static, carry, pod, cnt_lt, include_resources=True,
+                   own_lt=own_lt)
 
     score = jnp.zeros(req_mcpu.shape, jnp.int64)
     for name, weight in config.priorities:
@@ -394,18 +423,9 @@ def evaluate_pod(config: SchedulerConfig, num_zones: int, num_values: int, stati
         elif name == INTER_POD_AFFINITY:
             s = IP.interpod_priority(
                 cnt_lt,
-                IP.gather_lt(
-                    ip_rev_hard, static["ip_u_topo"], static["ip_topo_dom"],
-                    static["ip_lt_u"], static["ip_lt_sign"],
-                ),
-                IP.gather_lt(
-                    ip_rev_pref, static["ip_u_topo"], static["ip_topo_dom"],
-                    static["ip_lt_u"], static["ip_lt_sign"],
-                ),
-                IP.gather_lt(
-                    ip_rev_anti, static["ip_u_topo"], static["ip_topo_dom"],
-                    static["ip_lt_u"], static["ip_lt_sign"],
-                ),
+                views.rev_hard_lt,
+                views.rev_pref_lt,
+                views.rev_anti_lt,
                 static["ip_lt_spec"],
                 pod["ip_match_spec"],
                 pod["ip_fwd_lt"],
@@ -437,7 +457,13 @@ def evaluate_pod(config: SchedulerConfig, num_zones: int, num_values: int, stati
     return fit, score
 
 
-def _scan_fn(config: SchedulerConfig, num_zones: int, num_values: int, static, carry, pod):
+def _scan_fn(config: SchedulerConfig, num_zones: int, num_values: int, static, dom_lt,
+             loop, pod):
+    """One step of the scan. `loop` is `(carry, views)` and `dom_lt` the
+    domain ids the views were gathered at (ops/interpod.lt_domains),
+    which no commit moves; with views None the step derives them from
+    the carry's tables, as whoever holds a frozen carry does."""
+    carry, views = loop
     (
         res,
         port_mask,
@@ -461,7 +487,7 @@ def _scan_fn(config: SchedulerConfig, num_zones: int, num_values: int, static, c
     want_ip_pred = MATCH_INTER_POD_AFFINITY in config.predicates
     want_ip_prio = any(n == INTER_POD_AFFINITY for n, _ in config.priorities)
 
-    fit, score = evaluate_pod(config, num_zones, num_values, static, carry, pod)
+    fit, score = evaluate_pod(config, num_zones, num_values, static, carry, pod, views)
 
     chosen, scheduled = S.select_host(score, fit, last_idx, static["name_desc_order"])
 
@@ -488,6 +514,22 @@ def _scan_fn(config: SchedulerConfig, num_zones: int, num_values: int, static, c
     )
     class_count = class_count.at[safe, pod["class_id"]].add(inc)
     last_idx = last_idx + inc
+    if views is not None:
+        views = IP.interpod_commit_views(
+            views,
+            dom_lt,
+            ip_own_anti.shape[2],
+            static["ip_u_spec"],
+            static["ip_lt_u"],
+            static["ip_lt_sign"],
+            pod["ip_match_spec"],
+            pod["ip_own_hard"],
+            pod["ip_own_pref"],
+            pod["ip_own_anti_hard"],
+            pod["ip_own_anti_pref"],
+            chosen,
+            scheduled,
+        )
     if want_ip_pred or want_ip_prio:
         (
             ip_term_count,
@@ -558,7 +600,20 @@ def _scan_fn(config: SchedulerConfig, num_zones: int, num_values: int, static, c
         svc_peer_node_count,
         svc_peer_total,
     )
-    return carry, chosen
+    return (carry, views), chosen
+
+
+def scan_backlog(config: SchedulerConfig, num_zones: int, num_values: int, static, carry, pods):
+    """The backlog's scan -> (final carry, chosen[P]): the views made
+    once from the incoming carry, the steps, and the carry alone handed
+    back."""
+    views = interpod_views(config, static, carry)
+    dom_lt = None if views is None else IP.lt_domains(
+        static["ip_u_topo"], static["ip_topo_dom"], static["ip_lt_u"])
+    step = functools.partial(
+        _scan_fn, config, num_zones, num_values, static, dom_lt)
+    (final, _), chosen = jax.lax.scan(step, (carry, views), pods)
+    return final, chosen
 
 
 class BatchScheduler:
@@ -698,17 +753,13 @@ class BatchScheduler:
         key = (num_zones, num_values)
         fn = self._jitted.get(key)
         if fn is None:
-            scan_body = functools.partial(
-                _scan_fn, self.config, num_zones, num_values
-            )
+            config = self.config
 
             @jax.jit
             def batch_scan(static, carry, pods):
                 with jax.named_scope("scan"):
-                    final, chosen = jax.lax.scan(
-                        functools.partial(scan_body, static), carry, pods
-                    )
-                return final, chosen
+                    return scan_backlog(
+                        config, num_zones, num_values, static, carry, pods)
 
             fn = batch_scan
             self._jitted[key] = fn

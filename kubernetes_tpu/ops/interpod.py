@@ -1,10 +1,18 @@
 """Device kernels for inter-pod (anti-)affinity.
 
 Counts live in small `(term-class, domain)` tables threaded through the
-scheduling scan's carry; queries gather each node's domain id and expand
-logical terms by inclusion-exclusion (see snapshot/interpod.py for the
-compilation). Everything is integer arithmetic, bit-identical to the
-oracle (predicates.go:754-947, interpod_affinity.go:86-216).
+scheduling scan's carry; a query reads a table's VIEW, its per-node
+expansion: each node's domain id gathered from the table and the
+logical terms expanded by inclusion-exclusion (see snapshot/interpod.py
+for the compilation). `gather_counts` + `expand_lt` and `gather_lt`
+define a view, and whoever holds a frozen carry (the wave probe, the
+mesh scan, debug_evaluate) derives it with them. The single-chip scan
+gathers the five views once a dispatch (`interpod_views`), carries them
+beside the tables and adds each pick to them on the picked node's
+domain (`interpod_commit_views`, the views' increment; the tables take
+the same pick through `interpod_commit`), so its step gathers nothing.
+Everything is integer arithmetic, bit-identical to the oracle
+(predicates.go:754-947, interpod_affinity.go:86-216).
 
 All kernels are total-shape-robust: with no affinity anywhere in the
 workload every table is zero-width and XLA compiles the whole subsystem
@@ -13,6 +21,9 @@ away (the scheduler_perf benchmark pays nothing for this feature).
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
+import jax
 import jax.numpy as jnp
 
 
@@ -40,6 +51,17 @@ def expand_lt(cnt_u, lt_u, lt_sign, num_nodes):
     return jnp.where((lt_u >= 0)[:, :, None], signed, 0).sum(axis=1)
 
 
+def lt_domains(u_topo, topo_dom, lt_u):
+    """Each node's domain id under every slot's combo, (LT, E, N): the
+    index gather_lt reads a table at, -1 where the node lacks the label.
+    Zero-size without terms or combos."""
+    LT, E = lt_u.shape
+    if LT == 0 or u_topo.shape[0] == 0:
+        return jnp.zeros((LT, E, 0), jnp.int32)
+    q = u_topo[jnp.clip(lt_u, 0, u_topo.shape[0] - 1)]  # (LT, E)
+    return topo_dom[q]
+
+
 def gather_lt(table, u_topo, topo_dom, lt_u, lt_sign):
     """Owned-term table (LT, E, D) -> (LT, N) signed per-node sums.
 
@@ -51,13 +73,35 @@ def gather_lt(table, u_topo, topo_dom, lt_u, lt_sign):
     N = topo_dom.shape[1] if topo_dom.ndim == 2 else 0
     if LT == 0 or u_topo.shape[0] == 0:
         return jnp.zeros((LT, N), table.dtype)
-    q = u_topo[jnp.clip(lt_u, 0, u_topo.shape[0] - 1)]  # (LT, E)
-    dom = topo_dom[q]  # (LT, E, N)
+    dom = lt_domains(u_topo, topo_dom, lt_u)  # (LT, E, N)
     safe = jnp.clip(dom, 0, table.shape[2] - 1)
     vals = jnp.take_along_axis(table[:, :, :], safe, axis=2)  # (LT, E, N)
     valid = (lt_u >= 0)[:, :, None] & (dom >= 0)
     signed = vals * lt_sign[:, :, None].astype(vals.dtype)
     return jnp.where(valid, signed, 0).sum(axis=1)
+
+
+class Views(NamedTuple):
+    """The per-node expansions of the five domain tables, (LT, N) each."""
+
+    cnt_lt: jax.Array  # i32, of term_count (gather_counts + expand_lt)
+    own_lt: jax.Array  # i32, of own_anti (gather_lt, as the next three)
+    rev_hard_lt: jax.Array  # i32
+    rev_pref_lt: jax.Array  # i64
+    rev_anti_lt: jax.Array  # i64
+
+
+def interpod_views(
+    term_count, own_anti, rev_hard, rev_pref, rev_anti,
+    topo_dom, u_topo, lt_u, lt_sign, num_nodes,
+):
+    """The five tables' views, by the definitions above."""
+    cnt_u = gather_counts(term_count, u_topo, topo_dom)
+    return Views(
+        expand_lt(cnt_u, lt_u, lt_sign, num_nodes),
+        *(gather_lt(table, u_topo, topo_dom, lt_u, lt_sign)
+          for table in (own_anti, rev_hard, rev_pref, rev_anti)),
+    )
 
 
 def match_interpod(
@@ -245,3 +289,57 @@ def interpod_commit(
             jnp.int32
         )
     return term_count, own_anti, rev_hard, rev_pref, rev_anti, spec_total
+
+
+def interpod_commit_views(
+    views,
+    dom_lt,  # (LT, E, N) lt_domains
+    width,  # the five tables' domain axis (snapshot/interpod cuts all to one)
+    u_spec,
+    lt_u,
+    lt_sign,
+    pod_match_spec,
+    pod_own_hard,
+    pod_own_pref,
+    pod_own_anti_hard,
+    pod_own_anti_pref,
+    chosen,
+    scheduled,
+):
+    """The increment of the views under interpod_commit: what gathering
+    the committed tables anew would add to each, without a gather.
+
+    interpod_commit adds the pod's share to one entry per (term, slot),
+    the picked node's domain; a node's view reads that entry iff its own
+    domain under the slot's combo is the same one. `hit` is that
+    equality with interpod_commit's validity on the picked side,
+    gather_lt's on the node's and the tables' clip on both, so a picked
+    node without the label adds to no node; the signed sum over a
+    term's slots is shared by the four owner tables, and term_count's
+    weighs each slot with the pod's match on the slot's spec
+    (expand_lt is linear). Integer arithmetic in each view's dtype."""
+    LT, E = lt_u.shape
+    if LT == 0 or dom_lt.shape[2] == 0:
+        return views
+    at = jnp.maximum(chosen, 0)
+    domq = jax.lax.dynamic_slice_in_dim(dom_lt, at, 1, axis=2)  # (LT, E, 1)
+    hit = (
+        (lt_u >= 0)[:, :, None] & (domq >= 0) & (dom_lt >= 0) & scheduled
+        & (jnp.clip(dom_lt, 0, width - 1) == jnp.clip(domq, 0, width - 1)))
+    sign = jnp.where(hit, lt_sign.astype(jnp.int32)[:, :, None], 0)
+    owners = sign.sum(axis=1)  # (LT, N)
+    mu = pod_match_spec[u_spec].astype(jnp.int32)[
+        jnp.clip(lt_u, 0, u_spec.shape[0] - 1)]  # (LT, E)
+
+    def plus(view, share):
+        return view + owners.astype(view.dtype) * share.astype(
+            view.dtype)[:, None]
+
+    return Views(
+        views.cnt_lt + (sign * mu[:, :, None]).sum(axis=1).astype(
+            views.cnt_lt.dtype),
+        plus(views.own_lt, pod_own_anti_hard),
+        plus(views.rev_hard_lt, pod_own_hard),
+        plus(views.rev_pref_lt, pod_own_pref),
+        plus(views.rev_anti_lt, pod_own_anti_pref),
+    )

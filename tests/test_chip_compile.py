@@ -184,6 +184,70 @@ def test_a_pick_of_the_grouped_replay_costs_what_it_changes(served):
                     for op in computations[called.group(1)]), (name, line)
 
 
+@pytest.mark.slow
+def test_the_scans_step_gathers_no_nodes_domain_under_terms(one_chip):
+    """`jit_batch_scan` at podaffinity-2k's own widths (2,000 nodes in
+    2,048 slots, 2 combos, 10 logical terms, 2,000 domains), compiled
+    for the described v5e (some 20 s): the step carries the inter-pod
+    tables' views and adds each pick to them, so its body holds no
+    gather wider than a term row but `select_host`'s `pred[2048]`.
+    Before, seven gathers of 20,480 single values (each some 250 us on
+    the chip: 1.7 of a step's 1.93 ms) and three of `[10, 2048]` rows
+    stood in it. tests/test_interpod_views.py holds the same on the
+    step's jaxpr, in tier 1."""
+    import re
+
+    import jax
+
+    from benchmark import deploy
+    from kubernetes_tpu.client import rest
+    from kubernetes_tpu.models.batch import BatchScheduler, SchedulerConfig
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.parallel.mesh import _pad_snapshot
+    from kubernetes_tpu.snapshot.encode import SnapshotEncoder
+    from kubernetes_tpu.snapshot.pad import next_pow2, pad_batch
+
+    cfg = deploy.load_config("podaffinity-2k")
+    scheme = rest.default_scheme
+    bound = []
+    for i in range(200):
+        pod = scheme.decode(deploy.pod(cfg, i % 10, name=f"held-{i}"))
+        pod.spec.node_name = deploy.node_name(cfg, i * 7 % 2000)
+        bound.append(pod)
+    state = ClusterState.build(
+        [scheme.decode(d) for d in deploy.nodes(cfg)], bound,
+        controllers=[scheme.decode(d) for d in deploy.controllers(cfg)])
+    waiting = [scheme.decode(deploy.pod(cfg, i % 10, name=f"new-{i}"))
+               for i in range(64)]
+    snap, batch = SnapshotEncoder(state, waiting).encode()
+    snap = _pad_snapshot(snap, next_pow2(snap.num_nodes, 64))
+    batch = pad_batch(batch, 64)
+    assert snap.num_nodes == 2048 and snap.ip_lt_u.shape == (10, 1)
+    assert snap.ip_own_anti.shape == (10, 1, 2000)
+    sched = BatchScheduler(SchedulerConfig())
+    args = ({f: np.asarray(getattr(snap, f))
+             for f in BatchScheduler.STATIC_FIELDS},
+            sched.initial_carry(snap),
+            {f: np.asarray(getattr(batch, f))
+             for f in BatchScheduler.POD_FIELDS})
+    text = sched._compiled(3, 0).lower(
+        *_shapes(args, one_chip)).compile().as_text()
+    loops, computations = _loop_bodies(text)
+    (_, step), = loops
+    gathered = []
+    for line in step:
+        called = re.search(r"= (\S+) fusion\(.*calls=%?([\w.\-]+)", line)
+        if called and any(" gather(" in op
+                          for op in computations[called.group(2)]):
+            gathered.append(called.group(1))
+    wide = [shape for shape in gathered
+            if "2048" in shape and not shape.startswith("pred[2048]")
+            or "20480" in shape]
+    assert wide == [], wide
+    assert len(gathered) <= 8, gathered
+    assert len([line for line in step if " fusion(" in line]) <= 170
+
+
 #: seconds the chip's compiler may take over one shipment's unpack
 #: program. The chip's host compiles about three times slower than this
 #: sandbox and the deployment asks for under 30 s there; the uint8
