@@ -8,8 +8,9 @@ A from-scratch re-design of the reference Kubernetes scheduler stack
 - priorities  -> integer score matrices (0..10 per priority, reference math)
 - selection   -> deterministic argmax replicating generic_scheduler.selectHost
                  (score desc, host-name desc, round-robin among ties)
-- the backlog -> a lax.scan that threads resource commitments through the
-                 batch so results are bit-identical to the serial Go loop
+- the backlog -> a jitted loop, a step a pod, that threads resource
+                 commitments through the batch so results are
+                 bit-identical to the serial Go loop
 
 The event-driven shell around the tensor core (list/watch caches, optimistic
 assume with TTL expiry, binding, backoff, events, metrics, leader election)

@@ -204,11 +204,12 @@ def build_programs(include_mesh: bool = True, num_nodes: int = 13,
         ProgramSpec(
             name="scan",
             fn=sched._compiled(num_zones, num_values),
-            args=(static, carry, pods),
+            args=(static, carry, pods, np.int32(batch.num_pods)),
             allow_f64=True,  # reference-exact float64 score normalizers
             carry_out_leaves=carry_leaves,
-            expected_host_leaves=1,  # chosen[P]
-            notes="the serial-equivalent lax.scan fallback path",
+            expected_host_leaves=2,  # chosen[P], the steps the loop ran
+            notes="the serial-equivalent fallback path: one step a pod, "
+                  "the trip count an operand",
         ),
         ProgramSpec(
             name="probe",

@@ -2,10 +2,10 @@
 
 The reference schedules 50k pods as 50k serial scheduleOne cycles
 (scheduler.go:93), each a fresh O(nodes x predicates) CPU scan. Here the
-backlog is a single jitted lax.scan whose carry is the mutable slice of
-the cluster state (requested/nonzero resources, pod counts, port masks,
-per-class pod counts, lastNodeIndex, the inter-pod domain tables) and
-whose per-step body is:
+backlog is a single jitted loop, one step a pod, whose carry is the
+mutable slice of the cluster state (requested/nonzero resources, pod
+counts, port masks, per-class pod counts, lastNodeIndex, the inter-pod
+domain tables) and whose per-step body is:
 
     fit[N]    = AND of predicate masks          (ops.predicates)
     score[N]  = sum_i weight_i * priority_i[N]  (ops.priorities)
@@ -23,6 +23,12 @@ the five inter-pod tables (ops/interpod.Views), made once a dispatch
 from the incoming carry, read by every step in place of a gather and
 committed beside the tables; they are loop-local and dropped when the
 scan returns.
+
+The loop is a `lax.while_loop` over the pod axis, which is what
+`lax.scan` lowers to, with its trip count an operand (`scan_backlog`'s
+`count`): the pod axis is padded to a bucket so that a bucket is one
+program, and the loop ends at the backlog's real length instead of
+running the whole step on every padded row.
 """
 
 from __future__ import annotations
@@ -603,17 +609,46 @@ def _scan_fn(config: SchedulerConfig, num_zones: int, num_values: int, static, d
     return (carry, views), chosen
 
 
-def scan_backlog(config: SchedulerConfig, num_zones: int, num_values: int, static, carry, pods):
-    """The backlog's scan -> (final carry, chosen[P]): the views made
-    once from the incoming carry, the steps, and the carry alone handed
-    back."""
+def _run_steps(step, loop, pods, count):
+    """`step` over rows 0..count-1 of `pods` from `loop`, as `lax.scan`
+    runs it over all of them -> (loop, chosen i32[P], the steps run).
+    `count` is a traced int32, so the loop is a `while` and every count
+    shares the bucket's program. `chosen` starts at -1 and a step writes
+    its own row: past `count` it reads what a padded step (`pad_batch`'s
+    unschedulable rows, which fit nowhere and commit nothing) answered
+    when the loop still ran them."""
+    num_pods = jax.tree.leaves(pods)[0].shape[0]
+    count = jnp.minimum(count, jnp.int32(num_pods))
+
+    def body(state):
+        i, loop, chosen = state
+        pod = jax.tree.map(
+            lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False),
+            pods)
+        loop, pick = step(loop, pod)
+        return i + 1, loop, jax.lax.dynamic_update_index_in_dim(
+            chosen, pick.astype(jnp.int32), i, 0)
+
+    steps, loop, chosen = jax.lax.while_loop(
+        lambda state: state[0] < count, body,
+        (jnp.int32(0), loop, jnp.full((num_pods,), -1, jnp.int32)))
+    return loop, chosen, steps
+
+
+def scan_backlog(config: SchedulerConfig, num_zones: int, num_values: int, static, carry, pods,
+                 count):
+    """The backlog's scan -> (final carry, chosen[P], steps): the views
+    made once from the incoming carry, the first `count` pods' steps
+    (`_run_steps`; `count` an int32 scalar, `P` for all of them), and
+    the carry alone handed back. `chosen[count:]` is -1, and `steps` is
+    the loop's own counter where it stopped."""
     views = interpod_views(config, static, carry)
     dom_lt = None if views is None else IP.lt_domains(
         static["ip_u_topo"], static["ip_topo_dom"], static["ip_lt_u"])
     step = functools.partial(
         _scan_fn, config, num_zones, num_values, static, dom_lt)
-    (final, _), chosen = jax.lax.scan(step, (carry, views), pods)
-    return final, chosen
+    (final, _), chosen, steps = _run_steps(step, (carry, views), pods, count)
+    return final, chosen, steps
 
 
 class BatchScheduler:
@@ -756,10 +791,11 @@ class BatchScheduler:
             config = self.config
 
             @jax.jit
-            def batch_scan(static, carry, pods):
+            def batch_scan(static, carry, pods, count):
                 with jax.named_scope("scan"):
                     return scan_backlog(
-                        config, num_zones, num_values, static, carry, pods)
+                        config, num_zones, num_values, static, carry, pods,
+                        count)
 
             fn = batch_scan
             self._jitted[key] = fn
@@ -797,7 +833,9 @@ class BatchScheduler:
         self, snap: ClusterSnapshot, batch: PodBatch, last_node_index: int = 0
     ):
         """Returns (chosen_node_index[P] int32 with -1 == unschedulable,
-        final_carry). final_carry[LAST_IDX] is the post-wave lastNodeIndex."""
+        final_carry). final_carry[LAST_IDX] is the post-wave lastNodeIndex.
+        The whole batch is scheduled: the loop's trip count
+        (`scan_backlog`'s `count`) is P here."""
         if snap.num_nodes == 0:
             # empty cluster: every pod fails with FitError in the reference
             return (
@@ -810,8 +848,9 @@ class BatchScheduler:
         num_zones = int(snap.zone_id.max()) + 1 if snap.zone_id.size else 1
         # num_zones must cover the vocab; zone ids are dense from encoding
         run = self._compiled(max(num_zones, 1), int(snap.svc_num_values))
-        final, chosen = run(
-            static, self.initial_carry(snap, last_node_index), pods
+        final, chosen, _steps = run(
+            static, self.initial_carry(snap, last_node_index), pods,
+            np.int32(batch.num_pods),
         )
         return np.asarray(chosen), final
 

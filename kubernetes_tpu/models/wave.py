@@ -441,6 +441,13 @@ GROUP_COUNTERS = ("group_runs", "group_d2h_bytes", "group_reprobes")
 #: own counters, and the pods it placed
 ZREPLAY_COUNTERS = ("zreplay_steps", "zreplay_slots", "zreplay_rescores",
                     "zreplay_picks")
+#: what `stats` counts of the scan's loop (`scan_rows`: `flush`, and the
+#: optimizing profile's remainder): the steps `jit_batch_scan` ran, by the loop's
+#: own counter where it stopped, and the steps its pod buckets hold,
+#: which a loop over the padded axis would have run. `scan_steps` over
+#: `pods_by_path["scan"]` is 1.0 while the loop ends at a wave's real
+#: count; `scan_bucket_steps` over `scan_steps` is the padding it skips
+SCAN_COUNTERS = ("scan_steps", "scan_bucket_steps")
 #: what `stats` counts of the runs that carry a self-anti veto (pods
 #: whose required hostname anti-affinity term selects their own labels;
 #: `run_verdict`), which `run_single` decides one probe a run: the
@@ -473,9 +480,10 @@ ENCODERS = ("incremental", "full")
 
 
 def count_group(stats: dict, counted: dict) -> None:
-    """Some of `GROUP_COUNTERS`, `ZREPLAY_COUNTERS`, `ANTI_COUNTERS`,
-    `AFFINITY_COUNTERS` or `REWARM_COUNTERS` into a driver's cumulative
-    `stats`, and into the process-wide totals on /debug/traces."""
+    """Some of `GROUP_COUNTERS`, `ZREPLAY_COUNTERS`, `SCAN_COUNTERS`,
+    `ANTI_COUNTERS`, `AFFINITY_COUNTERS` or `REWARM_COUNTERS` into a
+    driver's cumulative `stats`, and into the process-wide totals on
+    /debug/traces."""
     from kubernetes_tpu.trace.profile import count_wave_group
 
     for key, n in counted.items():
@@ -818,6 +826,8 @@ class WaveScheduler:
             **dict.fromkeys(GROUP_COUNTERS, 0),
             # the grouped device replay (`run_group_device`), all waves
             **dict.fromkeys(ZREPLAY_COUNTERS, 0),
+            # the scan's loop (`scan_rows`), all waves
+            **dict.fromkeys(SCAN_COUNTERS, 0),
             # the runs with a self-anti veto (`run_single`), all waves
             **dict.fromkeys(ANTI_COUNTERS, 0),
             # the runs whose pod owns a required podAffinity term, and
@@ -1167,6 +1177,35 @@ class WaveScheduler:
             self._count("apply")
             return fn(static, carry, buf, jnp.asarray(counts))
 
+    def scan_rows(self, static, carry, batch: PodBatch, reps: np.ndarray,
+                  num_zones: int, num_values: int):
+        """`jit_batch_scan` over rows `reps` of `batch` in their order,
+        padded to their pod bucket (one program a bucket) with the loop
+        ending at their count -> (carry, chosen i32[len(reps)],
+        lastNodeIndex). Counts the dispatch and `SCAN_COUNTERS`."""
+        n = len(reps)
+        seg = pad_batch(gather_batch(batch, reps),
+                        next_pow2(n, self.pod_floor))
+        pods = self._packer.ship({
+            f: np.asarray(getattr(seg, f))
+            for f in BatchScheduler.POD_FIELDS
+        })
+        run = self.scan._compiled(num_zones, num_values)
+        # "score": the fused predicate+priority scan program — the
+        # asarray/int reads force the dispatch so the timer covers
+        # compute, not just enqueue. The count goes in as np.int32
+        # everywhere: another dtype is another program
+        with phase_timer("score"):
+            self._count("scan")
+            carry, chosen, steps = run(static, carry, pods, np.int32(n))
+            with device_wait():
+                chosen = np.asarray(chosen)[:n]
+                last = int(carry[self.LAST_IDX])
+                steps = int(steps)
+        count_group(self.stats, {"scan_steps": steps,
+                                 "scan_bucket_steps": seg.num_pods})
+        return carry, chosen, last
+
     def _count(self, key: str) -> None:
         self.dispatches[key] = self.dispatches.get(key, 0) + 1
         self.stats["dispatches"] += 1
@@ -1323,24 +1362,10 @@ class WaveScheduler:
             carry = settle(carry)
             rows = np.asarray(pending, np.int64)
             via[rows] = _SCAN
-            seg = gather_batch(batch, rep_idx[rows])
-            seg = pad_batch(seg, next_pow2(len(rows), self.pod_floor))
-            pods = self._packer.ship({
-                f: np.asarray(getattr(seg, f))
-                for f in BatchScheduler.POD_FIELDS
-            })
-            run = self.scan._compiled(num_zones, num_values)
-            # "score": the fused predicate+priority scan program — the
-            # asarray/int reads force the dispatch so the timer covers
-            # compute, not just enqueue
-            with phase_timer("score"):
-                self._count("scan")
-                new_carry, chosen = run(static, carry, pods)
-                with device_wait():
-                    out[rows] = np.asarray(chosen)[: len(rows)]
-                    L_host = int(new_carry[self.LAST_IDX])
+            carry, out[rows], L_host = self.scan_rows(
+                static, carry, batch, rep_idx[rows], num_zones, num_values)
             pending.clear()
-            return new_carry
+            return carry
 
         zoned = bool(np.any(np.asarray(snap.zone_id) > 0))
         from kubernetes_tpu.models.pack import pack_arrays

@@ -54,12 +54,10 @@ from kubernetes_tpu.metrics import (
     scheduler_optimizer_waves_total,
 )
 from kubernetes_tpu.models import hosttab
-from kubernetes_tpu.models.batch import BatchScheduler
 from kubernetes_tpu.models.wave import (
     WaveScheduler,
     _host_group_cap,
     config_eligible,
-    gather_batch,
     group_buffer,
     run_verdict,
     run_pure,
@@ -68,8 +66,8 @@ from kubernetes_tpu.scheduler.optimizer.ops.assign import (
     RES_ROWS,
     AssignSolver,
 )
-from kubernetes_tpu.snapshot.pad import next_pow2, pad_batch
-from kubernetes_tpu.trace.profile import device_wait, phase_timer
+from kubernetes_tpu.snapshot.pad import next_pow2
+from kubernetes_tpu.trace.profile import phase_timer
 
 log = logging.getLogger(__name__)
 
@@ -217,19 +215,8 @@ class OptimizingWaveDriver:
         L_host = int(last_node_index) + int(counts_sum)
         if remainder:
             rows = np.asarray(sorted(remainder), np.int64)
-            seg = gather_batch(batch, rep_idx[rows])
-            seg = pad_batch(seg, next_pow2(len(rows), wave.pod_floor))
-            pods = wave._packer.ship({
-                f: np.asarray(getattr(seg, f))
-                for f in BatchScheduler.POD_FIELDS
-            })
-            run = wave.scan._compiled(num_zones, num_values)
-            with phase_timer("score"):
-                wave._count("scan")
-                carry, chosen = run(static, carry, pods)
-                with device_wait():
-                    out[rows] = np.asarray(chosen)[: len(rows)]
-                    L_host = int(carry[wave.LAST_IDX])
+            carry, out[rows], L_host = wave.scan_rows(
+                static, carry, batch, rep_idx[rows], num_zones, num_values)
         return out, carry, L_host
 
     # -- the joint solve -----------------------------------------------------

@@ -465,8 +465,10 @@ _wave_lock = threading.Lock()
 #: in this process, all waves: pods decided by each path, device
 #: programs launched by kind, pods that fitted nowhere, what the
 #: grouped header probe did (models/wave.GROUP_COUNTERS) and what the
-#: grouped device replay's loops ran (models/wave.ZREPLAY_COUNTERS), what
-#: the runs with a self-anti veto did (models/wave.ANTI_COUNTERS), which
+#: grouped device replay's loops ran (models/wave.ZREPLAY_COUNTERS), the
+#: steps the scan's loop ran and its buckets hold
+#: (models/wave.SCAN_COUNTERS), what the runs with a self-anti veto did
+#: (models/wave.ANTI_COUNTERS), which
 #: encoder made each wave's snapshot, which scope gate sent it to the
 #: from-scratch one, and how often the incremental one rebuilt its
 #: inter-pod tables whole, by reason; served on /debug/traces as "wave"
@@ -475,7 +477,8 @@ _WAVE: Dict[str, Any] = {"waves": 0, "pods_by_path": {},
                          "group_runs": 0, "group_d2h_bytes": 0,
                          "group_reprobes": 0, "zreplay_steps": 0,
                          "zreplay_slots": 0, "zreplay_rescores": 0,
-                         "zreplay_picks": 0, "anti_runs": 0,
+                         "zreplay_picks": 0, "scan_steps": 0,
+                         "scan_bucket_steps": 0, "anti_runs": 0,
                          "anti_picks": 0, "anti_nodes_excluded": 0,
                          "waves_by_encoder": {}, "encoder_fallbacks": {},
                          "interpod_rebuilds": {},
@@ -509,8 +512,9 @@ def count_wave_group(counted: Dict[str, int]) -> None:
     bytes it fetched, whether it stopped early (`group_*` of _WAVE); or
     a grouped device replay came back: the steps and run slots its
     loops ran, the steps that rescored, the pods it placed
-    (`zreplay_*`); or a run with a self-anti veto was decided
-    (`anti_*`); or a wave held runs that own a required podAffinity
+    (`zreplay_*`); or a scan came back: the steps its loop ran and its
+    pod bucket holds (`scan_*`); or a run with a self-anti veto was
+    decided (`anti_*`); or a wave held runs that own a required podAffinity
     term (`affinity_*`); or the daemon warmed the scan again
     (`rewarm*`)."""
     with _wave_lock:

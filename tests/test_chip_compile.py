@@ -229,7 +229,8 @@ def test_the_scans_step_gathers_no_nodes_domain_under_terms(one_chip):
              for f in BatchScheduler.STATIC_FIELDS},
             sched.initial_carry(snap),
             {f: np.asarray(getattr(batch, f))
-             for f in BatchScheduler.POD_FIELDS})
+             for f in BatchScheduler.POD_FIELDS},
+            np.int32(batch.num_pods))
     text = sched._compiled(3, 0).lower(
         *_shapes(args, one_chip)).compile().as_text()
     loops, computations = _loop_bodies(text)

@@ -24,6 +24,7 @@ import pytest
 from kubernetes_tpu.models.batch import (
     BatchScheduler,
     SchedulerConfig,
+    _run_steps,
     _scan_fn,
     scan_backlog,
 )
@@ -261,7 +262,8 @@ def _gathered_scan(config, num_zones, num_values, static, carry, pods):
     carry's tables in every step -> (final carry, chosen)."""
     step = functools.partial(
         _scan_fn, config, num_zones, num_values, static, None)
-    (final, views), chosen = jax.lax.scan(step, (carry, None), pods)
+    (final, views), chosen, _steps = _run_steps(
+        step, (carry, None), pods, np.int32(len(pods["class_id"])))
     assert views is None
     return final, chosen
 
@@ -277,8 +279,10 @@ def test_the_scan_picks_as_the_oracle_and_returns_the_gathered_steps_carry(
     assert snap.ip_lt_u.shape[0] >= 5 and snap.ip_term_count.any()
     assert any(np.asarray(t).any() for t in carry[5:9])
     num_zones = max(int(snap.zone_id.max()) + 1, 1)
-    final, chosen = sched._compiled(num_zones, int(snap.svc_num_values))(
-        static, carry, pods)
+    final, chosen, steps = sched._compiled(
+        num_zones, int(snap.svc_num_values))(
+            static, carry, pods, np.int32(len(want)))
+    assert int(steps) == len(want)
     assert [snap.node_names[i] if i >= 0 else None
             for i in np.asarray(chosen)] == want
     assert len(final) == 17 and want.count(None) < len(want) // 2
@@ -307,10 +311,11 @@ def _equations(jaxpr):
 
 
 def _scan_body(fn, *args):
-    """The body of the one `scan` of fn's jaxpr, flattened."""
-    body, = [eqn.params["jaxpr"].jaxpr
+    """The body of the one loop of fn's jaxpr (`_run_steps`'s `while`),
+    flattened."""
+    body, = [eqn.params["body_jaxpr"].jaxpr
              for eqn in _equations(jax.make_jaxpr(fn)(*args).jaxpr)
-             if eqn.primitive.name == "scan"]
+             if eqn.primitive.name == "while"]
     return list(_equations(body))
 
 
@@ -331,7 +336,7 @@ def test_the_steps_jaxpr_gathers_no_nodes_domain(which, seed):
     num_zones = max(int(snap.zone_id.max()) + 1, 1)
     args = (sched.config, num_zones, int(snap.svc_num_values))
     body = _scan_body(functools.partial(scan_backlog, *args),
-                      static, carry, pods)
+                      static, carry, pods, np.int32(1))
     assert _node_gathers(body, snap) == []
 
     # what the step held: the five tables' reads (the chip's compiler
@@ -364,11 +369,18 @@ def test_a_step_without_terms_is_the_step_it_was():
     args = (sched.config, max(int(snap.zone_id.max()) + 1, 1), 0)
 
     now = _scan_body(functools.partial(scan_backlog, *args),
-                     static, carry, pods)
+                     static, carry, pods, np.int32(1))
     was = _scan_body(functools.partial(_gathered_scan, *args),
                      static, carry, pods)
-    empty = [eqn for eqn in was
-             if all(0 in v.aval.shape for v in eqn.outvars)]
-    assert len(now) == len(was) - len(empty)
-    assert not any(0 in v.aval.shape for eqn in now for v in eqn.outvars)
-    assert {e.primitive.name for e in empty} <= {"broadcast_in_dim", "iota"}
+
+    def empty(body):
+        return [eqn for eqn in body
+                if all(0 in v.aval.shape for v in eqn.outvars)]
+
+    # the loop reads a zero-width pod field's row like any other row,
+    # with or without views; the step itself makes nothing zero-size
+    reads = {"dynamic_slice", "squeeze"}
+    assert {e.primitive.name for e in empty(now)} <= reads
+    made = [e for e in empty(was) if e.primitive.name not in reads]
+    assert made and len(now) == len(was) - len(made)
+    assert {e.primitive.name for e in made} <= {"broadcast_in_dim", "iota"}

@@ -770,9 +770,9 @@ def test_two_scans_in_a_row_hand_on_the_class_counts(zones):
     cut = 31
     for rows in (np.arange(cut), np.arange(cut, len(backlog))):
         part = gather_batch(batch, rows)
-        carry, chosen = run(static, carry, {
+        carry, chosen, _steps = run(static, carry, {
             f: jnp.asarray(getattr(part, f))
-            for f in BatchScheduler.POD_FIELDS})
+            for f in BatchScheduler.POD_FIELDS}, np.int32(len(rows)))
         chosen = np.asarray(chosen)
         placed = chosen >= 0
         np.add.at(table, (chosen[placed], part.class_id[placed]), 1)
@@ -1079,8 +1079,17 @@ def test_the_rewarm_builds_every_bucket_once_and_leaves_the_view_alone(
     assert mine[0]["attrs"]["buckets"] == [64, 128, 256, 512, 1024, 2048,
                                            4096]
     assert mine[0]["attrs"]["domains"] == 48
-    # now a wave in every bucket: no scan is built, whatever else is
+    # now a wave in every bucket: no scan is built, whatever else is.
+    # A warm wave fills its bucket and a live one does not: the scan's
+    # trip count is an operand, the same program's (its callers pass it
+    # as one dtype), so the jitted scans hold the entries they held
+    def scans_held():
+        return sum(fn._cache_size()
+                   for fn in algo._wave.scan._jitted.values())
+
     monkeypatch.setenv("KUBERNETES_TPU_WARM_SCAN", "1")
+    warmed = scans_held()
+    assert warmed >= 7
     serial = 1000
     for pods in (10, 100, 200, 400, 900, 1800):
         backlog = _dealt(range(10), pods, serial)
@@ -1096,6 +1105,12 @@ def test_the_rewarm_builds_every_bucket_once_and_leaves_the_view_alone(
                 p.spec.node_name = host
                 cache.add_pod(p)
     assert stats["rewarms"] == 1
+    assert scans_held() == warmed
+    assert stats["scan_steps"] == stats["pods_by_path"]["scan"]
+    # the warm waves filled their buckets, the eight live ones did not
+    assert stats["scan_bucket_steps"] - stats["scan_steps"] \
+        == (64 - 10) + (128 - 70) + (64 - 10) + (128 - 100) + (256 - 200) \
+        + (512 - 400) + (1024 - 900) + (2048 - 1800)
 
 
 def test_a_cluster_without_terms_never_rewarms(monkeypatch):
