@@ -183,7 +183,7 @@ def build_programs(include_mesh: bool = True, num_nodes: int = 13,
     carry = sched.initial_carry(snap)
     carry_leaves = len(jax.tree_util.tree_leaves(carry))
     wave = WaveScheduler(config)
-    # the scan flush pads its pod axis to a pow2 bucket (wave.flush)
+    # the scan pads its pod axis to a pow2 bucket (wave.scan_rows)
     scan_batch = pad_batch(batch,
                            next_pow2(batch.num_pods, wave.pod_floor))
     pods = {f: jnp.asarray(getattr(scan_batch, f))
@@ -571,7 +571,7 @@ def _mesh_programs(config, snap, batch, pod_layout, pod_buf_host,
             arg_shardings=(sspec, cspec, PSpec()),
             out_shardings_decl=PSpec(None, M.AXIS),
             notes="sharded single-run probe "
-                  "(MeshWaveScheduler._probe_run)",
+                  "(MeshWaveScheduler.probe_run)",
         ),
         ProgramSpec(
             name="mesh_apply",

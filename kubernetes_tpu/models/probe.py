@@ -521,7 +521,7 @@ class WaveProbe:
               zone_id: Optional[np.ndarray] = None,
               self_anti_veto: Optional[np.ndarray] = None) -> RunTables:
         """rows (<= J) bounds the j-depth the replay can need (the
-        capacity bound from wave._pick_j, +2 so a node's fit observably
+        capacity bound from waveloop.pick_j, +2 so a node's fit observably
         reaches False before the table horizon). The full packed array
         still crosses the device->host boundary in ONE transfer (a
         dispatch and a transfer each have a fixed cost, so one fat

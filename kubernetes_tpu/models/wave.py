@@ -23,13 +23,17 @@ scan, threading the same carry, so the combined output is bit-identical
 to scanning the whole backlog. Eligibility is per-run and conservative;
 tests/test_wave.py fuzzes equivalence.
 
+This module classifies the runs (`classify_runs` -> `waveloop.Run`)
+and holds the single-chip side of the device seam (`WaveScheduler`);
+how a wave's runs are cut into dispatches and the loop that runs them
+are `models/waveloop`'s, shared with the mesh driver.
+
 Reference hot loop this replaces: generic_scheduler.go:72-135 +
 scheduler.go:122 AssumePod, iterated per pod.
 """
 
 from __future__ import annotations
 
-from dataclasses import replace as dc_replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -50,19 +54,46 @@ from kubernetes_tpu.models.batch import (
     TAINT_TOLERATION,
     BatchScheduler,
     SchedulerConfig,
-    wants_resources,
 )
-from kubernetes_tpu.models import hosttab
-from kubernetes_tpu.models.probe import (
-    RunTables,
-    WaveProbe,
-    tables_from_stk,
+# `hosttab` stays a name of this module: the benchmark's controls reach
+# `resource_tables` through it (tests/benchmark/test_benchmark_shapes.py)
+from kubernetes_tpu.models import hosttab  # noqa: F401
+from kubernetes_tpu.models.pack import Packer, pack_arrays, unpack
+from kubernetes_tpu.models.probe import WaveProbe
+from kubernetes_tpu.models.replay import replay_fast
+# the plan, the loop and what its executors share live in
+# models/waveloop; the names below are part of this module's surface
+from kubernetes_tpu.models.waveloop import (  # noqa: F401
+    PATHS,
+    ZREPLAY_GROUP_K_FLOOR,
+    ZREPLAY_K_FLOOR,
+    Policy,
+    Run,
+    Wave,
+    gather_batch,
+    group_buffer,
+    host_group_cap,
+    host_group_replay,
+    pick_j,
+    replay_k_bucket,
+    run_wave,
 )
-from kubernetes_tpu.models.replay import ReplayResult, replay_fast
+from kubernetes_tpu.models.zreplay import ZReplay
 from kubernetes_tpu.ops.narrow import narrow_dtype
-from kubernetes_tpu.snapshot.encode import ClusterSnapshot, PodBatch
+from kubernetes_tpu.snapshot.encode import (
+    ClusterSnapshot,
+    PodBatch,
+    service_config_labels,
+)
 from kubernetes_tpu.snapshot.pad import next_pow2, pad_batch
-from kubernetes_tpu.trace.profile import device_wait, phase_timer
+from kubernetes_tpu.trace.profile import (
+    count_wave,
+    count_wave_group,
+    count_wave_reasons,
+    count_wave_encoder,
+    device_wait,
+    phase_timer,
+)
 
 _WAVE_PRIORITIES = {
     LEAST_REQUESTED,
@@ -130,8 +161,6 @@ def run_pure(config: SchedulerConfig, batch: PodBatch, i: int,
     per-config invariant (no ServiceAffinity/ServiceAntiAffinity
     labels)."""
     if svc_free is None:
-        from kubernetes_tpu.snapshot.encode import service_config_labels
-
         svc_free = not service_config_labels(config)
     if not svc_free:
         # SA pin ordinals and SAA peer counts are per-probe state
@@ -147,149 +176,6 @@ def run_pure(config: SchedulerConfig, batch: PodBatch, i: int,
             if rows.size and np.any(rows[i] >= 0):
                 return False  # own terms fold into the reverse tables
     return True
-
-
-def group_buffer(batch: PodBatch, reps, floor: int = 8):
-    """Pack a group's run representatives (padded to a pow2 run bucket
-    by repeating the LAST rep — padded slots schedule nothing and their
-    commit counts stay zero) into ONE stacked buffer:
-    -> (G_bucket, layout, uint8 host buffer). Shared by the single-chip
-    and mesh wave drivers: the padding rule is part of the
-    host_group_replay / grouped-fold contract.  The mesh resident
-    driver passes floor=1: its exact host usage mirror lets even a
-    SINGLETON pure run ride the header-only probe (the j-table is a
-    host rebuild, models/hosttab), so padding the run bucket to 8 would
-    octuple the header shipment for nothing."""
-    from kubernetes_tpu.models.pack import pack_arrays
-
-    G_bucket = next_pow2(len(reps), floor=floor)
-    reps = list(reps) + [reps[-1]] * (G_bucket - len(reps))
-    seg = gather_batch(batch, np.asarray(reps, np.int64))
-    layout, buf = pack_arrays({
-        f: np.asarray(getattr(seg, f))
-        for f in BatchScheduler.POD_FIELDS
-    })
-    return G_bucket, layout, buf
-
-
-def gang_score_add(tables: RunTables, add: np.ndarray) -> RunTables:
-    """Fold a per-node additive score row (the heterogeneity-aware
-    throughput term: weight x normalized throughput of the gang's
-    workload class on each node's accelerator type) into a run's
-    tables. static_add is the per-node static score sum the replay
-    reads per pick, so the adjustment is exact — the pick sequence
-    maximizes the combined score including the term."""
-    return dc_replace(tables, static_add=tables.static_add + add)
-
-
-def host_group_replay(config: SchedulerConfig, snap: ClusterSnapshot,
-                      batch: PodBatch, group, headers: np.ndarray,
-                      usage: np.ndarray, replay_fn, perm: np.ndarray,
-                      L_host: int, out: np.ndarray, zoned: bool,
-                      max_j: int, num_zones: int, gang_marks=None):
-    """FIFO host replay of a group of runs from ONE grouped probe.
-
-    group: list of (rep, start, length); headers: i64[G, N_STK_ROWS, N]
-    probed against the pre-group carry; usage: the carry's resource
-    block i64[6, N] at probe time.  Each run's j-axis is rebuilt from
-    the LIVE usage (prior runs' commits folded in — models/hosttab),
-    its spread base is advanced by the prior runs' class commits, and
-    port-conflicting nodes are vetoed — exactly the adjustments a fresh
-    per-run probe would have baked in, so decisions are bit-identical
-    to the serial per-run sequence (tests/test_wave.py fuzz).
-
-    Returns (counts_mat i64[G, N] node-order commits per run, n_full
-    runs completely replayed, partial_done picks of run n_full when it
-    stopped early (0 otherwise), L_host). Shared by the single-chip and
-    mesh wave drivers.
-
-    gang_marks (aligned with `group`; None entries are ordinary runs)
-    makes a run ALL-OR-NOTHING: unless every member gets a node, the
-    gang is parked — no member binds (out stays -1), no commit folds,
-    and the replay continues with the NEXT run against the same state,
-    so a parked gang can never pollute the runs behind it. A mark's
-    optional `score_add` (i64[N]) is the gang's heterogeneity-aware
-    throughput term, folded into the run's static score row."""
-    G = len(group)
-    N = usage.shape[1]
-    usage = usage.astype(np.int64, copy=True)
-    alloc = {
-        f: np.asarray(getattr(snap, f)).astype(np.int64)
-        for f in ("alloc_mcpu", "alloc_mem", "alloc_gpu", "alloc_pods")
-    }
-    zone_arr = np.asarray(snap.zone_id) if zoned else None
-    counts_mat = np.zeros((G, N), np.int64)
-    class_acc: dict = {}  # class id -> accumulated commit counts [N]
-    port_kills: list = []  # (port row, touched mask) of committed runs
-    n_full = 0
-    partial_done = 0
-    for r, (rep, start, length) in enumerate(group):
-        pod = {
-            f: np.asarray(getattr(batch, f))[rep]
-            for f in ("req_mcpu", "req_mem", "req_gpu", "zero_req",
-                      "commit_mcpu", "commit_mem", "commit_gpu",
-                      "nz_mcpu", "nz_mem", "port_mask", "class_id",
-                      "spread_match")
-        }
-        K = length
-        _J, rows = pick_j(config, max_j, snap, batch, rep, K)
-        stk = headers[r].copy()
-        # cross-run host-port conflicts: a prior run's commit holds its
-        # ports on the touched nodes; overlapping wants can't land there
-        for port_row, touched in port_kills:
-            if np.any(port_row & pod["port_mask"]):
-                stk[0] = np.where(touched, 0, stk[0])
-        # spread base advance: prior commits of class c add
-        # spread_match[c] matches per committed copy on that node
-        spread_match = np.asarray(pod["spread_match"])
-        for cls, cnts in class_acc.items():
-            m = int(spread_match[cls]) if cls < spread_match.shape[0] else 0
-            if m:
-                stk[3] = stk[3] + m * cnts
-        res_fit, tab = hosttab.resource_tables(config, pod, alloc, usage,
-                                               rows)
-        tables = tables_from_stk(
-            config, stk, res_fit, tab, num_zones,
-            has_selectors=bool(batch.has_selectors[rep]),
-            zone_id=zone_arr,
-        )
-        gang = gang_marks[r] if gang_marks is not None else None
-        if gang is not None and gang.get("score_add") is not None:
-            tables = gang_score_add(tables, gang["score_add"])
-        res: ReplayResult = replay_fn(_permute_tables(tables, perm), K,
-                                      L_host)
-        if gang is not None and (res.n_done == 0
-                                 or bool((res.chosen < 0).any())):
-            # unfit member: park — no binds, no folds, round-robin
-            # counter untouched; the NEXT run replays against the same
-            # usage/spread/port state a never-attempted gang leaves.
-            # (A gang TABLE-HORIZON partial — n_done < K with every
-            # pick valid — is NOT unfit: it falls through to the
-            # normal partial path below, so the caller re-probes and
-            # continues the gang through run_single, whose gang
-            # failure path erases the whole span before any bind.)
-            n_full += 1
-            continue
-        if res.n_done == 0:
-            break  # no progress through tables: caller re-probes
-        ids = np.where(res.chosen >= 0, perm[res.chosen], -1)
-        out[start:start + res.n_done] = ids.astype(np.int32)
-        counts = np.zeros(N, np.int64)
-        counts[perm] = res.counts
-        counts_mat[r] = counts
-        L_host = res.last_node_index
-        # fold this run's commits into the host-tracked channels
-        usage += np.outer(hosttab.commit_vector(pod), counts)
-        if np.any(pod["port_mask"]):
-            port_kills.append((pod["port_mask"], counts > 0))
-        cls = int(pod["class_id"])
-        prev = class_acc.get(cls)
-        class_acc[cls] = counts if prev is None else prev + counts
-        if res.n_done < K:
-            partial_done = res.n_done
-            break  # table horizon: caller re-probes the remainder
-        n_full += 1
-    return counts_mat, n_full, partial_done, L_host
 
 
 #: why `run_verdict` leaves a run to the serial scan, the keys of
@@ -376,61 +262,6 @@ def run_verdict(config: SchedulerConfig, batch: PodBatch, i: int,
     return None, veto
 
 
-def _host_group_cap(num_nodes: int) -> int:
-    """How many runs one grouped header probe may carry: bounds the
-    device->host shipment (N_STK_ROWS i64 rows per run) to ~32 MB, so
-    one transfer stays cheap next to the dispatch it rides with."""
-    return max(8, min(256, (1 << 25) // max(num_nodes * 96, 1)))
-
-
-def pick_j(config: SchedulerConfig, max_j: int, snap: ClusterSnapshot,
-           batch: PodBatch, rep: int, K: int) -> Tuple[int, int]:
-    """-> (J, rows). J is the compiled table depth (pow2-bucketed
-    for compile reuse); rows <= J is the replay's table horizon —
-    the capacity bound +2, so the most capacious node's fit
-    observably goes False inside the table instead of tripping the
-    horizon bail (which would force a full re-probe of the
-    remaining run). The probe ships the full packed J-table in one
-    transfer and clips to `rows` host-side (transfer is latency-
-    bound, not bandwidth-bound); `rows` exists to bound the replay
-    and keep the host tables small. Computed from the run-start
-    snapshot only — commits monotonically shrink every node's
-    remaining capacity, so this stays an upper bound for the whole
-    backlog (no device sync). Shared by the single-chip and mesh
-    wave drivers."""
-    alloc_pods = np.asarray(snap.alloc_pods)
-    if not alloc_pods.size:
-        return 16, 16
-    if not wants_resources(config):
-        # no PodFitsResources: nothing enforces the capacity bound,
-        # res_fit never goes False, and clipping rows below J would
-        # horizon-bail (and re-probe) every `rows` picks
-        J = next_pow2(min(K + 1, max_j), floor=128)
-        return J, J
-    cap = np.maximum(alloc_pods - np.asarray(snap.pod_count), 0)
-    # the commit vector shrinks cpu/mem headroom too (a fit at j
-    # implies j*commit + request <= alloc); use whichever bound is
-    # tightest so the table stays small
-    for commit, alloc, used in (
-        (int(batch.commit_mcpu[rep]), snap.alloc_mcpu, snap.req_mcpu),
-        (int(batch.commit_mem[rep]), snap.alloc_mem, snap.req_mem),
-    ):
-        if commit > 0:
-            room = np.maximum(np.asarray(alloc) - np.asarray(used), 0)
-            cap = np.minimum(cap, room // commit + 1)
-    depth = min(K, int(cap.max()) + 1) + 1
-    # floor 128: one probe program serves every wave size (a small
-    # K would otherwise compile J=16/32/64 variants for nothing)
-    J = next_pow2(min(depth, max_j), floor=128)
-    return J, min(depth, J)
-
-
-#: which path decided a pod, in the order of `stats["pods_by_path"]`:
-#: the serial scan program (`flush`), a run's own probe or device
-#: replay (`run_single`), a grouped header probe replayed on the host,
-#: a grouped device replay
-PATHS = ("scan", "single", "group_host", "group_device")
-_SCAN, _SINGLE, _GROUP_HOST, _GROUP_DEVICE = range(len(PATHS))
 #: what `stats` counts of the grouped header probe, in both drivers
 GROUP_COUNTERS = ("group_runs", "group_d2h_bytes", "group_reprobes")
 #: what `stats` counts of the grouped device replay (the single-chip
@@ -441,8 +272,8 @@ GROUP_COUNTERS = ("group_runs", "group_d2h_bytes", "group_reprobes")
 #: own counters, and the pods it placed
 ZREPLAY_COUNTERS = ("zreplay_steps", "zreplay_slots", "zreplay_rescores",
                     "zreplay_picks")
-#: what `stats` counts of the scan's loop (`scan_rows`: `flush`, and the
-#: optimizing profile's remainder): the steps `jit_batch_scan` ran, by the loop's
+#: what `stats` counts of the scan's loop (`scan_rows`: the loop's `flush`,
+#: and the optimizing profile's remainder): the steps `jit_batch_scan` ran, by the loop's
 #: own counter where it stopped, and the steps its pod buckets hold,
 #: which a loop over the padded axis would have run. `scan_steps` over
 #: `pods_by_path["scan"]` is 1.0 while the loop ends at a wave's real
@@ -450,7 +281,7 @@ ZREPLAY_COUNTERS = ("zreplay_steps", "zreplay_slots", "zreplay_rescores",
 SCAN_COUNTERS = ("scan_steps", "scan_bucket_steps")
 #: what `stats` counts of the runs that carry a self-anti veto (pods
 #: whose required hostname anti-affinity term selects their own labels;
-#: `run_verdict`), which `run_single` decides one probe a run: the
+#: `run_verdict`), which `waveloop.run_single` decides one probe a run: the
 #: runs, the pods they placed, and summed over the runs the real nodes
 #: the run's FIRST probe found unfit, on the tables it shipped (where
 #: nothing but the terms of bound pods excludes a node, how much of the
@@ -484,8 +315,6 @@ def count_group(stats: dict, counted: dict) -> None:
     `ANTI_COUNTERS`, `AFFINITY_COUNTERS` or `REWARM_COUNTERS` into a
     driver's cumulative `stats`, and into the process-wide totals on
     /debug/traces."""
-    from kubernetes_tpu.trace.profile import count_wave_group
-
     for key, n in counted.items():
         stats[key] += n
     count_wave_group(counted)
@@ -500,8 +329,6 @@ def count_encoder(stats: dict, encoder: str,
     times, by reason (what its deltas do not cover:
     snapshot/interpod.InterPodTables). Into a driver's cumulative
     `stats` and the process-wide totals on /debug/traces."""
-    from kubernetes_tpu.trace.profile import count_wave_encoder
-
     stats["waves_by_encoder"][encoder] += 1
     if fallback:
         by_reason = stats["encoder_fallbacks"]
@@ -512,20 +339,6 @@ def count_encoder(stats: dict, encoder: str,
     count_wave_encoder(encoder, fallback, rebuilds)
 
 
-#: pick-buffer length floors of the zoned device replay: one run per
-#: dispatch pads to 256; the grouped form keeps K picks PER RUN SLOT, so
-#: its padding costs G times over and the floor is lower. The buckets
-#: bound the compiled shapes; the pick loop ends at a run's real length
-ZREPLAY_K_FLOOR = 256
-ZREPLAY_GROUP_K_FLOOR = 64
-
-
-def replay_k_bucket(length: int, floor: int) -> int:
-    """The compiled pick-buffer length for a device-replayed run (or a
-    group's longest run) of `length` pods."""
-    return next_pow2(min(length, 1 << 16), floor=floor)
-
-
 def svc_run_context(config: SchedulerConfig, snap: ClusterSnapshot,
                     batch: PodBatch, rep: int, num_values: int):
     """The host-side service context for one run (SA/SAA policy
@@ -533,8 +346,6 @@ def svc_run_context(config: SchedulerConfig, snap: ClusterSnapshot,
     ServiceAffinity first-pick pin and the ServiceAntiAffinity per-pick
     renormalization in the replay. None when the config has no service
     terms. Shared by the single-chip and mesh wave drivers."""
-    from kubernetes_tpu.snapshot.encode import service_config_labels
-
     svc_labels = service_config_labels(config)
     if not svc_labels:
         return None
@@ -590,20 +401,23 @@ def split_runs(rep_idx: np.ndarray,
 
 
 def classify_runs(config: SchedulerConfig, snap: ClusterSnapshot,
-                  batch: PodBatch, runs, num_values: int, min_run: int,
+                  batch: PodBatch, spans, num_values: int, min_run: int,
                   *, device_zoned: bool = False, zoned: bool = False,
-                  gang_starts: frozenset = frozenset()) -> List[dict]:
-    """Classify every run once: eligibility, the self-anti veto, the
-    service context, the device-replay route, and commit purity
-    (whether a grouped probe's host adjustments can cover its commits).
-    Shared by the single-chip and mesh wave drivers — the classification
-    IS the dispatch-shape contract, so the two drivers can never drift."""
-    from kubernetes_tpu.snapshot.encode import service_config_labels
-
+                  gangs: Sequence[dict] = ()) -> List[Run]:
+    """Classify every span of `split_runs` once, into a finished `Run`:
+    eligibility, the self-anti veto, the service context, the
+    device-replay route, commit purity (whether a grouped probe's host
+    adjustments can cover its commits) and the gang it is. Shared by
+    the single-chip and mesh wave drivers — the classification IS the
+    dispatch-shape contract: `waveloop.next_step` cuts a wave's steps
+    from these `Run`s and a `Policy` and from nothing else, and nothing
+    changes a `Run` once it is made, so the two drivers cannot drift."""
     config_ok = config_eligible(config)
     svc_free = not service_config_labels(config)
-    infos: List[dict] = []
-    for rep, start, length in runs:
+    gang_by_start = {int(g["start"]): g for g in gangs}
+    runs: List[Run] = []
+    for rep, start, length in spans:
+        gang = gang_by_start.get(start)
         # `refused`: why a run long enough for the run machinery goes
         # to the scan all the same (None for an eligible run and for
         # one that is merely short)
@@ -611,43 +425,46 @@ def classify_runs(config: SchedulerConfig, snap: ClusterSnapshot,
         # a gang span takes the run machinery at ANY length (typical
         # gangs are 2-16 pods, under the default min_run): the probe/
         # replay path is where the all-or-nothing commit is enforced
-        if length >= min_run or start in gang_starts:
+        if length >= min_run or gang is not None:
             refused, veto = run_verdict(
                 config, batch, rep, snap, config_ok=config_ok,
             )
             eligible = refused is None
+        if not (gang is not None and eligible and length == gang["length"]):
+            # a span the driver can't take atomically (mixed member
+            # templates or ineligible features) schedules plainly; the
+            # director's post-hoc check guards the binds
+            gang = None
         svc_ctx = svc_run_context(
             config, snap, batch, rep, num_values
         ) if eligible else None
-        device = bool(
-            eligible and device_zoned and zoned
-            and bool(batch.has_selectors[rep]) and svc_ctx is None
-        )
-        pure = bool(
-            eligible and veto is None and svc_ctx is None
-            and run_pure(config, batch, rep, svc_free=svc_free)
-        )
-        infos.append({
-            "rep": rep, "start": start, "length": length,
-            "eligible": eligible, "veto": veto, "svc_ctx": svc_ctx,
-            "device": device, "pure": pure, "refused": refused,
-        })
-    return infos
+        runs.append(Run(
+            rep, start, length, eligible=eligible, refused=refused,
+            veto=veto, svc_ctx=svc_ctx,
+            # an atomic gang takes the host probe/replay path only (the
+            # device zoned replay folds commits in-program and cannot
+            # discard a partial gang)
+            device=bool(
+                eligible and device_zoned and zoned and gang is None
+                and bool(batch.has_selectors[rep]) and svc_ctx is None),
+            pure=bool(
+                eligible and veto is None and svc_ctx is None
+                and run_pure(config, batch, rep, svc_free=svc_free)),
+            gang=gang,
+        ))
+    return runs
 
 
 def count_runs(stats: dict, snap: ClusterSnapshot, batch: PodBatch,
-               infos: Sequence[dict]) -> None:
+               runs: Sequence[Run]) -> None:
     """What a wave's classification says of its runs, into a driver's
     cumulative `stats` and the process-wide totals on /debug/traces, at
     the wave's end beside `pods_by_path`: `scan_reasons` ({reason: the
     pods of the runs `run_verdict` refused}) and AFFINITY_COUNTERS."""
-    from kubernetes_tpu.trace.profile import count_wave_reasons
-
     reasons: Dict[str, int] = {}
-    for info in infos:
-        if info["refused"] is not None:
-            reasons[info["refused"]] = \
-                reasons.get(info["refused"], 0) + info["length"]
+    for run in runs:
+        if run.refused is not None:
+            reasons[run.refused] = reasons.get(run.refused, 0) + run.length
     if reasons:
         tally = stats["scan_reasons"]
         for reason, n in reasons.items():
@@ -657,15 +474,15 @@ def count_runs(stats: dict, snap: ClusterSnapshot, batch: PodBatch,
     if not owners.any():
         return
     excluded: Dict[int, int] = {}  # by pod row: a wave's runs repeat it
-    runs = nodes = 0
-    for info in infos:
-        rep = info["rep"]
+    owned = nodes = 0
+    for run in runs:
+        rep = run.rep
         if owners[rep]:
             if rep not in excluded:
                 excluded[rep] = affinity_nodes_excluded(snap, batch, rep)
-            runs += 1
+            owned += 1
             nodes += excluded[rep]
-    count_group(stats, {"affinity_runs": runs,
+    count_group(stats, {"affinity_runs": owned,
                         "affinity_nodes_excluded": nodes})
 
 
@@ -700,59 +517,40 @@ def affinity_nodes_excluded(snap: ClusterSnapshot, batch: PodBatch,
     return int(np.count_nonzero(out & (np.asarray(snap.alloc_pods) > 0)))
 
 
-def gather_batch(batch: PodBatch, rows: np.ndarray) -> PodBatch:
-    """Materialize per-position rows from the unique-representative
-    batch (fancy-index every pod-axis array)."""
-    import dataclasses
+class WaveCounts:
+    """The tallies both wave drivers keep: `dispatches`, the device
+    programs of the wave in hand by kind, and `stats`, all waves."""
 
-    fields = {}
-    for f in dataclasses.fields(batch):
-        v = getattr(batch, f.name)
-        if f.name == "pod_keys":
-            fields[f.name] = [v[r] for r in rows]
-        elif isinstance(v, np.ndarray) and v.ndim >= 1 and v.shape[0] == batch.num_pods:
-            fields[f.name] = v[rows]
-        else:
-            fields[f.name] = v
-    return dc_replace(batch, **fields)
+    dispatches: dict
+    stats: dict
 
+    def _count(self, key: str) -> None:
+        self.dispatches[key] = self.dispatches.get(key, 0) + 1
+        self.stats["dispatches"] += 1
+        by_kind = self.stats["dispatches_by_kind"]
+        by_kind[key] = by_kind.get(key, 0) + 1
 
-def _permute_tables(t: RunTables, perm: np.ndarray) -> RunTables:
-    def p1(a):
-        return None if a is None else a[perm]
-
-    return RunTables(
-        fit_static=t.fit_static[perm],
-        res_fit=t.res_fit[:, perm],
-        tab=t.tab[:, perm],
-        static_add=t.static_add[perm],
-        w_spread=t.w_spread,
-        spread_base=p1(t.spread_base),
-        spread_selfmatch=t.spread_selfmatch,
-        has_selectors=t.has_selectors,
-        zone_id=p1(t.zone_id),
-        num_zones=t.num_zones,
-        w_na=t.w_na,
-        na_counts=p1(t.na_counts),
-        w_tt=t.w_tt,
-        tt_counts=p1(t.tt_counts),
-        w_ip=t.w_ip,
-        ip_totals=p1(t.ip_totals),
-        w_saa=t.w_saa,
-        saa_counts=p1(t.saa_counts),
-        saa_total=t.saa_total,
-        saa_lbl_val=p1(t.saa_lbl_val),
-        saa_num_values=t.saa_num_values,
-        saa_member=t.saa_member,
-        sa_refine_rows=(None if t.sa_refine_rows is None
-                        else t.sa_refine_rows[:, perm]),
-        sa_bail=t.sa_bail,
-    )
+    def _count_wave(self, wave: Wave, runs: Sequence[Run]) -> None:
+        """A finished wave into the cumulative tallies, here and on
+        /debug/traces (trace/profile.wave_totals): the pods each path
+        decided, what its steps tallied and what its classification
+        says of its runs, all at once (`Wave.tallies` says why)."""
+        pods = dict(zip(PATHS, np.bincount(wave.via, minlength=len(PATHS))
+                        .tolist()))
+        unplaced = int(np.count_nonzero(wave.out < 0))
+        for path, n in pods.items():
+            self.stats["pods_by_path"][path] += n
+        self.stats["pods_unplaced"] += unplaced
+        count_wave(pods, self.dispatches, unplaced)
+        if wave.tallies:
+            count_group(self.stats, dict(wave.tallies))
+        count_runs(self.stats, wave.snap, wave.batch, runs)
 
 
-class WaveScheduler:
+class WaveScheduler(WaveCounts):
     """Schedules an encoded backlog (unique rows + per-position rep
-    index) bit-identically to the serial scan, fast-pathing runs."""
+    index) bit-identically to the serial scan, fast-pathing runs: the
+    single-chip side of `waveloop.run_wave`'s device seam."""
 
     LAST_IDX = BatchScheduler.LAST_IDX
 
@@ -768,7 +566,8 @@ class WaveScheduler:
         self._replay = replay or replay_fast
         self._apply_packed_jit: dict = {}
         self._apply_group_jit: dict = {}
-        self._zreplay = None
+        self._zreplay = ZReplay(self.config, self._apply_fn,
+                                self._apply_group_fn)
         # per-wave device-dispatch tally (tests assert the grouped path
         # keeps this independent of the template count)
         self.dispatches: dict = {}
@@ -778,8 +577,6 @@ class WaveScheduler:
         # can't bucket and numpy pays ~0.4ms/pick for. Opt out (e.g.
         # for differential testing of the host path) via replay=.
         self._device_zoned = replay is None
-        from kubernetes_tpu.models.pack import Packer
-
         self._packer = Packer()
         # device-resident snapshot fields across waves (the mesh path's
         # resident/mirror design, single-chip): field ->
@@ -818,13 +615,13 @@ class WaveScheduler:
             "pods_by_path": dict.fromkeys(PATHS, 0),
             "pods_unplaced": 0,
             # the grouped header probe replayed on the host
-            # (`run_group_host`), all waves: the runs that went through
-            # it, the bytes its probes fetched from the device (every
-            # run slot's header rows and the resource block), and the
-            # groups that stopped before their last run and sent one to
-            # `run_single`, which costs a probe of its own
+            # (`waveloop.run_group_host`), all waves: the runs that went
+            # through it, the bytes its probes fetched from the device
+            # (every run slot's header rows and the resource block), and
+            # the groups that stopped before their last run and sent one
+            # to `run_single`, which costs a probe of its own
             **dict.fromkeys(GROUP_COUNTERS, 0),
-            # the grouped device replay (`run_group_device`), all waves
+            # the grouped device replay (`replay_group_device`), all waves
             **dict.fromkeys(ZREPLAY_COUNTERS, 0),
             # the scan's loop (`scan_rows`), all waves
             **dict.fromkeys(SCAN_COUNTERS, 0),
@@ -1066,56 +863,13 @@ class WaveScheduler:
             dev[f] for f in self._CARRY_FIELDS[2:]
         )
 
-    def _run_device_replay(self, static, carry, prev_buf, prev_counts,
-                           buf, layout, num_zones, num_values, J, rows,
-                           K, snap, perm, self_anti_veto, batch, rep,
-                           L_host):
-        """Zoned-spread runs: probe + pick sequence + commit fold in one
-        device dispatch (models/zreplay). Returns (carry', ReplayResult
-        in permuted space — same contract as the host replays); the
-        run's commits are ALREADY folded into carry'."""
-        from kubernetes_tpu.models.replay import ReplayResult
-        from kubernetes_tpu.models.zreplay import ZReplay
-
-        if self._zreplay is None:
-            self._zreplay = ZReplay(self.config, self._apply_fn,
-                                    self._apply_group_fn)
-        N = snap.num_nodes
-        zone_perm = np.ascontiguousarray(
-            np.asarray(snap.zone_id)[perm], np.int32
-        )
-        veto = np.zeros(N, bool)
-        if self_anti_veto is not None:
-            veto = np.asarray(self_anti_veto)
-        veto_perm = np.ascontiguousarray(veto[perm])
-        K_bucket = replay_k_bucket(K, ZREPLAY_K_FLOOR)
-        k_real = min(K, K_bucket)
-        carry, chosen, _counts, L, n_done = self._zreplay.run(
-            static, carry, prev_buf, prev_counts, buf, layout,
-            num_zones, num_values, J, K_bucket, zone_perm, veto_perm,
-            bool(batch.has_selectors[rep]), rows, k_real, L_host,
-        )
-        with device_wait():
-            chosen = np.asarray(chosen)
-            n_done = int(n_done)
-            L = int(L)
-        return carry, ReplayResult(
-            chosen=chosen[:n_done],
-            counts=None,  # already folded on device
-            n_done=n_done,
-            last_node_index=L,
-            scheduled=int((chosen[:n_done] >= 0).sum()),
-        )
-
     def _apply_packed(self, static, carry, buf, layout, counts):
         """The commit fold from a PACKED pod-row buffer — the settle
         path when no further probe will carry the fold for free."""
         fn = self._apply_packed_jit.get(layout)
         if fn is None:
-            from kubernetes_tpu.models.pack import unpack as _unpack_pod
-
             def wave_apply_packed(static_, carry_, buf_, counts_):
-                pod = _unpack_pod(layout, buf_)
+                pod = unpack(layout, buf_)
                 return self._apply_fn(static_, carry_, pod, counts_)
 
             fn = jax.jit(wave_apply_packed)
@@ -1135,9 +889,7 @@ class WaveScheduler:
         only carry channels their commits touch — the ip/vol/svc blocks
         pass through untouched, exactly as G zero-commit _apply_fn
         folds would have left them."""
-        from kubernetes_tpu.models.pack import unpack as _unpack_pod
-
-        pods = _unpack_pod(layout, buf)
+        pods = unpack(layout, buf)
         (res, port_mask, class_count, last_idx), rest = (
             carry[:4], carry[4:]
         )
@@ -1206,30 +958,7 @@ class WaveScheduler:
                                  "scan_bucket_steps": seg.num_pods})
         return carry, chosen, last
 
-    def _count(self, key: str) -> None:
-        self.dispatches[key] = self.dispatches.get(key, 0) + 1
-        self.stats["dispatches"] += 1
-        by_kind = self.stats["dispatches_by_kind"]
-        by_kind[key] = by_kind.get(key, 0) + 1
-
-    def _count_wave(self, via: np.ndarray, out: np.ndarray) -> None:
-        """A finished wave into the cumulative tallies, here and on
-        /debug/traces (trace/profile.wave_totals)."""
-        from kubernetes_tpu.trace.profile import count_wave
-
-        pods = dict(zip(PATHS, np.bincount(via, minlength=len(PATHS))
-                        .tolist()))
-        unplaced = int(np.count_nonzero(out < 0))
-        for path, n in pods.items():
-            self.stats["pods_by_path"][path] += n
-        self.stats["pods_unplaced"] += unplaced
-        count_wave(pods, self.dispatches, unplaced)
-
     # -- backlog -------------------------------------------------------------
-
-    def _pick_j(self, snap: ClusterSnapshot, batch: PodBatch, rep: int,
-                K: int) -> Tuple[int, int]:
-        return pick_j(self.config, self.max_j, snap, batch, rep, K)
 
     def _wave_setup(self, snap: ClusterSnapshot, keep: frozenset,
                     source: str, last_node_index: int,
@@ -1311,377 +1040,185 @@ class WaveScheduler:
         applies an unconditional post-hoc all-or-nothing check over
         the returned hosts before anything binds. None/[] = no gangs,
         and the wave is bit-identical to the pre-gang driver."""
-        static, carry, num_zones, num_values = self._wave_setup(
+        wave = Wave(self.config, snap, batch, rep_idx, int(last_node_index),
+                    self.max_j, self._replay)
+        wave.static, wave.carry, _zones, _values = self._wave_setup(
             snap, keep, source, last_node_index, reship)
-        P = len(rep_idx)
-        out = np.full(P, -1, np.int32)
-        # the path that decided each position (an index into PATHS): a
-        # fast path marks its span when it takes it, and what it hands
-        # on to `pending` is marked again by the scan that decides it
-        via = np.full(P, _SCAN, np.int8)
-        perm = np.asarray(snap.name_desc_order).astype(np.int64)
-        N = snap.num_nodes
+        runs, policy = self.plan(wave, gangs)
+        run_wave(self, wave, runs, policy)
+        self._count_wave(wave, runs)
+        return wave.out, wave.carry, wave.L_host
 
-        # maximal runs of consecutive equal reps; gang spans force
-        # their own run boundaries so all-or-nothing covers exactly
-        # the gang's members
-        gang_by_start: dict = {}
-        boundaries: List[int] = []
-        for g in (gangs or ()):
-            gang_by_start[int(g["start"])] = g
-            boundaries += [int(g["start"]),
-                           int(g["start"]) + int(g["length"])]
-        runs = split_runs(rep_idx, boundaries)
-
-        pending: List[int] = []
-        # lastNodeIndex is tracked host-side (the replay computes it
-        # exactly) so the fast path never blocks on the device carry
-        L_host = int(last_node_index)
-        # deferred commit fold: ("single", buf, layout, counts[N]) or
-        # ("group", buf, layout, counts[G, N]). A run's (or group's)
-        # apply rides the NEXT probe's dispatch — each dispatch has a
-        # fixed cost, so deferring halves the per-run dispatch count
-        # for multi-template backlogs
-        fold: list = []
-
-        def settle(carry):
-            if fold:
-                kind, buf, layout, counts = fold.pop()
-                if kind == "single":
-                    carry = self._apply_packed(static, carry, buf,
-                                               layout, counts)
-                else:
-                    carry = self._apply_group_packed(static, carry, buf,
-                                                     layout, counts)
-            return carry
-
-        def flush(carry):
-            nonlocal L_host
-            if not pending:
-                return carry
-            carry = settle(carry)
-            rows = np.asarray(pending, np.int64)
-            via[rows] = _SCAN
-            carry, out[rows], L_host = self.scan_rows(
-                static, carry, batch, rep_idx[rows], num_zones, num_values)
-            pending.clear()
-            return carry
-
-        zoned = bool(np.any(np.asarray(snap.zone_id) > 0))
-        from kubernetes_tpu.models.pack import pack_arrays
-
-        # classify every run once (shared with the mesh driver)
-        infos = classify_runs(
-            self.config, snap, batch, runs, num_values, self.min_run,
-            device_zoned=self._device_zoned, zoned=zoned,
-            gang_starts=frozenset(gang_by_start),
+    def plan(self, wave: Wave, gangs: Optional[Sequence[dict]] = None
+             ) -> Tuple[List[Run], Policy]:
+        """The wave's runs, classified, and the policy that cuts them
+        into steps (`waveloop.plan_steps` prints the plan): host work
+        on the wave's inputs alone, no device. Gang spans force their
+        own run boundaries so all-or-nothing covers exactly the gang's
+        members."""
+        gangs = list(gangs or ())
+        cuts = [int(g["start"]) + off
+                for g in gangs for off in (0, int(g["length"]))]
+        runs = classify_runs(
+            self.config, wave.snap, wave.batch,
+            split_runs(wave.rep_idx, cuts), wave.num_values, self.min_run,
+            device_zoned=self._device_zoned, zoned=wave.zoned, gangs=gangs,
         )
-        for info in infos:
-            g = gang_by_start.get(info["start"])
-            if g is not None and info["length"] == g["length"] \
-                    and info["eligible"]:
-                # atomic in-driver gang: host probe/replay path only
-                # (the device zoned replay folds commits in-program and
-                # cannot discard a partial gang)
-                info["gang"] = g
-                info["device"] = False
-            else:
-                # span the driver can't take atomically (mixed member
-                # templates or ineligible features): schedules plainly;
-                # the director's post-hoc check guards the binds
-                info["gang"] = None
+        return runs, Policy(host_group_cap(wave.N))
 
-        # what the wave's runs with a self-anti veto did (ANTI_COUNTERS),
-        # into `stats` with the wave's other tallies at its end: a read
-        # in the middle of a wave never finds picks ahead of the pods
-        # decided (one chip run's `anti_run_share.fill` read 101.4)
-        anti = dict.fromkeys(ANTI_COUNTERS, 0)
+    # -- the device seam (models/waveloop.run_wave) --------------------------
 
-        def run_single(carry, info, done0=0):
-            """The per-run fast path: probe_fused (or the single-run
-            device replay) + host replay + deferred fold — one device
-            round trip per re-probe, exactly the pre-grouping shape."""
-            nonlocal L_host
-            rep, start, length = info["rep"], info["start"], info["length"]
-            self_anti_veto = info["veto"]
-            svc_ctx = info["svc_ctx"]
-            layout, buf = pack_arrays({
-                f: np.asarray(getattr(batch, f)[rep])
-                for f in BatchScheduler.POD_FIELDS
-            })
-            done = done0
-            via[start + done:start + length] = _SINGLE
-            while done < length:
-                K = length - done
-                J, rows = self._pick_j(snap, batch, rep, K)
-                prev_buf = prev_counts = None
-                if fold:
-                    kind, fbuf, flayout, fcounts = fold[0]
-                    if kind == "single" and flayout == layout:
-                        fold.pop()
-                        prev_buf, prev_counts = fbuf, fcounts
-                    else:  # grouped fold or layout drift: settle apart
-                        carry = settle(carry)
-                if info["device"]:
-                    with phase_timer("replay"):
-                        self._count("zreplay")
-                        carry, res = self._run_device_replay(
-                            static, carry, prev_buf, prev_counts, buf,
-                            layout, num_zones, num_values, J, rows, K,
-                            snap, perm, self_anti_veto, batch, rep,
-                            L_host,
-                        )
-                    if res.n_done == 0:
-                        pending.extend(
-                            range(start + done, start + length))
-                        break
-                    ids = np.where(
-                        res.chosen >= 0, perm[res.chosen], -1)
-                    out[start + done:
-                        start + done + res.n_done] = ids.astype(np.int32)
-                    L_host = res.last_node_index
-                    done += res.n_done
-                    continue
-                with phase_timer("probe"):
-                    self._count("probe")
-                    carry, tables = self.probe.probe_fused(
-                        static, carry, prev_buf, prev_counts, buf,
-                        num_zones, num_values, J, rows, layout,
-                        self._apply_fn,
-                        has_selectors=bool(batch.has_selectors[rep]),
-                        zone_id=(np.asarray(snap.zone_id)
-                                 if zoned else None),
-                        self_anti_veto=self_anti_veto,
-                        svc_ctx=svc_ctx,
-                    )
-                if self_anti_veto is not None and done == done0:
-                    # the run's first probe: the nodes it finds unfit (a
-                    # slot without allocatable is padding or a node gone)
-                    real = np.asarray(snap.alloc_pods) > 0
-                    anti["anti_nodes_excluded"] += int(np.count_nonzero(
-                        real & ~(tables.fit_static & tables.res_fit[0])))
-                if tables.sa_bail:
-                    # ServiceAffinity dynamics the tables can't express
-                    # (mid-run re-pin hazard): scan the rest of the run
-                    # (a gang here schedules via the scan; the
-                    # director's post-hoc check still guards its binds)
-                    pending.extend(range(start + done, start + length))
-                    break
-                if info["gang"] is not None and \
-                        info["gang"].get("score_add") is not None:
-                    tables = gang_score_add(tables,
-                                            info["gang"]["score_add"])
-                with phase_timer("replay"):
-                    res: ReplayResult = self._replay(
-                        _permute_tables(tables, perm), K, L_host
-                    )
-                if info["gang"] is not None and (
-                        res.n_done == 0 or bool((res.chosen < 0).any())):
-                    # all-or-nothing: park the gang — no member binds
-                    # and THIS segment folds nothing. Erase the whole
-                    # span: earlier horizon segments (rare — the +2
-                    # table-depth rule makes resource-bounded runs fit-
-                    # bail inside the table) may have written picks and
-                    # folded counts; the picks are discarded here and
-                    # the folded counts remain only as conservative
-                    # in-wave phantom usage — no binds happen, so the
-                    # next wave starts from clean cluster state.
-                    out[start:start + length] = -1
-                    break
-                # a gang table-horizon partial (n_done < K, all picks
-                # valid) falls through: write + fold + re-probe, the
-                # same transactional continuation any run gets
-                if res.n_done == 0:
-                    # no progress possible through tables; scan the rest
-                    pending.extend(range(start + done, start + length))
-                    break
-                ids = np.where(res.chosen >= 0, perm[res.chosen], -1)
-                out[start + done : start + done + res.n_done] = ids.astype(
-                    np.int32
-                )
-                counts = np.zeros(N, np.int64)
-                counts[perm] = res.counts
-                # deferred: the fold rides the next probe's dispatch
-                fold.append(("single", buf, layout, counts))
-                # _apply_fn adds counts.sum() == res.scheduled to the
-                # device last_idx; mirror it host-side
-                L_host = res.last_node_index
-                done += res.n_done
-            if self_anti_veto is not None:
-                # what is left to `pending` is the scan's, decided later
-                anti["anti_runs"] += 1
-                anti["anti_picks"] += int(np.count_nonzero(
-                    out[start + done0:start + length] >= 0))
-            return carry
+    group_floor = 8
 
-        def run_group_host(carry, group):
-            """K pure runs, ONE probe dispatch + ONE deferred fold: the
-            grouped header probe ships every run's static channels and
-            the live resource block; the host rebuilds each run's
-            j-axis against the accumulating usage (models/hosttab) and
-            replays them in FIFO order."""
-            nonlocal L_host
-            G = len(group)
-            for g in group:
-                via[g["start"]:g["start"] + g["length"]] = _GROUP_HOST
-            G_bucket, glayout, gbuf = group_buffer(batch, [g["rep"] for g in group])
-            prev = fold.pop() if fold else None
-            with phase_timer("probe"):
-                self._count("group_probe")
-                carry, headers, usage = self.probe.probe_group(
-                    static, carry, prev, gbuf, num_zones, num_values,
-                    G_bucket, glayout, self._apply_fn,
-                    self._apply_group_fn,
-                )
-            count_group(self.stats, {
-                "group_runs": G,
-                "group_d2h_bytes": headers.nbytes + usage.nbytes})
-            with phase_timer("replay"):
-                counts_mat, n_full, partial_done, L_host = \
-                    host_group_replay(
-                        self.config, snap, batch,
-                        [(g["rep"], g["start"], g["length"])
-                         for g in group],
-                        headers[:G], usage, self._replay, perm, L_host,
-                        out, zoned, self.max_j, num_zones,
-                        gang_marks=[g["gang"] for g in group],
-                    )
-            if counts_mat.any():
-                cm = np.zeros((G_bucket, counts_mat.shape[1]), np.int64)
-                cm[:G] = counts_mat
-                fold.append(("group", gbuf, glayout, cm))
-            if n_full == G:
-                return carry, G, None
-            count_group(self.stats, {"group_reprobes": 1})
-            return carry, n_full, (n_full, partial_done)
+    def place(self, buf):
+        return buf  # the program that reads it ships it
 
-        def run_group_device(carry, group):
-            """K zoned-spread runs, ONE fused device dispatch: probe +
-            pick loop + commit fold per run inside one outer loop
-            (models/zreplay.run_group), carry threaded run to run."""
-            nonlocal L_host
-            from kubernetes_tpu.models.zreplay import ZReplay
+    def scan_pending(self, wave: Wave, rows: np.ndarray):
+        self._settle(wave)
+        wave.carry, chosen, last = self.scan_rows(
+            wave.static, wave.carry, wave.batch, wave.rep_idx[rows],
+            wave.num_zones, wave.num_values)
+        return chosen, last
 
-            if self._zreplay is None:
-                self._zreplay = ZReplay(self.config, self._apply_fn,
-                                        self._apply_group_fn)
-            G = len(group)
-            for g in group:
-                via[g["start"]:g["start"] + g["length"]] = _GROUP_DEVICE
-            G_bucket, glayout, gbuf = group_buffer(batch, [g["rep"] for g in group])
-            maxlen = max(g["length"] for g in group)
-            K_bucket = replay_k_bucket(maxlen, ZREPLAY_GROUP_K_FLOOR)
-            zone_perm = np.ascontiguousarray(
-                np.asarray(snap.zone_id)[perm], np.int32
+    def _settle(self, wave: Wave) -> None:
+        """The waiting fold in a dispatch of its own: no further probe
+        will carry it for free."""
+        if wave.fold is not None:
+            kind, buf, layout, counts = wave.fold
+            wave.fold = None
+            apply = self._apply_packed if kind == "single" \
+                else self._apply_group_packed
+            wave.carry = apply(wave.static, wave.carry, buf, layout, counts)
+
+    finish = _settle  # the wave's last fold
+
+    def _take_fold(self, wave: Wave, layout):
+        """-> (buf, counts) of the waiting fold where a single run's
+        dispatch can carry it (a run's own, or one of its layout), else
+        (None, None) with the fold settled apart."""
+        if wave.fold is not None and wave.fold[0] == "single" \
+                and wave.fold[2] == layout:
+            _kind, buf, _layout, counts = wave.fold
+            wave.fold = None
+            return buf, counts
+        self._settle(wave)
+        return None, None
+
+    def probe_run(self, wave: Wave, run: Run, layout, buf, J: int,
+                  rows: int):
+        prev_buf, prev_counts = self._take_fold(wave, layout)
+        with phase_timer("probe"):
+            self._count("probe")
+            wave.carry, tables = self.probe.probe_fused(
+                wave.static, wave.carry, prev_buf, prev_counts, buf,
+                wave.num_zones, wave.num_values, J, rows, layout,
+                self._apply_fn, **wave.table_context(run),
             )
-            vetos = np.zeros((G_bucket, N), bool)
-            has_sels = np.zeros(G_bucket, bool)
-            rows_arr = np.ones(G_bucket, np.int64)
-            k_reals = np.zeros(G_bucket, np.int32)
-            J_g = 128
-            for i, g in enumerate(group):
-                Jr, rr = self._pick_j(snap, batch, g["rep"],
-                                      g["length"])
-                J_g = max(J_g, Jr)
-                rows_arr[i] = rr
-                k_reals[i] = min(g["length"], K_bucket)
-                has_sels[i] = bool(batch.has_selectors[g["rep"]])
-                if g["veto"] is not None:
-                    vetos[i] = np.asarray(g["veto"])[perm]
-            prev = fold.pop() if fold else None
+        return tables
+
+    def commit_run(self, wave: Wave, run: Run, layout, buf, counts) -> None:
+        wave.fold = ("single", buf, layout, counts)  # rides the next probe
+
+    def probe_group(self, wave: Wave, G_bucket: int, layout, buf):
+        prev, wave.fold = wave.fold, None
+        with phase_timer("probe"):
+            self._count("group_probe")
+            wave.carry, headers, usage = self.probe.probe_group(
+                wave.static, wave.carry, prev, buf, wave.num_zones,
+                wave.num_values, G_bucket, layout, self._apply_fn,
+                self._apply_group_fn,
+            )
+        wave.tallies["group_d2h_bytes"] += headers.nbytes + usage.nbytes
+        return headers, usage
+
+    def commit_group(self, wave: Wave, runs, G_bucket: int, layout, buf,
+                     counts_mat) -> None:
+        cm = np.zeros((G_bucket, counts_mat.shape[1]), np.int64)
+        cm[:len(runs)] = counts_mat
+        wave.fold = ("group", buf, layout, cm)
+
+    def replay_run_device(self, wave: Wave, run: Run, done0: int) -> None:
+        """A zoned-spread run by itself: probe + pick sequence + commit
+        fold in one device dispatch (models/zreplay) for every table
+        horizon it meets; the run's commits are folded in the program."""
+        layout, buf = pack_arrays(wave.pod_row(run.rep))
+        zone_perm = wave.zone_perm()
+        veto = np.zeros(wave.N, bool) if run.veto is None \
+            else np.asarray(run.veto)
+        veto_perm = np.ascontiguousarray(veto[wave.perm])
+        done = done0
+        while done < run.length:
+            K = run.length - done
+            J, rows = pick_j(self.config, self.max_j, wave.snap,
+                             wave.batch, run.rep, K)
+            K_bucket = replay_k_bucket(K, ZREPLAY_K_FLOOR)
+            prev_buf, prev_counts = self._take_fold(wave, layout)
             with phase_timer("replay"):
-                self._count("zreplay_group")
-                carry, chosen, n_done, L = self._zreplay.run_group(
-                    static, carry, prev, gbuf, glayout, num_zones,
-                    num_values, J_g, K_bucket, G_bucket, zone_perm,
-                    vetos, has_sels, rows_arr, k_reals, G, L_host,
+                self._count("zreplay")
+                wave.carry, chosen, _counts, L, n_done = self._zreplay.run(
+                    wave.static, wave.carry, prev_buf, prev_counts, buf,
+                    layout, wave.num_zones, wave.num_values, J, K_bucket,
+                    zone_perm, veto_perm,
+                    bool(wave.batch.has_selectors[run.rep]), rows,
+                    min(K, K_bucket), wave.L_host,
                 )
                 with device_wait():
                     chosen = np.asarray(chosen)
-                    n_done = np.asarray(n_done)
-                    L_host = int(L)
-                    steps, slots, rescores = np.asarray(
-                        self._zreplay.group_ran)
-            count_group(self.stats, {
-                "zreplay_steps": int(steps), "zreplay_slots": int(slots),
-                "zreplay_rescores": int(rescores),
-                "zreplay_picks": int((chosen >= 0).sum())})
-            partial = None
-            consumed = 0
-            for i, g in enumerate(group):
-                nd = int(n_done[i])
-                if nd:
-                    ids = np.where(chosen[i, :nd] >= 0,
-                                   perm[chosen[i, :nd]], -1)
-                    out[g["start"]:
-                        g["start"] + nd] = ids.astype(np.int32)
-                if nd < g["length"]:
-                    partial = (i, nd)
-                    break
-                consumed += 1
-            return carry, consumed, partial
+                    n_done = int(n_done)
+                    L = int(L)
+            if n_done == 0:
+                break  # no progress through tables: the scan's
+            wave.write(run.start + done, chosen[:n_done])
+            wave.L_host = L
+            done += n_done
+        wave.pending.extend(range(run.start + done, run.stop))
 
-        host_cap = _host_group_cap(N)
-        idx = 0
-        while idx < len(infos):
-            info = infos[idx]
-            if not info["eligible"]:
-                pending.extend(range(info["start"],
-                                     info["start"] + info["length"]))
-                idx += 1
-                continue
-            carry = flush(carry)
-            group = [info]
-            jdx = idx + 1
-            if info["device"]:
-                # device-path runs group freely (each probe runs against
-                # the live in-program carry — no purity needed), bounded
-                # by what the shared K bucket still wastes: the picks
-                # come back as [G bucket, K bucket] whatever the runs'
-                # lengths (the pick loop itself ends at each run's)
-                picks = info["length"]
-                while (jdx < len(infos) and len(group) < 512
-                       and info["length"] <= (1 << 16)):
-                    nxt = infos[jdx]
-                    if not nxt["device"] or nxt["length"] > (1 << 16):
-                        break
-                    maxlen = max(max(g["length"] for g in group),
-                                 nxt["length"])
-                    if (len(group) + 1) * replay_k_bucket(
-                            maxlen, ZREPLAY_GROUP_K_FLOOR
-                    ) > 8 * (picks + nxt["length"]):
-                        break
-                    group.append(nxt)
-                    picks += nxt["length"]
-                    jdx += 1
-            else:
-                while (info["pure"] and jdx < len(infos)
-                       and len(group) < host_cap):
-                    nxt = infos[jdx]
-                    if not (nxt["pure"] and not nxt["device"]):
-                        break
-                    group.append(nxt)
-                    jdx += 1
-            if len(group) >= 2:
-                if info["device"]:
-                    carry, consumed, partial = run_group_device(
-                        carry, group)
-                else:
-                    carry, consumed, partial = run_group_host(
-                        carry, group)
-                if partial is not None:
-                    g_idx, done = partial
-                    carry = run_single(carry, group[g_idx], done0=done)
-                    idx += g_idx + 1
-                else:
-                    idx += consumed
-                continue
-            carry = run_single(carry, info)
-            idx += 1
-        carry = settle(carry)
-        carry = flush(carry)
-        self._count_wave(via, out)
-        if anti["anti_runs"]:
-            count_group(self.stats, anti)
-        count_runs(self.stats, snap, batch, infos)
-        return out, carry, L_host
+    def replay_group_device(self, wave: Wave, runs: Sequence[Run]):
+        """K zoned-spread runs, ONE fused device dispatch: probe + pick
+        loop + commit fold per run inside one outer loop
+        (models/zreplay.run_group), carry threaded run to run.
+        -> None, or (g, picks done of run g) where it stopped early."""
+        G = len(runs)
+        G_bucket, glayout, gbuf = group_buffer(
+            wave.batch, [run.rep for run in runs])
+        K_bucket = replay_k_bucket(max(run.length for run in runs),
+                                   ZREPLAY_GROUP_K_FLOOR)
+        zone_perm = wave.zone_perm()
+        vetos = np.zeros((G_bucket, wave.N), bool)
+        has_sels = np.zeros(G_bucket, bool)
+        rows_arr = np.ones(G_bucket, np.int64)
+        k_reals = np.zeros(G_bucket, np.int32)
+        J_g = 128
+        for i, run in enumerate(runs):
+            Jr, rows_arr[i] = pick_j(self.config, self.max_j, wave.snap,
+                                     wave.batch, run.rep, run.length)
+            J_g = max(J_g, Jr)
+            k_reals[i] = min(run.length, K_bucket)
+            has_sels[i] = bool(wave.batch.has_selectors[run.rep])
+            if run.veto is not None:
+                vetos[i] = np.asarray(run.veto)[wave.perm]
+        prev, wave.fold = wave.fold, None
+        with phase_timer("replay"):
+            self._count("zreplay_group")
+            wave.carry, chosen, n_done, L = self._zreplay.run_group(
+                wave.static, wave.carry, prev, gbuf, glayout,
+                wave.num_zones, wave.num_values, J_g, K_bucket, G_bucket,
+                zone_perm, vetos, has_sels, rows_arr, k_reals, G,
+                wave.L_host,
+            )
+            with device_wait():
+                chosen = np.asarray(chosen)
+                n_done = np.asarray(n_done)
+                wave.L_host = int(L)
+                steps, slots, rescores = np.asarray(
+                    self._zreplay.group_ran)
+        wave.tallies.update({
+            "zreplay_steps": int(steps), "zreplay_slots": int(slots),
+            "zreplay_rescores": int(rescores),
+            "zreplay_picks": int((chosen >= 0).sum())})
+        for i, run in enumerate(runs):
+            nd = int(n_done[i])
+            wave.write(run.start, chosen[i, :nd])
+            if nd < run.length:
+                return i, nd
+        return None

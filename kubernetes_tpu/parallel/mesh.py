@@ -61,6 +61,24 @@ from kubernetes_tpu.models.batch import (
     wants_resources,
     wants_selector,
 )
+from kubernetes_tpu.models.probe import N_STK_ROWS, tables_from_packed
+from kubernetes_tpu.models.replay import replay_fast
+from kubernetes_tpu.models.wave import (
+    AFFINITY_COUNTERS,
+    ANTI_COUNTERS,
+    GROUP_COUNTERS,
+    PATHS,
+    WaveCounts,
+    classify_runs,
+    split_runs,
+)
+from kubernetes_tpu.models.waveloop import (
+    Policy,
+    Wave,
+    gather_batch,
+    host_group_cap,
+    run_wave,
+)
 from kubernetes_tpu.ops import interpod as IP
 from kubernetes_tpu.ops import predicates as P
 from kubernetes_tpu.ops import select as S
@@ -68,6 +86,7 @@ from kubernetes_tpu.ops import priorities as R
 from kubernetes_tpu.ops import services as SV
 from kubernetes_tpu.ops import volumes as V
 from kubernetes_tpu.snapshot.encode import ClusterSnapshot, PodBatch, service_config_labels
+from kubernetes_tpu.snapshot.pad import next_pow2, pad_batch
 from kubernetes_tpu.trace.profile import device_wait, fetch, phase_timer
 
 
@@ -1044,6 +1063,12 @@ class MeshBatchScheduler:
         return [names[i] if i >= 0 else None for i in chosen]
 
 
+#: a pod row's fields that the resident state's host mirrors fold
+#: (`ResidentClusterState.note_commit` / `note_scan`)
+_COMMIT_FIELDS = ("commit_mcpu", "commit_mem", "commit_gpu", "nz_mcpu",
+                  "nz_mem", "port_mask", "class_id")
+
+
 def _opaque_blocks(config) -> tuple:
     """Resident carry blocks this config's scan/impure folds can touch
     in ways the host mirrors cannot track (they resync from the next
@@ -1066,8 +1091,6 @@ def _sparse_counts(counts: np.ndarray, floor: int = 64):
     """Dense i64[N] commit counts -> (idx i64[M], cnt i64[M]) scatter
     form, M pow2-bucketed (compile reuse) and padded with idx=-1: the
     commit shipment is O(touched nodes) <= O(picks), never O(N)."""
-    from kubernetes_tpu.snapshot.pad import next_pow2
-
     ids = np.nonzero(counts)[0]
     M = next_pow2(max(len(ids), 1), floor)
     idx = np.full(M, -1, np.int64)
@@ -1080,8 +1103,6 @@ def _sparse_counts(counts: np.ndarray, floor: int = 64):
 def _sparse_group_counts(counts_mat: np.ndarray, floor: int = 64):
     """Dense i64[G, N] -> (idx i64[G, M], cnt i64[G, M]) scatter form
     with a shared pow2 M bucket."""
-    from kubernetes_tpu.snapshot.pad import next_pow2
-
     G = counts_mat.shape[0]
     nz = [np.nonzero(row)[0] for row in counts_mat]
     width = max((len(i) for i in nz), default=0)
@@ -1094,8 +1115,9 @@ def _sparse_group_counts(counts_mat: np.ndarray, floor: int = 64):
     return idx, cnt
 
 
-class MeshWaveScheduler:
-    """The wave fast path over a device mesh, resident-state edition:
+class MeshWaveScheduler(WaveCounts):
+    """The wave fast path over a device mesh, resident-state edition
+    (the mesh side of `models/waveloop.run_wave`'s device seam):
     probe tables computed per shard against the DEVICE-RESIDENT sharded
     cluster state (node axis sharded, one shard per chip), the replay on
     the host exactly as single-chip, and the commit fold applied per
@@ -1111,8 +1133,6 @@ class MeshWaveScheduler:
                  config: Optional[SchedulerConfig] = None,
                  min_run: int = 16, max_j: int = 1024,
                  pod_floor: int = 64, replay=None):
-        from kubernetes_tpu.models.replay import replay_fast
-
         if mesh is None:
             mesh = Mesh(np.array(jax.devices()), (AXIS,))
         self.mesh = mesh
@@ -1126,6 +1146,7 @@ class MeshWaveScheduler:
         self._apply_jit = {}
         # the device-resident sharded cluster state (+ transfer stats)
         self.resident = ResidentClusterState(mesh)
+        self._blocks = _opaque_blocks(self.config)
         # reuse mode when the caller passes none: "auto" mirror-compares
         # (the daemon), "carry" trusts the resident carry, "reship"
         # re-places per wave (the r05-equivalent A/B baseline)
@@ -1138,16 +1159,11 @@ class MeshWaveScheduler:
         # numbers are diffs), plus what only a mesh has: the picks that
         # landed on each shard's nodes (they add up to the pods placed)
         # and the resident state's shipped bytes
-        from kubernetes_tpu.models.wave import (
-            AFFINITY_COUNTERS,
-            GROUP_COUNTERS,
-            PATHS,
-        )
-
         self.stats = {
             "waves": 0, "dispatches": 0, "dispatches_by_kind": {},
             "pods_by_path": dict.fromkeys(PATHS, 0), "pods_unplaced": 0,
             **dict.fromkeys(GROUP_COUNTERS, 0),
+            **dict.fromkeys(ANTI_COUNTERS, 0),
             **dict.fromkeys(AFFINITY_COUNTERS, 0), "scan_reasons": {},
             "picks_by_shard": [0] * int(mesh.devices.size),
             "h2d_bytes_total": 0,
@@ -1251,77 +1267,6 @@ class MeshWaveScheduler:
             out_shardings=_carry_out_shardings(self.mesh, empty),
         )
 
-    # -- dispatch wrappers ---------------------------------------------------
-
-    def _place_replicated(self, buf):
-        """Commit a packed pod/group buffer once per run: both the
-        probe and the fold consume the SAME device copy (a host numpy
-        arg would re-upload at every dispatch), and the shipment is
-        counted once."""
-        dev = jax.device_put(
-            buf, NamedSharding(self.mesh, PSpec()))
-        self.resident.count_h2d(buf.nbytes)
-        return dev
-
-    def _probe_run(self, static, carry, pod_layout, pod_buf, n,
-                   n_per_shard, num_zones, num_values, J):
-        run = self._probe_program(static, n, n_per_shard, num_zones,
-                                  num_values, J, pod_layout)
-        with phase_timer("probe"), self.mesh:
-            raw = run(static, carry, pod_buf)
-            with device_wait():
-                return np.ascontiguousarray(jax.device_get(raw))
-
-    def _apply_run(self, static, carry, pod_layout, pod_buf, counts, n,
-                   n_per_shard):
-        idx, cnt = _sparse_counts(counts)
-        run = self._apply_program(static, n, n_per_shard, pod_layout,
-                                  empty=empty_leaves(carry))
-        self.resident.count_h2d(idx.nbytes + cnt.nbytes)
-        with phase_timer("replay"), self.mesh:
-            carry = run(static, carry, pod_buf, idx, cnt)
-            # drain the donated fold before anything can re-donate its
-            # aliased buffers (the fold is the last dispatch of its
-            # run, so only fold-vs-host bookkeeping overlap is lost)
-            with device_wait():
-                jax.block_until_ready(carry)
-        self.resident.set_carry(carry)
-        return carry
-
-    def _group_probe_run(self, static, carry, pod_layout, group_buf, n,
-                         n_per_shard, num_zones, num_values, G):
-        """-> headers i64[G, N_STK_ROWS, N] — the grouped header probe
-        for G stacked runs, ONE sharded dispatch and ONE device->host
-        transfer (the resource block no longer ships: the resident
-        host mirror supplies the replay's usage exactly)."""
-        from kubernetes_tpu.models.probe import N_STK_ROWS
-
-        run = self._group_probe_program(static, n, n_per_shard,
-                                        num_zones, num_values, G,
-                                        pod_layout)
-        with phase_timer("probe"), self.mesh:
-            raw = run(static, carry, group_buf)
-            with device_wait():
-                arr = np.ascontiguousarray(jax.device_get(raw))
-        return arr.reshape(G, N_STK_ROWS, n)
-
-    def _apply_group_run(self, static, carry, pod_layout, group_buf,
-                         counts_mat, G_bucket, n, n_per_shard):
-        cm = np.zeros((G_bucket, n), np.int64)
-        cm[: counts_mat.shape[0]] = counts_mat
-        idx, cnt = _sparse_group_counts(cm)
-        run = self._apply_group_program(static, n, n_per_shard,
-                                        pod_layout,
-                                        empty=empty_leaves(carry))
-        self.resident.count_h2d(idx.nbytes + cnt.nbytes)
-        with phase_timer("replay"), self.mesh:
-            carry = run(static, carry, group_buf, idx, cnt)
-            # see _apply_run: donated folds drain before re-donation
-            with device_wait():
-                jax.block_until_ready(carry)
-        self.resident.set_carry(carry)
-        return carry
-
     # -- backlog driver ------------------------------------------------------
 
     def schedule_backlog(
@@ -1340,311 +1285,186 @@ class MeshWaveScheduler:
         outright (steady loops whose snapshot is the stale wave-0 view);
         "reship" re-places everything (the r05-equivalent baseline kept
         for A/B measurement)."""
-        from kubernetes_tpu.models.probe import tables_from_packed
-        from kubernetes_tpu.models.replay import ReplayResult
-        from kubernetes_tpu.models.pack import pack_arrays
-        from kubernetes_tpu.models.wave import (
-            _GROUP_HOST,
-            _SCAN,
-            _SINGLE,
-            _host_group_cap,
-            _permute_tables,
-            classify_runs,
-            count_runs,
-            count_group,
-            gather_batch,
-            group_buffer,
-            host_group_replay,
-            split_runs,
-        )
-        from kubernetes_tpu.snapshot.pad import next_pow2, pad_batch
-
-        if reuse is None:
-            reuse = self.reuse_default
-        n_dev = self.mesh.devices.size
-        snap = _pad_snapshot(snap, n_dev)
-        N = len(snap.node_names)
-        n_per_shard = N // n_dev
-        P = len(rep_idx)
-
+        snap = _pad_snapshot(snap, self.mesh.devices.size)
+        wave = Wave(self.config, snap, batch, rep_idx, int(last_node_index),
+                    self.max_j, self._replay)
+        reuse = reuse or self.reuse_default
+        self.dispatches = {}
+        self.stats["waves"] += 1
         self.resident.begin_wave()
         # "transfer": the mirror compare and whatever it ships (deltas
         # as row scatters, a changed replicated table whole)
         with phase_timer("transfer"):
-            static, carry = self.resident.sync(
+            wave.static, wave.carry = self.resident.sync(
                 self.config, snap, last_node_index, reuse=reuse
             )
-        num_zones = max(int(snap.zone_id.max()) + 1, 1)
-        num_values = int(snap.svc_num_values)
-        zoned = bool(np.any(np.asarray(snap.zone_id) > 0))
-        out = np.full(P, -1, np.int32)
-        perm = np.asarray(snap.name_desc_order).astype(np.int64)
-        runs = split_runs(rep_idx)
-        self.dispatches = {}
-        self.stats["waves"] += 1
-        # which path decided each backlog position (wave.PATHS)
-        via = np.full(P, _SINGLE, np.int8)
-        pending: list = []
-        L_host = int(last_node_index)
-        blocks = _opaque_blocks(self.config)
-        replicated = NamedSharding(self.mesh, PSpec())
+        runs, policy = self.plan(wave, reuse)
+        run_wave(self, wave, runs, policy)
+        self._count_wave(wave, runs)
+        return wave.out, wave.carry, wave.L_host
 
-        def count(key):
-            self.dispatches[key] = self.dispatches.get(key, 0) + 1
-            self.stats["dispatches"] += 1
-            by_kind = self.stats["dispatches_by_kind"]
-            by_kind[key] = by_kind.get(key, 0) + 1
-
-        def flush(carry):
-            nonlocal L_host
-            if not pending:
-                return carry
-            rows = np.asarray(pending, np.int64)
-            via[rows] = _SCAN
-            with phase_timer("transfer"):
-                seg = gather_batch(batch, rep_idx[rows])
-                segp = pad_batch(seg,
-                                 next_pow2(len(rows), self.pod_floor))
-                pods = {
-                    f: np.asarray(getattr(segp, f))
-                    for f in BatchScheduler.POD_FIELDS
-                }
-                self.resident.count_h2d(
-                    sum(v.nbytes for v in pods.values()))
-                pods = jax.device_put(pods, replicated)
-            # "score", as on one chip: from the dispatch of the sharded
-            # scan to the host's read of its picks
-            with phase_timer("score"):
-                count("scan")
-                carry, chosen = self.scan._exec(
-                    static, carry, pods, N, n_per_shard, num_zones,
-                    num_values, segp.num_pods,
-                )
-                self.resident.set_carry(carry)
-                with device_wait():
-                    chosen_host = np.asarray(chosen)[: len(rows)]
-                    L_host = int(
-                        jax.device_get(carry[BatchScheduler.LAST_IDX]))
-                out[rows] = chosen_host
-            # host-visible pure-channel commits keep the mirrors exact;
-            # the opaque feature blocks resync from the next snapshot
-            segf = {
-                f: np.asarray(getattr(seg, f))
-                for f in ("commit_mcpu", "commit_mem", "commit_gpu",
-                          "nz_mcpu", "nz_mem", "port_mask", "class_id")
-            }
-            self.resident.note_scan(
-                [{k: v[i] for k, v in segf.items()}
-                 for i in range(len(rows))],
-                chosen_host,
-            )
-            # invalidate only the blocks these pods can actually have
-            # folded on device: a featureless scan wave (the daemon's
-            # small mixed waves) must not force a next-wave resync
-            inv = []
-            if "ip" in blocks and any(
-                np.asarray(getattr(seg, f)).size
-                and np.asarray(getattr(seg, f)).any()
-                for f in ("ip_match_spec", "ip_own_hard", "ip_own_pref",
-                          "ip_own_anti_hard", "ip_own_anti_pref")
-            ):
-                inv.append("ip")
-            if "vol" in blocks and any(
-                np.asarray(getattr(seg, f)).any()
-                for f in ("vp_vol_rw", "vp_vol_ro", "vp_ebs", "vp_gce")
-            ):
-                inv.append("vol")
-            if "svc" in blocks and np.asarray(seg.svc_member).any():
-                inv.append("svc")
-            if inv:
-                self.resident.invalidate(*inv)
-            pending.clear()
-            return carry
-
-        infos = classify_runs(
-            self.config, snap, batch, runs, num_values, self.min_run,
-            device_zoned=False, zoned=zoned,
+    def plan(self, wave: Wave, reuse: Optional[str] = None):
+        """-> (the wave's classified runs, the policy that cuts them
+        into steps): `WaveScheduler.plan` without gangs or device
+        replays. The r05 dispatch shape (a full probe for a lone pure
+        run) is kept under reuse="reship" as the A/B baseline."""
+        runs = classify_runs(
+            self.config, wave.snap, wave.batch, split_runs(wave.rep_idx),
+            wave.num_values, self.min_run, zoned=wave.zoned,
         )
+        return runs, Policy(
+            host_group_cap(wave.N),
+            lone_pure_grouped=(reuse or self.reuse_default) != "reship")
 
-        def run_single(carry, info, done0=0):
-            nonlocal L_host
-            rep, start, length = (info["rep"], info["start"],
-                                  info["length"])
-            pod_host = {
-                f: np.asarray(getattr(batch, f)[rep])
-                for f in BatchScheduler.POD_FIELDS
-            }
-            pod_layout, pod_buf = pack_arrays(pod_host)
-            with phase_timer("transfer"):
-                pod_buf = self._place_replicated(pod_buf)
-            done = done0
-            via[start + done:start + length] = _SINGLE
-            while done < length:
-                K = length - done
-                J, rows_n = self._pick_j(snap, batch, rep, K)
-                count("probe")
-                arr = self._probe_run(
-                    static, carry, pod_layout, pod_buf, N, n_per_shard,
-                    num_zones, num_values, J,
-                )
-                tables = tables_from_packed(
-                    self.config, arr, num_zones, J, rows_n,
-                    has_selectors=bool(batch.has_selectors[rep]),
-                    zone_id=np.asarray(snap.zone_id) if zoned else None,
-                    self_anti_veto=info["veto"],
-                    svc_ctx=info["svc_ctx"],
-                )
-                if tables.sa_bail:
-                    # ServiceAffinity dynamics the tables can't express
-                    # (mid-run re-pin hazard): scan the rest of the run
-                    pending.extend(range(start + done, start + length))
-                    break
-                with phase_timer("replay"):
-                    res: ReplayResult = self._replay(
-                        _permute_tables(tables, perm), K, L_host
-                    )
-                if res.n_done == 0:
-                    pending.extend(range(start + done, start + length))
-                    break
-                ids = np.where(res.chosen >= 0, perm[res.chosen], -1)
-                out[start + done: start + done + res.n_done] = ids.astype(
-                    np.int32
-                )
-                counts = np.zeros(N, np.int64)
-                counts[perm] = res.counts
-                count("apply")
-                carry = self._apply_run(
-                    static, carry, pod_layout, pod_buf, counts, N,
-                    n_per_shard,
-                )
-                self.resident.note_commit(pod_host, counts)
-                if blocks and not info["pure"]:
-                    # impure-but-eligible runs fold ip/svc tables on
-                    # device; those mirrors go opaque until resynced
-                    self.resident.invalidate(*blocks)
-                L_host = res.last_node_index
-                done += res.n_done
-            return carry
-
-        def run_group(carry, group):
-            """K pure runs through ONE sharded header probe + ONE
-            donated grouped fold; the host replay (shared with the
-            single-chip driver) rebuilds each run's j-axis against the
-            resident usage mirror and replays in FIFO order."""
-            nonlocal L_host
-            G = len(group)
-            for g in group:
-                via[g["start"]:g["start"] + g["length"]] = _GROUP_HOST
-            G_bucket, glayout, gbuf = group_buffer(
-                batch, [g["rep"] for g in group], floor=1
-            )
-            with phase_timer("transfer"):
-                gbuf = self._place_replicated(gbuf)
-            count("group_probe")
-            headers = self._group_probe_run(
-                static, carry, glayout, gbuf, N, n_per_shard,
-                num_zones, num_values, G_bucket,
-            )
-            # (the usage is the resident state's host mirror: only the
-            # headers cross from the device)
-            count_group(self.stats, {"group_runs": G,
-                                     "group_d2h_bytes": headers.nbytes})
-            with phase_timer("replay"):
-                usage = self.resident.usage()
-                counts_mat, n_full, partial_done, L_host = \
-                    host_group_replay(
-                        self.config, snap, batch,
-                        [(g["rep"], g["start"], g["length"])
-                         for g in group],
-                        headers[:G], usage, self._replay, perm, L_host,
-                        out, zoned, self.max_j, num_zones,
-                    )
-            if counts_mat.any():
-                count("apply")
-                carry = self._apply_group_run(
-                    static, carry, glayout, gbuf, counts_mat, G_bucket,
-                    N, n_per_shard,
-                )
-                for g, info_g in enumerate(group):
-                    if counts_mat[g].any():
-                        pod_host = {
-                            f: np.asarray(getattr(batch, f)[info_g["rep"]])
-                            for f in ("commit_mcpu", "commit_mem",
-                                      "commit_gpu", "nz_mcpu", "nz_mem",
-                                      "port_mask", "class_id")
-                        }
-                        self.resident.note_commit(pod_host,
-                                                  counts_mat[g])
-            if n_full == G:
-                return carry, G, None
-            count_group(self.stats, {"group_reprobes": 1})
-            return carry, n_full, (n_full, partial_done)
-
-        host_cap = _host_group_cap(N)
-        idx = 0
-        while idx < len(infos):
-            info = infos[idx]
-            if not info["eligible"]:
-                pending.extend(range(info["start"],
-                                     info["start"] + info["length"]))
-                idx += 1
-                continue
-            carry = flush(carry)
-            group = [info]
-            jdx = idx + 1
-            while (info["pure"] and jdx < len(infos)
-                   and len(group) < host_cap and infos[jdx]["pure"]):
-                group.append(infos[jdx])
-                jdx += 1
-            # resident modes route even SINGLETON pure runs through the
-            # header-only probe: the exact host usage mirror rebuilds
-            # the j-table (models/hosttab), so the full [J, N] probe —
-            # its on-device j-axis compute AND its O(J*N) device->host
-            # shipment — drops out of the steady-state wave entirely.
-            # The r05 dispatch shape (full probe per singleton run) is
-            # kept under reuse="reship" as the A/B baseline.
-            if len(group) >= 2 or (info["pure"] and reuse != "reship"):
-                carry, consumed, partial = run_group(carry, group)
-                if partial is not None:
-                    g_idx, done = partial
-                    carry = run_single(carry, group[g_idx], done0=done)
-                    idx += g_idx + 1
-                else:
-                    idx += consumed
-                continue
-            carry = run_single(carry, info)
-            idx += 1
-        carry = flush(carry)
-        self.resident.finish_wave(carry, L_host)
-        self._count_wave(via, out, n_per_shard)
-        count_runs(self.stats, snap, batch, infos)
-        return out, carry, L_host
-
-    def _count_wave(self, via: np.ndarray, out: np.ndarray,
-                    n_per_shard: int) -> None:
-        """A finished wave into the cumulative tallies, here and on
-        /debug/traces (trace/profile.wave_totals), as
-        WaveScheduler._count_wave."""
-        from kubernetes_tpu.models.wave import PATHS
-        from kubernetes_tpu.trace.profile import count_wave
-
-        pods = dict(zip(PATHS, np.bincount(via, minlength=len(PATHS))
-                        .tolist()))
-        placed = out[out >= 0]
-        unplaced = int(out.size - placed.size)
-        for path, n in pods.items():
-            self.stats["pods_by_path"][path] += n
-        self.stats["pods_unplaced"] += unplaced
+    def _count_wave(self, wave: Wave, runs) -> None:
+        super()._count_wave(wave, runs)
+        placed = wave.out[wave.out >= 0]
         shards = self.stats["picks_by_shard"]
         for shard, n in enumerate(np.bincount(
-                placed // n_per_shard, minlength=len(shards)).tolist()):
+                placed // self._per_shard(wave),
+                minlength=len(shards)).tolist()):
             shards[shard] += n
         self.stats["h2d_bytes_total"] = \
             self.resident.stats["h2d_bytes_total"]
-        count_wave(pods, self.dispatches, unplaced)
 
-    def _pick_j(self, snap: ClusterSnapshot, batch: PodBatch, rep: int,
-                K: int):
-        from kubernetes_tpu.models.wave import pick_j
+    # -- the device seam (models/waveloop.run_wave) --------------------------
 
-        return pick_j(self.config, self.max_j, snap, batch, rep, K)
+    #: the exact host usage mirror lets even a SINGLETON pure run ride
+    #: the header-only probe (`waveloop.group_buffer`)
+    group_floor = 1
+
+    def _per_shard(self, wave: Wave) -> int:
+        return wave.N // self.mesh.devices.size
+
+    def place(self, buf):
+        """Commit a packed pod/group buffer once per run: both the
+        probe and the fold consume the SAME device copy (a host numpy
+        arg would re-upload at every dispatch), and the shipment is
+        counted once."""
+        with phase_timer("transfer"):
+            dev = jax.device_put(buf, NamedSharding(self.mesh, PSpec()))
+            self.resident.count_h2d(buf.nbytes)
+        return dev
+
+    def scan_pending(self, wave: Wave, rows: np.ndarray):
+        """The sharded scan over the resident carry, its commits noted
+        on the resident state's host mirrors."""
+        with phase_timer("transfer"):
+            seg = gather_batch(wave.batch, wave.rep_idx[rows])
+            segp = pad_batch(seg, next_pow2(len(rows), self.pod_floor))
+            pods = {
+                f: np.asarray(getattr(segp, f))
+                for f in BatchScheduler.POD_FIELDS
+            }
+            self.resident.count_h2d(sum(v.nbytes for v in pods.values()))
+            pods = jax.device_put(pods, NamedSharding(self.mesh, PSpec()))
+        # "score", as on one chip: from the dispatch of the sharded
+        # scan to the host's read of its picks
+        with phase_timer("score"):
+            self._count("scan")
+            wave.carry, chosen = self.scan._exec(
+                wave.static, wave.carry, pods, wave.N,
+                self._per_shard(wave), wave.num_zones, wave.num_values,
+                segp.num_pods,
+            )
+            self.resident.set_carry(wave.carry)
+            with device_wait():
+                chosen = np.asarray(chosen)[: len(rows)]
+                last = int(jax.device_get(
+                    wave.carry[BatchScheduler.LAST_IDX]))
+        # host-visible pure-channel commits keep the mirrors exact;
+        # the opaque feature blocks resync from the next snapshot
+        segf = {f: np.asarray(getattr(seg, f)) for f in _COMMIT_FIELDS}
+        self.resident.note_scan(
+            [{k: v[i] for k, v in segf.items()} for i in range(len(rows))],
+            chosen,
+        )
+        # invalidate only the blocks these pods can actually have
+        # folded on device: a featureless scan wave (the daemon's
+        # small mixed waves) must not force a next-wave resync
+        inv = [block for block, names in (
+            ("ip", ("ip_match_spec", "ip_own_hard", "ip_own_pref",
+                    "ip_own_anti_hard", "ip_own_anti_pref")),
+            ("vol", ("vp_vol_rw", "vp_vol_ro", "vp_ebs", "vp_gce")),
+            ("svc", ("svc_member",)),
+        ) if block in self._blocks
+            and any(np.asarray(getattr(seg, f)).any() for f in names)]
+        if inv:
+            self.resident.invalidate(*inv)
+        return chosen, last
+
+    def probe_run(self, wave: Wave, run, layout, buf, J: int, rows: int):
+        self._count("probe")
+        program = self._probe_program(
+            wave.static, wave.N, self._per_shard(wave), wave.num_zones,
+            wave.num_values, J, layout)
+        with phase_timer("probe"), self.mesh:
+            raw = program(wave.static, wave.carry, buf)
+            with device_wait():
+                arr = np.ascontiguousarray(jax.device_get(raw))
+        return tables_from_packed(self.config, arr, wave.num_zones, J, rows,
+                                  **wave.table_context(run))
+
+    def _fold(self, wave: Wave, program, buf, idx, cnt) -> None:
+        """A donated commit fold, applied at once: the resident carry
+        and its host mirrors, which the next grouped replay reads,
+        never part. Commits ship in scatter form, O(picks)."""
+        self._count("apply")
+        self.resident.count_h2d(idx.nbytes + cnt.nbytes)
+        with phase_timer("replay"), self.mesh:
+            wave.carry = program(wave.static, wave.carry, buf, idx, cnt)
+            # drain the donated fold before anything can re-donate its
+            # aliased buffers (the fold is the last dispatch of its
+            # run, so only fold-vs-host bookkeeping overlap is lost)
+            with device_wait():
+                jax.block_until_ready(wave.carry)
+        self.resident.set_carry(wave.carry)
+
+    def _note_commit(self, wave: Wave, run, counts) -> None:
+        self.resident.note_commit(
+            {f: np.asarray(getattr(wave.batch, f)[run.rep])
+             for f in _COMMIT_FIELDS}, counts)
+
+    def commit_run(self, wave: Wave, run, layout, buf, counts) -> None:
+        self._fold(wave, self._apply_program(
+            wave.static, wave.N, self._per_shard(wave), layout,
+            empty=empty_leaves(wave.carry)), buf, *_sparse_counts(counts))
+        self._note_commit(wave, run, counts)
+        if self._blocks and not run.pure:
+            # impure-but-eligible runs fold ip/svc tables on
+            # device; those mirrors go opaque until resynced
+            self.resident.invalidate(*self._blocks)
+
+    def probe_group(self, wave: Wave, G_bucket: int, layout, buf):
+        """-> (headers i64[G, N_STK_ROWS, N], usage): the grouped
+        header probe for G stacked runs, ONE sharded dispatch and ONE
+        device->host transfer (the resource block does not ship: the
+        resident host mirror supplies the replay's usage exactly)."""
+        self._count("group_probe")
+        program = self._group_probe_program(
+            wave.static, wave.N, self._per_shard(wave), wave.num_zones,
+            wave.num_values, G_bucket, layout)
+        with phase_timer("probe"), self.mesh:
+            raw = program(wave.static, wave.carry, buf)
+            with device_wait():
+                arr = np.ascontiguousarray(jax.device_get(raw))
+        wave.tallies["group_d2h_bytes"] += arr.nbytes
+        # the mirror's copy is the host replay's input, booked with it
+        with phase_timer("replay"):
+            return (arr.reshape(G_bucket, N_STK_ROWS, wave.N),
+                    self.resident.usage())
+
+    def commit_group(self, wave: Wave, runs, G_bucket: int, layout, buf,
+                     counts_mat) -> None:
+        cm = np.zeros((G_bucket, wave.N), np.int64)
+        cm[:len(runs)] = counts_mat
+        self._fold(wave, self._apply_group_program(
+            wave.static, wave.N, self._per_shard(wave), layout,
+            empty=empty_leaves(wave.carry)), buf,
+            *_sparse_group_counts(cm))
+        for run, counts in zip(runs, counts_mat):
+            if counts.any():
+                self._note_commit(wave, run, counts)
+
+    def finish(self, wave: Wave) -> None:
+        self.resident.finish_wave(wave.carry, wave.L_host)

@@ -489,7 +489,7 @@ class ResidentClusterState:
         )
         updated = run(tuple(arrays), buf)
         # donated dispatches drain before their aliased buffers can be
-        # re-donated (see mesh._apply_run)
+        # re-donated (see mesh.MeshWaveScheduler._fold)
         with device_wait():
             jax.block_until_ready(updated)
         for (f, host, _s, _ax), dev in zip(fields, updated):

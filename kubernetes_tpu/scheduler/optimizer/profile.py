@@ -56,7 +56,7 @@ from kubernetes_tpu.metrics import (
 from kubernetes_tpu.models import hosttab
 from kubernetes_tpu.models.wave import (
     WaveScheduler,
-    _host_group_cap,
+    host_group_cap,
     config_eligible,
     group_buffer,
     run_verdict,
@@ -230,7 +230,7 @@ class OptimizingWaveDriver:
         config = self.config
         positions = [i for u in units for i in u["positions"]]
         reps = sorted({int(rep_idx[i]) for i in positions})
-        cap_g = _host_group_cap(N)
+        cap_g = host_group_cap(N)
         if len(reps) > cap_g:
             # templates beyond the probe-shipment cap route to the scan
             keep_reps = set(reps[:cap_g])

@@ -457,6 +457,45 @@ def test_every_jit_site_under_models_and_parallel_jits_a_named_function():
         assert arg.id not in ("run", "fn", "f"), where
 
 
+def test_the_wave_loops_arrows_point_down():
+    """models/waveloop knows no jax, no driver, nothing of `parallel`
+    or `scheduler`; the mesh driver takes no private name from the
+    single-chip driver's module or the loop's; and none of the three
+    holds a wave's state in closures again (`nonlocal`)."""
+    import ast
+    import os
+
+    import kubernetes_tpu
+
+    root = os.path.dirname(kubernetes_tpu.__file__)
+    trees = {f: ast.parse(open(os.path.join(root, f)).read())
+             for f in ("models/waveloop.py", "models/wave.py",
+                       "parallel/mesh.py")}
+
+    def imports(tree):
+        """(module, name) for every import, wherever it stands."""
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                for alias in node.names:
+                    yield alias.name, None
+            elif isinstance(node, ast.ImportFrom):
+                for alias in node.names:
+                    yield node.module, alias.name
+
+    for module, name in imports(trees["models/waveloop.py"]):
+        parts = (module + "." + (name or "")).split(".")
+        assert parts[0] != "jax", (module, name)
+        assert not set(parts) & {"parallel", "scheduler", "wave"}, \
+            (module, name)
+    for module, name in imports(trees["parallel/mesh.py"]):
+        if module in ("kubernetes_tpu.models.wave",
+                      "kubernetes_tpu.models.waveloop"):
+            assert not name.startswith("_"), (module, name)
+    for fname, tree in trees.items():
+        assert not [n.lineno for n in ast.walk(tree)
+                    if isinstance(n, ast.Nonlocal)], fname
+
+
 def test_the_mesh_drivers_programs_carry_their_own_names():
     """Every program the sharded driver builds says which it is in a
     trace and among the compiles: jit_mesh_scan, jit_mesh_group_probe,
