@@ -279,6 +279,13 @@ ZREPLAY_COUNTERS = ("zreplay_steps", "zreplay_slots", "zreplay_rescores",
 #: `pods_by_path["scan"]` is 1.0 while the loop ends at a wave's real
 #: count; `scan_bucket_steps` over `scan_steps` is the padding it skips
 SCAN_COUNTERS = ("scan_steps", "scan_bucket_steps")
+#: what `stats` counts of the wave loop itself, in both drivers, at the
+#: wave's end with `pods_by_path`: `steps_by_kind` ({kind: the `Step`s
+#: `waveloop.run_wave` ran}, a dict beside this tuple) and the calls of
+#: `waveloop.flush` that found pods pending: each is one dispatch of the
+#: scan and one wait for its picks, so `pods_by_path["scan"]` over it is
+#: the pods a scan dispatch decides
+LOOP_COUNTERS = ("scan_flushes",)
 #: what `stats` counts of the runs that carry a self-anti veto (pods
 #: whose required hostname anti-affinity term selects their own labels;
 #: `run_verdict`), which `waveloop.run_single` decides one probe a run: the
@@ -312,7 +319,8 @@ ENCODERS = ("incremental", "full")
 
 def count_group(stats: dict, counted: dict) -> None:
     """Some of `GROUP_COUNTERS`, `ZREPLAY_COUNTERS`, `SCAN_COUNTERS`,
-    `ANTI_COUNTERS`, `AFFINITY_COUNTERS` or `REWARM_COUNTERS` into a
+    `LOOP_COUNTERS`, `ANTI_COUNTERS`, `AFFINITY_COUNTERS` or
+    `REWARM_COUNTERS` into a
     driver's cumulative `stats`, and into the process-wide totals on
     /debug/traces."""
     for key, n in counted.items():
@@ -538,10 +546,12 @@ class WaveCounts:
         pods = dict(zip(PATHS, np.bincount(wave.via, minlength=len(PATHS))
                         .tolist()))
         unplaced = int(np.count_nonzero(wave.out < 0))
-        for path, n in pods.items():
-            self.stats["pods_by_path"][path] += n
+        steps = {kind: wave.steps[kind] for kind in PATHS}
+        for path in PATHS:
+            self.stats["pods_by_path"][path] += pods[path]
+            self.stats["steps_by_kind"][path] += steps[path]
         self.stats["pods_unplaced"] += unplaced
-        count_wave(pods, self.dispatches, unplaced)
+        count_wave(pods, self.dispatches, unplaced, steps)
         if wave.tallies:
             count_group(self.stats, dict(wave.tallies))
         count_runs(self.stats, wave.snap, wave.batch, runs)
@@ -625,6 +635,9 @@ class WaveScheduler(WaveCounts):
             **dict.fromkeys(ZREPLAY_COUNTERS, 0),
             # the scan's loop (`scan_rows`), all waves
             **dict.fromkeys(SCAN_COUNTERS, 0),
+            # the wave loop's steps by kind and its flushes of the scan
+            "steps_by_kind": dict.fromkeys(PATHS, 0),
+            **dict.fromkeys(LOOP_COUNTERS, 0),
             # the runs with a self-anti veto (`run_single`), all waves
             **dict.fromkeys(ANTI_COUNTERS, 0),
             # the runs whose pod owns a required podAffinity term, and
@@ -1065,6 +1078,25 @@ class WaveScheduler(WaveCounts):
             device_zoned=self._device_zoned, zoned=wave.zoned, gangs=gangs,
         )
         return runs, Policy(host_group_cap(wave.N))
+
+    def run_kinds(self, snap: ClusterSnapshot, batch: PodBatch,
+                  reps: Sequence[int]) -> List[str]:
+        """What a run of `min_run` pods of each pod row `reps` is to the
+        plan: "scan" (the scan's, whatever its length), "device" (a
+        device replay's, alone or grouped with its like), "pure" (a
+        grouped header probe's with its like, else a probe's of its
+        own) or "single" (a probe's of its own whatever its neighbours:
+        a veto, a term owner). It says which runs `waveloop.next_step`
+        puts into one step, and so which programs they meet (the
+        daemon's re-warm deals its warm runs by it)."""
+        runs = classify_runs(
+            self.config, snap, batch,
+            [(int(rep), 0, self.min_run) for rep in reps],
+            int(snap.svc_num_values), self.min_run,
+            device_zoned=self._device_zoned,
+            zoned=bool(np.any(np.asarray(snap.zone_id) > 0)))
+        return ["scan" if not run.eligible else "device" if run.device
+                else "pure" if run.pure else "single" for run in runs]
 
     # -- the device seam (models/waveloop.run_wave) --------------------------
 

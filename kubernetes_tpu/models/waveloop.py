@@ -235,11 +235,15 @@ class Wave:
     #: backlog positions the fast paths left to the scan
     pending: List[int] = field(default_factory=list)
     #: what the wave's steps did, under the names of `models/wave`'s
-    #: GROUP_, ZREPLAY_ and ANTI_COUNTERS; into the driver's `stats`
-    #: with the wave's other tallies at its END: a read in the middle
-    #: of a wave never finds picks ahead of the pods decided (one chip
-    #: run's `anti_run_share.fill` read 101.4)
+    #: GROUP_, ZREPLAY_ and ANTI_COUNTERS and `scan_flushes`; into the
+    #: driver's `stats` with the wave's other tallies at its END: a read
+    #: in the middle of a wave never finds picks ahead of the pods
+    #: decided (one chip run's `anti_run_share.fill` read 101.4)
     tallies: Counter = field(default_factory=Counter)
+    #: the `Step`s the loop ran, by kind (PATHS): a group that stopped
+    #: early and the `run_single` that finished its run are one each;
+    #: `stats["steps_by_kind"]` at the wave's end, like `via`
+    steps: Counter = field(default_factory=Counter)
 
     def __post_init__(self):
         zone_id = np.asarray(self.snap.zone_id)
@@ -502,6 +506,7 @@ def run_wave(dev, wave: Wave, runs: Sequence[Run], policy: Policy) -> None:
     idx = 0
     while idx < len(runs):
         step = next_step(runs, idx, policy)
+        wave.steps[step.kind] += 1
         if step.kind == "scan":
             for run in step.runs:
                 wave.pending.extend(range(run.start, run.stop))
@@ -520,6 +525,8 @@ def run_wave(dev, wave: Wave, runs: Sequence[Run], policy: Policy) -> None:
             idx += len(step.runs)
         else:
             g, done = stopped
+            if step.kind != "single":
+                wave.steps["single"] += 1  # the run the group broke off at
             run_single(dev, wave, step.runs[g], done)
             idx += g + 1
     flush(dev, wave)
@@ -528,10 +535,12 @@ def run_wave(dev, wave: Wave, runs: Sequence[Run], policy: Policy) -> None:
 
 def flush(dev, wave: Wave) -> None:
     """The scan decides what the fast paths left in `pending`, in FIFO
-    order before the next of them runs."""
+    order before the next of them runs: one dispatch of the scan and one
+    wait for its picks a call that finds pods pending (`scan_flushes`)."""
     if not wave.pending:
         return
     rows = np.asarray(wave.pending, np.int64)
+    wave.tallies["scan_flushes"] += 1
     wave.via[rows] = _SCAN
     wave.out[rows], wave.L_host = dev.scan_pending(wave, rows)
     wave.pending.clear()

@@ -67,6 +67,7 @@ from kubernetes_tpu.models.wave import (
     AFFINITY_COUNTERS,
     ANTI_COUNTERS,
     GROUP_COUNTERS,
+    LOOP_COUNTERS,
     PATHS,
     WaveCounts,
     classify_runs,
@@ -1163,6 +1164,8 @@ class MeshWaveScheduler(WaveCounts):
             "waves": 0, "dispatches": 0, "dispatches_by_kind": {},
             "pods_by_path": dict.fromkeys(PATHS, 0), "pods_unplaced": 0,
             **dict.fromkeys(GROUP_COUNTERS, 0),
+            "steps_by_kind": dict.fromkeys(PATHS, 0),
+            **dict.fromkeys(LOOP_COUNTERS, 0),
             **dict.fromkeys(ANTI_COUNTERS, 0),
             **dict.fromkeys(AFFINITY_COUNTERS, 0), "scan_reasons": {},
             "picks_by_shard": [0] * int(mesh.devices.size),

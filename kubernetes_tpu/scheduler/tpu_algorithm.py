@@ -22,7 +22,7 @@ from kubernetes_tpu.trace import profile as trace_profile
 
 log = logging.getLogger(__name__)
 
-#: after this long the re-warm of the scan starts no further pod bucket
+#: after this long the re-warm starts no further pod bucket of the scan
 #: and hands the loop back for a wave (`TPUScheduleAlgorithm._rewarm`):
 #: from the compile cache all seven buckets take seconds; where they
 #: compile, 20-45 s each on the chip, two or three fit and the last one
@@ -139,17 +139,21 @@ class TPUScheduleAlgorithm:
             # unchanged incremental view ships zero node-table bytes)
             self._inc = self._new_encoder()
             cache.add_listener(self._inc.on_cache_event)
-        # the re-warm of the scan at inter-pod widths first seen
-        # (`_rewarm`): the daemon's own encoder (a warm-up swaps
-        # `_inc`), a pod of every template seen pending (by feature
-        # key), whether its pods take the scan as a rule, the widths
-        # already warmed, the widths being warmed and their pod buckets
-        # still to warm, and the last wave's widths
+        # the re-warm at inter-pod widths first seen (`_rewarm`): the
+        # daemon's own encoder (a warm-up swaps `_inc`), a pod of every
+        # template seen pending (by feature key) and the kind of step a
+        # run of it makes (`WaveScheduler.run_kinds`, once terms are
+        # live), whether its pods take the scan as a rule, the widths
+        # already warmed, the widths being warmed, whether their run
+        # programs and which of their pod buckets are still to warm, and
+        # the last wave's widths
         self._live_inc = self._inc
         self._templates: dict = {}
+        self._template_kinds: dict = {}
         self._warmed_widths: set = set()
         self._scan_bound = False
         self._rewarm_widths = None
+        self._rewarm_runs = False
         self._rewarm_left: List[int] = []
         self._last_widths = None
         self._service_lister = service_lister
@@ -238,16 +242,20 @@ class TPUScheduleAlgorithm:
         slots, the scan at every pod bucket, nine row-scatter bucket
         pairs); behind the first wave that shows a set of inter-pod
         widths on a cluster whose pods take the scan as a rule
-        (`_after_live_wave` says by what evidence), the scan again at
-        those widths and every pod bucket (`_rewarm`, with
-        KUBERNETES_TPU_WARM_SCAN on: the terms are the pods'
-        annotations, which nothing here can know). Still
-        warmed by nobody, and compiled where first met: the grouped
-        programs at the run-slot buckets a wave's run count makes (8 to
-        128), the spread-class axis while it grows as controllers'
-        pods first appear, and the probes and the fold at inter-pod
-        widths (a benchmark mix's prefill steps meet them:
-        PERF.md section 7)."""
+        (`_after_live_wave` says by what evidence), at those widths
+        the run programs on the kinds of run the templates seen pending
+        make (a run alone, its like side by side, the grouped programs
+        at their two smallest run-slot buckets) and the scan again at
+        every pod bucket (`_rewarm`, with KUBERNETES_TPU_WARM_SCAN on:
+        the terms are the pods' annotations, which nothing here can
+        know). Still warmed by nobody, and compiled where first met:
+        the grouped programs at the larger run-slot buckets a wave's
+        run count makes (32 to 128), the spread-class axis while it
+        grows as controllers' pods first appear, the probes and the
+        fold at inter-pod widths on a cluster whose pods do NOT take
+        the scan as a rule (no re-warm there), and the transfers'
+        unpack programs, one a set of tables shipped (a benchmark mix's
+        prefill steps meet those: PERF.md section 7)."""
         from kubernetes_tpu.api.types import (
             Container,
             Node,
@@ -557,9 +565,11 @@ class TPUScheduleAlgorithm:
     def _after_live_wave(self, reps, keys, state, snap, batch,
                          scanned: int) -> None:
         """Behind a wave of the daemon's own (never a warm-up's): keep a
-        pod of every template seen pending, and warm the scan where the
-        wave's inter-pod widths are new, or buckets are still left, on
-        a cluster whose pods take the scan as a rule. The evidence for
+        pod of every template seen pending and, once terms are live,
+        the kind of step a run of it makes; and warm the scan and the
+        run programs where the wave's inter-pod widths are new, or
+        buckets are still left, on a cluster whose pods take the scan
+        as a rule. The evidence for
         that, since a warm wave costs its whole bucket of steps (2 ms a
         step with ten logical terms on the chip: 30 s for the seven
         buckets from the compile cache; PERF.md, PR 45): a template that
@@ -570,54 +580,62 @@ class TPUScheduleAlgorithm:
         every term is the run tables' (a hostname anti-affinity term)
         the scan meets these widths only through the run a wave's end
         cuts short, in its smallest bucket, which that wave builds."""
-        from kubernetes_tpu.models.wave import run_verdict
-
         widths = self._last_widths
-        if widths is not None and widths not in self._warmed_widths:
+        if len(self._templates) + len(reps) > 8192:
+            # as PendingRows.MAX_ROWS bounds rows
+            self._templates.clear()
+            self._template_kinds.clear()
+        if widths is not None:
             fresh = [i for i, k in enumerate(keys)
-                     if k not in self._templates]
+                     if k not in self._template_kinds]
+            self._template_kinds.update(
+                (keys[i], kind) for i, kind in zip(
+                    fresh, self._wave.run_kinds(snap, batch, fresh)))
+        if widths is not None and widths not in self._warmed_widths:
             self._scan_bound = (
                 self._scan_bound or scanned > self._wave.pod_floor
-                or any(run_verdict(self._wave.config, batch, i, snap)[0]
-                       is not None for i in fresh))
+                or any(self._template_kinds[k] == "scan" for k in keys))
             if self._scan_bound:
                 # widths newer than the ones being warmed take their
                 # place: what was left of those serves a cluster that
                 # is gone
                 self._warmed_widths.add(widths)
                 self._rewarm_widths = widths
+                self._rewarm_runs = True
                 self._rewarm_left = self._pod_buckets()
-        if len(self._templates) + len(reps) > 8192:
-            self._templates.clear()  # as PendingRows.MAX_ROWS bounds rows
         self._templates.update(zip(keys, reps))
         if self._rewarm_left:
             self._rewarm(state)
 
     def _rewarm(self, state) -> None:
-        """Warm `jit_batch_scan` (and the transfers round it) at the
-        inter-pod widths a wave has just shown, for every pod bucket
-        from `pod_floor` to the wave cap, smallest first, before the
-        loop decides its next wave. `warmup` cannot: it runs before a
-        pod arrives and knows the controllers' selectors, not their
-        pods' annotations, so its programs have zero-width inter-pod
-        tables; and the scan is traced per width of those tables and
-        per pod bucket, so every bucket a later wave lands in first
-        would compile there, 20-45 s on the chip, inside a measured
-        window or a check batch (PERF.md, PRs 28, 35 and 45).
+        """Warm the wave programs at the inter-pod widths a wave has just
+        shown, before the loop decides its next wave: the run programs
+        on the kinds of run the templates seen pending make, then
+        `jit_batch_scan` (and the transfers round it) for every pod
+        bucket from `pod_floor` to the wave cap, smallest first.
+        `warmup` cannot: it runs before a pod arrives and knows the
+        controllers' selectors, not their pods' annotations, so its
+        programs have zero-width inter-pod tables; and every wave
+        program is traced per width of those tables (the scan per pod
+        bucket besides), so each would compile where a later wave first
+        meets it, 20-45 s on the chip, inside a measured window or a
+        check batch (PERF.md, PRs 28, 35, 45 and 49).
 
         Through `_warm_one`'s seam: a throwaway encoder fed the nodes
         and bound pods of the wave's own snapshot of the scheduler
         cache, so that its vocabularies, and so its widths, are the live
-        ones; backlogs of a pod of every template seen pending, dealt
-        in turn (runs of length 1: the scan). The live encoder,
-        `_last_node_index` and the driver's device mirrors are as they
-        were afterwards. Where the warm view's widths are not the live
-        ones (a term only deleted pods carried) that is counted
-        (`rewarm_mismatches`) and logged. It starts no further bucket
-        after REWARM_SLICE_S and goes on behind the next wave. Counted in
-        `stats` (`rewarms`, `rewarm_seconds`, `rewarm_programs`) and as
-        the span `scheduler.rewarm`; its time on the timeline is the
-        warm waves' own phases (`encode`, `transfer`, `score`)."""
+        ones. The run programs by ONE backlog (`_warm_runs`), the scan
+        by backlogs of a pod of every template seen pending, dealt in
+        turn (runs of length 1). The live encoder, `_last_node_index`
+        and the driver's device mirrors are as they were afterwards.
+        Where the warm view's widths are not the live ones (a term only
+        deleted pods carried) that is counted (`rewarm_mismatches`) and
+        logged. It starts no further warm wave after REWARM_SLICE_S and
+        goes on behind the next wave. Counted in `stats` (`rewarms`,
+        `rewarm_seconds`, `rewarm_programs`) and as the span
+        `scheduler.rewarm` (`steps`: the steps its run backlog made, by
+        kind); its time on the timeline is the warm waves' own phases
+        (`encode`, `transfer`, `probe`, `replay`, `score`)."""
         import time
 
         from kubernetes_tpu.models.wave import count_group
@@ -633,17 +651,31 @@ class TPUScheduleAlgorithm:
         wave = self._wave
         mirrors = wave._dev, wave._dev_source
         wave._dev, wave._dev_source = {}, None
-        buckets, off = [], 0
+        buckets, steps, waves, off = [], {}, 0, 0
+
+        def warm(backlog):
+            nonlocal waves, off
+            self._warm_one_locked(backlog, state, nodes, bound, [inc])
+            waves += 1
+            off += self._last_widths != widths
+
         try:
-            while self._rewarm_left:
+            if self._rewarm_runs:
+                self._rewarm_runs = False
+                backlog = self._warm_runs()
+                if backlog:
+                    ran = dict(wave.stats["steps_by_kind"])
+                    warm(backlog)
+                    steps = {kind: n - ran[kind] for kind, n in
+                             wave.stats["steps_by_kind"].items()
+                             if n > ran[kind]}
+            # a warm wave at the least; then the loop's turn once the
+            # slice is spent, and the rest behind its next wave
+            while self._rewarm_left and (
+                    not waves or time.time() - began < REWARM_SLICE_S):
                 bucket = self._rewarm_left.pop(0)
-                self._warm_one_locked(
-                    [templates[i % len(templates)] for i in range(bucket)],
-                    state, nodes, bound, [inc])
+                warm([templates[i % len(templates)] for i in range(bucket)])
                 buckets.append(bucket)
-                off += self._last_widths != widths
-                if time.time() - began >= REWARM_SLICE_S:
-                    break  # the loop's turn; the rest behind its wave
         finally:
             wave._dev, wave._dev_source = mirrors
             self._last_widths = widths
@@ -653,18 +685,74 @@ class TPUScheduleAlgorithm:
         if off:
             counted["rewarm_mismatches"] = off
             log.warning("re-warm: %d of %d warm waves had other inter-pod "
-                        "widths than the live %s", off, len(buckets),
+                        "widths than the live %s", off, waves,
                         dict(zip(WIDTH_NAMES, widths)))
         count_group(wave.stats, counted)
-        log.info("re-warmed the scan at %s, pod buckets %s, in %.1fs (%d "
-                 "programs; %d buckets left)", dict(zip(WIDTH_NAMES, widths)),
-                 buckets, ended - began, counted["rewarm_programs"],
+        log.info("re-warmed at %s: the runs' steps %s, the scan's pod "
+                 "buckets %s, in %.1fs (%d programs; %d buckets left)",
+                 dict(zip(WIDTH_NAMES, widths)), steps, buckets,
+                 ended - began, counted["rewarm_programs"],
                  len(self._rewarm_left))
         trace_span.record_span(
             "scheduler.rewarm", trace_span.new_trace_id(), began, ended,
-            buckets=buckets, left=len(self._rewarm_left),
+            buckets=buckets, steps=steps, left=len(self._rewarm_left),
             programs=counted["rewarm_programs"],
             **dict(zip(WIDTH_NAMES, widths)))
+
+    def _warm_runs(self) -> List[Pod]:
+        """The re-warm's one backlog of runs: `min_run` pods in a row of
+        every template seen pending whose runs the run machinery takes
+        (`_template_kinds`), dealt so that the plan holds every kind of
+        step they make on a live wave: each run alone between two scan
+        stretches (a `single`: the device replay of one run, or the
+        probe and its fold, with the veto where the template has one);
+        then all of them side by side, like kinds together (a
+        `group_device` or `group_host` of what groups, a probe that
+        carries the fold before it for what does not); then, of each
+        kind that groups, a group one run over the smallest run-slot
+        bucket, which is the next bucket (a live wave's neighbours are
+        seldom more: the grouped programs are traced a bucket). A scan
+        stretch is one pod of a template that is neither neighbour's: a
+        run too short for anything else; the templates take turns at it,
+        the scan's own first, and those it never reached close the
+        backlog, because a wave's programs are traced per width of its
+        pods' own term lists and of the spread classes too: a warm wave
+        has to hold every template a live one does. Empty where no
+        template's runs leave the scan: its buckets are then all there
+        is to warm."""
+        by_kind: dict = {}
+        for key, pod in self._templates.items():
+            by_kind.setdefault(self._template_kinds.get(key), []).append(pod)
+        runs = [pod for kind in ("device", "pure", "single")
+                for pod in by_kind.get(kind, ())]
+        if not runs:
+            return []
+        row = max(self._wave.min_run, 2)
+        lone = [pod for kind in ("scan", None, "device", "pure", "single")
+                for pod in by_kind.get(kind, ())]
+        backlog: List[Pod] = []
+
+        def stretch(nxt):
+            """One pod between the backlog's last run and `nxt`'s."""
+            for i, pod in enumerate(lone):
+                if pod is not backlog[-1] and pod is not nxt:
+                    lone.append(lone.pop(i))  # the next turn is another's
+                    backlog.append(pod)
+                    return
+
+        for pod, nxt in zip(runs, runs[1:] + runs[:1]):
+            backlog += [pod] * row
+            stretch(nxt)
+        for pod in runs:
+            backlog += [pod] * row
+        for kind in ("device", "pure"):
+            pair = by_kind.get(kind, [])[:2]
+            if len(pair) == 2:
+                stretch(pair[0])
+                for i in range(self._wave.group_floor + 1):
+                    backlog += [pair[i % 2]] * row
+        held = {id(pod) for pod in backlog}
+        return backlog + [pod for pod in lone if id(pod) not in held]
 
     def _schedule_backlog_mesh(
         self, pods: Sequence[Pod], state: ClusterState

@@ -474,6 +474,9 @@ _wave_lock = threading.Lock()
 #: inter-pod tables whole, by reason; served on /debug/traces as "wave"
 _WAVE: Dict[str, Any] = {"waves": 0, "pods_by_path": {},
                          "dispatches_by_kind": {}, "pods_unplaced": 0,
+                         # models/wave.LOOP_COUNTERS: the wave loop's
+                         # steps by kind, its flushes of the scan
+                         "steps_by_kind": {}, "scan_flushes": 0,
                          "group_runs": 0, "group_d2h_bytes": 0,
                          "group_reprobes": 0, "zreplay_steps": 0,
                          "zreplay_slots": 0, "zreplay_rescores": 0,
@@ -493,15 +496,16 @@ _WAVE: Dict[str, Any] = {"waves": 0, "pods_by_path": {},
 
 
 def count_wave(pods_by_path: Dict[str, int], dispatches: Dict[str, int],
-               unplaced: int) -> None:
+               unplaced: int, steps_by_kind: Dict[str, int]) -> None:
     """One wave is decided: `pods_by_path` pods went through each path,
     `dispatches` programs were launched by kind, `unplaced` pods
-    fitted nowhere."""
+    fitted nowhere, and the loop ran `steps_by_kind` steps."""
     with _wave_lock:
         _WAVE["waves"] += 1
         _WAVE["pods_unplaced"] += unplaced
         for key, add in (("pods_by_path", pods_by_path),
-                         ("dispatches_by_kind", dispatches)):
+                         ("dispatches_by_kind", dispatches),
+                         ("steps_by_kind", steps_by_kind)):
             tally = _WAVE[key]
             for k, n in add.items():
                 tally[k] = tally.get(k, 0) + n
@@ -513,7 +517,8 @@ def count_wave_group(counted: Dict[str, int]) -> None:
     a grouped device replay came back: the steps and run slots its
     loops ran, the steps that rescored, the pods it placed
     (`zreplay_*`); or a scan came back: the steps its loop ran and its
-    pod bucket holds (`scan_*`); or a run with a self-anti veto was
+    pod bucket holds (`scan_*`); or the loop handed pods to the scan
+    (`scan_flushes`); or a run with a self-anti veto was
     decided (`anti_*`); or a wave held runs that own a required podAffinity
     term (`affinity_*`); or the daemon warmed the scan again
     (`rewarm*`)."""

@@ -1190,3 +1190,284 @@ def test_terms_the_run_tables_hold_rewarm_only_once_the_scan_is_used(
     assert algo._warmed_widths == {algo._last_widths}
     wave([_anti_pod(t % 10, 200 + t) for t in range(70)])
     assert stats["rewarms"] == 1
+
+
+# -- a cluster of five kinds of pod: one wave that changes path run by run ----
+
+
+def _mixed_pod(t, i, groups=5):
+    """A replica of controller `t` of benchmark/configs/mixed-5k.json's
+    ten: kind t % 5 of (0) no annotation, (1) a required podAffinity
+    term over the zone, (2) a required podAntiAffinity term over the
+    hostname, (3) a preferred podAffinity term, weight 1, over the
+    hostname, (4) a preferred podAntiAffinity term, weight 1, over the
+    hostname, each on its own service (controllers t and t + groups);
+    cpu 100m and memory 500Mi stated."""
+    import json
+
+    from kubernetes_tpu.api.types import AFFINITY_ANNOTATION
+
+    k = t % groups
+    selector = {"matchExpressions": [{
+        "key": "group", "operator": "In",
+        "values": [f"g{k}", f"g{k + groups}"]}]}
+    required = "requiredDuringSchedulingIgnoredDuringExecution"
+    preferred = "preferredDuringSchedulingIgnoredDuringExecution"
+    soft = [{"weight": 1, "podAffinityTerm": {
+        "labelSelector": selector, "topologyKey": HOSTNAME}}]
+    stated = [None,
+              {"podAffinity": {required: [{
+                  "labelSelector": selector, "topologyKey": ZONE}]}},
+              {"podAntiAffinity": {required: [{
+                  "labelSelector": selector, "topologyKey": HOSTNAME}]}},
+              {"podAffinity": {preferred: soft}},
+              {"podAntiAffinity": {preferred: soft}}][k]
+    return Pod(
+        metadata=ObjectMeta(
+            name=f"mix{t}-{i:05d}", labels={"group": f"g{t}"},
+            annotations={} if stated is None else {
+                AFFINITY_ANNOTATION: json.dumps(stated)}),
+        spec=PodSpec(containers=[Container(requests={
+            "cpu": "100m", "memory": "500Mi"})]))
+
+
+def _mixed_rows(controllers, replicas, serial=0):
+    return [_mixed_pod(t, serial + 100 * j + i)
+            for j, t in enumerate(controllers) for i in range(replicas)]
+
+
+def _steps_and_flushes(algo):
+    """Wrap the loop's planner and the seam's scan: the steps
+    `next_step` handed `run_wave` and the scans the loop dispatched, of
+    the daemon's own waves and of its warm waves alike."""
+    from kubernetes_tpu.models import waveloop
+
+    seen = {"steps": [], "scans": 0}
+    plan, scan = waveloop.next_step, algo._wave.scan_pending
+
+    def next_step(runs, idx, policy):
+        step = plan(runs, idx, policy)
+        seen["steps"].append(step.kind)
+        return step
+
+    def scan_pending(wave, rows):
+        seen["scans"] += 1
+        return scan(wave, rows)
+
+    return seen, next_step, scan_pending
+
+
+#: (one-zone nodes, the controllers of a round's runs in order, pods a
+#: run, share deleted between rounds, seed): every stream begins with
+#: one pod of each controller (the escape of service 1, a
+#: preferred-affinity maximum above 0 from then on); a round's wave
+#: then goes scan -> device single with the veto -> scan -> device
+#: group of two plain runs -> scan -> device single, and the round
+#: after it deals the same runs in a seeded order. Small, because the
+#: serial oracle takes a second a pod once a few hundred are bound
+MIXED_CASES = [(48, (1, 2, 3, 0, 5, 4, 7), 16, 0.5, 49),
+               (24, (8, 7, 6, 5, 0, 9, 2, 3), 16, 1.0, 2 ** 31 + 49)]
+
+
+@pytest.mark.parametrize("n,runs,row,deleted,seed", MIXED_CASES)
+def test_a_mixed_wave_changes_path_run_by_run_and_picks_as_the_serial_oracle(
+        n, runs, row, deleted, seed, monkeypatch):
+    import itertools
+    import random
+
+    from kubernetes_tpu.models import waveloop
+
+    rng = random.Random(seed)
+    nodes, controllers = _nodes(n, "a"), _anti_controllers()
+    cache, algo = _daemon(nodes, controllers)
+    seen, next_step, scan_pending = _steps_and_flushes(algo)
+    monkeypatch.setattr(waveloop, "next_step", next_step)
+    monkeypatch.setattr(algo._wave, "scan_pending", scan_pending)
+    oracle = _a_serial_oracle()
+    live = []
+    shown = profile.wave_totals()
+    for r in range(3):
+        backlog = _mixed_rows(rng.sample(range(10), 10), 1) if r == 0 \
+            else _mixed_rows(runs if r == 1 else rng.sample(runs, len(runs)),
+                             row, serial=10_000 * r)
+        state = cache.snapshot(controllers=controllers)
+        got = algo.schedule_backlog(backlog, state)
+        assert got == oracle.schedule_backlog(backlog, state.clone())
+        if r == 1:
+            # kinds 0 and 2 take the device replay, neighbours as a group
+            want = []
+            for device, span in itertools.groupby(
+                    runs, key=lambda t: t % 5 in (0, 2)):
+                want.append("scan" if not device else "single"
+                            if len(list(span)) == 1 else "group_device")
+            assert seen["steps"][-len(want):] == want
+            assert {"scan", "single", "group_device"} == set(want)
+        for p, host in zip(backlog, got):
+            if host is not None:
+                p.spec.node_name = host
+                cache.add_pod(p)
+                live.append(p)
+        # the read-back guarantee: one pod of the anti-affine service a node
+        held = [p.spec.node_name for p in live
+                if int(p.metadata.labels["group"][1:]) % 5 == 2]
+        assert len(held) == len(set(held))
+        gone = rng.sample(range(len(live)), int(deleted * len(live)))
+        for i in sorted(gone, reverse=True):
+            cache.remove_pod(live.pop(i))
+    stats = algo._wave.stats
+    # the three services of refused terms go to the scan, by reason
+    assert set(stats["scan_reasons"]) == {"hard_affinity", "self_preferred"}
+    # on one zone a plain run and a vetoed run both take the device
+    # replay, alone or as neighbours; no probe reaches the host
+    kinds = stats["steps_by_kind"]
+    assert kinds["scan"] and kinds["single"] and kinds["group_device"]
+    assert kinds["group_host"] == 0 and stats["anti_nodes_excluded"] == 0
+    assert stats["anti_runs"] > 0
+    # the counters are the plan's steps and the scan's dispatches
+    assert sum(kinds.values()) == len(seen["steps"])
+    assert {k: n for k, n in kinds.items() if n} \
+        == {k: seen["steps"].count(k) for k in set(seen["steps"])}
+    assert stats["scan_flushes"] == seen["scans"] \
+        == stats["dispatches_by_kind"]["scan"]
+    # and /debug/traces moved by the same
+    after = profile.wave_totals()
+    assert _delta(after["steps_by_kind"], shown["steps_by_kind"]) == kinds
+    assert after["scan_flushes"] - shown["scan_flushes"] \
+        == stats["scan_flushes"]
+
+
+def test_a_group_that_breaks_off_counts_its_single_step_too(monkeypatch):
+    """Three pure runs planned as one group; the group stops in its
+    second run, which goes on as a `single`, and the third is planned
+    again, alone: one `group_host` step and two `single` steps."""
+    from collections import Counter
+
+    from kubernetes_tpu.models import waveloop
+
+    runs = [waveloop.Run(rep, 16 * rep, 16, eligible=True, pure=True)
+            for rep in range(3)]
+    ran = []
+    monkeypatch.setattr(
+        waveloop, "run_group_host",
+        lambda dev, wave, group: ran.append(len(group)) or (1, 5))
+    monkeypatch.setattr(
+        waveloop, "run_single",
+        lambda dev, wave, run, done=0: ran.append((run.rep, done)))
+    wave = SimpleNamespace(steps=Counter(), tallies=Counter(), pending=[])
+    waveloop.run_wave(SimpleNamespace(finish=lambda wave: None), wave, runs,
+                      waveloop.Policy(host_cap=8))
+    assert ran == [3, (1, 5), (2, 0)]
+    assert wave.steps == {"group_host": 1, "single": 2}
+    assert wave.tallies["scan_flushes"] == 0  # nothing was pending
+
+
+def test_both_drivers_keep_the_loops_counters():
+    import jax
+    from jax.sharding import Mesh
+
+    from kubernetes_tpu.models.wave import LOOP_COUNTERS, WaveScheduler
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.parallel.mesh import MeshWaveScheduler
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+    from kubernetes_tpu.trace.httpd import render_traces
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("nodes",))
+    on_mesh, on_chip = MeshWaveScheduler(mesh).stats, WaveScheduler().stats
+    assert LOOP_COUNTERS == ("scan_flushes",)
+    for stats in (on_mesh, on_chip):
+        assert stats["scan_flushes"] == 0
+        assert stats["steps_by_kind"] == dict.fromkeys(PATHS, 0)
+    # the mesh's loop is the same loop: rows, then lone pods
+    backlog = _shaped_rows(4, 40) + _dealt_in_turn(4, 3)
+    state = ClusterState.build(_nodes(30, ""), controllers=_controllers(4))
+    algo = TPUScheduleAlgorithm(mesh=mesh)
+    assert algo.schedule_backlog(backlog, state) == _oracle(state, backlog)
+    stats = algo._wave.stats
+    assert stats["steps_by_kind"] == {"scan": 1, "single": 0,
+                                      "group_host": 1, "group_device": 0}
+    assert stats["scan_flushes"] == 1
+    shown = render_traces({"limit": "1"})["wave"]
+    assert {"steps_by_kind", "scan_flushes"} <= set(shown)
+    assert set(shown["steps_by_kind"]) <= set(PATHS)
+
+
+def test_the_rewarm_of_a_mixed_cluster_warms_the_run_programs_once(
+        monkeypatch):
+    """On a cluster where inter-pod widths are live and some templates'
+    runs leave the scan, the re-warm also runs one backlog of runs of
+    every template seen pending: each eligible run alone, side by side
+    and as a group over the smallest run-slot bucket, scan stretches
+    between them. Afterwards a live wave of any such plan builds no
+    scheduling program (the transfers' unpack programs are the
+    prefill's), nothing is built twice, and the live encoder, the
+    round-robin counter and the driver's mirrors are what a daemon
+    that never re-warmed holds."""
+    import time
+
+    from kubernetes_tpu.trace import spans
+
+    profile.install_compile_listener()
+    controllers = _anti_controllers()
+    first = lambda: _mixed_rows(range(10), 1)  # noqa: E731
+    second = lambda: _mixed_rows(  # noqa: E731
+        (0, 5, 2, 1, 7, 3, 0, 4, 2, 7, 5, 0, 9), 16, serial=1000)
+
+    def two_waves(warm):
+        if warm:
+            monkeypatch.setenv("KUBERNETES_TPU_WARM_SCAN", "1")
+        else:
+            monkeypatch.delenv("KUBERNETES_TPU_WARM_SCAN", raising=False)
+        cache, algo = _daemon(_nodes(48, "a"), controllers)
+        seen = _recorded_waves(algo)
+        picks, built = [], []
+        for backlog in (first(), second()):
+            state = cache.snapshot(controllers=controllers)
+            t = time.time()
+            got = algo.schedule_backlog(backlog, state)
+            built.append([c["program"] for c in profile.recent_compiles()
+                          if c["at"] >= t])
+            picks.append(got)
+            for p, host in zip(backlog, got):
+                p.spec.node_name = host
+                cache.add_pod(p)
+        return cache, algo, seen, picks, built
+
+    t_began = time.time()
+    cache, algo, seen, picks, built = two_waves(warm=True)
+    _cache, plain, seen_plain, picks_plain, _built = two_waves(warm=False)
+    stats = algo._wave.stats
+    assert stats["rewarms"] == 1 and stats["rewarm_mismatches"] == 0
+    assert picks == picks_plain
+    assert list(algo._template_kinds.values()) == [
+        "device", "scan", "device", "scan", "scan"] * 2
+    # the wave behind the re-warm meets every kind of step and builds
+    # no scheduling program: the re-warm had them all
+    assert not [p for p in built[1]
+                if "scan" in p or "zreplay" in p or "probe" in p
+                or "apply" in p], built[1]
+    # nothing twice: every program of the re-warm's wave differs
+    warm_built = [p for p in built[0] if "zreplay" in p or "scan" in p]
+    assert sorted(warm_built).count("jit(zreplay_run)") == 1
+    assert sorted(warm_built).count("jit(zreplay_group)") == 2  # 8, 16 slots
+    assert sorted(warm_built).count("jit(batch_scan)") == 7
+    # the wave after the re-warm: the same snapshot, batch, `keep`,
+    # `reship` and round-robin counter as without it
+    assert len(seen) == len(seen_plain) == 2
+    for (snap, batch, keep, reship, last), \
+            (snap_p, batch_p, keep_p, reship_p, last_p) in zip(seen,
+                                                               seen_plain):
+        _same_arrays(snap, snap_p)
+        _same_arrays(batch, batch_p)
+        assert (keep, reship, last) == (keep_p, reship_p, last_p)
+    assert algo._last_node_index == plain._last_node_index
+    # one span: the steps its run backlog made, by kind, and the buckets
+    mine = [s for s in spans.BUFFER.snapshot(limit=16384)
+            if s["name"] == "scheduler.rewarm" and s["start"] >= t_began]
+    assert len(mine) == 1
+    attrs = mine[0]["attrs"]
+    assert attrs["buckets"] == [64, 128, 256, 512, 1024, 2048, 4096]
+    assert attrs["steps"]["single"] == 4  # each eligible template alone
+    assert attrs["steps"]["group_device"] == 2  # 4 side by side; 9 of two
+    assert attrs["steps"]["scan"] >= 5
+    # a cluster whose every template is the scan's warms no run backlog
+    assert plain._wave.stats["rewarms"] == 0
