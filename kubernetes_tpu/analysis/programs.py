@@ -336,7 +336,8 @@ def build_programs(include_mesh: bool = True, num_nodes: int = 13,
               jnp.asarray(np.int32(K)), np.int64(0)),
         allow_f64=True,  # mirrors replay._scores float64 exactly
         carry_out_leaves=carry_leaves,
-        expected_host_leaves=4,  # chosen, counts, L, n_done
+        # chosen, counts, L, n_done, the loop's own counters [2]
+        expected_host_leaves=5,
         notes="zoned-spread device replay (models/zreplay)",
     ))
     Gz = 8

@@ -152,11 +152,17 @@ def interpod_carry_tables(static, ip_term_count, num_nodes):
     )
 
 
+def wants_interpod(config: "SchedulerConfig") -> bool:
+    """The config reads the inter-pod tables: the predicate or the
+    priority."""
+    return (MATCH_INTER_POD_AFFINITY in config.predicates
+            or any(n == INTER_POD_AFFINITY for n, _ in config.priorities))
+
+
 def interpod_views(config: "SchedulerConfig", static, carry):
     """The views of a carry's five inter-pod tables (ops/interpod.Views);
     None where the config has neither the predicate nor the priority."""
-    if not (MATCH_INTER_POD_AFFINITY in config.predicates
-            or any(n == INTER_POD_AFFINITY for n, _ in config.priorities)):
+    if not wants_interpod(config):
         return None
     return IP.interpod_views(
         *carry[4:9], static["ip_topo_dom"], static["ip_u_topo"],

@@ -113,11 +113,14 @@ class RunTables:
 
 
 def _probe_rows(config: SchedulerConfig, num_zones: int, num_values: int,
-                J: int, static, carry, pod):
+                J: int, static, carry, pod, views=None):
     """The probe body: -> (stk i64[N_STK_ROWS, N] header rows,
     tab i64[J, N] weighted LR+BA j-table). Callers that consume only
     `stk` (the grouped header probe, the device replay) leave `tab`
-    dead and XLA eliminates it."""
+    dead and XLA eliminates it. `views` are the carry's inter-pod views
+    (ops/interpod.Views) where the caller carries them (the device
+    replay's run-slot loop); absent, they are gathered from the carry's
+    tables here."""
     (
         res,
         port_mask,
@@ -142,15 +145,17 @@ def _probe_rows(config: SchedulerConfig, num_zones: int, num_values: int,
 
     want_ip_pred = MATCH_INTER_POD_AFFINITY in config.predicates
     want_ip_prio = any(n == INTER_POD_AFFINITY for n, _ in config.priorities)
-    cnt_lt = None
-    if want_ip_pred or want_ip_prio:
+    cnt_lt = own_lt = None
+    if views is not None:
+        cnt_lt, own_lt = views.cnt_lt, views.own_lt
+    elif want_ip_pred or want_ip_prio:
         cnt_lt = interpod_carry_tables(static, ip_term_count, N)
 
     fit_static = jnp.broadcast_to(
         # a minimal config (e.g. PodFitsResources-only) leaves no
         # node-axis predicate here and the mask collapses to a scalar
         fit_mask(config, static, carry, pod, cnt_lt,
-                 include_resources=False),
+                 include_resources=False, own_lt=own_lt),
         (N,),
     )
 
@@ -216,15 +221,11 @@ def _probe_rows(config: SchedulerConfig, num_zones: int, num_values: int,
         elif name == INTER_POD_AFFINITY:
             stk_rows["ip_totals"] = IP.interpod_totals(
                 cnt_lt,
-                IP.gather_lt(ip_rev_hard, static["ip_u_topo"],
-                             static["ip_topo_dom"], static["ip_lt_u"],
-                             static["ip_lt_sign"]),
-                IP.gather_lt(ip_rev_pref, static["ip_u_topo"],
-                             static["ip_topo_dom"], static["ip_lt_u"],
-                             static["ip_lt_sign"]),
-                IP.gather_lt(ip_rev_anti, static["ip_u_topo"],
-                             static["ip_topo_dom"], static["ip_lt_u"],
-                             static["ip_lt_sign"]),
+                *(views[2:] if views is not None else (
+                    IP.gather_lt(table, static["ip_u_topo"],
+                                 static["ip_topo_dom"], static["ip_lt_u"],
+                                 static["ip_lt_sign"])
+                    for table in (ip_rev_hard, ip_rev_pref, ip_rev_anti))),
                 static["ip_lt_spec"], pod["ip_match_spec"],
                 pod["ip_fwd_lt"], pod["ip_fwd_w"],
                 config.hard_pod_affinity_weight, N,

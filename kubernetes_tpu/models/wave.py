@@ -79,6 +79,7 @@ from kubernetes_tpu.models.waveloop import (  # noqa: F401
     run_wave,
 )
 from kubernetes_tpu.models.zreplay import ZReplay
+from kubernetes_tpu.ops import interpod as IP
 from kubernetes_tpu.ops.narrow import narrow_dtype
 from kubernetes_tpu.snapshot.encode import (
     ClusterSnapshot,
@@ -264,12 +265,13 @@ def run_verdict(config: SchedulerConfig, batch: PodBatch, i: int,
 
 #: what `stats` counts of the grouped header probe, in both drivers
 GROUP_COUNTERS = ("group_runs", "group_d2h_bytes", "group_reprobes")
-#: what `stats` counts of the grouped device replay (the single-chip
-#: driver's alone: the mesh has none): the pick steps and the run-slot
-#: iterations `jit_zreplay_group`'s two loops ran and the pick steps
-#: that evaluated the carried score again (a node picked twice since
-#: the last evaluation, or one that left the fit set), by the program's
-#: own counters, and the pods it placed
+#: what `stats` counts of the device replay (the single-chip driver's
+#: alone: the mesh has none): the pick steps `jit_zreplay_group`'s and
+#: `jit_zreplay_run`'s loops ran, the run-slot iterations the group's
+#: outer loop ran, and the pick steps that evaluated the carried score
+#: again (a node picked twice since the last evaluation, or one that
+#: left the fit set holding a normaliser's extreme), by the programs'
+#: own counters, and the pods they placed
 ZREPLAY_COUNTERS = ("zreplay_steps", "zreplay_slots", "zreplay_rescores",
                     "zreplay_picks")
 #: what `stats` counts of the scan's loop (`scan_rows`: the loop's `flush`,
@@ -774,9 +776,13 @@ class WaveScheduler(WaveCounts):
     # -- carry commit of a whole run -----------------------------------------
 
     @jax.named_scope("apply")
-    def _apply_fn(self, static, carry, pod, counts):
+    def _apply_fn(self, static, carry, pod, counts, picks=None):
         """Fold j identical commits per node into the carry — the exact
-        sum of the scan's per-step commit section over the run."""
+        sum of the scan's per-step commit section over the run. `picks`
+        are the same commits one by one, (nodes i32[K], placed bool[K]),
+        where the caller holds them (the device replay): the inter-pod
+        tables then take an update a pick
+        (ops/interpod.interpod_commit_picks) and not one a node."""
         (
             res, port_mask, class_count, last_idx,
             ip_term_count, ip_own_anti, ip_rev_hard, ip_rev_pref,
@@ -797,7 +803,16 @@ class WaveScheduler(WaveCounts):
         class_count = class_count.at[:, pod["class_id"]].add(counts)
         last_idx = last_idx + k
         U = static["ip_u_topo"].shape[0]
-        if U and ip_term_count.shape[1]:
+        if picks is not None:
+            (ip_term_count, ip_own_anti, ip_rev_hard, ip_rev_pref,
+             ip_rev_anti) = IP.interpod_commit_picks(
+                ip_term_count, ip_own_anti, ip_rev_hard, ip_rev_pref,
+                ip_rev_anti, static["ip_topo_dom"], static["ip_u_topo"],
+                static["ip_u_spec"], static["ip_lt_u"],
+                pod["ip_match_spec"], pod["ip_own_hard"],
+                pod["ip_own_pref"], pod["ip_own_anti_hard"],
+                pod["ip_own_anti_pref"], *picks)
+        elif U and ip_term_count.shape[1]:
             # term_count[u, dom(u, n)] += match_spec[spec(u)] * counts[n]
             # — interpod_commit is linear in the commit count
             dom = static["ip_topo_dom"][static["ip_u_topo"]]  # (U, N)
@@ -811,7 +826,7 @@ class WaveScheduler(WaveCounts):
             ].add(add.astype(ip_term_count.dtype))
         LT = static["ip_lt_u"].shape[0] if "ip_lt_u" in static else 0
         E = static["ip_lt_u"].shape[1] if LT else 0
-        if LT and E and ip_own_anti.shape[2]:
+        if picks is None and LT and E and ip_own_anti.shape[2]:
             # the run's OWN terms, folded per node with multiplicity
             # counts[n] — ops/interpod.interpod_commit vectorized over N
             # (run_verdict guarantees these terms never feed back into
@@ -1198,6 +1213,11 @@ class WaveScheduler(WaveCounts):
                     chosen = np.asarray(chosen)
                     n_done = int(n_done)
                     L = int(L)
+                    steps, rescores = np.asarray(self._zreplay.run_ran)
+            wave.tallies.update({
+                "zreplay_steps": int(steps),
+                "zreplay_rescores": int(rescores),
+                "zreplay_picks": int((chosen >= 0).sum())})
             if n_done == 0:
                 break  # no progress through tables: the scan's
             wave.write(run.start + done, chosen[:n_done])

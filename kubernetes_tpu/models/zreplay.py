@@ -28,7 +28,10 @@ Two entry points share the same probe+replay body:
     device round trip instead of 500. A run that trips its table
     horizon ends the loop (n_done reports how far each run got) and the
     host driver resumes from there — output stays bit-identical to the
-    serial per-run sequence.
+    serial per-run sequence. On a cluster with inter-pod terms the loop
+    carries the tables' per-node views beside the carry (gathered once
+    a dispatch, advanced by each run's picks: _run_slots), and a run's
+    fold writes its picks into the tables, not every node.
 
 Scope: runs whose only cross-node coupling is the zone blend (the
 common zoned-cluster case). ServiceAffinity/ServiceAntiAffinity
@@ -51,8 +54,11 @@ from kubernetes_tpu.models.batch import (
     SELECTOR_SPREAD,
     TAINT_TOLERATION,
     SchedulerConfig,
+    interpod_carry_tables,
+    wants_interpod,
 )
 from kubernetes_tpu.models.probe import N_STK_ROWS, _probe_rows
+from kubernetes_tpu.ops import interpod as IP
 
 
 def _weights(config: SchedulerConfig):
@@ -94,10 +100,71 @@ def _name_desc_tables(static):
                        static["alloc_mem"][perm])
 
 
+#: the picks one step of `_advance_views` takes at once
+VIEW_PICKS = 64
+
+
+def _carried_views(config, static, carry):
+    """The carry's five inter-pod views and the node axis' domain ids
+    they are gathered at (ops/interpod.Views, lt_domains), made ONCE a
+    dispatch: a gather costs by its index vectors, LT x N of them a
+    table, so the four owned-term tables are read by one gather
+    (gather_lt_many). (None, None) where the cluster has no terms or
+    the config reads none: nothing to carry, and the program is the one
+    it was."""
+    if not (static["ip_lt_u"].shape[0] and wants_interpod(config)):
+        return None, None
+    where = (static["ip_u_topo"], static["ip_topo_dom"], static["ip_lt_u"])
+    views = IP.Views(
+        interpod_carry_tables(static, carry[4], carry[0].shape[1]),
+        *IP.gather_lt_many(carry[5:9], *where, static["ip_lt_sign"]))
+    return views, IP.lt_domains(*where)
+
+
+def _advance_views(static, width, dom_lt, views, pod, nodes, placed, ran):
+    """The views after one pod's picks `nodes` (i32[K], where `placed`;
+    none past the first `ran`): each pick's increment
+    (ops/interpod.interpod_commit_views), VIEW_PICKS of them summed at a
+    time, which is what gathering the tables anew behind the fold would
+    read, bit for bit: integer sums in the views' own dtypes."""
+    C = min(nodes.shape[0], VIEW_PICKS)  # both powers of two
+    zero = jax.tree.map(jnp.zeros_like, views)
+
+    def increment(node, ok):
+        return IP.interpod_commit_views(
+            zero, dom_lt, width, static["ip_u_spec"], static["ip_lt_u"],
+            static["ip_lt_sign"], pod["ip_match_spec"], pod["ip_own_hard"],
+            pod["ip_own_pref"], pod["ip_own_anti_hard"],
+            pod["ip_own_anti_pref"], node, ok)
+
+    def some(c, views):
+        inc = jax.vmap(increment)(
+            jax.lax.dynamic_slice_in_dim(nodes, c * C, C),
+            jax.lax.dynamic_slice_in_dim(placed, c * C, C))
+        return IP.Views(*(v + d.sum(axis=0) for v, d in zip(views, inc)))
+
+    return jax.lax.fori_loop(0, (ran + C - 1) // C, some, views)
+
+
+def _fold_run(apply_fn, static, carry, pod, perm, inv, j, picks, by_picks):
+    """A run's commits into the carry -> (carry', counts i64[N] in node
+    order, the picks as (nodes i32[K], placed bool[K]) or None). `j` are
+    the commit counts in name-desc order, `picks` the same commits one
+    by one. `by_picks` (terms live): the fold takes the picks beside the
+    counts, so the inter-pod tables pay an update a pick and not one a
+    node."""
+    counts = j[inv].astype(jnp.int64)
+    if not by_picks:
+        return apply_fn(static, carry, pod, counts), counts, None
+    at = (perm[jnp.maximum(picks, 0)], picks >= 0)
+    return apply_fn(static, carry, pod, counts, at), counts, at
+
+
 def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
                 perm, alloc, zone_id, veto, has_selectors, rows_dyn,
-                k_real, L0):
-    """Probe `pod` against the live carry, then one pick step per pod of
+                k_real, L0, views=None):
+    """Probe `pod` against the live carry (and its inter-pod `views`,
+    where the caller carries them), then one pick step per pod of
     the run: the loop ends at k_real (<= K) or at a table-horizon bail.
 
     A step scores in emulated 64-bit only what its pick changed. The
@@ -108,12 +175,16 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
     j and `nxt` = base at j + 1 for all nodes, and its steps carry
     `cur`: a pick of node m moves cur[m] to nxt[m] and marks m used. An
     epoch ends when nxt[m] was spent already (m picked twice in it) or
-    fit[m] flipped (the maxima NodeAffinity / TaintToleration /
-    InterPodAffinity normalise by may move), and the next one evaluates
+    m left the fit set while it HELD an extreme that NodeAffinity /
+    TaintToleration / InterPodAffinity normalise by (`holds_extreme`:
+    base depends on the fit set through those extremes alone, and
+    within a run the fit set only shrinks), and the next one evaluates
     again. Same functions on the same inputs as a step that evaluated
     all of it: the same integers. Where picks spread over the nodes a
-    run is one epoch; where they pile on one node an epoch is two steps,
-    which costs what the per-step evaluation did.
+    run is one epoch, with the self-anti veto too (every pick of such a
+    run takes its node out of the fit set); where they pile on one node
+    an epoch is two steps, which costs what the per-step evaluation
+    did.
 
     A step takes its pick as a MASK over the nodes and makes every
     update a select on it (the commit count, the fit bit, the zone sums,
@@ -126,7 +197,7 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
     ids, -1 past the last step, L, n_done, bailed, the steps run, the
     epochs after the first: the rescores)."""
     stk, _tab = _probe_rows(config, num_zones, num_values, J, static,
-                            carry, pod)
+                            carry, pod, views)
     N = perm.shape[0]
     # ONE gather by the permutation: the header rows and, behind them,
     # the two usage rows LR/BA read (a gather costs by its indices, not
@@ -239,6 +310,30 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
                 R.balanced_resource_allocation(*usage)
         return score + lrba[:N], score + lrba[N:]
 
+    def holds_extreme(fit):
+        """-> bool[N]: the nodes whose leaving `fit` may move one of the
+        extremes base_pair normalises by, which are all it reads of the
+        fit set: a node that holds the NodeAffinity / TaintToleration
+        maximum or the inter-pod totals' maximum or minimum, where the
+        clip at 0 does not hold it in place anyway. While none of them
+        leaves, the extremes and their holders stay what they were.
+        Two nodes that tie for an extreme both hold it: never
+        optimistic."""
+        holds = jnp.zeros((N,), bool)
+        if w_na:
+            mx = na_counts.max(where=fit, initial=0)
+            holds = holds | ((mx > 0) & (na_counts == mx))
+        if w_tt:
+            mx = tt_counts.max(where=fit, initial=0)
+            holds = holds | ((mx > 0) & (tt_counts == mx))
+        if w_ip:
+            big = jnp.int64(2**62)
+            mx = ip_totals.max(where=fit, initial=-big)
+            mn = ip_totals.min(where=fit, initial=big)
+            holds = holds | ((mx > 0) & (ip_totals == mx)) \
+                | ((mn < 0) & (ip_totals == mn))
+        return holds
+
     def counted(j, fit):
         """What SelectorSpread counts on each node that fits."""
         return jnp.where(
@@ -285,6 +380,7 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
         until a pick spends the evaluation or the run ends."""
         i0 = state[0]
         cur0, nxt = base_pair(state[1].astype(jnp.int64), state[2])
+        spends = holds_extreme(state[2])
 
         def step(state):
             (i, j, fit, zc, L, n_done, stopped, chosen, cur, used,
@@ -315,9 +411,9 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
             # the picked node moved to its next commit count, where the
             # carried score is nxt's: unless that was spent already (the
             # node picked before in this epoch) or the node left the fit
-            # set (the normalisers' maxima may move) — then the epoch
-            # ends
-            stale = (pick & (used | (fit_new != fit))).any()
+            # set holding one of the normalisers' extremes — then the
+            # epoch ends
+            stale = (pick & (used | (spends & (fit_new != fit)))).any()
             return (i + 1, j_new, fit_new, zc, L, n_done, stopped | bail,
                     chosen, jnp.where(pick, nxt, cur), used | pick, stale)
 
@@ -352,7 +448,8 @@ def _zreplay_fn(config, num_zones, num_values, J, K, layout, apply_fn,
 
     zone_id/veto are PERMUTED to name-desc order already; probe rows are
     permuted inside. Returns (carry', chosen[K] permuted-space ids,
-    counts[N] node-order, L', n_done)."""
+    counts[N] node-order, L', n_done, ran i32[2]: the pick steps the
+    loop ran and those that evaluated the carried score again)."""
     from kubernetes_tpu.models.pack import unpack as _unpack_pod
 
     if fold_prev:
@@ -360,14 +457,61 @@ def _zreplay_fn(config, num_zones, num_values, J, K, layout, apply_fn,
         carry = apply_fn(static, carry, prev_pod, prev_counts)
     pod = _unpack_pod(layout, pod_buf)
     perm, inv, alloc = _name_desc_tables(static)
-    j, chosen, L, n_done, _stopped, _steps, _rescores = _replay_run(
+    views, _dom_lt = _carried_views(config, static, carry)
+    j, chosen, L, n_done, _stopped, steps, rescores = _replay_run(
         config, num_zones, num_values, J, K, static, carry, pod, perm,
-        alloc, zone_id, veto, has_selectors, rows_dyn, k_real, L0,
+        alloc, zone_id, veto, has_selectors, rows_dyn, k_real, L0, views,
     )
     # permuted j -> node-order counts; fold THIS run's commits
-    counts = j[inv].astype(jnp.int64)
-    carry = apply_fn(static, carry, pod, counts)
-    return carry, chosen, counts, L, n_done
+    carry, counts, _at = _fold_run(
+        apply_fn, static, carry, pod, perm, inv, j, chosen,
+        views is not None)
+    return carry, chosen, counts, L, n_done, jnp.stack([steps, rescores])
+
+
+def _run_slots(config, num_zones, num_values, J, K, G, apply_fn, static,
+               carry, pods, zone_id, vetos, has_sels, rows_arr, k_reals,
+               runs, L0):
+    """The run-slot loop of the grouped replay: probe + replay + fold
+    for each of the first `runs` rows of `pods` (every field with a
+    leading G axis), the carry and its inter-pod views threaded from
+    slot to slot -> (carry', views' or None, chosen[G, K], n_done[G],
+    L', ran i32[3])."""
+    perm, inv, alloc = _name_desc_tables(static)
+    views, dom_lt = _carried_views(config, static, carry)
+
+    def run_body(state):
+        (g, carry, views, L, _bailed, chosen, n_done, steps,
+         rescores) = state
+        pod = {f: v[g] for f, v in pods.items()}
+        j, picks, L, done, bailed, ran, rescored = _replay_run(
+            config, num_zones, num_values, J, K, static, carry, pod,
+            perm, alloc, zone_id, vetos[g], has_sels[g], rows_arr[g],
+            k_reals[g], L, views,
+        )
+        carry, _counts, at = _fold_run(
+            apply_fn, static, carry, pod, perm, inv, j, picks,
+            views is not None)
+        if views is not None:
+            views = _advance_views(static, carry[5].shape[2], dom_lt,
+                                   views, pod, *at, ran)
+        return (g + 1, carry, views, L, bailed, chosen.at[g].set(picks),
+                n_done.at[g].set(done), steps + ran,
+                rescores + rescored)
+
+    def more(state):
+        g, bailed = state[0], state[4]
+        return (g < runs) & ~bailed
+
+    (slots, carry, views, L, _bailed, chosen, n_done, steps,
+     rescores) = jax.lax.while_loop(
+        more, run_body,
+        (jnp.int32(0), carry, views, jnp.int64(L0), jnp.bool_(False),
+         jnp.full((G, K), -1, jnp.int32), jnp.zeros((G,), jnp.int32),
+         jnp.int32(0), jnp.int32(0)),
+    )
+    return (carry, views, chosen, n_done, L,
+            jnp.stack([steps, slots, rescores]))
 
 
 @jax.named_scope("zreplay")
@@ -394,37 +538,24 @@ def _zreplay_group_fn(config, num_zones, num_values, J, K, G, layout,
         carry = apply_group_fn(prev_layout, static, carry, prev_buf,
                                prev_counts)
     pods = _unpack_pod(layout, group_buf)  # each field: leading G axis
-    perm, inv, alloc = _name_desc_tables(static)
+    carry, _views, chosen, n_done, L, ran = _run_slots(
+        config, num_zones, num_values, J, K, G, apply_fn, static, carry,
+        pods, zone_id, vetos, has_sels, rows_arr, k_reals, runs, L0)
+    return carry, chosen, n_done, L, ran
 
-    def run_body(state):
-        g, carry, L, _bailed, chosen, n_done, steps, rescores = state
-        pod = {f: v[g] for f, v in pods.items()}
-        j, picks, L, done, bailed, ran, rescored = _replay_run(
-            config, num_zones, num_values, J, K, static, carry, pod,
-            perm, alloc, zone_id, vetos[g], has_sels[g], rows_arr[g],
-            k_reals[g], L,
-        )
-        carry = apply_fn(static, carry, pod, j[inv].astype(jnp.int64))
-        return (g + 1, carry, L, bailed, chosen.at[g].set(picks),
-                n_done.at[g].set(done), steps + ran,
-                rescores + rescored)
 
-    def more(state):
-        g, bailed = state[0], state[3]
-        return (g < runs) & ~bailed
-
-    (slots, carry, L, _bailed, chosen, n_done, steps,
-     rescores) = jax.lax.while_loop(
-        more, run_body,
-        (jnp.int32(0), carry, jnp.int64(L0), jnp.bool_(False),
-         jnp.full((G, K), -1, jnp.int32), jnp.zeros((G,), jnp.int32),
-         jnp.int32(0), jnp.int32(0)),
-    )
-    return carry, chosen, n_done, L, jnp.stack([steps, slots, rescores])
+#: the deferred fold's operands where none rides the dispatch (host
+#: arrays: an argument's transfer, where `jnp.zeros` was a program of
+#: its own to launch before every dispatch)
+_NO_BUF = np.zeros(0, np.uint8)
+_NO_COUNTS = np.zeros(0, np.int64)
 
 
 class ZReplay:
-    """Compile cache for the fused probe+replay+fold programs."""
+    """Compile cache for the fused probe+replay+fold programs. A
+    dispatch hands its host-made operands over as numpy values: they
+    ride the call's own transfer, and no conversion program runs
+    before it."""
 
     def __init__(self, config: SchedulerConfig, apply_fn,
                  apply_group_fn=None):
@@ -436,6 +567,9 @@ class ZReplay:
         #: the last run_group dispatch ran and the steps that rescored
         #: (its return stays the four values its callers unpack)
         self.group_ran = None
+        #: i32[2] on the device: the last run dispatch's pick steps and
+        #: those that rescored
+        self.run_ran = None
 
     def run(self, static, carry, prev_buf, prev_counts, pod_buf, layout,
             num_zones, num_values, J, K_bucket, zone_id_perm, veto_perm,
@@ -452,15 +586,14 @@ class ZReplay:
             fn = jax.jit(zreplay_run)
             self._jitted[key] = fn
         if not fold_prev:
-            prev_buf = jnp.zeros(0, jnp.uint8)
-            prev_counts = jnp.zeros(0, jnp.int64)
-        return fn(
+            prev_buf, prev_counts = _NO_BUF, _NO_COUNTS
+        carry, chosen, counts, L, n_done, self.run_ran = fn(
             static, carry, prev_buf, prev_counts, pod_buf,
-            jnp.asarray(zone_id_perm), jnp.asarray(veto_perm),
-            jnp.asarray(bool(has_selectors)),
-            jnp.asarray(np.int64(rows)), jnp.asarray(np.int32(k_real)),
+            zone_id_perm, veto_perm,
+            np.bool_(has_selectors), np.int64(rows), np.int32(k_real),
             np.int64(L0),
         )
+        return carry, chosen, counts, L, n_done
 
     def run_group(self, static, carry, prev, group_buf, layout,
                   num_zones, num_values, J, K_bucket, G,
@@ -487,12 +620,10 @@ class ZReplay:
             fn = jax.jit(zreplay_group)
             self._jitted[key] = fn
         if prev_kind is None:
-            prev_buf = jnp.zeros(0, jnp.uint8)
-            prev_counts = jnp.zeros(0, jnp.int64)
+            prev_buf, prev_counts = _NO_BUF, _NO_COUNTS
         carry, chosen, n_done, L, self.group_ran = fn(
-            static, carry, prev_buf, jnp.asarray(prev_counts), group_buf,
-            jnp.asarray(zone_id_perm), jnp.asarray(vetos_perm),
-            jnp.asarray(has_sels), jnp.asarray(rows_arr),
-            jnp.asarray(k_reals), np.int32(runs), np.int64(L0),
+            static, carry, prev_buf, prev_counts, group_buf,
+            zone_id_perm, vetos_perm, has_sels, rows_arr, k_reals,
+            np.int32(runs), np.int64(L0),
         )
         return carry, chosen, n_done, L

@@ -81,6 +81,36 @@ def gather_lt(table, u_topo, topo_dom, lt_u, lt_sign):
     return jnp.where(valid, signed, 0).sum(axis=1)
 
 
+def gather_lt_many(tables, u_topo, topo_dom, lt_u, lt_sign):
+    """gather_lt of several owned-term tables (LT, E, D) that share
+    their domain axis, in ONE gather -> a (LT, N) view each, bit for
+    bit gather_lt's. A gather costs by its index vectors on the chip,
+    not by the words a vector moves: the tables' entries at one (term,
+    slot, domain) lie side by side as 32-bit words (an int64 is two)
+    and a node's domain id fetches them all."""
+    LT, E = lt_u.shape
+    N = topo_dom.shape[1] if topo_dom.ndim == 2 else 0
+    if LT == 0 or u_topo.shape[0] == 0:
+        return tuple(jnp.zeros((LT, N), t.dtype) for t in tables)
+    dom = lt_domains(u_topo, topo_dom, lt_u)  # (LT, E, N)
+    safe = jnp.clip(dom, 0, tables[0].shape[2] - 1)
+    words = [jax.lax.bitcast_convert_type(t, jnp.uint32) for t in tables]
+    packed = jnp.concatenate(
+        [w if w.ndim == 4 else w[..., None] for w in words], axis=3)
+    rows = jax.vmap(jax.vmap(lambda table, at: table[at]))(packed, safe)
+    valid = (lt_u >= 0)[:, :, None] & (dom >= 0)
+    out, at = [], 0
+    for t, w in zip(tables, words):
+        width = w.shape[3] if w.ndim == 4 else 1
+        vals = jax.lax.bitcast_convert_type(
+            rows[..., at] if width == 1 else rows[..., at:at + width],
+            t.dtype)  # (LT, E, N)
+        at += width
+        signed = vals * lt_sign[:, :, None].astype(vals.dtype)
+        out.append(jnp.where(valid, signed, 0).sum(axis=1))
+    return tuple(out)
+
+
 class Views(NamedTuple):
     """The per-node expansions of the five domain tables, (LT, N) each."""
 
@@ -289,6 +319,56 @@ def interpod_commit(
             jnp.int32
         )
     return term_count, own_anti, rev_hard, rev_pref, rev_anti, spec_total
+
+
+def interpod_commit_picks(
+    term_count,
+    own_anti,
+    rev_hard,
+    rev_pref,
+    rev_anti,
+    topo_dom,
+    u_topo,
+    u_spec,
+    lt_u,
+    pod_match_spec,
+    pod_own_hard,
+    pod_own_pref,
+    pod_own_anti_hard,
+    pod_own_anti_pref,
+    nodes,  # (K,) the picked nodes, any id where not placed
+    placed,  # (K,) bool
+):
+    """interpod_commit's five tables after K picks of ONE pod: a
+    scatter-add a table with an update a pick and slot (a scatter costs
+    by its updates on the chip, and a run's picks are far fewer than
+    the nodes). `spec_total` is linear in the count and the caller's."""
+    U = u_topo.shape[0]
+    safe_n = jnp.maximum(nodes, 0)
+    if U and term_count.shape[1]:
+        dom = topo_dom[u_topo][:, safe_n]  # (U, K)
+        valid = (dom >= 0) & placed
+        mu = pod_match_spec[u_spec].astype(jnp.int32)
+        term_count = term_count.at[
+            jnp.arange(U)[:, None],
+            jnp.clip(dom, 0, term_count.shape[1] - 1),
+        ].add(mu[:, None] * valid.astype(jnp.int32))
+    LT, E = lt_u.shape
+    if LT and E and U and own_anti.shape[2]:
+        q = u_topo[jnp.clip(lt_u, 0, U - 1)]  # (LT, E)
+        domq = topo_dom[q][:, :, safe_n]  # (LT, E, K)
+        validq = (lt_u >= 0)[:, :, None] & (domq >= 0) & placed
+        at = (jnp.arange(LT)[:, None, None], jnp.arange(E)[None, :, None],
+              jnp.clip(domq, 0, own_anti.shape[2] - 1))
+        v32 = validq.astype(jnp.int32)
+        v64 = validq.astype(jnp.int64)
+        own_anti = own_anti.at[at].add(
+            pod_own_anti_hard[:, None, None] * v32)
+        rev_hard = rev_hard.at[at].add(pod_own_hard[:, None, None] * v32)
+        rev_pref = rev_pref.at[at].add(pod_own_pref[:, None, None] * v64)
+        rev_anti = rev_anti.at[at].add(
+            pod_own_anti_pref[:, None, None] * v64)
+    return term_count, own_anti, rev_hard, rev_pref, rev_anti
 
 
 def interpod_commit_views(

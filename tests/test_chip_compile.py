@@ -249,6 +249,129 @@ def test_the_scans_step_gathers_no_nodes_domain_under_terms(one_chip):
     assert len([line for line in step if " fusion(" in line]) <= 170
 
 
+def _index_vectors(op):
+    """How many index vectors a compiled `gather` reads or a `scatter`
+    writes: the result's elements over a slice's (a gather), or the
+    elements of its scalar updates' index array (a scatter)."""
+    import re
+
+    sizes = lambda dims: int(np.prod([int(d) for d in dims.split(",") if d]
+                                     or [1]))  # noqa: E731
+    shape = re.search(r"= \(?\w+\[([\d,]*)\]", op).group(1)
+    if " gather(" in op:
+        return sizes(shape) // sizes(
+            re.search(r"slice_sizes=\{([\d,]*)\}", op).group(1))
+    return None
+
+
+def test_the_run_slot_of_the_grouped_replay_gathers_no_view_under_terms(
+        one_chip):
+    """`jit_zreplay_group` at mixed-5k's own term widths (2 combos, 4
+    logical terms of one slot, a domain a node) on the deployment cut to
+    1,000 nodes (1,024 slots; 8 run slots of 64 picks), compiled for the
+    described v5e (some 15 s). The five inter-pod views are gathered
+    once a dispatch, before the run-slot loop (two gathers of `LT x N`
+    index vectors), and ride it: no gather in
+    the loop's body reads `LT x N` index vectors, where seven did (each
+    some 0.35 ms at 8,192 slots on the chip: PERF.md section 5), and no
+    scatter writes a table by node: a run's fold writes its picks,
+    `LT x 64` updates, where five scatters of `LT x N` stood (the two
+    emulated-int64 ones 2.26 ms each at 8,192 slots). The views are
+    advanced by a loop of its own inside the run slot, a pass per 64
+    picks."""
+    import functools
+    import re
+
+    import jax
+
+    from benchmark import deploy
+    from kubernetes_tpu.client import rest
+    from kubernetes_tpu.models.batch import BatchScheduler, SchedulerConfig
+    from kubernetes_tpu.models.wave import WaveScheduler, group_buffer
+    from kubernetes_tpu.models.zreplay import _zreplay_group_fn
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.parallel.mesh import _pad_snapshot
+    from kubernetes_tpu.snapshot.encode import SnapshotEncoder
+    from kubernetes_tpu.snapshot.pad import next_pow2
+
+    cfg = deploy.load_config("mixed-5k")
+    cfg["nodes"]["count"] = 1000
+    scheme = rest.default_scheme
+    bound = []
+    for i in range(100):
+        pod = scheme.decode(deploy.pod(cfg, i % 10, name=f"held-{i}"))
+        pod.spec.node_name = deploy.node_name(cfg, i * 7 % 1000)
+        bound.append(pod)
+    state = ClusterState.build(
+        [scheme.decode(d) for d in deploy.nodes(cfg)], bound,
+        controllers=[scheme.decode(d) for d in deploy.controllers(cfg)])
+    waiting = [scheme.decode(deploy.pod(cfg, t, name=f"new-{t}"))
+               for t in range(10)]
+    snap, batch = SnapshotEncoder(state, waiting).encode()
+    snap = _pad_snapshot(snap, next_pow2(snap.num_nodes, 64))
+    N, (LT, E) = snap.num_nodes, snap.ip_lt_u.shape
+    assert (N, LT, E) == (1024, 4, 1)
+    assert snap.ip_own_anti.shape == (4, 1, 1000)
+    config = SchedulerConfig()
+    static = {f: np.asarray(getattr(snap, f))
+              for f in BatchScheduler.STATIC_FIELDS}
+    wave = WaveScheduler(config)
+    # the plain template and the vetoed one, four run slots of each
+    G, layout, buf = group_buffer(batch, [0, 2] * 4)
+    K = 64
+    args = (static, BatchScheduler(config).initial_carry(snap),
+            np.zeros(0, np.uint8), np.zeros(0, np.int64), np.asarray(buf),
+            np.zeros(N, np.int32), np.zeros((G, N), bool), np.ones(G, bool),
+            np.full(G, 32, np.int64), np.full(G, K, np.int32),
+            np.int32(G), np.int64(0))
+    text = jax.jit(functools.partial(
+        _zreplay_group_fn, config, 2, 0, 128, K, G, layout, wave._apply_fn,
+        None, None, wave._apply_group_fn)).lower(
+            *_shapes(args, one_chip)).compile().as_text()
+    loops, computations = _loop_bodies(text)
+    # run slots > epochs > pick steps, and the views' advance beside the
+    # epochs
+    assert len(loops) == 4, [name for name, _ in loops]
+    slot, = [body for _, body in loops
+             if sum(" while(" in line for line in body) == 2]
+
+    def fused(body, kind):
+        """The `kind` ops of a loop body, in its fusions or bare."""
+        found = [line for line in body if f" {kind}(" in line]
+        for line in body:
+            called = re.search(r" fusion\(.*calls=%?([\w.\-]+)", line)
+            if called:
+                found += [op for op in computations[called.group(1)]
+                          if f" {kind}(" in op]
+        return found
+
+    gathers = fused(slot, "gather")
+    assert gathers and max(map(_index_vectors, gathers)) <= N, [
+        g.strip()[:160] for g in gathers if _index_vectors(g) > N]
+    # by the node axis: the name-desc permutation of the header rows and
+    # the commit counts back (as without terms), never by LT x N
+    assert sum(_index_vectors(g) == N for g in gathers) <= 4
+    scatters = fused(slot, "scatter")
+    assert 3 <= len(scatters) <= 5  # the five tables' (int64: a pair each)
+    for op in scatters:
+        # scalar updates at (term, slot, domain): LT x E x K of them
+        indices = re.search(r"scatter\(([^)]*)\)", op).group(1).split(", ")
+        name = indices[len(indices) // 2].lstrip("%")
+        shape, = [re.search(r"= \w+\[([\d,]*)\]", line).group(1)
+                  for body in computations.values() for line in body
+                  if re.match(rf"\s*(ROOT )?%?{re.escape(name)} = ", line)]
+        assert int(np.prod([int(d) for d in shape.split(",")])) \
+            <= 3 * LT * E * K, op.strip()[:200]
+    # before the loop, once a dispatch: the views' own gathers, one of
+    # `ip_term_count`'s single values and one of the four owner tables'
+    # entries side by side, six 32-bit words an index vector
+    outside = [op for name, body in computations.items()
+               for op in body if " gather(" in op
+               and _index_vectors(op) == LT * N]
+    assert len(outside) == 2, len(outside)
+    assert sum("slice_sizes={1,1,1,6}" in op for op in outside) == 1
+
+
 #: seconds the chip's compiler may take over one shipment's unpack
 #: program. The chip's host compiles about three times slower than this
 #: sandbox and the deployment asks for under 30 s there; the uint8

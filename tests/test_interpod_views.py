@@ -185,6 +185,23 @@ def test_a_picked_node_without_the_label_adds_to_no_node():
         assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
 
 
+@pytest.mark.parametrize("name", list(SHAPES))
+def test_one_gather_of_the_four_owner_tables_reads_what_four_read(name):
+    """`gather_lt_many` (the device replay's views, once a dispatch):
+    the four owned-term tables side by side as 32-bit words under one
+    gather, each view bit for bit `gather_lt`'s, int64 weights past
+    2^31 and unlabelled nodes among them."""
+    static, tables, _ = _tables(name, 1, seed=len(name))
+    where = (static["u_topo"], static["topo_dom"], static["lt_u"],
+             static["lt_sign"])
+    many = jax.jit(IP.gather_lt_many)(tables[1:5], *where)
+    for table, got in zip(tables[1:5], many):
+        want = IP.gather_lt(table, *where)
+        assert got.dtype == want.dtype
+        assert got.shape == (10, N) and np.asarray(want).any()
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_zero_width_tables_carry_zero_size_views_and_commit_nothing():
     z = functools.partial(np.zeros, dtype=np.int32)
     views = IP.interpod_views(
@@ -384,3 +401,242 @@ def test_a_step_without_terms_is_the_step_it_was():
     made = [e for e in empty(was) if e.primitive.name not in reads]
     assert made and len(now) == len(was) - len(made)
     assert {e.primitive.name for e in made} <= {"broadcast_in_dim", "iota"}
+
+
+# -- the device replay's level: the views ride the run-slot loop --------------
+#
+# `jit_zreplay_group` gathers the five views once a dispatch and carries
+# them from run slot to run slot (models/zreplay._run_slots): a slot's
+# probe reads them, its fold scatters the run's PICKS into the tables
+# (ops/interpod.interpod_commit_picks, an update a pick) and adds the
+# same picks to the views (`_advance_views`). Here: after every run slot
+# the carried views are the folded tables' views bit for bit, the folded
+# tables are the ones the fold by counts makes, and the group picks as
+# the serial per-run sequence, the host spec replay and the serial
+# oracle do, on zoned clusters with hostname and zone terms of all four
+# kinds among the bound pods and the runs' own.
+
+HOSTNAME = "kubernetes.io/hostname"
+ZONE = "failure-domain.beta.kubernetes.io/zone"
+
+
+def _term(app, topo, weight=None):
+    term = {"labelSelector": {"matchLabels": {"app": app}},
+            "topologyKey": topo, "namespaces": []}
+    return term if weight is None else {
+        "weight": weight, "podAffinityTerm": term}
+
+
+def _app_pods(app, k, affinity=None, cpu="100m", node=None):
+    """k pods of service `app`; `affinity` is {"podAffinity" /
+    "podAntiAffinity": {"required": [...], "preferred": [...]}}."""
+    import json
+
+    from kubernetes_tpu.api.types import Container, ObjectMeta, Pod, PodSpec
+
+    long = {"required": "requiredDuringSchedulingIgnoredDuringExecution",
+            "preferred": "preferredDuringSchedulingIgnoredDuringExecution"}
+    pods = []
+    for i in range(k):
+        pod = Pod(metadata=ObjectMeta(name=f"{app}-{cpu}-{i:03d}",
+                                      labels={"app": app}),
+                  spec=PodSpec(containers=[Container(
+                      requests={"cpu": cpu, "memory": "200Mi"})]))
+        if affinity:
+            pod.metadata.annotations = {
+                "scheduler.alpha.kubernetes.io/affinity": json.dumps({
+                    kind: {long[when]: terms for when, terms in body.items()}
+                    for kind, body in affinity.items()})}
+        if node is not None:
+            pod.spec.node_name = node
+        pods.append(pod)
+    return pods
+
+
+#: the runs a backlog is dealt from: name -> (service, the pod's own
+#: terms). None owns a required podAffinity term or a preferred term on
+#: its own service (`run_verdict` leaves those to the scan).
+TERM_RUNS = {
+    # scored by the bound pods' and the earlier runs' terms on it
+    "plain-d": ("d", None),
+    "plain-b": ("b", None),
+    # preferred hostname anti against d, required hostname anti against g
+    "owner-p": ("p", {"podAntiAffinity": {
+        "preferred": [_term("d", HOSTNAME, 4)],
+        "required": [_term("g", HOSTNAME)]}}),
+    # the self-anti veto: one of g a node, none beside p's and f's
+    "veto-g": ("g", {"podAntiAffinity": {
+        "required": [_term("g", HOSTNAME)]}}),
+    # preferred zone affinity to d, required zone anti against z
+    "owner-q": ("q", {"podAffinity": {"preferred": [_term("d", ZONE, 2)]},
+                      "podAntiAffinity": {"required": [_term("z", ZONE)]}}),
+    # kept out of q's zones once q is placed (the symmetric check is
+    # made for a pod that owns an anti-affinity term, of whatever kind)
+    "owner-z": ("z", {"podAntiAffinity": {
+        "required": [_term("nobody", HOSTNAME)]}}),
+}
+
+
+def _term_cluster(order, lengths, nodes=18, seed=0):
+    """-> (make_state, backlog): zoned nodes with hostname labels, a
+    service an app, bound pods that own a term of every kind (required
+    zone affinity to b, preferred hostname affinity to d, preferred zone
+    anti against d, required hostname anti against g), and the backlog's
+    runs in a row each."""
+    from kubernetes_tpu.api.types import (
+        ObjectMeta, Service, ServiceSpec,
+    )
+    from kubernetes_tpu.oracle import ClusterState
+    from tests.test_wave import zoned_density_nodes
+
+    def make_state():
+        made = zoned_density_nodes(nodes, cpu="16")
+        for node in made:
+            node.metadata.labels[HOSTNAME] = node.metadata.name
+        at = [n.metadata.name for n in made]
+        spot = random.Random(seed)
+        bound = []
+        for app, k, affinity in [
+                ("b", 3, None), ("d", 2, None),
+                ("a", 3, {"podAffinity": {"required": [_term("b", ZONE)]}}),
+                ("c", 2, {"podAffinity": {
+                    "preferred": [_term("d", HOSTNAME, 5)]}}),
+                ("e", 2, {"podAntiAffinity": {
+                    "preferred": [_term("d", ZONE, 3)]}}),
+                ("f", 1, {"podAntiAffinity": {
+                    "required": [_term("g", HOSTNAME)]}})]:
+            for i, pod in enumerate(_app_pods(app, k, affinity)):
+                pod.metadata.name = f"held-{app}-{i}"
+                pod.spec.node_name = spot.choice(at)
+                bound.append(pod)
+        return ClusterState.build(made, bound, services=[
+            Service(metadata=ObjectMeta(name=f"svc-{app}"),
+                    spec=ServiceSpec(selector={"app": app}))
+            for app in "abcdefgpqz"])
+
+    backlog = []
+    for r, (name, k) in enumerate(zip(order, lengths)):
+        app, affinity = TERM_RUNS[name]
+        # a request shape a run, so that two runs of one service are two
+        # templates
+        run = _app_pods(app, k, affinity, cpu=f"{100 + 10 * r}m")
+        backlog += run
+    return make_state, backlog
+
+
+TERM_BACKLOGS = {
+    # every kind once: an owner, a plain run its terms score, the veto,
+    # a zone owner, the plain runs its terms score and exclude
+    "every-kind": (["owner-p", "plain-d", "veto-g", "owner-q", "plain-b",
+                    "plain-d", "owner-z"], [8, 10, 9, 8, 9, 8, 8]),
+    # the veto first and last, longer than the nodes left to it
+    "veto-runs-out-of-nodes": (["veto-g", "owner-p", "plain-d", "veto-g"],
+                               [12, 9, 9, 10]),
+}
+
+
+def _fuzzed(seed):
+    rng = random.Random(seed)
+    names = sorted(TERM_RUNS)
+    order = [rng.choice(names) for _ in range(rng.randint(3, 8))]
+    return order, [rng.randint(8, 14) for _ in order]
+
+
+TERM_BACKLOGS.update({f"fuzz-{seed}": _fuzzed(seed) for seed in range(6)})
+
+
+def _driven(make_state, backlog, **kw):
+    from tests.test_wave import _wave_scheduler_run
+
+    return _wave_scheduler_run(make_state(), backlog, **kw)
+
+
+@pytest.mark.parametrize("case", sorted(TERM_BACKLOGS))
+def test_the_grouped_replay_under_terms_picks_as_the_serial_sequences_do(
+        case, monkeypatch):
+    from kubernetes_tpu.models import waveloop
+    from kubernetes_tpu.models.replay import replay_spec
+    from kubernetes_tpu.oracle import GenericScheduler
+    from tests.test_conformance import ORACLE_PREDICATES, ORACLE_PRIORITIES
+
+    make_state, backlog = _term_cluster(*TERM_BACKLOGS[case])
+    want = GenericScheduler(
+        predicates=ORACLE_PREDICATES,
+        priorities=ORACLE_PRIORITIES).schedule_backlog(backlog, make_state())
+    got, ws = _driven(make_state, backlog)
+    assert got == want
+    assert ws.dispatches.get("zreplay_group", 0) >= 1, ws.dispatches
+    assert ws.stats["zreplay_steps"] >= ws.stats["zreplay_picks"] > 0
+    got_host, _ = _driven(make_state, backlog, replay=replay_spec)
+    assert got_host == want
+    # the serial per-run sequence: every run a `jit_zreplay_run` of its own
+    monkeypatch.setattr(waveloop, "DEVICE_GROUP_RUNS", 1)
+    got_serial, serial = _driven(make_state, backlog)
+    assert got_serial == want
+    assert "zreplay_group" not in serial.dispatches \
+        and serial.dispatches["zreplay"] >= 2
+    if case == "every-kind":
+        # every pod found a node but z's, which q's zones exclude
+        assert [h for h, p in zip(want, backlog)
+                if p.metadata.labels["app"] != "z"].count(None) == 0
+        assert any(h is None for h in want)
+
+
+@pytest.mark.parametrize("case", ["every-kind", "veto-runs-out-of-nodes",
+                                  "fuzz-1", "fuzz-4"])
+def test_after_every_run_slot_the_carried_views_are_the_folded_tables(
+        case, monkeypatch):
+    from kubernetes_tpu.models.pack import unpack
+    from kubernetes_tpu.models.zreplay import ZReplay, _run_slots
+    from kubernetes_tpu.models.batch import interpod_views
+
+    make_state, backlog = _term_cluster(*TERM_BACKLOGS[case])
+    calls = []
+    sound = ZReplay.run_group
+
+    def recorded(self, *a):
+        calls.append((self, a))
+        return sound(self, *a)
+
+    monkeypatch.setattr(ZReplay, "run_group", recorded)
+    _, ws = _driven(make_state, backlog)
+    zr, (static, carry, prev, buf, layout, num_zones, num_values, J, K, G,
+         zone_id, vetos, has_sels, rows_arr, k_reals, runs, L0) = calls[0]
+    assert prev is None and runs >= 3
+    assert static["ip_lt_u"].shape[0] >= 4
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def slots(by_picks, static, carry, buf, runs):
+        def by_counts(static, carry, pod, counts, picks=None):
+            return zr.apply_fn(static, carry, pod, counts)
+
+        carry, views, chosen, n_done, L, ran = _run_slots(
+            zr.config, num_zones, num_values, J, K, G,
+            zr.apply_fn if by_picks else by_counts, static, carry,
+            unpack(layout, buf), jnp.asarray(zone_id), jnp.asarray(vetos),
+            jnp.asarray(has_sels), jnp.asarray(rows_arr),
+            jnp.asarray(k_reals), runs, np.int64(L0))
+        return carry, views, interpod_views(zr.config, static, carry), \
+            chosen, ran
+
+    moved = 0
+    for r in range(1, runs + 1):
+        carry_r, carried, anew, chosen, ran = slots(
+            True, static, carry, buf, np.int32(r))
+        assert int(ran[1]) == r
+        for field, got, want in zip(IP.Views._fields, carried, anew):
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert np.array_equal(np.asarray(got), np.asarray(want)), \
+                (r, field)
+        # the fold by picks leaves the carry the fold by counts leaves
+        dense, _, _, chosen_dense, _ = slots(
+            False, static, carry, buf, np.int32(r))
+        assert np.array_equal(np.asarray(chosen), np.asarray(chosen_dense))
+        for i, (got, want) in enumerate(zip(jax.tree.leaves(carry_r),
+                                            jax.tree.leaves(dense))):
+            assert got.dtype == want.dtype, i
+            assert np.array_equal(np.asarray(got), np.asarray(want)), (r, i)
+        moved += any(
+            not np.array_equal(np.asarray(a), np.asarray(b))
+            for a, b in zip(carry_r[4:9], carry[4:9]))
+    assert moved  # some run's terms or matches reached the tables

@@ -36,6 +36,7 @@ from kubernetes_tpu.api.types import (
     ReplicationControllerSpec,
     Service,
     ServiceSpec,
+    Toleration,
 )
 from kubernetes_tpu.models.wave import (
     ANTI_COUNTERS,
@@ -173,11 +174,13 @@ def test_debug_traces_shows_the_wave_totals():
 # -- the grouped device replay's own counters ----------------------------------
 
 
-@pytest.mark.parametrize("case, runs", [
-    ("rows-zoned", 4), ("rows-then-turns", 3),
-    ("rows-unzoned", 0), ("dealt-in-turn-zoned", 0), ("one-row-zoned", 0),
+@pytest.mark.parametrize("case, runs, alone", [
+    ("rows-zoned", 4, 0), ("rows-then-turns", 3, 0),
+    ("rows-unzoned", 0, 0), ("dealt-in-turn-zoned", 0, 0),
+    # a lone run is `jit_zreplay_run`'s: its pick loop counts too
+    ("one-row-zoned", 0, 64),
 ])
-def test_zreplay_counters_say_what_the_two_loops_ran(case, runs):
+def test_zreplay_counters_say_what_the_two_loops_ran(case, runs, alone):
     from kubernetes_tpu.oracle import ClusterState
     from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
     from kubernetes_tpu.trace.httpd import render_traces
@@ -191,8 +194,10 @@ def test_zreplay_counters_say_what_the_two_loops_ran(case, runs):
     stats = algo._wave.stats
     # every pod of a whole run is placed: a step a pick, a slot a run
     assert stats["zreplay_slots"] == runs
-    assert stats["zreplay_steps"] == stats["zreplay_picks"] == 40 * runs \
-        == stats["pods_by_path"]["group_device"]
+    assert stats["zreplay_steps"] == stats["zreplay_picks"] \
+        == 40 * runs + alone
+    assert stats["pods_by_path"]["group_device"] == 40 * runs
+    assert stats["zreplay_rescores"] <= stats["zreplay_steps"]
     # where the parent paid 8 run slots x 64 pick steps
     assert stats["zreplay_steps"] <= 160 < 8 * 64
     shown = render_traces({"limit": "1"})["wave"]
@@ -240,6 +245,180 @@ def test_a_run_longer_than_its_nodes_rescores_and_says_so():
     rescores = algo._wave.stats["zreplay_rescores"]
     assert 0 < rescores <= algo._wave.stats["zreplay_steps"] == 160
     assert profile.wave_totals()["zreplay_rescores"] - before == rescores
+
+
+# -- an epoch of the device replay ends only when its evaluation is spent --------
+#
+# A run with the self-anti veto takes every node it picks out of the fit
+# set. The carried score reads the fit set through three normalisers'
+# extremes alone (models/zreplay._replay_run's `holds_extreme`), so such
+# a run is one evaluation unless a node that leaves HELD one of them.
+
+
+def _annotated(pod, **affinity):
+    import json
+
+    pod.metadata.annotations = {
+        "scheduler.alpha.kubernetes.io/affinity": json.dumps(affinity)}
+    return pod
+
+
+def _self_anti(t, i, **more):
+    """rc-t's replica i with a required hostname anti-affinity term on
+    its own controller's label: one a node."""
+    return _annotated(_pod(t, i), podAntiAffinity={
+        "requiredDuringSchedulingIgnoredDuringExecution": [{
+            "labelSelector": {"matchLabels": {"rc": f"rc-{t}"}},
+            "topologyKey": "kubernetes.io/hostname", "namespaces": []}]},
+        **more)
+
+
+@pytest.mark.parametrize("shape", ["alone", "in-a-group"])
+def test_a_vetoed_run_of_40_is_one_evaluation(shape):
+    """mixed-5k's vetoed controllers cut down: 40 replicas in a row on
+    one zone, their service scoring 0 on NodeAffinity, TaintToleration
+    and InterPodAffinity. Every pick leaves the fit set and none holds
+    an extreme: no step rescores, alone (`jit_zreplay_run`) or between
+    a plain run and another vetoed one (`jit_zreplay_group`)."""
+    from kubernetes_tpu.oracle import ClusterState, GenericScheduler
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    from tests.test_conformance import ORACLE_PREDICATES, ORACLE_PRIORITIES
+
+    rows = {"alone": [0], "in-a-group": [0, 1, 2]}[shape]
+    state = ClusterState.build(_nodes(64, "a"), controllers=_controllers(3))
+    backlog = [_pod(t, i) if t == 1 else _self_anti(t, i)
+               for t in rows for i in range(40)]
+    algo = TPUScheduleAlgorithm()
+    hosts = algo.schedule_backlog(backlog, state)
+    oracle = GenericScheduler(predicates=ORACLE_PREDICATES,
+                              priorities=ORACLE_PRIORITIES)
+    assert hosts == oracle.schedule_backlog(backlog, state.clone())
+    assert len(set(hosts[:40])) == 40  # one a node
+    stats = algo._wave.stats
+    kind = "zreplay" if shape == "alone" else "zreplay_group"
+    assert stats["dispatches_by_kind"] == {kind: 1}
+    assert stats["zreplay_steps"] == stats["zreplay_picks"] == len(backlog)
+    assert stats["zreplay_slots"] == (0 if shape == "alone" else 3)
+    assert stats["zreplay_rescores"] == 0
+
+
+def _loaded(node, cpu, i):
+    """A bound pod of no service that takes `cpu` of `node`."""
+    return Pod(metadata=ObjectMeta(name=f"load-{i}", labels={"load": "y"}),
+               spec=PodSpec(node_name=node, containers=[Container(
+                   requests={"cpu": cpu, "memory": "500Mi"})]))
+
+
+def _holder_of_the_node_affinity_maximum():
+    """node 0 alone holds gold (90) and silver (10): NodeAffinity's
+    maximum is 100 while it fits and 10 once it is picked, so the silver
+    nodes' share goes 1 -> 10 and they overtake the emptier plain ones."""
+    nodes = _nodes(12, "a")
+    nodes[0].metadata.labels["gold"] = "y"
+    for node in nodes[:6]:
+        node.metadata.labels["silver"] = "y"
+    bound = [_loaded(n.metadata.name, "2", i)
+             for i, n in enumerate(nodes[1:6])]
+
+    prefer = {"nodeAffinity": {
+        "preferredDuringSchedulingIgnoredDuringExecution": [
+            {"weight": 90, "preference": {"matchExpressions": [
+                {"key": "gold", "operator": "In", "values": ["y"]}]}},
+            {"weight": 10, "preference": {"matchExpressions": [
+                {"key": "silver", "operator": "In", "values": ["y"]}]}}]}}
+    return nodes, bound, prefer
+
+
+def _taints(node, count):
+    import json
+
+    from kubernetes_tpu.api.types import TAINTS_ANNOTATION
+
+    node.metadata.annotations = {TAINTS_ANNOTATION: json.dumps([
+        {"key": f"t{k}", "value": "v", "effect": "PreferNoSchedule"}
+        for k in range(count)])}
+
+
+def _holder_of_the_taint_maximum():
+    """node 0 alone has 20 PreferNoSchedule taints, node 1 has 18
+    (zreplay.py's own mx = 20, c = 18: (1 - 18/20) * 10 truncates to 0,
+    where (10 * (20 - 18)) // 20 gives 1), five have 10 and five none.
+    Node 0 is the only empty one, so it is picked while the others fit,
+    and TaintToleration's maximum falls 20 -> 18."""
+    nodes = _nodes(12, "a")
+    for node, count in zip(nodes, [20, 18, 10, 10, 10, 10, 10]):
+        _taints(node, count)
+    bound = [_loaded(n.metadata.name, cpu, i) for i, (n, cpu) in enumerate(
+        zip(nodes[1:], ["1", "1", "1", "1", "1", "1", "2", "2", "3", "3",
+                        "3"]))]
+    return nodes, bound, {}
+
+
+def _holder_of_an_interpod_extreme(sign):
+    """node 0 holds a bound pod whose preferred hostname (anti-)affinity
+    term on the run's controller weighs 50, nodes 1-5 one of weight 5:
+    the inter-pod totals' maximum (affinity) falls 50 -> 5, or their
+    minimum (anti-affinity) rises -50 -> -5, when node 0 is picked."""
+    nodes = _nodes(12, "a")
+    kind = "podAffinity" if sign > 0 else "podAntiAffinity"
+    bound = []
+    for i, node in enumerate(nodes[:6]):
+        owner = Pod(
+            metadata=ObjectMeta(name=f"owner-{i}", labels={"own": "y"}),
+            spec=PodSpec(node_name=node.metadata.name, containers=[
+                Container(requests={"cpu": "100m", "memory": "500Mi"})]))
+        bound.append(_annotated(owner, **{kind: {
+            "preferredDuringSchedulingIgnoredDuringExecution": [{
+                "weight": 50 if i == 0 else 5, "podAffinityTerm": {
+                    "labelSelector": {"matchLabels": {"rc": "rc-0"}},
+                    "topologyKey": "kubernetes.io/hostname",
+                    "namespaces": []}}]}}))
+    # the plain nodes are fuller: with the anti-affinity term node 0 is
+    # the least wanted of its kind and still goes before them
+    bound += [_loaded(n.metadata.name, "3", i)
+              for i, n in enumerate(nodes[6:])]
+    return nodes, bound, {}
+
+
+ADVERSARIAL = {
+    "node-affinity-maximum": _holder_of_the_node_affinity_maximum,
+    "taint-maximum-20-over-18": _holder_of_the_taint_maximum,
+    "interpod-maximum": lambda: _holder_of_an_interpod_extreme(+1),
+    "interpod-minimum": lambda: _holder_of_an_interpod_extreme(-1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ADVERSARIAL))
+def test_a_node_that_leaves_holding_an_extreme_still_ends_the_epoch(case):
+    """A vetoed run of 10 on 12 nodes: the one node that holds the
+    extreme is picked while others still fit, the normaliser moves, and
+    the picks behind it are the oracle's only if the score is evaluated
+    again."""
+    from kubernetes_tpu.oracle import ClusterState, GenericScheduler
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    from tests.test_conformance import ORACLE_PREDICATES, ORACLE_PRIORITIES
+
+    nodes, bound, more = ADVERSARIAL[case]()
+    state = ClusterState.build(nodes, bound, controllers=_controllers(1))
+    backlog = [_self_anti(0, i, **more) for i in range(10)]
+    for pod in backlog:
+        # a pod without tolerations fits no tainted node at all
+        pod.spec.tolerations = [Toleration(
+            key="t0", operator="Equal", value="v",
+            effect="PreferNoSchedule")]
+    oracle = GenericScheduler(predicates=ORACLE_PREDICATES,
+                              priorities=ORACLE_PRIORITIES)
+    want = oracle.schedule_backlog(backlog, state.clone())
+    holder = nodes[0].metadata.name
+    assert holder in want[:-1] and None not in want, want
+    algo = TPUScheduleAlgorithm(min_run=1)
+    assert algo.schedule_backlog(backlog, state) == want
+    stats = algo._wave.stats
+    assert stats["dispatches_by_kind"] == {"zreplay": 1}
+    assert stats["zreplay_steps"] == stats["zreplay_picks"] == 10
+    assert 0 < stats["zreplay_rescores"] < 10
 
 
 def test_a_wave_of_another_run_count_builds_no_program():
