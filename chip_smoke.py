@@ -142,10 +142,13 @@ def density_cluster(num_nodes: int, num_pods: int):
 
 def mixed_cluster(num_nodes: int, num_pods: int, min_run: int = 16):
     """A zoned cluster and a backlog that takes every single-chip
-    program: 8 adjacent request templates (grouped probe + grouped
-    fold), one soft anti-affinity template (per-run probe + apply), one
-    service-backed run (zoned device replay), and singletons below
-    min_run (the scan)."""
+    program: 8 adjacent request templates, the first of which a term
+    selects (a device replay of its own, then the grouped probe), one
+    soft anti-affinity template and one service-backed run (the term
+    owner and the zoned spread run side by side: the grouped device
+    replay, which carries the grouped fold), a lone plain run (a probe
+    of its own, its fold settled apart before the scan), and singletons
+    below min_run (the scan)."""
     from kubernetes_tpu.api.types import (
         AFFINITY_ANNOTATION,
         ObjectMeta,
@@ -162,7 +165,7 @@ def mixed_cluster(num_nodes: int, num_pods: int, min_run: int = 16):
         for i in range(num_nodes)
     ]
     singles = min(2 * (min_run - 1), max(num_pods // 16, 2))
-    per_run = (num_pods - singles) // 10
+    per_run = (num_pods - singles) // 11
     assert per_run >= min_run, "backlog too small to form template runs"
     pods = []
     for t in range(8):
@@ -181,10 +184,13 @@ def mixed_cluster(num_nodes: int, num_pods: int, min_run: int = 16):
     pods += [_pod(f"anti-{i:04d}", mem="200Mi", labels={"app": "anti"},
                   annotations={AFFINITY_ANNOTATION: soft_anti})
              for i in range(per_run)]
-    spread = num_pods - singles - 9 * per_run
+    spread = num_pods - singles - 10 * per_run
     pods += [_pod(f"svc-{i:04d}", mem="200Mi",
                   labels={"app": "svc-backed"})
              for i in range(spread)]
+    pods += [_pod(f"lone-{i:04d}", cpu="350m", mem="200Mi",
+                  labels={"app": "lone"})
+             for i in range(per_run)]
     # distinct requests, fewer than min_run of each: never a run
     pods += [_pod(f"single-{i:04d}", cpu=f"{300 + i}m", mem="200Mi",
                   labels={"app": "single"})
@@ -276,7 +282,8 @@ def phase_oracle(density_nodes: int, density_pods: int,
     want = GenericScheduler().schedule_backlog(pods, state)
     assert got == want, _first_diff("mixed", got, want)
     ran = dict(algo._wave.dispatches)
-    for program in ("scan", "group_probe", "probe", "apply", "zreplay"):
+    for program in ("scan", "group_probe", "probe", "apply", "zreplay",
+                    "zreplay_group"):
         assert ran.get(program, 0) >= 1, (
             f"mixed backlog never dispatched {program!r}: {ran}")
     return ran

@@ -336,8 +336,9 @@ def build_programs(include_mesh: bool = True, num_nodes: int = 13,
               jnp.asarray(np.int32(K)), np.int64(0)),
         allow_f64=True,  # mirrors replay._scores float64 exactly
         carry_out_leaves=carry_leaves,
-        # chosen, counts, L, n_done, the loop's own counters [2]
-        expected_host_leaves=5,
+        # chosen, counts, L, n_done, the loop's own counters [2], the
+        # nodes that fit at the probe
+        expected_host_leaves=6,
         notes="zoned-spread device replay (models/zreplay)",
     ))
     Gz = 8
@@ -361,8 +362,9 @@ def build_programs(include_mesh: bool = True, num_nodes: int = 13,
               np.int32(Gz), np.int64(0)),
         allow_f64=True,
         carry_out_leaves=carry_leaves,
-        # chosen[G,K], n_done[G], L, the loops' own counters [3]
-        expected_host_leaves=4,
+        # chosen[G,K], n_done[G], L, the loops' own counters [3], the
+        # nodes that fit at each run slot's probe [G]
+        expected_host_leaves=5,
         notes="grouped zoned device replay: G runs, one dispatch",
     ))
 

@@ -75,6 +75,7 @@ from kubernetes_tpu.models.waveloop import (  # noqa: F401
     host_group_cap,
     host_group_replay,
     pick_j,
+    replay_g_bucket,
     replay_k_bucket,
     run_wave,
 )
@@ -156,9 +157,12 @@ def run_pure(config: SchedulerConfig, batch: PodBatch, i: int,
     (models/hosttab rebuilds the j-axis from the shipped usage), host
     port masks and spread class counts (exact host-side deltas).
     Impure-but-eligible runs — inter-pod term owners / spec matchers,
-    service members — keep the per-run probe: their commits mutate carry
-    tables (ip reverse tables, svc peer counts) that later runs' probed
-    headers can't be adjusted for host-side.  svc_free is the hoistable
+    service members — get no grouped header probe: their commits mutate
+    carry tables (ip reverse tables, svc peer counts) that later runs'
+    probed headers can't be adjusted for host-side. They take a probe
+    of their own a run, or, term owners and spec matchers on one chip,
+    the device replay, whose every probe reads the live carry
+    (`classify_runs`).  svc_free is the hoistable
     per-config invariant (no ServiceAffinity/ServiceAntiAffinity
     labels)."""
     if svc_free is None:
@@ -290,12 +294,14 @@ SCAN_COUNTERS = ("scan_steps", "scan_bucket_steps")
 LOOP_COUNTERS = ("scan_flushes",)
 #: what `stats` counts of the runs that carry a self-anti veto (pods
 #: whose required hostname anti-affinity term selects their own labels;
-#: `run_verdict`), which `waveloop.run_single` decides one probe a run: the
-#: runs, the pods they placed, and summed over the runs the real nodes
-#: the run's FIRST probe found unfit, on the tables it shipped (where
-#: nothing but the terms of bound pods excludes a node, how much of the
-#: cluster they have taken from a run before it starts); counted at the
-#: wave's end, with `pods_by_path`
+#: `run_verdict`), which the device replay decides a group a dispatch on
+#: one chip and `waveloop.run_single` one probe a run elsewhere
+#: (`waveloop.count_veto`): the runs, the pods they placed, and summed
+#: over the runs the real nodes the run's FIRST probe found unfit, by
+#: the replay program's own count of the nodes that fit or on the tables
+#: a host probe shipped (where nothing but the terms of bound pods
+#: excludes a node, how much of the cluster they have taken from a run
+#: before it starts); counted at the wave's end, with `pods_by_path`
 ANTI_COUNTERS = ("anti_runs", "anti_picks", "anti_nodes_excluded")
 #: what `stats` counts of the runs whose pod owns a required podAffinity
 #: term (every run of a wave, whatever its length; `run_verdict` sends
@@ -448,18 +454,25 @@ def classify_runs(config: SchedulerConfig, snap: ClusterSnapshot,
         svc_ctx = svc_run_context(
             config, snap, batch, rep, num_values
         ) if eligible else None
+        pure = bool(
+            eligible and veto is None and svc_ctx is None
+            and run_pure(config, batch, rep, svc_free=svc_free))
         runs.append(Run(
             rep, start, length, eligible=eligible, refused=refused,
             veto=veto, svc_ctx=svc_ctx,
-            # an atomic gang takes the host probe/replay path only (the
-            # device zoned replay folds commits in-program and cannot
-            # discard a partial gang)
+            # the device replay takes what the host's routes decide
+            # badly: the zone blend (the C engine cannot bucket it) and a
+            # run the grouped header probe cannot take (a veto, an owner
+            # of a term, a matcher of a spec: a probe of its own a run on
+            # the host, grouped freely in the program). An atomic gang
+            # takes the host probe/replay path only (the device replay
+            # folds commits in-program and cannot discard a partial gang)
             device=bool(
-                eligible and device_zoned and zoned and gang is None
-                and bool(batch.has_selectors[rep]) and svc_ctx is None),
-            pure=bool(
-                eligible and veto is None and svc_ctx is None
-                and run_pure(config, batch, rep, svc_free=svc_free)),
+                eligible and device_zoned and gang is None
+                and svc_ctx is None
+                and (zoned and bool(batch.has_selectors[rep])
+                     or not pure)),
+            pure=pure,
             gang=gang,
         ))
     return runs
@@ -583,11 +596,15 @@ class WaveScheduler(WaveCounts):
         # per-wave device-dispatch tally (tests assert the grouped path
         # keeps this independent of the template count)
         self.dispatches: dict = {}
-        # zoned selector-spread runs replay ON DEVICE (one lax.scan
-        # dispatch) instead of the per-pick numpy spec replay — the
-        # zone blend couples whole zones per commit, which the C engine
-        # can't bucket and numpy pays ~0.4ms/pick for. Opt out (e.g.
-        # for differential testing of the host path) via replay=.
+        # two kinds of run replay ON DEVICE (`classify_runs` sets
+        # `Run.device`): zoned selector-spread runs — the zone blend
+        # couples whole zones per commit, which the C engine can't
+        # bucket and numpy pays ~0.4ms/pick for — and runs the grouped
+        # header probe cannot take (a self-anti veto, an owner of a
+        # term, a matcher of a spec), which the host decides one probe
+        # round trip a run and the device program a group a dispatch.
+        # Opt out (e.g. for differential testing of the host path) via
+        # replay=.
         self._device_zoned = replay is None
         self._packer = Packer()
         # device-resident snapshot fields across waves (the mesh path's
@@ -640,7 +657,7 @@ class WaveScheduler(WaveCounts):
             # the wave loop's steps by kind and its flushes of the scan
             "steps_by_kind": dict.fromkeys(PATHS, 0),
             **dict.fromkeys(LOOP_COUNTERS, 0),
-            # the runs with a self-anti veto (`run_single`), all waves
+            # the runs with a self-anti veto (`count_veto`), all waves
             **dict.fromkeys(ANTI_COUNTERS, 0),
             # the runs whose pod owns a required podAffinity term, and
             # why runs of `min_run` pods went to the scan (`count_runs`)
@@ -1098,10 +1115,13 @@ class WaveScheduler(WaveCounts):
                   reps: Sequence[int]) -> List[str]:
         """What a run of `min_run` pods of each pod row `reps` is to the
         plan: "scan" (the scan's, whatever its length), "device" (a
-        device replay's, alone or grouped with its like), "pure" (a
-        grouped header probe's with its like, else a probe's of its
-        own) or "single" (a probe's of its own whatever its neighbours:
-        a veto, a term owner). It says which runs `waveloop.next_step`
+        device replay's, alone or grouped with its like: a zoned spread
+        run, a veto, a term owner, a spec matcher), "pure" (a grouped
+        header probe's with its like, else a probe's of its own) or
+        "single" (a probe's of its own whatever its neighbours: what
+        "device" names where the host replays, `replay=`, and a member
+        of a service under a ServiceAffinity policy). It says which
+        runs `waveloop.next_step`
         puts into one step, and so which programs they meet (the
         daemon's re-warm deals its warm runs by it)."""
         runs = classify_runs(
@@ -1184,10 +1204,21 @@ class WaveScheduler(WaveCounts):
         cm[:len(runs)] = counts_mat
         wave.fold = ("group", buf, layout, cm)
 
+    def _count_excluded(self, wave: Wave, runs: Sequence[Run], fits) -> None:
+        """`anti_nodes_excluded` for the vetoed ones of `runs`, whose
+        first probes found `fits` nodes fit: the program's own count of
+        them, no second read."""
+        fits = [int(fit) for run, fit in zip(runs, fits)
+                if run.veto is not None]
+        if fits:
+            real = int(np.count_nonzero(np.asarray(wave.snap.alloc_pods) > 0))
+            wave.tallies["anti_nodes_excluded"] += real * len(fits) - sum(fits)
+
     def replay_run_device(self, wave: Wave, run: Run, done0: int) -> None:
-        """A zoned-spread run by itself: probe + pick sequence + commit
-        fold in one device dispatch (models/zreplay) for every table
-        horizon it meets; the run's commits are folded in the program."""
+        """A device run (`Run.device`) by itself: probe + pick sequence +
+        commit fold in one device dispatch (models/zreplay) for every
+        table horizon it meets; the run's commits are folded in the
+        program."""
         layout, buf = pack_arrays(wave.pod_row(run.rep))
         zone_perm = wave.zone_perm()
         veto = np.zeros(wave.N, bool) if run.veto is None \
@@ -1214,6 +1245,9 @@ class WaveScheduler(WaveCounts):
                     n_done = int(n_done)
                     L = int(L)
                     steps, rescores = np.asarray(self._zreplay.run_ran)
+                    fit = int(self._zreplay.run_fit)
+            if done == 0:  # the run's first probe (a group's otherwise)
+                self._count_excluded(wave, [run], [fit])
             wave.tallies.update({
                 "zreplay_steps": int(steps),
                 "zreplay_rescores": int(rescores),
@@ -1226,13 +1260,14 @@ class WaveScheduler(WaveCounts):
         wave.pending.extend(range(run.start + done, run.stop))
 
     def replay_group_device(self, wave: Wave, runs: Sequence[Run]):
-        """K zoned-spread runs, ONE fused device dispatch: probe + pick
-        loop + commit fold per run inside one outer loop
+        """K device runs, ONE fused device dispatch: probe + pick loop +
+        commit fold per run inside one outer loop
         (models/zreplay.run_group), carry threaded run to run.
         -> None, or (g, picks done of run g) where it stopped early."""
         G = len(runs)
+        # a floor no less than the runs is the bucket
         G_bucket, glayout, gbuf = group_buffer(
-            wave.batch, [run.rep for run in runs])
+            wave.batch, [run.rep for run in runs], floor=replay_g_bucket(G))
         K_bucket = replay_k_bucket(max(run.length for run in runs),
                                    ZREPLAY_GROUP_K_FLOOR)
         zone_perm = wave.zone_perm()
@@ -1264,6 +1299,8 @@ class WaveScheduler(WaveCounts):
                 wave.L_host = int(L)
                 steps, slots, rescores = np.asarray(
                     self._zreplay.group_ran)
+                fits = np.asarray(self._zreplay.group_fit)
+        self._count_excluded(wave, runs[:int(slots)], fits)
         wave.tallies.update({
             "zreplay_steps": int(steps), "zreplay_slots": int(slots),
             "zreplay_rescores": int(rescores),

@@ -88,8 +88,9 @@ class Run:
     #: one (`models/wave.run_verdict`)
     veto: Optional[np.ndarray] = None
     svc_ctx: Optional[dict] = None  # `models/wave.svc_run_context`
-    #: probe, picks and fold in one device program (zoned spread runs;
-    #: only the single-chip driver's classification sets it)
+    #: probe, picks and fold in one device program: zoned spread runs,
+    #: and runs the grouped header probe cannot take (a veto, or not
+    #: `pure`); only the single-chip driver's classification sets it
     device: bool = False
     #: a grouped probe's host adjustments cover its commits (`run_pure`)
     pure: bool = False
@@ -122,9 +123,18 @@ class Step(NamedTuple):
 #: the grouped device replay's bounds: runs a dispatch, pods a run, and
 #: how many pick slots its [G bucket, K bucket] buffer may hold for each
 #: pick the group makes
-DEVICE_GROUP_RUNS = 512
+DEVICE_GROUP_RUNS = 128
 DEVICE_RUN_PODS = 1 << 16
 DEVICE_SLOTS_PER_PICK = 8
+#: the run-slot buckets of the grouped device replay. A bucket is a
+#: program of its own (at 2,048 node slots 15-19 s to compile on the
+#: chip and 3.3 s to trace and load from the compile cache: PERF.md,
+#: PR 52), where a padded run slot costs bytes of operands alone (a veto
+#: row, a pod row, a row of picks back: the run loop ends at the real
+#: run count). So the ladder is two steps, and where a wave is one
+#: group (`classify_runs`) the daemon warms both behind the first wave
+#: that shows the widths they are traced per
+DEVICE_SLOT_BUCKETS = (32, DEVICE_GROUP_RUNS)
 
 #: pick-buffer length floors of the zoned device replay: one run per
 #: dispatch pads to 256; the grouped form keeps K picks PER RUN SLOT, so
@@ -138,6 +148,12 @@ def replay_k_bucket(length: int, floor: int) -> int:
     """The compiled pick-buffer length for a device-replayed run (or a
     group's longest run) of `length` pods."""
     return next_pow2(min(length, DEVICE_RUN_PODS), floor=floor)
+
+
+def replay_g_bucket(runs: int) -> int:
+    """The compiled run-slot count for a device-replayed group of `runs`
+    (<= DEVICE_GROUP_RUNS) runs."""
+    return next(b for b in DEVICE_SLOT_BUCKETS if runs <= b)
 
 
 def host_group_cap(num_nodes: int) -> int:
@@ -521,6 +537,10 @@ def run_wave(dev, wave: Wave, runs: Sequence[Run], policy: Policy) -> None:
             for run in step.runs:
                 wave.via[run.start:run.stop] = _GROUP_DEVICE
             stopped = dev.replay_group_device(wave, step.runs)
+            # the run a group broke off at is `run_single`'s to count
+            whole = len(step.runs) if stopped is None else stopped[0]
+            for run in step.runs[:whole]:
+                count_veto(wave, run)
         if stopped is None:
             idx += len(step.runs)
         else:
@@ -554,11 +574,18 @@ def run_single(dev, wave: Wave, run: Run, done0: int = 0) -> None:
         dev.replay_run_device(wave, run, done0)
     else:
         _run_tables(dev, wave, run, done0)
+    count_veto(wave, run)
+
+
+def count_veto(wave: Wave, run: Run) -> None:
+    """A run the run machinery has decided, into `anti_runs` and
+    `anti_picks` where it carries a self-anti veto: once a run, all its
+    picks so far (a group's before it broke off too); what it left to
+    `pending` is the scan's, decided later."""
     if run.veto is not None:
-        # what is left to `pending` is the scan's, decided later
         wave.tallies["anti_runs"] += 1
         wave.tallies["anti_picks"] += int(np.count_nonzero(
-            wave.out[run.start + done0:run.stop] >= 0))
+            wave.out[run.start:run.stop] >= 0))
 
 
 def _run_tables(dev, wave: Wave, run: Run, done0: int) -> None:

@@ -33,8 +33,12 @@ Two entry points share the same probe+replay body:
     a dispatch, advanced by each run's picks: _run_slots), and a run's
     fold writes its picks into the tables, not every node.
 
-Scope: runs whose only cross-node coupling is the zone blend (the
-common zoned-cluster case). ServiceAffinity/ServiceAntiAffinity
+Scope (`models/wave.classify_runs` sets `Run.device`): runs whose
+cross-node coupling is the zone blend (the common zoned-cluster case),
+and, whatever the zoning, runs the grouped header probe cannot take: a
+self-anti veto, an owner of a term, a matcher of a spec, which the host
+decides one probe round trip a run and this program a group a dispatch
+(`num_zones == 1` blends nothing). ServiceAffinity/ServiceAntiAffinity
 dynamics stay on the host spec replay (policy-config scale is smaller).
 """
 
@@ -195,7 +199,8 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
     zone_id/veto/alloc are PERMUTED to name-desc order already. Returns
     (j i32[N] permuted-space commit counts, chosen i32[K] permuted-space
     ids, -1 past the last step, L, n_done, bailed, the steps run, the
-    epochs after the first: the rescores)."""
+    epochs after the first: the rescores, and how many nodes fit before
+    the first pick: what the cluster left the run)."""
     stk, _tab = _probe_rows(config, num_zones, num_values, J, static,
                             carry, pod, views)
     N = perm.shape[0]
@@ -435,7 +440,8 @@ def _replay_run(config, num_zones, num_values, J, K, static, carry, pod,
             jnp.int64(L0), k_real, jnp.bool_(False),
             jnp.full((K,), -1, jnp.int32), jnp.int32(0),
         ))
-    return j, chosen, L, n_done, stopped, steps, rescores
+    return (j, chosen, L, n_done, stopped, steps, rescores,
+            fit0.sum(dtype=jnp.int32))
 
 
 @jax.named_scope("zreplay")
@@ -449,7 +455,8 @@ def _zreplay_fn(config, num_zones, num_values, J, K, layout, apply_fn,
     zone_id/veto are PERMUTED to name-desc order already; probe rows are
     permuted inside. Returns (carry', chosen[K] permuted-space ids,
     counts[N] node-order, L', n_done, ran i32[2]: the pick steps the
-    loop ran and those that evaluated the carried score again)."""
+    loop ran and those that evaluated the carried score again, fit i32:
+    the nodes that fit at the probe)."""
     from kubernetes_tpu.models.pack import unpack as _unpack_pod
 
     if fold_prev:
@@ -458,7 +465,7 @@ def _zreplay_fn(config, num_zones, num_values, J, K, layout, apply_fn,
     pod = _unpack_pod(layout, pod_buf)
     perm, inv, alloc = _name_desc_tables(static)
     views, _dom_lt = _carried_views(config, static, carry)
-    j, chosen, L, n_done, _stopped, steps, rescores = _replay_run(
+    j, chosen, L, n_done, _stopped, steps, rescores, fit = _replay_run(
         config, num_zones, num_values, J, K, static, carry, pod, perm,
         alloc, zone_id, veto, has_selectors, rows_dyn, k_real, L0, views,
     )
@@ -466,7 +473,8 @@ def _zreplay_fn(config, num_zones, num_values, J, K, layout, apply_fn,
     carry, counts, _at = _fold_run(
         apply_fn, static, carry, pod, perm, inv, j, chosen,
         views is not None)
-    return carry, chosen, counts, L, n_done, jnp.stack([steps, rescores])
+    return (carry, chosen, counts, L, n_done, jnp.stack([steps, rescores]),
+            fit)
 
 
 def _run_slots(config, num_zones, num_values, J, K, G, apply_fn, static,
@@ -476,15 +484,16 @@ def _run_slots(config, num_zones, num_values, J, K, G, apply_fn, static,
     for each of the first `runs` rows of `pods` (every field with a
     leading G axis), the carry and its inter-pod views threaded from
     slot to slot -> (carry', views' or None, chosen[G, K], n_done[G],
-    L', ran i32[3])."""
+    L', ran i32[3], fits i32[G]: the nodes that fit at each slot's
+    probe, 0 for a slot the loop never entered)."""
     perm, inv, alloc = _name_desc_tables(static)
     views, dom_lt = _carried_views(config, static, carry)
 
     def run_body(state):
         (g, carry, views, L, _bailed, chosen, n_done, steps,
-         rescores) = state
+         rescores, fits) = state
         pod = {f: v[g] for f, v in pods.items()}
-        j, picks, L, done, bailed, ran, rescored = _replay_run(
+        j, picks, L, done, bailed, ran, rescored, fit = _replay_run(
             config, num_zones, num_values, J, K, static, carry, pod,
             perm, alloc, zone_id, vetos[g], has_sels[g], rows_arr[g],
             k_reals[g], L, views,
@@ -497,21 +506,21 @@ def _run_slots(config, num_zones, num_values, J, K, G, apply_fn, static,
                                    views, pod, *at, ran)
         return (g + 1, carry, views, L, bailed, chosen.at[g].set(picks),
                 n_done.at[g].set(done), steps + ran,
-                rescores + rescored)
+                rescores + rescored, fits.at[g].set(fit))
 
     def more(state):
         g, bailed = state[0], state[4]
         return (g < runs) & ~bailed
 
     (slots, carry, views, L, _bailed, chosen, n_done, steps,
-     rescores) = jax.lax.while_loop(
+     rescores, fits) = jax.lax.while_loop(
         more, run_body,
         (jnp.int32(0), carry, views, jnp.int64(L0), jnp.bool_(False),
          jnp.full((G, K), -1, jnp.int32), jnp.zeros((G,), jnp.int32),
-         jnp.int32(0), jnp.int32(0)),
+         jnp.int32(0), jnp.int32(0), jnp.zeros((G,), jnp.int32)),
     )
     return (carry, views, chosen, n_done, L,
-            jnp.stack([steps, slots, rescores]))
+            jnp.stack([steps, slots, rescores]), fits)
 
 
 @jax.named_scope("zreplay")
@@ -528,7 +537,8 @@ def _zreplay_group_fn(config, num_zones, num_values, J, K, G, layout,
     -1); the host resumes from there. Returns (carry', chosen[G, K],
     n_done[G], L', ran i32[3]: the pick steps and the run-slot
     iterations the two loops ran, and the steps that evaluated the
-    carried score again)."""
+    carried score again, fits i32[G]: the nodes that fit at each run
+    slot's probe)."""
     from kubernetes_tpu.models.pack import unpack as _unpack_pod
 
     if prev_kind == "single":
@@ -538,10 +548,10 @@ def _zreplay_group_fn(config, num_zones, num_values, J, K, G, layout,
         carry = apply_group_fn(prev_layout, static, carry, prev_buf,
                                prev_counts)
     pods = _unpack_pod(layout, group_buf)  # each field: leading G axis
-    carry, _views, chosen, n_done, L, ran = _run_slots(
+    carry, _views, chosen, n_done, L, ran, fits = _run_slots(
         config, num_zones, num_values, J, K, G, apply_fn, static, carry,
         pods, zone_id, vetos, has_sels, rows_arr, k_reals, runs, L0)
-    return carry, chosen, n_done, L, ran
+    return carry, chosen, n_done, L, ran, fits
 
 
 #: the deferred fold's operands where none rides the dispatch (host
@@ -570,6 +580,11 @@ class ZReplay:
         #: i32[2] on the device: the last run dispatch's pick steps and
         #: those that rescored
         self.run_ran = None
+        #: i32[G] / i32 on the device: the nodes that fit at each run
+        #: slot's probe of the last run_group dispatch, at the probe of
+        #: the last run dispatch
+        self.group_fit = None
+        self.run_fit = None
 
     def run(self, static, carry, prev_buf, prev_counts, pod_buf, layout,
             num_zones, num_values, J, K_bucket, zone_id_perm, veto_perm,
@@ -587,7 +602,7 @@ class ZReplay:
             self._jitted[key] = fn
         if not fold_prev:
             prev_buf, prev_counts = _NO_BUF, _NO_COUNTS
-        carry, chosen, counts, L, n_done, self.run_ran = fn(
+        carry, chosen, counts, L, n_done, self.run_ran, self.run_fit = fn(
             static, carry, prev_buf, prev_counts, pod_buf,
             zone_id_perm, veto_perm,
             np.bool_(has_selectors), np.int64(rows), np.int32(k_real),
@@ -621,7 +636,7 @@ class ZReplay:
             self._jitted[key] = fn
         if prev_kind is None:
             prev_buf, prev_counts = _NO_BUF, _NO_COUNTS
-        carry, chosen, n_done, L, self.group_ran = fn(
+        carry, chosen, n_done, L, self.group_ran, self.group_fit = fn(
             static, carry, prev_buf, prev_counts, group_buf,
             zone_id_perm, vetos_perm, has_sels, rows_arr, k_reals,
             np.int32(runs), np.int64(L0),

@@ -144,17 +144,21 @@ class TPUScheduleAlgorithm:
         # template seen pending (by feature key) and the kind of step a
         # run of it makes (`WaveScheduler.run_kinds`, once terms are
         # live), whether its pods take the scan as a rule, the widths
-        # already warmed, the widths being warmed, whether their run
-        # programs and which of their pod buckets are still to warm, and
-        # the last wave's widths
+        # the scan and the widths the run programs are already warmed
+        # at, the widths being warmed, whether their run programs, which
+        # of the scan's pod buckets and which of the grouped device
+        # replay's run-slot buckets are still to warm, and the last
+        # wave's widths
         self._live_inc = self._inc
         self._templates: dict = {}
         self._template_kinds: dict = {}
         self._warmed_widths: set = set()
+        self._warmed_run_widths: set = set()
         self._scan_bound = False
         self._rewarm_widths = None
         self._rewarm_runs = False
         self._rewarm_left: List[int] = []
+        self._rewarm_slots: List[int] = []
         self._last_widths = None
         self._service_lister = service_lister
         self._controller_lister = controller_lister
@@ -241,21 +245,27 @@ class TPUScheduleAlgorithm:
         run's probe, replay and fold, the grouped programs at two run
         slots, the scan at every pod bucket, nine row-scatter bucket
         pairs); behind the first wave that shows a set of inter-pod
-        widths on a cluster whose pods take the scan as a rule
-        (`_after_live_wave` says by what evidence), at those widths
-        the run programs on the kinds of run the templates seen pending
-        make (a run alone, its like side by side, the grouped programs
-        at their two smallest run-slot buckets) and the scan again at
-        every pod bucket (`_rewarm`, with KUBERNETES_TPU_WARM_SCAN on:
-        the terms are the pods' annotations, which nothing here can
-        know). Still warmed by nobody, and compiled where first met:
-        the grouped programs at the larger run-slot buckets a wave's
-        run count makes (32 to 128), the spread-class axis while it
-        grows as controllers' pods first appear, the probes and the
-        fold at inter-pod widths on a cluster whose pods do NOT take
-        the scan as a rule (no re-warm there), and the transfers'
-        unpack programs, one a set of tables shipped (a benchmark mix's
-        prefill steps meet those: PERF.md section 7)."""
+        widths (`_rewarm`, with KUBERNETES_TPU_WARM_SCAN on: the terms
+        are the pods' annotations, which nothing here can know), at
+        those widths: on a cluster whose pods take the scan as a rule
+        (`_after_live_wave` says by what evidence) the run programs on
+        the kinds of run the templates seen pending make (a run alone,
+        its like side by side, the grouped programs at their smallest
+        run-slot buckets) and the scan again at every pod
+        bucket; on a cluster whose pods do not, and whose runs take the
+        device replay (owners of terms the run tables hold), the same
+        run programs and `jit_zreplay_group` at every run-slot bucket a
+        wave can fill, since there a wave is ONE group of as many run
+        slots as it has runs. Still warmed by nobody, and compiled
+        where first met: the grouped device replay at its larger
+        run-slot bucket (over 32 runs) and the grouped header probe over
+        16 on a cluster that does use the scan, whose stretches cut a
+        wave's groups short; the spread-class axis while
+        it grows as controllers' pods first appear; the probes and the
+        fold at inter-pod widths where the host replays the runs
+        (`replay=`); and the transfers' unpack programs, one a set of
+        tables shipped (a benchmark mix's prefill steps meet those:
+        PERF.md section 7)."""
         from kubernetes_tpu.api.types import (
             Container,
             Node,
@@ -361,6 +371,22 @@ class TPUScheduleAlgorithm:
         while bucket <= WAVE_CAP:
             buckets.append(bucket)
             bucket *= 2
+        return buckets
+
+    def _slot_buckets(self) -> List[int]:
+        """Every run-slot bucket a grouped device replay can land in,
+        smallest first (`waveloop.DEVICE_SLOT_BUCKETS`, as far as a wave
+        of WAVE_CAP pods in runs of `min_run` reaches): each is a
+        compiled shape of its own."""
+        from kubernetes_tpu.models.waveloop import DEVICE_SLOT_BUCKETS
+        from kubernetes_tpu.scheduler.core import WAVE_CAP
+
+        most = WAVE_CAP // max(self._wave.min_run, 2)
+        buckets: List[int] = []
+        for bucket in DEVICE_SLOT_BUCKETS:
+            buckets.append(bucket)
+            if bucket >= most:
+                break
         return buckets
 
     def _warm_row_scatter(self, backlog, state, nodes, bound, bind) -> None:
@@ -568,9 +594,13 @@ class TPUScheduleAlgorithm:
         pod of every template seen pending and, once terms are live,
         the kind of step a run of it makes; and warm the scan and the
         run programs where the wave's inter-pod widths are new, or
-        buckets are still left, on a cluster whose pods take the scan
-        as a rule. The evidence for
-        that, since a warm wave costs its whole bucket of steps (2 ms a
+        buckets are still left: the scan and the run programs on a
+        cluster whose pods take the scan as a rule, the run programs
+        with the grouped device replay at every run-slot bucket on one
+        whose pods do not and whose runs are that replay's (a wave is
+        then one group, as many run slots as it has runs: each bucket
+        would compile where a wave first fills it). The evidence for
+        the first, since a warm wave costs its whole bucket of steps (2 ms a
         step with ten logical terms on the chip: 30 s for the seven
         buckets from the compile cache; PERF.md, PR 45): a template that
         `run_verdict` refuses whatever its run's length (an own
@@ -579,7 +609,8 @@ class TPUScheduleAlgorithm:
         scan decided more pods than the smallest bucket holds. Where
         every term is the run tables' (a hostname anti-affinity term)
         the scan meets these widths only through the run a wave's end
-        cuts short, in its smallest bucket, which that wave builds."""
+        cuts short, in its smallest bucket, which that wave builds, and
+        none of the scan's buckets is warmed."""
         widths = self._last_widths
         if len(self._templates) + len(reps) > 8192:
             # as PendingRows.MAX_ROWS bounds rows
@@ -601,10 +632,17 @@ class TPUScheduleAlgorithm:
                 # is gone
                 self._warmed_widths.add(widths)
                 self._rewarm_widths = widths
-                self._rewarm_runs = True
                 self._rewarm_left = self._pod_buckets()
+        if widths is not None and widths not in self._warmed_run_widths:
+            grouped = not self._scan_bound and "device" in \
+                self._template_kinds.values()
+            if self._scan_bound or grouped:
+                self._warmed_run_widths.add(widths)
+                self._rewarm_widths = widths
+                self._rewarm_runs = True
+                self._rewarm_slots = self._slot_buckets() if grouped else []
         self._templates.update(zip(keys, reps))
-        if self._rewarm_left:
+        if self._rewarm_runs or self._rewarm_left or self._rewarm_slots:
             self._rewarm(state)
 
     def _rewarm(self, state) -> None:
@@ -612,7 +650,9 @@ class TPUScheduleAlgorithm:
         shown, before the loop decides its next wave: the run programs
         on the kinds of run the templates seen pending make, then
         `jit_batch_scan` (and the transfers round it) for every pod
-        bucket from `pod_floor` to the wave cap, smallest first.
+        bucket from `pod_floor` to the wave cap, smallest first, then
+        `jit_zreplay_group` for every run-slot bucket (whichever of the
+        two `_after_live_wave` asked for).
         `warmup` cannot: it runs before a pod arrives and knows the
         controllers' selectors, not their pods' annotations, so its
         programs have zero-width inter-pod tables; and every wave
@@ -626,15 +666,19 @@ class TPUScheduleAlgorithm:
         cache, so that its vocabularies, and so its widths, are the live
         ones. The run programs by ONE backlog (`_warm_runs`), the scan
         by backlogs of a pod of every template seen pending, dealt in
-        turn (runs of length 1). The live encoder, `_last_node_index`
-        and the driver's device mirrors are as they were afterwards.
+        turn (runs of length 1), the grouped device replay by backlogs
+        of a bucket's runs each (`_warm_group`). The live encoder,
+        `_last_node_index` and the driver's device mirrors are as they
+        were afterwards.
         Where the warm view's widths are not the live ones (a term only
         deleted pods carried) that is counted (`rewarm_mismatches`) and
         logged. It starts no further warm wave after REWARM_SLICE_S and
         goes on behind the next wave. Counted in `stats` (`rewarms`,
         `rewarm_seconds`, `rewarm_programs`) and as the span
         `scheduler.rewarm` (`steps`: the steps its run backlog made, by
-        kind); its time on the timeline is the warm waves' own phases
+        kind; `buckets`, `slots`: the scan's pod buckets and the
+        replay's run-slot buckets it warmed); its time on the timeline
+        is the warm waves' own phases
         (`encode`, `transfer`, `probe`, `replay`, `score`)."""
         import time
 
@@ -651,7 +695,7 @@ class TPUScheduleAlgorithm:
         wave = self._wave
         mirrors = wave._dev, wave._dev_source
         wave._dev, wave._dev_source = {}, None
-        buckets, steps, waves, off = [], {}, 0, 0
+        buckets, slots, steps, waves, off = [], [], {}, 0, 0
 
         def warm(backlog):
             nonlocal waves, off
@@ -671,11 +715,16 @@ class TPUScheduleAlgorithm:
                              if n > ran[kind]}
             # a warm wave at the least; then the loop's turn once the
             # slice is spent, and the rest behind its next wave
-            while self._rewarm_left and (
+            while (self._rewarm_left or self._rewarm_slots) and (
                     not waves or time.time() - began < REWARM_SLICE_S):
-                bucket = self._rewarm_left.pop(0)
-                warm([templates[i % len(templates)] for i in range(bucket)])
-                buckets.append(bucket)
+                if self._rewarm_left:
+                    bucket = self._rewarm_left.pop(0)
+                    warm([templates[i % len(templates)]
+                          for i in range(bucket)])
+                    buckets.append(bucket)
+                else:
+                    slots.append(self._rewarm_slots.pop(0))
+                    warm(self._warm_group(slots[-1]))
         finally:
             wave._dev, wave._dev_source = mirrors
             self._last_widths = widths
@@ -688,14 +737,15 @@ class TPUScheduleAlgorithm:
                         "widths than the live %s", off, waves,
                         dict(zip(WIDTH_NAMES, widths)))
         count_group(wave.stats, counted)
+        left = len(self._rewarm_left) + len(self._rewarm_slots)
         log.info("re-warmed at %s: the runs' steps %s, the scan's pod "
-                 "buckets %s, in %.1fs (%d programs; %d buckets left)",
-                 dict(zip(WIDTH_NAMES, widths)), steps, buckets,
-                 ended - began, counted["rewarm_programs"],
-                 len(self._rewarm_left))
+                 "buckets %s, the replay's run-slot buckets %s, in %.1fs "
+                 "(%d programs; %d buckets left)",
+                 dict(zip(WIDTH_NAMES, widths)), steps, buckets, slots,
+                 ended - began, counted["rewarm_programs"], left)
         trace_span.record_span(
             "scheduler.rewarm", trace_span.new_trace_id(), began, ended,
-            buckets=buckets, steps=steps, left=len(self._rewarm_left),
+            buckets=buckets, slots=slots, steps=steps, left=left,
             programs=counted["rewarm_programs"],
             **dict(zip(WIDTH_NAMES, widths)))
 
@@ -709,8 +759,9 @@ class TPUScheduleAlgorithm:
         then all of them side by side, like kinds together (a
         `group_device` or `group_host` of what groups, a probe that
         carries the fold before it for what does not); then, of each
-        kind that groups, a group one run over the smallest run-slot
-        bucket, which is the next bucket (a live wave's neighbours are
+        kind that groups, a group one run over the grouped header
+        probe's smallest run-slot bucket, which is its next bucket and
+        still the device replay's first (a live wave's neighbours are
         seldom more: the grouped programs are traced a bucket). A scan
         stretch is one pod of a template that is neither neighbour's: a
         run too short for anything else; the templates take turns at it,
@@ -753,6 +804,22 @@ class TPUScheduleAlgorithm:
                     backlog += [pair[i % 2]] * row
         held = {id(pod) for pod in backlog}
         return backlog + [pod for pod in lone if id(pod) not in held]
+
+    def _warm_group(self, slots: int) -> List[Pod]:
+        """A backlog that is one grouped device replay of `slots` runs,
+        which is its run-slot bucket: `min_run` pods in a row of the
+        templates whose runs take that replay, dealt in turn (two
+        neighbours never alike: one run), and behind them a pod of
+        every other template seen pending, for the widths a live wave's
+        batch has. One template alone makes no group: one run."""
+        device = [pod for key, pod in self._templates.items()
+                  if self._template_kinds.get(key) == "device"]
+        row = max(self._wave.min_run, 2)
+        runs = slots if len(device) > 1 else len(device)
+        return [pod for i in range(runs)
+                for pod in [device[i % len(device)]] * row] + [
+            pod for key, pod in self._templates.items()
+            if self._template_kinds.get(key) != "device"]
 
     def _schedule_backlog_mesh(
         self, pods: Sequence[Pod], state: ClusterState

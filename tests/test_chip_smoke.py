@@ -46,7 +46,8 @@ def test_raw_phase_tiny():
 def test_oracle_identity_phase_tiny():
     ran = chip_smoke.phase_oracle(40, 32, 24, 200)
     # the mixed backlog exists to execute every single-chip program
-    assert {"scan", "group_probe", "probe", "apply", "zreplay"} <= set(ran)
+    assert {"scan", "group_probe", "probe", "apply", "zreplay",
+            "zreplay_group"} <= set(ran)
 
 
 def test_served_phase_tiny():

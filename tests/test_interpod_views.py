@@ -610,7 +610,7 @@ def test_after_every_run_slot_the_carried_views_are_the_folded_tables(
         def by_counts(static, carry, pod, counts, picks=None):
             return zr.apply_fn(static, carry, pod, counts)
 
-        carry, views, chosen, n_done, L, ran = _run_slots(
+        carry, views, chosen, n_done, L, ran, _fits = _run_slots(
             zr.config, num_zones, num_values, J, K, G,
             zr.apply_fn if by_picks else by_counts, static, carry,
             unpack(layout, buf), jnp.asarray(zone_id), jnp.asarray(vetos),

@@ -180,8 +180,10 @@ def test_to_dev_many_holds_non_narrowable_table_at_full_width():
 
 def _staged_backlog(num_nodes=16, num_pods=120, templates=3, block=10):
     """Blocks of impure runs (soft anti-affinity against the NEXT
-    group): consecutive single runs, each handing its fold to the
-    next run's probe."""
+    group): no grouped header probe takes them. On one chip's own route
+    they are one grouped device replay; on the host's (`replay=`)
+    consecutive single runs, each handing its fold to the next run's
+    probe."""
     nodes = [
         Node(
             metadata=ObjectMeta(
@@ -228,21 +230,31 @@ def _staged_backlog(num_nodes=16, num_pods=120, templates=3, block=10):
     return ClusterState.build(nodes, services=services), pods
 
 
+@pytest.mark.parametrize("route", ["device", "host"])
 @pytest.mark.parametrize("num_nodes,num_pods,templates", [
     (16, 120, 3), (12, 90, 3), (10, 60, 2), (8, 40, 2)])
 def test_full_stack_matches_oracle_end_to_end(num_nodes, num_pods,
-                                              templates):
+                                              templates, route):
+    from kubernetes_tpu.models.replay import replay_fast
+
     state, pods = _staged_backlog(num_nodes=num_nodes, num_pods=num_pods,
                                   templates=templates, block=10)
     want = oracle_backlog(state, pods)
     # min_run=1: blocks of 10 stay under the default min_run and would
     # all take the scan
-    algo = TPUScheduleAlgorithm(min_run=1)
+    algo = TPUScheduleAlgorithm(
+        min_run=1, replay=replay_fast if route == "host" else None)
     got = algo.schedule_backlog(pods, state)
     assert got == want
-    # every pod through run_single, each run folding its predecessor
-    assert algo._wave.stats["pods_by_path"]["single"] == num_pods
-    assert algo._wave.dispatches["probe"] == num_pods // 10
+    if route == "host":
+        # every pod through run_single, each run folding its predecessor
+        assert algo._wave.stats["pods_by_path"]["single"] == num_pods
+        assert algo._wave.dispatches["probe"] == num_pods // 10
+    else:
+        # the blocks are neighbours: one dispatch of the device replay
+        assert algo._wave.stats["pods_by_path"]["group_device"] == num_pods
+        assert algo._wave.dispatches == {"zreplay_group": 1}
+        assert algo._wave.stats["zreplay_slots"] == num_pods // 10
 
 
 @pytest.mark.parametrize("seed", [11, 23])

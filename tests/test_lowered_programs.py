@@ -9,7 +9,10 @@ source location) as the commit before PR 50 lowered it, with the jax
 version it was made under: PR 50 gave `models/probe._probe_rows` and
 `models/wave.WaveScheduler._apply_fn` an optional argument for the
 device replay, and every caller that passes none must lower to the
-program it lowered to.
+program it lowered to. Since PR 52 the two device replay programs
+(`jit_zreplay_run`, `jit_zreplay_group`) are held too, as PR 52 left them
+(it gave each one more result, the nodes that fit at a run's probe): the
+eleven digests before them are the ones the file held.
 
 A PR that means to change one of these programs makes the file anew, on
 its own tree, and says so:
@@ -29,7 +32,7 @@ PINS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                     "lowered_programs.json")
 PROGRAMS = ("scan", "probe", "probe_fused_same", "group_probe_G8", "apply",
             "apply_group", "mesh_scan", "mesh_probe", "mesh_group_probe",
-            "mesh_apply", "mesh_apply_group")
+            "mesh_apply", "mesh_apply_group", "zreplay", "zreplay_group")
 HOSTNAME = "kubernetes.io/hostname"
 ZONE = "failure-domain.beta.kubernetes.io/zone"
 LONG = {"required": "requiredDuringSchedulingIgnoredDuringExecution",

@@ -1079,8 +1079,9 @@ def test_wave_grouped_device_horizon_resume():
 
 # -- the device replay's two loops end at the real runs and picks --------------
 #
-# The grouped program is compiled for a run-slot bucket (a power of two
-# from 8) and a pick bucket (a power of two from 64); its loops run the
+# The grouped program is compiled for a run-slot bucket (32 or 128:
+# `waveloop.DEVICE_SLOT_BUCKETS`) and a pick bucket (a power of two from
+# 64); its loops run the
 # group's real run count and each run's real length. Each case is one
 # the padding used to cover.
 

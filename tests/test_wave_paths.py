@@ -423,7 +423,7 @@ def test_a_node_that_leaves_holding_an_extreme_still_ends_the_epoch(case):
 
 def test_a_wave_of_another_run_count_builds_no_program():
     """7 runs, then 8 of the same seven controllers (the program is
-    built per width of the class axis): both in the bucket of 8 run
+    built per width of the class axis): both in the bucket of 32 run
     slots and 64 picks; the run count is an argument of the one
     program, not a shape."""
     import time
@@ -454,6 +454,39 @@ def test_a_wave_of_another_run_count_builds_no_program():
     built = [c["program"] for c in profile.recent_compiles()
              if c["at"] >= t_between]
     assert not [p for p in built if "zreplay" in p], built
+
+
+def test_a_wave_of_another_count_of_vetoed_runs_builds_no_program():
+    """The same on an unzoned cluster whose runs carry a self-anti veto:
+    7 runs, then 8 of the same seven controllers, both in the bucket of
+    32 run slots and 64 picks, one `jit_zreplay_group` for both, no
+    probe."""
+    import time
+
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    profile.install_compile_listener()
+    state = ClusterState.build(_nodes(80, ""),
+                               controllers=_anti_controllers())
+    algo = TPUScheduleAlgorithm()
+    oracle = _a_serial_oracle()
+    backlog = _rows_of(_anti_pod, range(7), 16)
+    assert algo.schedule_backlog(backlog, state) \
+        == oracle.schedule_backlog(backlog, state.clone())
+    stats = algo._wave.stats
+    assert (stats["zreplay_slots"], stats["zreplay_steps"]) == (7, 112)
+    t_between = time.time()
+    backlog = _rows_of(_anti_pod, tuple(range(7)) + (0,), 16, serial=1000)
+    assert algo.schedule_backlog(backlog, state) \
+        == oracle.schedule_backlog(backlog, state.clone())
+    assert (stats["zreplay_slots"], stats["zreplay_steps"]) == (15, 240)
+    assert stats["dispatches_by_kind"] == {"zreplay_group": 2}
+    assert len(algo._wave._zreplay._jitted) == 1
+    built = [c["program"] for c in profile.recent_compiles()
+             if c["at"] >= t_between]
+    assert not [p for p in built if "zreplay" in p or "probe" in p], built
+    assert stats["anti_runs"] == 15 and stats["anti_picks"] == 240
 
 
 # -- runs with a self-anti veto, and the encoder behind a wave ----------------
@@ -511,17 +544,36 @@ ANTI_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(ANTI_CASES))
-def test_anti_counters_say_what_the_vetoed_runs_decided(case):
-    from kubernetes_tpu.oracle import ClusterState
+def _on_route(route):
+    """The served driver on one of the two routes a run the grouped
+    header probe cannot take has: the device replay (one chip's own) or
+    a probe and a host replay a run (`replay=`, which the mesh's runs
+    take too)."""
+    from kubernetes_tpu.models.replay import replay_fast
     from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
+
+    return TPUScheduleAlgorithm(
+        replay=replay_fast if route == "host" else None)
+
+
+ROUTES = ("device", "host")
+
+
+@pytest.mark.parametrize("route", ROUTES)
+@pytest.mark.parametrize("case", sorted(ANTI_CASES))
+def test_anti_counters_say_what_the_vetoed_runs_decided(case, route):
+    """The same runs, picks and excluded nodes on both routes: on the
+    device the group is one dispatch and counts the nodes that fit in
+    the program, on the host a run is a probe and counts them on the
+    tables it shipped."""
+    from kubernetes_tpu.oracle import ClusterState
     from kubernetes_tpu.trace.httpd import render_traces
 
     nodes, backlog, runs, picks, excluded = ANTI_CASES[case]
     state = ClusterState.build(_nodes(nodes, ""),
                                controllers=_anti_controllers()
                                + _controllers(2))
-    algo = TPUScheduleAlgorithm()
+    algo = _on_route(route)
     shown_before = render_traces({"limit": "1"})["wave"]
     got = algo.schedule_backlog(backlog, state)
     assert got == _oracle(state, backlog)
@@ -533,49 +585,65 @@ def test_anti_counters_say_what_the_vetoed_runs_decided(case):
     assert stats["pods_unplaced"] == len(backlog) - sum(
         h is not None for h in got) == (8 if "fills" in case else 0)
     if runs:
-        # one node holds one pod of the group, and a run is one probe
-        # (the one that finds its last node gone asks once more)
+        # one node holds one pod of the group
         by_group = [h for h in got if h is not None]
         assert len(set(by_group)) == len(by_group) or case == "two-groups"
+    if runs and route == "host":
+        # a run is one probe (the one that finds its last node gone
+        # asks once more)
         assert stats["pods_by_path"]["single"] >= picks
         assert stats["dispatches_by_kind"]["probe"] >= runs
+    elif runs:
+        # the runs are one group, one dispatch, no probe
+        assert stats["pods_by_path"]["group_device"] == len(backlog)
+        assert algo._wave.dispatches == {"zreplay_group": 1}
+        assert stats["zreplay_slots"] == runs
+        assert stats["zreplay_picks"] == picks
     shown = render_traces({"limit": "1"})["wave"]
     for key in ANTI_COUNTERS:
         assert shown[key] - shown_before[key] == stats[key]
 
 
-def test_anti_picks_never_run_ahead_of_the_pods_decided(monkeypatch):
+@pytest.mark.parametrize("route", ROUTES)
+def test_anti_picks_never_run_ahead_of_the_pods_decided(route, monkeypatch):
     """Both tallies move at a wave's end: a reader that falls into the
     middle of a wave (the benchmark's second read did, on the chip:
-    `anti_run_share.fill` 101.4) finds the picks of whole waves only."""
+    `anti_run_share.fill` 101.4) finds the picks of whole waves only,
+    at a run's probe on the host route and at a wave's one dispatch on
+    the device's."""
     from kubernetes_tpu.models.probe import WaveProbe
+    from kubernetes_tpu.models.zreplay import ZReplay
     from kubernetes_tpu.oracle import ClusterState
-    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
 
     state = ClusterState.build(_nodes(40, ""),
                                controllers=_anti_controllers())
-    algo = TPUScheduleAlgorithm()
+    algo = _on_route(route)
     stats = algo._wave.stats
     seen = []
-    sound = WaveProbe.probe_fused
+    owner, name = (WaveProbe, "probe_fused") if route == "host" \
+        else (ZReplay, "run_group")
+    sound = getattr(owner, name)
 
     def watched(self, *args, **kwargs):
         seen.append((stats["anti_picks"], stats["anti_runs"],
                      sum(stats["pods_by_path"].values())))
         return sound(self, *args, **kwargs)
 
-    monkeypatch.setattr(WaveProbe, "probe_fused", watched)
+    monkeypatch.setattr(owner, name, watched)
     algo.schedule_backlog(_anti_rows((0, 1, 2), 16), state)
     algo.schedule_backlog(_anti_rows((3, 4), 16, serial=50), state)
-    assert seen == [(0, 0, 0)] * 3 + [(48, 3, 48)] * 2
+    per_wave = (3, 2) if route == "host" else (1, 1)
+    assert seen == [(0, 0, 0)] * per_wave[0] + [(48, 3, 48)] * per_wave[1]
     assert (stats["anti_picks"], stats["anti_runs"]) == (80, 5)
 
 
-def test_bound_pods_terms_take_nodes_from_a_run_before_it_starts():
+@pytest.mark.parametrize("route", ROUTES)
+def test_bound_pods_terms_take_nodes_from_a_run_before_it_starts(route):
     """12 of 30 nodes hold a pod of the group: a run of its other
-    controller finds them unfit at its first probe, and nothing else."""
+    controller finds them unfit at its first probe, and nothing else,
+    by the program's own count of the nodes that fit as by the host's
+    count on the shipped tables."""
     from kubernetes_tpu.oracle import ClusterState
-    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
 
     nodes = _nodes(30, "")
     bound = _anti_rows((0,), 12, serial=100)
@@ -584,7 +652,7 @@ def test_bound_pods_terms_take_nodes_from_a_run_before_it_starts():
     state = ClusterState.build(nodes, bound,
                                controllers=_anti_controllers())
     backlog = _anti_rows((5,), 16) + _anti_rows((1,), 16)
-    algo = TPUScheduleAlgorithm()
+    algo = _on_route(route)
     got = algo.schedule_backlog(backlog, state)
     assert got == _oracle(state, backlog)
     taken = {p.spec.node_name for p in bound}
@@ -592,6 +660,127 @@ def test_bound_pods_terms_take_nodes_from_a_run_before_it_starts():
     stats = algo._wave.stats
     assert (stats["anti_runs"], stats["anti_picks"]) == (2, 32)
     assert stats["anti_nodes_excluded"] == 12
+
+
+# -- a run the grouped header probe cannot take goes to the device replay -----
+#
+# On one chip a run with a self-anti veto, an owner of a term or a
+# matcher of a spec takes the device replay whatever the cluster's
+# zoning (`classify_runs`): neighbours are one `jit_zreplay_group`
+# dispatch where the host's route makes a probe round trip a run. The
+# picks are the host route's and the serial oracle's, pod for pod.
+
+
+def _rows_of(make, controllers, replicas, serial=0):
+    """`replicas` in a row of each of `controllers` in turn, a
+    controller that comes twice under other names."""
+    return [make(t, serial + 100 * j + i)
+            for j, t in enumerate(controllers) for i in range(replicas)]
+
+
+def _soft_on_the_next_group(t, i, groups=5):
+    """A replica of controller `t` with a preferred hostname
+    anti-affinity term, weight 10, on the NEXT service's pods: it owns
+    a term, so no grouped header probe takes its run, and the term does
+    not select the pod's own labels, so the run has no veto and no
+    refusal."""
+    k = (t + 1) % groups
+    pod = _annotated(_anti_pod(t, i), podAntiAffinity={
+        "preferredDuringSchedulingIgnoredDuringExecution": [{
+            "weight": 10, "podAffinityTerm": {
+                "labelSelector": {"matchExpressions": [{
+                    "key": "group", "operator": "In",
+                    "values": [f"g{k}", f"g{k + groups}"]}]},
+                "topologyKey": HOSTNAME}}]})
+    pod.metadata.name = f"soft{t}-{i:04d}"
+    return pod
+
+
+ROUTE_CASES = {
+    # name: (unzoned nodes, [(bound pods, the nodes they lie on)], the
+    #        backlog, the steps of the device's route by kind, of the
+    #        host's, runs with a veto, pods that fit nowhere)
+    "runs-of-several-controllers-and-groups": (
+        40, [(lambda: _anti_rows((0,), 6, serial=900), range(0, 18, 3)),
+             (lambda: _anti_rows((1,), 4, serial=950), range(1, 17, 4))],
+        lambda: _rows_of(_anti_pod, (0, 6, 2, 5), 16),
+        {"group_device": 1}, {"single": 4}, 4, 0),
+    "a-run-finds-fewer-nodes-than-pods": (
+        24, [(lambda: _anti_rows((0,), 12, serial=900), range(0, 24, 2))],
+        lambda: _rows_of(_anti_pod, (5, 1), 16),
+        {"group_device": 1}, {"single": 2}, 2, 4),
+    "a-run-cut-under-min-run": (
+        40, [(lambda: _anti_rows((2,), 5, serial=900), range(0, 40, 8))],
+        lambda: _rows_of(_anti_pod, (0, 1), 16) + _anti_rows((2,), 9),
+        {"group_device": 1, "scan": 1}, {"single": 2, "scan": 1}, 2, 0),
+    "a-group-alternates-between-two-groups-terms": (
+        64, [(lambda: _anti_rows((0,), 4, serial=900), range(0, 64, 16)),
+             (lambda: _anti_rows((6,), 4, serial=950), range(1, 64, 16))],
+        lambda: _rows_of(_anti_pod, (0, 1, 5, 6, 0, 1), 16),
+        {"group_device": 1}, {"single": 6}, 6, 0),
+    "a-lone-vetoed-run": (
+        30, [(lambda: _anti_rows((8,), 3, serial=900), (4, 5, 6))],
+        lambda: _anti_rows((3,), 20),
+        {"single": 1}, {"single": 1}, 1, 0),
+    # owners of a term that does not select them: no veto, and still no
+    # grouped header probe's
+    "owners-of-a-term-on-another-group": (
+        30, [(lambda: _anti_rows((1,), 5, serial=900), range(0, 30, 6)),
+             (lambda: _anti_rows((2,), 3, serial=950), (1, 2, 3))],
+        lambda: _rows_of(_soft_on_the_next_group, (0, 1, 5), 16),
+        {"group_device": 1}, {"single": 3}, 0, 0),
+    # a plain run whose labels no term selects keeps the host's route,
+    # beside term owners too: the fold of its probe rides the device
+    # run's dispatch
+    "a-vetoed-run-between-two-plain-ones": (
+        30, [(lambda: _anti_rows((0,), 4, serial=900), range(0, 28, 7))],
+        lambda: _in_rows(1, 16) + _anti_rows((5,), 16)
+        + [_pod(1, i) for i in range(16)],
+        {"single": 3}, {"single": 3}, 1, 0),
+    "plain-runs-on-a-cluster-with-terms-group-on-the-host": (
+        30, [(lambda: _anti_rows((0,), 4, serial=900), range(0, 28, 7))],
+        lambda: _in_rows(2, 16),
+        {"group_host": 1}, {"group_host": 1}, 0, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTE_CASES))
+def test_the_device_route_picks_as_the_host_route_and_the_oracle(case):
+    from kubernetes_tpu.oracle import ClusterState
+
+    n, bound_on, backlog, steps, host_steps, vetoed, unplaced = \
+        ROUTE_CASES[case]
+    nodes, bound = _nodes(n, ""), []
+    for pods, places in bound_on:
+        for p, at in zip(pods(), places):
+            p.spec.node_name = nodes[at].metadata.name
+            bound.append(p)
+    state = ClusterState.build(
+        nodes, bound, controllers=_anti_controllers() + _controllers(2))
+    backlog = backlog()
+    want = _oracle(state, backlog)
+    assert want.count(None) == unplaced
+    on_device, on_host = _on_route("device"), _on_route("host")
+    assert on_device.schedule_backlog(backlog, state) == want
+    assert on_host.schedule_backlog(backlog, state) == want
+    stats, host_stats = on_device._wave.stats, on_host._wave.stats
+    assert {k: v for k, v in stats["steps_by_kind"].items() if v} == steps
+    assert {k: v for k, v in host_stats["steps_by_kind"].items() if v} \
+        == host_steps
+    for key in ANTI_COUNTERS:
+        assert stats[key] == host_stats[key], key
+    assert stats["anti_runs"] == vetoed
+    # the host's route pays a probe for every run that is not a plain
+    # one's neighbour, the device's one dispatch for the neighbours
+    launched = on_device._wave.dispatches
+    if "group_device" in steps:
+        assert launched.get("zreplay_group") == 1 and "probe" not in launched
+        assert stats["zreplay_rescores"] >= 0
+        assert stats["zreplay_steps"] == stats["zreplay_picks"] + unplaced
+    if case == "a-vetoed-run-between-two-plain-ones":
+        assert launched == {"probe": 2, "zreplay": 1, "apply": 1}
+    assert "zreplay" not in on_host._wave.dispatches
+    assert "zreplay_group" not in on_host._wave.dispatches
 
 
 def _volume_pod(i):
@@ -687,19 +876,21 @@ def test_waves_are_counted_by_encoder_and_fallbacks_by_reason(case):
     assert {k: v for k, v in rebuilt.items() if v} == rebuilds
 
 
-def test_a_later_wave_of_the_same_terms_builds_no_program():
+@pytest.mark.parametrize("route", ROUTES)
+def test_a_later_wave_of_the_same_terms_builds_no_program(route):
     """The from-scratch encoder's tables are as wide as the terms and
     spread classes the cluster and the wave hold (specs, logical terms,
-    classes), and the probe, the fold and the scan are built per width.
-    Once a pod of every controller is bound the widths stand: a wave of
-    other runs of the same controllers builds none of them again, and a
-    third wave shaped like the second builds nothing at all (the second
-    may still build the transfer programs of a layout it is first to
-    ship: `jit_pack_unpack`, `jit_row_set`)."""
+    classes), and the probe, the fold, the device replay and the scan
+    are built per width. Once a pod of every controller is bound the
+    widths stand: a wave of other runs of the same controllers (another
+    run count in the same bucket of run slots, on the device's route)
+    builds none of them again, and a third wave shaped like the second
+    builds nothing at all (the second may still build the transfer
+    programs of a layout it is first to ship: `jit_pack_unpack`,
+    `jit_row_set`)."""
     import time
 
     from kubernetes_tpu.oracle import ClusterState, GenericScheduler
-    from kubernetes_tpu.scheduler.tpu_algorithm import TPUScheduleAlgorithm
 
     from tests.test_conformance import ORACLE_PREDICATES, ORACLE_PRIORITIES
 
@@ -707,7 +898,7 @@ def test_a_later_wave_of_the_same_terms_builds_no_program():
     nodes = _nodes(64, "")
     controllers = _anti_controllers()
     bound = []
-    algo = TPUScheduleAlgorithm()
+    algo = _on_route(route)
     # one oracle for all waves: the round-robin index goes on counting
     oracle = GenericScheduler(predicates=ORACLE_PREDICATES,
                               priorities=ORACLE_PRIORITIES)
@@ -728,15 +919,20 @@ def test_a_later_wave_of_the_same_terms_builds_no_program():
     first = wave(_anti_rows(range(10), 1, serial=0))
     assert any("scan" in p for p in first)
     runs = wave(_anti_rows((0, 6, 2, 5), 16, serial=10))
-    assert any("probe_fused" in p for p in runs)
+    assert any(("probe_fused" if route == "host" else "zreplay_group") in p
+               for p in runs)
     again = wave(_anti_rows((7, 1, 8, 3, 9), 16, serial=30))
-    assert not [p for p in again
-                if "probe" in p or "apply" in p or "scan" in p], again
+    assert not [p for p in again if "probe" in p or "apply" in p
+                or "scan" in p or "zreplay" in p], again
     third = wave(_anti_rows((4, 9, 3, 6, 2), 16, serial=50))
     assert third == [], third
     stats = algo._wave.stats
     assert stats["anti_runs"] == 14 and stats["anti_picks"] == 14 * 16
     assert stats["waves_by_encoder"] == {"incremental": 0, "full": 4}
+    if route == "device":
+        # 4, 5 and 5 runs in the bucket of 32 run slots: one program
+        assert stats["dispatches_by_kind"]["zreplay_group"] == 3
+        assert len(algo._wave._zreplay._jitted) == 1
 
 
 # -- a group of runs with distinct commit vectors -----------------------------
@@ -1338,37 +1534,113 @@ def test_the_rewarm_hands_the_loop_back_and_goes_on_behind_the_next_wave(
     assert stats["rewarms"] == 7 and len(algo._warmed_widths) == 1
 
 
-def test_terms_the_run_tables_hold_rewarm_only_once_the_scan_is_used(
+def test_terms_the_run_tables_hold_warm_the_replay_and_the_scan_once_used(
         monkeypatch):
-    """A hostname anti-affinity term is the run tables': its runs go
-    run by run, and the scan meets the term widths through lone pods
-    and cut runs alone, in its smallest bucket, which the wave that
-    meets it builds. No re-warm, until a wave's scan decides more pods
-    than that bucket holds: then every bucket is warmed, once."""
+    """A hostname anti-affinity term is the run tables': its runs take
+    the device replay, a wave is ONE group of as many run slots as it
+    has runs, and the scan meets the term widths through lone pods and
+    cut runs alone, in its smallest bucket, which the wave that meets it
+    builds. Behind the first wave that shows the widths the re-warm
+    builds the run programs (a run alone, the group at every run-slot
+    bucket a wave can fill) and none of the scan's buckets; a live wave
+    of any run count then builds no replay program. Once a wave's scan
+    decides more pods than its smallest bucket holds, every bucket of
+    the scan is warmed too, once."""
+    import time
+
+    from kubernetes_tpu.scheduler import core
+    from kubernetes_tpu.trace import spans
+
+    profile.install_compile_listener()
     monkeypatch.setenv("KUBERNETES_TPU_WARM_SCAN", "1")
+    monkeypatch.setattr(core, "WAVE_CAP", 1024)  # 64 runs of 16 at most
     controllers = _anti_controllers()
     cache, algo = _daemon(_nodes(200, ""), controllers)
     stats = algo._wave.stats
+    t_began = time.time()
 
     def wave(backlog):
+        state = cache.snapshot(controllers=controllers)
+        t = time.time()
+        got = algo.schedule_backlog(backlog, state)
+        for p, host in zip(backlog, got):
+            if host is not None:
+                p.spec.node_name = host
+                cache.add_pod(p)
+        return [c["program"] for c in profile.recent_compiles()
+                if c["at"] >= t]
+
+    built = wave(_anti_rows(range(10), 1, serial=0))  # one of each: lone
+    assert stats["rewarms"] == 1 and stats["rewarm_mismatches"] == 0
+    assert algo._warmed_run_widths == {algo._last_widths}
+    assert not algo._scan_bound and algo._warmed_widths == set()
+    assert set(algo._template_kinds.values()) == {"device"}
+    assert built.count("jit(zreplay_run)") == 1
+    assert built.count("jit(zreplay_group)") == 2  # 32, 128 run slots
+    # the scan at its smallest bucket alone: the wave's own lone pods
+    assert built.count("jit(batch_scan)") == 1
+    mine = [s for s in spans.BUFFER.snapshot(limit=16384)
+            if s["name"] == "scheduler.rewarm" and s["start"] >= t_began]
+    assert len(mine) == 1
+    attrs = mine[0]["attrs"]
+    assert (attrs["buckets"], attrs["slots"], attrs["left"]) \
+        == ([], [32, 128], 0)
+    assert attrs["steps"]["single"] == 10  # each template's run alone
+    assert attrs["steps"]["group_device"] == 2  # ten side by side; nine
+    # live waves of 3 runs, 2 and a cut one, 12 and 40: no program of
+    # the replay's or the scan's is built, and nothing warms again
+    anti_runs = stats["anti_runs"]
+
+    def rows(ts, serial):
+        return [_anti_pod(t, serial + 20 * j + i)
+                for j, t in enumerate(ts) for i in range(16)]
+
+    for backlog in (rows((0, 6, 2), 100),
+                    rows((7, 3), 200) + _anti_rows((1,), 9, serial=300),
+                    rows(tuple(range(10)) + (4, 8), 400),
+                    rows(tuple(range(10)) * 4, 1000)):
+        built = wave(backlog)
+        assert not [p for p in built if "zreplay" in p or "scan" in p
+                    or "probe" in p or "apply" in p], built
+    assert stats["rewarms"] == 1 and not algo._scan_bound
+    assert stats["anti_runs"] - anti_runs == 3 + 2 + 12 + 40
+    assert "probe" not in stats["dispatches_by_kind"]
+    # dealt in turn: 70 runs of one pod, the scan's second bucket
+    wave([_anti_pod(t % 10, 2000 + t) for t in range(70)])
+    assert stats["rewarms"] == 2 and algo._scan_bound
+    assert algo._warmed_widths == algo._warmed_run_widths \
+        == {algo._last_widths}
+    wave([_anti_pod(t % 10, 3000 + t) for t in range(70)])
+    assert stats["rewarms"] == 2
+
+
+def test_the_rewarm_of_the_replay_goes_a_run_slot_bucket_a_wave(monkeypatch):
+    """A slice of no seconds on a cluster whose runs are the device
+    replay's: the run backlog behind the first wave, then one run-slot
+    bucket behind each wave, smallest first, until none is left."""
+    from kubernetes_tpu.scheduler import core, tpu_algorithm
+
+    monkeypatch.setenv("KUBERNETES_TPU_WARM_SCAN", "1")
+    monkeypatch.setattr(tpu_algorithm, "REWARM_SLICE_S", 0.0)
+    monkeypatch.setattr(core, "WAVE_CAP", 1024)  # 64 runs of 16 at most
+    controllers = _anti_controllers()
+    cache, algo = _daemon(_nodes(24, ""), controllers)
+    stats = algo._wave.stats
+    left = []
+    for wave in range(4):
+        backlog = _dealt(range(10), 10, 100 * wave, make=_anti_pod)
         state = cache.snapshot(controllers=controllers)
         got = algo.schedule_backlog(backlog, state)
         for p, host in zip(backlog, got):
             if host is not None:
                 p.spec.node_name = host
                 cache.add_pod(p)
-
-    wave(_anti_rows(range(10), 1, serial=0))  # one of each: lone pods
-    wave(_anti_rows((0, 6, 2), 16, serial=10))  # runs: the probes
-    wave(_anti_rows((7, 3), 16, serial=30) + _anti_rows((1,), 9, serial=50))
-    assert stats["rewarms"] == 0 and algo._last_widths is not None
-    assert stats["anti_runs"] == 5 and not algo._scan_bound
-    # dealt in turn: 70 runs of one pod, the scan's second bucket
-    wave([_anti_pod(t % 10, 100 + t) for t in range(70)])
-    assert stats["rewarms"] == 1 and algo._scan_bound
-    assert algo._warmed_widths == {algo._last_widths}
-    wave([_anti_pod(t % 10, 200 + t) for t in range(70)])
-    assert stats["rewarms"] == 1
+        left.append((algo._rewarm_runs, list(algo._rewarm_slots)))
+    assert left == [(False, [32, 128]), (False, [128]), (False, []),
+                    (False, [])]
+    assert stats["rewarms"] == 3 and algo._rewarm_left == []
+    groups = stats["dispatches_by_kind"]["zreplay_group"]
+    assert groups == 2 + 2  # the run backlog's two, a bucket's one each
 
 
 # -- a cluster of five kinds of pod: one wave that changes path run by run ----
@@ -1456,9 +1728,12 @@ def test_a_mixed_wave_changes_path_run_by_run_and_picks_as_the_serial_oracle(
 
     from kubernetes_tpu.models import waveloop
 
+    import copy
+
     rng = random.Random(seed)
     nodes, controllers = _nodes(n, "a"), _anti_controllers()
     cache, algo = _daemon(nodes, controllers)
+    rounds = []
     seen, next_step, scan_pending = _steps_and_flushes(algo)
     monkeypatch.setattr(waveloop, "next_step", next_step)
     monkeypatch.setattr(algo._wave, "scan_pending", scan_pending)
@@ -1472,6 +1747,7 @@ def test_a_mixed_wave_changes_path_run_by_run_and_picks_as_the_serial_oracle(
         state = cache.snapshot(controllers=controllers)
         got = algo.schedule_backlog(backlog, state)
         assert got == oracle.schedule_backlog(backlog, state.clone())
+        rounds.append((copy.deepcopy(backlog), state.clone(), got))
         if r == 1:
             # kinds 0 and 2 take the device replay, neighbours as a group
             want = []
@@ -1497,11 +1773,14 @@ def test_a_mixed_wave_changes_path_run_by_run_and_picks_as_the_serial_oracle(
     # the three services of refused terms go to the scan, by reason
     assert set(stats["scan_reasons"]) == {"hard_affinity", "self_preferred"}
     # on one zone a plain run and a vetoed run both take the device
-    # replay, alone or as neighbours; no probe reaches the host
+    # replay, alone or as neighbours; no probe reaches the host, and
+    # the program's count of the nodes that fit at a vetoed run's probe
+    # says what the host's route reads off its tables
     kinds = stats["steps_by_kind"]
     assert kinds["scan"] and kinds["single"] and kinds["group_device"]
-    assert kinds["group_host"] == 0 and stats["anti_nodes_excluded"] == 0
-    assert stats["anti_runs"] > 0
+    assert kinds["group_host"] == 0 and "probe" not in \
+        stats["dispatches_by_kind"]
+    assert stats["anti_runs"] > 0 and stats["anti_nodes_excluded"] > 0
     # the counters are the plan's steps and the scan's dispatches
     assert sum(kinds.values()) == len(seen["steps"])
     assert {k: n for k, n in kinds.items() if n} \
@@ -1513,6 +1792,14 @@ def test_a_mixed_wave_changes_path_run_by_run_and_picks_as_the_serial_oracle(
     assert _delta(after["steps_by_kind"], shown["steps_by_kind"]) == kinds
     assert after["scan_flushes"] - shown["scan_flushes"] \
         == stats["scan_flushes"]
+    # the same waves on the host's route (a probe a run, counted on the
+    # tables it shipped): the same picks and the same three counts
+    on_host = _on_route("host")
+    for backlog, state, got in rounds:
+        assert on_host.schedule_backlog(backlog, state) == got
+    assert on_host._wave.stats["dispatches_by_kind"]["probe"] > 0
+    for key in ANTI_COUNTERS:
+        assert stats[key] == on_host._wave.stats[key], key
 
 
 def test_a_group_that_breaks_off_counts_its_single_step_too(monkeypatch):
@@ -1627,7 +1914,8 @@ def test_the_rewarm_of_a_mixed_cluster_warms_the_run_programs_once(
     # nothing twice: every program of the re-warm's wave differs
     warm_built = [p for p in built[0] if "zreplay" in p or "scan" in p]
     assert sorted(warm_built).count("jit(zreplay_run)") == 1
-    assert sorted(warm_built).count("jit(zreplay_group)") == 2  # 8, 16 slots
+    # four side by side and nine of two: both in the bucket of 32 slots
+    assert sorted(warm_built).count("jit(zreplay_group)") == 1
     assert sorted(warm_built).count("jit(batch_scan)") == 7
     # the wave after the re-warm: the same snapshot, batch, `keep`,
     # `reship` and round-robin counter as without it
@@ -1645,6 +1933,9 @@ def test_the_rewarm_of_a_mixed_cluster_warms_the_run_programs_once(
     assert len(mine) == 1
     attrs = mine[0]["attrs"]
     assert attrs["buckets"] == [64, 128, 256, 512, 1024, 2048, 4096]
+    # the scan's stretches cut a wave's groups short: no ladder of run
+    # slots on a cluster that uses the scan
+    assert attrs["slots"] == []
     assert attrs["steps"]["single"] == 4  # each eligible template alone
     assert attrs["steps"]["group_device"] == 2  # 4 side by side; 9 of two
     assert attrs["steps"]["scan"] >= 5

@@ -213,6 +213,146 @@ def test_an_atomic_gang_span_is_its_own_run_off_the_device():
         runs[0].device = False  # a classification is not changed
 
 
+HOSTNAME = "kubernetes.io/hostname"
+
+
+def _term_pod(name, group, anti=None, soft=None):
+    """A pod of service `group`, with a required hostname anti-affinity
+    term on service `anti` and a preferred one on service `soft`."""
+    import json
+
+    def term(on):
+        return {"labelSelector": {"matchLabels": {"group": on}},
+                "topologyKey": HOSTNAME}
+
+    stated = {}
+    if anti:
+        stated["requiredDuringSchedulingIgnoredDuringExecution"] = [
+            term(anti)]
+    if soft:
+        stated["preferredDuringSchedulingIgnoredDuringExecution"] = [
+            {"weight": 10, "podAffinityTerm": term(soft)}]
+    pod = _pod(name, labels={"group": group})
+    if stated:
+        pod.metadata.annotations = {
+            t.AFFINITY_ANNOTATION: json.dumps({"podAntiAffinity": stated})}
+    return pod
+
+
+def _hostnamed(nodes):
+    for node in nodes:
+        node.metadata.labels[HOSTNAME] = node.metadata.name
+    return nodes
+
+
+#: the pending pods of `KIND_CASES`: a vetoed one (its term selects its
+#: own service), an owner of a term on another service, one whose labels
+#: a bound pod's term selects, a plain one, and one with a required
+#: podAffinity term
+def _kind_pods():
+    import json
+
+    affine = _pod("affine", labels={"group": "e"})
+    affine.metadata.annotations = {t.AFFINITY_ANNOTATION: json.dumps({
+        "podAffinity": {"requiredDuringSchedulingIgnoredDuringExecution": [{
+            "labelSelector": {"matchLabels": {"group": "e"}},
+            "topologyKey": HOSTNAME}]}})}
+    return [_term_pod("vetoed", "a", anti="a"),
+            _term_pod("owner", "b", soft="c"),
+            _pod("matched", labels={"group": "c"}),
+            _pod("plain", labels={"group": "d"}), affine]
+
+
+KIND_CASES = {
+    # name: (zones, whether the host replays (`replay=`), the kinds of a
+    #        run of: vetoed, owner, matched, plain, affine)
+    "unzoned: what no grouped header probe takes is the device's":
+        ("", False, ["device", "device", "device", "pure", "scan"]),
+    "zoned: the plain run is the device's too, as before":
+        ("abc", False, ["device", "device", "device", "device", "scan"]),
+    "the host's route (replay=): a probe of its own a run":
+        ("", True, ["single", "single", "single", "pure", "scan"]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KIND_CASES))
+def test_run_kinds_say_which_runs_take_the_device_replay(case):
+    from kubernetes_tpu.models.replay import replay_fast
+    from kubernetes_tpu.models.wave import WaveScheduler
+    from kubernetes_tpu.oracle import ClusterState
+
+    zones, host, kinds = KIND_CASES[case]
+    nodes = _hostnamed(_nodes(6, zones))
+    bound = _term_pod("held", "b", soft="c")
+    bound.spec.node_name = nodes[0].metadata.name
+    # the plain pod's controller: a zoned run is the device's for the
+    # zone blend of its selector spread
+    rc = t.ReplicationController(
+        metadata=t.ObjectMeta(name="rc-d", namespace="default"),
+        spec=t.ReplicationControllerSpec(selector={"group": "d"}))
+    state = ClusterState.build(nodes, [bound], controllers=[rc])
+    snap, batch, _rep_idx = _encoded(state, _kind_pods(), 8)
+    ws = WaveScheduler(replay=replay_fast if host else None)
+    assert ws.run_kinds(snap, batch, range(5)) == kinds
+    # without a term anywhere every row is a grouped header probe's
+    bare = ClusterState.build(_hostnamed(_nodes(6, "")))
+    snap, batch, _rep_idx = _encoded(
+        bare, [_pod("p", labels={"group": "a"}),
+               _pod("q", cpu="200m", labels={"group": "b"})], 8)
+    assert ws.run_kinds(snap, batch, range(2)) == ["pure", "pure"]
+
+
+def test_the_mesh_keeps_its_runs_on_the_host_tables():
+    """The mesh's classification sets no `device`: a vetoed run there is
+    a `single` whatever the zoning."""
+    import jax
+    from jax.sharding import Mesh
+
+    from kubernetes_tpu.oracle import ClusterState
+    from kubernetes_tpu.parallel.mesh import MeshWaveScheduler
+
+    state = ClusterState.build(_hostnamed(_nodes(8, "")))
+    pods = [_term_pod(f"v{i}", "a", anti="a") for i in range(16)] \
+        + [_term_pod(f"w{i}", "b", anti="b") for i in range(16)]
+    snap, batch, rep_idx = _encoded(state, pods, 8)
+    ws = MeshWaveScheduler(
+        mesh=Mesh(np.array(jax.devices()[:2]), ("nodes",)))
+    wave = Wave(ws.config, snap, batch, rep_idx, 0, ws.max_j, ws._replay)
+    runs, policy = ws.plan(wave)
+    assert [(r.eligible, r.device, r.pure, r.veto is not None)
+            for r in runs] == [(True, False, False, True)] * 2
+    assert format_plan(plan_steps(runs, policy)) \
+        == "single[1 runs, 16 pods] single[1 runs, 16 pods]"
+
+
+def test_a_wave_of_vetoed_runs_plans_one_group_and_a_cut_run_for_the_scan():
+    """Unzoned nodes, three services' vetoed runs and the head of a
+    fourth that the wave's end cut under `min_run`: one device group,
+    one scan, and the dispatches are the plan's."""
+    from kubernetes_tpu.models.wave import WaveScheduler
+    from kubernetes_tpu.oracle import ClusterState
+
+    state = ClusterState.build(_hostnamed(_nodes(40, "")))
+    pods = [_term_pod(f"{g}{i}", g, anti=g)
+            for g in "abc" for i in range(16)] \
+        + [_term_pod(f"d{i}", "d", anti="d") for i in range(7)]
+    snap, batch, rep_idx = _encoded(state, pods, 64)
+    ws = WaveScheduler()
+    wave = Wave(ws.config, snap, batch, rep_idx, 0, ws.max_j, ws._replay)
+    steps = plan_steps(*ws.plan(wave))
+    assert format_plan(steps) \
+        == "group_device[3 runs, 48 pods] scan[1 runs, 7 pods]"
+    chosen, _carry, _last = ws.schedule_backlog(snap, batch, rep_idx)
+    assert (chosen >= 0).all()
+    for start in (0, 16, 32):  # one of a service a node
+        assert len(set(chosen[start:start + 16].tolist())) == 16
+    assert dict(ws.dispatches) == {"zreplay_group": 1, "scan": 1}
+    assert ws.stats["pods_by_path"] == {
+        "scan": 7, "single": 0, "group_host": 0, "group_device": 48}
+    assert (ws.stats["anti_runs"], ws.stats["anti_picks"],
+            ws.stats["anti_nodes_excluded"]) == (3, 48, 0)
+
+
 #: which program a step of each kind launches (a `single` on the host
 #: tables; `apply`, a fold in a dispatch of its own, is the drivers')
 LAUNCHES = {"scan": "scan", "single": "probe", "group_host": "group_probe",
